@@ -1,0 +1,671 @@
+"""The quickest proof that the system still starts on the chip.
+
+Drives the main path once, through the entry points a user would call,
+at the full width of GPT-2 124M with seeded random weights:
+
+1. **train** — ``ray_tpu.init()`` finds the host's chips without jax;
+   ``JaxTrainer`` starts one worker that owns all of them, builds
+   ``build_gpt_train(cfg, make_mesh(dp=-1))`` with no kernel pins and
+   takes a few steps on one seeded batch (24 x 1024 per chip).
+2. **kernels** — a one-chip task checks every Pallas kernel the
+   dispatch gates selected against the XLA formulation in the tree, at
+   the shapes the model runs them, outputs and gradients.
+3. **serve** — after those workers have exited and released the chips,
+   ``serve.run(GPTDeployment.bind(model="gpt2", ...))`` puts a replica
+   on one chip and answers streaming requests that span prefill buckets,
+   share a prefix, and arrive while others decode; a second, identical
+   wave must compile nothing.
+
+This process is the parent: it never initialises a jax backend (a
+process that has touched jax holds the chip its workers need).  Each
+phase prints one JSON line; a phase that fails raises and the script
+exits non-zero.  The last line of a passing run is
+``{"ok": true, "device": {...}}`` with the device as jax reported it in
+the train worker.  Times in the phase lines are observations of one run,
+not benchmark results.
+
+It exits non-zero, printing no result, when the host has no TPU chip.
+``--rehearse-on-cpu[=N]`` walks the same code at toy shapes on N virtual
+CPU devices (Pallas in interpret mode) to debug the script without a
+chip; a rehearsal never prints the result line and always exits 3.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import os
+import shutil
+import sys
+import tempfile
+import threading
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+TRAIN_STEPS = 16
+
+# Parity tolerances, as max|kernel - reference| / max|reference| per
+# tensor.  The references run in f32 on the kernel's own bf16 inputs
+# (or, for the two XLA mirrors the tree names, in the same mixed
+# precision); the kernels round probabilities, rotated q/k, score
+# gradients and outputs to bf16 (2^-8 relative) at points the
+# references do not, so a few bf16 ulps of the tensor's largest element
+# is what a correct kernel shows.  A wrong lane roll, mask or block index
+# moves whole rows and shows as O(1).
+TOL_OUT = 2e-2
+TOL_GRAD = 5e-2
+TOL_LOSS = 1e-3      # f32-accumulated scalar sums
+
+REAL = {
+    "model": {"preset": "gpt2",
+              "kwargs": {"vocab_size": 50304, "max_seq": 1024,
+                         "dtype": "bfloat16"}},
+    # the r05 recipe for the single-chip step
+    "train_kwargs": {"remat": False, "unroll_layers": True,
+                     "ce_chunk": -1},
+    "batch_per_chip": 24, "seq": 1024,
+    # (prompt length, new tokens); page 128, buckets 32..1024
+    "shared_prefix": 288,
+    "requests": {"r0": (20, 48), "r1": (100, 64), "r2": (300, 32),
+                 "r3": (600, 40), "r4": (340, 32), "r5": (50, 64),
+                 "r6": (200, 32)},
+}
+TOY = {
+    # wide enough (d 128, head_dim 64) for every kernel gate to pass, so
+    # a rehearsal interprets the kernels the chip compiles; the serving
+    # deployment only takes presets, whose toy one is narrower
+    "model": {"preset": None,
+              "kwargs": {"vocab_size": 512, "d_model": 128, "n_layers": 2,
+                         "n_heads": 2, "max_seq": 512,
+                         "dtype": "bfloat16"}},
+    "serve_model": {"preset": "tiny",
+                    "kwargs": {"vocab_size": 512, "max_seq": 512,
+                               "dtype": "bfloat16"}},
+    "train_kwargs": {"remat": False, "unroll_layers": True,
+                     "ce_chunk": -1},
+    "batch_per_chip": 2, "seq": 256,
+    "shared_prefix": 288,
+    "requests": {"r0": (20, 8), "r1": (100, 8), "r2": (300, 6),
+                 "r3": (40, 8), "r4": (340, 6), "r5": (50, 8),
+                 "r6": (200, 6)},
+}
+
+
+def emit(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+# ---------------------------------------------------------------------------
+# code that runs in the workers (pickled by value from __main__)
+# ---------------------------------------------------------------------------
+
+def _model_cfg(spec: dict, **extra):
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import GPTConfig
+    kwargs = dict(spec["kwargs"], **extra)
+    kwargs["dtype"] = getattr(jnp, kwargs["dtype"])
+    if spec["preset"] is None:
+        return GPTConfig(**kwargs)
+    return getattr(GPTConfig, spec["preset"])(**kwargs)
+
+
+def _device_block(rehearsal: bool) -> dict:
+    """This process's device as jax reports it; a non-TPU platform in a
+    run that is not a rehearsal fails the run."""
+    import jax
+    devices = jax.devices()
+    block = {"platform": devices[0].platform,
+             "kind": devices[0].device_kind, "count": len(devices)}
+    if block["platform"] != "tpu" and not rehearsal:
+        raise RuntimeError(f"worker is on {block}, not on a TPU")
+    return block
+
+
+def _peak_hbm(devices) -> dict:
+    out = {}
+    for d in devices:
+        stats = d.memory_stats() or {}
+        out[str(d.id)] = {k: stats.get(k) for k in
+                          ("peak_bytes_in_use", "bytes_in_use",
+                           "bytes_limit")}
+    return out
+
+
+def _hlo_kernels(hlo: str) -> dict:
+    import re
+    scopes = ("attn/pack2", "attn/flash", "attn/xla", "ce/flash_norm",
+              "ce/flash", "ce/xla", "norm/fused_epilogue")
+    return {"custom_calls": hlo.count("tpu_custom_call"),
+            "all_reduce_ops": len(re.findall(r"= [^\n]*? all-reduce", hlo)),
+            "scopes": [s for s in scopes
+                       if re.search(rf'op_name="[^"]*{s}[/"]', hlo)]}
+
+
+def train_loop(config: dict) -> None:
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu._private.compile_cache import (compile_stats,
+                                                enable_compile_cache)
+    from ray_tpu.models import training
+    from ray_tpu.ops import flash_ce, fused_norm
+    from ray_tpu.ops.attention import uses_pack2
+    from ray_tpu.parallel.mesh import make_mesh
+
+    cache_dir = enable_compile_cache()
+    device = _device_block(config["rehearsal"])
+    devices = jax.devices()
+    mesh = make_mesh(dp=-1)
+    cfg = _model_cfg(config["model"], **config["train_kwargs"])
+    fns = training.build_gpt_train(cfg, mesh)       # no kernel pins
+    n = len(devices)
+    B, S = config["batch_per_chip"] * n, config["seq"]
+    batch = jax.device_put(
+        training.synthetic_lm_batch(jax.random.PRNGKey(1), B, S,
+                                    cfg.vocab_size),
+        fns["batch_sharding"])
+    state = fns["init_fn"](jax.random.PRNGKey(0))
+    first_step_s = None
+    t0 = time.monotonic()
+    for i in range(config["steps"]):
+        state, metrics = fns["step_fn"](state, batch)
+        loss = float(metrics["loss"])                # waits for the step
+        if first_step_s is None:
+            first_step_s = time.monotonic() - t0
+        train.report({"step": i, "loss": loss})
+
+    # what the gates chose, from the shapes the step ran ...
+    N, d, V = B * S, cfg.d_model, cfg.vocab_size
+    gate = dict(n_devices=n, norm=cfg.norm, has_bias=cfg.use_bias)
+    if flash_ce.uses_flash_ce_norm(N, d, V, **gate):
+        ce = "flash_norm"
+    elif flash_ce.uses_flash_ce(N, d, V, n_devices=n):
+        ce = "flash"
+    else:
+        ce = "xla_noremat" if cfg.ce_chunk < 0 else "xla_chunked"
+    gates = {
+        "attn_pack2": uses_pack2(S, S, cfg.n_heads, cfg.head_dim),
+        "ce": ce,
+        "fuse_norm": bool(fused_norm.out_proj_norm_plan(
+            N, cfg.n_heads * cfg.head_dim, d, seq=S, **gate)),
+    }
+    # ... and what the compiled step holds (the jitted call's own
+    # executable comes back out of the cache)
+    raw_step = fns.get("raw_step_fn", fns["step_fn"])
+    hlo = _hlo_kernels(raw_step.lower(state, batch).compile().as_text())
+    summary = {
+        "device": device, "mesh": dict(mesh.shape), "gates": gates,
+        "hlo": hlo, "batch": [B, S],
+        "batch_shards": {str(s.device.id): list(s.data.shape)
+                         for s in batch["tokens"].addressable_shards},
+        "param_bytes": sum(p.nbytes for p in jax.tree.leaves(state.params)),
+        "peak_hbm": _peak_hbm(devices),
+        "first_step_s": round(first_step_s, 2),
+        "compile": compile_stats(), "compile_cache_dir": cache_dir,
+    }
+    tel = fns.get("telemetry")
+    if tel is not None:
+        s = tel.summary()
+        summary["observed"] = {k: s.get(k) for k in
+                               ("steady_step_s",
+                                "tokens_per_sec_per_device", "mfu")}
+    train.report({"summary": summary})
+
+
+def _rel_err(got, want) -> float:
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want))
+                 / max(float(np.max(np.abs(want))), 1e-30))
+
+
+def kernel_parity(config: dict) -> dict:
+    """Every Pallas kernel the gates select on this host, against the XLA
+    formulation in the tree, at the shapes the model runs it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private.compile_cache import (compile_stats,
+                                                enable_compile_cache)
+    from ray_tpu.inference.config import default_buckets, infer_config
+    from ray_tpu.ops import attention as A
+    from ray_tpu.ops import flash_ce, fused_norm
+    from ray_tpu.ops.substrate import use_interpret
+    from ray_tpu.parallel.ring_attention import local_attention
+
+    enable_compile_cache()
+    device = _device_block(config["rehearsal"])
+    cfg = _model_cfg(config["model"])
+    n_train = config["train_devices"]
+    B, S = config["batch_per_chip"], config["seq"]
+    H, D, d, V = cfg.n_heads, cfg.head_dim, cfg.d_model, cfg.vocab_size
+    N, K = B * S, H * D
+    f32, dt = jnp.float32, cfg.dtype
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 64))
+    rows = []
+
+    def rand(shape, scale=1.0, dtype=dt):
+        return (jax.random.normal(next(keys), shape) * scale).astype(dtype)
+
+    def up(x):
+        return x.astype(f32)
+
+    def row(kernel, shape, errs, tols):
+        rows.append({"kernel": kernel, "shape": shape,
+                     "err": {k: float(f"{v:.3g}") for k, v in errs.items()},
+                     "ok": all(errs[k] <= tols[k] for k in errs)})
+
+    def vjp_np(fn, args, cts):
+        """(outputs, grads) as host arrays, so nothing stays on the
+        device between the kernel and its reference; jitted, so the
+        reference's [N, V]-sized intermediates are fused, not each
+        materialised."""
+        def both(args, cts):
+            out, pull = jax.vjp(fn, *args)
+            return out, pull(cts)
+        return jax.tree.map(np.asarray, jax.jit(both)(args, cts))
+
+    # -- training attention: fused-RoPE flash (pack2 at head_dim 64) ------
+    if A.supports(S, S, D):
+        pos = jnp.arange(S)
+        q, k, v = (rand((B, S, H, D)) for _ in range(3))
+        w = rand((B, S, H, D))
+        o, g = vjp_np(lambda q, k, v: A.flash_attention(
+            q, k, v, positions=pos, rope_theta=cfg.rope_theta), (q, k, v), w)
+        o_ref, g_ref = vjp_np(lambda q, k, v: local_attention(
+            A.rope_rotate(q, pos, cfg.rope_theta),
+            A.rope_rotate(k, pos, cfg.rope_theta), v, causal=True),
+            (up(q), up(k), up(v)), up(w))
+        name = "attn/pack2" if A.uses_pack2(S, S, H, D) else "attn/flash"
+        row(name + "+rope fwd+bwd", [B, S, H, D],
+            {"o": _rel_err(o, o_ref), "dq": _rel_err(g[0], g_ref[0]),
+             "dk": _rel_err(g[1], g_ref[1]), "dv": _rel_err(g[2], g_ref[2])},
+            {"o": TOL_OUT, "dq": TOL_GRAD, "dk": TOL_GRAD, "dv": TOL_GRAD})
+        del q, k, v, w
+
+    # -- fused out-proj + residual + rmsnorm epilogue ----------------------
+    norm_gate = dict(norm=cfg.norm, has_bias=cfg.use_bias)
+    if fused_norm.out_proj_norm_plan(N, K, d, seq=S, n_devices=n_train,
+                                     **norm_gate):
+        a, wo, resid = rand((N, K)), rand((K, d), K ** -0.5), rand((N, d))
+        scale = (1 + 0.1 * rand((d,), dtype=f32)).astype(dt)
+        cts = (rand((N, d)), rand((N, d)))
+        eps = 1e-6
+        o, g = vjp_np(lambda *x: fused_norm.matmul_residual_norm(
+            *x, eps=eps), (a, wo, resid, scale), cts)
+        o_ref, g_ref = vjp_np(
+            lambda *x: fused_norm.xla_matmul_residual_norm(*x, eps=eps),
+            (a, wo, resid, scale), cts)
+        names = ("da", "dw", "dresid", "dscale")
+        errs = {"r": _rel_err(o[0], o_ref[0]), "y": _rel_err(o[1], o_ref[1])}
+        errs.update({nm: _rel_err(x, y)
+                     for nm, x, y in zip(names, g, g_ref)})
+        row("norm/fused_epilogue fwd+bwd", [N, K, d], errs,
+            {"r": TOL_OUT, "y": TOL_OUT, **dict.fromkeys(names, TOL_GRAD)})
+        del a, wo, resid, cts
+
+    # -- flash-CE with the final norm in its prologue ----------------------
+    if flash_ce.uses_flash_ce_norm(N, d, V, n_devices=n_train, **norm_gate):
+        x, head = rand((N, d)), rand((d, V), 0.02)
+        scale = (1 + 0.1 * rand((d,), dtype=f32)).astype(dt)
+        tgt = jax.random.randint(next(keys), (N,), 0, V)
+        tgt = jnp.where(jax.random.uniform(next(keys), (N,)) < 0.05, -1, tgt)
+        eps = 1e-6
+
+        def fused(x, head, scale):
+            s, n = flash_ce.flash_ce_norm_sum(x, head, tgt, scale, eps=eps)
+            return s / n
+
+        def unfused(x, head, scale):
+            x32 = x.astype(f32)
+            x32 = x32 * jax.lax.rsqrt(
+                jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+            y = (x32 * scale.astype(f32)).astype(x.dtype)
+            s, n = flash_ce._xla_ce_sum(y, head, tgt)
+            return s / n
+
+        loss, g = jax.tree.map(np.asarray, jax.jit(jax.value_and_grad(
+            fused, (0, 1, 2)))(x, head, scale))
+        ref, g_ref = jax.tree.map(np.asarray, jax.jit(jax.value_and_grad(
+            unfused, (0, 1, 2)))(x, head, scale))
+        loss, ref = float(loss), float(ref)
+        names = ("dx", "dhead", "dscale")
+        errs = {"loss": abs(loss - ref) / abs(ref)}
+        errs.update({nm: _rel_err(a_, b_)
+                     for nm, a_, b_ in zip(names, g, g_ref)})
+        row("ce/flash_norm fwd+bwd", [N, d, V], errs,
+            {"loss": TOL_LOSS, **dict.fromkeys(names, TOL_GRAD)})
+        del x, head, g, g_ref
+
+    # -- serving: prefill attention and epilogue per bucket, decode -------
+    icfg = infer_config()
+    buckets = [b for b in (icfg.buckets or default_buckets(cfg.max_seq))
+               if b <= cfg.max_seq]
+    attn_buckets = [b for b in buckets if A.supports(b, b, D)]
+    for b in sorted({buckets[0], buckets[-1], *attn_buckets[:1]}):
+        if not use_interpret() and b in attn_buckets:
+            q, k, v = (rand((1, b, H, D)) for _ in range(3))
+            o = jax.jit(lambda q, k, v: A.flash_attention(
+                q, k, v, causal=True))(q, k, v)
+            o_ref = jax.jit(lambda q, k, v: local_attention(
+                q, k, v, causal=True))(up(q), up(k), up(v))
+            name = "attn/pack2" if A.uses_pack2(b, b, H, D) else "attn/flash"
+            row(name + " prefill fwd", [1, b, H, D],
+                {"o": _rel_err(o, o_ref)}, {"o": TOL_OUT})
+        if fused_norm.out_proj_norm_plan(b, K, d, seq=b, **norm_gate):
+            a, wo, resid = rand((b, K)), rand((K, d), K ** -0.5), rand((b, d))
+            scale = jnp.ones((d,), dt)
+            r, y = jax.jit(fused_norm.matmul_residual_norm)(
+                a, wo, resid, scale)
+            r_ref, y_ref = jax.jit(fused_norm.xla_matmul_residual_norm)(
+                a, wo, resid, scale)
+            row("norm/fused_epilogue prefill fwd", [b, K, d],
+                {"r": _rel_err(r, r_ref), "y": _rel_err(y, y_ref)},
+                {"r": TOL_OUT, "y": TOL_OUT})
+    ctx = -(-cfg.max_seq // icfg.page_size) * icfg.page_size
+    if A.decode_uses_pallas(ctx, D, impl=icfg.decode_impl):
+        slots = icfg.slots
+        q = rand((slots, H, D))
+        k, v = rand((slots, ctx, H, D)), rand((slots, ctx, H, D))
+        lengths = jax.random.randint(next(keys), (slots,), 1, ctx + 1)
+        o = jax.jit(lambda *x: A.decode_attention(*x, impl="pallas"))(
+            q, k, v, lengths)
+        o_ref = jax.jit(lambda *x: A.decode_attention(*x, impl="xla"))(
+            up(q), up(k), up(v), lengths)
+        row("attn/decode_pallas fwd", [slots, ctx, H, D],
+            {"o": _rel_err(o, o_ref)}, {"o": TOL_OUT})
+
+    return {"device": device, "kernels": rows,
+            "tolerance": {"out": TOL_OUT, "grad": TOL_GRAD,
+                          "loss": TOL_LOSS},
+            "peak_hbm": _peak_hbm(jax.devices()),
+            "compile": compile_stats()}
+
+
+# ---------------------------------------------------------------------------
+# the parent's phases
+# ---------------------------------------------------------------------------
+
+def phase_train(sizes: dict, chips: int, rehearsal: bool) -> dict:
+    from ray_tpu.train import RunConfig, ScalingConfig
+    from ray_tpu.train.jax import JaxTrainer
+
+    storage = tempfile.mkdtemp(prefix="chip_smoke_train_")
+    try:
+        result = JaxTrainer(
+            train_loop,
+            train_loop_config={**sizes, "steps": TRAIN_STEPS,
+                               "rehearsal": rehearsal},
+            scaling_config=ScalingConfig(
+                num_workers=1, use_tpu=True,
+                resources_per_worker={"TPU": chips}),
+            run_config=RunConfig(name="chip_smoke",
+                                 storage_path=storage)).fit()
+    finally:
+        shutil.rmtree(storage, ignore_errors=True)
+    if result.error is not None:
+        raise result.error
+    losses = [m["loss"] for m in result.metrics_history if "loss" in m]
+    summary = result.metrics["summary"]
+    emit("train", losses=[round(x, 4) for x in losses], **summary)
+
+    vocab = sizes["model"]["kwargs"]["vocab_size"]
+    check(len(losses) >= 8, f"{len(losses)} train steps reported")
+    check(all(math.isfinite(x) for x in losses), f"loss not finite: {losses}")
+    check(abs(losses[0] - math.log(vocab)) < 0.5,
+          f"first loss {losses[0]} is not ~ln({vocab})={math.log(vocab):.3f}")
+    check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    check(summary["device"]["count"] == chips,
+          f"worker saw {summary['device']['count']} devices of {chips}")
+    per_chip = [sizes["batch_per_chip"], sizes["seq"]]
+    check(len(summary["batch_shards"]) == chips
+          and all(s == per_chip for s in summary["batch_shards"].values()),
+          f"batch shards {summary['batch_shards']} != {per_chip} x {chips}")
+    gates, scopes = summary["gates"], summary["hlo"]["scopes"]
+    for gate_on, scope in ((gates["attn_pack2"], "attn/pack2"),
+                           (gates["ce"] == "flash_norm", "ce/flash_norm"),
+                           (gates["fuse_norm"], "norm/fused_epilogue")):
+        check(gate_on == (scope in scopes),
+              f"gate says {scope}={gate_on}, compiled step has {scopes}")
+    if chips > 1:
+        check(summary["hlo"]["all_reduce_ops"] > 0,
+              "no gradient all-reduce in the dp step's HLO")
+    if not rehearsal:
+        for dev, mem in summary["peak_hbm"].items():
+            check((mem["peak_bytes_in_use"] or 0) > summary["param_bytes"],
+                  f"device {dev} peak HBM {mem} below the parameter bytes")
+    return summary
+
+
+def phase_kernels(sizes: dict, chips: int, rehearsal: bool) -> None:
+    import ray_tpu
+    task = ray_tpu.remote(num_tpus=1)(kernel_parity)
+    report = ray_tpu.get(task.remote({**sizes, "train_devices": chips,
+                                      "rehearsal": rehearsal}),
+                         timeout=900)
+    emit("kernels", **report)
+    check(report["device"]["count"] == 1 or rehearsal,
+          f"task owns {report['device']['count']} chips, asked for one")
+    check(report["kernels"], "no Pallas kernel was selected")
+    bad = [r for r in report["kernels"] if not r["ok"]]
+    check(not bad, f"kernel parity outside tolerance: {bad}")
+
+
+def _prompts(sizes: dict, seed: int) -> dict:
+    """Seeded prompts of the planned lengths; r2 and r4 share a prefix
+    of more than two pages."""
+    import numpy as np
+    rng = np.random.RandomState(seed)
+    vocab = sizes.get("serve_model",
+                      sizes["model"])["kwargs"]["vocab_size"]
+    shared = rng.randint(0, vocab, size=sizes["shared_prefix"]).tolist()
+    out = {}
+    for name, (plen, _new) in sizes["requests"].items():
+        body = rng.randint(0, vocab, size=plen).tolist()
+        if name in ("r2", "r4"):
+            body[:len(shared)] = shared
+        out[name] = body
+    return out
+
+
+def _wave(handle, sizes: dict, seed: int) -> dict:
+    """One wave of streaming requests: r0-r2 at once; r3 and r4 (the
+    prefix sharer) once r2's prompt is in the cache; r5 and r6 while r0
+    is mid-decode."""
+    prompts = _prompts(sizes, seed)
+    got = {name: [] for name in prompts}
+    errors, threads = [], {}
+    r2_started, r0_midway = threading.Event(), threading.Event()
+
+    def stream(name):
+        try:
+            payload = {"tokens": prompts[name],
+                       "max_new_tokens": sizes["requests"][name][1]}
+            if name in ("r1", "r5"):       # the sampler's other branch
+                payload.update(temperature=0.8, top_p=0.95, seed=seed)
+            for tok in handle.options(stream=True).remote(payload):
+                got[name].append(tok)
+                if name == "r2":
+                    r2_started.set()
+                if name == "r0" and len(got[name]) >= 4:
+                    r0_midway.set()
+        except BaseException as e:  # noqa: BLE001 — re-raised by _wave
+            errors.append((name, e))
+            r2_started.set()
+            r0_midway.set()
+
+    def start(*names):
+        for name in names:
+            threads[name] = threading.Thread(target=stream, args=(name,),
+                                             name=f"stream-{name}")
+            threads[name].start()
+
+    start("r0", "r1", "r2")
+    check(r2_started.wait(600), "r2 produced no token in 600 s")
+    start("r3", "r4")
+    check(r0_midway.wait(600), "r0 produced no token in 600 s")
+    start("r5", "r6")
+    for name, t in threads.items():
+        t.join(600)
+        check(not t.is_alive(), f"stream {name} did not finish in 600 s")
+    if errors:
+        raise errors[0][1]
+    return got
+
+
+def phase_serve(sizes: dict, rehearsal: bool) -> None:
+    import jax.numpy as jnp
+
+    import ray_tpu.serve as serve
+    from ray_tpu.inference.serve_gpt import GPTDeployment
+
+    model = sizes.get("serve_model", sizes["model"])
+    model_config = dict(model["kwargs"],
+                        dtype=getattr(jnp, model["kwargs"]["dtype"]))
+    t0 = time.monotonic()
+    handle = serve.run(GPTDeployment.bind(model=model["preset"],
+                                          model_config=model_config),
+                       name="gpt")
+    deploy_s = time.monotonic() - t0
+
+    def summary():
+        return handle.telemetry_summary.remote().result(timeout_s=120)
+
+    t0 = time.monotonic()
+    _wave(handle, sizes, seed=1)                     # warms every shape
+    warm_s, warm = time.monotonic() - t0, summary()
+    t0 = time.monotonic()
+    got = _wave(handle, sizes, seed=2)
+    wave_s, after = time.monotonic() - t0, summary()
+
+    device, stats = after["device"], after["stats"]
+    recompiles = (after["jax_compiles"]["compiles"]
+                  - warm["jax_compiles"]["compiles"])
+    decode_steps = after.get("decode_steps", 0) - warm.get("decode_steps", 0)
+    decode_tokens = (after.get("decode_tokens", 0)
+                     - warm.get("decode_tokens", 0))
+    emit("serve", device={k: device[k] for k in ("platform", "kind",
+                                                 "count")},
+         decode_impl=stats["decode_impl"], kv_dtype=stats["kv_dtype"],
+         requests=len(got),
+         tokens_returned={k: len(v) for k, v in got.items()},
+         engine_compiles=stats["compiles"],
+         engine_compiles_after_warmup={
+             k: v - warm["stats"]["compiles"][k]
+             for k, v in stats["compiles"].items()},
+         recompiles_after_warmup=recompiles,
+         compile=after["jax_compiles"],
+         prefix={k: stats["prefix"][k] - warm["stats"]["prefix"][k]
+                 for k in ("hit_pages", "requests_hit")},
+         decode_tokens_per_step=round(decode_tokens / max(decode_steps, 1),
+                                      2),
+         peak_hbm=device["memory"] and {
+             k: device["memory"].get(k) for k in
+             ("peak_bytes_in_use", "bytes_in_use", "bytes_limit")},
+         observed={"deploy_s": round(deploy_s, 2),
+                   "warm_wave_s": round(warm_s, 2),
+                   "second_wave_s": round(wave_s, 2)})
+
+    check(device["platform"] == "tpu" or rehearsal,
+          f"replica is on {device['platform']}, not on a TPU")
+    # (virtual CPU devices are not chips: nothing confines them)
+    check(device["count"] == 1 or rehearsal,
+          f"replica owns {device['count']} chips, asked for one")
+    vocab = model["kwargs"]["vocab_size"]
+    for name, (_plen, new) in sizes["requests"].items():
+        check(len(got[name]) == new,
+              f"{name}: {len(got[name])} of {new} tokens returned")
+        check(all(isinstance(t, int) and 0 <= t < vocab for t in got[name]),
+              f"{name}: token outside the vocabulary: {got[name]}")
+    check(len(got) >= 6, f"{len(got)} requests served")
+    check(recompiles == 0 and not any(
+        v - warm["stats"]["compiles"][k]
+        for k, v in stats["compiles"].items()),
+        f"{recompiles} compiles after warm-up")
+    buckets_hit = stats["compiles"]["prefill"]
+    check(buckets_hit >= 3, f"{buckets_hit} prefill buckets compiled")
+    check(stats["prefix"]["hit_pages"]
+          - warm["stats"]["prefix"]["hit_pages"] >= 2,
+          f"no >=2-page prefix hit: {stats['prefix']}")
+    check(decode_tokens > decode_steps,
+          "no decode step carried more than one sequence")
+    check(stats["decode_impl"] == "pallas" or rehearsal,
+          f"decode dispatched to {stats['decode_impl']}")
+
+
+def main() -> int:
+    flag = next((a for a in sys.argv[1:]
+                 if a.startswith("--rehearse-on-cpu")), None)
+    rehearsal = flag is not None
+    if rehearsal:
+        virtual = int(flag.partition("=")[2] or 1)
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={virtual}")
+
+    from ray_tpu.accelerators.tpu import detect_num_tpus
+    chips = virtual if rehearsal else detect_num_tpus()
+    if chips < 1:
+        print("chip_smoke: no TPU chip on this host (no /dev/accel* or "
+              "/dev/vfio/<n> device node, or JAX_PLATFORMS asks for "
+              f"{os.environ.get('JAX_PLATFORMS')!r})", file=sys.stderr)
+        return 2
+
+    # built from what git would commit: the native object store is
+    # git-ignored, so start without it and let the runtime rebuild it
+    # from src/shmstore/shmstore.cc.  That takes g++ and a checkout that
+    # can be written to and loaded from; a host without them runs every
+    # phase below on the Python file store, the runtime's other complete
+    # backend, and the init line says which one it was and why.
+    native = os.path.join(HERE, "ray_tpu", "_native", "libshmstore.so")
+    if os.path.exists(native):
+        os.remove(native)
+
+    import ray_tpu
+    from ray_tpu._private.worker import global_worker
+    sizes = TOY if rehearsal else REAL
+    # no num_tpus: autodetection finds the chips (a rehearsal has none
+    # to find and names its virtual ones)
+    ray_tpu.init(num_tpus=chips if rehearsal else None)
+    try:
+        store = global_worker().store
+        emit("init", chips=chips, rehearsal=rehearsal,
+             cluster_tpus=ray_tpu.cluster_resources().get("TPU", 0),
+             object_store=store.backend,
+             native_store_rebuilt=os.path.exists(native),
+             native_store_error=store.native_error)
+        check(ray_tpu.cluster_resources().get("TPU", 0) == chips,
+              "init() did not register the host's chips")
+
+        train = phase_train(sizes, chips, rehearsal)
+        phase_kernels(sizes, chips, rehearsal)
+        phase_serve(sizes, rehearsal)
+    finally:
+        import ray_tpu.serve as serve
+        if ray_tpu.is_initialized():
+            serve.shutdown()
+            ray_tpu.shutdown()
+
+    # the parent imported jax (GPTDeployment's module does) but must
+    # never have initialised a backend: that would have taken the chip
+    from jax._src import xla_bridge
+    check(not xla_bridge._backends,
+          f"the parent initialised jax backends {list(xla_bridge._backends)}")
+    if rehearsal:
+        print("chip_smoke: rehearsal on the CPU complete — not a chip run, "
+              "no result", file=sys.stderr)
+        return 3
+    print(json.dumps({"ok": True, "device": train["device"]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

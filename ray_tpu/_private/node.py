@@ -11,6 +11,7 @@ from __future__ import annotations
 import atexit
 import getpass
 import json
+import logging
 import os
 import shutil
 import subprocess
@@ -27,6 +28,8 @@ from ray_tpu._private.ids import JobID, NodeID, WorkerID
 from ray_tpu._private.node_manager import NodeManager
 from ray_tpu._private.object_store import ShmStore
 from ray_tpu._private.worker import CoreWorker
+
+logger = logging.getLogger(__name__)
 
 
 def _default_tmp_root() -> str:
@@ -349,6 +352,11 @@ class HeadNode:
         with open(os.path.join(self.session_dir, "cp_address"), "w") as f:
             f.write(self.cp_sock_path)
         self.store = ShmStore(self.shm_root, spill_dir=self.spill_dir)
+        if self.store.native_error:
+            # said once, by the head: every worker falls back the same way
+            logger.warning("object store: using the Python file store, the "
+                           "native arena is unavailable (%s)",
+                           self.store.native_error)
         self.node_id = NodeID.from_random().binary()
         self.resources = default_resources(num_cpus, num_tpus, resources)
         self.node_manager = NodeManager(

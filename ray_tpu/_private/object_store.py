@@ -96,6 +96,8 @@ class ShmStore:
         # Native C++ arena fastpath (src/shmstore): one mmap shared by all
         # node processes; first process creates, the rest attach.
         self._arena = None
+        # why the arena is missing where it was wanted
+        self.native_error: Optional[str] = None
         if os.environ.get("RAY_TPU_DISABLE_NATIVE_STORE") != "1":
             try:
                 from ray_tpu._private.shmstore_native import NativeArena
@@ -103,8 +105,14 @@ class ShmStore:
                 self._arena = NativeArena(
                     os.path.join(root, "arena"), capacity=arena_cap,
                     create=True)
-            except Exception:  # noqa: BLE001 - python file path still works
-                self._arena = None
+            except Exception as e:  # noqa: BLE001 - python file path still works
+                self.native_error = f"{type(e).__name__}: {e}"
+
+    @property
+    def backend(self) -> str:
+        """``"native"`` (C++ arena for small objects, files above it) or
+        ``"python"`` (files only)."""
+        return "python" if self._arena is None else "native"
 
     # -------------------------------------------------------- paths -----
     def _path(self, object_id: bytes) -> str:

@@ -16,7 +16,14 @@ from typing import Optional
 
 _LIB_LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
-_BUILD_FAILED = False
+# why this process has no native library (None: it has one, or has not
+# tried); the build is attempted once per process
+_UNAVAILABLE: Optional[str] = None
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why :func:`get_lib` returned None, in the toolchain's own words."""
+    return _UNAVAILABLE
 
 
 def _native_dir() -> str:
@@ -34,29 +41,36 @@ def _src_dir() -> str:
 
 
 def _ensure_built() -> Optional[str]:
-    global _BUILD_FAILED
+    global _UNAVAILABLE
     path = _lib_path()
     src = os.path.join(_src_dir(), "shmstore.cc")
     if os.path.exists(path) and os.path.exists(src) and \
             os.path.getmtime(path) >= os.path.getmtime(src):
         return path
-    if _BUILD_FAILED or not os.path.exists(src):
-        return path if os.path.exists(path) else None
-    os.makedirs(_native_dir(), exist_ok=True)
-    try:
-        subprocess.run(
-            ["g++", "-O2", "-fPIC", "-std=c++17", "-shared", "-o",
-             path + ".tmp", src, "-lpthread"],
-            check=True, capture_output=True, timeout=120)
-        os.replace(path + ".tmp", path)
-        return path
-    except (subprocess.SubprocessError, OSError):
-        _BUILD_FAILED = True
-        return path if os.path.exists(path) else None
+    if not os.path.exists(src):
+        _UNAVAILABLE = f"no source at {src}"
+    elif _UNAVAILABLE is None:
+        # a name of its own: processes that start together and all find
+        # the library missing must not link into one another's output
+        tmp = f"{path}.tmp.{os.getpid()}"
+        try:
+            os.makedirs(_native_dir(), exist_ok=True)
+            subprocess.run(
+                ["g++", "-O2", "-fPIC", "-std=c++17", "-shared", "-o",
+                 tmp, src, "-lpthread"],
+                check=True, capture_output=True, timeout=120)
+            os.replace(tmp, path)
+            return path
+        except subprocess.CalledProcessError as e:
+            _UNAVAILABLE = (f"g++ exited {e.returncode}: "
+                            f"{e.stderr.decode(errors='replace')[-500:]}")
+        except (subprocess.SubprocessError, OSError) as e:
+            _UNAVAILABLE = f"build failed: {type(e).__name__}: {e}"
+    return path if os.path.exists(path) else None
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _LIB
+    global _LIB, _UNAVAILABLE
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
@@ -65,7 +79,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
+            _UNAVAILABLE = f"cannot load {path}: {e}"
             return None
         lib.shmstore_create.restype = ctypes.c_void_p
         lib.shmstore_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
@@ -108,7 +123,8 @@ class NativeArena:
                  max_entries: int = 65536, create: bool = False):
         lib = get_lib()
         if lib is None:
-            raise RuntimeError("native shmstore unavailable")
+            raise RuntimeError(
+                f"native shmstore unavailable ({unavailable_reason()})")
         self.lib = lib
         self.path = path
         if create:

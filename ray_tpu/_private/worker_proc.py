@@ -116,12 +116,13 @@ class WorkerProcess:
                 continue
             spec: TaskSpec = msg["spec"]
             chips = msg.get("chips")
+            host_chips = msg.get("host_chips", 0)
             if spec.actor_creation:
-                self._execute_creation(spec, chips)
+                self._execute_creation(spec, chips, host_chips)
             elif spec.actor_id is not None:
                 self._dispatch_actor_task(spec)
             else:
-                self._execute_task(spec, chips)
+                self._execute_task(spec, chips, host_chips)
 
     # ------------------------------------------------------------------
     def _resolve_args(self, spec: TaskSpec):
@@ -135,13 +136,21 @@ class WorkerProcess:
         kwargs = {k: one(v) for k, v in spec.kwargs.items()}
         return args, kwargs
 
-    def _set_visible_chips(self, chips: Optional[List[int]]):
-        # Parity with the reference's per-task accelerator isolation
-        # (python/ray/_private/accelerators/tpu.py TPU_VISIBLE_CHIPS).
-        if chips is not None:
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, chips))
-            os.environ.setdefault("TPU_CHIPS_PER_HOST_BOUNDS",
-                                  f"1,{len(chips)},1")
+    def _set_visible_chips(self, chips: Optional[List[int]],
+                           host_chips: int):
+        """Take ownership of the chips the node manager assigned (parity
+        with the reference's per-task accelerator isolation,
+        python/ray/_private/accelerators/tpu.py).  One assignment per
+        process: the node manager retires a chip-holding worker after
+        its task instead of reusing it, because a process that has
+        initialised jax cannot be pointed at other chips."""
+        if chips is None:
+            return
+        from ray_tpu._private.compile_cache import enable_compile_cache
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+        TPUAcceleratorManager.set_visible_accelerator_ids(chips,
+                                                          host_chips)
+        enable_compile_cache()
 
     def _commit_results(self, spec: TaskSpec, result: Any):
         if spec.is_generator:
@@ -201,14 +210,14 @@ class WorkerProcess:
         return inline
 
     # ------------------------------------------------------------------
-    def _execute_task(self, spec: TaskSpec, chips):
+    def _execute_task(self, spec: TaskSpec, chips, host_chips):
         from ray_tpu.util.tracing import task_span
         self.core.current_task_id = spec.task_id
         error = False
         error_payload = None
         try:
             from ray_tpu._private import runtime_env as _renv
-            self._set_visible_chips(chips)
+            self._set_visible_chips(chips, host_chips)
             fn = self.core.load_function(spec.function_key)
             args, kwargs = self._resolve_args(spec)
             with _renv.applied(spec.runtime_env), task_span(spec):
@@ -232,10 +241,10 @@ class WorkerProcess:
         self._send({"type": "done", "task_id": spec.task_id, "error": error,
                     "error_payload": error_payload})
 
-    def _execute_creation(self, spec: TaskSpec, chips):
+    def _execute_creation(self, spec: TaskSpec, chips, host_chips):
         try:
             from ray_tpu._private import runtime_env as _renv
-            self._set_visible_chips(chips)
+            self._set_visible_chips(chips, host_chips)
             if spec.runtime_env:
                 # actors own their process: applied for life
                 _renv.apply(spec.runtime_env)

@@ -1,15 +1,19 @@
 """TPU accelerator manager.
 
 Parity target: reference ``python/ray/_private/accelerators/tpu.py``
-(``TPUAcceleratorManager``) — chip detection, per-task visibility via
-``TPU_VISIBLE_CHIPS``, pod metadata.  Re-designed for a JAX-first stack:
-detection prefers an already-imported jax, falls back to GCE/GKE metadata
-env vars, and never imports jax eagerly (importing jax grabs the chips).
+(``TPUAcceleratorManager``) — chip detection, per-process chip ownership
+through libtpu's environment variables, pod metadata.  Re-designed for a
+JAX-first stack: detection counts the host's device nodes (as the
+reference does), falls back to GCE/GKE metadata env vars, and never
+imports jax (a driver that initialises jax takes the chips its workers
+need).
 """
 
 from __future__ import annotations
 
+import glob
 import os
+import socket
 import sys
 from typing import List, Optional
 
@@ -18,6 +22,29 @@ VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 # GKE injects these; GCE metadata equivalents handled via env for now.
 _TPU_CHIP_COUNT_ENVS = ("TPU_CHIP_COUNT", "TPU_NUM_DEVICES")
 _TPU_TYPE_ENVS = ("TPU_ACCELERATOR_TYPE", "ACCELERATOR_TYPE")
+
+# What libtpu reads when a process is to own a subset of its host's
+# chips (the set jax's own multi-process TPU tests export,
+# jax/_src/test_multiprocess.py): which chips, the shape of that subset,
+# a process grid of one (each worker is its own jax world), a runtime
+# port of its own, and leave to load beside another process's libtpu.
+_SUBSET_ENVS = (VISIBLE_CHIPS_ENV, "TPU_CHIPS_PER_PROCESS_BOUNDS",
+                "TPU_PROCESS_BOUNDS", "TPU_PROCESS_ADDRESSES",
+                "TPU_PROCESS_PORT", "CLOUD_TPU_TASK_ID",
+                "ALLOW_MULTIPLE_LIBTPU_LOAD")
+_SUBSET_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
+def _count_device_nodes() -> int:
+    """TPU chips on this host by their device nodes: ``/dev/accel<N>``
+    (v2–v4) or the VFIO groups ``/dev/vfio/<N>`` (v5e and later) — what
+    the reference's ``TPUAcceleratorManager`` and libtpu itself look
+    for.  No jax, no libtpu: nothing here can take a chip."""
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return len([p for p in glob.glob("/dev/vfio/[0-9]*")
+                if os.path.basename(p).isdigit()])
 
 
 def _jax_backend_initialized() -> bool:
@@ -70,6 +97,10 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
+        # 0. the CPU was asked for: the chips are not this session's
+        platforms = os.environ.get("JAX_PLATFORMS", "")
+        if platforms and "tpu" not in platforms.split(","):
+            return 0
         # 1. explicit override
         for env in _TPU_CHIP_COUNT_ENVS:
             value = os.environ.get(env)
@@ -82,7 +113,11 @@ class TPUAcceleratorManager:
         visible = os.environ.get(VISIBLE_CHIPS_ENV)
         if visible:
             return len([c for c in visible.split(",") if c != ""])
-        # 3. jax — but only if this process ALREADY initialized the
+        # 3. the host's device nodes
+        nodes = _count_device_nodes()
+        if nodes:
+            return nodes
+        # 4. jax — but only if this process ALREADY initialized the
         #    backend.  jax.devices() would otherwise claim the chips for
         #    this process, starving workers that need them.
         jax = sys.modules.get("jax")
@@ -146,8 +181,38 @@ class TPUAcceleratorManager:
         return out
 
     @staticmethod
-    def set_visible_accelerator_ids(ids: List[int]) -> None:
-        os.environ[VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
+    def set_visible_accelerator_ids(ids: List[int],
+                                    host_chips: int) -> None:
+        """Make chips ``ids`` (of the host's ``host_chips``) the ones
+        libtpu gives this process.  Must run before jax initialises its
+        backend here; afterwards the process owns what it took until it
+        exits.
+
+        All of the host's chips is libtpu's default, so nothing is set
+        for it (and a subset inherited from a parent is scrubbed).  A
+        proper subset is named through :data:`_SUBSET_ENVS`."""
+        if len(ids) >= host_chips:
+            for var in _SUBSET_ENVS:
+                os.environ.pop(var, None)
+            return
+        bounds = _SUBSET_BOUNDS.get(len(ids))
+        if bounds is None:
+            raise ValueError(
+                f"cannot give one process {len(ids)} of a host's "
+                f"{host_chips} TPU chips: libtpu takes a subset of "
+                f"{sorted(_SUBSET_BOUNDS)} chips, or all of them")
+        with socket.socket() as s:       # a free port for this runtime
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        os.environ.update({
+            VISIBLE_CHIPS_ENV: ",".join(str(i) for i in ids),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            "TPU_PROCESS_PORT": str(port),
+            "CLOUD_TPU_TASK_ID": "0",
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+        })
 
     @staticmethod
     def get_current_process_visible_accelerator_ids() -> Optional[List[int]]:

@@ -97,7 +97,7 @@ from ray_tpu.inference.scheduler import (DeadlineExceededError,
                                          Request, SlotScheduler)
 from ray_tpu.inference.spec import DraftState
 from ray_tpu.models import gpt as gpt_mod
-from ray_tpu.ops.attention import _NEG_INF
+from ray_tpu.ops.attention import _NEG_INF, decode_uses_pallas
 
 
 class StepEvent(tuple):
@@ -213,6 +213,8 @@ class InferenceEngine:
                  adapter_store: Optional["AdapterStore"] = None):
         if cfg.n_experts > 0:
             raise NotImplementedError("MoE decode cache not supported yet")
+        from ray_tpu._private.compile_cache import enable_compile_cache
+        enable_compile_cache()
         icfg = infer_config()
         self.cfg = cfg
         self.params = jax.device_put(params)
@@ -962,6 +964,10 @@ class InferenceEngine:
             "active": len(self.scheduler.active),
             "cache_bytes": self.cache.bytes,
             "kv_dtype": self.kv_dtype,
+            # what decode attention dispatches to at this geometry
+            "decode_impl": "pallas" if decode_uses_pallas(
+                self.max_pages_per_slot * self.page_size,
+                self.cfg.head_dim, impl=self.decode_impl) else "xla",
             "kv_bytes_per_slot": self.cache.bytes_per_slot(
                 self.max_pages_per_slot),
             "max_queue": self.max_queue,
@@ -1781,14 +1787,16 @@ class InferenceEngine:
 
     def _prefill_attention(self, q, k, v):
         """Causal self-attention over the bucket (no cache read — the
-        prompt is the whole context).  Flash kernel on a real TPU,
-        einsum elsewhere (interpret-mode Pallas is only paid for in the
-        dedicated kernel tests, not every engine test)."""
-        if jax.default_backend() == "tpu":
-            from ray_tpu.ops.attention import flash_attention
-            return flash_attention(q, k, v, causal=True)
-        from ray_tpu.parallel.ring_attention import local_attention
-        return local_attention(q, k, v, causal=True)
+        prompt is the whole context).  Flash kernel on a TPU; einsum
+        where the CPU was asked for (interpret-mode Pallas is only paid
+        for in the dedicated kernel tests, not every engine test); any
+        other backend is refused by ``use_interpret``."""
+        from ray_tpu.ops.substrate import use_interpret
+        if use_interpret():
+            from ray_tpu.parallel.ring_attention import local_attention
+            return local_attention(q, k, v, causal=True)
+        from ray_tpu.ops.attention import flash_attention
+        return flash_attention(q, k, v, causal=True)
 
     def _build_prefill_cached(self, all_rows: bool = False):
         """Suffix-only prefill over a prefix-cached context.

@@ -110,7 +110,18 @@ def _build_engine(model: str, model_config: Optional[Dict[str, Any]],
     return cfg, InferenceEngine(cfg, params, **(engine_config or {}))
 
 
-@serve.deployment(max_ongoing_requests=32)
+def _replica_resources() -> Dict[str, Any]:
+    """One chip per replica wherever the cluster has chips (resolved by
+    ``serve.run``): a replica without the TPU resource lands on a worker
+    its node manager pinned to the CPU, and would serve from there
+    without a word.  ``.options(ray_actor_options=...)`` overrides."""
+    import ray_tpu
+    return ({"num_tpus": 1}
+            if ray_tpu.cluster_resources().get("TPU", 0) >= 1 else {})
+
+
+@serve.deployment(max_ongoing_requests=32,
+                  ray_actor_options=_replica_resources)
 class GPTDeployment:
     """Streaming GPT deployment over the continuous-batching engine.
 
@@ -368,8 +379,20 @@ class GPTDeployment:
                 "waiting": stats["waiting"]}
 
     def telemetry_summary(self) -> Dict[str, Any]:
+        import jax
+
+        from ray_tpu._private.compile_cache import compile_stats
         summary = self.engine.telemetry.summary()
         summary["stats"] = self.engine.stats()
+        # only this process can say where its engine runs and what it
+        # compiled: the device as jax reports it, its memory counters,
+        # and the process-wide compile/persistent-cache counts
+        devices = jax.devices()
+        summary["device"] = {"platform": devices[0].platform,
+                             "kind": devices[0].device_kind,
+                             "count": len(devices),
+                             "memory": devices[0].memory_stats()}
+        summary["jax_compiles"] = compile_stats()
         summary["draining"] = self._draining
         summary["streams_reaped"] = self.streams_reaped
         if self._watchdog is not None:

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu._private.compile_cache import enable_compile_cache
 from ray_tpu.models import gpt as gpt_mod
 from ray_tpu.parallel import sharding as shd
 from ray_tpu.parallel.ring_attention import make_ring_attention_fn
@@ -117,12 +118,8 @@ def _state_shardings(init, param_sh, mesh) -> TrainState:
     replicate."""
     example = jax.eval_shape(init, jax.random.PRNGKey(0))
     shape_to_sh = {}
-    # jax.tree.leaves_with_path appeared in 0.5; tree_util spelling works
-    # on the 0.4.x the container may pin
-    leaves_with_path = getattr(jax.tree, "leaves_with_path",
-                               jax.tree_util.tree_leaves_with_path)
-    for (path, leaf), sh in zip(leaves_with_path(example.params),
-                                jax.tree.leaves(param_sh)):
+    for leaf, sh in zip(jax.tree.leaves(example.params),
+                        jax.tree.leaves(param_sh)):
         shape_to_sh[leaf.shape] = sh
     replicated = NamedSharding(mesh, P())
     opt_sh = jax.tree.map(lambda leaf: shape_to_sh.get(leaf.shape,
@@ -281,6 +278,7 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
     from ray_tpu.ops.attention import make_flash_attention_fn
     from ray_tpu.parallel import overlap as ovl
 
+    enable_compile_cache()
     tx = optimizer or default_optimizer()
     if accum_steps is None:
         accum_steps = default_accum_steps()
@@ -541,6 +539,7 @@ def build_gpt_rl_train(cfg: "gpt_mod.GPTConfig", mesh, *,
     accum_steps = int(accum_steps)
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
+    enable_compile_cache()
     # NOT default_optimizer(): its warmup schedule starts at lr 0, so
     # an RL run's first (often only) handful of steps would be no-ops
     tx = optimizer or optax.chain(optax.clip_by_global_norm(1.0),
@@ -822,6 +821,7 @@ def build_gpt_train_pp(cfg: "gpt_mod.GPTConfig", mesh, *,
         raise ValueError("MoE + pipeline parallelism not supported yet")
     Ls = cfg.n_layers // pp
     M = num_microbatches or default_pp_microbatches() or 2 * pp
+    enable_compile_cache()
     tx = optimizer or default_optimizer()
     stats = pipe.pipeline_schedule_stats(pp, M, schedule)
 

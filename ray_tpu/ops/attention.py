@@ -42,9 +42,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # shared kernel infrastructure lives in ops/substrate.py (one home for
-# the interpret policy, the CompilerParams rename shim, the lane-padded
-# row-stats convention, and env-knob readers); the historical private
-# names stay importable — flash_ce/tests grew up on them
+# the interpret policy, the lane-padded row-stats convention, and
+# env-knob readers); the historical private names stay importable —
+# flash_ce/tests grew up on them
 from ray_tpu.ops.substrate import (NEG_INF as _NEG_INF, STATS_LANES,
                                    CompilerParams as _CompilerParams,
                                    env_flag, env_int,
@@ -142,9 +142,8 @@ def rope_rotate(x, positions, theta: float):
 
 
 def _roll_half(x, D: int):
-    # Mosaic's lane rotate is 32-bit only; callers pass f32.
-    if _use_interpret():
-        return jnp.roll(x, D // 2, axis=-1)
+    # Mosaic's lane rotate is 32-bit only; callers pass f32.  (The
+    # interpreter runs pltpu.roll too, so the tests check this code.)
     return pltpu.roll(x, D // 2, 1)
 
 
@@ -248,10 +247,6 @@ def _roll_sub(x, sub_d: int):
     destination lane l wants source (l - sub_d/2) mod sub_d within its
     group, which is roll(sub_d/2) for the upper half-group and
     roll(sub_d/2 + sub_d) for the lower half-group."""
-    if _use_interpret():
-        r = x.shape[0]
-        return jnp.roll(x.reshape(r, 2, sub_d), sub_d // 2,
-                        axis=-1).reshape(r, 2 * sub_d)
     lo = pltpu.roll(x, sub_d // 2, 1)
     hi = pltpu.roll(x, sub_d // 2 + sub_d, 1)
     return jnp.where(_lane_ids(x.shape[0]) % sub_d < sub_d // 2, hi, lo)
@@ -1319,10 +1314,11 @@ _DECODE_QROWS = 8      # sublane-pad the single query row to a tileable block
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
                    block_k: int, num_kv: int, quantized: bool = False):
     """``quantized`` (static): K/V arrive as int8 codes plus
-    per-(position, head) f32 scale refs and are dequantized *inside*
-    the 128-lane context strip — the quantized cache never
-    materializes in anything wider than its strip.  One body for both
-    modes so the scratch discipline cannot diverge."""
+    per-(position, head) f32 scale rows and are dequantized *inside*
+    the 128-lane context strip (the scales applied to the strip's
+    score/probability rows) — the quantized cache never materializes
+    in anything wider than its strip.  One body for both modes so the
+    scratch discipline cannot diverge."""
     if quantized:
         ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = rest
     else:
@@ -1336,15 +1332,21 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         l_sc[:] = jnp.zeros_like(l_sc)
 
     q = q_ref[0, 0]                          # [QROWS, D]
-    k = k_ref[0, :, 0, :]                    # [bk, D]
-    v = v_ref[0, :, 0, :]
+    k = k_ref[0, 0]                          # [bk, D]
+    v = v_ref[0, 0]
     if quantized:
+        # one scale per (position, head) = per row of k/v, so it
+        # commutes out of both matmuls and is applied to the [QROWS, bk]
+        # score/probability rows — the scales stay lane-major ([1, bk]),
+        # no lane->sublane relayout and bk*D fewer multiplies
         q = q.astype(jnp.float32)
-        k = k.astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0, 0][:, None]
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale      # [QROWS, bk]
+    if quantized:
+        s = s * ks_ref[0, 0]                             # [1, bk] bcast
     col = (j * block_k
            + jax.lax.broadcasted_iota(jnp.int32,
                                       (_DECODE_QROWS, block_k), 1))
@@ -1354,6 +1356,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new[:, :1])
     l_sc[:] = l_sc[:] * alpha + jnp.sum(p, 1, keepdims=True)
+    if quantized:
+        p = p * vs_ref[0, 0]
     acc_sc[:] = (acc_sc[:] * alpha[:, :1]
                  + jax.lax.dot_general(
                      p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -1384,6 +1388,21 @@ def decode_supports(S: int, D: int, *, block_k: int = 512) -> bool:
     return _decode_block(S, block_k) >= 128 and D <= 256
 
 
+def decode_uses_pallas(S: int, D: int, *, impl: str = "auto",
+                       block_k: int = 512) -> bool:
+    """Whether :func:`decode_attention` runs the Pallas kernel for this
+    context shape and ``impl`` — the single source of the decision (the
+    engine reports it, so a summary cannot claim a kernel the dispatch
+    declined).  ``"auto"``: the kernel wherever kernels are compiled (a
+    TPU) and the context tiles, the einsum where the CPU was asked for;
+    ``use_interpret`` refuses a backend nobody asked for instead of
+    quietly picking one."""
+    if impl == "pallas":
+        return True
+    return (impl == "auto" and not _use_interpret()
+            and decode_supports(S, D, block_k=block_k))
+
+
 def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
                      impl: str = "auto", block_k: int = 512,
                      k_scale=None, v_scale=None):
@@ -1405,8 +1424,9 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
     ``impl``: "pallas" (strip-mined online-softmax kernel; raises for
     untileable shapes), "xla" (masked einsum formulation, shards and
     runs anywhere), or "auto" (pallas on a TPU backend for lane-aligned
-    shapes, xla otherwise — interpret-mode parity for the kernel lives
-    in ``tests/test_ops.py``).
+    shapes, xla where the CPU was asked for, an error on any other
+    backend — interpret-mode parity for the kernel lives in
+    ``tests/test_ops.py``).
     """
     B, H, D = q.shape
     S = k.shape[1]
@@ -1419,11 +1439,7 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
     if impl == "pallas" and not decode_supports(S, D, block_k=block_k):
         raise ValueError(f"decode kernel cannot tile S={S}, D={D} "
                          f"(block_k={block_k})")
-    block_k = _decode_block(S, block_k) or block_k
-    use_pallas = impl == "pallas" or (
-        impl == "auto" and jax.default_backend() == "tpu"
-        and decode_supports(S, D, block_k=block_k))
-    if not use_pallas:
+    if not decode_uses_pallas(S, D, impl=impl, block_k=block_k):
         with jax.named_scope("attn/decode_xla"):
             if quantized:
                 # masked-einsum fallback: dequantize as one fused
@@ -1442,16 +1458,19 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
             o = jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v,
                            preferred_element_type=jnp.float32)
             return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
-    bk = min(block_k, S)
+    bk = _decode_block(S, block_k)
     grid = (B, H, S // bk)
     qp = jnp.broadcast_to(q[:, :, None, :], (B, H, _DECODE_QROWS, D))
+    # the context goes in head-major ([B, H, S, D], one XLA transpose
+    # of the gathered pages): a (1, bk, 1, D) strip over [B, S, H, D]
+    # has a unit sublane dim Mosaic refuses ("last two dimensions of
+    # your block shape ..."); (bk, D) strips are the flash kernels'
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, j, lens: (b, h, j, 0))
     qkv_specs = [
         pl.BlockSpec((1, 1, _DECODE_QROWS, D),
                      lambda b, h, j, lens: (b, h, 0, 0)),
-        pl.BlockSpec((1, bk, 1, D),
-                     lambda b, h, j, lens: (b, j, h, 0)),
-        pl.BlockSpec((1, bk, 1, D),
-                     lambda b, h, j, lens: (b, j, h, 0)),
+        kv_spec, kv_spec,
     ]
     common = dict(
         out_specs=pl.BlockSpec((1, 1, _DECODE_QROWS, D),
@@ -1464,13 +1483,14 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
     )
     scale_in, scale_args = [], []
     if quantized:
-        # scales travel [B, H, S] so the strip lands on the 128-lane
-        # (trailing) dim — one [bk] vector per (b, h, j) grid cell
-        scale_spec = pl.BlockSpec((1, 1, bk),
-                                  lambda b, h, j, lens: (b, h, j))
+        # scales travel [B, H, 1, S]: the strip lands on the 128-lane
+        # (trailing) dim as a [1, bk] row per (b, h, j) grid cell, and
+        # the unit dim keeps the block's last two dims legal
+        scale_spec = pl.BlockSpec((1, 1, 1, bk),
+                                  lambda b, h, j, lens: (b, h, 0, j))
         scale_in = [scale_spec, scale_spec]
-        scale_args = [jnp.swapaxes(k_scale, 1, 2),
-                      jnp.swapaxes(v_scale, 1, 2)]
+        scale_args = [jnp.swapaxes(k_scale, 1, 2)[:, :, None, :],
+                      jnp.swapaxes(v_scale, 1, 2)[:, :, None, :]]
     name = "attn/decode_pallas_int8" if quantized else \
         "attn/decode_pallas"
     with jax.named_scope(name):
@@ -1489,7 +1509,8 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
             out_shape=jax.ShapeDtypeStruct((B, H, _DECODE_QROWS, D),
                                            q.dtype),
             interpret=_use_interpret(),
-        )(lengths, qp, k, v, *scale_args)
+        )(lengths, qp, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+          *scale_args)
         return out[:, :, 0]
 
 

@@ -51,9 +51,10 @@ Dispatch is owned by :func:`ce_config` — the single home for CE env
 knobs (the round-5 ``RAY_TPU_CE_BF16_RESID`` astype round-trip was
 measured dead (+2.5 ms: XLA materializes the f32 tensor anyway) and is
 removed; ``RAY_TPU_FUSED_CE`` folded in as ``RAY_TPU_CE=fused``).
-Unsupported shapes fall back to the dense XLA formulation; a Mosaic
-compile failure on new hardware degrades loudly via ``bench.py``'s
-fallback chain (flash → no-remat → chunked).
+Unsupported shapes fall back to the dense XLA formulation, decided
+from shapes by :func:`supports`; a Mosaic compile failure is a failure
+(``tests/test_tpu_aot.py`` compiles the kernels for a v5e ahead of any
+chip run).
 
 Reference role: the loss path of the reference's torch trainers
 (``F.cross_entropy`` in ``train/torch/train_loop_utils.py``); the
@@ -71,10 +72,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# one home for the Pallas infrastructure shims: the jax-version
-# CompilerParams rename shim, interpret-mode policy, lane-padded
-# row-stats convention, block resolution and env-knob readers are
-# shared with the attention / fused-norm kernels via the substrate
+# one home for the Pallas infrastructure: the interpret-mode policy,
+# lane-padded row-stats convention, block resolution and env-knob
+# readers are shared with the attention / fused-norm kernels via the
+# substrate
 from ray_tpu.ops.substrate import (NEG_INF as _NEG_INF, STATS_LANES,
                                    CompilerParams as _CompilerParams,
                                    Support, env_int, env_str,
@@ -154,6 +155,15 @@ def uses_flash_ce(N: int, d: int, V: int, *,
         mode = ce_config().mode
     return mode == "flash" and n_devices <= 1 and supports(N, d, V)
 
+
+# Mosaic's default scoped-VMEM budget is 16 MiB; at the default blocks
+# the backward's double-buffered [bn, d] / [d, bv] tiles, the f32 dx
+# accumulator and the [bn, bv] f32 temporaries need 16.5 MiB (18 MiB
+# with the norm prologue) at d=768 — "Ran out of memory in memory space
+# vmem ... limit 16.00M" when compiled for v5e.  The widest shape
+# `supports` admits (d = 2048) needs 36 MiB; 48 MiB covers it inside a
+# v5e's 128 MiB of VMEM.
+_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 # block resolution and the lane-broadcast stats layout are the
 # substrate's resolve_blocks/stats_in (this module wrote the originals;
@@ -258,7 +268,8 @@ def _fwd_pallas(x, head, targets, *, block_n: int, block_v: int,
                           norm_eps=norm[1] if norm else None),
         grid=(num_n, num_v),
         compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, bv), lambda i, j: (0, j)),
@@ -346,7 +357,7 @@ def _bwd_kernel(x_ref, h_ref, tgt_ref, lse_ref, srow_ref,
             dxhat = dy * s_ref[...].astype(jnp.float32)
             m = jnp.mean(dxhat * xhat, -1, keepdims=True)
             dx_ref[...] = (rstd * (dxhat - xhat * m)).astype(dx_ref.dtype)
-            dsp_ref[...] = jnp.sum(dy * xhat, 0, keepdims=True)
+            dsp_ref[0] = jnp.sum(dy * xhat, 0, keepdims=True)
 
 
 def _bwd_pallas(x, head, targets, lse, gs, *, block_n: int,
@@ -391,8 +402,11 @@ def _bwd_pallas(x, head, targets, lse, gs, *, block_n: int,
         norm_args = (scale[None, :], _stats_in(rstd, num_n, bn))
         norm_in = [pl.BlockSpec((1, d), lambda i, j: (0, 0)),
                    stats_spec]
-        norm_out = [pl.BlockSpec((1, d), lambda i, j: (i, 0))]
-        norm_shape = [jax.ShapeDtypeStruct((num_n, d), jnp.float32)]
+        # [num_n, 1, d] partials: the (1, d) block is then the full
+        # extent of the last two dims (the TPU tiling rule refuses it
+        # over [num_n, d])
+        norm_out = [pl.BlockSpec((1, 1, d), lambda i, j: (i, 0, 0))]
+        norm_shape = [jax.ShapeDtypeStruct((num_n, 1, d), jnp.float32)]
         norm_sc = [pltpu.VMEM((bn, d), x.dtype)]
     out = pl.pallas_call(
         functools.partial(_bwd_kernel, block_n=bn, block_v=bv,
@@ -401,7 +415,8 @@ def _bwd_pallas(x, head, targets, lse, gs, *, block_n: int,
                           norm_eps=norm[1] if norm else None),
         grid=(num_n, num_v),
         compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, bv), lambda i, j: (0, j)),
@@ -429,7 +444,7 @@ def _bwd_pallas(x, head, targets, lse, gs, *, block_n: int,
         return dx[:N], dhead.astype(head.dtype)
     # per-row-block dscale partials summed in ONE XLA pass — this sum
     # replaces the standalone [d]-output reduction dispatch
-    dscale = jnp.sum(out[2], axis=0)
+    dscale = jnp.sum(out[2], axis=(0, 1))
     return dx[:N], dhead.astype(head.dtype), dscale
 
 

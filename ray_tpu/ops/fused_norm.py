@@ -172,7 +172,7 @@ def _bwd_kernel(a_ref, w_ref, rout_ref, s_ref, rstd_ref, drout_ref,
     # total cotangent into the residual stream: the norm backward plus
     # whatever flowed in from downstream consumers of r
     dr32 = rstd * (dxhat - xhat * m) + drout_ref[...].astype(jnp.float32)
-    dsp_ref[...] = jnp.sum(dy * xhat, 0, keepdims=True)  # [1, d] partial
+    dsp_ref[0] = jnp.sum(dy * xhat, 0, keepdims=True)    # [1, d] partial
     dresid_ref[...] = dr32.astype(dresid_ref.dtype)
     dp = dr32.astype(w_ref.dtype)
     da_ref[...] = jax.lax.dot_general(
@@ -271,20 +271,23 @@ def _mrn_bwd(eps, block_n, res, cts):
             pl.BlockSpec((bn, K), lambda i: (i, 0)),
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
             pl.BlockSpec((1, K, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
+            # [num_n, 1, d]: a (1, d) block over [num_n, d] breaks the
+            # TPU tiling rule (sublane dim 1 neither 8-aligned nor the
+            # array's); over a unit middle dim it is the full extent
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Np, K), a.dtype),
             jax.ShapeDtypeStruct((Np, d), rout.dtype),
             jax.ShapeDtypeStruct((num_n, K, d), w.dtype),
-            jax.ShapeDtypeStruct((num_n, d), jnp.float32),
+            jax.ShapeDtypeStruct((num_n, 1, d), jnp.float32),
         ],
         interpret=use_interpret(),
     )(a, w, rout, scale[None, :], rstd_b, drout, dy)
     # per-row-block partials summed in ONE XLA pass each — these sums
     # replace the standalone [d]-output reduction dispatches
     dw = jnp.sum(dwp.astype(jnp.float32), 0).astype(w.dtype)
-    dscale = jnp.sum(dsp, 0).astype(scale.dtype)
+    dscale = jnp.sum(dsp, (0, 1)).astype(scale.dtype)
     return da[:N], dw, dresid[:N], dscale
 
 

@@ -3,20 +3,20 @@
 Four kernel families grew up in this tree — flash attention (fwd/bwd +
 decode), two-head lane packing (pack2), flash-CE, and the fused norm
 epilogues — and by round 12 each carried its own copy of the same
-infrastructure: an interpret-mode policy, the jax-version
-``CompilerParams`` rename shim, lane-padded row-stats conventions,
-block/grid validation, env-knob config plumbing, and (in ``bench.py``)
-a hand-rolled compile-failure fallback ladder per kernel.  Copies
-drift; ``rmsnorm.py``'s private ``_use_interpret`` was the proof.
+infrastructure: an interpret-mode policy, lane-padded row-stats
+conventions, block/grid validation and env-knob config plumbing.
+Copies drift; ``rmsnorm.py``'s private ``_use_interpret`` was the proof.
 
 This module is the single home for all of it.  A new kernel (quantized
 KV strips, ragged prefill, the next norm fusion) should be a page of
 code on top of these pieces, not a subsystem:
 
-- :func:`use_interpret` — the one interpret-mode policy (Pallas kernels
-  run interpreted off-TPU so the parity suite runs on CPU).
-- :data:`CompilerParams` — the ``TPUCompilerParams`` →
-  ``CompilerParams`` rename shim, resolved once.
+- :func:`use_interpret` — the one interpret-mode policy: kernels run
+  interpreted only where the CPU was asked for (``JAX_PLATFORMS=cpu``,
+  the parity suite); a non-TPU backend nobody asked for is an error.
+- :func:`compile_for_tpu` — lowers the kernels for Mosaic while the
+  process itself runs on the CPU (AOT checks against a TPU topology
+  description, ``tests/test_tpu_aot.py``).
 - :data:`NEG_INF` / :data:`STATS_LANES` — masking constant and the
   lane-padded row-stats width shared by every online-softmax kernel.
 - :func:`round_up` / :func:`resolve_blocks` / :func:`stats_in` —
@@ -28,17 +28,17 @@ code on top of these pieces, not a subsystem:
 - :func:`env_int` / :func:`env_str` / :func:`env_flag` — env-knob
   readers for the per-family config dataclasses
   (``attention_config()`` / ``ce_config()`` / ``fuse_config()``).
-- :func:`run_ladder` — the cumulative compile-failure fallback ladder
-  ``bench.py`` previously reimplemented per kernel: try the most
-  capable configuration, degrade loudly rung by rung on Mosaic
-  compile/run failures, never silently.
+
+A kernel that Mosaic refuses is declined by its own gate, from shapes,
+before tracing — never by catching a compile error.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
-import sys
-from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,19 +54,58 @@ NEG_INF = -1e30
 # dim) where a 1-D (rows,) column cannot
 STATS_LANES = 8
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; resolve
-# whichever this jaxlib ships, once, for every pallas_call in the tree
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+CompilerParams = pltpu.CompilerParams
+
+_COMPILE_FOR_TPU = contextvars.ContextVar("compile_for_tpu", default=False)
+
+
+def cpu_requested() -> bool:
+    """Whether the CPU backend was asked for by name (``JAX_PLATFORMS=cpu``
+    or the equivalent ``jax_platforms`` config), as opposed to being what
+    jax fell back to when no accelerator initialised."""
+    platforms = jax.config.jax_platforms or ""
+    return platforms.split(",")[0].strip() == "cpu"
 
 
 def use_interpret() -> bool:
     """Whether pallas_calls should run in interpret mode.
 
-    The one policy for every kernel family: interpret off-TPU so the
-    parity suite (and any CPU smoke run) executes the same kernel
-    bodies the chip will."""
-    return jax.default_backend() != "tpu"
+    The one policy for every kernel family: compiled on a TPU (and inside
+    :func:`compile_for_tpu`), interpreted where the CPU was asked for so
+    the parity suite executes the same kernel bodies the chip will, and
+    an error anywhere else — any other backend means the chip failed to
+    initialise (or a worker was started without it), and the kernels
+    would quietly run somewhere their numbers mean nothing."""
+    if _COMPILE_FOR_TPU.get():
+        return False
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu" and cpu_requested():
+        return True
+    raise RuntimeError(
+        f"Pallas kernel dispatch: jax's default backend is {backend!r} "
+        "but neither a TPU was found nor the CPU asked for "
+        f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}). Set "
+        "JAX_PLATFORMS=cpu to run on the CPU deliberately (Pallas "
+        "kernels then run in interpret mode); otherwise the TPU did not "
+        "initialise in this process — is it held by another process, or "
+        "was this worker started without the TPU resource?")
+
+
+@contextlib.contextmanager
+def compile_for_tpu():
+    """Trace kernels for Mosaic regardless of the process's own backend.
+
+    For ahead-of-time compilation against a TPU topology description
+    (``jax.experimental.topologies.get_topology_desc``) from a CPU-only
+    process: inside the block :func:`use_interpret` is false, so
+    ``jit(f).lower(...).compile()`` hands the real kernels to Mosaic."""
+    token = _COMPILE_FOR_TPU.set(True)
+    try:
+        yield
+    finally:
+        _COMPILE_FOR_TPU.reset(token)
 
 
 # ---------------------------------------------------------------------------
@@ -138,43 +177,3 @@ def env_flag(name: str, default: bool = True) -> bool:
     """Boolean env knob: unset -> ``default``; ``"0"`` is the one
     falsey spelling (matches every existing ``RAY_TPU_*`` gate)."""
     return os.environ.get(name, "1" if default else "0") != "0"
-
-
-# ---------------------------------------------------------------------------
-# compile-failure fallback ladder
-# ---------------------------------------------------------------------------
-
-def run_ladder(attempt: Callable[[Any], Any],
-               rungs: Sequence[Tuple[Optional[str], Any]],
-               *, log: Optional[Callable[[str], None]] = None
-               ) -> Tuple[Any, Any, List[str]]:
-    """Cumulative loud fallback ladder for Mosaic compile/run failures.
-
-    ``rungs`` is ``[(what, args), ...]``, most capable first — the
-    primary configuration (``what`` is ``None``) followed by the
-    fallback rungs, each isolating one suspect.  ``attempt(args)``
-    builds and warms one configuration, raising on failure.  Returns
-    ``(result, args, taken)`` where ``args`` is the configuration that
-    actually ran and ``taken`` lists the descriptions of every rung
-    that had to engage (empty = primary ran).
-
-    Every degradation is announced on stderr (or ``log``): a kernel
-    that cannot compile on new hardware must show up in the console and
-    the headline JSON, never as a silent perf/loss regression.
-    """
-    emit = log or (lambda msg: print(msg, file=sys.stderr))
-    remaining = list(rungs)
-    if not remaining:
-        raise ValueError("run_ladder needs at least the primary rung")
-    taken: List[str] = []
-    while True:
-        what, args = remaining.pop(0)
-        if what:
-            taken.append(what)
-        try:
-            return attempt(args), args, taken
-        except Exception as e:
-            if not remaining:
-                raise
-            emit(f"step failed to compile/run ({e!r}); "
-                 f"falling back: {remaining[0][0]}")

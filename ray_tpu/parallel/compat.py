@@ -1,62 +1,29 @@
-"""JAX API compatibility shims (jax.shard_map moved/renamed across 0.4→0.9)."""
+"""``shard_map`` as this tree uses it: replication checking off."""
 
 from __future__ import annotations
 
-import functools
-from typing import Any
+import jax
 
 
 def shard_map(f=None, *, mesh=None, in_specs=None, out_specs=None,
               check: bool = False, axis_names=None):
-    """Uniform shard_map wrapper with replication checking disabled.
+    """``jax.shard_map`` with the replication checker disabled.
 
     The manual collectives here (ppermute rings, all_to_all) confuse the
-    replication checker on some jax versions; numerical tests cover
-    correctness instead.
+    checker; numerical tests cover correctness instead.
 
-    ``axis_names`` (jax >= 0.8): partial-manual mode — only the named mesh
-    axes are manual inside the body; the rest stay automatic, so sharding
-    constraints on them still propagate (used by the pipeline layer to be
-    manual over ``pp`` while dp/fsdp/tp compose automatically).
+    ``axis_names``: partial-manual mode — only the named mesh axes are
+    manual inside the body; the rest stay automatic, so sharding
+    constraints on them still propagate (the pipeline layer is manual
+    over ``pp`` while dp/fsdp/tp compose automatically).
     """
-    import jax
-
     def wrap(fn):
-        if hasattr(jax, "shard_map"):
-            kw = {}
-            if axis_names is not None:
-                kw["axis_names"] = frozenset(axis_names)
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check,
-                                 **kw)
+        kw = {}
         if axis_names is not None:
-            raise NotImplementedError(
-                "partial-manual shard_map needs jax.shard_map (jax>=0.8)")
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
+            kw["axis_names"] = frozenset(axis_names)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check, **kw)
 
     if f is None:
         return wrap
     return wrap(f)
-
-
-def axis_size(axis_name):
-    """Size of a bound mesh axis inside shard_map.
-
-    ``lax.axis_size`` appeared in jax 0.5; ``psum(1)`` is the 0.4.x
-    spelling (constant-folded to a static int).  One home for the shim
-    — ring_attention, moe and overlap all need it."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def supports_partial_manual() -> bool:
-    import inspect
-
-    import jax
-    if not hasattr(jax, "shard_map"):
-        return False
-    return "axis_names" in inspect.signature(jax.shard_map).parameters

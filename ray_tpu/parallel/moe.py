@@ -110,8 +110,7 @@ def moe_layer(params: MoEParams, x, *, top_k: int = 2,
         return out.astype(x.dtype), aux
 
     # ---- expert-parallel: params.w1/w2 are the LOCAL expert shard ----
-    from ray_tpu.parallel.compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     E_local = params.w1.shape[0]
     E_global = E_local * n
     assert params.wg.shape[1] == E_global, (

@@ -208,8 +208,7 @@ def ring_allgather_matmul(x, ws, axis_name: Optional[str], *,
         outs = [mm(x, w) for w in ws_list]
         return outs[0] if single else outs
 
-    from ray_tpu.parallel.compat import axis_size
-    W = axis_size(axis_name)
+    W = lax.axis_size(axis_name)
     if W == 1:
         outs = [mm(x, w) for w in ws_list]
         return outs[0] if single else outs
@@ -318,8 +317,7 @@ def _quant_ring_reduce_scatter(g2, axis_name: str, *, key_base: int):
     hops — and no two steps — reuse a rounding pattern."""
     from ray_tpu.quant import (data_salt, dequantize_block,
                                quantize_block, stochastic_key)
-    from ray_tpu.parallel.compat import axis_size
-    W = axis_size(axis_name)
+    W = lax.axis_size(axis_name)
     if W == 1:
         return g2
     rank = lax.axis_index(axis_name)
@@ -396,8 +394,7 @@ def quant_psum(x, axis_name: str, *, key_base: int = 0):
     codes + per-block f32 scales with deterministic rounding (read-many
     data, like the weight gathers)."""
     from ray_tpu.quant import dequantize_block, quantize_block
-    from ray_tpu.parallel.compat import axis_size
-    W = axis_size(axis_name)
+    W = lax.axis_size(axis_name)
     if W == 1:
         return x
     shape, dtype = x.shape, x.dtype

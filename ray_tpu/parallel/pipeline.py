@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.compat import shard_map, supports_partial_manual
+from ray_tpu.parallel.compat import shard_map
 
 
 def pipeline_schedule_stats(pp: int, num_microbatches: int,
@@ -84,7 +84,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, *, mesh,
 
     Returns the last stage's outputs, ``[M, mb, ...]``.
 
-    On jax>=0.8 the shard_map is *partial-manual*: only the stage axis is
+    The shard_map is *partial-manual*: only the stage axis is
     manual, so dp/fsdp/tp shardings inside ``stage_fn`` compose
     automatically (XLA partitions the within-stage math as usual).
     """
@@ -93,7 +93,6 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, *, mesh,
     if xs_m != num_microbatches:
         raise ValueError(f"x leading dim {xs_m} != "
                          f"num_microbatches {num_microbatches}")
-    partial_manual = supports_partial_manual()
     if params_spec is None:
         params_spec = jax.tree.map(
             lambda leaf: P(axis, *([None] * (leaf.ndim - 1))),
@@ -102,7 +101,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, *, mesh,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(params_spec, P()), out_specs=P(),
-        axis_names={axis} if partial_manual else None)
+        axis_names={axis})
     def run(params, xs):
         # params leaves: [1, ...] local stage slice -> squeeze
         params = jax.tree.map(lambda p: jnp.squeeze(p, 0), params)
@@ -189,12 +188,6 @@ def pipeline_1f1b_value_and_grad(
             raise ValueError(
                 f"mb_inputs leading dim {leaf.shape[0]} != "
                 f"num_microbatches {M}")
-    partial_manual = supports_partial_manual()
-    if not partial_manual and any(
-            int(v) > 1 for a, v in dict(mesh.shape).items() if a != axis):
-        raise ValueError(
-            f"1F1B over axis {axis!r} with other sharded mesh axes "
-            "requires partial-manual shard_map (jax >= 0.8)")
     if stage_spec is None:
         stage_spec = jax.tree.map(
             lambda leaf: P(axis, *([None] * (leaf.ndim - 1))),
@@ -209,7 +202,7 @@ def pipeline_1f1b_value_and_grad(
         shard_map, mesh=mesh,
         in_specs=(stage_spec, P(), P(), P(), P()),
         out_specs=(P(), stage_spec, P()),
-        axis_names={axis} if partial_manual else None)
+        axis_names={axis})
     def run(p_stage, p_shared, mbs, w, act0):
         p_stage = jax.tree.map(lambda p: jnp.squeeze(p, 0), p_stage)
         s = lax.axis_index(axis)

@@ -100,8 +100,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
     block, identical on every rank: causal work is balanced AND ~halved
     (striped/zigzag context parallelism).
     """
-    from ray_tpu.parallel.compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, S, H, D = q.shape
     if scale is None:

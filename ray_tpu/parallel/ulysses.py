@@ -23,9 +23,8 @@ import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.compat import shard_map, supports_partial_manual
+from ray_tpu.parallel.compat import shard_map
 from ray_tpu.parallel.ring_attention import local_attention
-from ray_tpu.parallel.sharding import data_axes
 
 
 def make_ulysses_attention_fn(mesh, *, causal: bool = True,
@@ -44,19 +43,12 @@ def make_ulysses_attention_fn(mesh, *, causal: bool = True,
     if sp <= 1:
         return functools.partial(inner, causal=causal, scale=scale)
 
-    if supports_partial_manual():
-        # partial-manual: specs name only the manual axis; dp/tp
-        # shardings propagate automatically through the auto axes
-        spec = P(None, "sp", None, None)
-        manual = {"sp"}
-    else:
-        batch = data_axes(mesh)
-        tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
-        spec = P(batch, "sp", tp, None)
-        manual = None
+    # partial-manual: specs name only the manual axis; dp/tp
+    # shardings propagate automatically through the auto axes
+    spec = P(None, "sp", None, None)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec,) * 3,
-                       out_specs=spec, axis_names=manual)
+                       out_specs=spec, axis_names={"sp"})
     def fn(q, k, v):
         H = q.shape[2]
         if H % sp:

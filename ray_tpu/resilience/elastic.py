@@ -91,10 +91,8 @@ class ReshardError(ElasticError):
 
 def _leaf_paths(tree) -> List[str]:
     import jax
-    leaves_with_path = getattr(jax.tree, "leaves_with_path",
-                               jax.tree_util.tree_leaves_with_path)
     keystr = jax.tree_util.keystr
-    return [keystr(p) for p, _ in leaves_with_path(tree)]
+    return [keystr(p) for p, _ in jax.tree.leaves_with_path(tree)]
 
 
 def _axis_sizes(mesh, entry) -> int:

@@ -24,7 +24,9 @@ class Deployment:
     func_or_class: Any
     name: str
     num_replicas: int = 1
-    ray_actor_options: Optional[Dict[str, Any]] = None
+    # a dict, or a zero-argument callable returning one that ``run``
+    # resolves against the live cluster (e.g. "a chip if there are any")
+    ray_actor_options: Any = None
     max_ongoing_requests: int = 8
     user_config: Optional[Dict[str, Any]] = None
     # {min_replicas, max_replicas, target_ongoing_requests,
@@ -166,7 +168,9 @@ def _collect_deployments(app: Application, app_name: str,
             "init_args": args,
             "init_kwargs": kwargs,
             "num_replicas": dep.num_replicas,
-            "actor_options": dep.ray_actor_options,
+            "actor_options": (dep.ray_actor_options()
+                              if callable(dep.ray_actor_options)
+                              else dep.ray_actor_options),
             "max_ongoing": dep.max_ongoing_requests,
             "user_config": dep.user_config,
             "autoscaling_config": dep.autoscaling_config,

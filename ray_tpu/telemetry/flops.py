@@ -13,7 +13,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-# bf16 peak of the chip families we may land on (for the MFU figure)
+# bf16 peak TFLOP/s per chip, keyed by a substring of ``device_kind``
+# (Google Cloud TPU documentation, per-generation system pages)
 CHIP_PEAK_TFLOPS = {
     "v4": 275.0,
     "v5 lite": 197.0, "v5e": 197.0,
@@ -21,14 +22,13 @@ CHIP_PEAK_TFLOPS = {
     "v6 lite": 918.0, "v6e": 918.0,
 }
 
-# unknown device kinds (CPU host-sim included) fall back here so MFU
-# stays defined everywhere; off-chip the figure is only a consistency
-# check on the arithmetic, not a hardware claim
-DEFAULT_PEAK_TFLOPS = 197.0
-
 
 def chip_peak_tflops(device=None) -> float:
-    """bf16 peak TFLOP/s of ``device`` (default: first visible device)."""
+    """bf16 peak TFLOP/s of ``device`` (default: first visible device).
+
+    A device that is not in the table is an error, not a default: an MFU
+    priced against some other chip's peak is a wrong number under a
+    device metric's name."""
     if device is None:
         import jax
         device = jax.devices()[0]
@@ -36,7 +36,10 @@ def chip_peak_tflops(device=None) -> float:
     for key, peak in CHIP_PEAK_TFLOPS.items():
         if key in kind:
             return peak
-    return DEFAULT_PEAK_TFLOPS
+    raise ValueError(
+        f"no bf16 peak on record for device_kind={kind!r} (known: "
+        f"{sorted(CHIP_PEAK_TFLOPS)}); add it to CHIP_PEAK_TFLOPS with "
+        "its source, or pass the peak explicitly")
 
 
 def gpt_fwd_flops_per_token(cfg, seq: int, *, causal: bool = True) -> float:

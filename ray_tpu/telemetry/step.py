@@ -96,8 +96,8 @@ class StepTelemetry:
 
     ``aot=True`` routes the first call through
     ``step_fn.lower(...).compile()`` — one compile total, an exact
-    compile/steady split, and ``memory_analysis()`` HBM numbers; any
-    failure on that path falls back loudly to the plain jit call.
+    compile/steady split, and ``memory_analysis()`` HBM numbers; a
+    step that does not compile raises there.
     ``aot=False`` (the default the train-step builders use) never
     re-routes compilation: the first step's wall time simply includes
     the jit compile and is reported as ``first_step_s``.
@@ -198,11 +198,11 @@ class StepTelemetry:
             if i > 0:
                 rec["tokens_per_sec"] = (self._tokens_per_step
                                          / rec["wall_s"])
-                fpt = self.flops_per_token()
-                if fpt is not None:
+                fpt, peak = self.flops_per_token(), self.chip_peak()
+                if fpt is not None and peak is not None:
                     rec["mfu"] = flops_mod.mfu(
                         rec["tokens_per_sec"] / self.n_devices(), fpt,
-                        self.chip_peak())
+                        peak)
         loss = self._maybe_loss(out)
         if loss is not None:
             rec["loss"] = loss
@@ -221,33 +221,18 @@ class StepTelemetry:
         if not self._aot:
             return step_fn(*args, **kwargs)
         if i == 0:
-            try:
-                self._compile_ts = ts
-                t0 = time.monotonic()
-                compiled = step_fn.lower(*args, **kwargs).compile()
-                self.compile_s = time.monotonic() - t0
-                self.memory = _memory_dict(compiled)
-                out = compiled(*args, **kwargs)
-                self._compiled = compiled
-                self._signature = _arg_signature((args, kwargs))
-                return out
-            except Exception as e:  # noqa: BLE001 — loud jit fallback
-                print(f"telemetry: AOT compile path failed ({e!r}); "
-                      "falling back to plain jit dispatch "
-                      "(no compile/HBM split)", file=sys.stderr)
-                self._aot = False
-                self._compiled = None
-                self.compile_s = None
-                return step_fn(*args, **kwargs)
-        if (self._compiled is not None
-                and _arg_signature((args, kwargs)) == self._signature):
-            try:
-                return self._compiled(*args, **kwargs)
-            except Exception as e:  # noqa: BLE001
-                print(f"telemetry: compiled step call failed ({e!r}); "
-                      "reverting to jit dispatch", file=sys.stderr)
-                self._compiled = None
-        return step_fn(*args, **kwargs)
+            # a step that does not compile fails here: no catch, no
+            # second attempt through plain jit
+            self._compile_ts = ts
+            t0 = time.monotonic()
+            compiled = step_fn.lower(*args, **kwargs).compile()
+            self.compile_s = time.monotonic() - t0
+            self.memory = _memory_dict(compiled)
+            self._compiled = compiled
+            self._signature = _arg_signature((args, kwargs))
+        if _arg_signature((args, kwargs)) == self._signature:
+            return self._compiled(*args, **kwargs)
+        return step_fn(*args, **kwargs)     # other shapes: plain jit
 
     # ------------------------------------------------------- accounting --
 
@@ -279,9 +264,16 @@ class StepTelemetry:
     def n_devices(self) -> int:
         return getattr(self.mesh, "size", None) or 1
 
-    def chip_peak(self) -> float:
+    def chip_peak(self) -> Optional[float]:
+        """bf16 peak of the chip the step runs on; ``None`` on the CPU,
+        where MFU is not a quantity (records then carry no ``mfu``)."""
         if self._peak is None:
-            self._peak = flops_mod.chip_peak_tflops()
+            import jax
+            device = (self.mesh.devices.flat[0] if self.mesh is not None
+                      else jax.devices()[0])
+            if device.platform == "cpu":
+                return None
+            self._peak = flops_mod.chip_peak_tflops(device)
         return self._peak
 
     def _ce_recompute(self) -> Optional[bool]:
@@ -359,13 +351,13 @@ class StepTelemetry:
                 out["tokens_per_sec"] = tok_s
                 out["tokens_per_sec_per_device"] = \
                     tok_s / self.n_devices()
-                fpt = self.flops_per_token()
+                fpt, peak = self.flops_per_token(), self.chip_peak()
                 if fpt is not None:
                     out["flops_per_token"] = fpt
-                    out["chip_peak_tflops"] = self.chip_peak()
+                if fpt is not None and peak is not None:
+                    out["chip_peak_tflops"] = peak
                     out["mfu"] = flops_mod.mfu(
-                        tok_s / self.n_devices(), fpt,
-                        self.chip_peak())
+                        tok_s / self.n_devices(), fpt, peak)
         out["hbm"] = self.memory
         cb = self.collective_bytes()
         out["collective_bytes_per_step"] = cb

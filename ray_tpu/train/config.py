@@ -16,7 +16,12 @@ from typing import Any, Dict, Optional
 @dataclass
 class ScalingConfig:
     num_workers: int = 1
-    use_tpu: bool = False
+    # None: the workers take the cluster's TPU chips when it has any,
+    # split evenly (a worker without the TPU resource is pinned to the
+    # CPU by its node manager, so a default of "no" would train on the
+    # CPU of a TPU host without a word).  True insists on chips — the
+    # job is infeasible without them; False asks for the CPU.
+    use_tpu: Optional[bool] = None
     resources_per_worker: Optional[Dict[str, float]] = None
     placement_strategy: str = "PACK"
     # TPU-first: logical mesh per worker-collective (axis name -> size);
@@ -27,8 +32,13 @@ class ScalingConfig:
     def _resources(self) -> Dict[str, float]:
         res = dict(self.resources_per_worker or {})
         res.setdefault("CPU", 1.0)
-        if self.use_tpu and "TPU" not in res:
-            res["TPU"] = 1.0
+        if "TPU" in res or self.use_tpu is False:
+            return res
+        import ray_tpu
+        chips = (ray_tpu.cluster_resources().get("TPU", 0)
+                 if ray_tpu.is_initialized() else 0)
+        if self.use_tpu or chips:
+            res["TPU"] = float(max(1, int(chips // self.num_workers)))
         return res
 
     def as_placement_group_factory(self):

@@ -27,13 +27,9 @@ def profile_device(logdir: Optional[str] = None,
     import jax
     logdir = logdir or os.path.join(
         "/tmp", f"ray_tpu_profile_{int(time.time())}")
-    try:
-        opts = jax.profiler.ProfileOptions()
-        opts.host_tracer_level = host_tracer_level
-        ctx = jax.profiler.trace(logdir, profiler_options=opts)
-    except (AttributeError, TypeError):  # older jax: no options
-        ctx = jax.profiler.trace(logdir)
-    with ctx:
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = host_tracer_level
+    with jax.profiler.trace(logdir, profiler_options=opts):
         yield logdir
 
 

@@ -24,10 +24,8 @@ def test_gpt_logical_axes_match_params():
     cfg = GPTConfig.tiny(n_experts=2)
     params = init_params(cfg, jax.random.PRNGKey(0))
     axes = param_logical_axes(cfg)
-    leaves_with_path = getattr(jax.tree, "leaves_with_path",
-                               jax.tree_util.tree_leaves_with_path)
-    pl = leaves_with_path(params)
-    al = leaves_with_path(
+    pl = jax.tree.leaves_with_path(params)
+    al = jax.tree.leaves_with_path(
         axes, is_leaf=lambda x: isinstance(x, tuple))
     assert len(pl) == len(al)
     for (ppath, leaf), (apath, ax) in zip(pl, al):
@@ -167,9 +165,7 @@ def test_gpt_train_fuse_norm_parity():
                                   fuse_norm=fuse))(params)
     assert float(losses[True]) == pytest.approx(float(losses[False]),
                                                 abs=2e-5)
-    leaves_with_path = getattr(jax.tree, "leaves_with_path",
-                               jax.tree_util.tree_leaves_with_path)
-    for (path, a), b in zip(leaves_with_path(grads[True]),
+    for (path, a), b in zip(jax.tree.leaves_with_path(grads[True]),
                             jax.tree.leaves(grads[False])):
         na, nb = np.asarray(a), np.asarray(b)
         denom = max(1e-8, float(np.abs(nb).max()))

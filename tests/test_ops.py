@@ -1,14 +1,10 @@
 """Numerics tests: Pallas kernels vs their XLA reference paths.
 
 Runs in interpret mode on the CPU test mesh (tests/conftest.py); the same
-kernels compile to Mosaic on a real chip (exercised by bench.py and the
-driver's entry check).  Mirrors the reference's kernel-vs-eager parity
+kernels compile to Mosaic for a chip (``tests/test_tpu_aot.py`` compiles
+them for a described v5e; ``chip_smoke.py`` repeats the parity on the
+chip at the real shapes).  Mirrors the reference's kernel-vs-eager parity
 tests (e.g. ``python/ray/train/tests`` numerical checks).
-
-The ``kernel_smoke`` marker scopes the fast representative core that
-``bench.py``'s preamble re-runs before every paid chip measurement —
-one parity test per kernel schedule; the heavier sweep cases (full
-GPT-2 vocab, dispatch/env plumbing) run only in tier-1.
 """
 
 import functools
@@ -22,7 +18,6 @@ from ray_tpu.parallel.ring_attention import local_attention
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.kernel_smoke
 def test_flash_fwd_matches_einsum(causal):
     key = jax.random.PRNGKey(0)
     B, S, H, D = 2, 256, 4, 64
@@ -34,7 +29,6 @@ def test_flash_fwd_matches_einsum(causal):
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
-@pytest.mark.kernel_smoke
 @pytest.mark.slow
 def test_flash_grads_match_einsum():
     key = jax.random.PRNGKey(1)
@@ -76,7 +70,6 @@ def test_flash_grads_fused_single_kv_block(causal):
         assert float(jnp.abs(a - b).max()) < 5e-4
 
 
-@pytest.mark.kernel_smoke
 def test_flash_fused_rope_matches_external_rotation():
     # in-kernel rope (fwd + fused bwd) vs rotate-then-attend reference
     from ray_tpu.models.gpt import _rope
@@ -129,7 +122,6 @@ def test_flash_rope_multiblock_falls_back_to_external():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.kernel_smoke
 def test_pack2_fwd_matches_einsum(causal):
     key = jax.random.PRNGKey(20)
     B, S, H, D = 2, 256, 4, 64
@@ -157,7 +149,6 @@ def test_pack2_fwd_bf16():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.kernel_smoke
 def test_pack2_grads_match_einsum_multistrip(causal):
     # bwd_block_k < S: the packed fused backward walks 2 kv strips and
     # (causal) skips the dead one for the first q block
@@ -200,7 +191,6 @@ def test_pack2_grads_single_kv_block():
         assert float(jnp.abs(a - b).max()) < 5e-4
 
 
-@pytest.mark.kernel_smoke
 def test_pack2_fused_rope_matches_external_rotation():
     # packed in-kernel rope rotates per-sub-head (grouped lane roll);
     # multi-strip bwd also exercises the cached packed k rotation
@@ -231,7 +221,6 @@ def test_pack2_fused_rope_matches_external_rotation():
         assert float(jnp.abs(a - b).max()) < 5e-4
 
 
-@pytest.mark.kernel_smoke
 def test_pack2_matches_unpacked_kernel():
     # the packed and single-head schedules are the same math — outputs
     # agree to f32 accumulation noise, not just to the einsum reference
@@ -381,7 +370,6 @@ def test_chunked_ce_matches_dense():
     assert float(jnp.abs(g - g_ref).max()) < 1e-4
 
 
-@pytest.mark.kernel_smoke
 @pytest.mark.slow
 def test_pallas_rmsnorm_matches_reference():
     """Fused rmsnorm fwd/bwd (ops/rmsnorm.py) vs the XLA formulation."""
@@ -420,7 +408,6 @@ def test_pallas_rmsnorm_matches_reference():
         assert err / scale < 2e-2, (err, scale)
 
 
-@pytest.mark.kernel_smoke
 @pytest.mark.slow
 def test_fused_ce_matches_reference():
     """bf16-resident-logit CE (ops/fused_ce.py) vs the f32 formulation."""
@@ -495,10 +482,9 @@ def test_gpt_env_gated_paths_train(monkeypatch):
 
 # ---------------------------------------------------------------------------
 # flash-CE (ops/flash_ce.py): streamed-logits Pallas cross-entropy vs
-# the dense f32 formulation.  All run in interpret mode on CPU; the
-# kernel_smoke pair is re-run by the bench.py preamble before any chip
-# measurement (ISSUE r07 acceptance: loss within 1e-3 relative, grads
-# within bf16 tolerance of the f32 reference).
+# the dense f32 formulation.  All run in interpret mode on CPU (ISSUE
+# r07 acceptance: loss within 1e-3 relative, grads within bf16
+# tolerance of the f32 reference).
 # ---------------------------------------------------------------------------
 
 def _ce_inputs(N, d, V, dtype=jnp.float32, seed=0, head_scale=0.1,
@@ -513,7 +499,6 @@ def _ce_inputs(N, d, V, dtype=jnp.float32, seed=0, head_scale=0.1,
     return x, head, tgt
 
 
-@pytest.mark.kernel_smoke
 def test_flash_ce_fwd_matches_reference():
     from ray_tpu.ops.flash_ce import _xla_ce_sum, flash_ce_sum
     x, head, tgt = _ce_inputs(256, 128, 512)
@@ -523,7 +508,6 @@ def test_flash_ce_fwd_matches_reference():
     assert abs(float(s) - float(s_ref)) / abs(float(s_ref)) < 1e-3
 
 
-@pytest.mark.kernel_smoke
 def test_flash_ce_grads_match_reference():
     from ray_tpu.ops.flash_ce import _xla_ce_sum, flash_ce_sum
     x, head, tgt = _ce_inputs(256, 128, 512, seed=1)
@@ -699,7 +683,6 @@ def _decode_ref(q, k, v, lengths):
     return out
 
 
-@pytest.mark.kernel_smoke
 def test_decode_attention_pallas_matches_xla():
     """The strip-mined decode kernel (interpret mode here, Mosaic on
     chip) and the masked-einsum XLA fallback agree with the reference
@@ -762,7 +745,6 @@ def test_decode_attention_bf16_and_dispatch():
                                rtol=0.06, atol=0.06)
 
 
-@pytest.mark.kernel_smoke
 def test_decode_attention_int8_scales_parity():
     """r11 int8-KV decode: both impls dequantize the block-scaled int8
     context (one f32 scale per (position, head) lane vector) and agree
@@ -845,7 +827,6 @@ def _mrn_inputs(N, K, d, dtype, seed=0):
     pytest.param(jnp.bfloat16, 192, 3e-2,     # bf16 residual add
                  marks=pytest.mark.slow),
 ])
-@pytest.mark.kernel_smoke
 def test_matmul_residual_norm_matches_reference(dtype, N, tol):
     """The fused out-proj epilogue kernel (interpret mode here, Mosaic
     on chip): fwd (residual stream + normed hidden) and every grad —
@@ -895,11 +876,10 @@ def test_matmul_residual_norm_matches_reference(dtype, N, tol):
     (jnp.float32, 200, 1000, 1e-5),   # ragged rows AND vocab padding
     (jnp.bfloat16, 192, 770, 4e-2),   # bf16
 ])
-@pytest.mark.kernel_smoke
 # r13 --durations re-profile: every case jits the custom-vjp through
 # the interpret-mode kernel twice (>5s each) and the tier-1 budget is
-# at its ceiling — the full sweep rides the bench preamble
-# (kernel_smoke) + the full suite; tier-1 keeps the fused-CE path
+# at its ceiling — the full sweep rides the full suite; tier-1 keeps
+# the fused-CE path
 # covered through test_flash_ce_norm_all_masked, the dispatch test and
 # test_models.py's end-to-end fuse_norm grad parity (where the gate is
 # asserted to engage)

@@ -140,7 +140,7 @@ def test_gpt_pipeline_parallel_matches_dense():
     l_ref = float(fns["loss_fn"](st.params, batch))
     # f32 reduction order moves this loss by ~1e-2 *between meshes* on
     # some XLA builds (measured: dense 5.539–5.553 over dp/tp/fsdp
-    # layouts on CPU jax 0.4.37, pp microbatch-count stable) — a real
+    # layouts on the CPU backend, pp microbatch-count stable) — a real
     # pipeline bug (dropped microbatch, wrong stage order) shows up at
     # O(0.1+), so 2e-2 still guards the schedule
     assert abs(l_pp - l_ref) < 2e-2
@@ -798,28 +798,3 @@ def test_1f1b_stages_over_dcn_axis():
     l_1f1b = float(fns["loss_fn"](st.params, batch))
     l_gpipe = float(gp["loss_fn"](st_g.params, batch))
     np.testing.assert_allclose(l_1f1b, l_gpipe, rtol=2e-5, atol=2e-6)
-
-
-def test_1f1b_guard_without_partial_manual():
-    """On a jax without partial-manual shard_map, 1F1B over a mesh
-    whose non-stage axes are >1 must refuse loudly (the stage fn would
-    need in-stage sharding the full-manual fallback cannot express)."""
-    from ray_tpu.models import training
-    from ray_tpu.models.gpt import GPTConfig
-    from ray_tpu.parallel import compat
-
-    if compat.supports_partial_manual():
-        pytest.skip("partial-manual shard_map available: "
-                    "pp x fsdp is supported here")
-    cfg = GPTConfig(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
-                    max_seq=32, dtype=jnp.float32)
-    mesh = make_mesh(pp=2, fsdp=2, devices=jax.devices()[:4])
-    fns = training.build_gpt_train_pp(cfg, mesh, schedule="1f1b",
-                                      num_microbatches=2,
-                                      telemetry=False)
-    st = fns["init_fn"](jax.random.PRNGKey(0))
-    from ray_tpu.models.training import synthetic_lm_batch
-    batch = synthetic_lm_batch(jax.random.PRNGKey(1), 4, 32,
-                               cfg.vocab_size)
-    with pytest.raises(ValueError, match="partial-manual"):
-        fns["loss_fn"](st.params, batch)

@@ -21,6 +21,9 @@ def _tiny_cfg():
                      max_seq=64, dtype=jnp.float32)
 
 
+_PEAK = 197.0
+
+
 def _single_dev_mesh():
     import jax
 
@@ -39,8 +42,11 @@ def aot_run():
     cfg = _tiny_cfg()
     mesh = _single_dev_mesh()
     fns = training.build_gpt_train(cfg, mesh, telemetry=False)
+    # an explicit peak: on the CPU there is no chip to price MFU
+    # against (chip_peak_tflops refuses unknown devices), and the
+    # arithmetic under test does not care whose peak it is
     tel = StepTelemetry(cfg, mesh, comm_mode=fns["comm_mode"],
-                        label="t9", aot=True)
+                        label="t9", aot=True, chip_peak_tflops=_PEAK)
     step = tel.wrap(fns["step_fn"])
     state = fns["init_fn"](jax.random.PRNGKey(0))
     batch = training.synthetic_lm_batch(jax.random.PRNGKey(1), 4, 32,
@@ -88,8 +94,7 @@ def test_mfu_arithmetic_vs_hand_computed_flops(aot_run):
     """The analytic FLOPs/token matches an independently hand-computed
     count for the tiny GPT, and the recorded MFU is exactly
     tokens/s/device * flops_per_token / peak."""
-    from ray_tpu.telemetry import (chip_peak_tflops,
-                                   gpt_train_flops_per_token)
+    from ray_tpu.telemetry import gpt_train_flops_per_token
 
     cfg, tel = aot_run["cfg"], aot_run["tel"]
     seq = 32
@@ -110,7 +115,7 @@ def test_mfu_arithmetic_vs_hand_computed_flops(aot_run):
 
     rec = tel.records[2]
     expect_mfu = (rec["tokens_per_sec"] * got
-                  / (chip_peak_tflops() * 1e12))
+                  / (_PEAK * 1e12))
     assert rec["mfu"] == pytest.approx(expect_mfu, rel=1e-6)
 
 

@@ -1,0 +1,169 @@
+"""What can be known about a TPU run without a TPU.
+
+libtpu can describe a v5e topology and compile for it from a CPU-only
+process, so Mosaic's verdict on every Pallas family is available at zero
+chip cost: each test below lowers a kernel family, forward and backward,
+at the shapes GPT-2 124M runs it (batch 24 x 1024, the serving engine's
+8 slots x 1024 context) and compiles it for ``v5e:2x2``.  A block spec
+Mosaic refuses fails here, not on the first chip run.
+
+The rest pins the policies that keep a CPU from passing for a chip: the
+interpret-mode rule, the peak-FLOPs table, and where the compile cache
+lives.
+"""
+
+import os
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ray_tpu.ops import attention, flash_ce, fused_norm, substrate
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BF16 = jnp.bfloat16
+B, S, H, D = 24, 1024, 12, 64            # GPT-2 124M, the bench batch
+N, DM, V = B * S, 768, 50304
+SLOTS, CTX = 8, 1024                     # the engine's decode geometry
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding onto one device of a described (not attached) v5e."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"libtpu cannot describe a v5e:2x2 topology here: {e}")
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+
+def _compile_for_v5e(fn, sharding, *shapes):
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+             for s, d in shapes]
+    with substrate.compile_for_tpu():
+        compiled = jax.jit(fn, out_shardings=sharding).lower(*specs) \
+            .compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("pack2", [True, False])
+def test_flash_attention_fwd_bwd_compiles_for_v5e(v5e, pack2):
+    def step(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: attention.flash_attention(
+                q, k, v, positions=jnp.arange(S), pack2=pack2)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    _compile_for_v5e(step, v5e, *[((B, S, H, D), BF16)] * 3)
+
+
+def test_flash_ce_with_norm_fwd_bwd_compiles_for_v5e(v5e):
+    def step(x, head, targets, scale):
+        return jax.value_and_grad(
+            lambda x, head, scale: flash_ce.flash_ce_norm_sum(
+                x, head, targets, scale)[0], argnums=(0, 1, 2))(
+                    x, head, scale)
+
+    _compile_for_v5e(step, v5e, ((N, DM), BF16), ((DM, V), BF16),
+                     ((N,), jnp.int32), ((DM,), BF16))
+
+
+def test_fused_norm_epilogue_fwd_bwd_compiles_for_v5e(v5e):
+    def step(a, w, resid, scale):
+        def loss(a, w, resid, scale):
+            r, y = fused_norm.matmul_residual_norm(a, w, resid, scale)
+            return (r.astype(jnp.float32).sum()
+                    + (y.astype(jnp.float32) ** 2).sum())
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
+            a, w, resid, scale)
+
+    _compile_for_v5e(step, v5e, ((N, DM), BF16), ((DM, DM), BF16),
+                     ((N, DM), BF16), ((DM,), BF16))
+
+
+@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8])
+def test_decode_attention_compiles_for_v5e(v5e, kv_dtype):
+    kv = ((SLOTS, CTX, H, D), kv_dtype)
+    shapes = [((SLOTS, H, D), BF16), kv, kv, ((SLOTS,), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        shapes += [((SLOTS, CTX, H), jnp.float32)] * 2
+
+    def step(q, k, v, lengths, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        # "auto" must pick the kernel wherever kernels are compiled
+        return attention.decode_attention(q, k, v, lengths, impl="auto",
+                                          **kw)
+
+    _compile_for_v5e(step, v5e, *shapes)
+
+
+def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
+    # the suite asks for the CPU by name (conftest): interpret mode
+    assert substrate.cpu_requested()
+    assert substrate.use_interpret() is True
+    assert not attention.decode_uses_pallas(CTX, D, impl="auto")
+    # the same backend when nobody asked for it is a chip that failed to
+    # initialise, or a worker started without one: an error, not a
+    # quiet interpret-mode run
+    monkeypatch.setattr(substrate, "cpu_requested", lambda: False)
+    with pytest.raises(RuntimeError, match="neither a TPU was found"):
+        substrate.use_interpret()
+    with pytest.raises(RuntimeError, match="neither a TPU was found"):
+        attention.decode_uses_pallas(CTX, D, impl="auto")
+    # compiling for a described TPU needs no backend at all
+    with substrate.compile_for_tpu():
+        assert substrate.use_interpret() is False
+
+
+def test_chip_peak_is_an_error_for_a_device_not_in_the_table():
+    from ray_tpu.telemetry.flops import chip_peak_tflops
+
+    v5e_chip = types.SimpleNamespace(device_kind="TPU v5 lite")
+    assert chip_peak_tflops(v5e_chip) == 197.0
+    with pytest.raises(ValueError, match="no bf16 peak on record"):
+        chip_peak_tflops(types.SimpleNamespace(device_kind="cpu"))
+    with pytest.raises(ValueError, match="no bf16 peak on record"):
+        chip_peak_tflops(jax.devices()[0])
+
+
+def _cache_dir_in_fresh_process(env_overrides):
+    """(returned dir, jax's configured dir) from a process that did not
+    ask for the CPU — the cache stays off where it did."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")}
+    env.update(env_overrides, PYTHONPATH=REPO)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from ray_tpu._private.compile_cache import enable_compile_cache\n"
+         "print(enable_compile_cache())\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"
+         "from jax._src import xla_bridge\n"
+         "assert not xla_bridge._backends  # placing it starts nothing\n"],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.split()
+
+
+def test_compile_cache_dir_comes_from_outside_or_is_fixed(tmp_path):
+    # unset: a fixed path inside the checkout (git-ignored), set in code
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert _cache_dir_in_fresh_process({}) == [fixed, fixed]
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    # set: jax reads the variable itself and the code sets nothing else
+    outside = str(tmp_path / "cache")
+    assert _cache_dir_in_fresh_process(
+        {"JAX_COMPILATION_CACHE_DIR": outside}) == [outside, outside]
+    # the CPU suite itself keeps the checkout clean
+    from ray_tpu._private.compile_cache import enable_compile_cache
+    assert enable_compile_cache() == ""
+    assert jax.config.jax_compilation_cache_dir is None
