@@ -75,6 +75,7 @@ ROADMAP item.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -98,6 +99,7 @@ from ray_tpu.inference.scheduler import (DeadlineExceededError,
 from ray_tpu.inference.spec import DraftState
 from ray_tpu.models import gpt as gpt_mod
 from ray_tpu.ops.attention import _NEG_INF, decode_uses_pallas
+from ray_tpu.util import tracing
 
 
 class StepEvent(tuple):
@@ -1024,17 +1026,58 @@ class InferenceEngine:
         :class:`StepEvent`: 3-tuple-compatible, ``.logprob`` rides
         along)."""
         events: List[StepEvent] = []
-        self._process_cancels()
-        self._expire_deadlines(events)
-        self._resolve_adapters(events)
+        with tracing.span("infer/step", tick=self.ticks) as tick:
+            admitted = self._admit(events)
+            if self.scheduler.active:
+                # speculating slots leave the plain decode batch for
+                # this tick (their verify forward IS their decode) and
+                # plain slots co-batch as always; an all-speculating
+                # tick skips the decode dispatch entirely
+                plan = self._plan_speculation()
+                if len(plan) < len(self.scheduler.active):
+                    self._decode(events, skip=set(plan))
+                for slot, drafts in plan.items():
+                    self._verify(slot, drafts, events)
+            self.ticks += 1
+            self.last_tick_ts = time.monotonic()
+            if self.store is not None:
+                ev = self.store.evictions
+                if ev > self._store_evictions_seen:
+                    self.telemetry.record_kv_store_evictions(
+                        ev - self._store_evictions_seen)
+                    self._store_evictions_seen = ev
+            if self.tiered and self.telemetry.enabled:
+                self.telemetry.record_tier_occupancy(
+                    hbm=len(self.scheduler.prefix_index or ()),
+                    dram=len(self.host_pool) if self.host_pool else 0,
+                    store=len(self.store) if self.store else 0)
+            tick.set(admitted=admitted, events=len(events),
+                     active=len(self.scheduler.active))
+        return events
+
+    def _admit(self, events: List[StepEvent]) -> int:
+        """The scheduler's part of a tick, an ``infer/admit`` span per
+        pass: cancels, deadlines and adapters first, then requests taken
+        off the queue one at a time (prefix walk, page allocation, tier
+        and import installs), each prefilled before the next is
+        looked at.  Returns how many were admitted."""
+        admitted = 0
         while True:
-            with self._lock:
-                req = self.scheduler.try_admit()
-            if req is None:
-                break
-            if req.import_payload is not None:
-                self._install_import(req, events)
-            else:
+            with tracing.span("infer/admit",
+                              waiting=len(self.scheduler.waiting)) as sp:
+                if not admitted:
+                    self._process_cancels()
+                    self._expire_deadlines(events)
+                    self._resolve_adapters(events)
+                with self._lock:
+                    req = self.scheduler.try_admit()
+                if req is None:
+                    return admitted
+                admitted += 1
+                sp.set(hit_pages=req.n_hit_pages)
+                if req.import_payload is not None:
+                    self._install_import(req, events)
+                    continue
                 if req.n_hit_pages:
                     self.tier_hits["hbm"] += req.n_hit_pages
                     if self.telemetry.enabled:
@@ -1042,31 +1085,7 @@ class InferenceEngine:
                             req.n_hit_pages, tier="hbm")
                 if req.tier_plan:
                     self._install_tier_hits(req)
-                self._prefill(req, events)
-        if self.scheduler.active:
-            # speculating slots leave the plain decode batch for this
-            # tick (their verify forward IS their decode) and plain
-            # slots co-batch as always; an all-speculating tick skips
-            # the decode dispatch entirely
-            plan = self._plan_speculation()
-            if len(plan) < len(self.scheduler.active):
-                self._decode(events, skip=set(plan))
-            for slot, drafts in plan.items():
-                self._verify(slot, drafts, events)
-        self.ticks += 1
-        self.last_tick_ts = time.monotonic()
-        if self.store is not None:
-            ev = self.store.evictions
-            if ev > self._store_evictions_seen:
-                self.telemetry.record_kv_store_evictions(
-                    ev - self._store_evictions_seen)
-                self._store_evictions_seen = ev
-        if self.tiered and self.telemetry.enabled:
-            self.telemetry.record_tier_occupancy(
-                hbm=len(self.scheduler.prefix_index or ()),
-                dram=len(self.host_pool) if self.host_pool else 0,
-                store=len(self.store) if self.store else 0)
-        return events
+            self._prefill(req, events)
 
     def generate(self, prompts, max_new_tokens: int = 16,
                  sampling: Optional[SamplingParams] = None,
@@ -1119,7 +1138,6 @@ class InferenceEngine:
         raise ValueError(f"no prefill bucket fits length {n}")
 
     def _prefill(self, req: Request, events) -> None:
-        from ray_tpu.util import tracing
         sched = self.scheduler
         slot = req.slot
         plen = len(req.prompt)
@@ -1139,9 +1157,11 @@ class InferenceEngine:
         bucket = self._bucket_for(len(fill))
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(fill)] = fill
-        t0 = time.monotonic()
+        tr = req.trace if req.trace is not None and req.trace.sampled \
+            else None
+        ids = {"trace_id": tr.trace_id} if tr is not None else {}
         with tracing.span(f"infer/{kind}", rid=req.rid, bucket=bucket,
-                          cached=cached):
+                          cached=cached, **ids) as sp:
             if self.lora_cfg is not None:
                 aid = np.array([max(req.adapter_slot, 0)], np.int32)
                 args = (self.params, self.lora_bank, *self.cache.state,
@@ -1155,40 +1175,43 @@ class InferenceEngine:
             self.cache.state = tuple(state)
             toks, logps = self._sample_slots(logits, [req])
             tok, logp = toks[0], logps[0]
-        # the prompt's K/V are now fully in cache: its full pages are
-        # immutable from here on and safe to hand to other requests
-        self._register_prefix(req)
-        if self.debug_logits:
-            self.logits_trace.setdefault(req.rid, []).append(
-                np.asarray(logits[0]))
-        sched.lengths[slot] = plen
-        now = time.monotonic()
-        tr = req.trace
-        if tr is not None and tr.sampled:
-            from ray_tpu.telemetry import trace as trace_mod
-            trace_mod.record_span(
-                "queue", tr,
-                start=trace_mod.epoch_of(req.submitted_ts),
-                dur=req.admitted_ts - req.submitted_ts, rid=req.rid,
-                replica=self.trace_label)
-            trace_mod.record_span(
-                "prefill", tr, start=trace_mod.epoch_of(t0),
-                dur=now - t0, rid=req.rid, bucket=bucket,
-                cached=cached, kind=kind, replica=self.trace_label)
-            trace_mod.event("first_token", tr, rid=req.rid,
-                            ttft_s=now - req.submitted_ts,
-                            replica=self.trace_label)
-        if self.telemetry.enabled:
-            self.telemetry.record_queue(
-                req.admitted_ts - req.submitted_ts,
-                depth=len(sched.waiting))
-            self.telemetry.record_prefill(now - t0, prompt_tokens=plen,
-                                          bucket=bucket,
-                                          cached_tokens=cached)
-            self.telemetry.record_ttft(
-                now - req.submitted_ts, prefix_hit=cached > 0,
-                trace_id=tr.trace_id if tr is not None else None)
-        self._deliver(req, int(tok), float(logp), events)
+        with self._deliver_span(events):
+            # the prompt's K/V are now fully in cache: its full pages
+            # are immutable from here on and safe to hand to other
+            # requests
+            self._register_prefix(req)
+            if self.debug_logits:
+                self.logits_trace.setdefault(req.rid, []).append(
+                    np.asarray(logits[0]))
+            sched.lengths[slot] = plen
+            # the first token exists when the prefill span ends: the
+            # span's one pair of clock reads feeds every sink
+            ttft = sp.end - req.submitted_ts
+            if tr is not None:
+                from ray_tpu.telemetry import trace as trace_mod
+                trace_mod.record_span(
+                    "queue", tr,
+                    start=trace_mod.epoch_of(req.submitted_ts),
+                    dur=req.admitted_ts - req.submitted_ts, rid=req.rid,
+                    replica=self.trace_label)
+                trace_mod.record_span(
+                    "prefill", tr, start=trace_mod.epoch_of(sp.start),
+                    dur=sp.dur, rid=req.rid, bucket=bucket,
+                    cached=cached, kind=kind, replica=self.trace_label)
+                trace_mod.event("first_token", tr, rid=req.rid,
+                                ttft_s=ttft, replica=self.trace_label)
+            if self.telemetry.enabled:
+                self.telemetry.record_queue(
+                    req.admitted_ts - req.submitted_ts,
+                    depth=len(sched.waiting))
+                self.telemetry.record_prefill(
+                    sp.dur, prompt_tokens=plen, bucket=bucket,
+                    cached_tokens=cached)
+                self.telemetry.record_ttft(
+                    ttft, prefix_hit=cached > 0,
+                    trace_id=req.trace.trace_id
+                    if req.trace is not None else None)
+            self._deliver(req, int(tok), float(logp), events)
 
     def _install_import(self, req: Request, events) -> None:
         """Seed an admitted import's slot from its handoff payload —
@@ -1388,7 +1411,7 @@ class InferenceEngine:
 
     # ----------------------------------------------------------- decode
     def _decode(self, events, skip: Optional[Set[int]] = None) -> None:
-        from ray_tpu.util import chaos, tracing
+        from ray_tpu.util import chaos
 
         # fault site BEFORE any cache/scheduler mutation and before the
         # donated executable dispatches: an injected decode failure
@@ -1414,8 +1437,7 @@ class InferenceEngine:
             # their sampled outputs are never delivered
             page_table = page_table.copy()
             page_table[list(skip), :] = kvc.GARBAGE_PAGE
-        t0 = time.monotonic()
-        with tracing.span("infer/decode", active=len(active)):
+        with tracing.span("infer/decode", active=len(active)) as sp:
             if self.lora_cfg is not None:
                 # per-slot adapter ids: co-batched tenants share this
                 # one tick (the bank gather routes each row through its
@@ -1434,32 +1456,34 @@ class InferenceEngine:
             logits, *state = fn(*args)
             self.cache.state = tuple(state)
             sampled, logps = self._sample_slots(logits, reqs)
-        wall = time.monotonic() - t0
-        traced = [r.trace.trace_id for r in active
-                  if r.trace is not None and r.trace.sampled]
-        if traced:
-            # ONE coalesced span per tick (trace_id=None: a global
-            # span), carrying the sampled trace ids it served — a span
-            # per (tick, request) would swamp the ring at decode rate
-            from ray_tpu.telemetry import trace as trace_mod
-            trace_mod.record_span(
-                "decode_tick", None, start=trace_mod.epoch_of(t0),
-                dur=wall, active=len(active), trace_ids=traced,
-                replica=self.trace_label)
-        if self.telemetry.enabled:
-            self.telemetry.record_decode(wall, active=len(active))
-        if self.debug_logits:
-            host_logits = np.asarray(logits)
-        for slot in list(sched.active):
-            if slot in skip:
-                continue
-            req = sched.active[slot]
-            sched.lengths[slot] += 1     # the input token is now cached
+        with self._deliver_span(events):
+            traced = [r.trace.trace_id for r in active
+                      if r.trace is not None and r.trace.sampled]
+            if traced:
+                # ONE coalesced span per tick (trace_id=None: a global
+                # span), carrying the sampled trace ids it served — a
+                # span per (tick, request) would swamp the ring at
+                # decode rate
+                from ray_tpu.telemetry import trace as trace_mod
+                trace_mod.record_span(
+                    "decode_tick", None,
+                    start=trace_mod.epoch_of(sp.start), dur=sp.dur,
+                    active=len(active), trace_ids=traced,
+                    replica=self.trace_label)
+            if self.telemetry.enabled:
+                self.telemetry.record_decode(sp.dur, active=len(active))
             if self.debug_logits:
-                self.logits_trace.setdefault(req.rid, []).append(
-                    host_logits[slot])
-            self._deliver(req, int(sampled[slot]),
-                          float(logps[slot]), events)
+                host_logits = np.asarray(logits)
+            for slot in list(sched.active):
+                if slot in skip:
+                    continue
+                req = sched.active[slot]
+                sched.lengths[slot] += 1   # the input token is now cached
+                if self.debug_logits:
+                    self.logits_trace.setdefault(req.rid, []).append(
+                        host_logits[slot])
+                self._deliver(req, int(sampled[slot]),
+                              float(logps[slot]), events)
 
     # ---------------------------------------------- speculation (r21)
     def _plan_speculation(self) -> Dict[int, List[int]]:
@@ -1509,7 +1533,6 @@ class InferenceEngine:
         K/V stays behind the length mask and is overwritten by the
         next writes, which IS the rollback (the write window is
         slot-private; asserted below)."""
-        from ray_tpu.util import tracing
         sched = self.scheduler
         req = sched.active[slot]
         L = int(sched.lengths[slot])
@@ -1526,8 +1549,7 @@ class InferenceEngine:
         tokens = np.zeros((1, kb + 1), np.int32)
         tokens[0, 0] = req.generated[-1]
         tokens[0, 1:1 + n_drafts] = drafts
-        t0 = time.monotonic()
-        with tracing.span("infer/verify", rid=req.rid, k=n_drafts):
+        with tracing.span("infer/verify", rid=req.rid, k=n_drafts) as sp:
             if self.lora_cfg is not None:
                 aid = np.array([max(req.adapter_slot, 0)], np.int32)
                 args = (self.params, self.lora_bank, *self.cache.state,
@@ -1549,46 +1571,62 @@ class InferenceEngine:
             # len(generated) + i tokens, so counts advance from there
             c = len(req.generated)
             n_rows = kb + 1
-            seeds = np.full((n_rows,), req.sampling.seed, np.int32)
-            counts = c + np.arange(n_rows, dtype=np.int32)
-            temps = np.full((n_rows,), req.sampling.temperature,
-                            np.float32)
-            top_ks = np.full((n_rows,), req.sampling.top_k, np.int32)
-            top_ps = np.full((n_rows,), req.sampling.top_p, np.float32)
-            toks, logps = sample_tokens_logprobs(
-                logits[0], seeds, counts, temps, top_ks, top_ps)
-            toks, logps = np.asarray(toks), np.asarray(logps)
-        wall = time.monotonic() - t0
-        m, emitted = accept_drafts(toks[:n_drafts + 1], drafts)
-        self.spec_proposed += n_drafts
-        self.spec_accepted += m
-        self.spec_k_hist[m] = self.spec_k_hist.get(m, 0) + 1
-        if req.trace is not None and req.trace.sampled:
-            from ray_tpu.telemetry import trace as trace_mod
-            trace_mod.record_span(
-                "verify", req.trace, start=trace_mod.epoch_of(t0),
-                dur=wall, rid=req.rid, proposed=n_drafts, accepted=m,
-                replica=self.trace_label)
-        if self.debug_logits:
-            host_logits = np.asarray(logits[0])
-        delivered = 0
-        for i, tok in enumerate(emitted):
-            # the input token of row i (last_token or draft i) is now
-            # cached at position L + i; advancing BEFORE delivery
-            # keeps the decode-step length semantics, and a retire
-            # inside the block (EOS / max_new) resets the slot anyway
-            sched.lengths[slot] = L + i + 1
+            with tracing.span("infer/sample", rows=n_rows):
+                seeds = np.full((n_rows,), req.sampling.seed, np.int32)
+                counts = c + np.arange(n_rows, dtype=np.int32)
+                temps = np.full((n_rows,), req.sampling.temperature,
+                                np.float32)
+                top_ks = np.full((n_rows,), req.sampling.top_k, np.int32)
+                top_ps = np.full((n_rows,), req.sampling.top_p,
+                                 np.float32)
+                toks, logps = sample_tokens_logprobs(
+                    logits[0], seeds, counts, temps, top_ks, top_ps)
+                toks, logps = np.asarray(toks), np.asarray(logps)
+        with self._deliver_span(events):
+            m, emitted = accept_drafts(toks[:n_drafts + 1], drafts)
+            self.spec_proposed += n_drafts
+            self.spec_accepted += m
+            self.spec_k_hist[m] = self.spec_k_hist.get(m, 0) + 1
+            if req.trace is not None and req.trace.sampled:
+                from ray_tpu.telemetry import trace as trace_mod
+                trace_mod.record_span(
+                    "verify", req.trace,
+                    start=trace_mod.epoch_of(sp.start), dur=sp.dur,
+                    rid=req.rid, proposed=n_drafts, accepted=m,
+                    replica=self.trace_label)
             if self.debug_logits:
-                self.logits_trace.setdefault(req.rid, []).append(
-                    host_logits[i])
-            self._deliver(req, int(tok), float(logps[i]), events)
-            delivered += 1
-            if req.done:
-                break
-        if self.telemetry.enabled:
-            self.telemetry.record_verify(
-                wall, proposed=n_drafts, accepted=m,
-                emitted=delivered)
+                host_logits = np.asarray(logits[0])
+            delivered = 0
+            for i, tok in enumerate(emitted):
+                # the input token of row i (last_token or draft i) is
+                # now cached at position L + i; advancing BEFORE
+                # delivery keeps the decode-step length semantics, and
+                # a retire inside the block (EOS / max_new) resets the
+                # slot anyway
+                sched.lengths[slot] = L + i + 1
+                if self.debug_logits:
+                    self.logits_trace.setdefault(req.rid, []).append(
+                        host_logits[i])
+                self._deliver(req, int(tok), float(logps[i]), events)
+                delivered += 1
+                if req.done:
+                    break
+            if self.telemetry.enabled:
+                self.telemetry.record_verify(
+                    sp.dur, proposed=n_drafts, accepted=m,
+                    emitted=delivered)
+
+    @contextlib.contextmanager
+    def _deliver_span(self, events):
+        """``infer/deliver``: what a tick does with tokens once they are
+        on the host (prefix registration, lengths, records, retiring),
+        closed with how many events it appended and how many of them
+        finished a request."""
+        n0 = len(events)
+        with tracing.span("infer/deliver") as sp:
+            yield sp
+            sp.set(events=len(events) - n0,
+                   done=sum(1 for ev in events[n0:] if ev[2]))
 
     def _deliver(self, req: Request, tok: int, logp: float,
                  events) -> None:
@@ -1625,32 +1663,37 @@ class InferenceEngine:
         batch (None rows are inactive, result discarded) or a prefill's
         single [1, V] row.  Returns ``(tokens, model logprobs)``."""
         null = SamplingParams()
-        seeds = np.array([(r.sampling.seed if r else 0) for r in reqs],
-                         np.int32)
-        counts = np.array([(len(r.generated) if r else 0) for r in reqs],
-                          np.int32)
-        temps = np.array(
-            [(r.sampling.temperature if r else null.temperature)
-             for r in reqs], np.float32)
-        top_ks = np.array([(r.sampling.top_k if r else 0) for r in reqs],
-                          np.int32)
-        top_ps = np.array([(r.sampling.top_p if r else 1.0)
-                           for r in reqs], np.float32)
-        toks, logps = sample_tokens_logprobs(logits, seeds, counts,
-                                             temps, top_ks, top_ps)
-        return np.asarray(toks), np.asarray(logps)
+        with tracing.span("infer/sample", rows=len(reqs)):
+            seeds = np.array([(r.sampling.seed if r else 0)
+                              for r in reqs], np.int32)
+            counts = np.array([(len(r.generated) if r else 0)
+                               for r in reqs], np.int32)
+            temps = np.array(
+                [(r.sampling.temperature if r else null.temperature)
+                 for r in reqs], np.float32)
+            top_ks = np.array([(r.sampling.top_k if r else 0)
+                               for r in reqs], np.int32)
+            top_ps = np.array([(r.sampling.top_p if r else 1.0)
+                               for r in reqs], np.float32)
+            toks, logps = sample_tokens_logprobs(logits, seeds, counts,
+                                                 temps, top_ks, top_ps)
+            # the fetch: here the host waits for the device
+            return np.asarray(toks), np.asarray(logps)
 
     # ---------------------------------------------------- compile cache
     def _get_compiled(self, key, build_fn, example_args, *, kind: str):
-        key = self._exec_key + key
-        fn = self._compiled.get(key)
+        fn = self._compiled.get(self._exec_key + key)
         if fn is not None:
             self.hit_counts[kind] += 1
             return fn
         self.compile_counts[kind] += 1
-        jitted = build_fn()
-        fn = jitted.lower(*example_args).compile()
-        self._compiled[key] = fn
+        # a miss only: which step compiled (or loaded from the
+        # persistent cache), and for how long
+        with tracing.span("infer/compile", kind=kind,
+                          bucket=key[1] if len(key) > 1 else 0):
+            jitted = build_fn()
+            fn = jitted.lower(*example_args).compile()
+        self._compiled[self._exec_key + key] = fn
         return fn
 
     # ------------------------------------------------------- step fns --

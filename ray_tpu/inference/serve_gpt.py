@@ -48,6 +48,7 @@ from typing import Any, Dict, Optional
 
 import ray_tpu.serve as serve
 from ray_tpu.inference.sampling import SamplingParams
+from ray_tpu.util import tracing
 
 _PRESETS = ("tiny", "gpt2", "gpt2_medium", "gpt2_large")
 
@@ -267,27 +268,35 @@ class GPTDeployment:
         while self.engine.has_work():
             events = await loop.run_in_executor(None,
                                                 self.engine.step)
-            for ev in events:
-                rid, token, done = ev
-                queue = self._queues.get(rid)
-                if queue is None:
-                    continue
-                if queue.qsize() == 0:
-                    # empty -> nonempty: the idle clock measures how
-                    # long tokens sit UNREAD, so it starts when the
-                    # first unread token lands — not at the last
-                    # consumer read (a consumer blocked in get()
-                    # through a slow step would otherwise look idle
-                    # the moment the token arrives)
-                    self._last_pumped[rid] = time.monotonic()
-                if ev.error is not None:
-                    # deadline expiry: the engine already released
-                    # the slot/pages; surface the typed error as the
-                    # stream's failure
-                    queue.put_nowait(ev.error)
-                else:
-                    queue.put_nowait((token, done, ev.logprob))
-            self._reap_idle_streams()
+            # from the tick's return to the last queue fed: the time
+            # between two ticks is the serve front's, and this is the
+            # part of it the pump itself spends
+            with tracing.span("serve/fanout", events=len(events),
+                              streams=len(self._queues)):
+                self._fan_out(events)
+                self._reap_idle_streams()
+
+    def _fan_out(self, events) -> None:
+        for ev in events:
+            rid, token, done = ev
+            queue = self._queues.get(rid)
+            if queue is None:
+                continue
+            if queue.qsize() == 0:
+                # empty -> nonempty: the idle clock measures how
+                # long tokens sit UNREAD, so it starts when the
+                # first unread token lands — not at the last
+                # consumer read (a consumer blocked in get()
+                # through a slow step would otherwise look idle
+                # the moment the token arrives)
+                self._last_pumped[rid] = time.monotonic()
+            if ev.error is not None:
+                # deadline expiry: the engine already released
+                # the slot/pages; surface the typed error as the
+                # stream's failure
+                queue.put_nowait(ev.error)
+            else:
+                queue.put_nowait((token, done, ev.logprob))
 
     def _reap_idle_streams(self) -> None:
         """Cancel requests whose stream has tokens waiting but whose

@@ -169,22 +169,28 @@ class StepTelemetry:
         self._note_tokens(args, kwargs)
         self._profile(i, before=True)
         ts = time.time()
-        t0 = time.monotonic()
-        with tracing.span(f"{self.label}/step", step=i):
-            with jax.profiler.StepTraceAnnotation(self.label, step_num=i):
-                with tracing.span(f"{self.label}/dispatch", step=i):
-                    out = self._dispatch(step_fn, args, kwargs, i, ts)
-                t_disp = time.monotonic()
-                with tracing.span(f"{self.label}/sync", step=i):
-                    jax.block_until_ready(out)
-        t_end = time.monotonic()
+        # the step annotation is what the profiler's step view reads;
+        # the phases inside it are the program's spans, and the record's
+        # times are theirs (wall = dispatch start to sync end)
+        with jax.profiler.StepTraceAnnotation(self.label, step_num=i):
+            with tracing.span(f"{self.label}/dispatch", step=i) as disp:
+                out = self._dispatch(step_fn, args, kwargs, i, ts)
+            with tracing.span(f"{self.label}/sync", step=i) as sync:
+                jax.block_until_ready(out)
+            with tracing.span(f"{self.label}/loss_read", step=i):
+                loss = self._maybe_loss(out)
+            with tracing.span(f"{self.label}/record", step=i):
+                self._record(i, ts, disp, sync, loss)
         self._profile(i, before=False)
+        return out
+
+    def _record(self, i, ts, disp, sync, loss):
         rec: Dict[str, Any] = {
             "step": i,
             "ts": ts,
-            "wall_s": t_end - t0,
-            "dispatch_s": t_disp - t0,
-            "sync_s": t_end - t_disp,
+            "wall_s": sync.end - disp.start,
+            "dispatch_s": sync.start - disp.start,
+            "sync_s": sync.end - sync.start,
         }
         if i == 0 and self.compile_s is not None:
             rec["compile_s"] = self.compile_s
@@ -203,7 +209,6 @@ class StepTelemetry:
                     rec["mfu"] = flops_mod.mfu(
                         rec["tokens_per_sec"] / self.n_devices(), fpt,
                         peak)
-        loss = self._maybe_loss(out)
         if loss is not None:
             rec["loss"] = loss
         self.records.append(rec)
@@ -215,7 +220,6 @@ class StepTelemetry:
             # reports.
             del self.records[:len(self.records) - self._MAX_RECORDS]
         self._emit(rec)
-        return out
 
     def _dispatch(self, step_fn, args, kwargs, i, ts):
         if not self._aot:

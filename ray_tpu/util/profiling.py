@@ -14,6 +14,8 @@ import os
 import time
 from typing import Iterator, Optional
 
+from ray_tpu.util import tracing
+
 
 @contextlib.contextmanager
 def profile_device(logdir: Optional[str] = None,
@@ -33,12 +35,9 @@ def profile_device(logdir: Optional[str] = None,
         yield logdir
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region inside a device profile (TraceAnnotation)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
+# A named region inside a device profile: the program's one span
+# primitive, which annotates the profiler's trace wherever jax is loaded.
+annotate = tracing.span
 
 
 def device_memory_stats() -> dict:

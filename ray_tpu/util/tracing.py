@@ -6,12 +6,15 @@ light seam over the same points: if ``opentelemetry`` is importable the
 spans are real OTel spans (exported by whatever provider the user
 configured); otherwise an in-process recorder keeps (name, start, end,
 attributes) tuples so tests and the timeline can still observe the
-graph.  Zero overhead when never enabled.
+graph.  Where jax is loaded every span is also a profiler
+``TraceAnnotation``: one primitive, and the profiler's clock for the
+spans a device trace is read against.
 """
 
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -124,39 +127,96 @@ def clear_recorded() -> None:
         _records.clear()
 
 
-@contextlib.contextmanager
-def span(name: str, **attributes):
-    """Trace one operation.  No-op (two attr reads) when disabled.
+def _otel_attributes(otel_span, attributes: Dict[str, Any]) -> None:
+    for k, v in attributes.items():
+        try:
+            otel_span.set_attribute(k, v)
+        except Exception:  # noqa: BLE001
+            pass
+
+
+class Span:
+    """One open span: what :func:`span` returns and its ``with`` block
+    binds.  ``start`` is the monotonic stamp at entry and ``dur`` the
+    seconds to exit (``None`` until then), so a caller that also feeds
+    another sink (the flight recorder, ``InferTelemetry``) takes both
+    from here and reads no clock of its own."""
+
+    __slots__ = ("name", "attributes", "start", "dur", "_ann", "_otel",
+                 "_otel_span", "_rec")
+
+    def __init__(self, name: str, attributes: Dict[str, Any]):
+        self.name = name
+        self.attributes = attributes
+        self.start = 0.0
+        self.dur: Optional[float] = None
+        self._ann = self._otel = self._otel_span = self._rec = None
+
+    @property
+    def end(self) -> float:
+        """Monotonic stamp of the exit (after it)."""
+        return self.start + self.dur
+
+    def set(self, **attributes) -> None:
+        """Attach what is known only now (a count at the span's end)."""
+        self.attributes.update(attributes)
+        if self._ann is not None:
+            self._ann.set_metadata(**attributes)
+        if self._otel_span is not None:
+            _otel_attributes(self._otel_span, attributes)
+
+    def __enter__(self) -> "Span":
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self.name,
+                                                 **self.attributes)
+            self._ann.__enter__()
+        if _enabled:
+            if _tracer is not None:
+                self._otel = _tracer.start_as_current_span(self.name)
+                self._otel_span = self._otel.__enter__()
+                _otel_attributes(self._otel_span, self.attributes)
+            else:
+                self._rec = {"name": self.name, "start": time.time(),
+                             "tid": threading.get_ident(),
+                             "attributes": self.attributes}
+        self.start = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur = time.monotonic() - self.start
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._otel is not None:
+            self._otel.__exit__(*exc)
+        rec = self._rec
+        if rec is not None:
+            rec["dur"] = self.dur
+            rec["end"] = rec["start"] + self.dur
+            with _lock:
+                _records.append(rec)
+                if len(_records) > _MAX_RECORDS:
+                    del _records[:len(_records) - _MAX_RECORDS]
+
+
+def span(name: str, **attributes) -> Span:
+    """Trace one operation: the one way the program opens a span.
+
+    Where ``jax`` is already imported (never imported from here:
+    control-plane processes stay free of it) the span is a
+    ``jax.profiler.TraceAnnotation`` on the profiler's clock, so a
+    device trace shows what the host was doing; with no profile running
+    that is a flag check.  With :func:`enable_tracing` on it is also an
+    OTel span or a fallback record, as before.  Attributes are plain
+    ``str``/``int``/``float``.
 
     The fallback record keeps an *epoch* ``start`` for timeline
-    placement but computes ``dur`` (and the derived ``end``) from the
+    placement but takes ``dur`` (and the derived ``end``) from the
     monotonic clock: ``time.time()`` can step backwards under NTP
     slew, which used to yield negative/garbage durations for spans
     straddling a clock adjustment."""
-    if not _enabled:
-        yield None
-        return
-    if _tracer is not None:
-        with _tracer.start_as_current_span(name) as s:
-            for k, v in attributes.items():
-                try:
-                    s.set_attribute(k, v)
-                except Exception:  # noqa: BLE001
-                    pass
-            yield s
-        return
-    rec = {"name": name, "start": time.time(),
-           "tid": threading.get_ident(), "attributes": attributes}
-    t0 = time.monotonic()
-    try:
-        yield rec
-    finally:
-        rec["dur"] = time.monotonic() - t0
-        rec["end"] = rec["start"] + rec["dur"]
-        with _lock:
-            _records.append(rec)
-            if len(_records) > _MAX_RECORDS:
-                del _records[:len(_records) - _MAX_RECORDS]
+    return Span(name, attributes)
 
 
 def task_span(spec) -> "contextlib.AbstractContextManager":
