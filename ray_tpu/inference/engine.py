@@ -65,8 +65,14 @@ written to the paged cache, decode attention over the gathered pages
 via ``ops/attention.py:decode_attention``), plus the model's own final
 norm / tied head so cached decode logits match teacher-forced
 ``forward`` logits bit-for-bit-modulo-dtype (parity-tested in
-``tests/test_inference.py``).  The cache arrays are donated through
-every step, so steady-state decode allocates nothing.
+``tests/test_inference.py``).  The stacked ``[L, pages, page, H, D]``
+cache arrays are donated through every step and carried whole through
+the layer scan: the hook rewrites only the pages the new tokens land
+in, at ``(layer, page)``, and reads only through the ``(layer, page)``
+gather, so no step materialises a layer's pool and steady-state decode
+allocates nothing (the lowered structure is asserted in
+``tests/test_inference.py``, the TPU compiler's in
+``tests/test_tpu_aot.py``).
 
 Single-device by design for now: ``pallas_call`` has no SPMD rule and
 a serving replica owns one chip; sharded multi-chip decode is an open
@@ -1711,14 +1717,18 @@ class InferenceEngine:
 
     def _layer_scan(self, params, x, caches, positions, attn_hook,
                     lora_bank=None, lora_ids=None):
-        """Run the layer stack with per-layer cache slices in the scan
-        carry (dynamic-slice in / dynamic-update out, the donation-
-        friendly pattern) -> (final normed hidden, caches).
+        """Run the layer stack with the whole stacked cache arrays in
+        the scan carry -> (final normed hidden, caches).
 
         ``caches`` is the cache's state tuple of stacked ``[L, ...]``
         arrays — ``(k, v)`` or, quantized, ``(k, v, k_scale,
-        v_scale)``; the per-layer slice tuple is opaque to
-        ``layer_apply`` and round-trips through ``attn_hook``.
+        v_scale)``.  No layer's pool is ever sliced out or put back:
+        each layer hands ``layer_apply`` the opaque ``cache = (layer
+        index, caches)``, which round-trips to ``attn_hook``; the hook
+        writes the new tokens into their pages at ``(layer, page)``
+        (:meth:`_write_cache`), reads through the ``(layer, page)``
+        gather, and returns the updated stacked arrays for the carry —
+        so only the touched and the gathered pages cross HBM.
 
         ``lora_bank``/``lora_ids`` (r25 multi-tenant): bank factors are
         stacked ``[N, L, ...]`` — layer axis 1 — sliced per scan step;
@@ -1740,15 +1750,9 @@ class InferenceEngine:
                         for k, v in lora_bank.items() if k != "scale"}
                 lora["scale"] = lora_bank["scale"]
                 lora["ids"] = lora_ids
-            layer_cache = tuple(
-                lax.dynamic_index_in_dim(c, i, 0, keepdims=False)
-                for c in caches)
-            x, _aux, layer_cache = gpt_mod.layer_apply(
+            x, _aux, caches = gpt_mod.layer_apply(
                 lp, x, cfg, positions=positions, attn_fn=attn_hook,
-                cache=layer_cache, lora=lora)
-            caches = tuple(
-                lax.dynamic_update_index_in_dim(c, nc, i, 0)
-                for c, nc in zip(caches, layer_cache))
+                cache=(i, caches), lora=lora)
             return (x, caches), None
 
         (x, caches), _ = lax.scan(
@@ -1766,11 +1770,23 @@ class InferenceEngine:
         q, s = quantize_block(kv, block=self.cfg.head_dim, axis=-1)
         return q, s[..., 0]
 
+    def _write_cache(self, write, cache, k, v, *where):
+        """Write post-RoPE K and V rows ``[..., H, D]`` into one layer
+        of every cache array — codes and scales when quantized — with
+        the ``kv_cache`` writer ``write`` at ``where`` (the writer's
+        page and position arguments).  ``cache`` is ``_layer_scan``'s
+        ``(layer, stacked arrays)`` -> ``(layer, updated arrays)``."""
+        layer, arrays = cache
+        rows = (k, v)
+        if self.kv_dtype == "int8":
+            (kq, ks), (vq, vs) = self._quantize_kv(k), self._quantize_kv(v)
+            rows = (kq, vq, ks, vs)
+        return layer, tuple(
+            write(a, r, layer, *where, self.page_size)
+            for a, r in zip(arrays, rows))
+
     def _build_prefill(self):
         cfg = self.cfg
-        page_size = self.page_size
-        quantized = self.kv_dtype == "int8"
-
         lora_on = self.lora_cfg is not None
 
         def prefill(params, *args):
@@ -1787,29 +1803,12 @@ class InferenceEngine:
             positions = jnp.arange(S)
 
             def attn_hook(q, k, v, cache):
-                if quantized:
-                    ck, cv, cks, cvs = cache
-                    kq, ks = self._quantize_kv(k[0])
-                    vq, vs = self._quantize_kv(v[0])
-                    ck = kvc.write_prefill(ck, kq, page_row, page_size)
-                    cv = kvc.write_prefill(cv, vq, page_row, page_size)
-                    cks = kvc.write_prefill(cks, ks, page_row,
-                                            page_size)
-                    cvs = kvc.write_prefill(cvs, vs, page_row,
-                                            page_size)
-                    new_cache = (ck, cv, cks, cvs)
-                else:
-                    ck, cv = cache
-                    ck = kvc.write_prefill(ck, k[0], page_row,
-                                           page_size)
-                    cv = kvc.write_prefill(cv, v[0], page_row,
-                                           page_size)
-                    new_cache = (ck, cv)
+                _, arrays = self._write_cache(kvc.write_prefill, cache,
+                                              k[0], v[0], page_row)
                 # attention reads the full-precision prompt K/V (the
                 # prompt IS the whole context); quantization only
                 # affects what later decode steps read back
-                o = self._prefill_attention(q, k, v)
-                return o, new_cache
+                return self._prefill_attention(q, k, v), arrays
 
             x = self._embed(params, tokens, positions)
             x, cache_state = self._layer_scan(params, x,
@@ -1868,8 +1867,6 @@ class InferenceEngine:
         slot at a time.
         """
         cfg = self.cfg
-        page_size = self.page_size
-        quantized = self.kv_dtype == "int8"
         lora_on = self.lora_cfg is not None
 
         def prefill_cached(params, *args):
@@ -1887,49 +1884,23 @@ class InferenceEngine:
             positions = cached_len + jnp.arange(S)   # absolute
 
             def attn_hook(q, k, v, cache):
+                layer, arrays = self._write_cache(
+                    kvc.write_prefill_at, cache, k[0], v[0], page_row,
+                    cached_len, suffix_len)
                 row = page_row[None]                 # [1, max_pages]
-                if quantized:
-                    ck, cv, cks, cvs = cache
-                    kq, ks_ = self._quantize_kv(k[0])
-                    vq, vs_ = self._quantize_kv(v[0])
-                    ck = kvc.write_prefill_at(ck, kq, page_row,
-                                              cached_len, suffix_len,
-                                              page_size)
-                    cv = kvc.write_prefill_at(cv, vq, page_row,
-                                              cached_len, suffix_len,
-                                              page_size)
-                    cks = kvc.write_prefill_at(cks, ks_, page_row,
-                                               cached_len, suffix_len,
-                                               page_size)
-                    cvs = kvc.write_prefill_at(cvs, vs_, page_row,
-                                               cached_len, suffix_len,
-                                               page_size)
-                    new_cache = (ck, cv, cks, cvs)
-                    kctx = kvc.gather_pages(ck, row)
-                    vctx = kvc.gather_pages(cv, row)
-                    ksc = kvc.gather_pages(cks, row)
-                    vsc = kvc.gather_pages(cvs, row)
+                kctx, vctx, *scales = (
+                    kvc.gather_pages(a, layer, row) for a in arrays)
+                if scales:
                     kctx = (kctx.astype(jnp.float32)
-                            * ksc[..., None]).astype(q.dtype)
+                            * scales[0][..., None]).astype(q.dtype)
                     vctx = (vctx.astype(jnp.float32)
-                            * vsc[..., None]).astype(q.dtype)
-                else:
-                    ck, cv = cache
-                    ck = kvc.write_prefill_at(ck, k[0], page_row,
-                                              cached_len, suffix_len,
-                                              page_size)
-                    cv = kvc.write_prefill_at(cv, v[0], page_row,
-                                              cached_len, suffix_len,
-                                              page_size)
-                    new_cache = (ck, cv)
-                    kctx = kvc.gather_pages(ck, row)
-                    vctx = kvc.gather_pages(cv, row)
+                            * scales[1][..., None]).astype(q.dtype)
                 # suffix self-attention reads the full-precision k/v
                 # (like the cold prefill); only the cached prefix is
                 # read back through the (possibly quantized) cache
                 o = _cached_context_attention(q, kctx, vctx, k, v,
                                               cached_len)
-                return o, new_cache
+                return o, arrays
 
             x = self._embed(params, tokens, positions)
             x, cache_state = self._layer_scan(params, x,
@@ -1954,9 +1925,7 @@ class InferenceEngine:
 
     def _build_decode(self):
         cfg = self.cfg
-        page_size = self.page_size
         impl = self.decode_impl
-        quantized = self.kv_dtype == "int8"
         lora_on = self.lora_cfg is not None
 
         def decode(params, *args):
@@ -1974,35 +1943,16 @@ class InferenceEngine:
 
             def attn_hook(q, k, v, cache):
                 from ray_tpu.ops.attention import decode_attention
-                if quantized:
-                    ck, cv, cks, cvs = cache
-                    kq, ks = self._quantize_kv(k[:, 0])
-                    vq, vs = self._quantize_kv(v[:, 0])
-                    ck = kvc.write_decode(ck, kq, page_table, lengths,
-                                          page_size)
-                    cv = kvc.write_decode(cv, vq, page_table, lengths,
-                                          page_size)
-                    cks = kvc.write_decode(cks, ks, page_table,
-                                           lengths, page_size)
-                    cvs = kvc.write_decode(cvs, vs, page_table,
-                                           lengths, page_size)
-                    o = decode_attention(
-                        q[:, 0], kvc.gather_pages(ck, page_table),
-                        kvc.gather_pages(cv, page_table), lengths + 1,
-                        impl=impl,
-                        k_scale=kvc.gather_pages(cks, page_table),
-                        v_scale=kvc.gather_pages(cvs, page_table))
-                    return o[:, None], (ck, cv, cks, cvs)
-                ck, cv = cache
-                ck = kvc.write_decode(ck, k[:, 0], page_table, lengths,
-                                      page_size)
-                cv = kvc.write_decode(cv, v[:, 0], page_table, lengths,
-                                      page_size)
-                kctx = kvc.gather_pages(ck, page_table)
-                vctx = kvc.gather_pages(cv, page_table)
-                o = decode_attention(q[:, 0], kctx, vctx, lengths + 1,
-                                     impl=impl)
-                return o[:, None], (ck, cv)
+                layer, arrays = self._write_cache(
+                    kvc.write_decode, cache, k[:, 0], v[:, 0],
+                    page_table, lengths)
+                kctx, vctx, *scales = (
+                    kvc.gather_pages(a, layer, page_table)
+                    for a in arrays)
+                o = decode_attention(
+                    q[:, 0], kctx, vctx, lengths + 1, impl=impl,
+                    **dict(zip(("k_scale", "v_scale"), scales)))
+                return o[:, None], arrays
 
             x = self._embed(params, tokens[:, None], positions)
             x, cache_state = self._layer_scan(params, x,

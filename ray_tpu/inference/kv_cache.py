@@ -49,9 +49,9 @@ as prefix hits and the handoff moves no contents at all.
 (``ray_tpu.quant``): codes in int8, one f32 scale per (page, position,
 head) lane vector riding in per-page scale arrays
 ``[n_layers, pages, page_size, kv_heads]``.  The write/gather helpers
-are shape-generic (they address ``[P, page_size, ...]`` storage by
-page), so the same scatter/gather moves codes and scales; the engine
-quantizes post-RoPE on write and ``decode_attention`` dequantizes
+are shape-generic (they address ``[L, P, page_size, ...]`` storage by
+(layer, page)), so the same scatter/gather moves codes and scales; the
+engine quantizes post-RoPE on write and ``decode_attention`` dequantizes
 inside its context strips.  At head_dim 64 that is 68 bytes per cached
 vector (64 codes + one f32 scale) vs 128 in bf16 — :meth:`KVCache.bytes`
 counts both arrays, so the ~2x capacity-per-HBM-byte claim is
@@ -898,67 +898,106 @@ class KVCache:
         return pages_per_slot * per_page
 
 
-def write_prefill(pages, new, page_row, page_size: int):
-    """Scatter a prompt's K (or V) into one slot's pages — the cold
-    (start-0, whole-bucket) case of :func:`write_prefill_at`.
+def _blend_pages(pages, layer, page, rows, hit):
+    """Write ``rows`` where ``hit`` into pages ``page`` of ``layer`` and
+    return the updated stacked array.
 
-    pages: [P, page_size, H, D] (one layer); new: [S, H, D] (bucket-
-    padded — with ``valid_len = S`` tail positions land in whatever
-    ``page_row`` maps them to, the garbage page for unreserved tail
-    entries); page_row: [max_pages] int32.  Returns the updated pages
-    array."""
-    return write_prefill_at(pages, new, page_row, 0, new.shape[0],
+    pages: [L, P, page_size, *rest]; page: [n] int32; rows:
+    [n, page_size, *rest]; hit: [n, page_size] bool.  Read the n pages,
+    lay the new rows over them, scatter the n *whole* pages back at
+    ``(layer, page)``: only those pages cross HBM, never a layer's
+    pool.  Whole pages and not single rows because of how a TPU lays
+    the array out: its default layout puts the page offset on the
+    lanes (head_dim 64 would waste half of them), where a row is one
+    lane of every tile of its page.  XLA answers a row scatter by
+    re-laying the whole cache out, head_dim minor and padded 2.7x, on
+    the way in and back on the way out of every step; the same
+    compiler keeps a whole-page scatter, like the page gather, in
+    place in the layout the array came in
+    (``tests/test_tpu_aot.py``)."""
+    hit = hit.reshape(hit.shape + (1,) * (rows.ndim - 2))
+    old = pages[layer, page]
+    return pages.at[layer, page].set(
+        jnp.where(hit, rows.astype(pages.dtype), old))
+
+
+def write_prefill(pages, new, layer, page_row, page_size: int):
+    """Write a prompt's K (or V) into one slot's pages of one layer —
+    the cold (start-0, whole-bucket) case of :func:`write_prefill_at`.
+
+    pages: [L, P, page_size, H, D] (the whole stacked array); new:
+    [S, H, D] (bucket-padded — with ``valid_len = S`` tail positions
+    land in whatever ``page_row`` maps them to, the garbage page for
+    unreserved tail entries); layer: traced scalar; page_row:
+    [max_pages] int32.  Returns the updated stacked array."""
+    return write_prefill_at(pages, new, layer, page_row, 0, new.shape[0],
                             page_size)
 
 
-def write_prefill_at(pages, new, page_row, start, valid_len,
+def write_prefill_at(pages, new, layer, page_row, start, valid_len,
                      page_size: int):
-    """Scatter a *suffix*'s K (or V) at absolute positions
-    ``start .. start+S`` of one slot's pages (the cached-context
-    prefill: positions below ``start`` are prefix-cache hits that must
-    not be touched).
+    """Write a *suffix*'s K (or V) at absolute positions
+    ``start .. start+valid_len`` of one slot's pages of one layer (the
+    cached-context prefill: positions below ``start`` are prefix-cache
+    hits that must not be touched).
 
-    pages: [P, page_size, *rest] (one layer); new: [S, *rest] (bucket-
-    padded suffix); page_row: [max_pages] int32; start/valid_len:
-    traced scalars.  Rows past ``valid_len`` route to the garbage page
-    *explicitly* — a suffix bucket can overhang the slot's reserved
-    pages (start + bucket > max_pages * page_size), where the cold
-    prefill's garbage-padded ``page_row`` tail no longer covers them.
-    Returns the updated pages array."""
+    pages: [L, P, page_size, *rest] (the whole stacked array — the
+    layer is one more coordinate of the write, never a slice); new:
+    [S, *rest] (bucket-padded suffix); layer/start/valid_len: traced
+    scalars; page_row: [max_pages] int32.  The suffix touches at most
+    ``ceil(S / page_size) + 1`` of the slot's pages (``start`` need
+    not be page-aligned: a speculative verify starts mid-page); each
+    is rewritten whole with the valid rows laid over what it held
+    (:func:`_blend_pages`).  Rows past ``valid_len`` are written
+    nowhere, and a candidate page that holds no valid row routes to
+    the garbage page *explicitly* — a suffix bucket can overhang the
+    slot's reserved pages (start + bucket > max_pages * page_size),
+    where the cold prefill's garbage-padded ``page_row`` tail no longer
+    covers it.  Returns the updated stacked array."""
     S = new.shape[0]
-    idx = jnp.arange(S)
-    pos = start + idx
+    n = pages_needed(S, page_size) + 1
+    cand = start // page_size + jnp.arange(n)   # indices into page_row
+    # row r of candidate page j holds suffix row idx[j, r]
+    idx = (cand[:, None] * page_size
+           + jnp.arange(page_size)[None, :] - start)
+    hit = (idx >= 0) & (idx < valid_len)
     page = jnp.where(
-        idx < valid_len,
-        page_row[jnp.clip(pos // page_size, 0, page_row.shape[0] - 1)],
+        hit.any(axis=1),
+        page_row[jnp.clip(cand, 0, page_row.shape[0] - 1)],
         GARBAGE_PAGE)
-    return pages.at[page, pos % page_size].set(new)
+    return _blend_pages(pages, layer, page,
+                        new[jnp.clip(idx, 0, S - 1)], hit)
 
 
-def write_decode(pages, new, page_table, lengths, page_size: int):
-    """Scatter one new token per slot into its page.
+def write_decode(pages, new, layer, page_table, lengths, page_size: int):
+    """Write one new token per slot into its page of one layer.
 
-    pages: [P, page_size, H, D]; new: [B, H, D]; page_table:
-    [B, max_pages] int32; lengths: [B] int32 — the token's absolute
-    position (inactive slots point at the garbage page)."""
-    B = new.shape[0]
+    pages: [L, P, page_size, H, D] (the whole stacked array); new:
+    [B, H, D]; layer: traced scalar; page_table: [B, max_pages] int32;
+    lengths: [B] int32 — the token's absolute position (inactive slots
+    point at the garbage page).  Each slot's tail page is rewritten
+    whole with the token laid over it (:func:`_blend_pages`).  Returns
+    the updated stacked array."""
     page = jnp.take_along_axis(page_table,
                                (lengths // page_size)[:, None], 1)[:, 0]
-    return pages.at[page, lengths % page_size].set(new)
+    hit = jnp.arange(page_size)[None, :] == (lengths % page_size)[:, None]
+    return _blend_pages(pages, layer, page, new[:, None], hit)
 
 
-def gather_pages(pages, page_table):
-    """[P, page_size, *rest] x [B, max_pages] -> [B, max_pages*page, *rest].
+def gather_pages(pages, layer, page_table):
+    """[L, P, page_size, *rest] x layer x [B, max_pages]
+    -> [B, max_pages*page, *rest].
 
-    The padded per-slot context the decode attention masks by length —
-    gather-then-attend (indexing pages *inside* the kernel is the
-    natural next step once this path has chip numbers).  Shape-generic
-    past the page dims, so K/V codes ([..., H, D]) and their scale
-    arrays ([..., H]) ride the same gather."""
+    One gather indexed by (layer, page) on the whole stacked array — no
+    slice of the layer first.  The padded per-slot context the decode
+    attention masks by length — gather-then-attend (indexing pages
+    *inside* the kernel is the natural next step, ROADMAP S2).
+    Shape-generic past the page dims, so K/V codes ([..., H, D]) and
+    their scale arrays ([..., H]) ride the same gather."""
     B, max_pages = page_table.shape
-    ps = pages.shape[1]
-    ctx = pages[page_table]             # [B, max_pages, ps, *rest]
-    return ctx.reshape((B, max_pages * ps) + pages.shape[2:])
+    ps = pages.shape[2]
+    ctx = pages[layer, page_table]      # [B, max_pages, ps, *rest]
+    return ctx.reshape((B, max_pages * ps) + pages.shape[3:])
 
 
 def pages_needed(tokens: int, page_size: int) -> int:

@@ -105,6 +105,68 @@ def test_decode_attention_compiles_for_v5e(v5e, kv_dtype):
     _compile_for_v5e(step, v5e, *shapes)
 
 
+@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached"])
+def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, kind):
+    """The TPU compiler's verdict on the engine's cache handling, at
+    GPT-2 124M's width: the stacked K and V come in, are written and
+    read, and go out in ONE layout — no ``copy`` of them, nothing that
+    produces a layer's ``[pages, page, H, D]`` pool.  (A row-granular
+    scatter compiles to a relayout of the whole cache, head_dim minor
+    and padded 2.7x, before and after the layer loop; a per-layer
+    dynamic-slice copies a layer's pool out and back in every layer.
+    Neither shows on the CPU.)"""
+    import re
+
+    from ray_tpu.inference.engine import InferenceEngine
+    from ray_tpu.models.gpt import GPTConfig, init_params
+
+    cfg = GPTConfig.gpt2(vocab_size=V, max_seq=CTX, dtype=BF16)
+    page, pages = 128, SLOTS * (CTX // 128) + 1
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    stacked = (cfg.n_layers, pages, page, H, D)
+    state = (spec(stacked, BF16),) * 2
+    # the step builders read only these: no arrays, no allocation
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.page_size, eng.decode_impl = cfg, page, "auto"
+    eng.kv_dtype, eng.lora_cfg = "model", None
+    eng.cache = types.SimpleNamespace(state=state)
+    i32, mp = jnp.int32, CTX // page
+    if kind == "decode":
+        fn, tail = eng._build_decode(), (
+            spec((SLOTS,), i32), spec((SLOTS,), i32),
+            spec((SLOTS, mp), i32))
+    elif kind == "prefill":
+        fn, tail = eng._build_prefill(), (
+            spec((1, 256), i32), spec((), i32), spec((mp,), i32))
+    else:
+        fn, tail = eng._build_prefill_cached(), (
+            spec((1, 64), i32), spec((), i32), spec((), i32),
+            spec((mp,), i32))
+    with substrate.compile_for_tpu():
+        hlo = fn.lower(params, *state, *tail).compile().as_text()
+
+    def dims(shape):
+        return "[" + ",".join(map(str, shape)) + "]"
+
+    layouts = set()
+    for ln in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16(\[[0-9,]+\])"
+                     r"(\{[^ ]*\}) ([\w\-]+)\(", ln)
+        if not m:
+            continue
+        shape, layout, op = m.groups()
+        assert shape not in (dims(stacked[1:]),
+                             dims((1,) + stacked[1:])), ln
+        if shape == dims(stacked):
+            assert not op.startswith("copy"), ln
+            layouts.add(re.sub(r"S\(\d+\)", "", layout))
+    assert len(layouts) == 1, layouts
+
+
 def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
     # the suite asks for the CPU by name (conftest): interpret mode
     assert substrate.cpu_requested()
