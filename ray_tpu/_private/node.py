@@ -57,7 +57,8 @@ def _gc_stale_sessions(keep: Optional[str] = None) -> None:
                  + glob.glob(_shm_root("session_*"))
                  # cross-host client stores: client_<session>_<clientpid>
                  + glob.glob(os.path.join(_default_tmp_root(), "client_*"))):
-        if keep and path.endswith(keep):
+        # the kept session's dirs, its added nodes' shm roots among them
+        if keep and (path.endswith(keep) or f"{keep}_node_" in path):
             continue
         m = re.search(r"_(\d+)$", path)
         if not m:
@@ -67,8 +68,8 @@ def _gc_stale_sessions(keep: Optional[str] = None) -> None:
             os.kill(pid, 0)
         except ProcessLookupError:
             shutil.rmtree(path, ignore_errors=True)
-        except PermissionError:
-            pass
+        except (PermissionError, OverflowError):
+            pass    # not ours / a node id's digits, no pid at all
 
 
 def default_resources(num_cpus: Optional[float],

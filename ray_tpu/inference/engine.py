@@ -99,6 +99,7 @@ from ray_tpu.adapters import lora as lora_mod
 from ray_tpu.inference import kv_cache as kvc
 from ray_tpu.inference.config import default_buckets, infer_config
 from ray_tpu.inference.sampling import (SamplingParams, accept_drafts,
+                                        sample_path,
                                         sample_tokens_logprobs)
 from ray_tpu.inference.scheduler import (DeadlineExceededError,
                                          Request, SlotScheduler)
@@ -1577,7 +1578,7 @@ class InferenceEngine:
             # len(generated) + i tokens, so counts advance from there
             c = len(req.generated)
             n_rows = kb + 1
-            with tracing.span("infer/sample", rows=n_rows):
+            with tracing.span("infer/sample", rows=n_rows) as ssp:
                 seeds = np.full((n_rows,), req.sampling.seed, np.int32)
                 counts = c + np.arange(n_rows, dtype=np.int32)
                 temps = np.full((n_rows,), req.sampling.temperature,
@@ -1585,9 +1586,8 @@ class InferenceEngine:
                 top_ks = np.full((n_rows,), req.sampling.top_k, np.int32)
                 top_ps = np.full((n_rows,), req.sampling.top_p,
                                  np.float32)
-                toks, logps = sample_tokens_logprobs(
-                    logits[0], seeds, counts, temps, top_ks, top_ps)
-                toks, logps = np.asarray(toks), np.asarray(logps)
+                toks, logps = self._sample_fetch(
+                    ssp, logits[0], seeds, counts, temps, top_ks, top_ps)
         with self._deliver_span(events):
             m, emitted = accept_drafts(toks[:n_drafts + 1], drafts)
             self.spec_proposed += n_drafts
@@ -1669,7 +1669,7 @@ class InferenceEngine:
         batch (None rows are inactive, result discarded) or a prefill's
         single [1, V] row.  Returns ``(tokens, model logprobs)``."""
         null = SamplingParams()
-        with tracing.span("infer/sample", rows=len(reqs)):
+        with tracing.span("infer/sample", rows=len(reqs)) as ssp:
             seeds = np.array([(r.sampling.seed if r else 0)
                               for r in reqs], np.int32)
             counts = np.array([(len(r.generated) if r else 0)
@@ -1681,10 +1681,26 @@ class InferenceEngine:
                                for r in reqs], np.int32)
             top_ps = np.array([(r.sampling.top_p if r else 1.0)
                                for r in reqs], np.float32)
-            toks, logps = sample_tokens_logprobs(logits, seeds, counts,
-                                                 temps, top_ks, top_ps)
-            # the fetch: here the host waits for the device
-            return np.asarray(toks), np.asarray(logps)
+            return self._sample_fetch(ssp, logits, seeds, counts, temps,
+                                      top_ks, top_ps)
+
+    def _sample_fetch(self, ssp, logits, seeds, counts, temps, top_ks,
+                      top_ps) -> Tuple[np.ndarray, np.ndarray]:
+        """Inside an ``infer/sample`` span: where the counter or a trace
+        will keep it, name the body this call's rows select
+        (``sampling.sample_path``, the executable's own rule on the same
+        arrays); dispatch the sampler, and fetch tokens and logprobs in
+        one transfer."""
+        counted = self.telemetry.enabled
+        if counted or ssp.recording:
+            path = sample_path(temps, top_ks, top_ps)
+            ssp.set(path=path)
+            if counted:
+                self.telemetry.record_sample(path)
+        out = sample_tokens_logprobs(logits, seeds, counts, temps,
+                                     top_ks, top_ps)
+        # the fetch: here the host waits for the device
+        return jax.device_get(out)
 
     # ---------------------------------------------------- compile cache
     def _get_compiled(self, key, build_fn, example_args, *, kind: str):

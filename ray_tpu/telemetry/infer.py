@@ -64,6 +64,10 @@ class InferTelemetry:
         self.prompt_tokens = 0
         self.prefix_hit_tokens = 0
         self.deadline_exceeded: Dict[str, int] = {}
+        # sampler calls by the body their rows selected (``plain`` /
+        # ``draw`` / ``filter``, ``inference/sampling.py``): only
+        # ``filter`` pays the full-vocabulary sorts
+        self.sample_paths: Dict[str, int] = {}
         # speculative decoding (r21): cumulative proposed/accepted
         # draft counts and verify-step count — the accept rate is the
         # one number that decides whether speculation pays
@@ -133,6 +137,11 @@ class InferTelemetry:
         self.decodes.append({"wall_s": wall_s, "active": emitted})
         del self.decodes[:-self._MAX_RECORDS]
         self._emit_verify(wall_s, proposed, accepted, emitted)
+
+    def record_sample(self, path: str) -> None:
+        """One sampler call that ran the body ``path`` (the engine has
+        checked ``enabled``)."""
+        self.sample_paths[path] = self.sample_paths.get(path, 0) + 1
 
     def record_ttft(self, ttft_s: float, *, prefix_hit: bool = False,
                     trace_id: Optional[str] = None) -> None:
@@ -297,6 +306,13 @@ class InferTelemetry:
         out["prompt_tokens"] = self.prompt_tokens
         out["prefill_tokens_skipped"] = self.prefix_hit_tokens
         out["deadline_exceeded"] = dict(self.deadline_exceeded)
+        if self.sample_paths:
+            calls = sum(self.sample_paths.values())
+            out["sample"] = {
+                "calls": calls,
+                "path_share": {path: n / calls for path, n
+                               in sorted(self.sample_paths.items())},
+            }
         if self.spec_verify_steps:
             out["spec"] = {
                 "verify_steps": self.spec_verify_steps,

@@ -157,6 +157,14 @@ class Span:
         """Monotonic stamp of the exit (after it)."""
         return self.start + self.dur
 
+    @property
+    def recording(self) -> bool:
+        """Whether a sink keeps this open span (a running profile, OTel,
+        the fallback list): an attribute that costs something to compute
+        is worth computing only then."""
+        return (self._rec is not None or self._otel_span is not None
+                or (self._ann is not None and self._ann.is_enabled()))
+
     def set(self, **attributes) -> None:
         """Attach what is known only now (a count at the span's end)."""
         self.attributes.update(attributes)

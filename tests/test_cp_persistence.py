@@ -15,6 +15,43 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.mark.parametrize("keep", [False, True])
+def test_gc_stale_sessions_spares_node_roots(tmp_path, monkeypatch, keep):
+    """An added node's shm root (``..._node_<id>``) carries no pid: the
+    sweep leaves it where the node may outlive its head, does not take
+    an id of twelve digits for one (``os.kill`` overflowed, and no
+    ``init`` on the host got past the sweep), and a restarted head keeps
+    every dir of the session it re-enters."""
+    import tempfile
+
+    from ray_tpu._private import node
+    monkeypatch.setattr(tempfile, "gettempdir", lambda: str(tmp_path))
+    monkeypatch.setattr(node, "_shm_root",
+                        lambda name: str(tmp_path / f"ray_tpu_{name}"))
+    dead = subprocess.Popen([sys.executable, "-c", "pass"])
+    dead.wait()
+    old = f"session_20260928_053528_{dead.pid}"
+    other = f"session_20260928_053527_{dead.pid}"
+    root = node._default_tmp_root()
+    head_dirs = [os.path.join(root, old), node._shm_root(old)]
+    node_roots = [node._shm_root(f"{old}_node_ab12cd34ef56"),
+                  node._shm_root(f"{old}_node_935230712118")]
+    gone = [os.path.join(root, other), node._shm_root(other),
+            os.path.join(root, f"client_{old}_{dead.pid}")]
+    kept = [os.path.join(root, f"session_20260928_053529_{os.getpid()}"),
+            os.path.join(root, f"client_{old}_{os.getpid()}")]
+    for path in head_dirs + node_roots + gone + kept:
+        os.makedirs(path)
+    node._gc_stale_sessions(keep=old if keep else None)
+    if keep:
+        kept += head_dirs + node_roots
+    else:
+        gone += head_dirs
+        kept += node_roots
+    assert [p for p in gone if os.path.exists(p)] == []
+    assert [p for p in kept if not os.path.exists(p)] == []
+
+
 def test_journal_roundtrip(tmp_path):
     from ray_tpu._private.control_plane import ControlPlane
     from ray_tpu._private.persistence import Journal, restore_control_plane

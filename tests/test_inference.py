@@ -729,20 +729,184 @@ def test_sampling_modes():
     assert (tp == greedy).all()
 
 
-def test_sampled_sequence_independent_of_cobatch(tiny_f32):
-    """Per-sequence PRNG: a temperature-sampled request produces the
-    same tokens whether it runs alone or co-batched."""
+def _reference_sample_one(logits, seed, count, temp, top_k, top_p):
+    """The sampler as it stood before it chose its work by what the
+    call's rows ask for: one body for every row, both sorts always
+    (``top_k == 0`` and ``top_p >= 1`` read as "off").  Kept here as the
+    plain reference the three bodies are held to."""
+    import jax
+    import jax.numpy as jnp
+    V = logits.shape[-1]
+    l = logits.astype(jnp.float32)
+    greedy = jnp.argmax(l, -1).astype(jnp.int32)
+    model_logp = jax.nn.log_softmax(l)
+    z = l / jnp.maximum(temp, 1e-6)
+    kth = jnp.sort(z)[::-1][jnp.clip(top_k - 1, 0, V - 1)]
+    z = jnp.where((top_k > 0) & (z < kth), -jnp.inf, z)
+    probs = jax.nn.softmax(z)
+    sp = jnp.sort(probs)[::-1]
+    cum = jnp.cumsum(sp)
+    keep = (cum - sp) < top_p
+    thresh = jnp.min(jnp.where(keep, sp, jnp.inf))
+    z = jnp.where((top_p >= 1.0) | (probs >= thresh), z, -jnp.inf)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), count)
+    g = -jnp.log(-jnp.log(
+        jax.random.uniform(key, (V,), minval=1e-20, maxval=1.0)))
+    sampled = jnp.argmax(z + g, -1).astype(jnp.int32)
+    tok = jnp.where(temp <= 0.0, greedy, sampled)
+    return tok, model_logp[tok]
+
+
+# per-row (temperature, top_k, top_p) of each case, and the body the
+# call has to select; the mixed batch ends in an inactive row, which
+# carries the null (greedy) parameters as the engine gives them
+_SAMPLER_CASES = {
+    "all_greedy": ([(0.0, 0, 1.0)] * 5, "plain"),
+    "all_temperature_only": ([(0.7, 0, 1.0), (1.0, 0, 1.0),
+                              (1.3, 0, 1.0), (0.2, 0, 1.0),
+                              (2.0, 0, 1.0)], "draw"),
+    "all_top_k": ([(0.8, 1, 1.0), (1.0, 5, 1.0), (1.0, 20, 1.0),
+                   (1.5, 63, 1.0), (0.5, 500, 1.0)], "filter"),
+    "all_top_p": ([(0.8, 0, 0.9), (1.0, 0, 0.5), (1.0, 0, 1e-6),
+                   (1.5, 0, 0.99), (0.5, 0, 0.3)], "filter"),
+    "mixed": ([(0.0, 0, 1.0), (0.9, 0, 1.0), (1.0, 8, 1.0),
+               (1.1, 0, 0.8), (0.0, 0, 1.0)], "filter"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SAMPLER_CASES))
+def test_sampler_bodies_match_the_one_body_reference(case):
+    """Whichever body a call selects, every row gets the token and the
+    logprob the old one-body sampler gives that row."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.inference.sampling import (sample_path, sample_tokens,
+                                            sample_tokens_logprobs)
+    rows, path = _SAMPLER_CASES[case]
+    temps = jnp.array([r[0] for r in rows], jnp.float32)
+    top_ks = jnp.array([r[1] for r in rows], jnp.int32)
+    top_ps = jnp.array([r[2] for r in rows], jnp.float32)
+    assert sample_path(temps, top_ks, top_ps) == path
+    reference = jax.jit(jax.vmap(_reference_sample_one))
+    n = len(rows)
+    for trial, count0 in enumerate((0, 1, 17, 400)):
+        logits = 3.0 * jnp.array(
+            np.random.RandomState(trial).randn(n, 64), jnp.float32)
+        seeds = jnp.arange(n, dtype=jnp.int32) * 7919 + trial
+        counts = count0 + jnp.arange(n, dtype=jnp.int32)
+        args = (logits, seeds, counts, temps, top_ks, top_ps)
+        want_tok, want_lp = reference(*args)
+        tok, lp = sample_tokens_logprobs(*args)
+        np.testing.assert_array_equal(np.asarray(tok),
+                                      np.asarray(want_tok))
+        np.testing.assert_allclose(np.asarray(lp), np.asarray(want_lp),
+                                   rtol=0, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(sample_tokens(*args)),
+                                      np.asarray(want_tok))
+
+
+def test_sample_path_counter(tiny_f32, monkeypatch):
+    """``infer/sample`` names the body its call selected and
+    ``InferTelemetry`` counts calls by it: all ``plain`` for default
+    ``SamplingParams()``, ``draw`` / ``filter`` for the other two."""
+    from ray_tpu.inference import SamplingParams
+    from ray_tpu.inference import engine as engine_mod
+    from ray_tpu.util import tracing
+    cfg, params = tiny_f32
+    prompt = _prompt(7, cfg.vocab_size, seed=2)
+    for sp, path in ((SamplingParams(), "plain"),
+                     (SamplingParams(temperature=1.0, seed=3), "draw"),
+                     (SamplingParams(temperature=1.0, top_p=0.9, seed=3),
+                      "filter")):
+        engine = _make_engine(cfg, params, telemetry=True)
+        tracing.clear_recorded()
+        tracing.enable_tracing()
+        try:
+            engine.generate([prompt], max_new_tokens=3, sampling=sp)
+        finally:
+            tracing.disable_tracing()
+        spans = [r for r in tracing.recorded_spans()
+                 if r["name"] == "infer/sample"]
+        assert len(spans) == 3
+        assert {r["attributes"]["path"] for r in spans} == {path}
+        assert engine.telemetry.summary()["sample"] == {
+            "calls": 3, "path_share": {path: 1.0}}
+    # a greedy and a top-p request in one decode batch: the prefills
+    # (one row each) take their own row's path, the shared ticks filter
+    engine = _make_engine(cfg, params, telemetry=True)
+    engine.submit(prompt, 3, SamplingParams())
+    engine.submit(prompt, 3, SamplingParams(temperature=1.0, top_p=0.9))
+    while engine.has_work():
+        engine.step()
+    assert engine.telemetry.sample_paths == {"plain": 1, "filter": 3}
+    # nobody to keep it, nothing computed: no counter, no trace
+    engine = _make_engine(cfg, params, telemetry=False)
+    seen = []
+    monkeypatch.setattr(engine_mod, "sample_path",
+                        lambda *a: seen.append(a) or "plain")
+    engine.generate([prompt], max_new_tokens=2)
+    assert seen == [] and engine.telemetry.sample_paths == {}
+    tracing.enable_tracing()
+    try:
+        engine.generate([prompt], max_new_tokens=2)
+    finally:
+        tracing.disable_tracing()
+    assert len(seen) == 2 and engine.telemetry.sample_paths == {}
+
+
+_COBATCH_KINDS = {
+    "greedy": dict(),
+    "temperature_only": dict(temperature=0.8, seed=123),
+    "top_k": dict(temperature=0.8, top_k=20, seed=123),
+    "top_p": dict(temperature=0.8, top_p=0.7, seed=123),
+}
+
+
+@pytest.fixture(scope="module")
+def cobatched(tiny_f32):
+    """One engine run with a request of every kind in its decode batch
+    (so every tick runs the ``filter`` body): kind -> (tokens,
+    logprobs)."""
     from ray_tpu.inference import SamplingParams
     cfg, params = tiny_f32
-    p1 = _prompt(8, cfg.vocab_size, seed=4)
+    engine = _make_engine(cfg, params, slots=4)
+    rids = {engine.submit(_prompt(8 + i, cfg.vocab_size, seed=4 + i), 6,
+                          SamplingParams(**kw)): kind
+            for i, (kind, kw) in enumerate(_COBATCH_KINDS.items())}
+    out = {kind: ([], []) for kind in _COBATCH_KINDS}
+    while engine.has_work():
+        for ev in engine.step():
+            rid, tok, _done = ev
+            out[rids[rid]][0].append(tok)
+            out[rids[rid]][1].append(ev.logprob)
+    return out
+
+
+@pytest.mark.parametrize("kind", list(_COBATCH_KINDS))
+def test_sampled_sequence_independent_of_cobatch(tiny_f32, cobatched,
+                                                 kind):
+    """Per-sequence PRNG, and one function of a row's own parameters in
+    all three sampler bodies: a greedy, a temperature-only, a top-k and
+    a top-p request each produce alone (the ``plain``, ``draw``,
+    ``filter``, ``filter`` body) the tokens they produce co-batched
+    with the others (the ``filter`` body for all)."""
+    from ray_tpu.inference import SamplingParams
+    cfg, params = tiny_f32
+    i = list(_COBATCH_KINDS).index(kind)
+    sp = SamplingParams(**_COBATCH_KINDS[kind])
+    p1 = _prompt(8 + i, cfg.vocab_size, seed=4 + i)
+    solo, solo_lp = _make_engine(cfg, params).generate(
+        [p1], max_new_tokens=6, sampling=sp, return_logprobs=True)
+    assert cobatched[kind][0] == solo[0]
+    np.testing.assert_allclose(cobatched[kind][1], solo_lp[0],
+                               rtol=1e-5, atol=1e-6)
+    # and beside a stranger under the same parameters, as before
     p2 = _prompt(15, cfg.vocab_size, seed=5)
-    sp = SamplingParams(temperature=0.8, top_k=20, seed=123)
-    solo = _make_engine(cfg, params).generate([p1], max_new_tokens=6,
-                                              sampling=sp)[0]
     both = _make_engine(cfg, params).generate([p1, p2],
                                               max_new_tokens=6,
                                               sampling=sp)
-    assert both[0] == solo
+    assert both[0] == solo[0]
 
 
 # ------------------------------------------------------- config / telemetry

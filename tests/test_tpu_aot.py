@@ -167,6 +167,64 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, kind):
     assert len(layouts) == 1, layouts
 
 
+def _sampler_specs(rows, vocab, sharding=None):
+    return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in (
+        ((rows, vocab), jnp.float32), ((rows,), jnp.int32),
+        ((rows,), jnp.int32), ((rows,), jnp.float32),
+        ((rows,), jnp.int32), ((rows,), jnp.float32))]
+
+
+def _sorts(jaxpr, under_cond=False):
+    """``under_cond`` of every ``sort`` in a jaxpr, sub-jaxprs included:
+    whether it sits inside a branch of a ``cond``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            found.append(under_cond)
+        inside = under_cond or eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _sorts(sub, inside)
+    return found
+
+
+def test_sampler_sorts_only_under_its_cond():
+    """The sampler's full-vocabulary sorts sit inside a branch of its
+    one batch-level ``cond`` (a ``cond`` under ``vmap`` would be a
+    ``select`` that runs both sides) and nowhere a greedy call runs;
+    two of the three branches hold none."""
+    from ray_tpu.inference.sampling import sample_tokens_logprobs
+    jaxpr = jax.make_jaxpr(sample_tokens_logprobs)(
+        *_sampler_specs(4, 64)).jaxpr
+    sorts = _sorts(jaxpr)
+    assert sorts and all(sorts), sorts
+    assert _sorts(jax.make_jaxpr(
+        jax.vmap(lambda x, p: jax.lax.cond(p, jnp.sort, jnp.negative, x)))(
+        jnp.ones((4, 64)), jnp.ones((4,), bool)).jaxpr) == [False]
+
+    def conds(jaxpr):
+        return [e for eqn in jaxpr.eqns for e in (
+            [eqn] if eqn.primitive.name == "cond" else
+            [c for sub in jax.core.jaxprs_in_params(eqn.params)
+             for c in conds(sub)])]
+    (cond,) = conds(jaxpr)
+    assert [bool(_sorts(br.jaxpr)) for br in cond.params["branches"]] \
+        == [False, False, True]
+
+
+def test_sampler_keeps_its_sorts_in_the_branch_on_v5e(v5e):
+    """What the jaxpr cannot show: compiled for the chip at the batch
+    cell's ``[128, 50304]``, the sorts stay in the conditional's branch
+    (not hoisted into what every call runs) and the branches take the
+    logits as given, with no copy."""
+    from ray_tpu.inference.sampling import sample_tokens_logprobs
+    text = sample_tokens_logprobs.lower(
+        *_sampler_specs(128, V, v5e)).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert " sort(" in text and " sort(" not in entry
+    assert entry.count(" conditional(") == 1
+    assert " copy(" not in entry
+
+
 def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
     # the suite asks for the CPU by name (conftest): interpret mode
     assert substrate.cpu_requested()
