@@ -371,7 +371,7 @@ def kernel_parity(config: dict) -> dict:
                 {"r": _rel_err(r, r_ref), "y": _rel_err(y, y_ref)},
                 {"r": TOL_OUT, "y": TOL_OUT})
     ctx = -(-cfg.max_seq // icfg.page_size) * icfg.page_size
-    if A.decode_uses_pallas(ctx, D, impl=icfg.decode_impl):
+    if A.decode_uses_pallas(ctx, D):
         slots = icfg.slots
         q = rand((slots, H, D))
         k, v = rand((slots, ctx, H, D)), rand((slots, ctx, H, D))

@@ -173,8 +173,8 @@ def bank_clear(bank: Dict[str, Any], slot: int) -> Dict[str, Any]:
 
 
 def adapter_nbytes(adapter: Dict[str, Any]) -> int:
-    """Publish payload size — the rank·(in+out)·L·itemsize sum that
-    docs/PERF.md's r25 math quotes against full-params publishes."""
+    """Publish payload size — the rank·(in+out)·L·itemsize sum, against
+    a full-params publish."""
     total = 0
     for leaf in jax.tree.leaves(adapter):
         arr = np.asarray(jax.device_get(leaf)) if hasattr(leaf, "dtype") \

@@ -38,8 +38,7 @@ class FleetConfig:
       ``model_id`` is already resident in a replica's LoRA bank scores
       toward that replica (skipping the store fetch + bank install a
       cold replica would pay), composing with the prefix-affinity
-      score above; ``0`` is the residency-blind A/B arm
-      (``bench.py --infer --lora`` measures the delta).
+      score above; ``0`` is the residency-blind A/B arm.
     - ``RAY_TPU_FLEET_UP_DEPTH`` (default ``4``): mean waiting-queue
       depth per running replica that, sustained for the dwell, scales
       the fleet up.
@@ -75,8 +74,8 @@ class FleetConfig:
       floor in seconds (and the whole deadline until enough TTFT
       samples exist) — a cold fleet must not hedge every request.
     - ``RAY_TPU_FLEET_DISAGG`` (default ``0``): serve in disaggregated
-      prefill/decode mode — ``bench.py --infer`` (and drivers reading
-      this config) split the fleet into a prefill pool and a decode
+      prefill/decode mode — drivers reading this config split the
+      fleet into a prefill pool and a decode
       pool behind the :class:`~ray_tpu.fleet.disagg.DisaggRouter`
       instead of N co-located replicas.
     - ``RAY_TPU_FLEET_PREFILL_REPLICAS`` (default ``1``): how many of

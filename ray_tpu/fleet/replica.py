@@ -52,7 +52,7 @@ LATENCY_EWMA_ALPHA = 0.25
 # recycled — the severity of the score decides blip vs restart.  The
 # half-life must stay of the dwell's order: a fast decay flaps
 # demote/re-promote inside one routing episode (measured: it doubles
-# demotions and wastes hedges in the `bench.py --gray` scenario).
+# demotions and wastes hedges under a sustained-slow replica).
 LATENCY_IDLE_HALFLIFE_S = 5.0
 
 

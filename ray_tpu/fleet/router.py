@@ -208,8 +208,7 @@ class FleetRouter:
     modeled sequentially — a straggling replica's tick would stall
     the whole drive loop, taxing every replica equally, when the
     point of gray-failure mitigation is that it must not
-    (``bench.py --gray`` and the r19 latency A/Bs run this mode;
-    event interleaving is timing-dependent there, so its tests assert
+    (the r19 latency A/Bs run this mode; event interleaving is timing-dependent there, so its tests assert
     order-independent invariants).
     """
 

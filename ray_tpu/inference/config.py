@@ -34,10 +34,6 @@ class InferConfig:
       buckets.  Prompts are padded up to the smallest bucket that fits,
       so arbitrary request lengths hit at most ``len(buckets)`` prefill
       compiles and the decode step exactly one.
-    - ``RAY_TPU_INFER_DECODE`` (default ``auto``): decode-attention
-      implementation — ``pallas`` (strip-mined online-softmax kernel,
-      ``ops/attention.py:decode_attention``), ``xla`` (masked einsum),
-      or ``auto`` (pallas on a TPU backend when the context tiles).
     - ``RAY_TPU_KV_DTYPE`` (default ``model``): KV-cache storage dtype
       — ``model`` (the model's ``cfg.dtype``) or ``int8``
       (block-scaled int8, one f32 scale per (position, head) lane
@@ -46,8 +42,7 @@ class InferConfig:
       context strips).  ``int8`` roughly halves ``KVCache.bytes`` per
       page — i.e. ~2x the decode slots per HBM byte — at a bounded
       logits error (parity-tested against the ``model``-dtype cache).
-      Default stays ``model`` until the on-chip A/B
-      (``scratch/r11_quant.py``).
+      Default stays ``model`` until an on-chip A/B.
     - ``RAY_TPU_INFER_PREFIX`` (default ``1``): content-addressed
       prefix caching — full prompt pages register in a host-side
       chained-hash index and later requests sharing the prefix install
@@ -127,7 +122,6 @@ class InferConfig:
     page_size: int = 128
     pages: int = 0
     buckets: Tuple[int, ...] = ()
-    decode_impl: str = "auto"
     kv_dtype: str = "model"
     prefix: bool = True
     max_queue: int = 0
@@ -151,11 +145,6 @@ def infer_config(refresh: bool = False) -> InferConfig:
     global _CONFIG
     if _CONFIG is None or refresh:
         env = os.environ.get
-        impl = env("RAY_TPU_INFER_DECODE", "auto")
-        if impl not in ("auto", "pallas", "xla"):
-            print(f"RAY_TPU_INFER_DECODE={impl!r} unknown; using 'auto'",
-                  file=sys.stderr)
-            impl = "auto"
         raw_buckets = env("RAY_TPU_INFER_BUCKETS", "")
         buckets = tuple(sorted(int(b) for b in raw_buckets.split(",")
                                if b.strip())) if raw_buckets else ()
@@ -211,7 +200,6 @@ def infer_config(refresh: bool = False) -> InferConfig:
             page_size=int(env("RAY_TPU_INFER_PAGE_SIZE", "128")),
             pages=int(env("RAY_TPU_INFER_PAGES", "0")),
             buckets=buckets,
-            decode_impl=impl,
             kv_dtype=kv_dtype,
             prefix=env("RAY_TPU_INFER_PREFIX", "1") != "0",
             max_queue=max_queue,

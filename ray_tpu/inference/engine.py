@@ -82,7 +82,6 @@ ROADMAP item.
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 import time
 from typing import Any, Dict, List, Optional, Set, Tuple, Union
@@ -204,7 +203,6 @@ class InferenceEngine:
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  buckets: Optional[Tuple[int, ...]] = None,
-                 decode_impl: Optional[str] = None,
                  kv_dtype: Optional[str] = None,
                  prefix: Optional[bool] = None,
                  max_queue: Optional[int] = None,
@@ -230,7 +228,6 @@ class InferenceEngine:
         self.slots = slots if slots is not None else icfg.slots
         self.page_size = (page_size if page_size is not None
                           else icfg.page_size)
-        self.decode_impl = decode_impl or icfg.decode_impl
         self.kv_dtype = kv_dtype or icfg.kv_dtype
         self.prefix = icfg.prefix if prefix is None else bool(prefix)
         self.max_queue = (icfg.max_queue if max_queue is None
@@ -362,8 +359,7 @@ class InferenceEngine:
         self._compiled: Dict[Any, Any] = (
             executable_cache if executable_cache is not None else {})
         self._exec_key = (cfg, self.slots, self.page_size, num_pages,
-                          max_pages_per_slot, self.decode_impl,
-                          self.kv_dtype, lora_key)
+                          max_pages_per_slot, self.kv_dtype, lora_key)
         self.compile_counts: Dict[str, int] = {
             "prefill": 0, "prefill_cached": 0, "decode": 0,
             "verify": 0}
@@ -711,7 +707,7 @@ class InferenceEngine:
         arrays = kvc.export_pages(self.cache, req.pages[:n_pages])
         handoff = kvc.KVHandoff(
             context=context, page_size=self.page_size,
-            kv_dtype=self.kv_dtype, dtype=str(self.cache.k.dtype),
+            kv_dtype=self.kv_dtype, dtype=str(self.cache.dtype),
             chain_hashes=kvc.PrefixIndex.chain_hashes(
                 context, self.page_size, salt=req.hash_salt),
             next_token=int(req.generated[-1]),
@@ -762,11 +758,11 @@ class InferenceEngine:
                 f"page_size {self.page_size} — one fleet geometry")
         if handoff.kv_dtype != self.kv_dtype \
                 or (handoff.k is not None
-                    and str(handoff.k.dtype) != str(self.cache.k.dtype)):
+                    and str(handoff.k.dtype) != str(self.cache.dtype)):
             raise ValueError(
                 f"handoff kv_dtype {handoff.kv_dtype!r} "
                 f"(storage {handoff.dtype}) != engine "
-                f"{self.kv_dtype!r} ({self.cache.k.dtype}) — the "
+                f"{self.kv_dtype!r} ({self.cache.dtype}) — the "
                 "contents would be reinterpreted, not converted")
         if max_new_tokens < 1:
             raise ValueError("a handoff needs >= 1 token left to "
@@ -976,7 +972,7 @@ class InferenceEngine:
             # what decode attention dispatches to at this geometry
             "decode_impl": "pallas" if decode_uses_pallas(
                 self.max_pages_per_slot * self.page_size,
-                self.cfg.head_dim, impl=self.decode_impl) else "xla",
+                self.cfg.head_dim) else "xla",
             "kv_bytes_per_slot": self.cache.bytes_per_slot(
                 self.max_pages_per_slot),
             "max_queue": self.max_queue,
@@ -1155,11 +1151,11 @@ class InferenceEngine:
         # compute for the shared prefix)
         if cached:
             fill = req.prompt[cached:]
-            kind, build = "prefill_cached", self._build_prefill_cached
+            kind = "prefill_cached"
             scalars = (np.int32(cached), np.int32(len(fill)))
         else:
             fill = req.prompt
-            kind, build = "prefill", self._build_prefill
+            kind = "prefill"
             scalars = (np.int32(plen),)
         bucket = self._bucket_for(len(fill))
         tokens = np.zeros((1, bucket), np.int32)
@@ -1169,17 +1165,8 @@ class InferenceEngine:
         ids = {"trace_id": tr.trace_id} if tr is not None else {}
         with tracing.span(f"infer/{kind}", rid=req.rid, bucket=bucket,
                           cached=cached, **ids) as sp:
-            if self.lora_cfg is not None:
-                aid = np.array([max(req.adapter_slot, 0)], np.int32)
-                args = (self.params, self.lora_bank, *self.cache.state,
-                        tokens, *scalars, sched.page_table[slot], aid)
-            else:
-                args = (self.params, *self.cache.state, tokens,
-                        *scalars, sched.page_table[slot])
-            fn = self._get_compiled((kind, bucket), build, args,
-                                    kind=kind)
-            logits, *state = fn(*args)
-            self.cache.state = tuple(state)
+            logits = self._run_step((kind, bucket), [req], tokens,
+                                    *scalars, sched.page_table[slot])
             toks, logps = self._sample_slots(logits, [req])
             tok, logp = toks[0], logps[0]
         with self._deliver_span(events):
@@ -1445,23 +1432,8 @@ class InferenceEngine:
             page_table = page_table.copy()
             page_table[list(skip), :] = kvc.GARBAGE_PAGE
         with tracing.span("infer/decode", active=len(active)) as sp:
-            if self.lora_cfg is not None:
-                # per-slot adapter ids: co-batched tenants share this
-                # one tick (the bank gather routes each row through its
-                # own A/B factors; dead/base rows ride slot 0 identity)
-                aids = np.zeros((self.slots,), np.int32)
-                for slot, req in sched.active.items():
-                    if slot not in skip and req.adapter_slot > 0:
-                        aids[slot] = req.adapter_slot
-                args = (self.params, self.lora_bank, *self.cache.state,
-                        tokens, sched.lengths, page_table, aids)
-            else:
-                args = (self.params, *self.cache.state, tokens,
-                        sched.lengths, page_table)
-            fn = self._get_compiled(("decode",), self._build_decode,
-                                    args, kind="decode")
-            logits, *state = fn(*args)
-            self.cache.state = tuple(state)
+            logits = self._run_step(("decode",), reqs, tokens,
+                                    sched.lengths, page_table)
             sampled, logps = self._sample_slots(logits, reqs)
         with self._deliver_span(events):
             traced = [r.trace.trace_id for r in active
@@ -1557,22 +1529,9 @@ class InferenceEngine:
         tokens[0, 0] = req.generated[-1]
         tokens[0, 1:1 + n_drafts] = drafts
         with tracing.span("infer/verify", rid=req.rid, k=n_drafts) as sp:
-            if self.lora_cfg is not None:
-                aid = np.array([max(req.adapter_slot, 0)], np.int32)
-                args = (self.params, self.lora_bank, *self.cache.state,
-                        tokens, np.int32(L), np.int32(n_drafts + 1),
-                        sched.page_table[slot], aid)
-            else:
-                args = (self.params, *self.cache.state, tokens,
-                        np.int32(L), np.int32(n_drafts + 1),
-                        sched.page_table[slot])
-            fn = self._get_compiled(
-                ("verify", kb),
-                functools.partial(self._build_prefill_cached,
-                                  all_rows=True),
-                args, kind="verify")
-            logits, *state = fn(*args)
-            self.cache.state = tuple(state)
+            logits = self._run_step(
+                ("verify", kb), [req], tokens, np.int32(L),
+                np.int32(n_drafts + 1), sched.page_table[slot])
             # every row samples under the key plain decode would use
             # at that position: row i's token lands when generated has
             # len(generated) + i tokens, so counts advance from there
@@ -1703,7 +1662,27 @@ class InferenceEngine:
         return jax.device_get(out)
 
     # ---------------------------------------------------- compile cache
-    def _get_compiled(self, key, build_fn, example_args, *, kind: str):
+    def _run_step(self, key, reqs, *step_args):
+        """Run the serve executable ``key`` = ``(kind[, bucket])`` over
+        the cache -> its logits.  The one place an executable's
+        arguments are assembled: ``(params, [lora_bank,] *cache.state,
+        *step_args[, adapter ids])``, the ids one per row of ``reqs``
+        (co-batched tenants share a tick: the bank gather routes each
+        row through its own A/B factors; a dead (``None``) or base row
+        rides slot 0, the identity).  The donated state that comes back
+        is the cache's from here on."""
+        bank = aids = ()
+        if self.lora_cfg is not None:
+            bank = (self.lora_bank,)
+            aids = (np.array([0 if r is None else max(r.adapter_slot, 0)
+                              for r in reqs], np.int32),)
+        args = (self.params, *bank, *self.cache.state, *step_args, *aids)
+        logits, *state = self._get_compiled(key, args)(*args)
+        self.cache.state = tuple(state)
+        return logits
+
+    def _get_compiled(self, key, example_args):
+        kind = key[0]
         fn = self._compiled.get(self._exec_key + key)
         if fn is not None:
             self.hit_counts[kind] += 1
@@ -1713,8 +1692,7 @@ class InferenceEngine:
         # persistent cache), and for how long
         with tracing.span("infer/compile", kind=kind,
                           bucket=key[1] if len(key) > 1 else 0):
-            jitted = build_fn()
-            fn = jitted.lower(*example_args).compile()
+            fn = self._build_step(kind).lower(*example_args).compile()
         self._compiled[self._exec_key + key] = fn
         return fn
 
@@ -1737,14 +1715,15 @@ class InferenceEngine:
         the scan carry -> (final normed hidden, caches).
 
         ``caches`` is the cache's state tuple of stacked ``[L, ...]``
-        arrays — ``(k, v)`` or, quantized, ``(k, v, k_scale,
-        v_scale)``.  No layer's pool is ever sliced out or put back:
+        arrays, whose format only ``kv_cache.py`` knows.  No layer's
+        pool is ever sliced out or put back:
         each layer hands ``layer_apply`` the opaque ``cache = (layer
         index, caches)``, which round-trips to ``attn_hook``; the hook
         writes the new tokens into their pages at ``(layer, page)``
-        (:meth:`_write_cache`), reads through the ``(layer, page)``
-        gather, and returns the updated stacked arrays for the carry —
-        so only the touched and the gathered pages cross HBM.
+        (``kv_cache.append``), reads through the ``(layer, page)``
+        gather (``kv_cache.context``), and returns the updated stacked
+        arrays for the carry — so only the touched and the gathered
+        pages cross HBM.
 
         ``lora_bank``/``lora_ids`` (r25 multi-tenant): bank factors are
         stacked ``[N, L, ...]`` — layer axis 1 — sliced per scan step;
@@ -1778,71 +1757,6 @@ class InferenceEngine:
                           eps=gpt_mod.norm_eps(cfg))
         return x, caches
 
-    def _quantize_kv(self, kv):
-        """[..., H, D] post-RoPE K or V -> (int8 codes, [..., H] f32
-        scales): one scale per head_dim lane vector (deterministic
-        rounding — cache entries are weights-like, read many times)."""
-        from ray_tpu.quant import quantize_block
-        q, s = quantize_block(kv, block=self.cfg.head_dim, axis=-1)
-        return q, s[..., 0]
-
-    def _write_cache(self, write, cache, k, v, *where):
-        """Write post-RoPE K and V rows ``[..., H, D]`` into one layer
-        of every cache array — codes and scales when quantized — with
-        the ``kv_cache`` writer ``write`` at ``where`` (the writer's
-        page and position arguments).  ``cache`` is ``_layer_scan``'s
-        ``(layer, stacked arrays)`` -> ``(layer, updated arrays)``."""
-        layer, arrays = cache
-        rows = (k, v)
-        if self.kv_dtype == "int8":
-            (kq, ks), (vq, vs) = self._quantize_kv(k), self._quantize_kv(v)
-            rows = (kq, vq, ks, vs)
-        return layer, tuple(
-            write(a, r, layer, *where, self.page_size)
-            for a, r in zip(arrays, rows))
-
-    def _build_prefill(self):
-        cfg = self.cfg
-        lora_on = self.lora_cfg is not None
-
-        def prefill(params, *args):
-            """(params, [lora_bank,] *cache_state, tokens [1,
-            S_bucket], length scalar (valid prefix), page_row
-            [max_pages][, adapter_ids [1]]) -> (last-token logits
-            [1, V] f32, *cache_state)."""
-            bank = aids = None
-            if lora_on:
-                bank, *args = args
-                *args, aids = args
-            *cache_state, tokens, length, page_row = args
-            S = tokens.shape[1]
-            positions = jnp.arange(S)
-
-            def attn_hook(q, k, v, cache):
-                _, arrays = self._write_cache(kvc.write_prefill, cache,
-                                              k[0], v[0], page_row)
-                # attention reads the full-precision prompt K/V (the
-                # prompt IS the whole context); quantization only
-                # affects what later decode steps read back
-                return self._prefill_attention(q, k, v), arrays
-
-            x = self._embed(params, tokens, positions)
-            x, cache_state = self._layer_scan(params, x,
-                                              tuple(cache_state),
-                                              positions, attn_hook,
-                                              lora_bank=bank,
-                                              lora_ids=aids)
-            h = jnp.take(x[0], length - 1, axis=0)[None, None]  # [1,1,d]
-            logits = jnp.einsum("bsd,dv->bsv", h,
-                                gpt_mod.lm_head(params, cfg))
-            return (logits[:, 0].astype(jnp.float32),) + cache_state
-
-        n_state = len(self.cache.state)
-        first = 2 if lora_on else 1      # cache state shifts past bank
-        return jax.jit(prefill,
-                       donate_argnums=tuple(range(first,
-                                                  first + n_state)))
-
     def _prefill_attention(self, q, k, v):
         """Causal self-attention over the bucket (no cache read — the
         prompt is the whole context).  Flash kernel on a TPU; einsum
@@ -1856,132 +1770,101 @@ class InferenceEngine:
         from ray_tpu.ops.attention import flash_attention
         return flash_attention(q, k, v, causal=True)
 
-    def _build_prefill_cached(self, all_rows: bool = False):
-        """Suffix-only prefill over a prefix-cached context.
+    def _build_step(self, kind: str):
+        """The jitted serve step of ``kind``: ``(params, [lora_bank,]
+        *cache_state, <kind's arguments>[, adapter_ids]) -> (logits
+        f32, *cache_state)``, the cache state donated.  Every kind
+        embeds, runs the layer stack with its own attention hook
+        (append the new rows to the cache, read the context back,
+        attend) and applies the head to the rows it answers for:
 
-        The prompt's first ``cached_len`` tokens are already in the
-        slot's pages (prefix-index hits: written by an earlier request
-        with an identical prefix — byte-identical content, and for
-        int8 caches bit-identical codes because cache writes round
-        deterministically).  Only the suffix runs through the model:
-        its queries attend over the gathered cached pages (length-
-        masked) *plus* causally over the suffix itself, merged in one
-        softmax — the masked-einsum XLA formulation (a Pallas variant
-        is an on-chip follow-up; see docs/PERF.md r12).
+        - ``"prefill"`` (tokens [1, S_bucket], length, page_row
+          [max_pages]): a cold prompt.  Attention is causal over the
+          bucket itself at full precision (the prompt IS the whole
+          context; quantization only affects what later steps read
+          back).  Logits [1, V] of the last valid row.
+        - ``"prefill_cached"`` (tokens [1, S_bucket] (suffix, padded),
+          cached_len, suffix_len, page_row): the prompt's first
+          ``cached_len`` tokens are already in the slot's pages (prefix
+          hits — byte-identical content, bit-identical codes for int8
+          because cache writes round deterministically).  The suffix's
+          queries attend over the gathered cached pages (length-masked)
+          *plus* causally over the suffix itself at full precision,
+          merged in one softmax.  ``cached_len`` / ``suffix_len`` are
+          traced scalars, so one executable per *suffix bucket* serves
+          every cached length.  Logits [1, V] of the last valid row.
+        - ``"verify"`` (r21): the same step one slot at a time over
+          ``[last_token, d1..dk]`` with logits at EVERY suffix row
+          [1, S_bucket, V] (row i scores the token after draft i).  It
+          is jitted under the name ``prefill_cached``.
+        - ``"decode"`` (tokens [slots] (each slot's next input token),
+          lengths [slots] (tokens already cached = the new token's
+          absolute position), page_table [slots, max_pages]): one row
+          per slot.  Logits [slots, V].
 
-        ``cached_len``/``suffix_len`` are traced scalars, so one
-        executable per *suffix bucket* serves every cached length —
-        the zero-steady-state-recompile counters still hold.
-
-        ``all_rows=True`` is the speculative **verify** flavor (r21):
-        the suffix is ``[last_token, d1..dk]`` and the caller needs
-        the logits at EVERY suffix position (row i scores the token
-        after draft i), so the head runs over the whole suffix and
-        the executable returns ``[1, S_bucket, V]`` instead of the
-        last valid row.  Same attention, same cache writes — the
-        verify step is literally the cached-context prefill run one
-        slot at a time.
-        """
+        The benchmark finds these executables and their operations by
+        name (``jit_prefill*``, ``jit_decode``, ``gpt/attn/gather``,
+        ``gpt/attn/reshape``, ``attn/decode_pallas``): the traced
+        function carries the kind's name and no scope is added here."""
         cfg = self.cfg
         lora_on = self.lora_cfg is not None
+        n_state = len(self.cache.state)
 
-        def prefill_cached(params, *args):
-            """(params, [lora_bank,] *cache_state, tokens [1, S_bucket]
-            (suffix, padded), cached_len scalar (prefix tokens already
-            in cache), suffix_len scalar (valid suffix), page_row
-            [max_pages][, adapter_ids [1]]) -> (last-suffix-token
-            logits [1, V] f32, *cache_state)."""
+        def step(params, *args):
             bank = aids = None
             if lora_on:
                 bank, *args = args
                 *args, aids = args
-            *cache_state, tokens, cached_len, suffix_len, page_row = args
-            S = tokens.shape[1]
-            positions = cached_len + jnp.arange(S)   # absolute
+            cache_state, args = tuple(args[:n_state]), args[n_state:]
+            if kind == "decode":
+                tokens, lengths, page_table = args
+                positions = lengths[:, None]                   # [B, 1]
+                tokens = tokens[:, None]
 
-            def attn_hook(q, k, v, cache):
-                layer, arrays = self._write_cache(
-                    kvc.write_prefill_at, cache, k[0], v[0], page_row,
-                    cached_len, suffix_len)
-                row = page_row[None]                 # [1, max_pages]
-                kctx, vctx, *scales = (
-                    kvc.gather_pages(a, layer, row) for a in arrays)
-                if scales:
-                    kctx = (kctx.astype(jnp.float32)
-                            * scales[0][..., None]).astype(q.dtype)
-                    vctx = (vctx.astype(jnp.float32)
-                            * scales[1][..., None]).astype(q.dtype)
-                # suffix self-attention reads the full-precision k/v
-                # (like the cold prefill); only the cached prefix is
-                # read back through the (possibly quantized) cache
-                o = _cached_context_attention(q, kctx, vctx, k, v,
-                                              cached_len)
-                return o, arrays
+                def attn_hook(q, k, v, cache):
+                    from ray_tpu.ops.attention import decode_attention
+                    cache = kvc.append(kvc.write_decode, cache, k[:, 0],
+                                       v[:, 0], page_table, lengths)
+                    kctx, vctx, scales = kvc.context(cache, page_table)
+                    o = decode_attention(q[:, 0], kctx, vctx,
+                                         lengths + 1, **scales)
+                    return o[:, None], cache[1]
+            elif kind == "prefill":
+                tokens, last, page_row = args
+                positions = jnp.arange(tokens.shape[1])
+
+                def attn_hook(q, k, v, cache):
+                    cache = kvc.append(kvc.write_prefill, cache, k[0],
+                                       v[0], page_row)
+                    return self._prefill_attention(q, k, v), cache[1]
+            else:
+                tokens, cached_len, last, page_row = args
+                positions = cached_len + jnp.arange(tokens.shape[1])
+
+                def attn_hook(q, k, v, cache):
+                    cache = kvc.append(kvc.write_prefill_at, cache, k[0],
+                                       v[0], page_row, cached_len, last)
+                    kctx, vctx = kvc.context_dense(cache, page_row[None],
+                                                   q.dtype)
+                    o = _cached_context_attention(q, kctx, vctx, k, v,
+                                                  cached_len)
+                    return o, cache[1]
 
             x = self._embed(params, tokens, positions)
-            x, cache_state = self._layer_scan(params, x,
-                                              tuple(cache_state),
+            x, cache_state = self._layer_scan(params, x, cache_state,
                                               positions, attn_hook,
                                               lora_bank=bank,
                                               lora_ids=aids)
-            if all_rows:
-                logits = jnp.einsum("bsd,dv->bsv", x,
-                                    gpt_mod.lm_head(params, cfg))
-                return (logits.astype(jnp.float32),) + cache_state
-            h = jnp.take(x[0], suffix_len - 1, axis=0)[None, None]
-            logits = jnp.einsum("bsd,dv->bsv", h,
-                                gpt_mod.lm_head(params, cfg))
-            return (logits[:, 0].astype(jnp.float32),) + cache_state
-
-        n_state = len(self.cache.state)
-        first = 2 if lora_on else 1
-        return jax.jit(prefill_cached,
-                       donate_argnums=tuple(range(first,
-                                                  first + n_state)))
-
-    def _build_decode(self):
-        cfg = self.cfg
-        impl = self.decode_impl
-        lora_on = self.lora_cfg is not None
-
-        def decode(params, *args):
-            """(params, [lora_bank,] *cache_state, tokens [slots] (each
-            slot's next input token), lengths [slots] (tokens already
-            cached = the new token's absolute position), page_table
-            [slots, max_pages][, adapter_ids [slots]]) -> (logits
-            [slots, V] f32, *cache_state)."""
-            bank = aids = None
-            if lora_on:
-                bank, *args = args
-                *args, aids = args
-            *cache_state, tokens, lengths, page_table = args
-            positions = lengths[:, None]                   # [B, 1]
-
-            def attn_hook(q, k, v, cache):
-                from ray_tpu.ops.attention import decode_attention
-                layer, arrays = self._write_cache(
-                    kvc.write_decode, cache, k[:, 0], v[:, 0],
-                    page_table, lengths)
-                kctx, vctx, *scales = (
-                    kvc.gather_pages(a, layer, page_table)
-                    for a in arrays)
-                o = decode_attention(
-                    q[:, 0], kctx, vctx, lengths + 1, impl=impl,
-                    **dict(zip(("k_scale", "v_scale"), scales)))
-                return o[:, None], arrays
-
-            x = self._embed(params, tokens[:, None], positions)
-            x, cache_state = self._layer_scan(params, x,
-                                              tuple(cache_state),
-                                              positions, attn_hook,
-                                              lora_bank=bank,
-                                              lora_ids=aids)
+            if kind in ("prefill", "prefill_cached"):
+                x = jnp.take(x[0], last - 1, axis=0)[None, None]  # [1,1,d]
             logits = jnp.einsum("bsd,dv->bsv", x,
                                 gpt_mod.lm_head(params, cfg))
-            return (logits[:, 0].astype(jnp.float32),) + cache_state
+            if kind != "verify":
+                logits = logits[:, 0]
+            return (logits.astype(jnp.float32),) + cache_state
 
-        n_state = len(self.cache.state)
-        first = 2 if lora_on else 1
-        return jax.jit(decode,
+        step.__name__ = "prefill_cached" if kind == "verify" else kind
+        first = 2 if lora_on else 1      # cache state shifts past bank
+        return jax.jit(step,
                        donate_argnums=tuple(range(first,
                                                   first + n_state)))

@@ -50,9 +50,10 @@ as prefix hits and the handoff moves no contents at all.
 head) lane vector riding in per-page scale arrays
 ``[n_layers, pages, page_size, kv_heads]``.  The write/gather helpers
 are shape-generic (they address ``[L, P, page_size, ...]`` storage by
-(layer, page)), so the same scatter/gather moves codes and scales; the
-engine quantizes post-RoPE on write and ``decode_attention`` dequantizes
-inside its context strips.  At head_dim 64 that is 68 bytes per cached
+(layer, page)), so the same scatter/gather moves codes and scales;
+:func:`append` quantizes post-RoPE on write and ``decode_attention``
+dequantizes inside its context strips (:func:`context` hands it the
+scales).  At head_dim 64 that is 68 bytes per cached
 vector (64 codes + one f32 scale) vs 128 in bf16 — :meth:`KVCache.bytes`
 counts both arrays, so the ~2x capacity-per-HBM-byte claim is
 asserted, not assumed.
@@ -214,8 +215,8 @@ def handoff_page_bytes(*, n_layers: int, page_size: int, n_heads: int,
                        quantized: bool) -> int:
     """Analytic content bytes one handoff page carries — K and V across
     all layers (+ their f32 scale lanes when quantized).  The figure
-    ``bench.py --infer --disagg`` checks the measured
-    ``serve_handoff_bytes_total`` against: int8 caches move
+    the measured ``serve_handoff_bytes_total`` is checked against
+    (``tests/test_disagg.py``): int8 caches move
     ``head_dim + 4`` bytes per cached vector vs ``head_dim * itemsize``
     for the model dtype — ~half of bf16, the disagg wire saving."""
     per_vector = head_dim * itemsize + (4 if quantized else 0)
@@ -861,6 +862,11 @@ class KVCache:
             self.v_scale = jnp.zeros(shape[:-1], jnp.float32)
 
     @property
+    def dtype(self):
+        """Storage dtype of K and V: int8 codes when quantized."""
+        return self.k.dtype
+
+    @property
     def state(self) -> Tuple:
         """The donated device arrays, in step-argument order."""
         if self.quantized:
@@ -887,7 +893,7 @@ class KVCache:
     def bytes_per_slot(self, pages_per_slot: int) -> int:
         """HBM bytes one fully-reserved decode slot pins (codes +
         scales across all layers) — the capacity-planning figure the
-        telemetry summary and ``bench.py --infer`` report."""
+        telemetry summary reports."""
         per_page = (2 * self.k.shape[0] * self.page_size
                     * self.k.shape[3] * self.k.shape[4]
                     * self.k.dtype.itemsize)
@@ -921,7 +927,7 @@ def _blend_pages(pages, layer, page, rows, hit):
         jnp.where(hit, rows.astype(pages.dtype), old))
 
 
-def write_prefill(pages, new, layer, page_row, page_size: int):
+def write_prefill(pages, new, layer, page_row):
     """Write a prompt's K (or V) into one slot's pages of one layer —
     the cold (start-0, whole-bucket) case of :func:`write_prefill_at`.
 
@@ -930,12 +936,10 @@ def write_prefill(pages, new, layer, page_row, page_size: int):
     land in whatever ``page_row`` maps them to, the garbage page for
     unreserved tail entries); layer: traced scalar; page_row:
     [max_pages] int32.  Returns the updated stacked array."""
-    return write_prefill_at(pages, new, layer, page_row, 0, new.shape[0],
-                            page_size)
+    return write_prefill_at(pages, new, layer, page_row, 0, new.shape[0])
 
 
-def write_prefill_at(pages, new, layer, page_row, start, valid_len,
-                     page_size: int):
+def write_prefill_at(pages, new, layer, page_row, start, valid_len):
     """Write a *suffix*'s K (or V) at absolute positions
     ``start .. start+valid_len`` of one slot's pages of one layer (the
     cached-context prefill: positions below ``start`` are prefix-cache
@@ -954,7 +958,7 @@ def write_prefill_at(pages, new, layer, page_row, start, valid_len,
     slot's reserved pages (start + bucket > max_pages * page_size),
     where the cold prefill's garbage-padded ``page_row`` tail no longer
     covers it.  Returns the updated stacked array."""
-    S = new.shape[0]
+    S, page_size = new.shape[0], pages.shape[2]
     n = pages_needed(S, page_size) + 1
     cand = start // page_size + jnp.arange(n)   # indices into page_row
     # row r of candidate page j holds suffix row idx[j, r]
@@ -969,7 +973,7 @@ def write_prefill_at(pages, new, layer, page_row, start, valid_len,
                         new[jnp.clip(idx, 0, S - 1)], hit)
 
 
-def write_decode(pages, new, layer, page_table, lengths, page_size: int):
+def write_decode(pages, new, layer, page_table, lengths):
     """Write one new token per slot into its page of one layer.
 
     pages: [L, P, page_size, H, D] (the whole stacked array); new:
@@ -978,6 +982,7 @@ def write_decode(pages, new, layer, page_table, lengths, page_size: int):
     point at the garbage page).  Each slot's tail page is rewritten
     whole with the token laid over it (:func:`_blend_pages`).  Returns
     the updated stacked array."""
+    page_size = pages.shape[2]
     page = jnp.take_along_axis(page_table,
                                (lengths // page_size)[:, None], 1)[:, 0]
     hit = jnp.arange(page_size)[None, :] == (lengths % page_size)[:, None]
@@ -998,6 +1003,61 @@ def gather_pages(pages, layer, page_table):
     ps = pages.shape[2]
     ctx = pages[layer, page_table]      # [B, max_pages, ps, *rest]
     return ctx.reshape((B, max_pages * ps) + pages.shape[3:])
+
+
+# ------------------------------------------------- what a step needs --
+# A compiled step sees the cache as ``cache = (layer, arrays)``: the
+# scan's layer index and :attr:`KVCache.state`.  What a row is — K and V
+# ``[H, D]``, plus an f32 scale per head when the arrays are int8 codes
+# — is decided here and nowhere else.
+
+def _quantize_rows(kv):
+    """[..., H, D] post-RoPE K or V -> (int8 codes, [..., H] f32
+    scales): one scale per head_dim lane vector (deterministic
+    rounding — cache entries are weights-like, read many times)."""
+    from ray_tpu.quant import quantize_block
+    q, s = quantize_block(kv, block=kv.shape[-1], axis=-1)
+    return q, s[..., 0]
+
+
+def append(write, cache, k, v, *where):
+    """Write the new tokens' post-RoPE K and V rows ``[..., H, D]``
+    into one layer of every cache array — codes and scales when the
+    cache is int8 — with the writer ``write`` at ``where``:
+    :func:`write_prefill` at ``page_row`` (a whole prompt),
+    :func:`write_prefill_at` at ``page_row, start, valid_len`` (a
+    suffix), :func:`write_decode` at ``page_table, lengths`` (one row
+    per slot).  -> ``(layer, updated arrays)``."""
+    layer, arrays = cache
+    rows = (k, v)
+    if len(arrays) == 4:
+        (kq, ks), (vq, vs) = _quantize_rows(k), _quantize_rows(v)
+        rows = (kq, vq, ks, vs)
+    return layer, tuple(write(a, r, layer, *where)
+                        for a, r in zip(arrays, rows))
+
+
+def context(cache, page_table):
+    """Gather one layer's pages for ``page_table`` [B, max_pages] ->
+    ``(K, V, scales)``: K and V ``[B, max_pages * page, H, D]`` as
+    stored, and ``scales`` the keyword arguments ``decode_attention``
+    dequantises an int8 context with (empty for a model-dtype
+    cache)."""
+    layer, arrays = cache
+    k, v, *scales = (gather_pages(a, layer, page_table) for a in arrays)
+    return k, v, dict(zip(("k_scale", "v_scale"), scales))
+
+
+def context_dense(cache, page_table, dtype):
+    """:func:`context` with an int8 cache's K and V dequantised to
+    ``dtype`` -> ``(K, V)`` (a model-dtype cache's come as stored)."""
+    k, v, scales = context(cache, page_table)
+    if scales:
+        k = (k.astype(jnp.float32)
+             * scales["k_scale"][..., None]).astype(dtype)
+        v = (v.astype(jnp.float32)
+             * scales["v_scale"][..., None]).astype(dtype)
+    return k, v
 
 
 def pages_needed(tokens: int, page_size: int) -> int:

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -196,16 +195,8 @@ def param_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
     return axes
 
 
-# env-gated alternate norm path for per-shape A/B (step-neutral at the
-# v5e GPT-2 bench shape — XLA's scheduler already overlaps the traffic
-# it removes — but it cuts streamed bytes, which matters in
-# memory-bound regimes):
-#   PALLAS_NORM — fused rmsnorm fwd/bwd kernel (ops/rmsnorm.py)
 # The CE path knobs live in ray_tpu.ops.flash_ce.ce_config() (env
-# RAY_TPU_CE; the r05 RAY_TPU_CE_BF16_RESID astype round-trip was
-# measured dead (+2.5 ms) and removed, RAY_TPU_FUSED_CE folded in as
-# RAY_TPU_CE=fused — same consolidation as r06's attention_config).
-_PALLAS_NORM = os.environ.get("RAY_TPU_PALLAS_NORM", "0") == "1"
+# RAY_TPU_CE).
 
 
 def norm_eps(cfg: "GPTConfig") -> float:
@@ -214,9 +205,6 @@ def norm_eps(cfg: "GPTConfig") -> float:
 
 
 def _norm(x, scale, kind: str, bias=None, eps: float = 1e-6):
-    if kind == "rmsnorm" and bias is None and _PALLAS_NORM:
-        from ray_tpu.ops.rmsnorm import rmsnorm
-        return rmsnorm(x, scale, eps)
     x32 = x.astype(jnp.float32)
     if kind == "rmsnorm":
         x32 = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
@@ -651,9 +639,7 @@ def _chunked_ce(x, head, targets, *, chunk: int = _CE_CHUNK, mesh=None,
       for supported shapes on single-device meshes regardless of
       ``chunk`` (it strictly dominates both XLA formulations on
       memory).
-    - ``fused``: bf16-resident-logit custom vjp (``ops/fused_ce.py``),
-      no-remat (``chunk < 0``) only.
-    - ``xla`` (or any decline above): the ``chunk``-driven XLA paths —
+    - ``xla`` (or a decline above): the ``chunk``-driven XLA paths —
       ``chunk < 0`` no-remat (backward reuses saved f32 logits),
       ``chunk > 0`` row-chunked remat.  Chunks are a *python* loop
       (static N): a lax.scan here stashes its residuals with
@@ -669,11 +655,6 @@ def _chunked_ce(x, head, targets, *, chunk: int = _CE_CHUNK, mesh=None,
             and flash_ce.supports(N, d, head.shape[1])):
         return flash_ce.flash_ce_sum(x, head.astype(x.dtype), targets)
     remat = chunk >= 0
-    # fused is plain XLA (no pallas_call), so unlike flash it needs no
-    # single-device gate — it shards like the formulations below
-    if not remat and mode == "fused":
-        from ray_tpu.ops.fused_ce import ce_sum_bf16
-        return ce_sum_bf16(x, head.astype(x.dtype), targets)
     if chunk <= 0:
         chunk = N
 

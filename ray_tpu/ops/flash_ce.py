@@ -36,10 +36,11 @@ write-once/read-once transient at ~1/13th the traffic of the logits
 residual it replaces (and it vanishes from the *resident* footprint,
 which is what re-opens the batch-32 probe the r05 recipe was capped
 by).  Total matmul work is 4 vocab-matmul-equivalents (fwd, bwd
-recompute, dx, dhead) vs the no-remat path's 3 — the bet recorded in
-``docs/PERF.md`` is that one extra matmul at MXU rate beats 17 ms of
-serialized HBM-rate reduces, *iff* the Pallas matmul is competitive
-with XLA's 150+ TFLOPs at ``[24576, 768] x [768, 50304]``.
+recompute, dx, dhead) vs the no-remat path's 3 — the bet is that one
+extra matmul at MXU rate beats the serialized HBM-rate reduces, *iff*
+the Pallas matmul is competitive with XLA's at ``[24576, 768] x [768,
+50304]`` (``ce_ms_per_step`` in ``benchmark/run.py``'s train cells;
+the A/B against ``xla`` is ROADMAP S3).
 
 Handles: masked ``-1`` targets (excluded from both loss and grads),
 vocab sizes that are not a multiple of the block (lane-aligned padding
@@ -48,9 +49,7 @@ columns contribute exp(-inf)=0), and row counts that are not a multiple
 of ``block_n`` (zero-padded rows with ``-1`` targets).
 
 Dispatch is owned by :func:`ce_config` — the single home for CE env
-knobs (the round-5 ``RAY_TPU_CE_BF16_RESID`` astype round-trip was
-measured dead (+2.5 ms: XLA materializes the f32 tensor anyway) and is
-removed; ``RAY_TPU_FUSED_CE`` folded in as ``RAY_TPU_CE=fused``).
+knobs.
 Unsupported shapes fall back to the dense XLA formulation, decided
 from shapes by :func:`supports`; a Mosaic compile failure is a failure
 (``tests/test_tpu_aot.py`` compiles the kernels for a v5e ahead of any
@@ -90,14 +89,13 @@ class CEConfig:
     """Loss-head schedule knobs, resolved once from the environment.
 
     The single home for CE env flags (consolidation precedent: r06's
-    ``attention_config``; the dead ``RAY_TPU_CE_BF16_RESID`` knob was
-    removed and ``RAY_TPU_FUSED_CE`` folded into ``mode``):
+    ``attention_config``):
 
     - ``RAY_TPU_CE`` (default ``flash``): which CE custom path the
       model's loss head dispatches to for supported shapes —
-      ``flash`` (this kernel), ``fused`` (bf16-resident logits,
-      ``ops/fused_ce.py``), or ``xla`` (no custom path: the
-      ``ce_chunk``-driven no-remat / chunked XLA formulations).
+      ``flash`` (this kernel) or ``xla`` (no custom path: the
+      ``ce_chunk``-driven no-remat / chunked XLA formulations; any
+      other value runs as ``xla``).
     - ``RAY_TPU_CE_BN`` / ``RAY_TPU_CE_BV`` (default 1024/1024):
       forward row/vocab blocking.
     - ``RAY_TPU_CE_BWD_BN`` / ``RAY_TPU_CE_BWD_BV`` (default
@@ -146,8 +144,8 @@ def uses_flash_ce(N: int, d: int, V: int, *,
                   n_devices: int = 1) -> bool:
     """Whether the model loss head takes the flash-CE path for this
     shape under the current :func:`ce_config` (``mode`` overrides the
-    config, for A/B drivers) — the reporting mirror ``bench.py`` uses
-    so the JSON line can't claim a schedule the dispatch declined.
+    config, for A/B drivers) — the reporting mirror, so a summary
+    can't claim a schedule the dispatch declined.
     ``n_devices`` is the mesh size the loss head will run under: the
     dispatch declines sharded meshes (a ``pallas_call`` has no SPMD
     rule), so pass it for anything but a single-chip run."""
@@ -525,8 +523,8 @@ def uses_flash_ce_norm(N: int, d: int, V: int, *,
     """Dispatch gate (with reason) for the final-norm-fused CE path.
 
     The single source of the decision ``models.gpt.loss_fn`` makes
-    before skipping the XLA final norm — also the ``bench.py``
-    reporting mirror.  Requires the flash-CE path itself
+    before skipping the XLA final norm — also the reporting mirror.
+    Requires the flash-CE path itself
     (:func:`uses_flash_ce`'s conditions) plus the fused-norm knob and
     a norm the prologue can fuse."""
     from ray_tpu.ops.fused_norm import fuse_config

@@ -117,8 +117,8 @@ def out_proj_norm_plan(N: int, K: int, d: int, *, norm: str = "rmsnorm",
     """The full out-proj epilogue dispatch gate, with reasons.
 
     The single source of the fused-vs-XLA decision — shared by
-    ``models.gpt.layer_apply`` and the ``bench.py`` reporting mirror so
-    the JSON line can't claim a fusion the dispatch declined.
+    ``models.gpt.layer_apply`` and whatever reports the schedule, so a
+    summary can't claim a fusion the dispatch declined.
     ``enabled`` pins the knob for A/B drivers (default:
     :func:`fuse_config`)."""
     if enabled is None:

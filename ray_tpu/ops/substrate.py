@@ -5,7 +5,7 @@ decode), two-head lane packing (pack2), flash-CE, and the fused norm
 epilogues — and by round 12 each carried its own copy of the same
 infrastructure: an interpret-mode policy, lane-padded row-stats
 conventions, block/grid validation and env-knob config plumbing.
-Copies drift; ``rmsnorm.py``'s private ``_use_interpret`` was the proof.
+Copies drift.
 
 This module is the single home for all of it.  A new kernel (quantized
 KV strips, ragged prefill, the next norm fusion) should be a page of

@@ -40,9 +40,8 @@ class MeshAxisError(ValueError):
     """A mesh-axis string was malformed; ``axis`` names the offender.
 
     Raised by :func:`parse_mesh_axes` (and ``MeshSpec.create``) with the
-    offending axis attached so CLI surfaces (``bench.py --mesh``,
-    scratch drivers) can point at the exact token instead of the whole
-    argument."""
+    offending axis attached so a CLI surface can point at the exact
+    token instead of the whole argument."""
 
     def __init__(self, msg: str, *, axis: Optional[str] = None):
         super().__init__(msg)
@@ -146,8 +145,8 @@ def mesh_tiers(mesh) -> Dict[str, Tuple[str, ...]]:
 
 
 def parse_mesh_axes(arg: str) -> Dict[str, int]:
-    """``"dcn=2,fsdp=4"`` -> ``{"dcn": 2, "fsdp": 4}`` (CLI mesh syntax
-    shared by ``bench.py --mesh`` and the scratch drivers).
+    """``"dcn=2,fsdp=4"`` -> ``{"dcn": 2, "fsdp": 4}`` (CLI mesh
+    syntax).
 
     Rejections all raise :class:`MeshAxisError` naming the offending
     axis: unknown names, duplicates, non-positive sizes (``-1`` is the

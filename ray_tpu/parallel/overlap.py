@@ -45,8 +45,8 @@ the GSPMD one (env ``RAY_TPU_COMM=overlap``, see :func:`comm_config`):
 
 Correctness story in this CPU-only environment: the path is proved by
 loss + per-parameter grad parity against the GSPMD path on an
-8-host-device mesh (``tests/test_parallel.py``); the on-chip A/B rides
-``scratch/r8_overlap.py``.  Dispatch consolidates into
+8-host-device mesh (``tests/test_parallel.py``); the on-chip A/B is
+ROADMAP S7.  Dispatch consolidates into
 :func:`comm_config` (default ``gspmd`` until that A/B lands), matching
 the ``attention_config()`` / ``ce_config()`` precedent in ``ops/``.
 
@@ -86,9 +86,8 @@ class CommConfig:
       ``overlap`` (this module's explicit shard_map schedule:
       prefetched per-block FSDP gathers, as-you-go grad
       reduce-scatters, ring all-gather-matmul TP).  The default stays
-      ``gspmd`` until the on-chip A/B (``scratch/r8_overlap.py``)
-      lands, per the r05 rule: flip it here once the measured delta
-      has a sign.
+      ``gspmd`` until the on-chip A/B (ROADMAP S7) lands, per the
+      r05 rule: flip it here once the measured delta has a sign.
     - ``RAY_TPU_COMM_QUANT`` (default ``none``): wire dtype of the
       overlap schedule's collectives, **per fabric tier** —
 
@@ -112,7 +111,7 @@ class CommConfig:
       grad error (``tests/test_parallel.py`` budgets).  Only the
       overlap schedule can honor it — GSPMD owns its own collectives —
       so ``build_gpt_train`` drops it loudly under ``gspmd``.  Default
-      stays ``none`` until the on-chip A/B (``scratch/r11_quant.py``).
+      stays ``none`` until an on-chip A/B.
     """
     mode: str = "gspmd"
     quant: str = "none"
@@ -869,8 +868,7 @@ def collective_bytes_per_step(cfg, mesh, *, batch: int, seq: int,
     fsdp ring spanning ``dcn*fsdp`` devices, would push across the
     same pod-boundary link: full weight gathers + grad reduce-scatters
     instead of one shard-sized all-reduce) and ``reduction_vs_flat``
-    (their ratio, ~pod size — asserted in tests, emitted by
-    ``bench.py --mesh``).  Each tier carries an analytic ``seconds``
+    (their ratio, ~pod size — asserted in tests).  Each tier carries an analytic ``seconds``
     at the :data:`TIER_BANDWIDTH_GBPS` price; the top-level ``total``
     stays a plain int (Prometheus emission reads it).
 

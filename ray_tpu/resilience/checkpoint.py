@@ -348,7 +348,7 @@ def run_train_ckpt_loop(cfg, mesh=None, *, steps: int,
                         on_step: Optional[Callable[[int], None]] = None
                         ) -> Dict[str, Any]:
     """A checkpointed synthetic-LM training loop — the resume-proof
-    driver for tests, ``scratch/r15_ft.py`` and preempted-run recovery.
+    driver for tests and preempted-run recovery.
 
     Every batch is a pure function of ``(seed, cursor)`` —
     ``synthetic_lm_batch(fold_in(data_key, cursor))`` — so the data

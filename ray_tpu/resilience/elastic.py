@@ -191,8 +191,8 @@ def run_elastic_train_loop(cfg, *, steps: int,
                            topologies: Optional[Dict[int, Dict[str, Any]]]
                            = None) -> Dict[str, Any]:
     """A synthetic-LM training loop that survives mesh shrink/expand —
-    the elastic acceptance driver for tests, ``scratch/r18_elastic.py``
-    and degraded-restore recovery.
+    the elastic acceptance driver for tests and degraded-restore
+    recovery.
 
     Topology events come from the deterministic chaos sites (armed via
     ``RAY_TPU_FAULTS`` or :func:`~ray_tpu.util.chaos.install_faults`;

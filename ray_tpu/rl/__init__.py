@@ -15,7 +15,7 @@ arXiv:2104.06272, with arXiv:2011.03641's concurrency-limits argument
 applied to staleness: separate replica pools, hard version-lag bound.
 
 Config via ``RAY_TPU_RL_*`` (:func:`rl_config`); ``run_rl_loop`` is
-the driver (``bench.py --rl`` / ``scratch/r14_rl.py`` entry); the
+the driver; the
 RLlib :class:`~ray_tpu.rllib.core.learner_group.LearnerGroup` hosts
 multi-learner DDP via ``learner_cls="ray_tpu.rl.learner.
 GPTPolicyLearner"``.

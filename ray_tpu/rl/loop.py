@@ -52,7 +52,7 @@ def run_rl_loop(cfg, *, steps: int,
     """Run ``steps`` learner updates of the actor/learner loop.
 
     ``num_learners=0`` runs the learner in-process (host-sim parity
-    tests, ``bench.py --rl``); ``>= 1`` hosts it on the RLlib
+    tests); ``>= 1`` hosts it on the RLlib
     LearnerGroup (requires an initialized ray_tpu session) with the
     group's object-store snapshot as the publication path.  Engines
     across actor replicas share one executable cache.

@@ -1,9 +1,11 @@
 """CLI (parity: ``python/ray/scripts/scripts.py``): status, list, summary,
-timeline, memory, microbenchmark, dashboard against a live session.
+timeline, memory, dashboard against a live session.
 
 Usage: ``python -m ray_tpu.scripts <command> [...]`` (also installed as
-the ``ray-tpu`` entrypoint).  Commands attach to the newest live session's
-control-plane socket, so they work from any terminal on the node.
+the ``ray-tpu`` entrypoint).  Commands attach to the control plane that
+``RAY_TPU_CP_SOCK`` / ``RAY_TPU_ADDRESS`` names (what the runtime exports
+to its own workers and job entrypoints), else to the newest live
+session's socket, so they work from any terminal on the node.
 """
 
 from __future__ import annotations
@@ -19,6 +21,10 @@ from typing import Optional
 
 
 def _find_session_cp_sock() -> Optional[str]:
+    given = (os.environ.get("RAY_TPU_CP_SOCK")
+             or os.environ.get("RAY_TPU_ADDRESS"))
+    if given:
+        return given
     import getpass
     root = os.path.join(tempfile.gettempdir(),
                         f"ray_tpu_{getpass.getuser()}")
@@ -245,16 +251,6 @@ def cmd_stack(args):
     print(f"\n{len(total)} workers signalled, {shown} stack dumps shown")
 
 
-def cmd_microbenchmark(args):
-    import ray_tpu
-    from ray_tpu._private import ray_perf
-    ray_tpu.init()
-    try:
-        ray_perf.main(duration=args.duration)
-    finally:
-        ray_tpu.shutdown()
-
-
 def cmd_dashboard(args):
     import ray_tpu
     ray_tpu.init(ignore_reinit_error=True)
@@ -475,8 +471,6 @@ def main(argv=None):
     p_tl.add_argument("--output", "-o", default=None)
     sub.add_parser("memory")
     sub.add_parser("stack")
-    p_mb = sub.add_parser("microbenchmark")
-    p_mb.add_argument("--duration", type=float, default=2.0)
     p_db = sub.add_parser("dashboard")
     p_db.add_argument("--port", type=int, default=8265)
     p_start = sub.add_parser("start")
@@ -504,7 +498,6 @@ def main(argv=None):
     {"status": cmd_status, "list": cmd_list, "summary": cmd_summary,
      "timeline": cmd_timeline, "memory": cmd_memory,
      "stack": cmd_stack, "logs": cmd_logs,
-     "microbenchmark": cmd_microbenchmark,
      "dashboard": cmd_dashboard, "jobs": cmd_jobs,
      "start": cmd_start, "stop": cmd_stop}[args.command](args)
 

@@ -1,9 +1,7 @@
 """Step-level training telemetry.
 
-The training path used to fly blind: ``bench.py`` hand-rolled
-``perf_counter`` around whole steps and the tracing/metrics/dashboard
-plumbing only ever saw Ray-parity tasks.  This package instruments the
-train step itself:
+The tracing/metrics/dashboard plumbing only ever saw Ray-parity
+tasks.  This package instruments the train step itself:
 
 - :class:`StepTelemetry` / :func:`instrument` wrap a jitted step and
   emit per-step records (wall/dispatch/sync with a blocking sync,
@@ -14,8 +12,8 @@ train step itself:
 - per-step Prometheus series (``train_step_seconds``, ``train_mfu``,
   ``train_collective_bytes``) flow through the control-plane metrics
   to ``/metrics``,
-- ``bench.py`` / ``ray_perf.py`` attach :meth:`StepTelemetry.summary`
-  as the ``telemetry`` block of their JSON artifacts.
+- :meth:`StepTelemetry.summary` is the block a driver reports
+  (``benchmark/run.py`` reads ``train_step_ms`` from the records).
 
 ``RAY_TPU_TELEMETRY=0`` disables everything (identity wrapper);
 ``RAY_TPU_PROFILE=<dir>`` adds an xplane capture of the first steady

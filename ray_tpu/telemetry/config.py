@@ -28,9 +28,8 @@ class TelemetryConfig:
       time, enforced by ``tests/test_telemetry.py``.
     - ``RAY_TPU_PROFILE`` (default unset): a directory; when set, the
       step recorder captures a ``jax.profiler`` xplane trace of steps
-      1..3 (the steady window right after compile) into it — the
-      on-chip A/B drivers (``scratch/r9_telemetry.py``) use this to get
-      a device timeline without editing the loop under test.
+      1..3 (the steady window right after compile) into it — a
+      device timeline without editing the loop under test.
     """
     enabled: bool = True
     profile_dir: Optional[str] = None

@@ -17,7 +17,7 @@ Prometheus through the control plane when a session is up
 ``serve_ttft_seconds`` histograms, ``serve_replica_queue_depth`` /
 ``serve_replica_latency_score`` / ``serve_pool_queue_depth`` /
 ``serve_fleet_affinity_hit_rate`` gauges), and :meth:`summary` as the
-``fleet`` block of ``bench.py --infer --replicas N`` JSON.
+``fleet`` block a driver reports.
 
 ``RAY_TPU_TELEMETRY=0`` disables recording entirely.
 """

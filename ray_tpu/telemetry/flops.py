@@ -5,8 +5,7 @@ the telemetry layer wants the *analytic* count from ``GPTConfig`` —
 per-matmul, attention included, remat recompute charged — so the MFU
 in a step record means "fraction of the MXU the schedule actually
 earned" rather than "fraction of a rule of thumb".  The chip peak
-table lives here too (it used to be private to ``bench.py``); both
-consumers import it from this single home.
+table lives here too.
 """
 
 from __future__ import annotations

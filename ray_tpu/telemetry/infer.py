@@ -14,8 +14,7 @@ Sinks mirror r09:
   / ``infer_queue_wait_seconds`` histograms,
   ``infer_decode_tokens_per_sec`` / ``infer_queue_depth`` gauges),
   throttled and dead-on-first-failure exactly like the train recorder;
-- :meth:`summary` is the ``telemetry`` block of ``bench.py --infer``
-  and ``ray_perf`` JSON.
+- :meth:`summary` is the block a driver reports.
 
 ``RAY_TPU_TELEMETRY=0`` disables recording entirely (the engine checks
 ``enabled`` before touching the recorder).
@@ -282,8 +281,7 @@ class InferTelemetry:
                           kv_bytes_per_slot: int) -> None:
         """Static KV-cache geometry the engine reports once at
         construction: the storage dtype and the *true* per-slot
-        footprint (codes + scale arrays for int8 caches) — the figures
-        the ``bench.py --infer`` headline carries."""
+        footprint (codes + scale arrays for int8 caches)."""
         if self.enabled:
             self.cache_info = {"kv_dtype": kv_dtype,
                                "kv_cache_bytes": int(cache_bytes),
@@ -292,7 +290,7 @@ class InferTelemetry:
 
     # ---------------------------------------------------------- summary
     def summary(self) -> Dict[str, Any]:
-        """The ``telemetry`` block for ``bench.py --infer`` JSON."""
+        """The block a driver reports."""
         if not self.enabled:
             return {"enabled": False}
         out: Dict[str, Any] = {

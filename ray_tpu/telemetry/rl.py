@@ -10,8 +10,7 @@ an operator can see actor/learner skew without reading logs.  Sinks
 mirror r09: Prometheus through the control plane when a session is up
 (``rl_rollout_tokens_per_sec`` / ``rl_learner_steps_per_sec`` /
 ``rl_param_version_lag`` gauges, ``rl_weight_publish_seconds``
-histogram), and :meth:`summary` as the ``telemetry`` block of
-``bench.py --rl`` JSON.
+histogram), and :meth:`summary` as the block a driver reports.
 
 ``RAY_TPU_TELEMETRY=0`` disables recording entirely.
 """
@@ -119,7 +118,7 @@ class RLTelemetry:
 
     # ---------------------------------------------------------- summary
     def summary(self) -> Dict[str, Any]:
-        """The ``telemetry`` block for ``bench.py --rl`` JSON."""
+        """The block a driver reports."""
         if not self.enabled:
             return {"enabled": False}
         out: Dict[str, Any] = {

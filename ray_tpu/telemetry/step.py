@@ -17,8 +17,9 @@ Records flow to three sinks: the Chrome-trace exporter
 (:mod:`ray_tpu.telemetry.chrome_trace`, merged into the dashboard
 ``/api/timeline``), Prometheus gauges/histograms through the
 control-plane metrics (``train_step_seconds`` / ``train_mfu`` /
-``train_collective_bytes`` on ``/metrics``), and the ``telemetry``
-block in ``bench.py`` / ``ray_perf.py`` JSON.  ``RAY_TPU_TELEMETRY=0``
+``train_collective_bytes`` on ``/metrics``), and
+:meth:`StepTelemetry.summary` (``benchmark/run.py`` reads
+``train_step_ms`` from its records).  ``RAY_TPU_TELEMETRY=0``
 turns the whole wrapper into identity; ``RAY_TPU_PROFILE=<dir>``
 additionally captures a ``jax.profiler`` xplane trace of the first
 steady steps (see :mod:`ray_tpu.telemetry.config`).
@@ -287,7 +288,7 @@ class StepTelemetry:
         chunk_remat = getattr(self.cfg, "ce_chunk", 0) >= 0
         if self.ce_mode == "flash":
             return True
-        if self.ce_mode in ("xla", "fused"):
+        if self.ce_mode == "xla":
             return chunk_remat
         if chunk_remat or self._seq is None or self._batch is None:
             return chunk_remat

@@ -32,8 +32,8 @@ def test_channel_capacity_enforced(tmp_path):
 @pytest.mark.slow
 def test_compiled_dag_pipeline(ray_start_regular):
     """3-stage pipeline over channels: correct, pipelined, and much
-    faster than per-call task submission (gate kept conservative here;
-    ray_perf records the headline ratio)."""
+    faster than per-call task submission (gate kept conservative
+    here)."""
     import time
 
     import ray_tpu

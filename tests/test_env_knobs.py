@@ -7,7 +7,7 @@ them documented-but-unread (``RAY_TPU_ATTN_EXP2``,
 ``RAY_TPU_CE_BF16_RESID``, ``RAY_TPU_FUSED_CE``); this test automates
 the drift check in both directions.
 
-Scope: string literals in ``ray_tpu/**/*.py`` + ``bench.py`` (AST
+Scope: string literals in ``ray_tpu/**/*.py`` (AST
 scan, docstrings excluded — prose mentions of removed knobs are fine)
 against ``README.md`` markdown table rows (``| `RAY_TPU_X` | ... |``;
 the ``RAY_TPU_FOO_BQ/BK`` shorthand expands to both spellings).
@@ -23,9 +23,7 @@ KNOB = re.compile(r"RAY_TPU_[A-Z0-9_]+")
 
 def code_knobs():
     found = {}
-    files = sorted((REPO / "ray_tpu").rglob("*.py"))
-    files.append(REPO / "bench.py")
-    for f in files:
+    for f in sorted((REPO / "ray_tpu").rglob("*.py")):
         try:
             tree = ast.parse(f.read_text())
         except SyntaxError:

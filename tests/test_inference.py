@@ -916,13 +916,9 @@ def test_infer_config_env_knobs(monkeypatch):
     monkeypatch.setenv("RAY_TPU_INFER_PAGE_SIZE", "32")
     monkeypatch.setenv("RAY_TPU_INFER_PAGES", "11")
     monkeypatch.setenv("RAY_TPU_INFER_BUCKETS", "64,256,128")
-    monkeypatch.setenv("RAY_TPU_INFER_DECODE", "xla")
     cfg = infer_config(refresh=True)
     assert (cfg.slots, cfg.page_size, cfg.pages) == (3, 32, 11)
     assert cfg.buckets == (64, 128, 256)
-    assert cfg.decode_impl == "xla"
-    monkeypatch.setenv("RAY_TPU_INFER_DECODE", "bogus")
-    assert infer_config(refresh=True).decode_impl == "auto"
     # r12 knobs: prefix cache + load-shedding queue cap
     assert infer_config().prefix and infer_config().max_queue == 0
     monkeypatch.setenv("RAY_TPU_INFER_PREFIX", "0")
@@ -935,7 +931,6 @@ def test_infer_config_env_knobs(monkeypatch):
     monkeypatch.delenv("RAY_TPU_INFER_PAGE_SIZE")
     monkeypatch.delenv("RAY_TPU_INFER_PAGES")
     monkeypatch.delenv("RAY_TPU_INFER_BUCKETS")
-    monkeypatch.delenv("RAY_TPU_INFER_DECODE")
     monkeypatch.delenv("RAY_TPU_INFER_PREFIX")
     monkeypatch.delenv("RAY_TPU_INFER_MAX_QUEUE")
     infer_config(refresh=True)
@@ -1410,15 +1405,16 @@ def _step_executable(engine, kind):
     i32 = jnp.int32
     mp = engine.max_pages_per_slot
     head = (engine.params,) + tuple(engine.cache.state)
+    fn = engine._build_step(kind)
     if kind == "decode":
-        return engine._build_decode(), head + (
+        return fn, head + (
             jnp.zeros((engine.slots,), i32),
             jnp.zeros((engine.slots,), i32),
             jnp.zeros((engine.slots, mp), i32))
     if kind == "prefill":
-        return engine._build_prefill(), head + (
+        return fn, head + (
             jnp.zeros((1, 32), i32), jnp.int32(20), jnp.zeros((mp,), i32))
-    return engine._build_prefill_cached(all_rows=kind == "verify"), head + (
+    return fn, head + (
         jnp.zeros((1, 16), i32), jnp.int32(32), jnp.int32(5),
         jnp.zeros((mp,), i32))
 
@@ -1616,3 +1612,92 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
         # were never written
         free = [p for p in range(got[n].shape[1]) if p not in owned]
         assert not got[n][:, free].any()
+
+
+# --------------------------------------------- the cache's seam, on its own
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+@pytest.mark.parametrize("writer", ["prefill", "suffix", "decode"])
+def test_cache_append_reads_back_through_context(writer, kv_dtype):
+    """What a step does to the cache, without a step: ``append`` one
+    layer's new rows with each writer, read them back through
+    ``context`` — equal to what was written (int8: to the quantiser's
+    bound of half a scale step), every row not written as it was, the
+    other layer and the garbage page untouched."""
+    import jax.numpy as jnp
+
+    from ray_tpu.inference import kv_cache as kvc
+
+    L, P, ps, H, D = 2, 6, 4, 2, 8
+    rng = np.random.default_rng(0)
+    cache = kvc.KVCache(n_layers=L, num_pages=P, page_size=ps, n_heads=H,
+                        head_dim=D, dtype=jnp.float32, kv_dtype=kv_dtype)
+    assert (cache.num_pages, cache.page_size) == (P, ps)
+    assert cache.dtype == (jnp.int8 if kv_dtype == "int8" else jnp.float32)
+    # nothing starts at zero, so "untouched" is a real claim
+    cache.state = tuple(
+        jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
+        if a.dtype == jnp.int8
+        else jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+        for a in cache.state)
+    before = [np.asarray(a) for a in cache.state]
+    layer = jnp.int32(1)
+
+    def rows(*shape):
+        return jnp.asarray(rng.normal(size=shape + (H, D)), jnp.float32)
+
+    if writer == "prefill":            # a whole 8-token bucket: 2 pages
+        table = np.array([[3, 1, 0]], np.int32)
+        k, v = rows(8), rows(8)
+        where = (kvc.write_prefill, table[0])
+        slots, pos = np.zeros(8, int), np.arange(8)
+    elif writer == "suffix":           # 4 valid rows of 8 from position 3
+        table = np.array([[3, 1, 0]], np.int32)
+        k, v = rows(8), rows(8)
+        where = (kvc.write_prefill_at, table[0], jnp.int32(3),
+                 jnp.int32(4))
+        slots, pos = np.zeros(4, int), 3 + np.arange(4)
+        k_put, v_put = k[:4], v[:4]
+    else:                              # one row per slot
+        table = np.array([[3, 1], [2, 4]], np.int32)
+        k, v = rows(2), rows(2)
+        lengths = np.array([5, 2], np.int32)
+        where = (kvc.write_decode, table, lengths)
+        slots, pos = np.arange(2), lengths
+    if writer != "suffix":
+        k_put, v_put = k, v
+
+    write, *at = where
+    got_layer, arrays = kvc.append(write, (layer, cache.state), k, v, *at)
+    assert int(got_layer) == 1 and len(arrays) == len(before)
+    after = [np.asarray(a) for a in arrays]
+    for a, b in zip(after, before):
+        np.testing.assert_array_equal(a[0], b[0])       # the other layer
+        np.testing.assert_array_equal(a[1, kvc.GARBAGE_PAGE],
+                                      b[1, kvc.GARBAGE_PAGE])
+        np.testing.assert_array_equal(a[1, 5], b[1, 5])  # in no table
+
+    kctx, vctx, scales = kvc.context((layer, arrays), table)
+    assert sorted(scales) == (["k_scale", "v_scale"]
+                              if kv_dtype == "int8" else [])
+    kd, vd = kvc.context_dense((layer, arrays), table, jnp.float32)
+    old_k, old_v = kvc.context_dense((layer, cache.state), table,
+                                     jnp.float32)
+    written = np.zeros(kd.shape[:2], bool)
+    written[slots, pos] = True
+    for dense, old, put in ((kd, old_k, k_put), (vd, old_v, v_put)):
+        dense, old, put = map(np.asarray, (dense, old, put))
+        np.testing.assert_array_equal(dense[~written], old[~written])
+        if kv_dtype == "model":
+            np.testing.assert_array_equal(dense[slots, pos], put)
+        else:
+            step = np.abs(put).max(-1, keepdims=True) / 127.0
+            assert (np.abs(dense[slots, pos] - put)
+                    <= step / 2 + 1e-6).all()
+    if kv_dtype == "int8":
+        assert kctx.dtype == jnp.int8
+        np.testing.assert_array_equal(
+            np.asarray(kd), np.asarray(kctx).astype(np.float32)
+            * np.asarray(scales["k_scale"])[..., None])
+    else:
+        np.testing.assert_array_equal(np.asarray(kd), np.asarray(kctx))
+        np.testing.assert_array_equal(np.asarray(vd), np.asarray(vctx))

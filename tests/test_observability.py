@@ -162,6 +162,16 @@ def test_dashboard_api(ray_start_regular):
     assert adetail.get("state") == "ALIVE"
 
 
+def _cli(*argv):
+    """Run the CLI against this test's own cluster: with several
+    sessions live on the host (xdist workers) "the newest" is not it."""
+    from ray_tpu._private.worker import global_node
+    env = dict(os.environ, RAY_TPU_CP_SOCK=global_node().cp_sock_path)
+    return subprocess.run(
+        [sys.executable, "-m", "ray_tpu.scripts", *argv],
+        capture_output=True, text=True, timeout=60, env=env)
+
+
 def test_cli_status_and_list(ray_start_regular):
     ray = ray_start_regular
 
@@ -172,25 +182,16 @@ def test_cli_status_and_list(ray_start_regular):
 
     a = Named.options(name="cli_actor").remote()
     ray.get(a.hi.remote())
-    out = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts", "status"],
-        capture_output=True, text=True, timeout=60)
+    out = _cli("status")
     assert out.returncode == 0
     assert "ALIVE" in out.stdout
-    out2 = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts", "list", "actors"],
-        capture_output=True, text=True, timeout=60)
+    out2 = _cli("list", "actors")
     assert "cli_actor" in out2.stdout
     # predicate filters narrow server-side rows (ray list parity)
-    out3 = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts", "list", "actors",
-         "--filter", "state=DEAD"],
-        capture_output=True, text=True, timeout=60)
+    out3 = _cli("list", "actors", "--filter", "state=DEAD")
     assert out3.returncode == 0 and "cli_actor" not in out3.stdout
-    out4 = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts", "list", "actors",
-         "--filter", "state=ALIVE", "--limit", "1"],
-        capture_output=True, text=True, timeout=60)
+    out4 = _cli("list", "actors", "--filter", "state=ALIVE",
+                "--limit", "1")
     assert out4.returncode == 0 and len(
         out4.stdout.strip().splitlines()) == 1
 
@@ -206,18 +207,13 @@ def test_cli_logs_list_and_tail(ray_start_regular):
         return 1
 
     ray.get(noisy.remote())
-    listing = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts", "logs"],
-        capture_output=True, text=True, timeout=60)
+    listing = _cli("logs")
     assert listing.returncode == 0
     names = [line.split()[-1]
              for line in listing.stdout.strip().splitlines() if line]
     worker_logs = [n for n in names if n.startswith("worker-")]
     assert worker_logs, listing.stdout
-    tail = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts", "logs",
-         worker_logs[0]],
-        capture_output=True, text=True, timeout=60)
+    tail = _cli("logs", worker_logs[0])
     assert tail.returncode == 0
 
 

@@ -321,8 +321,7 @@ def test_chunked_ce_noremat_matches_dense():
     g0 = jax.grad(lambda x: _chunked_ce(x, head, tgt, chunk=0)[0])(x)
     g1 = jax.grad(lambda x: _chunked_ce(x, head, tgt, chunk=-1)[0])(x)
     # bf16 probability residuals put ~1% noise on the largest grads —
-    # well under minibatch noise; bench.py's final_loss gate is the
-    # end-to-end check that training quality holds
+    # well under minibatch noise
     scale = float(jnp.abs(g0).max())
     assert float(jnp.abs(g0 - g1).max()) < 2e-2 * max(scale, 1e-6)
 
@@ -368,116 +367,6 @@ def test_chunked_ce_matches_dense():
                 jnp.maximum(tgt, 0)[:, None], axis=-1)[:, 0]
             * mask))(x)
     assert float(jnp.abs(g - g_ref).max()) < 1e-4
-
-
-@pytest.mark.slow
-def test_pallas_rmsnorm_matches_reference():
-    """Fused rmsnorm fwd/bwd (ops/rmsnorm.py) vs the XLA formulation."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.ops.rmsnorm import rmsnorm
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (4, 96, 256),
-                          jnp.bfloat16)
-    s = (jax.random.normal(jax.random.PRNGKey(1), (256,), jnp.float32)
-         * 0.1 + 1.0)
-
-    def ref(x, s, eps=1e-6):
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-        return (y * s.astype(jnp.float32)).astype(x.dtype)
-
-    y1, y2 = rmsnorm(x, s), ref(x, s)
-    assert float(jnp.max(jnp.abs(
-        y1.astype(jnp.float32) - y2.astype(jnp.float32)))) < 1e-2
-
-    def l1(x, s):
-        return jnp.sum(jnp.sin(rmsnorm(x, s).astype(jnp.float32)))
-
-    def l2(x, s):
-        return jnp.sum(jnp.sin(ref(x, s).astype(jnp.float32)))
-
-    g1 = jax.grad(l1, argnums=(0, 1))(x, s)
-    g2 = jax.grad(l2, argnums=(0, 1))(x, s)
-    for a, b in zip(g1, g2):
-        err = float(jnp.max(jnp.abs(
-            a.astype(jnp.float32) - b.astype(jnp.float32))))
-        scale = float(jnp.max(jnp.abs(b.astype(jnp.float32)))) + 1e-6
-        assert err / scale < 2e-2, (err, scale)
-
-
-@pytest.mark.slow
-def test_fused_ce_matches_reference():
-    """bf16-resident-logit CE (ops/fused_ce.py) vs the f32 formulation."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.ops.fused_ce import ce_sum_bf16
-
-    N, d, V = 256, 64, 1024
-    x = jax.random.normal(jax.random.PRNGKey(0), (N, d), jnp.bfloat16)
-    h = jax.random.normal(jax.random.PRNGKey(1), (d, V),
-                          jnp.bfloat16) * 0.1
-    t = jax.random.randint(jax.random.PRNGKey(2), (N,), -1, V)
-
-    def ref(x, h, t):
-        logits = (x @ h).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, -1)
-        true = jnp.take_along_axis(
-            logits, jnp.maximum(t, 0)[:, None], -1)[:, 0]
-        m = (t >= 0).astype(jnp.float32)
-        return jnp.sum((lse - true) * m) / jnp.sum(m)
-
-    def ours(x, h, t):
-        s, n = ce_sum_bf16(x, h, t)
-        return s / n
-
-    assert abs(float(ours(x, h, t)) - float(ref(x, h, t))) < 5e-2
-    g1 = jax.grad(ours, argnums=(0, 1))(x, h, t)
-    g2 = jax.grad(ref, argnums=(0, 1))(x, h, t)
-    for a, b in zip(g1, g2):
-        err = float(jnp.max(jnp.abs(
-            a.astype(jnp.float32) - b.astype(jnp.float32))))
-        scale = float(jnp.max(jnp.abs(b.astype(jnp.float32)))) + 1e-9
-        assert err / scale < 2e-2, (err, scale)
-
-
-@pytest.mark.slow
-def test_gpt_env_gated_paths_train(monkeypatch):
-    """PALLAS_NORM + RAY_TPU_CE=fused paths produce a finite training
-    step on the tiny config.  The tiny config's d=64 makes flash-CE's
-    ``supports`` decline (and the Pallas path is mesh-gated anyway),
-    so ``fused`` — plain XLA, no device gate — is the rung that
-    actually runs."""
-    import importlib
-
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.ops import flash_ce
-
-    monkeypatch.setenv("RAY_TPU_PALLAS_NORM", "1")
-    monkeypatch.setenv("RAY_TPU_CE", "fused")
-    from ray_tpu.models import gpt as gpt_mod
-    importlib.reload(gpt_mod)          # _PALLAS_NORM is read at import
-    flash_ce.ce_config(refresh=True)   # CE mode is config-cached
-    try:
-        from ray_tpu.models import training
-        from ray_tpu.parallel.mesh import make_mesh
-        cfg = gpt_mod.GPTConfig.tiny(ce_chunk=-1)
-        mesh = make_mesh(dp=1, devices=jax.devices("cpu")[:1])
-        fns = training.build_gpt_train(cfg, mesh)
-        state = fns["init_fn"](jax.random.PRNGKey(0))
-        batch = training.synthetic_lm_batch(
-            jax.random.PRNGKey(1), 2, 32, cfg.vocab_size)
-        state, m = fns["step_fn"](state, batch)
-        assert jnp.isfinite(m["loss"])
-    finally:
-        monkeypatch.undo()
-        importlib.reload(gpt_mod)
-        flash_ce.ce_config(refresh=True)
 
 
 # ---------------------------------------------------------------------------

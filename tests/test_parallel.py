@@ -357,7 +357,7 @@ def test_overlap_quantized_wire_grad_budget():
     stochastic-rounding ring grad RS) against the unquantized overlap
     schedule, same params/batch, on the fsdp=4,tp=2 host-sim mesh.
 
-    The documented budget (docs/PERF.md r11): per-parameter relative
+    The documented budget (r11): per-parameter relative
     grad error ||g_q - g|| / ||g|| <= 5% in f32, loss within 1%.  The
     weight AG contributes <= 1/254 of each 128-block's amax per
     element; each of the fsdp-1 RS hops adds <= 1/127 stochastic-

@@ -276,7 +276,7 @@ def test_no_span_name_reaches_a_compiled_function():
     args = (engine.params, *engine.cache.state,
             np.zeros((engine.slots,), np.int32), sched.lengths,
             sched.page_table)
-    texts.append(engine._build_decode().lower(*args).as_text())
+    texts.append(engine._build_step("decode").lower(*args).as_text())
     for text in texts:
         assert len(text) > 1000
         for name in NEW_NAMES:

@@ -129,23 +129,20 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, kind):
         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
     stacked = (cfg.n_layers, pages, page, H, D)
     state = (spec(stacked, BF16),) * 2
-    # the step builders read only these: no arrays, no allocation
+    # the step builder reads only these: no arrays, no allocation
     eng = object.__new__(InferenceEngine)
-    eng.cfg, eng.page_size, eng.decode_impl = cfg, page, "auto"
-    eng.kv_dtype, eng.lora_cfg = "model", None
+    eng.cfg, eng.lora_cfg = cfg, None
     eng.cache = types.SimpleNamespace(state=state)
+    fn = eng._build_step(kind)
     i32, mp = jnp.int32, CTX // page
     if kind == "decode":
-        fn, tail = eng._build_decode(), (
-            spec((SLOTS,), i32), spec((SLOTS,), i32),
-            spec((SLOTS, mp), i32))
+        tail = (spec((SLOTS,), i32), spec((SLOTS,), i32),
+                spec((SLOTS, mp), i32))
     elif kind == "prefill":
-        fn, tail = eng._build_prefill(), (
-            spec((1, 256), i32), spec((), i32), spec((mp,), i32))
+        tail = (spec((1, 256), i32), spec((), i32), spec((mp,), i32))
     else:
-        fn, tail = eng._build_prefill_cached(), (
-            spec((1, 64), i32), spec((), i32), spec((), i32),
-            spec((mp,), i32))
+        tail = (spec((1, 64), i32), spec((), i32), spec((), i32),
+                spec((mp,), i32))
     with substrate.compile_for_tpu():
         hlo = fn.lower(params, *state, *tail).compile().as_text()
 
