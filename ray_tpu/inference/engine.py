@@ -82,6 +82,7 @@ ROADMAP item.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
 import time
 from typing import Any, Dict, List, Optional, Set, Tuple, Union
@@ -136,6 +137,35 @@ class StepEvent(tuple):
         return (self[0], self[1], self[2], self.logprob, self.error)
 
 
+@dataclasses.dataclass(eq=False)
+class _Flight:
+    """One dispatched step whose sampled tokens are still on the device.
+
+    The engine fetches and delivers these oldest first (``_land``): a
+    synchronous tick right after the dispatch, a tick that runs ahead
+    only after the *next* decode is dispatched, so the fetch, the
+    per-row delivery and the serve front's fan-out happen while the
+    chip works.  ``rows`` pairs a row of the sampler's output with the
+    request it was dispatched for: a request that ended meanwhile
+    (``eos_token``, cancel, deadline) is recognised by identity and its
+    row dropped, whoever holds the slot by then."""
+    kind: str                       # "prefill" | "prefill_cached" | "decode"
+    rows: List[Tuple[int, Request]]
+    out: Any                        # (tokens, logprobs), device arrays
+    span: "tracing.Span"            # the dispatch: infer/<kind>, with
+    #                                 its attributes (bucket, ahead)
+    path: Optional[str] = None      # the sampler body, where counted
+    logits: Any = None              # device logits, under debug_logits
+
+
+@jax.jit
+def _lay_token(tokens, slot, token):
+    """``tokens`` [slots] with ``token`` [1] at ``slot``: a first token
+    (a prefill's sample, still on the device) laid over the previous
+    decode's sampled tokens, which are the next decode's input."""
+    return tokens.at[slot].set(token[0])
+
+
 def _cached_context_attention(q, kctx, vctx, ks, vs, cached_len,
                               scale: Optional[float] = None):
     """Suffix queries over (cached prefix pages + causal suffix self).
@@ -184,6 +214,30 @@ class InferenceEngine:
     events.  ``generate()`` is the run-to-completion convenience;
     streaming callers (the serve deployment) pump ``step()`` and fan
     events out per request.
+
+    **One decode in flight.**  The engine runs one decode ahead of what
+    the host has seen: a tick dispatches its decode on the device's own
+    tokens (the previous sampler call's output, the first token of a
+    request prefilled in between laid over its slot), and only then
+    fetches and delivers the *previous* decode's tokens — so a tick
+    returns the first tokens of the requests it admitted and the events
+    of the decode dispatched one tick earlier, and the host's fetch,
+    delivery and fan-out run while the chip works.  What the next
+    dispatch needs is reckoned by count at dispatch (``lengths``, the
+    sampler's ``counts``, "this row is the request's last":
+    ``len(generated) + in_flight``); what the caller sees (the event,
+    retiring, telemetry) happens at delivery.  A request ended by what
+    no count foresees (``eos_token``, a cancel, a deadline) may meet
+    one row of its own still in flight: the row's cache write lands
+    beyond the request's context, ordered before any later writer of
+    the same page by the donated cache state every step threads, and
+    its sampled token is dropped.  The engine chooses from its own
+    state: a tick in which a slot may draft (``spec_k > 0``: drafting
+    reads the tokens on the host) first brings the host level with the
+    device and runs synchronously, as every tick did before.  No
+    token, logprob or logits row differs between the two; only the
+    tick an event is returned in.  ``has_work()`` stays true while a
+    token is in flight.
 
     Knobs default to :func:`ray_tpu.inference.config.infer_config`
     (``RAY_TPU_INFER_*``); constructor arguments pin them for tests and
@@ -391,6 +445,11 @@ class InferenceEngine:
         # cumulative counter, deltas reported per tick
         self._store_evictions_seen = (self.store.evictions
                                       if self.store is not None else 0)
+        # dispatched steps whose tokens the host has not fetched,
+        # oldest first (see _Flight), and events delivered outside a
+        # tick (``_level``), which the next tick returns
+        self._flight: List[_Flight] = []
+        self._backlog: List[StepEvent] = []
         self._next_rid = 0
         self._cancelled: set = set()
         self._lock = threading.Lock()   # submit() vs step() admissions
@@ -659,9 +718,12 @@ class InferenceEngine:
         """Retire ``rid`` early (abandoned stream / client disconnect).
 
         Processed at the start of the next :meth:`step` tick — the only
-        place scheduler state mutates besides admission, so a cancel
-        can never race a decode that is mid-flight over the slot.  A
-        no-op for finished/unknown rids."""
+        place scheduler state mutates besides admission and delivery.
+        The slot and pages free there and then; a row of the request
+        still in flight on the device is dropped when it is fetched
+        (its cache write precedes any later writer of the page through
+        the cache state every step threads).  A no-op for
+        finished/unknown rids."""
         with self._lock:
             if rid in self._requests:
                 self._cancelled.add(rid)
@@ -681,6 +743,10 @@ class InferenceEngine:
         for rid in rids:
             self.cancel(rid)
         self._process_cancels()
+        # tokens in flight belonged to those requests: dropped unfetched
+        # (the device may be the reason for the teardown)
+        self._flight.clear()
+        self._backlog.clear()
         held = list(self._held)
         for rid in held:
             self.release_held(rid)
@@ -843,8 +909,9 @@ class InferenceEngine:
 
     def _expire_deadlines(self, events: List["StepEvent"]) -> None:
         """Retire every request past its deadline, at the same safe
-        point cancels process (tick start — nothing is mid-flight over
-        a slot).  A waiting request can blow either budget (TTFT is
+        point cancels process (tick start; a row still in flight for
+        an expired request is dropped at its fetch, like a cancelled
+        one's).  A waiting request can blow either budget (TTFT is
         total-bounded too: ``ttft <= total``); an active request only
         the total one, since admission delivered its first token in
         its admission tick.  Retirement releases everything — slot,
@@ -926,6 +993,9 @@ class InferenceEngine:
         Returns the new ``param_version`` (monotonic; explicit
         ``version`` pins it — publications carry the learner's own
         counter so actor-side lag is measured in learner versions)."""
+        # a decode in flight ran under the snapshot about to be deleted:
+        # its tokens are fetched first, and come out of the next step()
+        self._level()
         self.scheduler.flush_prefix()
         if self.host_pool is not None:
             # spilled entries hold K/V computed under the old params;
@@ -948,8 +1018,11 @@ class InferenceEngine:
         return self.param_version
 
     def has_work(self) -> bool:
+        """Something is queued, active, in flight on the device, or
+        delivered and not yet returned: ``step()`` has events to come."""
         with self._lock:
-            return self.scheduler.has_work
+            return bool(self.scheduler.has_work or self._flight
+                        or self._backlog)
 
     def prefix_digest(self) -> frozenset:
         """Registered prefix chain hashes (the ``stats()["prefix"]``
@@ -960,6 +1033,9 @@ class InferenceEngine:
             return self.scheduler.prefix_digest()
 
     def stats(self) -> Dict[str, Any]:
+        """The host's view, safe to read beside a running tick: a slot
+        whose request has a token in flight counts as active, and
+        nothing here waits for the device."""
         return {
             "compiles": dict(self.compile_counts),
             "hits": dict(self.hit_counts),
@@ -1027,20 +1103,38 @@ class InferenceEngine:
     def step(self) -> List[StepEvent]:
         """One engine tick -> [(rid, token, done), ...] events (each a
         :class:`StepEvent`: 3-tuple-compatible, ``.logprob`` rides
-        along)."""
-        events: List[StepEvent] = []
+        along).
+
+        A tick admits (dispatching each admitted request's prefill),
+        dispatches the decode of every active row, and only then
+        fetches and delivers what was dispatched before that decode: so
+        it returns the first tokens of the requests it admitted and the
+        tokens of the decode the *previous* tick dispatched, while the
+        chip is already at work on this tick's.  A tick with a slot
+        that may draft is synchronous and returns its own decode's
+        tokens too (see the class docstring)."""
+        events, self._backlog = self._backlog, []
         with tracing.span("infer/step", tick=self.ticks) as tick:
             admitted = self._admit(events)
-            if self.scheduler.active:
+            active = self.scheduler.active
+            # drafting reads a request's tokens on the host: a tick in
+            # which any slot may draft brings the host level first and
+            # fetches its own decode at once, as every tick used to
+            ahead = not any(r.spec_k > 0 for r in active.values())
+            plan: Dict[int, List[int]] = {}
+            if not ahead:
+                self._land(events)
                 # speculating slots leave the plain decode batch for
                 # this tick (their verify forward IS their decode) and
                 # plain slots co-batch as always; an all-speculating
                 # tick skips the decode dispatch entirely
                 plan = self._plan_speculation()
-                if len(plan) < len(self.scheduler.active):
-                    self._decode(events, skip=set(plan))
-                for slot, drafts in plan.items():
-                    self._verify(slot, drafts, events)
+            sent = None
+            if len(plan) < len(active):
+                sent = self._decode(skip=set(plan), ahead=ahead)
+            self._land(events, keep=sent if ahead else None)
+            for slot, drafts in plan.items():
+                self._verify(slot, drafts, events)
             self.ticks += 1
             self.last_tick_ts = time.monotonic()
             if self.store is not None:
@@ -1062,8 +1156,9 @@ class InferenceEngine:
         """The scheduler's part of a tick, an ``infer/admit`` span per
         pass: cancels, deadlines and adapters first, then requests taken
         off the queue one at a time (prefix walk, page allocation, tier
-        and import installs), each prefilled before the next is
-        looked at.  Returns how many were admitted."""
+        and import installs), each one's prefill dispatched before the
+        next is looked at (its first token is fetched with the rest of
+        the tick's, ``_land``).  Returns how many were admitted."""
         admitted = 0
         while True:
             with tracing.span("infer/admit",
@@ -1088,7 +1183,7 @@ class InferenceEngine:
                             req.n_hit_pages, tier="hbm")
                 if req.tier_plan:
                     self._install_tier_hits(req)
-            self._prefill(req, events)
+            self._prefill(req)
 
     def generate(self, prompts, max_new_tokens: int = 16,
                  sampling: Optional[SamplingParams] = None,
@@ -1128,6 +1223,7 @@ class InferenceEngine:
             for r in rids:
                 self.cancel(r)
             self._process_cancels()
+            self._level()       # nor a row of theirs in flight
             raise err
         if return_logprobs:
             return ([out[r] for r in rids], [lps[r] for r in rids])
@@ -1140,7 +1236,14 @@ class InferenceEngine:
                 return b
         raise ValueError(f"no prefill bucket fits length {n}")
 
-    def _prefill(self, req: Request, events) -> None:
+    def _prefill(self, req: Request) -> None:
+        """Dispatch ``req``'s prefill and the sampling of its first
+        token; nothing is fetched here.  What the rest of the tick
+        needs is settled now: the slot's length, and the prompt's pages
+        in the prefix index — every later reader of those pages takes
+        the cache state this dispatch returns, so it runs after it.
+        The first token stays on the device, where the tick's decode
+        takes it as its input (``_token_input``)."""
         sched = self.scheduler
         slot = req.slot
         plen = len(req.prompt)
@@ -1167,45 +1270,50 @@ class InferenceEngine:
                           cached=cached, **ids) as sp:
             logits = self._run_step((kind, bucket), [req], tokens,
                                     *scalars, sched.page_table[slot])
-            toks, logps = self._sample_slots(logits, [req])
-            tok, logp = toks[0], logps[0]
-        with self._deliver_span(events):
-            # the prompt's K/V are now fully in cache: its full pages
-            # are immutable from here on and safe to hand to other
-            # requests
-            self._register_prefix(req)
-            if self.debug_logits:
-                self.logits_trace.setdefault(req.rid, []).append(
-                    np.asarray(logits[0]))
-            sched.lengths[slot] = plen
-            # the first token exists when the prefill span ends: the
-            # span's one pair of clock reads feeds every sink
-            ttft = sp.end - req.submitted_ts
-            if tr is not None:
-                from ray_tpu.telemetry import trace as trace_mod
-                trace_mod.record_span(
-                    "queue", tr,
-                    start=trace_mod.epoch_of(req.submitted_ts),
-                    dur=req.admitted_ts - req.submitted_ts, rid=req.rid,
-                    replica=self.trace_label)
-                trace_mod.record_span(
-                    "prefill", tr, start=trace_mod.epoch_of(sp.start),
-                    dur=sp.dur, rid=req.rid, bucket=bucket,
-                    cached=cached, kind=kind, replica=self.trace_label)
-                trace_mod.event("first_token", tr, rid=req.rid,
-                                ttft_s=ttft, replica=self.trace_label)
-            if self.telemetry.enabled:
-                self.telemetry.record_queue(
-                    req.admitted_ts - req.submitted_ts,
-                    depth=len(sched.waiting))
-                self.telemetry.record_prefill(
-                    sp.dur, prompt_tokens=plen, bucket=bucket,
-                    cached_tokens=cached)
-                self.telemetry.record_ttft(
-                    ttft, prefix_hit=cached > 0,
-                    trace_id=req.trace.trace_id
-                    if req.trace is not None else None)
-            self._deliver(req, int(tok), float(logp), events)
+            out, path = self._sample_slots(sp, logits, [req])
+        req.in_flight += 1
+        self._register_prefix(req)
+        sched.lengths[slot] = plen
+        self._flight.append(_Flight(
+            kind, [(0, req)], out, sp, path=path,
+            logits=logits if self.debug_logits else None))
+
+    def _land_prefill(self, rec: _Flight, ssp) -> None:
+        """The records of a prefill whose first token has reached the
+        host (the fetch span ``ssp`` has just ended).  Its wall time is
+        what the step thread spent on it: the dispatch and the wait of
+        the fetch, not what was dispatched in between."""
+        sp = rec.span
+        req = rec.rows[0][1]
+        plen, cached = len(req.prompt), req.cached_tokens
+        bucket = sp.attributes["bucket"]
+        wall = sp.dur + ssp.dur
+        ttft = ssp.end - req.submitted_ts
+        if req.trace is not None and req.trace.sampled:
+            from ray_tpu.telemetry import trace as trace_mod
+            tr = req.trace
+            trace_mod.record_span(
+                "queue", tr,
+                start=trace_mod.epoch_of(req.submitted_ts),
+                dur=req.admitted_ts - req.submitted_ts, rid=req.rid,
+                replica=self.trace_label)
+            trace_mod.record_span(
+                "prefill", tr, start=trace_mod.epoch_of(sp.start),
+                dur=wall, rid=req.rid, bucket=bucket,
+                cached=cached, kind=rec.kind, replica=self.trace_label)
+            trace_mod.event("first_token", tr, rid=req.rid,
+                            ttft_s=ttft, replica=self.trace_label)
+        if self.telemetry.enabled:
+            self.telemetry.record_queue(
+                req.admitted_ts - req.submitted_ts,
+                depth=len(self.scheduler.waiting))
+            self.telemetry.record_prefill(
+                wall, prompt_tokens=plen, bucket=bucket,
+                cached_tokens=cached)
+            self.telemetry.record_ttft(
+                ttft, prefix_hit=cached > 0,
+                trace_id=req.trace.trace_id
+                if req.trace is not None else None)
 
     def _install_import(self, req: Request, events) -> None:
         """Seed an admitted import's slot from its handoff payload —
@@ -1404,7 +1512,13 @@ class InferenceEngine:
         return True
 
     # ----------------------------------------------------------- decode
-    def _decode(self, events, skip: Optional[Set[int]] = None) -> None:
+    def _decode(self, skip: Set[int], ahead: bool) -> Optional[_Flight]:
+        """Dispatch one decode over every active row that has a token
+        to come and is not in ``skip`` -> its record in ``_flight``
+        (None if there is no such row).  Nothing is fetched: the token
+        input is the previous decode's sampled tokens where that decode
+        is still in flight, and what delivery used to settle for the
+        next dispatch is settled here, by count."""
         from ray_tpu.util import chaos
 
         # fault site BEFORE any cache/scheduler mutation and before the
@@ -1412,57 +1526,138 @@ class InferenceEngine:
         # leaves the engine state consistent (slots/pages still held,
         # cache arrays live), so supervisors can cancel/drain cleanly
         chaos.maybe_fail("infer.decode")
-        skip = skip or set()
         sched = self.scheduler
-        tokens = np.zeros((self.slots,), np.int32)
         reqs: List[Optional[Request]] = [None] * self.slots
+        dead = []
         for slot, req in sched.active.items():
-            if slot in skip:
-                continue
-            tokens[slot] = req.generated[-1]
-            reqs[slot] = req
-        active = [r for r in reqs if r is not None]
-        page_table = sched.page_table
-        if skip:
-            # speculating slots ride this dispatch as dead rows (the
-            # decode step's shape is fixed): their page rows mask to
-            # the garbage page so the batched K/V write cannot touch
-            # the positions their verify forward is about to fill, and
-            # their sampled outputs are never delivered
-            page_table = page_table.copy()
-            page_table[list(skip), :] = kvc.GARBAGE_PAGE
-        with tracing.span("infer/decode", active=len(active)) as sp:
-            logits = self._run_step(("decode",), reqs, tokens,
-                                    sched.lengths, page_table)
-            sampled, logps = self._sample_slots(logits, reqs)
-        with self._deliver_span(events):
-            traced = [r.trace.trace_id for r in active
-                      if r.trace is not None and r.trace.sampled]
-            if traced:
-                # ONE coalesced span per tick (trace_id=None: a global
-                # span), carrying the sampled trace ids it served — a
-                # span per (tick, request) would swamp the ring at
-                # decode rate
-                from ray_tpu.telemetry import trace as trace_mod
-                trace_mod.record_span(
-                    "decode_tick", None,
-                    start=trace_mod.epoch_of(sp.start), dur=sp.dur,
-                    active=len(active), trace_ids=traced,
-                    replica=self.trace_label)
-            if self.telemetry.enabled:
-                self.telemetry.record_decode(sp.dur, active=len(active))
-            if self.debug_logits:
-                host_logits = np.asarray(logits)
-            for slot in list(sched.active):
-                if slot in skip:
-                    continue
-                req = sched.active[slot]
-                sched.lengths[slot] += 1   # the input token is now cached
-                if self.debug_logits:
-                    self.logits_trace.setdefault(req.rid, []).append(
-                        host_logits[slot])
-                self._deliver(req, int(sampled[slot]),
-                              float(logps[slot]), events)
+            # a request whose last token is in flight holds its slot
+            # until that token is delivered, and is not decoded again
+            if slot not in skip and (len(req.generated) + req.in_flight
+                                     < req.max_new_tokens):
+                reqs[slot] = req
+            else:
+                dead.append(slot)
+        rows = [(slot, r) for slot, r in enumerate(reqs) if r is not None]
+        if not rows:
+            return None
+        # fresh copies: the scheduler's arrays change below, while the
+        # dispatched step may still be reading its arguments
+        lengths = sched.lengths.copy()
+        page_table = sched.page_table.copy()
+        if dead:
+            # held slots that sit this decode out (speculating, or
+            # done but for delivery) ride it as dead rows (the decode
+            # step's shape is fixed): their page rows mask to the
+            # garbage page so the batched K/V write cannot touch the
+            # positions a verify forward is about to fill, and their
+            # sampled outputs are never delivered
+            page_table[dead, :] = kvc.GARBAGE_PAGE
+        with tracing.span("infer/decode", active=len(rows),
+                          ahead=int(ahead)) as sp:
+            logits = self._run_step(("decode",), reqs,
+                                    self._token_input(rows), lengths,
+                                    page_table)
+            out, path = self._sample_slots(sp, logits, reqs)
+        for slot, req in rows:
+            sched.lengths[slot] += 1    # the input token is cached
+            req.in_flight += 1
+        rec = _Flight("decode", rows, out, sp, path=path,
+                      logits=logits if self.debug_logits else None)
+        self._flight.append(rec)
+        return rec
+
+    def _token_input(self, rows: List[Tuple[int, Request]]):
+        """Each row's next input token, [slots].  Where a decode is in
+        flight its sampled tokens are the input and never leave the
+        device; otherwise the host's own last tokens are.  Laid over
+        either: the first token of a request whose prefill is in flight
+        (still on the device), and the host's token of a row the decode
+        in flight did not hold (an import seeded at its install)."""
+        prev, first = None, {}
+        for rec in self._flight:
+            if rec.kind == "decode":
+                prev = rec
+            else:
+                first[rec.rows[0][1].rid] = rec.out[0]
+        if prev is not None:
+            tokens = prev.out[0]
+            held = {req.rid for _row, req in prev.rows}
+        else:
+            tokens = np.zeros((self.slots,), np.int32)
+            for slot, req in rows:
+                if req.generated:
+                    tokens[slot] = req.generated[-1]
+        for slot, req in rows:
+            token = first.get(req.rid)
+            if token is None and prev is not None and req.rid not in held:
+                token = np.array(req.generated[-1:], np.int32)
+            if token is not None:
+                tokens = _lay_token(tokens, np.int32(slot), token)
+        return tokens
+
+    # ------------------------------------------------- fetch and deliver
+    def _land(self, events, keep: Optional[_Flight] = None) -> None:
+        """Fetch and deliver every dispatched step but ``keep`` (the
+        decode a tick leaves in flight), oldest first: an
+        ``infer/sample`` span around each fetch — there the host waits
+        for the device — and an ``infer/deliver`` span around what the
+        tokens mean for the caller."""
+        landing = [rec for rec in self._flight if rec is not keep]
+        self._flight = [] if keep is None else [keep]
+        for rec in landing:
+            with tracing.span("infer/sample", rows=len(rec.rows)) as ssp:
+                if rec.path is not None:
+                    ssp.set(path=rec.path)
+                toks, logps = jax.device_get(rec.out)
+            with self._deliver_span(events):
+                for _row, req in rec.rows:
+                    req.in_flight -= 1
+                # a request that ended with this row in flight (eos,
+                # cancel, deadline): the row's token is dropped
+                live = [(row, req) for row, req in rec.rows
+                        if not req.done]
+                if rec.kind == "decode":
+                    self._land_decode(rec, ssp, len(live))
+                elif live:
+                    self._land_prefill(rec, ssp)
+                host_logits = (np.asarray(rec.logits) if live
+                               and rec.logits is not None else None)
+                for row, req in live:
+                    if host_logits is not None:
+                        self.logits_trace.setdefault(req.rid, []).append(
+                            host_logits[row])
+                    self._deliver(req, int(toks[row]), float(logps[row]),
+                                  events)
+
+    def _land_decode(self, rec: _Flight, ssp, delivered: int) -> None:
+        """The records of a decode whose tokens have reached the host:
+        its wall time is what the step thread spent on it, the dispatch
+        and the wait of the fetch."""
+        sp = rec.span
+        wall = sp.dur + ssp.dur
+        traced = [r.trace.trace_id for _row, r in rec.rows
+                  if r.trace is not None and r.trace.sampled]
+        if traced:
+            # ONE coalesced span per tick (trace_id=None: a global
+            # span), carrying the sampled trace ids it served — a
+            # span per (tick, request) would swamp the ring at
+            # decode rate
+            from ray_tpu.telemetry import trace as trace_mod
+            trace_mod.record_span(
+                "decode_tick", None,
+                start=trace_mod.epoch_of(sp.start), dur=wall,
+                active=delivered,
+                trace_ids=traced, replica=self.trace_label)
+        if self.telemetry.enabled:
+            self.telemetry.record_decode(
+                wall, active=delivered,
+                ahead=bool(sp.attributes["ahead"]))
+
+    def _level(self) -> None:
+        """Bring the host level with the device from outside a tick:
+        whatever is in flight is fetched and delivered, and the events
+        wait for the next :meth:`step` to return them."""
+        self._land(self._backlog)
 
     # ---------------------------------------------- speculation (r21)
     def _plan_speculation(self) -> Dict[int, List[int]]:
@@ -1545,8 +1740,12 @@ class InferenceEngine:
                 top_ks = np.full((n_rows,), req.sampling.top_k, np.int32)
                 top_ps = np.full((n_rows,), req.sampling.top_p,
                                  np.float32)
-                toks, logps = self._sample_fetch(
-                    ssp, logits[0], seeds, counts, temps, top_ks, top_ps)
+                path = self._sample_path(ssp, temps, top_ks, top_ps)
+                if path is not None:
+                    ssp.set(path=path)
+                # a verify is fetched at once: its tick is synchronous
+                toks, logps = jax.device_get(sample_tokens_logprobs(
+                    logits[0], seeds, counts, temps, top_ks, top_ps))
         with self._deliver_span(events):
             m, emitted = accept_drafts(toks[:n_drafts + 1], drafts)
             self.spec_proposed += n_drafts
@@ -1584,9 +1783,9 @@ class InferenceEngine:
     @contextlib.contextmanager
     def _deliver_span(self, events):
         """``infer/deliver``: what a tick does with tokens once they are
-        on the host (prefix registration, lengths, records, retiring),
-        closed with how many events it appended and how many of them
-        finished a request."""
+        on the host (records, the caller's events, retiring), closed
+        with how many events it appended and how many of them finished
+        a request."""
         n0 = len(events)
         with tracing.span("infer/deliver") as sp:
             yield sp
@@ -1622,44 +1821,44 @@ class InferenceEngine:
         events.append(StepEvent(req.rid, tok, done, logp))
 
     # --------------------------------------------------------- sampling
-    def _sample_slots(self, logits, reqs: List[Optional[Request]]
-                      ) -> Tuple[np.ndarray, np.ndarray]:
-        """Sample one token per logits row — the full [slots, V] decode
-        batch (None rows are inactive, result discarded) or a prefill's
-        single [1, V] row.  Returns ``(tokens, model logprobs)``."""
+    def _sample_slots(self, sp, logits, reqs: List[Optional[Request]]
+                      ) -> Tuple[Tuple[Any, Any], Optional[str]]:
+        """Dispatch the sampler over one token per logits row — the
+        full [slots, V] decode batch (None rows are inactive, result
+        discarded) or a prefill's single [1, V] row — inside the
+        dispatch span ``sp``.  Returns ``((tokens, model logprobs),
+        path)``, the arrays still on the device: ``_land`` fetches
+        them.  A row's key is folded with the count of tokens sampled
+        for its request before this one, seen by the host or not."""
         null = SamplingParams()
-        with tracing.span("infer/sample", rows=len(reqs)) as ssp:
-            seeds = np.array([(r.sampling.seed if r else 0)
-                              for r in reqs], np.int32)
-            counts = np.array([(len(r.generated) if r else 0)
-                               for r in reqs], np.int32)
-            temps = np.array(
-                [(r.sampling.temperature if r else null.temperature)
-                 for r in reqs], np.float32)
-            top_ks = np.array([(r.sampling.top_k if r else 0)
-                               for r in reqs], np.int32)
-            top_ps = np.array([(r.sampling.top_p if r else 1.0)
-                               for r in reqs], np.float32)
-            return self._sample_fetch(ssp, logits, seeds, counts, temps,
-                                      top_ks, top_ps)
+        seeds = np.array([(r.sampling.seed if r else 0)
+                          for r in reqs], np.int32)
+        counts = np.array([(len(r.generated) + r.in_flight if r else 0)
+                           for r in reqs], np.int32)
+        temps = np.array(
+            [(r.sampling.temperature if r else null.temperature)
+             for r in reqs], np.float32)
+        top_ks = np.array([(r.sampling.top_k if r else 0)
+                           for r in reqs], np.int32)
+        top_ps = np.array([(r.sampling.top_p if r else 1.0)
+                           for r in reqs], np.float32)
+        path = self._sample_path(sp, temps, top_ks, top_ps)
+        return sample_tokens_logprobs(logits, seeds, counts, temps,
+                                      top_ks, top_ps), path
 
-    def _sample_fetch(self, ssp, logits, seeds, counts, temps, top_ks,
-                      top_ps) -> Tuple[np.ndarray, np.ndarray]:
-        """Inside an ``infer/sample`` span: where the counter or a trace
-        will keep it, name the body this call's rows select
-        (``sampling.sample_path``, the executable's own rule on the same
-        arrays); dispatch the sampler, and fetch tokens and logprobs in
-        one transfer."""
+    def _sample_path(self, sp, temps, top_ks, top_ps) -> Optional[str]:
+        """Where the counter or a trace will keep it (``sp`` is the
+        open span around the call), the body this sampler call's rows
+        select (``sampling.sample_path``, the executable's own rule on
+        the same arrays): counted here, and the ``path`` of the
+        ``infer/sample`` span around the call's fetch."""
         counted = self.telemetry.enabled
-        if counted or ssp.recording:
-            path = sample_path(temps, top_ks, top_ps)
-            ssp.set(path=path)
-            if counted:
-                self.telemetry.record_sample(path)
-        out = sample_tokens_logprobs(logits, seeds, counts, temps,
-                                     top_ks, top_ps)
-        # the fetch: here the host waits for the device
-        return jax.device_get(out)
+        if not (counted or sp.recording):
+            return None
+        path = sample_path(temps, top_ks, top_ps)
+        if counted:
+            self.telemetry.record_sample(path)
+        return path
 
     # ---------------------------------------------------- compile cache
     def _run_step(self, key, reqs, *step_args):

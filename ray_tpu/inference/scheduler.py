@@ -110,6 +110,11 @@ class Request:
     submitted_ts: float = dataclasses.field(default_factory=time.monotonic)
     admitted_ts: Optional[float] = None
     done: bool = False
+    # tokens dispatched for this request (its prefill, a decode row)
+    # whose values the host has not fetched yet: the engine runs one
+    # decode ahead of what it has seen, so everything the next dispatch
+    # needs is reckoned as ``len(generated) + in_flight``
+    in_flight: int = 0
     # deadlines (seconds from submit; None = none): ``ttft_deadline_s``
     # bounds time-to-first-token — it can only expire while the request
     # is still waiting, because admission delivers the first token in

@@ -10,7 +10,12 @@ generator that drains its queue — tokens flow through the existing
 token, and the handle-side ``DeploymentResponseGenerator`` yields them
 as they land.  Continuous batching happens inside the engine: requests
 arriving mid-stream join free decode slots without disturbing running
-sequences.
+sequences.  The engine keeps one decode in flight: a tick returns the
+first tokens of the requests it admitted and the events of the decode
+the *previous* tick dispatched, so this fan-out (and the executor hop
+back into ``step()``) runs while the chip is at work on the next
+decode, and ``has_work()`` stays true until the last token in flight
+has been returned.
 
 Abandoned streams: closing the request's (replica-side) generator —
 asyncio cancellation, ``aclose()``, the proxy tearing down a

@@ -8,6 +8,11 @@ looks "busy" forever.  The watchdog is the liveness cross-check: the
 engine stamps ``ticks``/``last_tick_ts`` at the end of every completed
 ``step()``, and a background thread declares a **wedge** when the
 engine has work pending but neither stamp has moved for ``timeout_s``.
+The engine keeps one decode in flight, which changes neither meaning:
+a completed ``step()`` has dispatched its decode and fetched the one
+before it (a device that hangs holds that fetch, so no stamp moves),
+and ``has_work()`` counts a token still in flight as work pending, so
+the last tokens of a stream are watched like the first.
 
 Detection is deliberately separated from reaction: the default
 ``on_wedge`` warns on stderr and counts (``wedges``, surfaced through

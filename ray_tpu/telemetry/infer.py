@@ -67,6 +67,9 @@ class InferTelemetry:
         # ``draw`` / ``filter``, ``inference/sampling.py``): only
         # ``filter`` pays the full-vocabulary sorts
         self.sample_paths: Dict[str, int] = {}
+        # plain decodes by how their tick ran: [synchronous, ahead of
+        # the host] (a verify step is counted by ``spec_verify_steps``)
+        self.decode_dispatches = [0, 0]
         # speculative decoding (r21): cumulative proposed/accepted
         # draft counts and verify-step count — the accept rate is the
         # one number that decides whether speculation pays
@@ -108,10 +111,16 @@ class InferTelemetry:
                               "bucket": bucket})
         del self.prefills[:-self._MAX_RECORDS]
 
-    def record_decode(self, wall_s: float, *, active: int) -> None:
+    def record_decode(self, wall_s: float, *, active: int,
+                      ahead: bool = False) -> None:
+        """One plain decode, recorded when its tokens are on the host.
+        ``ahead``: it was dispatched on the device's own tokens, ahead
+        of the host (``inference/engine.py``), and not by a synchronous
+        tick."""
         if not self.enabled:
             return
         self.decode_count += 1
+        self.decode_dispatches[bool(ahead)] += 1
         self.decode_tokens += active
         self.decodes.append({"wall_s": wall_s, "active": active})
         del self.decodes[:-self._MAX_RECORDS]
@@ -311,6 +320,10 @@ class InferTelemetry:
                 "path_share": {path: n / calls for path, n
                                in sorted(self.sample_paths.items())},
             }
+        if any(self.decode_dispatches):
+            sync, ahead = self.decode_dispatches
+            out["decode"] = {"dispatches": sync + ahead,
+                             "ahead_share": ahead / (sync + ahead)}
         if self.spec_verify_steps:
             out["spec"] = {
                 "verify_steps": self.spec_verify_steps,

@@ -138,8 +138,11 @@ def test_export_import_roundtrip_fp32(tiny_f32, plen):
     # step's decode tick already appended ONE token at position plen,
     # which lands inside the tail page when the context has one — so
     # the tail compares only its context positions)
-    dec.step()
+    # (the engine runs one decode ahead: that decode is dispatched and
+    # its write is in the cache, its token comes out of the next step)
+    assert dec.step() == [] and dec.has_work()
     (req,) = dec.scheduler.active.values()
+    assert req.in_flight == 1 and len(req.generated) == 1
     arrays = kvc.export_pages(dec.cache, req.pages[:handoff.n_pages])
     tail = plen % 16
     for got, sent in ((arrays["k"], handoff.k),
@@ -149,7 +152,7 @@ def test_export_import_roundtrip_fp32(tiny_f32, plen):
         if tail:
             np.testing.assert_array_equal(got[:, -1, :tail],
                                           sent[:, -1, :tail])
-    out = [t0, dec._requests[rid2].generated[1]]
+    out = [t0]
     assert _drain(dec, out) == want
     assert dec.stats()["imports"] == 1
     assert dec.stats()["compiles"] == {"prefill": 0,
@@ -191,14 +194,14 @@ def test_export_import_roundtrip_int8(tiny_f32):
         (cfg.head_dim + 4) / (cfg.head_dim * 4))
 
     rid2 = dec.import_submit(h8, max_new_tokens=5)
-    dec.step()
+    assert dec.step() == []          # the first decode is in flight
     (req,) = dec.scheduler.active.values()
     arrays = kvc.export_pages(dec.cache, req.pages[:h8.n_pages])
     np.testing.assert_array_equal(arrays["k"], h8.k)
     np.testing.assert_array_equal(arrays["v"], h8.v)
     np.testing.assert_array_equal(arrays["k_scale"], h8.k_scale)
     np.testing.assert_array_equal(arrays["v_scale"], h8.v_scale)
-    out = [t0, dec._requests[rid2].generated[1]]
+    out = [t0]
     assert _drain(dec, out) == want
     assert dec.stats()["compiles"] == {"prefill": 0,
                                        "prefill_cached": 0,

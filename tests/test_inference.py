@@ -1701,3 +1701,304 @@ def test_cache_append_reads_back_through_context(writer, kv_dtype):
     else:
         np.testing.assert_array_equal(np.asarray(kd), np.asarray(kctx))
         np.testing.assert_array_equal(np.asarray(vd), np.asarray(vctx))
+
+
+# ------------------------------------------- one decode in flight (PR 32)
+# The engine dispatches a tick's decode on the device's own tokens and
+# only then fetches the previous decode's.  Each case below drives the
+# same script through two engines: one as it runs (ahead), one brought
+# level with the device after every tick (``_level``: every token is on
+# the host before the next dispatch, which is the synchronous tick the
+# engine ran before).  What a caller sees per request must be equal.
+def _pump(engine, level, script, limit=400):
+    """Run ``script(engine, tick, streams)`` before every tick and step
+    to the end -> ({rid: [(token, done, logprob, error type)]}, how
+    many ticks left a decode in flight)."""
+    from ray_tpu.util import chaos
+    streams, left_in_flight, tick = {}, 0, 0
+    while tick == 0 or engine.has_work():
+        script(engine, tick, streams)
+        try:
+            events = engine.step()
+        except chaos.InjectedFault:
+            events = []     # the tick is lost; what it dispatched is not
+        if level:           # the tick returns its own decode's tokens
+            engine._level()
+            events, engine._backlog = events + engine._backlog, []
+        for ev in events:
+            streams.setdefault(ev[0], []).append(
+                (ev[1], ev[2], ev.logprob,
+                 type(ev.error).__name__ if ev.error is not None
+                 else None))
+        left_in_flight += any(f.kind == "decode" for f in engine._flight)
+        tick += 1
+        assert tick < limit, "the engine never ran dry"
+    return streams, left_in_flight
+
+
+def _at_start(submit):
+    """A script that submits everything before the first tick."""
+    def script(engine, tick, streams):
+        if tick == 0:
+            submit(engine)
+    return script
+
+
+def _ahead_plain(cfg, sampling=None, **submit_kw):
+    """Three requests over two slots: the third takes the slot of
+    whichever finishes first, while a decode is in flight."""
+    def submit(engine):
+        engine.submit(_prompt(9, cfg.vocab_size), max_new_tokens=7,
+                      sampling=sampling, **submit_kw)
+        engine.submit(_prompt(20, cfg.vocab_size, seed=1),
+                      max_new_tokens=4, sampling=sampling, **submit_kw)
+        engine.submit(_prompt(5, cfg.vocab_size, seed=2),
+                      max_new_tokens=5, sampling=sampling, **submit_kw)
+    return _at_start(submit)
+
+
+def _ahead_case(name, cfg, params):
+    """-> (engine kwargs, script, what the ahead engine's
+    ``ahead_share`` must read, a check of the two engines or None)."""
+    from ray_tpu.inference import SamplingParams
+    kwargs, share, check = {}, 1.0, None
+    if name == "greedy":
+        script = _ahead_plain(cfg)
+    elif name == "temperature":
+        script = _ahead_plain(cfg, SamplingParams(temperature=0.9,
+                                                  seed=11))
+    elif name == "top_k_top_p":
+        script = _ahead_plain(cfg, SamplingParams(
+            temperature=1.1, top_k=20, top_p=0.8, seed=5))
+    elif name == "eos_mid_stream":
+        # the third greedy token of the first prompt ends its stream:
+        # no count foresees it, so one more row of it is in flight
+        probe = _make_engine(cfg, params).generate(
+            [_prompt(9, cfg.vocab_size)], max_new_tokens=3)[0]
+        assert probe[2] not in probe[:2]
+        script = _ahead_plain(cfg, eos_token=probe[2])
+
+        def check(ahead, level, streams):
+            assert [t for t, *_ in streams[0]] == probe
+            assert streams[0][-1][1]            # done at the eos token
+    elif name in ("max_new_1", "max_new_2"):
+        n = int(name[-1])
+
+        def submit(engine):
+            for seed in range(3):
+                engine.submit(_prompt(6 + seed, cfg.vocab_size, seed),
+                              max_new_tokens=n)
+        script = _at_start(submit)
+
+        def check(ahead, level, streams):
+            assert all(len(s) == n and s[-1][1] for s in streams.values())
+    elif name in ("cancel_in_flight", "deadline_in_flight"):
+        state = {}
+
+        def script(engine, tick, streams):
+            if tick == 0:
+                engine.submit(_prompt(9, cfg.vocab_size),
+                              max_new_tokens=12, deadline_s=3600.0)
+                engine.submit(_prompt(7, cfg.vocab_size, seed=1),
+                              max_new_tokens=8)
+            if len(streams.get(0, ())) == 3 and not state.get(engine):
+                state[engine] = True
+                req = engine._requests[0]
+                # the engine as it runs has the fourth token in flight
+                state["in_flight", bool(engine._flight)] = req.in_flight
+                if name == "cancel_in_flight":
+                    engine.cancel(0)
+                else:
+                    req.submitted_ts -= 7200.0
+
+        def check(ahead, level, streams):
+            assert state["in_flight", True] == 1
+            assert state["in_flight", False] == 0
+            tail = streams[0][3:]
+            if name == "cancel_in_flight":
+                assert tail == []
+            else:
+                assert [(t, d, e) for t, d, _lp, e in tail] == [
+                    (-1, True, "DeadlineExceededError")]
+            assert len(streams[1]) == 8
+    elif name in ("prefix_hits", "prefix_hits_int8"):
+        kwargs = {"prefix": True, "slots": 3}
+        if name.endswith("int8"):
+            kwargs["kv_dtype"] = "int8"
+        shared = _prompt(32, cfg.vocab_size, seed=4)
+
+        def script(engine, tick, streams):
+            if tick == 0:
+                engine.submit(shared + [3, 1, 4], max_new_tokens=5)
+            if tick == 2:       # hits the two pages registered above,
+                # and two admitted in one tick share them as well
+                engine.submit(shared + [1, 5, 9, 2], max_new_tokens=6)
+                engine.submit(shared + [6, 5], max_new_tokens=4)
+
+        def check(ahead, level, streams):
+            for engine in (ahead, level):
+                assert engine.scheduler.prefix_requests_hit == 2
+    elif name == "int8_cache":
+        kwargs = {"kv_dtype": "int8"}
+        script = _ahead_plain(cfg)
+    elif name == "lora_bank":
+        import jax
+        from ray_tpu.adapters import LoraConfig, init_adapter
+        lcfg = LoraConfig(enabled=True, rank=4, scale=0.5, cache_slots=3)
+        kwargs = {"lora": lcfg, "slots": 3}
+        adapter = init_adapter(cfg, lcfg, jax.random.PRNGKey(11),
+                               random_b=True)
+
+        def submit(engine):
+            engine.load_adapter("t1", adapter, scale=0.5)
+            engine.submit(_prompt(9, cfg.vocab_size), max_new_tokens=6,
+                          sampling=SamplingParams(model_id="t1"))
+            engine.submit(_prompt(9, cfg.vocab_size), max_new_tokens=6)
+            engine.submit(_prompt(12, cfg.vocab_size, seed=2),
+                          max_new_tokens=4,
+                          sampling=SamplingParams(model_id="t1"))
+        script = _at_start(submit)
+
+        def check(ahead, level, streams):
+            # the tenant's tokens are its own, not the base model's
+            assert ([t for t, *_ in streams[0]]
+                    != [t for t, *_ in streams[1]])
+    elif name == "hold_pages_export":
+        handoffs = {}
+
+        def script(engine, tick, streams):
+            if tick == 0:
+                engine.submit(_prompt(20, cfg.vocab_size),
+                              max_new_tokens=1, hold_pages=True)
+                engine.submit(_prompt(9, cfg.vocab_size, seed=1),
+                              max_new_tokens=3, hold_pages=True)
+                engine.submit(_prompt(7, cfg.vocab_size, seed=2),
+                              max_new_tokens=6)
+            for rid in (0, 1):
+                if rid in engine._held:     # as soon as it has retired
+                    handoffs[engine, rid] = engine.export_request(rid)
+
+        def check(ahead, level, streams):
+            for rid in (0, 1):
+                a, b = handoffs[ahead, rid], handoffs[level, rid]
+                assert (a.context, a.next_token, a.next_logprob) == (
+                    b.context, b.next_token, b.next_logprob)
+                np.testing.assert_array_equal(a.k, b.k)
+                np.testing.assert_array_equal(a.v, b.v)
+            assert ahead.stats()["exports"] == 2
+    elif name == "set_params_between_ticks":
+        import jax
+        new = jax.tree.map(lambda a: np.asarray(a) * 1.05, params)
+
+        def script(engine, tick, streams):
+            if tick == 0:
+                engine.submit(_prompt(9, cfg.vocab_size),
+                              max_new_tokens=8)
+                engine.submit(_prompt(5, cfg.vocab_size, seed=1),
+                              max_new_tokens=6)
+            if tick == 3:       # the decode in flight ran under the old
+                engine.set_params(new, version=7)
+
+        def check(ahead, level, streams):
+            unswapped, _ = _pump(_make_engine(cfg, params), False,
+                                 lambda e, t, s: script(e, t, s)
+                                 if t == 0 else None)
+            assert streams[0][:4] == unswapped[0][:4]
+            assert streams[0] != unswapped[0]
+            assert ahead.param_version == 7
+    elif name == "speculating_slot":
+        # a slot that may draft makes its ticks synchronous: exact, and
+        # the counter shows it; the plain request outlives it, and the
+        # engine runs ahead again
+        share = None
+
+        def submit(engine):
+            motif = _prompt(6, cfg.vocab_size, seed=3) * 4
+            engine.submit(motif, max_new_tokens=6,
+                          sampling=SamplingParams(spec=True, spec_k=4))
+            engine.submit(_prompt(9, cfg.vocab_size), max_new_tokens=16)
+        script = _at_start(submit)
+
+        def check(ahead, level, streams):
+            solo = _make_engine(cfg, params).generate(
+                [_prompt(9, cfg.vocab_size)], max_new_tokens=16)[0]
+            assert [t for t, *_ in streams[1]] == solo
+            seen = ahead.telemetry.summary()["decode"]
+            assert 0.0 < seen["ahead_share"] < 1.0
+            assert ahead.stats()["spec"]["proposed"] > 0
+    elif name == "decode_fault_then_resume":
+        # the first decode's dispatch fails after the prefills of its
+        # tick were dispatched: their first tokens, never fetched, are
+        # still the next decode's input
+        from ray_tpu.util import chaos
+        plain = _ahead_plain(cfg)
+
+        def script(engine, tick, streams):
+            if tick == 0:
+                chaos.install_faults("infer.decode@1")
+            plain(engine, tick, streams)
+
+        def check(ahead, level, streams):
+            solo = _make_engine(cfg, params).generate(
+                [_prompt(9, cfg.vocab_size)], max_new_tokens=7)[0]
+            assert [t for t, *_ in streams[0]] == solo
+    elif name == "debug_logits":
+        kwargs = {"debug_logits": True}
+        script = _ahead_plain(cfg)
+
+        def check(ahead, level, streams):
+            # one row per generated token, the row that produced it
+            for rid, stream in streams.items():
+                rows = np.stack(ahead.logits_trace[rid])
+                assert len(rows) == len(stream)
+                np.testing.assert_array_equal(
+                    rows, np.stack(level.logits_trace[rid]))
+                assert list(rows.argmax(-1)) == [t for t, *_ in stream]
+            prompt = _prompt(9, cfg.vocab_size)
+            np.testing.assert_allclose(
+                np.stack(ahead.logits_trace[0]),
+                _teacher_forced_rows(cfg, params, prompt,
+                                     [t for t, *_ in streams[0]]),
+                rtol=2e-4, atol=2e-4)
+    else:
+        raise KeyError(name)
+    return kwargs, script, share, check
+
+
+_AHEAD_CASES = (
+    "greedy", "temperature", "top_k_top_p", "eos_mid_stream",
+    "max_new_1", "max_new_2", "cancel_in_flight", "deadline_in_flight",
+    "prefix_hits", "prefix_hits_int8", "int8_cache", "lora_bank",
+    "hold_pages_export", "set_params_between_ticks", "speculating_slot",
+    "decode_fault_then_resume", "debug_logits")
+
+
+@pytest.mark.parametrize("case", _AHEAD_CASES)
+def test_running_ahead_matches_the_synchronous_tick(tiny_f32, case):
+    cfg, params = tiny_f32
+    kwargs, script, share, check = _ahead_case(case, cfg, params)
+    ahead = _make_engine(cfg, params, telemetry=True, **kwargs)
+    level = _make_engine(cfg, params, telemetry=True, **kwargs)
+    try:
+        got, in_flight = _pump(ahead, False, script)
+        want, never = _pump(level, True, script)
+    finally:
+        from ray_tpu.util import chaos
+        chaos.clear_faults()
+    assert never == 0 and (in_flight > 0 or case == "max_new_1")
+    assert got and got == want
+    if check is not None:
+        check(ahead, level, got)
+    for engine in (ahead, level):
+        assert engine.leak_free() and not engine.has_work()
+        assert not engine._flight and not engine._backlog
+        sched = engine.scheduler
+        assert not sched.active and not sched.waiting
+        assert len(sched.free_slots) == engine.slots
+        assert (sched.allocator.free_count      # idle pages count
+                == sched.allocator.num_pages - 1)
+        assert all(r.in_flight == 0 for r in engine._requests.values())
+    decode = ahead.telemetry.summary().get("decode")
+    if share is not None and decode is not None:
+        assert decode["ahead_share"] == share
+        assert decode["dispatches"] == in_flight

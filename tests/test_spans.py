@@ -123,8 +123,20 @@ def test_engine_tick_opens_every_span_nested_on_one_thread(engine_trace):
 
     assert parents("infer/admit") == {"infer/step"}
     assert parents("infer/deliver") == {"infer/step"}
-    assert parents("infer/sample") == {"infer/prefill", "infer/decode",
-                                       "infer/prefill_cached"}
+    # a fetch is no part of a dispatch: the tick fetches what was
+    # dispatched before its own decode, after dispatching that decode
+    assert parents("infer/sample") == {"infer/step"}
+    decodes = [e for e in spans if e.name == "infer/decode"]
+    assert {d.stats["ahead"] for d in decodes} == {1}
+    for step, nxt in zip(steps, steps[1:]):
+        inside = [e for e in spans if e.name in ("infer/decode",
+                                                 "infer/sample")
+                  and step.start_ps <= e.start_ps < step.end_ps]
+        names_in = [e.name for e in sorted(inside,
+                                           key=lambda e: e.start_ps)]
+        if "infer/decode" in names_in:
+            # nothing is fetched before the tick's decode is dispatched
+            assert names_in[0] == "infer/decode", names_in
     assert parents("infer/compile") <= {"infer/prefill", "infer/decode",
                                         "infer/prefill_cached"}
 
@@ -158,8 +170,13 @@ def test_engine_spans_carry_counts_and_the_trace_id(engine_trace):
     from ray_tpu.telemetry import trace as trace_mod
     ring = [s for s in trace_mod.spans_for(engine_trace["trace_id"])
             if s["name"] == "prefill"]
+    # the ring's prefill: its dispatch and the wait for its token, the
+    # first fetch after it on the thread
+    fetch = min((s for s in by["infer/sample"]
+                 if s.start_ps >= cold.end_ps), key=lambda s: s.start_ps)
+    assert fetch.stats["rows"] == 1
     assert ring and ring[0]["dur"] == pytest.approx(
-        cold.dur_ps / 1e12, rel=0.2, abs=2e-3)
+        (cold.dur_ps + fetch.dur_ps) / 1e12, rel=0.2, abs=2e-3)
     assert {d.stats["active"] for d in by["infer/decode"]} <= {1, 2}
     assert {s.stats["rows"] for s in by["infer/sample"]} == {1, 2}
     kinds = {(c.stats["kind"], c.stats["bucket"])
