@@ -370,18 +370,25 @@ def kernel_parity(config: dict) -> dict:
             row("norm/fused_epilogue prefill fwd", [b, K, d],
                 {"r": _rel_err(r, r_ref), "y": _rel_err(y, y_ref)},
                 {"r": TOL_OUT, "y": TOL_OUT})
-    ctx = -(-cfg.max_seq // icfg.page_size) * icfg.page_size
-    if A.decode_uses_pallas(ctx, D):
-        slots = icfg.slots
-        q = rand((slots, H, D))
-        k, v = rand((slots, ctx, H, D)), rand((slots, ctx, H, D))
-        lengths = jax.random.randint(next(keys), (slots,), 1, ctx + 1)
-        o = jax.jit(lambda *x: A.decode_attention(*x, impl="pallas"))(
-            q, k, v, lengths)
-        o_ref = jax.jit(lambda *x: A.decode_attention(*x, impl="xla"))(
-            up(q), up(k), up(v), lengths)
-        row("attn/decode_pallas fwd", [slots, ctx, H, D],
+    # decode attention over the paged pool at the two serve cells' own
+    # geometry (benchmark/cells/serve-*.json: slots, pages, heads), two
+    # layers of it: every slot's pages through a shuffled table, ragged
+    # lengths, the second layer
+    page, mp = icfg.page_size, -(-cfg.max_seq // icfg.page_size)
+    cells = ((64, 513, 20), (128, 1025, 12))
+    for slots, pages, heads in cells if A.decode_uses_pallas(D, page) else ():
+        q = rand((slots, heads, D))
+        k, v = (rand((2, pages, heads, D, page)) for _ in range(2))
+        table = 1 + jax.random.permutation(
+            next(keys), pages - 1)[:slots * mp].reshape(slots, mp)
+        lengths = jax.random.randint(next(keys), (slots,), 1, mp * page + 1)
+        o = jax.jit(lambda *x: A.decode_attention(*x, 1, impl="pallas"))(
+            q, k, v, lengths, table)
+        o_ref = jax.jit(lambda *x: A.decode_attention(*x, 1, impl="xla"))(
+            up(q), up(k), up(v), lengths, table)
+        row("attn/decode_pallas fwd", [slots, pages, heads, D, page],
             {"o": _rel_err(o, o_ref)}, {"o": TOL_OUT})
+        del q, k, v
 
     return {"device": device, "kernels": rows,
             "tolerance": {"out": TOL_OUT, "grad": TOL_GRAD,

@@ -39,7 +39,7 @@ class InferConfig:
       (block-scaled int8, one f32 scale per (position, head) lane
       vector stored in per-page scale arrays; keys/values quantize
       post-RoPE on write and dequantize inside the decode-attention
-      context strips).  ``int8`` roughly halves ``KVCache.bytes`` per
+      page blocks).  ``int8`` roughly halves ``KVCache.bytes`` per
       page — i.e. ~2x the decode slots per HBM byte — at a bounded
       logits error (parity-tested against the ``model``-dtype cache).
       Default stays ``model`` until an on-chip A/B.

@@ -61,15 +61,15 @@ garbage page, then each speculating slot verifies.
 
 The steps themselves derive from the training model: ``embed`` +
 ``layer_apply`` with a KV-cache hook threaded through (post-RoPE keys
-written to the paged cache, decode attention over the gathered pages
-via ``ops/attention.py:decode_attention``), plus the model's own final
-norm / tied head so cached decode logits match teacher-forced
+written to the paged cache, decode attention over the live pages where
+they lie via ``ops/attention.py:decode_attention``), plus the model's
+own final norm / tied head so cached decode logits match teacher-forced
 ``forward`` logits bit-for-bit-modulo-dtype (parity-tested in
-``tests/test_inference.py``).  The stacked ``[L, pages, page, H, D]``
-cache arrays are donated through every step and carried whole through
-the layer scan: the hook rewrites only the pages the new tokens land
-in, at ``(layer, page)``, and reads only through the ``(layer, page)``
-gather, so no step materialises a layer's pool and steady-state decode
+``tests/test_inference.py``).  The stacked cache arrays (their format
+is ``kv_cache.py``'s) are donated through every step and carried whole
+through the layer scan: the hook rewrites only the pages the new tokens
+land in, at ``(layer, page)``, and reads only pages named by ``(layer,
+page)``, so no step materialises a layer's pool and steady-state decode
 allocates nothing (the lowered structure is asserted in
 ``tests/test_inference.py``, the TPU compiler's in
 ``tests/test_tpu_aot.py``).
@@ -1047,8 +1047,8 @@ class InferenceEngine:
             "kv_dtype": self.kv_dtype,
             # what decode attention dispatches to at this geometry
             "decode_impl": "pallas" if decode_uses_pallas(
-                self.max_pages_per_slot * self.page_size,
-                self.cfg.head_dim) else "xla",
+                self.cfg.head_dim, self.page_size,
+                quantized=self.cache.quantized) else "xla",
             "kv_bytes_per_slot": self.cache.bytes_per_slot(
                 self.max_pages_per_slot),
             "max_queue": self.max_queue,
@@ -1552,8 +1552,12 @@ class InferenceEngine:
             # positions a verify forward is about to fill, and their
             # sampled outputs are never delivered
             page_table[dead, :] = kvc.GARBAGE_PAGE
+        # the pages this decode's attention reads: each row's context
+        # with the token it writes, by the host's own count
+        pages = int((lengths[[slot for slot, _req in rows]]
+                     // self.page_size + 1).sum())
         with tracing.span("infer/decode", active=len(rows),
-                          ahead=int(ahead)) as sp:
+                          ahead=int(ahead), pages=pages) as sp:
             logits = self._run_step(("decode",), reqs,
                                     self._token_input(rows), lengths,
                                     page_table)
@@ -1651,7 +1655,9 @@ class InferenceEngine:
         if self.telemetry.enabled:
             self.telemetry.record_decode(
                 wall, active=delivered,
-                ahead=bool(sp.attributes["ahead"]))
+                ahead=bool(sp.attributes["ahead"]),
+                pages_read=sp.attributes["pages"],
+                pages_table=self.slots * self.max_pages_per_slot)
 
     def _level(self) -> None:
         """Bring the host level with the device from outside a tick:
@@ -1919,10 +1925,11 @@ class InferenceEngine:
         each layer hands ``layer_apply`` the opaque ``cache = (layer
         index, caches)``, which round-trips to ``attn_hook``; the hook
         writes the new tokens into their pages at ``(layer, page)``
-        (``kv_cache.append``), reads through the ``(layer, page)``
-        gather (``kv_cache.context``), and returns the updated stacked
-        arrays for the carry — so only the touched and the gathered
-        pages cross HBM.
+        (``kv_cache.append``), attends over the pool in place
+        (``kv_cache.attend``; a cached-suffix prefill over one slot's
+        gathered pages, ``kv_cache.context_dense``), and returns the
+        updated stacked arrays for the carry — so only the touched
+        and the live pages cross HBM.
 
         ``lora_bank``/``lora_ids`` (r25 multi-tenant): bank factors are
         stacked ``[N, L, ...]`` — layer axis 1 — sliced per scan step;
@@ -2003,7 +2010,7 @@ class InferenceEngine:
 
         The benchmark finds these executables and their operations by
         name (``jit_prefill*``, ``jit_decode``, ``gpt/attn/gather``,
-        ``gpt/attn/reshape``, ``attn/decode_pallas``): the traced
+        ``attn/decode_pallas``): the traced
         function carries the kind's name and no scope is added here."""
         cfg = self.cfg
         lora_on = self.lora_cfg is not None
@@ -2021,12 +2028,9 @@ class InferenceEngine:
                 tokens = tokens[:, None]
 
                 def attn_hook(q, k, v, cache):
-                    from ray_tpu.ops.attention import decode_attention
                     cache = kvc.append(kvc.write_decode, cache, k[:, 0],
                                        v[:, 0], page_table, lengths)
-                    kctx, vctx, scales = kvc.context(cache, page_table)
-                    o = decode_attention(q[:, 0], kctx, vctx,
-                                         lengths + 1, **scales)
+                    o = kvc.attend(q[:, 0], cache, page_table, lengths + 1)
                     return o[:, None], cache[1]
             elif kind == "prefill":
                 tokens, last, page_row = args

@@ -1,9 +1,11 @@
 """Paged KV cache for continuous-batching decode.
 
 The cache is two preallocated device arrays per model —
-``[n_layers, pages, page_size, kv_heads, head_dim]`` K and V — plus a
-*host-side* page table: each decode slot owns a row of page indices
-covering its reserved context.  Sequences of wildly different lengths
+``[n_layers, pages, kv_heads, head_dim, page_size]`` K and V, the page
+offset minor (a page of one layer is ``kv_heads`` lane-dense
+``[head_dim, page_size]`` tiles: the block the decode kernel reads in
+place) — plus a *host-side* page table: each decode slot owns a row of
+page indices covering its reserved context.  Sequences of wildly different lengths
 then share one fixed allocation (the vLLM paged-attention idea, here
 XLA-functional): admission reserves ``ceil((prompt + max_new) / page)``
 pages from a free list, retirement returns them, and the device arrays
@@ -18,7 +20,8 @@ masked by per-slot lengths in ``decode_attention``.
 
 Device-side update/gather helpers are plain functional jnp ops (scatter
 via ``.at[]``, gather via advanced indexing) so they trace into the
-engine's compiled steps; the host-side :class:`PageAllocator` owns the
+engine's compiled steps, and a decode attends over the pool where it
+lies (:func:`attend`); the host-side :class:`PageAllocator` owns the
 refcounts, free structures and the leak invariants
 (``tests/test_inference.py``).
 
@@ -48,11 +51,11 @@ as prefix hits and the handoff moves no contents at all.
 ``kv_dtype="int8"`` stores the K/V arrays block-scale-quantized
 (``ray_tpu.quant``): codes in int8, one f32 scale per (page, position,
 head) lane vector riding in per-page scale arrays
-``[n_layers, pages, page_size, kv_heads]``.  The write/gather helpers
-are shape-generic (they address ``[L, P, page_size, ...]`` storage by
+``[n_layers, pages, kv_heads, page_size]``.  The write/gather helpers
+are shape-generic (they address ``[L, P, ..., page_size]`` storage by
 (layer, page)), so the same scatter/gather moves codes and scales;
 :func:`append` quantizes post-RoPE on write and ``decode_attention``
-dequantizes inside its context strips (:func:`context` hands it the
+dequantizes inside its page blocks (:func:`attend` hands it the
 scales).  At head_dim 64 that is 68 bytes per cached
 vector (64 codes + one f32 scale) vs 128 in bf16 — :meth:`KVCache.bytes`
 counts both arrays, so the ~2x capacity-per-HBM-byte claim is
@@ -87,6 +90,9 @@ import numpy as np
 import jax.numpy as jnp
 
 GARBAGE_PAGE = 0
+# the cache's arrays in :attr:`KVCache.state` order, as a handoff and
+# an :func:`export_pages` gather name them
+_NAMES = ("k", "v", "k_scale", "v_scale")
 
 
 class HandoffContentMissing(RuntimeError):
@@ -131,10 +137,14 @@ class KVHandoff:
     Shapes: ``k``/``v`` are ``[n_layers, n_pages, page_size, kv_heads,
     head_dim]`` in the cache's storage dtype; ``k_scale``/``v_scale``
     (int8 caches only) are ``[n_layers, n_pages, page_size, kv_heads]``
-    f32.  Page order matches :func:`pages_needed` over ``context``:
-    full pages first, then the partial tail (whose positions past
-    ``len(context) % page_size`` are garbage the decode attention
-    masks, exactly as on the exporter).
+    f32.  That is the format of everything that leaves this file (a
+    handoff, a spill entry), whatever the device pool's own layout:
+    :func:`export_pages` and the installers convert at the boundary,
+    so contents written by an older replica still install.  Page order
+    matches :func:`pages_needed` over ``context``: full pages first,
+    then the partial tail (whose positions past ``len(context) %
+    page_size`` are garbage the decode attention masks, exactly as on
+    the exporter).
     """
 
     context: List[int]              # token ids whose K/V are cached
@@ -230,12 +240,9 @@ def export_pages(cache: "KVCache", pages: Sequence[int]
     in page order.  One gather per array (a DMA on a real device; the
     in-place object-store put is the on-chip follow-up)."""
     idx = np.asarray(list(pages), np.int32)
-    out = {"k": np.asarray(cache.k[:, idx]),
-           "v": np.asarray(cache.v[:, idx])}
-    if cache.quantized:
-        out["k_scale"] = np.asarray(cache.k_scale[:, idx])
-        out["v_scale"] = np.asarray(cache.v_scale[:, idx])
-    return out
+    return {name: np.ascontiguousarray(
+                np.moveaxis(np.asarray(a[:, idx]), -1, 2))
+            for name, a in zip(_NAMES, cache.state)}
 
 
 def import_pages(cache: "KVCache", pages: Sequence[int],
@@ -249,13 +256,9 @@ def import_pages(cache: "KVCache", pages: Sequence[int],
         return
     idx = np.asarray(list(pages), np.int32)
     sel = np.asarray(list(sel), np.int64)
-    cache.k = cache.k.at[:, idx].set(handoff.k[:, sel])
-    cache.v = cache.v.at[:, idx].set(handoff.v[:, sel])
-    if cache.quantized:
-        cache.k_scale = cache.k_scale.at[:, idx].set(
-            handoff.k_scale[:, sel])
-        cache.v_scale = cache.v_scale.at[:, idx].set(
-            handoff.v_scale[:, sel])
+    cache.state = tuple(
+        a.at[:, idx].set(np.moveaxis(getattr(handoff, name)[:, sel], 2, -1))
+        for name, a in zip(_NAMES, cache.state))
 
 
 SPILL_DTYPES = ("int8", "model")
@@ -306,8 +309,8 @@ def spill_entry_matches(cache: "KVCache",
     """Geometry guard before an install: a fleet-shared store entry
     written by a different-geometry engine must read as a miss, never
     a shape error mid-admission."""
-    want = tuple(cache.k.shape[:1]) + tuple(cache.k.shape[2:])
-    return tuple(entry["k"].shape) == want
+    L, _, H, D, page_size = cache.k.shape
+    return tuple(entry["k"].shape) == (L, page_size, H, D)
 
 
 def install_spill_page(cache: "KVCache", page: int,
@@ -326,19 +329,16 @@ def install_spill_page(cache: "KVCache", page: int,
         else:
             k, ks = _quantize_page(entry["k"])
             v, vs = _quantize_page(entry["v"])
-        cache.k = cache.k.at[:, page].set(k)
-        cache.v = cache.v.at[:, page].set(v)
-        cache.k_scale = cache.k_scale.at[:, page].set(ks)
-        cache.v_scale = cache.v_scale.at[:, page].set(vs)
-        return
-    if entry["fmt"] == "int8":
-        k = entry["k"].astype(np.float32) * entry["k_scale"][..., None]
-        v = entry["v"].astype(np.float32) * entry["v_scale"][..., None]
+        rows = (k, v, ks, vs)
+    elif entry["fmt"] == "int8":
+        rows = (entry["k"].astype(np.float32) * entry["k_scale"][..., None],
+                entry["v"].astype(np.float32) * entry["v_scale"][..., None])
     else:
-        k, v = entry["k"], entry["v"]
-    dt = cache.k.dtype
-    cache.k = cache.k.at[:, page].set(jnp.asarray(k, dt))
-    cache.v = cache.v.at[:, page].set(jnp.asarray(v, dt))
+        rows = (entry["k"], entry["v"])
+    # an entry's page is [L, page_size, ...]; the pool's [L, ..., page_size]
+    cache.state = tuple(
+        a.at[:, page].set(jnp.asarray(np.moveaxis(r, 1, -1), a.dtype))
+        for a, r in zip(cache.state, rows))
 
 
 class HostPagePool:
@@ -832,8 +832,14 @@ class PageAllocator:
 class KVCache:
     """The preallocated paged K/V arrays plus their static geometry.
 
+    K and V are ``[n_layers, pages, kv_heads, head_dim, page_size]``:
+    the page offset is the minor dimension, which is how a TPU lays a
+    head_dim-64 cache out whatever it is declared as (head_dim on the
+    lanes would waste half of them), and declared so the decode kernel
+    reads a page's ``[H, D, page_size]`` block where it lies.
     ``kv_dtype``: ``"model"`` stores ``dtype`` K/V; ``"int8"`` stores
-    int8 codes plus per-(page, position, head) f32 scale arrays.  The
+    int8 codes plus per-(page, head, position) f32 scale arrays
+    ``[n_layers, pages, kv_heads, page_size]``.  The
     engine threads :attr:`state` — ``(k, v)`` or
     ``(k, v, k_scale, v_scale)`` — through its donated compiled steps,
     so decode allocates nothing in either mode.
@@ -849,7 +855,7 @@ class KVCache:
         self.page_size = page_size
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
-        shape = (n_layers, num_pages, page_size, n_heads, head_dim)
+        shape = (n_layers, num_pages, n_heads, head_dim, page_size)
         store = jnp.int8 if self.quantized else dtype
         self.k = jnp.zeros(shape, store)
         self.v = jnp.zeros(shape, store)
@@ -858,8 +864,8 @@ class KVCache:
             # but writes routed to the garbage page overwrite them with
             # real values — its harmlessness rests on decode_attention
             # masking positions >= length, same as the unquantized cache
-            self.k_scale = jnp.zeros(shape[:-1], jnp.float32)
-            self.v_scale = jnp.zeros(shape[:-1], jnp.float32)
+            self.k_scale = jnp.zeros(shape[:3] + shape[4:], jnp.float32)
+            self.v_scale = jnp.zeros(shape[:3] + shape[4:], jnp.float32)
 
     @property
     def dtype(self):
@@ -885,53 +891,43 @@ class KVCache:
         """True cache footprint — K/V *and* (when quantized) the scale
         arrays; the r10 figure omitted nothing only because there were
         no scales yet."""
-        total = 2 * self.k.size * self.k.dtype.itemsize
-        if self.quantized:
-            total += 2 * self.k_scale.size * self.k_scale.dtype.itemsize
-        return total
+        return sum(a.size * a.dtype.itemsize for a in self.state)
 
     def bytes_per_slot(self, pages_per_slot: int) -> int:
         """HBM bytes one fully-reserved decode slot pins (codes +
         scales across all layers) — the capacity-planning figure the
         telemetry summary reports."""
-        per_page = (2 * self.k.shape[0] * self.page_size
-                    * self.k.shape[3] * self.k.shape[4]
-                    * self.k.dtype.itemsize)
-        if self.quantized:
-            per_page += (2 * self.k.shape[0] * self.page_size
-                         * self.k.shape[3]
-                         * self.k_scale.dtype.itemsize)
-        return pages_per_slot * per_page
+        return pages_per_slot * (self.bytes // self.num_pages)
 
 
 def _blend_pages(pages, layer, page, rows, hit):
     """Write ``rows`` where ``hit`` into pages ``page`` of ``layer`` and
     return the updated stacked array.
 
-    pages: [L, P, page_size, *rest]; page: [n] int32; rows:
-    [n, page_size, *rest]; hit: [n, page_size] bool.  Read the n pages,
-    lay the new rows over them, scatter the n *whole* pages back at
-    ``(layer, page)``: only those pages cross HBM, never a layer's
-    pool.  Whole pages and not single rows because of how a TPU lays
-    the array out: its default layout puts the page offset on the
-    lanes (head_dim 64 would waste half of them), where a row is one
-    lane of every tile of its page.  XLA answers a row scatter by
-    re-laying the whole cache out, head_dim minor and padded 2.7x, on
-    the way in and back on the way out of every step; the same
-    compiler keeps a whole-page scatter, like the page gather, in
-    place in the layout the array came in
-    (``tests/test_tpu_aot.py``)."""
-    hit = hit.reshape(hit.shape + (1,) * (rows.ndim - 2))
+    pages: [L, P, *rest, page_size]; page: [n] int32; rows:
+    [n, page_size, *rest] (or [n, 1, *rest], one row laid wherever
+    ``hit``); hit: [n, page_size] bool.  Read the n pages, lay the new
+    rows over them, scatter the n *whole* pages back at ``(layer,
+    page)``: only those pages cross HBM, never a layer's pool, and the
+    rows are turned offset-minor n pages at a time, never a pool.
+    Whole pages and not single rows because the page offset is on the
+    lanes, where a row is one lane of every tile of its page: XLA
+    answers a row scatter by re-laying the whole cache out on the way
+    in and back on the way out of every step; the same compiler keeps
+    a whole-page scatter, like the page gather, in place in the layout
+    the array came in (``tests/test_tpu_aot.py``)."""
+    hit = hit.reshape(hit.shape[:1] + (1,) * (rows.ndim - 2)
+                      + hit.shape[1:])
     old = pages[layer, page]
     return pages.at[layer, page].set(
-        jnp.where(hit, rows.astype(pages.dtype), old))
+        jnp.where(hit, jnp.moveaxis(rows, 1, -1).astype(pages.dtype), old))
 
 
 def write_prefill(pages, new, layer, page_row):
     """Write a prompt's K (or V) into one slot's pages of one layer —
     the cold (start-0, whole-bucket) case of :func:`write_prefill_at`.
 
-    pages: [L, P, page_size, H, D] (the whole stacked array); new:
+    pages: [L, P, H, D, page_size] (the whole stacked array); new:
     [S, H, D] (bucket-padded — with ``valid_len = S`` tail positions
     land in whatever ``page_row`` maps them to, the garbage page for
     unreserved tail entries); layer: traced scalar; page_row:
@@ -945,7 +941,7 @@ def write_prefill_at(pages, new, layer, page_row, start, valid_len):
     cached-context prefill: positions below ``start`` are prefix-cache
     hits that must not be touched).
 
-    pages: [L, P, page_size, *rest] (the whole stacked array — the
+    pages: [L, P, *rest, page_size] (the whole stacked array — the
     layer is one more coordinate of the write, never a slice); new:
     [S, *rest] (bucket-padded suffix); layer/start/valid_len: traced
     scalars; page_row: [max_pages] int32.  The suffix touches at most
@@ -958,7 +954,7 @@ def write_prefill_at(pages, new, layer, page_row, start, valid_len):
     slot's reserved pages (start + bucket > max_pages * page_size),
     where the cold prefill's garbage-padded ``page_row`` tail no longer
     covers it.  Returns the updated stacked array."""
-    S, page_size = new.shape[0], pages.shape[2]
+    S, page_size = new.shape[0], pages.shape[-1]
     n = pages_needed(S, page_size) + 1
     cand = start // page_size + jnp.arange(n)   # indices into page_row
     # row r of candidate page j holds suffix row idx[j, r]
@@ -976,33 +972,17 @@ def write_prefill_at(pages, new, layer, page_row, start, valid_len):
 def write_decode(pages, new, layer, page_table, lengths):
     """Write one new token per slot into its page of one layer.
 
-    pages: [L, P, page_size, H, D] (the whole stacked array); new:
+    pages: [L, P, H, D, page_size] (the whole stacked array); new:
     [B, H, D]; layer: traced scalar; page_table: [B, max_pages] int32;
     lengths: [B] int32 — the token's absolute position (inactive slots
     point at the garbage page).  Each slot's tail page is rewritten
     whole with the token laid over it (:func:`_blend_pages`).  Returns
     the updated stacked array."""
-    page_size = pages.shape[2]
+    page_size = pages.shape[-1]
     page = jnp.take_along_axis(page_table,
                                (lengths // page_size)[:, None], 1)[:, 0]
     hit = jnp.arange(page_size)[None, :] == (lengths % page_size)[:, None]
     return _blend_pages(pages, layer, page, new[:, None], hit)
-
-
-def gather_pages(pages, layer, page_table):
-    """[L, P, page_size, *rest] x layer x [B, max_pages]
-    -> [B, max_pages*page, *rest].
-
-    One gather indexed by (layer, page) on the whole stacked array — no
-    slice of the layer first.  The padded per-slot context the decode
-    attention masks by length — gather-then-attend (indexing pages
-    *inside* the kernel is the natural next step, ROADMAP S2).
-    Shape-generic past the page dims, so K/V codes ([..., H, D]) and
-    their scale arrays ([..., H]) ride the same gather."""
-    B, max_pages = page_table.shape
-    ps = pages.shape[2]
-    ctx = pages[layer, page_table]      # [B, max_pages, ps, *rest]
-    return ctx.reshape((B, max_pages * ps) + pages.shape[3:])
 
 
 # ------------------------------------------------- what a step needs --
@@ -1037,27 +1017,39 @@ def append(write, cache, k, v, *where):
                         for a, r in zip(arrays, rows))
 
 
-def context(cache, page_table):
-    """Gather one layer's pages for ``page_table`` [B, max_pages] ->
-    ``(K, V, scales)``: K and V ``[B, max_pages * page, H, D]`` as
-    stored, and ``scales`` the keyword arguments ``decode_attention``
-    dequantises an int8 context with (empty for a model-dtype
-    cache)."""
+def attend(q, cache, page_table, lengths):
+    """One query row per slot, ``q`` [B, H, D], over the first
+    ``lengths`` [B] positions of each slot's pages (``page_table``
+    [B, max_pages]) in one layer of the cache -> [B, H, D].  The pool
+    is read where it lies: ``ops/attention.py:decode_attention`` gets
+    the whole stacked arrays, the layer and the table (an int8 cache's
+    scales with them), and no context is gathered.  Rows of no
+    sequence come back as zeros."""
+    from ray_tpu.ops.attention import decode_attention
     layer, arrays = cache
-    k, v, *scales = (gather_pages(a, layer, page_table) for a in arrays)
-    return k, v, dict(zip(("k_scale", "v_scale"), scales))
+    k, v, *scales = arrays
+    # a row whose table starts at the garbage page is no sequence (a
+    # free slot, or a held one sitting this decode out): nothing of
+    # the pool is read for it
+    lengths = jnp.where(page_table[:, 0] == GARBAGE_PAGE, 0, lengths)
+    return decode_attention(q, k, v, lengths, page_table, layer,
+                            **dict(zip(_NAMES[2:], scales)))
 
 
 def context_dense(cache, page_table, dtype):
-    """:func:`context` with an int8 cache's K and V dequantised to
-    ``dtype`` -> ``(K, V)`` (a model-dtype cache's come as stored)."""
-    k, v, scales = context(cache, page_table)
+    """Gather one layer's pages for ``page_table`` [B, max_pages] ->
+    ``(K, V)``, each ``[B, max_pages * page, H, D]``: a model-dtype
+    cache's as stored, an int8 cache's dequantised to ``dtype``.  What
+    a cached-suffix prefill and a verify attend over (one slot's row);
+    a decode reads the pool in place (:func:`attend`)."""
+    layer, arrays = cache
+    k, v, *scales = (a[layer, page_table] for a in arrays)
     if scales:
-        k = (k.astype(jnp.float32)
-             * scales["k_scale"][..., None]).astype(dtype)
-        v = (v.astype(jnp.float32)
-             * scales["v_scale"][..., None]).astype(dtype)
-    return k, v
+        k, v = ((a.astype(jnp.float32) * s[:, :, :, None]).astype(dtype)
+                for a, s in zip((k, v), scales))
+    B, max_pages, H, D, page_size = k.shape
+    return tuple(jnp.moveaxis(a, -1, 2).reshape(
+        B, max_pages * page_size, H, D) for a in (k, v))
 
 
 def pages_needed(tokens: int, page_size: int) -> int:

@@ -1296,34 +1296,43 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# cache-aware decode attention (inference engine)
+# decode attention over the paged KV pool (inference engine)
 #
-# One query token per sequence against a padded KV context gathered from
-# the paged cache ([B, S, H, D], valid prefix per sequence given by
-# ``lengths``).  The q "matrix" is a single row, which the TPU tiling
-# rules cannot block — the kernel broadcasts it to 8 sublanes (every row
-# computes the same result; row 0 is returned) and walks the context in
-# ``block_k`` strips with the same online-softmax scratch discipline as
-# ``_fwd_kernel``.  Lengths ride in scalar-prefetch SMEM so the mask is
-# a per-strip iota compare, not a precomputed [B, S] tensor.
+# One query token per sequence against the pages its slot holds, read
+# where they lie: K and V are the cache's whole stacked pools
+# ``[L, P, H, D, page]`` (the page offset minor, so a page of one layer
+# is ``H`` lane-dense ``[D, page]`` tiles), and the kernel's K/V block
+# is one page of one layer, all heads.  The grid is the decode's work
+# list, one step per *live* page: ``(slot, page)`` pairs in slot order,
+# compacted outside the kernel from ``lengths`` and the page table and
+# read from scalar-prefetch SMEM; its bound is the list's (traced)
+# length, so a decode costs what is live, not what the table could
+# hold.  A slot's steps are consecutive (its output block stays in VMEM
+# across them, the pipeline fetches the next page meanwhile); a slot
+# that holds nothing is never visited and reads as zeros.  The single
+# query row per head is broadcast to 8 sublanes, which the TPU tiling
+# can block (row 0 is returned); online softmax as in ``_fwd_kernel``.
 # ---------------------------------------------------------------------------
 
 _DECODE_QROWS = 8      # sublane-pad the single query row to a tileable block
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
-                   block_k: int, num_kv: int, quantized: bool = False):
-    """``quantized`` (static): K/V arrive as int8 codes plus
-    per-(position, head) f32 scale rows and are dequantized *inside*
-    the 128-lane context strip (the scales applied to the strip's
-    score/probability rows) — the quantized cache never materializes
-    in anything wider than its strip.  One body for both modes so the
-    scratch discipline cannot diverge."""
+def _decode_kernel(slot_ref, j_ref, page_ref, layer_ref, len_ref, q_ref,
+                   k_ref, v_ref, *rest, scale: float, page: int,
+                   quantized: bool = False):
+    """q [1, H, QROWS, D]; k, v [1, 1, H, D, page] (one live page, all
+    heads).  ``quantized`` (static): K/V arrive as int8 codes plus
+    per-(head, position) f32 scale blocks ``[1, 1, H, page]`` and are
+    dequantized *inside* the page (the scales applied to the page's
+    score/probability rows).  One body for both modes so the scratch
+    discipline cannot diverge."""
+    del page_ref, layer_ref                  # the index maps read them
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = rest
+        ks_ref, vs_ref, _zeros, o_ref, acc_sc, m_sc, l_sc = rest
     else:
-        o_ref, acc_sc, m_sc, l_sc = rest
-    b, j = pl.program_id(0), pl.program_id(2)
+        _zeros, o_ref, acc_sc, m_sc, l_sc = rest
+    g = pl.program_id(0)
+    j, n = j_ref[g], len_ref[slot_ref[g]]    # this slot's j-th page of n rows
 
     @pl.when(j == 0)
     def _init():
@@ -1331,186 +1340,177 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    q = q_ref[0, 0]                          # [QROWS, D]
-    k = k_ref[0, 0]                          # [bk, D]
+    q = q_ref[0]                             # [H, QROWS, D]
+    k = k_ref[0, 0]                          # [H, D, page]
     v = v_ref[0, 0]
     if quantized:
-        # one scale per (position, head) = per row of k/v, so it
-        # commutes out of both matmuls and is applied to the [QROWS, bk]
-        # score/probability rows — the scales stay lane-major ([1, bk]),
-        # no lane->sublane relayout and bk*D fewer multiplies
+        # one scale per (head, position) = per column of k/v: it
+        # commutes out of both matmuls onto the [H, QROWS, page] score /
+        # probability rows (lane-major scales, page*D fewer multiplies)
         q = q.astype(jnp.float32)
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # [QROWS, bk]
+        q, k, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale      # [H, QROWS, page]
     if quantized:
-        s = s * ks_ref[0, 0]                             # [1, bk] bcast
-    col = (j * block_k
-           + jax.lax.broadcasted_iota(jnp.int32,
-                                      (_DECODE_QROWS, block_k), 1))
-    s = jnp.where(col < len_ref[b], s, _NEG_INF)
-    m_prev = m_sc[:]                          # [QROWS, 128] (col-bcast)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        s = s * ks_ref[0, 0][:, None, :]
+    col = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(col < n, s, _NEG_INF)
+    m_prev = m_sc[:]                         # [H, QROWS, 128] (col-bcast)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, :1])
-    l_sc[:] = l_sc[:] * alpha + jnp.sum(p, 1, keepdims=True)
+    p = jnp.exp(s - m_new[:, :, :1])
+    l_sc[:] = l_sc[:] * alpha + jnp.sum(p, 2, keepdims=True)
     if quantized:
-        p = p * vs_ref[0, 0]
-    acc_sc[:] = (acc_sc[:] * alpha[:, :1]
+        p = p * vs_ref[0, 0][:, None, :]
+    acc_sc[:] = (acc_sc[:] * alpha[:, :, :1]
                  + jax.lax.dot_general(
-                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                     p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
                      preferred_element_type=jnp.float32))
     m_sc[:] = m_new
 
-    @pl.when(j == num_kv - 1)
+    @pl.when((j + 1) * page >= n)            # the slot's last live page
     def _finalize():
-        o_ref[0, 0] = (acc_sc[:]
-                       / jnp.maximum(l_sc[:, :1], 1e-30)).astype(
-                           o_ref.dtype)
+        o_ref[0] = (acc_sc[:]
+                    / jnp.maximum(l_sc[:, :, :1], 1e-30)).astype(
+                        o_ref.dtype)
 
 
-def _decode_block(S: int, block_k: int) -> int:
-    """Largest 128-multiple strip <= block_k that divides S (0: none).
-
-    Dropping to a narrower strip beats silently leaving the kernel for
-    the XLA fallback: any 128-multiple context (every paged-cache
-    gather at the default page_size) stays on the Pallas path."""
-    bk = min(block_k, S) // 128 * 128
-    while bk >= 128 and S % bk:
-        bk -= 128
-    return max(bk, 0)
-
-
-def decode_supports(S: int, D: int, *, block_k: int = 512) -> bool:
-    """Context shapes the decode kernel grid can tile (XLA otherwise)."""
-    return _decode_block(S, block_k) >= 128 and D <= 256
+def _live_pages(lengths, page_table, page: int):
+    """The work list of a decode: ``(count, slot, j, page)`` — for each
+    of the ``count`` live pages, slot by slot in order, its slot, its
+    index in the slot's row of ``page_table`` and the pool page there
+    (lists ``[B * max_pages]`` long, meaningless past ``count``)."""
+    B, max_pages = page_table.shape
+    n = jnp.minimum((lengths + page - 1) // page, max_pages)
+    ends = jnp.cumsum(n)
+    g = jnp.arange(B * max_pages, dtype=jnp.int32)
+    slot = jnp.minimum((g[:, None] >= ends[None, :]).sum(1), B - 1)
+    j = jnp.clip(g - (ends - n)[slot], 0, max_pages - 1)
+    return ends[-1], slot, j, page_table[slot, j]
 
 
-def decode_uses_pallas(S: int, D: int, *, impl: str = "auto",
-                       block_k: int = 512) -> bool:
+def _decode_supports(D: int, page: int, quantized: bool) -> bool:
+    """Pool shapes the decode kernel can block: whole 128-lane pages,
+    and a head_dim that fills the sublane tiles of the pool's dtype."""
+    return page % 128 == 0 and D % (32 if quantized else 16) == 0
+
+
+def decode_uses_pallas(D: int, page: int, *, quantized: bool = False,
+                       impl: str = "auto") -> bool:
     """Whether :func:`decode_attention` runs the Pallas kernel for this
-    context shape and ``impl`` — the single source of the decision (the
-    engine reports it, so a summary cannot claim a kernel the dispatch
-    declined).  ``"auto"``: the kernel wherever kernels are compiled (a
-    TPU) and the context tiles, the einsum where the CPU was asked for;
-    ``use_interpret`` refuses a backend nobody asked for instead of
-    quietly picking one."""
+    pool geometry and ``impl`` — the single source of the decision (the
+    engine reports it).  ``"auto"``: the kernel wherever kernels are
+    compiled (a TPU) and the pool blocks, the einsum where the CPU was
+    asked for; ``use_interpret`` refuses a backend nobody asked for."""
     if impl == "pallas":
         return True
     return (impl == "auto" and not _use_interpret()
-            and decode_supports(S, D, block_k=block_k))
+            and _decode_supports(D, page, quantized))
 
 
-def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
-                     impl: str = "auto", block_k: int = 512,
+def decode_attention(q, k, v, lengths, page_table, layer=0, *,
+                     scale: Optional[float] = None, impl: str = "auto",
                      k_scale=None, v_scale=None):
-    """Single-token decode attention against a padded KV context.
+    """Single-token decode attention over the paged KV pool, in place.
 
     q: [B, H, D] — the current token's (already-rotated) queries;
-    k, v: [B, S, H, D] — the per-sequence context gathered from the
-    paged cache (positions >= ``lengths[b]`` are garbage and masked);
+    k, v: [L, P, H, D, page] — the cache's whole stacked pools;
     lengths: [B] int32 — valid context length per sequence (including
-    the current token, whose K/V the caller has already written).
-    Returns [B, H, D] in q's dtype.
+    the current token, whose K/V the caller has already written; 0: the
+    slot holds nothing and reads as zeros); page_table: [B, max_pages]
+    int32 — the pages of each sequence in order (entries past
+    ``ceil(lengths / page)`` are never read); layer: int32 scalar,
+    traced or not.  Returns [B, H, D] in q's dtype.
 
-    ``k_scale``/``v_scale`` ([B, S, H] f32, both or neither): the
-    context is block-scaled int8 (``kv_dtype="int8"`` caches) and is
-    dequantized here — inside the kernel's 128-lane context strips on
-    the Pallas path, as a fused ``codes * scale`` element-wise on the
-    XLA path — so the int8 cache is never materialized wide.
+    ``k_scale``/``v_scale`` ([L, P, H, page] f32, both or neither): the
+    pool is block-scaled int8 (``kv_dtype="int8"`` caches) and is
+    dequantized here — inside the kernel's page blocks on the Pallas
+    path, as a fused ``codes * scale`` element-wise on the XLA path —
+    so the int8 cache is never materialized wide.
 
-    ``impl``: "pallas" (strip-mined online-softmax kernel; raises for
-    untileable shapes), "xla" (masked einsum formulation, shards and
-    runs anywhere), or "auto" (pallas on a TPU backend for lane-aligned
-    shapes, xla where the CPU was asked for, an error on any other
-    backend — interpret-mode parity for the kernel lives in
-    ``tests/test_ops.py``).
-    """
+    ``impl``: "pallas" (one live page a grid step; raises for pools it
+    cannot block), "xla" (masked einsum over the gathered pages, runs
+    anywhere), or "auto" (:func:`decode_uses_pallas`; interpret-mode
+    parity for the kernel lives in ``tests/test_ops.py``)."""
     B, H, D = q.shape
-    S = k.shape[1]
+    page = k.shape[-1]
+    max_pages = page_table.shape[1]
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale/v_scale must be passed together")
     quantized = k_scale is not None
     if scale is None:
         scale = D ** -0.5
     lengths = lengths.astype(jnp.int32)
-    if impl == "pallas" and not decode_supports(S, D, block_k=block_k):
-        raise ValueError(f"decode kernel cannot tile S={S}, D={D} "
-                         f"(block_k={block_k})")
-    if not decode_uses_pallas(S, D, impl=impl, block_k=block_k):
+    page_table = page_table.astype(jnp.int32)
+    if impl == "pallas" and not _decode_supports(D, page, quantized):
+        raise ValueError(f"decode kernel cannot block page={page}, D={D} "
+                         f"of a {k.dtype} pool")
+    if not decode_uses_pallas(D, page, quantized=quantized, impl=impl):
         with jax.named_scope("attn/decode_xla"):
+            k, v = k[layer, page_table], v[layer, page_table]
             if quantized:
                 # masked-einsum fallback: dequantize as one fused
                 # elementwise (XLA folds it into the gather consumers)
                 k = (k.astype(jnp.float32)
-                     * k_scale[..., None]).astype(q.dtype)
+                     * k_scale[layer, page_table][:, :, :, None]
+                     ).astype(q.dtype)
                 v = (v.astype(jnp.float32)
-                     * v_scale[..., None]).astype(q.dtype)
-            s = jnp.einsum("bhd,bshd->bhs", q, k,
+                     * v_scale[layer, page_table][:, :, :, None]
+                     ).astype(q.dtype)
+            s = jnp.einsum("bhd,bphdk->bhpk", q, k,
                            preferred_element_type=jnp.float32) * scale
-            mask = jnp.arange(S)[None, None, :] < lengths[:, None, None]
+            pos = (jnp.arange(max_pages)[:, None] * page
+                   + jnp.arange(page)[None, :])
+            mask = pos[None, None] < lengths[:, None, None, None]
             s = jnp.where(mask, s, _NEG_INF)
-            m = jnp.max(s, -1, keepdims=True)
-            p = jnp.exp(s - m)
-            l = jnp.sum(p, -1, keepdims=True)
-            o = jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v,
+            m = jnp.max(s, (2, 3), keepdims=True)
+            p = jnp.where(mask, jnp.exp(s - m), 0.0)
+            l = jnp.sum(p, (2, 3))[..., None]              # [B, H, 1]
+            o = jnp.einsum("bhpk,bphdk->bhd", p.astype(v.dtype), v,
                            preferred_element_type=jnp.float32)
             return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
-    bk = _decode_block(S, block_k)
-    grid = (B, H, S // bk)
-    qp = jnp.broadcast_to(q[:, :, None, :], (B, H, _DECODE_QROWS, D))
-    # the context goes in head-major ([B, H, S, D], one XLA transpose
-    # of the gathered pages): a (1, bk, 1, D) strip over [B, S, H, D]
-    # has a unit sublane dim Mosaic refuses ("last two dimensions of
-    # your block shape ..."); (bk, D) strips are the flash kernels'
-    kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, j, lens: (b, h, j, 0))
-    qkv_specs = [
-        pl.BlockSpec((1, 1, _DECODE_QROWS, D),
-                     lambda b, h, j, lens: (b, h, 0, 0)),
-        kv_spec, kv_spec,
-    ]
-    common = dict(
-        out_specs=pl.BlockSpec((1, 1, _DECODE_QROWS, D),
-                               lambda b, h, j, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((_DECODE_QROWS, D), jnp.float32),
-            pltpu.VMEM((_DECODE_QROWS, 128), jnp.float32),
-            pltpu.VMEM((_DECODE_QROWS, 128), jnp.float32),
-        ],
-    )
-    scale_in, scale_args = [], []
-    if quantized:
-        # scales travel [B, H, 1, S]: the strip lands on the 128-lane
-        # (trailing) dim as a [1, bk] row per (b, h, j) grid cell, and
-        # the unit dim keeps the block's last two dims legal
-        scale_spec = pl.BlockSpec((1, 1, 1, bk),
-                                  lambda b, h, j, lens: (b, h, 0, j))
-        scale_in = [scale_spec, scale_spec]
-        scale_args = [jnp.swapaxes(k_scale, 1, 2)[:, :, None, :],
-                      jnp.swapaxes(v_scale, 1, 2)[:, :, None, :]]
-    name = "attn/decode_pallas_int8" if quantized else \
-        "attn/decode_pallas"
-    with jax.named_scope(name):
+    # everything this path does is under its name: the work list, the
+    # query's rows, the zeros the output starts as (aliased in: a slot
+    # with no live page is no step of the grid and keeps them)
+    with jax.named_scope("attn/decode_pallas" + "_int8" * quantized):
+        count, slot, j, pages = _live_pages(lengths, page_table, page)
+        qp = jnp.broadcast_to(q[:, :, None, :], (B, H, _DECODE_QROWS, D))
+        q_spec = pl.BlockSpec((1, H, _DECODE_QROWS, D),
+                              lambda g, slot, *_: (slot[g], 0, 0, 0))
+        kv_spec = pl.BlockSpec(
+            (1, 1, H, D, page),
+            lambda g, slot, j, pages, lay, lens: (lay[0], pages[g], 0, 0, 0))
+        in_specs, args = [q_spec, kv_spec, kv_spec], [qp, k, v]
+        if quantized:
+            in_specs += [pl.BlockSpec(
+                (1, 1, H, page), lambda g, slot, j, pages, lay, lens:
+                (lay[0], pages[g], 0, 0))] * 2
+            args += [k_scale, v_scale]
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        args.append(jnp.zeros(qp.shape, q.dtype))
         out = pl.pallas_call(
-            functools.partial(_decode_kernel, scale=scale, block_k=bk,
-                              num_kv=grid[2], quantized=quantized),
+            functools.partial(_decode_kernel, scale=scale, page=page,
+                              quantized=quantized),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=grid,
-                in_specs=qkv_specs + scale_in,
-                **common,
+                num_scalar_prefetch=5,
+                grid=(count,),
+                in_specs=in_specs,
+                out_specs=q_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((H, _DECODE_QROWS, D), jnp.float32),
+                    pltpu.VMEM((H, _DECODE_QROWS, 128), jnp.float32),
+                    pltpu.VMEM((H, _DECODE_QROWS, 128), jnp.float32),
+                ],
             ),
             compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")),
-            out_shape=jax.ShapeDtypeStruct((B, H, _DECODE_QROWS, D),
-                                           q.dtype),
+                dimension_semantics=("arbitrary",)),
+            out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            input_output_aliases={5 + len(args) - 1: 0},
             interpret=_use_interpret(),
-        )(lengths, qp, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-          *scale_args)
+        )(slot, j, pages, jnp.asarray(layer, jnp.int32).reshape(1),
+          lengths, *args)
         return out[:, :, 0]
 
 

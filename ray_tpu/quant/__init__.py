@@ -6,7 +6,7 @@ block-scaled int8:
 
 - the **int8 KV cache** (``ray_tpu.inference.kv_cache``): paged K/V
   stored as int8 with one scale per (position, head) lane vector,
-  dequantized inside ``decode_attention``'s 128-lane context strips —
+  dequantized inside ``decode_attention``'s page blocks —
   roughly doubling decode-slot capacity per HBM byte;
 - the **quantized overlap collectives**
   (``ray_tpu.parallel.overlap``): EQuARX-style (arXiv:2506.17615)

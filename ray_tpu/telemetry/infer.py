@@ -70,6 +70,11 @@ class InferTelemetry:
         # plain decodes by how their tick ran: [synchronous, ahead of
         # the host] (a verify step is counted by ``spec_verify_steps``)
         self.decode_dispatches = [0, 0]
+        # pages of the KV pool the plain decodes' attention read (each
+        # dispatched row's live pages) and pages their tables could
+        # name (slots x max pages a slot): what reading the pool in
+        # place saves over a padded context
+        self.decode_pages = [0, 0]
         # speculative decoding (r21): cumulative proposed/accepted
         # draft counts and verify-step count — the accept rate is the
         # one number that decides whether speculation pays
@@ -112,15 +117,19 @@ class InferTelemetry:
         del self.prefills[:-self._MAX_RECORDS]
 
     def record_decode(self, wall_s: float, *, active: int,
-                      ahead: bool = False) -> None:
+                      ahead: bool = False, pages_read: int = 0,
+                      pages_table: int = 0) -> None:
         """One plain decode, recorded when its tokens are on the host.
         ``ahead``: it was dispatched on the device's own tokens, ahead
         of the host (``inference/engine.py``), and not by a synchronous
-        tick."""
+        tick.  ``pages_read`` of ``pages_table``: the pages its rows
+        held at the dispatch, of those its page table has room for."""
         if not self.enabled:
             return
         self.decode_count += 1
         self.decode_dispatches[bool(ahead)] += 1
+        self.decode_pages[0] += pages_read
+        self.decode_pages[1] += pages_table
         self.decode_tokens += active
         self.decodes.append({"wall_s": wall_s, "active": active})
         del self.decodes[:-self._MAX_RECORDS]
@@ -323,7 +332,9 @@ class InferTelemetry:
         if any(self.decode_dispatches):
             sync, ahead = self.decode_dispatches
             out["decode"] = {"dispatches": sync + ahead,
-                             "ahead_share": ahead / (sync + ahead)}
+                             "ahead_share": ahead / (sync + ahead),
+                             "pages_read": self.decode_pages[0],
+                             "pages_table": self.decode_pages[1]}
         if self.spec_verify_steps:
             out["spec"] = {
                 "verify_steps": self.spec_verify_steps,

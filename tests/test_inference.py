@@ -1506,7 +1506,8 @@ def _reference_kv(cfg, params, tokens):
 def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
     """Every cache array, element for element, against a numpy writer
     that places each cached token's post-RoPE K and V (int8: codes and
-    scales) at ``[layer, page, position % page_size]`` — after a cold
+    scales) at ``[layer, page, ..., position % page_size]`` (the pool's
+    page offset is its minor dimension) — after a cold
     prefill, a prefix hit with a cached-suffix prefill, decode ticks
     beside an inactive slot, and a speculative verify that rejects a
     tail; pages no request owns stay untouched; and the emitted tokens
@@ -1560,7 +1561,7 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
     names = ("k", "v", "k_scale", "v_scale")[:len(engine.cache.state)]
     got = dict(zip(names, map(np.asarray, engine.cache.state)))
     want = {n: np.zeros_like(a) for n, a in got.items()}
-    live = np.zeros(got["k"].shape[1:3], bool)      # [page, offset]
+    live = np.zeros((got["k"].shape[1], ps), bool)  # [page, offset]
     owned = {GARBAGE_PAGE}
     for req in reqs:
         tokens = list(req.prompt) + list(req.generated[:-1])
@@ -1579,14 +1580,17 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
             page, off = req.pages[t // ps], t % ps
             live[page, off] = True
             if kv_dtype == "model":
-                want["k"][:, page, off] = k[:, t]
-                want["v"][:, page, off] = v[:, t]
+                want["k"][:, page, ..., off] = k[:, t]
+                want["v"][:, page, ..., off] = v[:, t]
             else:
-                want["k"][:, page, off], want["k_scale"][:, page, off] = \
-                    _quantize_page(k[:, t])
-                want["v"][:, page, off], want["v_scale"][:, page, off] = \
-                    _quantize_page(v[:, t])
+                (want["k"][:, page, ..., off],
+                 want["k_scale"][:, page, ..., off]) = _quantize_page(k[:, t])
+                (want["v"][:, page, ..., off],
+                 want["v_scale"][:, page, ..., off]) = _quantize_page(v[:, t])
     assert live.sum() == sum(engine.scheduler.lengths) - 32   # shared pages
+    # [L, page, offset, ...] views, for the [page, offset] mask
+    got, want = ({n: np.moveaxis(a, -1, 2) for n, a in d.items()}
+                 for d in (got, want))
     if kv_dtype == "int8":
         # layer 0 sees no cached context, so its codes are the plain
         # writer's (a rounding tie may flip one); deeper layers read the
@@ -1620,7 +1624,7 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
 def test_cache_append_reads_back_through_context(writer, kv_dtype):
     """What a step does to the cache, without a step: ``append`` one
     layer's new rows with each writer, read them back through
-    ``context`` — equal to what was written (int8: to the quantiser's
+    ``context_dense`` — equal to what was written (int8: to the quantiser's
     bound of half a scale step), every row not written as it was, the
     other layer and the garbage page untouched."""
     import jax.numpy as jnp
@@ -1633,6 +1637,10 @@ def test_cache_append_reads_back_through_context(writer, kv_dtype):
                         head_dim=D, dtype=jnp.float32, kv_dtype=kv_dtype)
     assert (cache.num_pages, cache.page_size) == (P, ps)
     assert cache.dtype == (jnp.int8 if kv_dtype == "int8" else jnp.float32)
+    # the page offset is the pool's minor dimension
+    assert [a.shape for a in cache.state] == (
+        [(L, P, H, D, ps)] * 2
+        + [(L, P, H, ps)] * (2 if kv_dtype == "int8" else 0))
     # nothing starts at zero, so "untouched" is a real claim
     cache.state = tuple(
         jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
@@ -1676,10 +1684,8 @@ def test_cache_append_reads_back_through_context(writer, kv_dtype):
                                       b[1, kvc.GARBAGE_PAGE])
         np.testing.assert_array_equal(a[1, 5], b[1, 5])  # in no table
 
-    kctx, vctx, scales = kvc.context((layer, arrays), table)
-    assert sorted(scales) == (["k_scale", "v_scale"]
-                              if kv_dtype == "int8" else [])
     kd, vd = kvc.context_dense((layer, arrays), table, jnp.float32)
+    assert kd.shape == vd.shape == (len(table), table.shape[1] * ps, H, D)
     old_k, old_v = kvc.context_dense((layer, cache.state), table,
                                      jnp.float32)
     written = np.zeros(kd.shape[:2], bool)
@@ -1693,14 +1699,55 @@ def test_cache_append_reads_back_through_context(writer, kv_dtype):
             step = np.abs(put).max(-1, keepdims=True) / 127.0
             assert (np.abs(dense[slots, pos] - put)
                     <= step / 2 + 1e-6).all()
-    if kv_dtype == "int8":
-        assert kctx.dtype == jnp.int8
+    # the dense context is the pool's own pages, offset-minor as
+    # stored (int8: the codes times their scales), turned row-major
+    for dense, i in ((kd, 0), (vd, 1)):
+        pages = after[i][1][table].astype(np.float32)
+        if kv_dtype == "int8":
+            assert arrays[i].dtype == jnp.int8
+            pages = pages * after[i + 2][1][table][:, :, :, None]
         np.testing.assert_array_equal(
-            np.asarray(kd), np.asarray(kctx).astype(np.float32)
-            * np.asarray(scales["k_scale"])[..., None])
-    else:
-        np.testing.assert_array_equal(np.asarray(kd), np.asarray(kctx))
-        np.testing.assert_array_equal(np.asarray(vd), np.asarray(vctx))
+            np.asarray(dense),
+            np.moveaxis(pages, -1, 2).reshape(dense.shape))
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_cache_attend_matches_the_dense_context(kv_dtype):
+    """``attend`` reads the pool in place: one query row per slot over
+    the first ``lengths`` positions of its pages equals a plain softmax
+    over the same rows of ``context_dense``, in the layer asked for,
+    with a shuffled table, a slot whose tail pages are garbage and a
+    row at the garbage page (a free slot), which reads as zeros."""
+    import jax.numpy as jnp
+
+    from ray_tpu.inference import kv_cache as kvc
+
+    L, P, ps, H, D = 2, 7, 4, 2, 8
+    rng = np.random.default_rng(1)
+    cache = kvc.KVCache(n_layers=L, num_pages=P, page_size=ps, n_heads=H,
+                        head_dim=D, dtype=jnp.float32, kv_dtype=kv_dtype)
+    cache.state = tuple(
+        jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
+        if a.dtype == jnp.int8
+        else jnp.asarray(rng.uniform(0.1, 1.0, a.shape), a.dtype)
+        for a in cache.state)
+    table = np.array([[5, 2, 6], [3, 0, 0], [1, 4, 0], [0, 0, 0]], np.int32)
+    lengths = np.array([12, 1, 5, 3], np.int32)
+    q = jnp.asarray(rng.normal(size=(4, H, D)), jnp.float32)
+    for layer in range(L):
+        c = (jnp.int32(layer), cache.state)
+        got = np.asarray(kvc.attend(q, c, table, lengths))
+        kd, vd = map(np.asarray, kvc.context_dense(c, table, jnp.float32))
+        # a row whose table starts at the garbage page is no sequence,
+        # whatever its length says: nothing is read for it
+        assert not got[3].any()
+        for b, n in enumerate(lengths[:3]):
+            for h in range(H):
+                s = kd[b, :n, h] @ np.asarray(q)[b, h] * D ** -0.5
+                p = np.exp(s - s.max())
+                np.testing.assert_allclose(
+                    got[b, h], (p / p.sum()) @ vd[b, :n, h],
+                    rtol=2e-5, atol=2e-5)
 
 
 # ------------------------------------------- one decode in flight (PR 32)
@@ -2002,3 +2049,83 @@ def test_running_ahead_matches_the_synchronous_tick(tiny_f32, case):
     if share is not None and decode is not None:
         assert decode["ahead_share"] == share
         assert decode["dispatches"] == in_flight
+
+
+# ------------------------------ the pool's layout stays inside kv_cache.py
+def test_handoff_in_the_row_major_wire_format_installs_and_decodes(tiny_f32):
+    """A handoff as replicas wrote it before the pool turned its page
+    offset minor — contents ``[L, pages, page, H, D]``, here laid down
+    by a plain numpy writer from a cache-free forward — installs into
+    the pool and decodes to the tokens of an engine that prefilled the
+    prompt itself; and what this engine exports is still that format."""
+    from ray_tpu.inference import kv_cache as kvc
+    cfg, params = tiny_f32
+    ps, n_new = 16, 6
+    prompt = _prompt(37, cfg.vocab_size, seed=5)
+    solo = _make_engine(cfg, params, page_size=ps)
+    rid = solo.submit(prompt, max_new_tokens=1, hold_pages=True)
+    while solo.has_work():
+        solo.step()
+    exported = solo.export_request(rid)
+    want = _make_engine(cfg, params, page_size=ps).generate(
+        [prompt], max_new_tokens=1 + n_new)[0]
+
+    k, v = _reference_kv(cfg, params, prompt)          # [L, T, H, D]
+    n_pages = kvc.pages_needed(len(prompt), ps)
+    old = {}
+    for name, rows in (("k", k), ("v", v)):
+        pages = np.zeros((cfg.n_layers, n_pages * ps) + rows.shape[2:],
+                         rows.dtype)
+        pages[:, :len(prompt)] = rows
+        old[name] = pages.reshape((cfg.n_layers, n_pages, ps)
+                                  + rows.shape[2:])
+        got = getattr(exported, name)
+        assert got.shape == old[name].shape and got.flags.c_contiguous
+        np.testing.assert_allclose(
+            got.reshape(pages.shape)[:, :len(prompt)], rows,
+            rtol=2e-4, atol=2e-5)
+    assert exported.next_token == want[0]
+    handoff = kvc.KVHandoff(
+        context=list(prompt), page_size=ps, kv_dtype="model",
+        dtype=str(solo.cache.dtype),
+        chain_hashes=kvc.PrefixIndex.chain_hashes(prompt, ps),
+        next_token=want[0], next_logprob=0.0, **old)
+
+    engine = _make_engine(cfg, params, page_size=ps)
+    rid = engine.import_submit(handoff, max_new_tokens=n_new)
+    got = []
+    while engine.has_work():
+        got += [ev[1] for ev in engine.step() if ev[0] == rid]
+    assert got == want[1:]
+    assert engine.stats()["imports"] == 1 and engine.leak_free()
+
+
+def test_decode_counts_the_pages_it_reads_of_those_its_table_names(tiny_f32):
+    """``decode.pages_read`` over ``decode.pages_table``: each plain
+    decode adds its dispatched rows' live pages (the context with the
+    token being written) and the whole table's room, by the host's own
+    lengths; nothing is counted with telemetry off."""
+    from ray_tpu.inference.kv_cache import pages_needed
+    cfg, params = tiny_f32
+    ps, slots = 16, 3
+    jobs = [(5, 4), (30, 9), (16, 2)]      # (prompt tokens, new tokens)
+    engines = [_make_engine(cfg, params, page_size=ps, slots=slots,
+                            telemetry=on) for on in (True, False)]
+    for engine in engines:
+        for i, (n, m) in enumerate(jobs):
+            engine.submit(_prompt(n, cfg.vocab_size, seed=i),
+                          max_new_tokens=m)
+        while engine.has_work():
+            engine.step()
+    on, off = engines
+    decode = on.telemetry.summary()["decode"]
+    # the k-th decoded token of a request is written at position n+k-1
+    read = sum(pages_needed(n + k, ps) for n, m in jobs
+               for k in range(1, m))
+    table = decode["dispatches"] * slots * on.max_pages_per_slot
+    assert decode["dispatches"] == max(m for _n, m in jobs) - 1
+    assert (decode["pages_read"], decode["pages_table"]) == (read, table)
+    assert decode["pages_read"] / decode["pages_table"] == read / table
+    assert 0 < read < table
+    assert off.telemetry.summary() == {"enabled": False}
+    assert off.telemetry.decode_pages == [0, 0]

@@ -553,18 +553,18 @@ def test_flash_ce_fallback_and_dispatch(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# cache-aware decode attention (inference engine, r10)
+# decode attention over the paged KV pool (inference engine)
 # ---------------------------------------------------------------------------
 def _decode_ref(q, k, v, lengths):
-    """Masked-softmax numpy reference for single-token decode."""
+    """Masked-softmax numpy reference for single-token decode over a
+    row-major context ``[B, S, H, D]``."""
     import numpy as np
     q_, k_, v_ = (np.asarray(a, np.float32) for a in (q, k, v))
     B, H, D = q_.shape
-    S = k_.shape[1]
     out = np.zeros_like(q_)
     for b in range(B):
         n = int(lengths[b])
-        for h in range(H):
+        for h in range(H if n else 0):      # an empty slot reads as 0
             s = (k_[b, :n, h] @ q_[b, h]) * D ** -0.5
             p = np.exp(s - s.max())
             p /= p.sum()
@@ -572,122 +572,130 @@ def _decode_ref(q, k, v, lengths):
     return out
 
 
-def test_decode_attention_pallas_matches_xla():
-    """The strip-mined decode kernel (interpret mode here, Mosaic on
-    chip) and the masked-einsum XLA fallback agree with the reference
-    over ragged lengths, including a length-1 row and a full row."""
-    key = jax.random.PRNGKey(3)
-    B, S, H, D = 4, 256, 3, 64
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, H, D), jnp.float32)
-    k = jax.random.normal(kk, (B, S, H, D), jnp.float32)
-    v = jax.random.normal(kv, (B, S, H, D), jnp.float32)
-    lengths = jnp.array([1, 100, 129, 256], jnp.int32)
-    ref = _decode_ref(q, k, v, lengths)
-    out_x = A.decode_attention(q, k, v, lengths, impl="xla")
-    out_p = A.decode_attention(q, k, v, lengths, impl="pallas",
-                               block_k=128)
-    import numpy as np
-    np.testing.assert_allclose(np.asarray(out_x), ref, rtol=2e-5,
-                               atol=2e-5)
-    np.testing.assert_allclose(np.asarray(out_p), ref, rtol=2e-5,
-                               atol=2e-5)
+_PAGE, _MAX_PAGES = 128, 3
+# a slot at 1 token, at a page's last row, at the next page's first,
+# at the whole table, at nothing at all, and mid-page
+_DECODE_LENGTHS = (1, _PAGE, _PAGE + 1, _MAX_PAGES * _PAGE, 0, 200)
 
 
-def test_decode_attention_bf16_and_dispatch():
-    """bf16 I/O stays f32 in the accumulators; ``decode_supports``
-    gates the kernel (untileable context -> xla silently under auto,
-    raise under impl="pallas")."""
+def _paged_pool(dtype, seed, H=3):
+    """A two-layer pool ``[L, P, H, D, page]`` (int8: codes and scales
+    ``[L, P, H, page]``), a shuffled page table over it, queries, and
+    each layer's contexts gathered row-major for the reference."""
     import numpy as np
-    key = jax.random.PRNGKey(4)
-    B, S, H, D = 2, 128, 2, 64
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
-    k = jax.random.normal(kk, (B, S, H, D), jnp.bfloat16)
-    v = jax.random.normal(kv, (B, S, H, D), jnp.bfloat16)
-    lengths = jnp.array([37, 128], jnp.int32)
-    ref = _decode_ref(q, k, v, lengths)
-    out_p = A.decode_attention(q, k, v, lengths, impl="pallas")
-    assert out_p.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(out_p, np.float32), ref,
+
+    from ray_tpu.quant import quantize_block
+    L, D, B = 2, 64, len(_DECODE_LENGTHS)
+    P = 1 + B * _MAX_PAGES
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    store = jnp.float32 if dtype == "int8" else dtype
+    q = jax.random.normal(kq, (B, H, D), store)
+    rows = [jax.random.normal(key, (L, P, _PAGE, H, D), store)
+            for key in (kk, kv)]
+    scales = {}
+    if dtype == "int8":
+        for name, i in (("k_scale", 0), ("v_scale", 1)):
+            rows[i], sc = quantize_block(rows[i], block=D)
+            scales[name] = jnp.moveaxis(sc[..., 0], 2, -1)  # [L, P, H, page]
+    table = np.random.default_rng(seed).permutation(
+        np.arange(1, P)).reshape(B, _MAX_PAGES).astype(np.int32)
+    dense = []      # per layer: K, V [B, max_pages * page, H, D], f32
+    for layer in range(L):
+        ctx = []
+        for i, name in ((0, "k_scale"), (1, "v_scale")):
+            a = np.asarray(rows[i], np.float32)[layer][table]
+            if scales:          # [B, mp, page, H, D] x [B, mp, page, H]
+                a = a * np.moveaxis(np.asarray(scales[name])[layer][table],
+                                    -1, 2)[..., None]
+            ctx.append(a.reshape(B, _MAX_PAGES * _PAGE, H, D))
+        dense.append(ctx)
+    k, v = (jnp.moveaxis(a, 2, -1) for a in rows)       # offset minor
+    return q, k, v, jnp.asarray(table), scales, dense
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "int8"],
+                         ids=["f32", "bf16", "int8"])
+def test_decode_attention_reads_the_paged_pool(dtype, layer):
+    """The paged decode kernel (interpret mode here, Mosaic on chip) and
+    the masked einsum over the gathered pages agree with the reference
+    over ragged lengths through a shuffled page table, in the layer
+    asked for: a slot of 1 token, of exactly a page, of a page and one,
+    of the whole table, and an empty slot beside them (no live page: it
+    is no step of the kernel's grid, and reads as zeros).  bf16 I/O stays
+    f32 in the accumulators; an int8 pool is dequantized inside the
+    page blocks by its per-(head, position) scales."""
+    import numpy as np
+    q, k, v, table, scales, dense = _paged_pool(dtype, seed=3 + layer)
+    lengths = jnp.array(_DECODE_LENGTHS, jnp.int32)
+    live = np.array(_DECODE_LENGTHS) > 0
+    ref = _decode_ref(q, *dense[layer], lengths)
+    tol = 0.06 if dtype == jnp.bfloat16 else 2e-5
+    for impl in ("xla", "pallas"):
+        out = A.decode_attention(q, k, v, lengths, table, jnp.int32(layer),
+                                 impl=impl, **scales)
+        assert out.dtype == q.dtype and out.shape == q.shape
+        out = np.asarray(out, np.float32)
+        np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+        assert not out[~live].any()         # nothing live: zeros
+    # the other layer's pages are other numbers: the layer was honoured
+    other = _decode_ref(q, *dense[1 - layer], lengths)
+    assert np.abs(other[live] - ref[live]).max() > 0.05
+
+
+@pytest.mark.parametrize("heads", [12, 20])
+def test_decode_attention_takes_its_heads_from_the_pool(heads):
+    """One kernel for GPT-2 124M's 12 heads and GPT-2 large's 20: the
+    block is a page with all its heads, read off the pool's shape."""
+    import numpy as np
+    q, k, v, table, _s, dense = _paged_pool(jnp.bfloat16, seed=heads,
+                                            H=heads)
+    lengths = jnp.array(_DECODE_LENGTHS, jnp.int32)
+    live = np.array(_DECODE_LENGTHS) > 0
+    out = np.asarray(A.decode_attention(q, k, v, lengths, table, 1,
+                                        impl="pallas"), np.float32)
+    np.testing.assert_allclose(out[live],
+                               _decode_ref(q, *dense[1], lengths)[live],
                                rtol=0.06, atol=0.06)
-    # S=100 cannot tile into 128-lane strips
-    assert not A.decode_supports(100, D)
-    with pytest.raises(ValueError):
-        A.decode_attention(q, k[:, :100], v[:, :100], lengths,
+    assert not out[~live].any()
+
+
+def test_decode_attention_dispatch():
+    """``decode_uses_pallas`` gates the kernel from the pool's geometry
+    (a page that is not whole 128-lane tiles, or a head_dim that does
+    not fill the dtype's sublane tiles -> the einsum silently under
+    auto, an error under impl="pallas"); auto where the CPU was asked
+    for is the einsum; scales come as a pair."""
+    import numpy as np
+    q, k, v, table, _s, dense = _paged_pool(jnp.float32, seed=9)
+    lengths = jnp.array(_DECODE_LENGTHS, jnp.int32)
+    live = np.array(_DECODE_LENGTHS) > 0
+    out = A.decode_attention(q, k, v, lengths, table, 1, impl="auto")
+    np.testing.assert_allclose(
+        np.asarray(out)[live], _decode_ref(q, *dense[1], lengths)[live],
+        rtol=2e-5, atol=2e-5)
+    assert A.decode_uses_pallas(64, 128, impl="pallas")
+    assert not A.decode_uses_pallas(64, 128, impl="auto")   # the CPU
+    assert not A.decode_uses_pallas(64, 128, impl="xla")
+    assert A._decode_supports(64, 128, False)
+    assert A._decode_supports(64, 256, True)
+    assert not A._decode_supports(64, 16, False)    # a 16-row page
+    assert not A._decode_supports(16, 128, True)    # int8 tiles 32 rows
+    assert not A._decode_supports(8, 128, False)
+    with pytest.raises(ValueError, match="cannot block"):
+        A.decode_attention(q, k[..., :16], v[..., :16], lengths, table,
                            impl="pallas")
-    # 128-multiple contexts not divisible by the default 512 strip
-    # drop to a narrower strip instead of leaving the kernel
-    assert A._decode_block(640, 512) == 128
-    assert A._decode_block(768, 512) == 384
-    assert A.decode_supports(640, D)
-    k6 = jnp.concatenate([k] * 5, axis=1)          # S = 640
-    v6 = jnp.concatenate([v] * 5, axis=1)
-    l6 = jnp.array([500, 640], jnp.int32)
-    ref6 = _decode_ref(q, k6, v6, l6)
-    out6 = A.decode_attention(q, k6, v6, l6, impl="pallas")
-    np.testing.assert_allclose(np.asarray(out6, np.float32), ref6,
-                               rtol=0.06, atol=0.06)
-    # auto on CPU takes the xla path (no TPU backend), same numerics
-    out_auto = A.decode_attention(q, k, v, lengths, impl="auto")
-    np.testing.assert_allclose(np.asarray(out_auto, np.float32), ref,
-                               rtol=0.06, atol=0.06)
-
-
-def test_decode_attention_int8_scales_parity():
-    """r11 int8-KV decode: both impls dequantize the block-scaled int8
-    context (one f32 scale per (position, head) lane vector) and agree
-    with the full-precision reference within the quantization budget —
-    per-element K/V error <= amax/254, so logits-path error is O(1%).
-    The Pallas kernel dequantizes inside its 128-lane strips; scale
-    shapes must also survive the narrower-strip fallback (S=640)."""
-    import numpy as np
-
-    from ray_tpu.quant import dequantize_block, quantize_block
-
-    key = jax.random.PRNGKey(6)
-    B, S, H, D = 4, 256, 3, 64
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, H, D), jnp.float32)
-    k = jax.random.normal(kk, (B, S, H, D), jnp.float32)
-    v = jax.random.normal(kv, (B, S, H, D), jnp.float32)
-    lengths = jnp.array([1, 100, 129, 256], jnp.int32)
-
-    k8, ks = quantize_block(k, block=D)
-    v8, vs = quantize_block(v, block=D)
-    ks, vs = ks[..., 0], vs[..., 0]          # [B, S, H]
-    # reference: exact attention over the *dequantized* context — this
-    # isolates the kernels' dequant plumbing from the quant error
-    kd = dequantize_block(k8, ks[..., None], block=D)
-    vd = dequantize_block(v8, vs[..., None], block=D)
-    ref = _decode_ref(q, kd, vd, lengths)
-
-    out_x = A.decode_attention(q, k8, v8, lengths, impl="xla",
-                               k_scale=ks, v_scale=vs)
-    out_p = A.decode_attention(q, k8, v8, lengths, impl="pallas",
-                               block_k=128, k_scale=ks, v_scale=vs)
-    np.testing.assert_allclose(np.asarray(out_x), ref, rtol=2e-5,
-                               atol=2e-5)
-    np.testing.assert_allclose(np.asarray(out_p), ref, rtol=2e-5,
-                               atol=2e-5)
-    # and vs the unquantized context: bounded by the int8 budget
-    full = _decode_ref(q, k, v, lengths)
-    np.testing.assert_allclose(np.asarray(out_p), full, rtol=0.05,
-                               atol=0.05)
-
-    # narrower-strip fallback keeps the scale blocks aligned
-    k6, v6 = (jnp.concatenate([a] * 5, axis=1) for a in (k8, v8))
-    ks6, vs6 = (jnp.concatenate([a] * 5, axis=1) for a in (ks, vs))
-    l6 = jnp.array([500, 640, 3, 640], jnp.int32)
-    ref6 = _decode_ref(q, jnp.concatenate([kd] * 5, axis=1),
-                       jnp.concatenate([vd] * 5, axis=1), l6)
-    out6 = A.decode_attention(q, k6, v6, l6, impl="pallas",
-                              k_scale=ks6, v_scale=vs6)
-    np.testing.assert_allclose(np.asarray(out6), ref6, rtol=2e-5,
-                               atol=2e-5)
-    # scales must come as a pair
+    # a 16-row page is the einsum's under auto
+    short = jnp.minimum(lengths, 16 * _MAX_PAGES)
+    out16 = A.decode_attention(q, k[..., :16], v[..., :16], short, table)
+    ctx16 = [a.reshape(len(table), _MAX_PAGES, _PAGE, 3, 64)[:, :, :16]
+             .reshape(len(table), -1, 3, 64) for a in dense[0]]
+    np.testing.assert_allclose(
+        np.asarray(out16)[live], _decode_ref(q, *ctx16, short)[live],
+        rtol=2e-5, atol=2e-5)
     with pytest.raises(ValueError, match="together"):
-        A.decode_attention(q, k8, v8, lengths, k_scale=ks)
+        A.decode_attention(q, k, v, lengths, table,
+                           k_scale=jnp.ones(k.shape[:3] + k.shape[4:]))
 
 
 # ---------------------------------------------------------------------------

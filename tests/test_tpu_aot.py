@@ -29,7 +29,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BF16 = jnp.bfloat16
 B, S, H, D = 24, 1024, 12, 64            # GPT-2 124M, the bench batch
 N, DM, V = B * S, 768, 50304
-SLOTS, CTX = 8, 1024                     # the engine's decode geometry
+SLOTS, CTX, PAGE = 8, 1024, 128          # the engine's decode geometry
+PAGES = SLOTS * (CTX // PAGE) + 1
 
 
 @pytest.fixture(scope="module")
@@ -91,43 +92,65 @@ def test_fused_norm_epilogue_fwd_bwd_compiles_for_v5e(v5e):
 
 @pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8])
 def test_decode_attention_compiles_for_v5e(v5e, kv_dtype):
-    kv = ((SLOTS, CTX, H, D), kv_dtype)
-    shapes = [((SLOTS, H, D), BF16), kv, kv, ((SLOTS,), jnp.int32)]
+    """The kernel over the cache's own pool, ``[L, P, H, D, page]``: a
+    page of one layer is the block, picked by the table from SMEM."""
+    L = 12
+    kv = ((L, PAGES, H, D, PAGE), kv_dtype)
+    shapes = [((SLOTS, H, D), BF16), kv, kv, ((SLOTS,), jnp.int32),
+              ((SLOTS, CTX // PAGE), jnp.int32), ((), jnp.int32)]
     if kv_dtype == jnp.int8:
-        shapes += [((SLOTS, CTX, H), jnp.float32)] * 2
+        shapes += [((L, PAGES, H, PAGE), jnp.float32)] * 2
 
-    def step(q, k, v, lengths, *scales):
+    def step(q, k, v, lengths, page_table, layer, *scales):
         kw = dict(zip(("k_scale", "v_scale"), scales))
         # "auto" must pick the kernel wherever kernels are compiled
-        return attention.decode_attention(q, k, v, lengths, impl="auto",
-                                          **kw)
+        return attention.decode_attention(q, k, v, lengths, page_table,
+                                          layer, impl="auto", **kw)
 
-    _compile_for_v5e(step, v5e, *shapes)
+    hlo = _compile_for_v5e(step, v5e, *shapes).as_text()
+    # the K and V pools go to the kernel as they came: no copy of them
+    pool = "[" + ",".join(map(str, kv[0])) + "]"
+    assert not [ln for ln in hlo.splitlines()
+                if pool in ln.split(" copy(")[0] and " copy(" in ln]
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached"])
-def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, kind):
-    """The TPU compiler's verdict on the engine's cache handling, at
-    GPT-2 124M's width: the stacked K and V come in, are written and
+# the two serve cells' engines (benchmark/cells/serve-*.json): preset,
+# slots, pages
+_SERVE_CELLS = {"124m": ("gpt2", 128, 1025), "large": ("gpt2_large", 64, 513)}
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached",
+                                  "verify"])
+@pytest.mark.parametrize("cell", sorted(_SERVE_CELLS))
+def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, cell, kind):
+    """The TPU compiler's verdict on the engine's cache handling, at the
+    two serve cells' own geometry (GPT-2 124M: 12 heads, 128 slots, 1025
+    pages; GPT-2 large: 20 heads, 64 slots, 513 pages) and for all four
+    serve executables: the stacked K and V come in, are written and
     read, and go out in ONE layout — no ``copy`` of them, nothing that
-    produces a layer's ``[pages, page, H, D]`` pool.  (A row-granular
+    produces a layer's ``[pages, H, D, page]`` pool.  (A row-granular
     scatter compiles to a relayout of the whole cache, head_dim minor
     and padded 2.7x, before and after the layer loop; a per-layer
     dynamic-slice copies a layer's pool out and back in every layer.
-    Neither shows on the CPU.)"""
+    Neither shows on the CPU.)  The decode attends over the pool where
+    it lies: its executable holds one kernel and no operation whose
+    result is the slots' padded context, gathered or turned, and its
+    temporaries are the logits and the touched tail pages, not a
+    context."""
     import re
 
     from ray_tpu.inference.engine import InferenceEngine
     from ray_tpu.models.gpt import GPTConfig, init_params
 
-    cfg = GPTConfig.gpt2(vocab_size=V, max_seq=CTX, dtype=BF16)
-    page, pages = 128, SLOTS * (CTX // 128) + 1
+    preset, slots, pages = _SERVE_CELLS[cell]
+    cfg = getattr(GPTConfig, preset)(vocab_size=V, max_seq=CTX, dtype=BF16)
+    heads, page = cfg.n_heads, PAGE
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=v5e)
     params = jax.tree.map(
         lambda a: spec(a.shape, a.dtype),
         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    stacked = (cfg.n_layers, pages, page, H, D)
+    stacked = (cfg.n_layers, pages, heads, D, page)
     state = (spec(stacked, BF16),) * 2
     # the step builder reads only these: no arrays, no allocation
     eng = object.__new__(InferenceEngine)
@@ -136,15 +159,16 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, kind):
     fn = eng._build_step(kind)
     i32, mp = jnp.int32, CTX // page
     if kind == "decode":
-        tail = (spec((SLOTS,), i32), spec((SLOTS,), i32),
-                spec((SLOTS, mp), i32))
+        tail = (spec((slots,), i32), spec((slots,), i32),
+                spec((slots, mp), i32))
     elif kind == "prefill":
         tail = (spec((1, 256), i32), spec((), i32), spec((mp,), i32))
-    else:
-        tail = (spec((1, 64), i32), spec((), i32), spec((), i32),
-                spec((mp,), i32))
+    else:       # a cached suffix's bucket, a verify's draft bucket
+        tail = (spec((1, 64 if kind == "prefill_cached" else 8), i32),
+                spec((), i32), spec((), i32), spec((mp,), i32))
     with substrate.compile_for_tpu():
-        hlo = fn.lower(params, *state, *tail).compile().as_text()
+        compiled = fn.lower(params, *state, *tail).compile()
+    hlo = compiled.as_text()
 
     def dims(shape):
         return "[" + ",".join(map(str, shape)) + "]"
@@ -158,10 +182,18 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, kind):
         shape, layout, op = m.groups()
         assert shape not in (dims(stacked[1:]),
                              dims((1,) + stacked[1:])), ln
+        if kind == "decode":
+            assert shape not in (dims((slots, CTX, heads, D)),
+                                 dims((slots, mp, page, heads, D)),
+                                 dims((slots, mp, heads, D, page)),
+                                 dims((slots, heads, CTX, D))), ln
         if shape == dims(stacked):
             assert not op.startswith("copy"), ln
             layouts.add(re.sub(r"S\(\d+\)", "", layout))
     assert len(layouts) == 1, layouts
+    if kind == "decode":
+        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def _sampler_specs(rows, vocab, sharding=None):
@@ -226,7 +258,7 @@ def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
     # the suite asks for the CPU by name (conftest): interpret mode
     assert substrate.cpu_requested()
     assert substrate.use_interpret() is True
-    assert not attention.decode_uses_pallas(CTX, D, impl="auto")
+    assert not attention.decode_uses_pallas(D, PAGE, impl="auto")
     # the same backend when nobody asked for it is a chip that failed to
     # initialise, or a worker started without one: an error, not a
     # quiet interpret-mode run
@@ -234,7 +266,7 @@ def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
     with pytest.raises(RuntimeError, match="neither a TPU was found"):
         substrate.use_interpret()
     with pytest.raises(RuntimeError, match="neither a TPU was found"):
-        attention.decode_uses_pallas(CTX, D, impl="auto")
+        attention.decode_uses_pallas(D, PAGE, impl="auto")
     # compiling for a described TPU needs no backend at all
     with substrate.compile_for_tpu():
         assert substrate.use_interpret() is False
