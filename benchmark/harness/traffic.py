@@ -6,7 +6,10 @@ The seed permutes and does not resample.  A length distribution is
 evaluated at as many evenly spaced quantiles as there are requests, so
 every seed offers the same multiset of lengths (the same tokens of
 work); the seed decides which request gets which length, the token ids,
-and the arrival times.
+and the arrival times.  Clumped arrivals (``gamma``) are a fixed set of
+gaps too, in one order that the seed turns: every seed offers the same
+clumps (PR 43: a decode's time follows the live pages since PR 37, so
+gaps drawn afresh made each seed another amount of work).
 """
 
 from __future__ import annotations
@@ -76,20 +79,61 @@ def _poisson(n: int, seconds: float, mix: Dict[str, Any],
     return sorted(rng.uniform(0.0, seconds, size=n).tolist())
 
 
+def _gamma_p(k: float, x: np.ndarray) -> np.ndarray:
+    """The regularised lower incomplete gamma function P(k, x) by its
+    series, x^k e^-x sum_m x^m / Gamma(k + m + 1): numpy alone, good for
+    the x a gap's quantile reaches (under 100)."""
+    x = np.asarray(x, dtype=np.float64)
+    term = np.full_like(x, 1.0 / math.gamma(k + 1.0))
+    total = term.copy()
+    for m in range(1, 400):
+        term = term * x / (k + m)
+        total += term
+    with np.errstate(under="ignore"):
+        return np.clip(np.exp(k * np.log(x) - x) * total, 0.0, 1.0)
+
+
+def gamma_strata(k: float, n: int) -> np.ndarray:
+    """A gamma distribution of shape ``k`` cut into ``n`` strata of equal
+    probability, each at its mean: ascending, as shares of their sum.
+    The edges are the quantiles at i / n, found by bisection on log x;
+    the mean of a stratum is k (P(k + 1, b) - P(k + 1, a)) n."""
+    lo = np.full(n - 1, -700.0)
+    hi = np.full(n - 1, math.log(k + 40.0 * math.sqrt(k) + 40.0))
+    want = np.arange(1, n) / n
+    for _ in range(100):
+        mid = 0.5 * (lo + hi)
+        below = _gamma_p(k, np.exp(mid)) < want
+        lo, hi = np.where(below, mid, lo), np.where(below, hi, mid)
+    edges = np.exp(0.5 * (lo + hi))
+    return np.diff(np.concatenate(
+        [[0.0], _gamma_p(k + 1.0, edges), [1.0]]))
+
+
 def _gamma(n: int, seconds: float, mix: Dict[str, Any],
            rng: np.random.Generator) -> List[float]:
     """A renewal process whose gaps are gamma-distributed with
     coefficient of variation ``arrival_cv`` (shape 1 / cv^2: 1 is
     Poisson, above it arrivals clump), conditioned on its count as the
-    Poisson one is: ``n + 1`` gaps, their running sum scaled onto
-    [0, seconds).  Scaling leaves the gaps' coefficient of variation as
-    drawn, so only the clumping differs from ``poisson``."""
+    Poisson one is: ``n + 1`` gaps that fill [0, seconds).  The gaps are
+    not drawn: they are the distribution's ``n + 1`` strata of equal
+    probability, each at its mean, in one order fixed by the count and
+    the coefficient alone, and the seed turns that cycle to where the
+    window starts.  So every seed offers the same gaps and the same
+    clumps, as it offers the same lengths, and only which request meets
+    which clump differs."""
     cv = float(mix["arrival_cv"])
     if not cv > 0:
         raise ValueError(f"arrival_cv must be positive, not {cv!r}")
-    gaps = rng.gamma(1.0 / cv ** 2, size=n + 1)
-    # a draw at this shape can underflow to 0: ties in due time are fine
-    return (np.cumsum(gaps)[:n] * (seconds / gaps.sum())).tolist()
+    gaps = gamma_strata(1.0 / cv ** 2, n + 1)
+    order = np.random.default_rng(
+        [n + 1, int(round(1000 * cv))]).permutation(n + 1)
+    gaps = np.roll(gaps[order], int(rng.integers(0, n + 1)))
+    # the least strata are 0 to rounding: ties in due time are fine, and
+    # where the last gap is such a one the last request is due just
+    # inside the window
+    due = np.cumsum(gaps)[:n] * (seconds / gaps.sum())
+    return np.minimum(due, np.nextafter(seconds, 0.0)).tolist()
 
 
 # arrival processes by the name a mix gives under ``arrivals``

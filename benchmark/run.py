@@ -12,7 +12,11 @@ in a traced run).  With ``--trace 0`` the metrics are the cell's
 end-to-end metrics, with ``--trace 1`` its per-layer metrics.
 
 This process never touches jax: the chip belongs to the worker the
-runtime starts.  Without a TPU it exits non-zero and prints no result;
+runtime starts.  Before the runner is called it waits, outside the
+clock, until no ``/dev/vfio/<n>`` is still held by whoever ran before,
+and after its shutdown until its own are let go of
+(``harness/chips.py``).  Without a TPU it exits non-zero and prints no
+result;
 ``--rehearse-on-cpu`` walks the same code at toy shapes on the CPU,
 prints counts and no rates, and exits 3.
 """
@@ -29,6 +33,11 @@ import traceback
 
 T_START = time.time()
 SHUTDOWN_LIMIT_S = 50.0
+# the most a run waits for the chips before its clock starts, and holds
+# its exit for them after its shutdown: a four-chip holder's groups come
+# free 13-20 s after it was reaped (builder, PR 42)
+CHIPS_TAKE_LIMIT_S = 90.0
+CHIPS_RELEASE_LIMIT_S = 60.0
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
@@ -37,6 +46,22 @@ def _say_why(e: BaseException) -> None:
     print("benchmark: failed: %s: %s" % (
         type(e).__name__, " ".join(str(e).split())[:2000]),
         file=sys.stderr, flush=True)
+
+
+def _wait_for_chips(limit_s: float, freed: str, still_held: str) -> float:
+    """Wait, outside the clock, until no ``/dev/vfio/<n>`` is held by a
+    process that has exited (``harness/chips.py``); the seconds waited,
+    0.0 and no word where the chips were free.  One line on standard
+    error otherwise: ``freed`` over the nodes that were seen busy, or
+    ``still_held`` over those busy at the limit, after which the run goes
+    on and libtpu says what it says."""
+    from benchmark.harness import chips
+    waited, seen, still = chips.wait_free(limit_s)
+    if still or waited > 0:
+        print("benchmark: " + (still_held if still else freed).format(
+            s=waited, nodes=", ".join(still or seen) or "the chips"),
+            file=sys.stderr, flush=True)
+    return waited
 
 
 def main() -> int:
@@ -87,9 +112,14 @@ def main() -> int:
     else:
         raise SystemExit(f"traffic kind {kind!r} has no runner")
 
+    # the previous holder's release is no part of this system's set-up:
+    # ``setup_s`` is counted from ``T_START`` plus this wait
+    chip_wait_s = 0.0 if args.rehearse_on_cpu else _wait_for_chips(
+        CHIPS_TAKE_LIMIT_S, "waited {s:.2f} s for {nodes} to be free",
+        "{nodes} still busy after {s:.2f} s; going on")
     import ray_tpu
     try:
-        out = runner.run(files, args, T_START)
+        out = runner.run(files, args, T_START + chip_wait_s)
     except Exception as e:      # noqa: BLE001 — the boundary that reports
         # the worker's traceback travels in the exception re-raised from
         # ``result.error`` (or chained to it); then the line that says
@@ -107,6 +137,12 @@ def main() -> int:
             import ray_tpu.serve as serve
             serve.shutdown()
             ray_tpu.shutdown()
+        if not args.rehearse_on_cpu:
+            # the next run, of whatever checkout, finds the chips free
+            _wait_for_chips(
+                CHIPS_RELEASE_LIMIT_S,
+                "held the exit {s:.2f} s until the chips were free",
+                "{nodes} still busy {s:.2f} s after the shutdown")
 
     device = out["device"]
     if device["platform"] != "tpu" and not args.rehearse_on_cpu:
@@ -163,7 +199,7 @@ def main() -> int:
                         for name, value, limit in out["compared"]}
     detail = dict(out.get("detail", {}), workload=args.workload,
                   seed=args.seed, seconds=args.seconds, trace=args.trace,
-                  facts=facts)
+                  chip_wait_s=chip_wait_s, facts=facts)
     os.makedirs(common.OUT_DIR, exist_ok=True)
     with open(os.path.join(
             common.OUT_DIR, f"{args.workload}.seed{args.seed}."

@@ -165,9 +165,13 @@ def test_segments_take_the_innermost_span_across_threads():
 @pytest.mark.parametrize("cell", [w["name"] for w in
                                   common.manifest()["workloads"]])
 def test_traced_rehearsal_exits_3_with_what_a_cpu_trace_can_give(cell):
+    # the seed is one at which the toy burst tail (24 requests in 12 s,
+    # the gamma cycle turned by the seed) has arrivals in its first
+    # seconds: a rehearsal takes one piece, and one with no arrival in it
+    # has no prefill for tick_tail_ms to read
     out = subprocess.run(
         [sys.executable, os.path.join(common.BENCH_DIR, "run.py"),
-         "--workload", cell, "--seed", "3000000019", "--trace", "1",
+         "--workload", cell, "--seed", "3000000025", "--trace", "1",
          "--rehearse-on-cpu"], cwd=common.CHECKOUT, capture_output=True,
         text=True, timeout=900)
     assert out.returncode == 3, out.stderr[-2000:]

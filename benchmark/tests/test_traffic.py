@@ -1,6 +1,7 @@
 """The generator: every seed offers the same work, in another order."""
 
 import collections
+import math
 
 import numpy as np
 import pytest
@@ -162,6 +163,70 @@ def test_gamma_gaps_have_the_coefficient_of_variation_asked_for(seed, cv):
     flat = np.diff([0.0] + traffic.arrival_times(
         n, seconds, {"arrivals": "poisson"}, traffic.rng_for(seed, "a")))
     assert flat.std() / flat.mean() == pytest.approx(1.0, rel=0.1)
+
+
+def test_every_seed_offers_the_same_gaps_in_one_cycle_turned():
+    # the seed does not resample the clumps: the n + 1 gaps are one fixed
+    # cycle (the gamma's strata of equal probability, each at its mean)
+    # and the seed decides where in it the window starts
+    n, seconds = 130, 45.0
+    mix = {"arrivals": "gamma", "arrival_cv": 2.5}
+
+    def gaps(seed):
+        due = traffic.arrival_times(n, seconds, mix,
+                                    traffic.rng_for(seed, "arrivals"))
+        assert due == sorted(due) and 0 <= due[0] and due[-1] < seconds
+        return np.diff([0.0] + due + [seconds])
+
+    first = gaps(1)
+    assert first.sum() == pytest.approx(seconds)
+    turns = set()
+    for seed in (2, 7, 4300000601, BIG):
+        g = gaps(seed)
+        np.testing.assert_allclose(np.sort(g), np.sort(first), atol=1e-9)
+        turn = [k for k in range(n + 1)
+                if np.allclose(np.roll(first, k), g, atol=1e-9)]
+        assert turn
+        turns.add(turn[0])
+    assert len(turns) > 1
+    # the set is the distribution's: its strata at their means keep the
+    # mean exactly and nearly all of the variance (CV 2.455 of 2.5 at 131)
+    strata = traffic.gamma_strata(1 / 2.5 ** 2, n + 1)
+    assert strata.sum() == pytest.approx(1.0)
+    assert (np.diff(strata) >= 0).all()
+    np.testing.assert_allclose(np.sort(first) / seconds, strata, atol=1e-9)
+    assert strata.std() / strata.mean() == pytest.approx(2.455, abs=0.01)
+    # another count or coefficient is another cycle
+    other = traffic.arrival_times(n, seconds, {"arrivals": "gamma",
+                                               "arrival_cv": 2.0},
+                                  traffic.rng_for(1, "arrivals"))
+    assert not np.allclose(np.diff([0.0] + other + [seconds]), first)
+
+
+@pytest.mark.parametrize("k", [0.16, 0.25, 1.0, 4.0])
+def test_the_incomplete_gamma_series_against_closed_forms(k):
+    x = np.array([1e-30, 1e-9, 1e-3, 0.1, 1.0, 5.0, 20.0, 60.0])
+    if k == 1.0:
+        np.testing.assert_allclose(traffic._gamma_p(k, x), -np.expm1(-x),
+                                   rtol=1e-12)
+    # P(k + 1, x) = P(k, x) - x^k e^-x / Gamma(k + 1)
+    np.testing.assert_allclose(
+        traffic._gamma_p(k + 1, x),
+        traffic._gamma_p(k, x) - np.exp(k * np.log(x) - x) / math.gamma(k + 1),
+        atol=1e-13)
+    # strata of equal probability: P at the edges is i / n
+    n = 50
+    s = traffic.gamma_strata(k, n)
+    assert len(s) == n and s.sum() == pytest.approx(1.0)
+    assert s.mean() == pytest.approx(1.0 / n)
+    # the exponential's strata have a closed form: the integral of
+    # x e^-x between two edges is u (1 - ln u) taken between their
+    # survival shares u
+    if k == 1.0:
+        u = 1.0 - np.arange(n + 1) / n
+        safe = np.where(u > 0, u, 1.0)
+        np.testing.assert_allclose(
+            s, np.diff(-(u * (1 - np.log(safe)))), atol=1e-12)
 
 
 def test_the_tail_of_a_traced_run_is_other_conversations():
