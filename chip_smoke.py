@@ -388,6 +388,22 @@ def kernel_parity(config: dict) -> dict:
             up(q), up(k), up(v), lengths, table)
         row("attn/decode_pallas fwd", [slots, pages, heads, D, page],
             {"o": _rel_err(o, o_ref)}, {"o": TOL_OUT})
+        # the decode's write, in place, against the cache's whole-page
+        # blend: every third slot sits the decode out, and the pools are
+        # equal to the bit in every page but the garbage page
+        if A.decode_write_uses_pallas(D, page, k.dtype):
+            from ray_tpu.inference import kv_cache as kvc
+            k_new, v_new = rand((slots, heads, D)), rand((slots, heads, D))
+            live = table.at[::3].set(kvc.GARBAGE_PAGE)
+            at = (lengths - 1, live, jnp.int32(1))
+            got = jax.jit(lambda *x: A.decode_write(
+                *x, skip_page=kvc.GARBAGE_PAGE))(k, v, k_new, v_new, *at)
+            want = [jax.jit(kvc.write_decode)(pool, new, at[2], live, at[0])
+                    for pool, new in ((k, k_new), (v, v_new))]
+            row("attn/write_pallas", [slots, pages, heads, D, page],
+                {n: float(not jnp.array_equal(a[:, 1:], b[:, 1:]))
+                 for n, a, b in zip("kv", got, want)}, {"k": 0.0, "v": 0.0})
+            del got, want
         del q, k, v
 
     return {"device": device, "kernels": rows,
@@ -561,7 +577,9 @@ def phase_serve(sizes: dict, rehearsal: bool) -> None:
                      - warm.get("decode_tokens", 0))
     emit("serve", device={k: device[k] for k in ("platform", "kind",
                                                  "count")},
-         decode_impl=stats["decode_impl"], kv_dtype=stats["kv_dtype"],
+         decode_impl=stats["decode_impl"],
+         decode_write_impl=stats["decode_write_impl"],
+         kv_dtype=stats["kv_dtype"],
          requests=len(got),
          tokens_returned={k: len(v) for k, v in got.items()},
          engine_compiles=stats["compiles"],
@@ -606,6 +624,8 @@ def phase_serve(sizes: dict, rehearsal: bool) -> None:
           "no decode step carried more than one sequence")
     check(stats["decode_impl"] == "pallas" or rehearsal,
           f"decode dispatched to {stats['decode_impl']}")
+    check(stats["decode_write_impl"] == "pallas" or rehearsal,
+          f"decode's write dispatched to {stats['decode_write_impl']}")
 
 
 def main() -> int:

@@ -105,7 +105,8 @@ from ray_tpu.inference.scheduler import (DeadlineExceededError,
                                          Request, SlotScheduler)
 from ray_tpu.inference.spec import DraftState
 from ray_tpu.models import gpt as gpt_mod
-from ray_tpu.ops.attention import _NEG_INF, decode_uses_pallas
+from ray_tpu.ops.attention import (_NEG_INF, decode_uses_pallas,
+                                   decode_write_uses_pallas)
 from ray_tpu.util import tracing
 
 
@@ -1032,6 +1033,13 @@ class InferenceEngine:
         with self._lock:
             return self.scheduler.prefix_digest()
 
+    def _writes_in_place(self) -> bool:
+        """Whether a decode lays its rows into the live slots' tail
+        pages in place (the write kernel) or blends every slot's tail
+        page whole: ``kv_cache.append_decode``'s own decision."""
+        return decode_write_uses_pallas(self.cfg.head_dim, self.page_size,
+                                        self.cache.dtype)
+
     def stats(self) -> Dict[str, Any]:
         """The host's view, safe to read beside a running tick: a slot
         whose request has a token in flight counts as active, and
@@ -1049,6 +1057,9 @@ class InferenceEngine:
             "decode_impl": "pallas" if decode_uses_pallas(
                 self.cfg.head_dim, self.page_size,
                 quantized=self.cache.quantized) else "xla",
+            # and what lays a decode's new rows into the pool
+            "decode_write_impl": ("pallas" if self._writes_in_place()
+                                  else "blend"),
             "kv_bytes_per_slot": self.cache.bytes_per_slot(
                 self.max_pages_per_slot),
             "max_queue": self.max_queue,
@@ -1657,7 +1668,11 @@ class InferenceEngine:
                 wall, active=delivered,
                 ahead=bool(sp.attributes["ahead"]),
                 pages_read=sp.attributes["pages"],
-                pages_table=self.slots * self.max_pages_per_slot)
+                pages_table=self.slots * self.max_pages_per_slot,
+                rows_written=sp.attributes["active"],
+                tail_pages_rewritten=(sp.attributes["active"]
+                                      if self._writes_in_place()
+                                      else self.slots))
 
     def _level(self) -> None:
         """Bring the host level with the device from outside a tick:
@@ -1925,7 +1940,8 @@ class InferenceEngine:
         each layer hands ``layer_apply`` the opaque ``cache = (layer
         index, caches)``, which round-trips to ``attn_hook``; the hook
         writes the new tokens into their pages at ``(layer, page)``
-        (``kv_cache.append``), attends over the pool in place
+        (``kv_cache.append``; a decode's rows in place,
+        ``kv_cache.append_decode``), attends over the pool in place
         (``kv_cache.attend``; a cached-suffix prefill over one slot's
         gathered pages, ``kv_cache.context_dense``), and returns the
         updated stacked arrays for the carry — so only the touched
@@ -2010,7 +2026,7 @@ class InferenceEngine:
 
         The benchmark finds these executables and their operations by
         name (``jit_prefill*``, ``jit_decode``, ``gpt/attn/gather``,
-        ``attn/decode_pallas``): the traced
+        ``attn/decode_pallas``, ``attn/write_pallas``): the traced
         function carries the kind's name and no scope is added here."""
         cfg = self.cfg
         lora_on = self.lora_cfg is not None
@@ -2028,8 +2044,8 @@ class InferenceEngine:
                 tokens = tokens[:, None]
 
                 def attn_hook(q, k, v, cache):
-                    cache = kvc.append(kvc.write_decode, cache, k[:, 0],
-                                       v[:, 0], page_table, lengths)
+                    cache = kvc.append_decode(cache, k[:, 0], v[:, 0],
+                                              page_table, lengths)
                     o = kvc.attend(q[:, 0], cache, page_table, lengths + 1)
                     return o[:, None], cache[1]
             elif kind == "prefill":

@@ -976,7 +976,9 @@ def write_decode(pages, new, layer, page_table, lengths):
     [B, H, D]; layer: traced scalar; page_table: [B, max_pages] int32;
     lengths: [B] int32 — the token's absolute position (inactive slots
     point at the garbage page).  Each slot's tail page is rewritten
-    whole with the token laid over it (:func:`_blend_pages`).  Returns
+    whole with the token laid over it (:func:`_blend_pages`): the
+    decode's writer where the write kernel does not run, and of an
+    int8 cache's scales everywhere (:func:`append_decode`).  Returns
     the updated stacked array."""
     page_size = pages.shape[-1]
     page = jnp.take_along_axis(page_table,
@@ -1015,6 +1017,31 @@ def append(write, cache, k, v, *where):
         rows = (kq, vq, ks, vs)
     return layer, tuple(write(a, r, layer, *where)
                         for a, r in zip(arrays, rows))
+
+
+def append_decode(cache, k, v, page_table, lengths):
+    """A decode's :func:`append`: one new row ``[B, H, D]`` of K and of
+    V per slot at ``page_table, lengths``.  Where the pools block for
+    the write kernel (``ops/attention.py:decode_write_uses_pallas``, the
+    one decision, from the backend and the pool's shape and dtype) the
+    rows of the slots that hold a sequence are laid into their tail
+    pages in place, K and V in one kernel; a slot whose tail page is
+    the garbage page writes nothing, nothing reads that page below a
+    length mask.  Everywhere else, and for an int8 cache's rank-4
+    scale pools, :func:`write_decode` blends whole pages as before."""
+    from ray_tpu.ops.attention import decode_write, decode_write_uses_pallas
+    layer, arrays = cache
+    if not decode_write_uses_pallas(arrays[0].shape[-2],
+                                    arrays[0].shape[-1], arrays[0].dtype):
+        return append(write_decode, cache, k, v, page_table, lengths)
+    scales = ()
+    if len(arrays) == 4:
+        (k, ks), (v, vs) = _quantize_rows(k), _quantize_rows(v)
+        scales = tuple(write_decode(a, r, layer, page_table, lengths)
+                       for a, r in zip(arrays[2:], (ks, vs)))
+    return layer, tuple(decode_write(
+        arrays[0], arrays[1], k, v, lengths, page_table, layer,
+        skip_page=GARBAGE_PAGE)) + scales
 
 
 def attend(q, cache, page_table, lengths):

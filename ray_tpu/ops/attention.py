@@ -1599,3 +1599,113 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
 
     sharded_fn.fused_rope = False
     return sharded_fn
+
+
+# ---------------------------------------------------------------------------
+# the decode's cache write, in place
+#
+# One new K and V row per live slot, laid into the slot's tail page of
+# one layer where the page lies.  The page offset is the pool's minor
+# dimension, so a row is one lane of every ``[D, page]`` tile of its
+# page and cannot be written narrower than the page: a grid step moves
+# one tail page of K and of V in, lays the row over lane ``offset`` and
+# moves them out, and the pools are aliased in and out, so the pages no
+# step visits keep what they held and nothing of a pool is copied.  The
+# grid is the live slots, compacted outside the kernel as
+# :func:`_live_pages` compacts the live pages; a decode pays for the
+# slots that hold a sequence, not for the table's rows.
+# ---------------------------------------------------------------------------
+
+def _write_kernel(slot_ref, page_ref, off_ref, layer_ref, kn_ref, vn_ref,
+                  k_ref, v_ref, ko_ref, vo_ref):
+    """kn, vn [1, H, D] (the slot's new rows); k, v, ko, vo
+    [1, 1, H, D, page] (its tail page, in and out).  A row arrives with
+    head_dim on the lanes and leaves as one lane of ``[D, page]`` tiles:
+    it is turned once, ``[H, D] -> [D, H]`` in float32 (exact for every
+    pool dtype), and each head's column is spread over the lanes."""
+    del slot_ref, page_ref, layer_ref        # the index maps read them
+    H, D, page = k_ref.shape[2:]
+    off = off_ref[pl.program_id(0)]
+    hit = jax.lax.broadcasted_iota(jnp.int32, (D, page), 1) == off
+    for new_ref, in_ref, out_ref in ((kn_ref, k_ref, ko_ref),
+                                     (vn_ref, v_ref, vo_ref)):
+        cols = new_ref[0].astype(jnp.float32).T              # [D, H]
+        for h in range(H):
+            col = jnp.broadcast_to(cols[:, h:h + 1], (D, page))
+            out_ref[0, 0, h] = jnp.where(hit, col.astype(out_ref.dtype),
+                                         in_ref[0, 0, h])
+
+
+def _live_rows(lengths, page_table, page: int, skip_page: int):
+    """The work list of a decode's write: ``(count, slot, page, offset)``
+    — for each of the ``count`` slots whose tail page is not
+    ``skip_page``, in slot order, the slot, the pool page its next row
+    lands in and the row's offset there (lists ``[B]`` long, meaningless
+    past ``count``).  A slot whose length has run off its row of the
+    table (a full sequence that sits this decode out) is no step."""
+    B, max_pages = page_table.shape
+    j = lengths // page
+    tail = jnp.where(j < max_pages,
+                     page_table[jnp.arange(B), jnp.minimum(j, max_pages - 1)],
+                     skip_page)
+    ends = jnp.cumsum(tail != skip_page)
+    g = jnp.arange(B, dtype=jnp.int32)
+    slot = jnp.minimum((g[:, None] >= ends[None, :]).sum(1), B - 1)
+    return ends[-1], slot, tail[slot], (lengths % page)[slot]
+
+
+def decode_write_uses_pallas(D: int, page: int, dtype) -> bool:
+    """Whether :func:`decode_write` can lay a decode's rows into a
+    ``dtype`` pool of this geometry — the single source of the decision
+    (the cache's writer asks, the engine reports it): wherever kernels
+    are compiled (a TPU) and the pool blocks, as for the attention
+    (:func:`decode_uses_pallas`).  Where the CPU was asked for the
+    cache keeps its whole-page blend; ``use_interpret`` refuses a
+    backend nobody asked for."""
+    return not _use_interpret() and _decode_supports(
+        D, page, jnp.dtype(dtype).itemsize == 1)
+
+
+def decode_write(k, v, k_new, v_new, lengths, page_table, layer, *,
+                 skip_page: int):
+    """Lay one new row per live slot into the paged KV pools, in place.
+
+    k, v: [L, P, H, D, page] — the cache's whole stacked pools; k_new,
+    v_new: [B, H, D] — each slot's new row; lengths: [B] int32 — the
+    row's absolute position in its slot; page_table: [B, max_pages]
+    int32; layer: int32 scalar, traced or not.  A slot whose tail page
+    (``page_table[b, lengths[b] // page]``) is ``skip_page`` holds no
+    sequence and is no step of the grid.  No two live slots may share a
+    tail page.  Returns the two pools, which alias the arguments."""
+    B, H, D = k_new.shape
+    page = k.shape[-1]
+    if not _decode_supports(D, page, k.dtype.itemsize == 1):
+        raise ValueError(f"write kernel cannot block page={page}, D={D} "
+                         f"of a {k.dtype} pool")
+    # the work list runs under the kernel's name, and not under the
+    # attention's: a trace reads each alone
+    with jax.named_scope("attn/write_pallas"):
+        count, slot, pages, offs = _live_rows(
+            lengths.astype(jnp.int32), page_table.astype(jnp.int32), page,
+            skip_page)
+        row_spec = pl.BlockSpec((1, H, D),
+                                lambda g, slot, *_: (slot[g], 0, 0))
+        page_spec = pl.BlockSpec(
+            (1, 1, H, D, page),
+            lambda g, slot, pages, offs, lay: (lay[0], pages[g], 0, 0, 0))
+        return pl.pallas_call(
+            _write_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(count,),
+                in_specs=[row_spec, row_spec, page_spec, page_spec],
+                out_specs=[page_spec, page_spec],
+            ),
+            compiler_params=_CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            input_output_aliases={6: 0, 7: 1},
+            interpret=_use_interpret(),
+        )(slot, pages, offs, jnp.asarray(layer, jnp.int32).reshape(1),
+          k_new.astype(k.dtype), v_new.astype(v.dtype), k, v)

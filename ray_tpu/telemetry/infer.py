@@ -75,6 +75,11 @@ class InferTelemetry:
         # name (slots x max pages a slot): what reading the pool in
         # place saves over a padded context
         self.decode_pages = [0, 0]
+        # rows of K/V the plain decodes laid into the pool (one a
+        # dispatched row) and tail pages they moved to lay them: the
+        # same count where the write is in place, every slot's page
+        # where whole pages are blended
+        self.decode_writes = [0, 0]
         # speculative decoding (r21): cumulative proposed/accepted
         # draft counts and verify-step count — the accept rate is the
         # one number that decides whether speculation pays
@@ -118,18 +123,23 @@ class InferTelemetry:
 
     def record_decode(self, wall_s: float, *, active: int,
                       ahead: bool = False, pages_read: int = 0,
-                      pages_table: int = 0) -> None:
+                      pages_table: int = 0, rows_written: int = 0,
+                      tail_pages_rewritten: int = 0) -> None:
         """One plain decode, recorded when its tokens are on the host.
         ``ahead``: it was dispatched on the device's own tokens, ahead
         of the host (``inference/engine.py``), and not by a synchronous
         tick.  ``pages_read`` of ``pages_table``: the pages its rows
-        held at the dispatch, of those its page table has room for."""
+        held at the dispatch, of those its page table has room for.
+        ``rows_written``: the rows it laid into the pool;
+        ``tail_pages_rewritten``: the pages it moved to lay them."""
         if not self.enabled:
             return
         self.decode_count += 1
         self.decode_dispatches[bool(ahead)] += 1
         self.decode_pages[0] += pages_read
         self.decode_pages[1] += pages_table
+        self.decode_writes[0] += rows_written
+        self.decode_writes[1] += tail_pages_rewritten
         self.decode_tokens += active
         self.decodes.append({"wall_s": wall_s, "active": active})
         del self.decodes[:-self._MAX_RECORDS]
@@ -334,7 +344,9 @@ class InferTelemetry:
             out["decode"] = {"dispatches": sync + ahead,
                              "ahead_share": ahead / (sync + ahead),
                              "pages_read": self.decode_pages[0],
-                             "pages_table": self.decode_pages[1]}
+                             "pages_table": self.decode_pages[1],
+                             "rows_written": self.decode_writes[0],
+                             "tail_pages_rewritten": self.decode_writes[1]}
         if self.spec_verify_steps:
             out["spec"] = {
                 "verify_steps": self.spec_verify_steps,

@@ -2100,6 +2100,46 @@ def test_handoff_in_the_row_major_wire_format_installs_and_decodes(tiny_f32):
     assert engine.stats()["imports"] == 1 and engine.leak_free()
 
 
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_append_decode_in_place_matches_the_blend(kv_dtype, monkeypatch):
+    """``append_decode`` where its one decision says the pools block:
+    K and V (an int8 cache's codes) go through the write kernel, the
+    int8 scales through the whole-page blend, and every array comes out
+    equal to the bit to ``append(write_decode, ...)``'s but for the
+    garbage page, which only the blend's dead slot rewrites."""
+    import jax.numpy as jnp
+
+    from ray_tpu.inference import kv_cache as kvc
+    from ray_tpu.ops import attention
+    L, P, ps, H, D = 2, 6, 128, 2, 32
+    rng = np.random.default_rng(1)
+    cache = kvc.KVCache(n_layers=L, num_pages=P, page_size=ps, n_heads=H,
+                        head_dim=D, dtype=jnp.bfloat16, kv_dtype=kv_dtype)
+    cache.state = tuple(
+        jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
+        if a.dtype == jnp.int8
+        else jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+        for a in cache.state)
+    table = np.array([[3, 1], [0, 0], [2, 4]], np.int32)   # slot 1 is free
+    lengths = np.array([ps + 7, 0, ps - 1], np.int32)
+    k, v = (jnp.asarray(rng.normal(size=(3, H, D)), jnp.bfloat16)
+            for _ in range(2))
+    at = ((jnp.int32(1), cache.state), k, v, table, lengths)
+    _layer, want = kvc.append(kvc.write_decode, *at)
+    monkeypatch.setattr(attention, "decode_write_uses_pallas",
+                        lambda D, page, dtype: True)
+    layer, got = kvc.append_decode(*at)
+    assert int(layer) == 1 and len(got) == len(want) == len(cache.state)
+    for a, b, before in zip(got, want, cache.state):
+        a, b = np.array(a), np.array(b)
+        assert a.dtype == b.dtype
+        if a.ndim == 5:         # the kernel's: the garbage page as it was
+            np.testing.assert_array_equal(a[1, 0], np.asarray(before)[1, 0])
+            b[1, 0] = a[1, 0]
+        np.testing.assert_array_equal(a, b)
+    assert not np.array_equal(np.asarray(got[0]), np.asarray(cache.state[0]))
+
+
 def test_decode_counts_the_pages_it_reads_of_those_its_table_names(tiny_f32):
     """``decode.pages_read`` over ``decode.pages_table``: each plain
     decode adds its dispatched rows' live pages (the context with the
@@ -2129,3 +2169,45 @@ def test_decode_counts_the_pages_it_reads_of_those_its_table_names(tiny_f32):
     assert 0 < read < table
     assert off.telemetry.summary() == {"enabled": False}
     assert off.telemetry.decode_pages == [0, 0]
+
+
+@pytest.mark.parametrize("in_place", [False, True], ids=["blend", "kernel"])
+def test_decode_counts_the_rows_it_lays_and_the_pages_it_moves(
+        tiny_f32, monkeypatch, in_place):
+    """``decode.rows_written`` and ``decode.tail_pages_rewritten``, two
+    counts: every dispatched row lays one row; the whole-page blend
+    (the CPU's path) moves every slot's tail page to do it, the write
+    kernel the live slots' alone.  The engine reports the one decision
+    (``ops/attention.py:decode_write_uses_pallas``) beside the
+    attention's, and decodes the same tokens either way: the kernel arm
+    runs the real kernel, in interpret mode, where the decision is made
+    to say yes."""
+    from ray_tpu.inference import engine as engine_mod
+    from ray_tpu.ops import attention
+    cfg, params = tiny_f32
+    slots, jobs = 3, [(5, 4), (30, 6)]     # (prompt tokens, new tokens)
+
+    def run():
+        # a 128-token page and head_dim 16 block; executables of its own
+        engine = _make_engine(cfg, params, page_size=128, slots=slots,
+                              telemetry=True, executable_cache={})
+        tokens = {engine.submit(_prompt(n, cfg.vocab_size, seed=i),
+                                max_new_tokens=m): []
+                  for i, (n, m) in enumerate(jobs)}
+        while engine.has_work():
+            for rid, token, _done in engine.step():
+                tokens[rid].append(token)
+        return (list(tokens.values()), engine.stats()["decode_write_impl"],
+                engine.telemetry.summary()["decode"])
+
+    rows = sum(m - 1 for _n, m in jobs)
+    tokens, impl, decode = run()
+    assert [len(t) for t in tokens] == [m for _n, m in jobs]
+    assert impl == "blend" and decode["rows_written"] == rows
+    assert decode["tail_pages_rewritten"] == decode["dispatches"] * slots
+    if in_place:
+        for mod in (attention, engine_mod):
+            monkeypatch.setattr(mod, "decode_write_uses_pallas",
+                                lambda D, page, dtype: True)
+        assert run() == (tokens, "pallas", {**decode,
+                                            "tail_pages_rewritten": rows})

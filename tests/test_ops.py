@@ -698,6 +698,101 @@ def test_decode_attention_dispatch():
                            k_scale=jnp.ones(k.shape[:3] + k.shape[4:]))
 
 
+# the decode's cache write: slots x (pages a slot, tokens it already
+# holds); the new row lands at a page's first offset, mid-page, at its
+# last offset, on a second page, and one slot has run off its table
+_WRITE_LENGTHS = (0, 37, _PAGE - 1, _PAGE + 5, 2 * _PAGE)
+_WRITE_LIVE = {"none": (), "one": (2,), "some": (0, 2, 3),
+               "all": (0, 1, 2, 3)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("live", sorted(_WRITE_LIVE))
+@pytest.mark.parametrize("heads", [12, 20])
+def test_decode_write_lays_the_live_rows_in_place(heads, live, dtype):
+    """The write kernel (interpret mode here, Mosaic on the chip) leaves
+    the pools equal to the bit to what the cache's whole-page blend
+    leaves, in every page but the garbage page, which the blend's dead
+    slots rewrite and the kernel never visits: no live slot, one, a dead
+    slot between two live ones, all; pages in no table, and the other
+    layer, keep what they held."""
+    import numpy as np
+
+    from ray_tpu.inference import kv_cache as kvc
+    L, D, B, mp = 2, 64, len(_WRITE_LENGTHS), 2
+    P = 1 + B * mp + 2                       # two pages in no table
+    rng = np.random.default_rng(heads + len(live))
+
+    def draw(*shape):
+        if dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        return jnp.asarray(rng.normal(size=shape), dtype)
+
+    k, v = draw(L, P, heads, D, _PAGE), draw(L, P, heads, D, _PAGE)
+    k_new, v_new = draw(B, heads, D), draw(B, heads, D)
+    table = rng.permutation(np.arange(1, 1 + B * mp)).reshape(B, mp) \
+        .astype(np.int32)
+    held = [i for i in range(B) if i not in _WRITE_LIVE[live]]
+    table[held] = kvc.GARBAGE_PAGE           # free, or sitting it out
+    lengths = jnp.asarray(_WRITE_LENGTHS, jnp.int32)
+    layer = jnp.int32(1)
+    got = A.decode_write(k, v, k_new, v_new, lengths, jnp.asarray(table),
+                         layer, skip_page=kvc.GARBAGE_PAGE)
+    for pool, new, out in zip((k, v), (k_new, v_new), got):
+        want = np.array(kvc.write_decode(pool, new, layer,
+                                         jnp.asarray(table), lengths))
+        out, before = np.array(out), np.asarray(pool)
+        assert out.dtype == want.dtype and out.shape == want.shape
+        want[1, kvc.GARBAGE_PAGE] = out[1, kvc.GARBAGE_PAGE]
+        np.testing.assert_array_equal(out, want)
+        np.testing.assert_array_equal(out[1, kvc.GARBAGE_PAGE],
+                                      before[1, kvc.GARBAGE_PAGE])
+        untouched = [p for p in range(P) if p not in table[
+            list(_WRITE_LIVE[live])].ravel()]
+        np.testing.assert_array_equal(out[1, untouched],
+                                      before[1, untouched])
+        np.testing.assert_array_equal(out[0], before[0])
+        for i in _WRITE_LIVE[live]:          # and the row is where it goes
+            n = _WRITE_LENGTHS[i]
+            np.testing.assert_array_equal(
+                out[1, table[i, n // _PAGE], :, :, n % _PAGE],
+                np.asarray(new[i]))
+
+
+def test_decode_write_dispatch():
+    """``decode_write_uses_pallas`` is the one decision: the kernel
+    wherever kernels are compiled and the pool blocks (whole 128-lane
+    pages, a head_dim that fills the dtype's sublane tiles), the
+    cache's blend where the CPU was asked for; ``append_decode`` asks
+    it and nothing else, and the kernel itself refuses a pool it cannot
+    block."""
+    import numpy as np
+
+    from ray_tpu.inference import kv_cache as kvc
+    from ray_tpu.ops import substrate
+    assert not A.decode_write_uses_pallas(64, 128, jnp.bfloat16)  # the CPU
+    with substrate.compile_for_tpu():
+        assert A.decode_write_uses_pallas(64, 128, jnp.bfloat16)
+        assert A.decode_write_uses_pallas(64, 256, jnp.int8)
+        assert not A.decode_write_uses_pallas(64, 16, jnp.bfloat16)
+        assert not A.decode_write_uses_pallas(16, 128, jnp.int8)
+        assert not A.decode_write_uses_pallas(8, 128, jnp.float32)
+    k = jnp.zeros((1, 3, 2, 64, 16), jnp.bfloat16)
+    new = jnp.ones((1, 2, 64), jnp.bfloat16)
+    table, lengths = jnp.array([[1, 2]], jnp.int32), jnp.array([17])
+    with pytest.raises(ValueError, match="cannot block"):
+        A.decode_write(k, k, new, new, lengths, table, 0, skip_page=0)
+    # where the CPU was asked for, a decode's append is the blend's
+    layer, (ka, va) = kvc.append_decode((jnp.int32(0), (k, k)), new, new,
+                                        table, lengths)
+    _l, (kb, vb) = kvc.append(kvc.write_decode, (jnp.int32(0), (k, k)),
+                              new, new, table, lengths)
+    np.testing.assert_array_equal(np.asarray(ka), np.asarray(kb))
+    np.testing.assert_array_equal(np.asarray(va), np.asarray(vb))
+    assert np.asarray(ka)[0, 2, :, :, 1].all()
+
+
 # ---------------------------------------------------------------------------
 # fused norm epilogues (r13): out-proj matmul + residual + rmsnorm in
 # one kernel, and the ln_f-in-flash-CE prologue

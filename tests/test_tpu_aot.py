@@ -119,6 +119,40 @@ def test_decode_attention_compiles_for_v5e(v5e, kv_dtype):
 _SERVE_CELLS = {"124m": ("gpt2", 128, 1025), "large": ("gpt2_large", 64, 513)}
 
 
+@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8])
+@pytest.mark.parametrize("cell", sorted(_SERVE_CELLS))
+def test_decode_write_compiles_for_v5e(v5e, cell, kv_dtype):
+    """The write kernel alone, at the two serve cells' geometry: Mosaic
+    takes it, "the pool blocks" is true of both pools a cache can hold,
+    and the pools go in and come out as they are, with no copy and
+    nothing of a pool's size beside them."""
+    from ray_tpu.models.gpt import GPTConfig
+
+    preset, slots, pages = _SERVE_CELLS[cell]
+    cfg = getattr(GPTConfig, preset)(vocab_size=V, max_seq=CTX, dtype=BF16)
+    pool = ((cfg.n_layers, pages, cfg.n_heads, D, PAGE), kv_dtype)
+    rows = ((slots, cfg.n_heads, D), BF16)
+    with substrate.compile_for_tpu():
+        assert attention.decode_write_uses_pallas(D, PAGE, kv_dtype)
+
+    def step(k, v, k_new, v_new, lengths, page_table, layer):
+        return attention.decode_write(k, v, k_new, v_new, lengths,
+                                      page_table, layer, skip_page=0)
+
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in (
+        pool, pool, rows, rows, ((slots,), jnp.int32),
+        ((slots, CTX // PAGE), jnp.int32), ((), jnp.int32))]
+    with substrate.compile_for_tpu():
+        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*specs) \
+            .compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    dims = "[" + ",".join(map(str, pool[0])) + "]"
+    assert not [ln for ln in hlo.splitlines()
+                if dims in ln.split(" copy(")[0] and " copy(" in ln]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached",
                                   "verify"])
 @pytest.mark.parametrize("cell", sorted(_SERVE_CELLS))
@@ -133,9 +167,11 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, cell, kind):
     and padded 2.7x, before and after the layer loop; a per-layer
     dynamic-slice copies a layer's pool out and back in every layer.
     Neither shows on the CPU.)  The decode attends over the pool where
-    it lies: its executable holds one kernel and no operation whose
-    result is the slots' padded context, gathered or turned, and its
-    temporaries are the logits and the touched tail pages, not a
+    it lies and lays its new rows into the live slots' tail pages in
+    place: its executable holds two kernels, the write and the
+    attention, no operation whose result is the slots' padded context,
+    gathered or turned, or every slot's tail page (the blend's
+    ``[slots, H, D, page]``), and its temporaries are the logits, not a
     context."""
     import re
 
@@ -186,13 +222,14 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, cell, kind):
             assert shape not in (dims((slots, CTX, heads, D)),
                                  dims((slots, mp, page, heads, D)),
                                  dims((slots, mp, heads, D, page)),
-                                 dims((slots, heads, CTX, D))), ln
+                                 dims((slots, heads, CTX, D)),
+                                 dims((slots, heads, D, page))), ln
         if shape == dims(stacked):
             assert not op.startswith("copy"), ln
             layouts.add(re.sub(r"S\(\d+\)", "", layout))
     assert len(layouts) == 1, layouts
     if kind == "decode":
-        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
         assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
@@ -259,6 +296,7 @@ def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
     assert substrate.cpu_requested()
     assert substrate.use_interpret() is True
     assert not attention.decode_uses_pallas(D, PAGE, impl="auto")
+    assert not attention.decode_write_uses_pallas(D, PAGE, BF16)
     # the same backend when nobody asked for it is a chip that failed to
     # initialise, or a worker started without one: an error, not a
     # quiet interpret-mode run
@@ -267,6 +305,8 @@ def test_interpret_mode_only_where_the_cpu_was_asked_for(monkeypatch):
         substrate.use_interpret()
     with pytest.raises(RuntimeError, match="neither a TPU was found"):
         attention.decode_uses_pallas(D, PAGE, impl="auto")
+    with pytest.raises(RuntimeError, match="neither a TPU was found"):
+        attention.decode_write_uses_pallas(D, PAGE, BF16)
     # compiling for a described TPU needs no backend at all
     with substrate.compile_for_tpu():
         assert substrate.use_interpret() is False
