@@ -228,8 +228,14 @@ class GPTDeployment:
                 if isinstance(item, BaseException):
                     raise item       # pump died: surface, don't hang
                 token, done, logprob = item
-                yield ({"token": token, "logprob": logprob}
-                       if want_logprobs else token)
+                # the yield returns when the consumer asks for the next
+                # item, and the consumers here (the replica's re-yield,
+                # the worker's commit of the item to the object store)
+                # await nothing in between: the span is what one
+                # streamed token holds the event loop for
+                with tracing.span("serve/emit", rid=rid, done=int(done)):
+                    yield ({"token": token, "logprob": logprob}
+                           if want_logprobs else token)
                 if done:
                     return
         finally:
@@ -270,16 +276,21 @@ class GPTDeployment:
             raise
 
     async def _pump_engine(self, loop) -> None:
-        while self.engine.has_work():
+        more = self.engine.has_work()
+        while more:
             events = await loop.run_in_executor(None,
                                                 self.engine.step)
             # from the tick's return to the last queue fed: the time
             # between two ticks is the serve front's, and this is the
-            # part of it the pump itself spends
+            # part of it the pump itself spends.  ``more=0``: the pump
+            # stops here, and until the next tick the replica has
+            # nothing to serve
             with tracing.span("serve/fanout", events=len(events),
-                              streams=len(self._queues)):
+                              streams=len(self._queues)) as sp:
                 self._fan_out(events)
                 self._reap_idle_streams()
+                more = self.engine.has_work()
+                sp.set(more=int(more))
 
     def _fan_out(self, events) -> None:
         for ev in events:

@@ -342,6 +342,7 @@ class InferTelemetry:
         if any(self.decode_dispatches):
             sync, ahead = self.decode_dispatches
             out["decode"] = {"dispatches": sync + ahead,
+                             "dispatches_ahead": ahead,
                              "ahead_share": ahead / (sync + ahead),
                              "pages_read": self.decode_pages[0],
                              "pages_table": self.decode_pages[1],

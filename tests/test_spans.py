@@ -16,8 +16,9 @@ sys.path.insert(0, REPO)        # benchmark/ is read-only tooling here
 ENGINE_SPANS = ("infer/step", "infer/admit", "infer/prefill",
                 "infer/prefill_cached", "infer/decode", "infer/sample",
                 "infer/deliver", "infer/compile")
-NEW_NAMES = ENGINE_SPANS + ("serve/fanout", "loss_read", "train/record",
-                            "train/dispatch", "train/sync")
+NEW_NAMES = ENGINE_SPANS + ("serve/fanout", "serve/emit", "more",
+                            "loss_read", "train/record", "train/dispatch",
+                            "train/sync")
 
 
 def _host_threads(path):
@@ -26,10 +27,10 @@ def _host_threads(path):
     out = {}
     for plane in read_xplane(
             path, want_lines=lambda n: n.startswith("/host:")):
-        for line in plane.lines:
+        for index, line in enumerate(plane.lines):
             evs = [e for e in line.events if not e.name.startswith("$")]
-            if evs:
-                out[f"{plane.name}/{line.name}"] = evs
+            if evs:     # two threads may share a name
+                out[f"{plane.name}/{line.name}#{index}"] = evs
     return out
 
 
@@ -229,13 +230,161 @@ def test_wrapped_train_step_spans_sit_inside_the_step_annotation(tmp_path):
     assert rec["loss"] == pytest.approx(float(_["loss"]))
 
 
-def test_span_off_records_nothing_and_changes_no_token(engine_trace):
+def _stream_two(dep):
+    """Two streams through the deployment's own pump, consumed with
+    asyncio: ([tokens of the first, of the second], their rids)."""
+    import asyncio
+    rids = []
+    submit = dep.engine.submit
+
+    def recording_submit(*args, **kwargs):
+        rids.append(submit(*args, **kwargs))
+        return rids[-1]
+
+    dep.engine.submit = recording_submit
+
+    async def consume(request):
+        return [token async for token in dep(request)]
+
+    async def run():
+        rng = np.random.RandomState(11)
+        vocab = dep.cfg.vocab_size
+        return await asyncio.gather(
+            consume({"tokens": list(rng.randint(0, vocab, size=20)),
+                     "max_new_tokens": 5}),
+            consume({"tokens": list(rng.randint(0, vocab, size=9)),
+                     "max_new_tokens": 3}))
+
+    tokens = asyncio.run(asyncio.wait_for(run(), timeout=300))
+    return tokens, rids
+
+
+def _tiny_deployment():
+    import jax.numpy as jnp
+
+    from ray_tpu.inference.serve_gpt import GPTDeployment
+    return GPTDeployment.func_or_class(
+        model="tiny", model_config={"dtype": jnp.float32},
+        engine_config={"slots": 2, "page_size": 16,
+                       "buckets": (16, 32, 64), "telemetry": True})
+
+
+@pytest.fixture(scope="module")
+def serve_trace(tmp_path_factory):
+    """One profiled run of a tiny ``GPTDeployment`` in process: the host
+    threads of its xplane, the two streams' tokens and their rids."""
+    import jax
+
+    from benchmark.reduce.xplane import find_xplane
+    dep = _tiny_deployment()
+    logdir = str(tmp_path_factory.mktemp("serve_trace"))
+    jax.profiler.start_trace(logdir)
+    try:
+        tokens, rids = _stream_two(dep)
+    finally:
+        jax.profiler.stop_trace()
+    path = find_xplane(logdir)
+    threads = _host_threads(path)
+
+    def named(*names):
+        return sorted((e for evs in threads.values() for e in evs
+                       if e.name in names), key=lambda e: e.start_ps)
+
+    return {"path": path, "threads": threads, "named": named,
+            "tokens": dict(zip(rids, tokens)), "rids": rids}
+
+
+def _one_emit_a_delivered_token(t):
+    emits = t["named"]("serve/emit")
+    assert len(emits) == sum(len(v) for v in t["tokens"].values()) == 8
+    for rid, tokens in t["tokens"].items():
+        assert sum(e.stats["rid"] == rid for e in emits) == len(tokens)
+
+
+def _emits_are_on_the_event_loops_thread(t):
+    def holding(name):
+        return {thread for thread, evs in t["threads"].items()
+                if any(e.name == name for e in evs)}
+    (loop,) = holding("serve/emit")
+    # the pump's own span is the loop's too; the ticks run on the executor
+    assert holding("serve/fanout") == {loop}
+    assert holding("infer/step") and loop not in holding("infer/step")
+
+
+def _an_emit_carries_the_rid_of_its_requests_prefill(t):
+    prefills = t["named"]("infer/prefill", "infer/prefill_cached")
+    assert sorted(p.stats["rid"] for p in prefills) == sorted(t["rids"])
+    assert {e.stats["rid"] for e in t["named"]("serve/emit")} == \
+        set(t["rids"])
+
+
+def _the_last_emit_of_a_stream_is_done(t):
+    for rid, tokens in t["tokens"].items():
+        done = [e.stats["done"] for e in t["named"]("serve/emit")
+                if e.stats["rid"] == rid]
+        assert done == [0] * (len(tokens) - 1) + [1]
+
+
+def _every_fanout_says_whether_the_pump_goes_on(t):
+    fans = t["named"]("serve/fanout")
+    steps = t["named"]("infer/step")
+    assert len(fans) == len(steps) >= 5
+    assert [f.stats["more"] for f in fans][-1] == 0
+    for f in fans:
+        # more=1: another tick starts, on the executor, before the pump
+        # opens its next fan-out; more=0: none until a request comes
+        after = [s for s in steps if s.start_ps >= f.end_ps]
+        assert f.stats["more"] == int(bool(after)), f.stats
+    assert sum(f.stats["events"] for f in fans) == 8
+
+
+def _no_emit_is_open_across_anothers_start(t):
+    emits = t["named"]("serve/emit")
+    for a, b in zip(emits, emits[1:]):
+        assert a.end_ps <= b.start_ps
+    assert all(e.dur_ps > 0 for e in emits)
+
+
+def _the_front_readers_find_the_spans_and_read_nothing_without_ticks(t):
+    from benchmark.reduce import front, spans
+    trace = spans.load(t["path"])
+    assert len(trace.named("serve/emit")) == 8
+    assert [f.stats["more"] for f in trace.named("serve/fanout")][-1] == 0
+    # no tick of the harness and no device plane here: nothing to divide
+    # by, and no reader raises
+    assert front.split(trace) is None
+    for name in (*front.IDLE_READERS, "emit_ms_per_tok",
+                 "pump_wait_ms_per_tick"):
+        assert front.read_metric(name, path=t["path"]) is None
+
+
+@pytest.mark.parametrize("holds", [
+    _one_emit_a_delivered_token, _emits_are_on_the_event_loops_thread,
+    _an_emit_carries_the_rid_of_its_requests_prefill,
+    _the_last_emit_of_a_stream_is_done,
+    _every_fanout_says_whether_the_pump_goes_on,
+    _no_emit_is_open_across_anothers_start,
+    _the_front_readers_find_the_spans_and_read_nothing_without_ticks,
+], ids=lambda f: f.__name__.strip("_"))
+def test_serve_front_spans(serve_trace, holds):
+    holds(serve_trace)
+
+
+def test_span_off_records_nothing_and_changes_no_token(engine_trace,
+                                                       serve_trace):
     from ray_tpu.util import tracing
     assert not tracing.is_enabled()
     tracing.clear_recorded()
     cfg, engine = _tiny_engine()
     tokens, first, second = _drive(engine, cfg.vocab_size)
     assert tracing.recorded_spans() == []
+    # the serve front's spans too: with no profile and tracing off the
+    # deployment streams the same tokens and nothing is kept
+    streamed, rids = _stream_two(_tiny_deployment())
+    assert tracing.recorded_spans() == []
+    assert streamed == [serve_trace["tokens"][r]
+                        for r in serve_trace["rids"]]
+    assert [len(s) for s in streamed] == [5, 3]
     with tracing.span("off", n=1) as sp:
         pass
     assert sp.dur is not None and sp.dur >= 0 and sp.end >= sp.start
