@@ -105,8 +105,8 @@ from ray_tpu.inference.scheduler import (DeadlineExceededError,
                                          Request, SlotScheduler)
 from ray_tpu.inference.spec import DraftState
 from ray_tpu.models import gpt as gpt_mod
-from ray_tpu.ops.attention import (_NEG_INF, decode_uses_pallas,
-                                   decode_write_uses_pallas)
+from ray_tpu.ops.attention import (_NEG_INF, absorb_query, expand_output,
+                                   latent_kv, latent_prefill_attention)
 from ray_tpu.util import tracing
 
 
@@ -157,6 +157,8 @@ class _Flight:
     #                                 its attributes (bucket, ahead)
     path: Optional[str] = None      # the sampler body, where counted
     logits: Any = None              # device logits, under debug_logits
+    moe: Any = None                 # the step's expert-layer counts
+    #                                 (device array), for a routed model
 
 
 @jax.jit
@@ -165,6 +167,19 @@ def _lay_token(tokens, slot, token):
     (a prefill's sample, still on the device) laid over the previous
     decode's sampled tokens, which are the next decode's input."""
     return tokens.at[slot].set(token[0])
+
+
+def _token_rows(kind: str, args):
+    """bool [B, S]: the rows of a step of ``kind`` that are tokens of a
+    sequence, from the step's own arguments (``InferenceEngine.
+    _build_step``): not a free slot's row of a decode (its table starts
+    at the garbage page), not a bucket's padding.  For a stack that
+    treats rows unequally (an expert layer computes and counts the
+    tokens alone)."""
+    if kind == "decode":
+        return args[2][:, :1] != kvc.GARBAGE_PAGE
+    tokens, last = args[0], args[-2]
+    return (jnp.arange(tokens.shape[1]) < last)[None]
 
 
 def _cached_context_attention(q, kctx, vctx, ks, vs, cached_len,
@@ -273,8 +288,16 @@ class InferenceEngine:
                  executable_cache: Optional[Dict[Any, Any]] = None,
                  lora: Union["LoraConfig", bool, None] = None,
                  adapter_store: Optional["AdapterStore"] = None):
-        if cfg.n_experts > 0:
-            raise NotImplementedError("MoE decode cache not supported yet")
+        if getattr(cfg, "n_experts", 0) > 0:
+            # a routed model is served through models/longcat.py's
+            # block, whose expert layer is dropless
+            raise NotImplementedError(
+                "GPTConfig(n_experts > 0) is not served: its expert layer "
+                "(parallel/moe.py:moe_layer) drops the picks past a "
+                "static capacity computed from the step's row count, so "
+                "a decode of a few rows through the cache cannot "
+                "reproduce a prefill's output row by row; the serve "
+                "path's routed layer is parallel/moe.py:dropless_moe")
         from ray_tpu._private.compile_cache import enable_compile_cache
         enable_compile_cache()
         icfg = infer_config()
@@ -326,11 +349,17 @@ class InferenceEngine:
             slots=self.slots, page_size=self.page_size,
             num_pages=num_pages, max_pages_per_slot=max_pages_per_slot,
             prefix=self.prefix, max_queue=self.max_queue)
-        self.cache = kvc.KVCache(
-            n_layers=cfg.n_layers, num_pages=num_pages,
-            page_size=self.page_size, n_heads=cfg.n_heads,
-            head_dim=cfg.head_dim, dtype=cfg.dtype,
-            kv_dtype=self.kv_dtype)
+        if self._latent:
+            self.cache = kvc.KVCache(
+                n_layers=cfg.cache_layers, num_pages=num_pages,
+                page_size=self.page_size, dtype=cfg.dtype,
+                kv_dtype=self.kv_dtype, latent=self._latent)
+        else:
+            self.cache = kvc.KVCache(
+                n_layers=cfg.n_layers, num_pages=num_pages,
+                page_size=self.page_size, n_heads=cfg.n_heads,
+                head_dim=cfg.head_dim, dtype=cfg.dtype,
+                kv_dtype=self.kv_dtype)
         # tiered KV cache (r23): HBM (tier 0, the refcounted pages
         # above) -> per-engine host-DRAM spill pool (tier 1) ->
         # fleet-shared content-addressed page store (tier 2).  ``store``
@@ -359,6 +388,10 @@ class InferenceEngine:
             self.store = None
         self.tiered = self.prefix and (self.host_pages > 0
                                        or self.store is not None)
+        if self._latent and self.tiered:
+            kvc.refuse_latent("the spill tiers (host_pages / store)")
+        if self._latent and self.spec:
+            kvc.refuse_latent("speculative decoding (spec)")
         if self.tiered:
             self.host_pool: Optional[kvc.HostPagePool] = \
                 kvc.HostPagePool(self.host_pages, store=self.store)
@@ -390,6 +423,8 @@ class InferenceEngine:
             self.lora_cfg = lora_config()
         else:
             self.lora_cfg = None
+        if self._latent and self.lora_cfg is not None:
+            kvc.refuse_latent("LoRA adapters (lora)")
         if self.lora_cfg is not None:
             self._lora_targets = lora_mod.effective_targets(
                 cfg, self.lora_cfg)
@@ -634,6 +669,8 @@ class InferenceEngine:
         on = self.spec if sampling.spec is None else bool(sampling.spec)
         if not on:
             return 0
+        if self._latent:
+            kvc.refuse_latent("speculative decoding (SamplingParams.spec)")
         k = (self.spec_k if sampling.spec_k is None
              else int(sampling.spec_k))
         if k < 1:
@@ -768,6 +805,8 @@ class InferenceEngine:
         seeds its slot with.  Registered full pages park idle in the
         prefix pool on release, so a later handoff of the same prefix
         still prefills nothing here."""
+        if self._latent:
+            kvc.refuse_latent("export_request (a KVHandoff)")
         req = self._held.pop(rid)
         context = list(req.prompt) + list(req.generated[:-1])
         n_pages = kvc.pages_needed(len(context), self.page_size)
@@ -819,6 +858,8 @@ class InferenceEngine:
         prefill side's first token is already delivered and seeds the
         sampling counts, so sampled continuations stay
         trajectory-exact, not just greedy ones)."""
+        if self._latent:
+            kvc.refuse_latent("import_submit (a KVHandoff)")
         if handoff.page_size != self.page_size:
             raise ValueError(
                 f"handoff page_size {handoff.page_size} != engine "
@@ -1033,13 +1074,6 @@ class InferenceEngine:
         with self._lock:
             return self.scheduler.prefix_digest()
 
-    def _writes_in_place(self) -> bool:
-        """Whether a decode lays its rows into the live slots' tail
-        pages in place (the write kernel) or blends every slot's tail
-        page whole: ``kv_cache.append_decode``'s own decision."""
-        return decode_write_uses_pallas(self.cfg.head_dim, self.page_size,
-                                        self.cache.dtype)
-
     def stats(self) -> Dict[str, Any]:
         """The host's view, safe to read beside a running tick: a slot
         whose request has a token in flight counts as active, and
@@ -1054,11 +1088,10 @@ class InferenceEngine:
             "cache_bytes": self.cache.bytes,
             "kv_dtype": self.kv_dtype,
             # what decode attention dispatches to at this geometry
-            "decode_impl": "pallas" if decode_uses_pallas(
-                self.cfg.head_dim, self.page_size,
-                quantized=self.cache.quantized) else "xla",
+            "decode_impl": ("pallas" if self.cache.reads_in_place
+                            else "xla"),
             # and what lays a decode's new rows into the pool
-            "decode_write_impl": ("pallas" if self._writes_in_place()
+            "decode_write_impl": ("pallas" if self.cache.writes_in_place
                                   else "blend"),
             "kv_bytes_per_slot": self.cache.bytes_per_slot(
                 self.max_pages_per_slot),
@@ -1279,15 +1312,15 @@ class InferenceEngine:
         ids = {"trace_id": tr.trace_id} if tr is not None else {}
         with tracing.span(f"infer/{kind}", rid=req.rid, bucket=bucket,
                           cached=cached, **ids) as sp:
-            logits = self._run_step((kind, bucket), [req], tokens,
-                                    *scalars, sched.page_table[slot])
+            logits, moe = self._run_step((kind, bucket), [req], tokens,
+                                         *scalars, sched.page_table[slot])
             out, path = self._sample_slots(sp, logits, [req])
         req.in_flight += 1
         self._register_prefix(req)
         sched.lengths[slot] = plen
         self._flight.append(_Flight(
             kind, [(0, req)], out, sp, path=path,
-            logits=logits if self.debug_logits else None))
+            logits=logits if self.debug_logits else None, moe=moe))
 
     def _land_prefill(self, rec: _Flight, ssp) -> None:
         """The records of a prefill whose first token has reached the
@@ -1565,19 +1598,21 @@ class InferenceEngine:
             page_table[dead, :] = kvc.GARBAGE_PAGE
         # the pages this decode's attention reads: each row's context
         # with the token it writes, by the host's own count
-        pages = int((lengths[[slot for slot, _req in rows]]
-                     // self.page_size + 1).sum())
+        live = lengths[[slot for slot, _req in rows]]
+        pages = int((live // self.page_size + 1).sum())
         with tracing.span("infer/decode", active=len(rows),
-                          ahead=int(ahead), pages=pages) as sp:
-            logits = self._run_step(("decode",), reqs,
-                                    self._token_input(rows), lengths,
-                                    page_table)
+                          ahead=int(ahead), pages=pages,
+                          tokens=int(live.sum()) + len(rows)) as sp:
+            logits, moe = self._run_step(("decode",), reqs,
+                                         self._token_input(rows), lengths,
+                                         page_table)
             out, path = self._sample_slots(sp, logits, reqs)
         for slot, req in rows:
             sched.lengths[slot] += 1    # the input token is cached
             req.in_flight += 1
         rec = _Flight("decode", rows, out, sp, path=path,
-                      logits=logits if self.debug_logits else None)
+                      logits=logits if self.debug_logits else None,
+                      moe=moe)
         self._flight.append(rec)
         return rec
 
@@ -1623,7 +1658,11 @@ class InferenceEngine:
             with tracing.span("infer/sample", rows=len(rec.rows)) as ssp:
                 if rec.path is not None:
                     ssp.set(path=rec.path)
-                toks, logps = jax.device_get(rec.out)
+                # a routed model's counts ride on the same fetch (None,
+                # and nothing more to fetch, for any other)
+                (toks, logps), moe = jax.device_get((rec.out, rec.moe))
+                if moe is not None:
+                    self._land_moe(rec, ssp, moe)
             with self._deliver_span(events):
                 for _row, req in rec.rows:
                     req.in_flight -= 1
@@ -1643,6 +1682,18 @@ class InferenceEngine:
                             host_logits[row])
                     self._deliver(req, int(toks[row]), float(logps[row]),
                                   events)
+
+    def _land_moe(self, rec: _Flight, ssp, moe) -> None:
+        """A routed model's expert-layer counts of one step
+        (``parallel/moe.py:MOE_COUNTS``, summed over its layers), on
+        the host: the fetch's span carries the picks this chip computed
+        and the experts they hit, and the telemetry adds them up."""
+        counts = dict(zip(self.cfg.step_counts, (int(c) for c in moe)))
+        ssp.set(moe_held=counts["held_picks"],
+                moe_hit=counts["experts_hit"])
+        if self.telemetry.enabled:
+            self.telemetry.record_moe(decode=rec.kind == "decode",
+                                      **counts)
 
     def _land_decode(self, rec: _Flight, ssp, delivered: int) -> None:
         """The records of a decode whose tokens have reached the host:
@@ -1671,7 +1722,7 @@ class InferenceEngine:
                 pages_table=self.slots * self.max_pages_per_slot,
                 rows_written=sp.attributes["active"],
                 tail_pages_rewritten=(sp.attributes["active"]
-                                      if self._writes_in_place()
+                                      if self.cache.writes_in_place
                                       else self.slots))
 
     def _level(self) -> None:
@@ -1745,7 +1796,7 @@ class InferenceEngine:
         tokens[0, 0] = req.generated[-1]
         tokens[0, 1:1 + n_drafts] = drafts
         with tracing.span("infer/verify", rid=req.rid, k=n_drafts) as sp:
-            logits = self._run_step(
+            logits, _moe = self._run_step(
                 ("verify", kb), [req], tokens, np.int32(L),
                 np.int32(n_drafts + 1), sched.page_table[slot])
             # every row samples under the key plain decode would use
@@ -1884,7 +1935,8 @@ class InferenceEngine:
     # ---------------------------------------------------- compile cache
     def _run_step(self, key, reqs, *step_args):
         """Run the serve executable ``key`` = ``(kind[, bucket])`` over
-        the cache -> its logits.  The one place an executable's
+        the cache -> (its logits, a routed model's expert-layer counts
+        or None).  The one place an executable's
         arguments are assembled: ``(params, [lora_bank,] *cache.state,
         *step_args[, adapter ids])``, the ids one per row of ``reqs``
         (co-batched tenants share a tick: the bank gather routes each
@@ -1898,8 +1950,11 @@ class InferenceEngine:
                               for r in reqs], np.int32),)
         args = (self.params, *bank, *self.cache.state, *step_args, *aids)
         logits, *state = self._get_compiled(key, args)(*args)
+        # a model that declares ``step_counts`` returns them here
+        moe = (state.pop(0) if getattr(self.cfg, "step_counts", ())
+               else None)
         self.cache.state = tuple(state)
-        return logits
+        return logits, moe
 
     def _get_compiled(self, key, example_args):
         kind = key[0]
@@ -1917,6 +1972,14 @@ class InferenceEngine:
         return fn
 
     # ------------------------------------------------------- step fns --
+    @property
+    def _latent(self) -> Optional[Tuple[int, int]]:
+        """What the model keeps in the cache: None for K and V rows
+        ``[H, D]`` a layer (a ``GPTConfig``), ``(rank, rope)`` for one
+        latent row a sublayer (a config that declares ``latent_row``
+        and ``cache_layers``: ``models/longcat.py``)."""
+        return getattr(self.cfg, "latent_row", None)
+
     def _embed(self, params, tokens, positions):
         """tokens [B, S], positions [S] or [B, S] -> hidden [B, S, d].
 
@@ -1995,10 +2058,14 @@ class InferenceEngine:
     def _build_step(self, kind: str):
         """The jitted serve step of ``kind``: ``(params, [lora_bank,]
         *cache_state, <kind's arguments>[, adapter_ids]) -> (logits
-        f32, *cache_state)``, the cache state donated.  Every kind
+        f32, [counts,] *cache_state)``, the cache state donated
+        (``counts``: one int32 vector, only from a model whose config
+        names them in ``step_counts``).  Every kind
         embeds, runs the layer stack with its own attention hook
         (append the new rows to the cache, read the context back,
-        attend) and applies the head to the rows it answers for:
+        attend: :meth:`_kv_hooks`, or :meth:`_latent_hooks` where the
+        cache keeps a latent row) and applies the head to the rows it
+        answers for:
 
         - ``"prefill"`` (tokens [1, S_bucket], length, page_row
           [max_pages]): a cold prompt.  Attention is causal over the
@@ -2031,6 +2098,13 @@ class InferenceEngine:
         cfg = self.cfg
         lora_on = self.lora_cfg is not None
         n_state = len(self.cache.state)
+        # the hook follows what the cache keeps, the stack whose model
+        # it is: a config that runs its own (``models/longcat.py``)
+        # offers ``serve_hidden`` and ``lm_head``, and names in
+        # ``step_counts`` what its step returns beside the logits
+        hooks = self._latent_hooks if self._latent else self._kv_hooks
+        own_stack = getattr(cfg, "serve_hidden", None)
+        counted = bool(getattr(cfg, "step_counts", ()))
 
         def step(params, *args):
             bank = aids = None
@@ -2038,52 +2112,124 @@ class InferenceEngine:
                 bank, *args = args
                 *args, aids = args
             cache_state, args = tuple(args[:n_state]), args[n_state:]
-            if kind == "decode":
-                tokens, lengths, page_table = args
-                positions = lengths[:, None]                   # [B, 1]
-                tokens = tokens[:, None]
-
-                def attn_hook(q, k, v, cache):
-                    cache = kvc.append_decode(cache, k[:, 0], v[:, 0],
-                                              page_table, lengths)
-                    o = kvc.attend(q[:, 0], cache, page_table, lengths + 1)
-                    return o[:, None], cache[1]
-            elif kind == "prefill":
-                tokens, last, page_row = args
-                positions = jnp.arange(tokens.shape[1])
-
-                def attn_hook(q, k, v, cache):
-                    cache = kvc.append(kvc.write_prefill, cache, k[0],
-                                       v[0], page_row)
-                    return self._prefill_attention(q, k, v), cache[1]
+            tokens, positions, last, attn_hook = hooks(kind, args)
+            counts = ()
+            if own_stack is None:
+                x = self._embed(params, tokens, positions)
+                x, cache_state = self._layer_scan(params, x, cache_state,
+                                                  positions, attn_hook,
+                                                  lora_bank=bank,
+                                                  lora_ids=aids)
             else:
-                tokens, cached_len, last, page_row = args
-                positions = cached_len + jnp.arange(tokens.shape[1])
-
-                def attn_hook(q, k, v, cache):
-                    cache = kvc.append(kvc.write_prefill_at, cache, k[0],
-                                       v[0], page_row, cached_len, last)
-                    kctx, vctx = kvc.context_dense(cache, page_row[None],
-                                                   q.dtype)
-                    o = _cached_context_attention(q, kctx, vctx, k, v,
-                                                  cached_len)
-                    return o, cache[1]
-
-            x = self._embed(params, tokens, positions)
-            x, cache_state = self._layer_scan(params, x, cache_state,
-                                              positions, attn_hook,
-                                              lora_bank=bank,
-                                              lora_ids=aids)
+                x, cache_state, *counts = own_stack(
+                    params, tokens, positions, cache_state, attn_hook,
+                    _token_rows(kind, args))
             if kind in ("prefill", "prefill_cached"):
                 x = jnp.take(x[0], last - 1, axis=0)[None, None]  # [1,1,d]
             logits = jnp.einsum("bsd,dv->bsv", x,
-                                gpt_mod.lm_head(params, cfg))
+                                gpt_mod.lm_head(params, cfg)
+                                if own_stack is None
+                                else cfg.lm_head(params))
             if kind != "verify":
                 logits = logits[:, 0]
-            return (logits.astype(jnp.float32),) + cache_state
+            return ((logits.astype(jnp.float32),)
+                    + (tuple(counts) if counted else ())
+                    + tuple(cache_state))
 
         step.__name__ = "prefill_cached" if kind == "verify" else kind
         first = 2 if lora_on else 1      # cache state shifts past bank
         return jax.jit(step,
                        donate_argnums=tuple(range(first,
                                                   first + n_state)))
+
+    def _kv_hooks(self, kind: str, args):
+        """A step's ``(tokens [B, S], positions, last, attn_hook)`` over
+        K and V rows ``[H, D]`` (:meth:`_build_step` has the kinds and
+        their arguments)."""
+        last = None
+        if kind == "decode":
+            tokens, lengths, page_table = args
+            positions = lengths[:, None]                   # [B, 1]
+            tokens = tokens[:, None]
+
+            def attn_hook(q, k, v, cache):
+                cache = kvc.append_decode(cache, k[:, 0], v[:, 0],
+                                          page_table, lengths)
+                o = kvc.attend(q[:, 0], cache, page_table, lengths + 1)
+                return o[:, None], cache[1]
+        elif kind == "prefill":
+            tokens, last, page_row = args
+            positions = jnp.arange(tokens.shape[1])
+
+            def attn_hook(q, k, v, cache):
+                cache = kvc.append(kvc.write_prefill, cache, k[0],
+                                   v[0], page_row)
+                return self._prefill_attention(q, k, v), cache[1]
+        else:
+            tokens, cached_len, last, page_row = args
+            positions = cached_len + jnp.arange(tokens.shape[1])
+
+            def attn_hook(q, k, v, cache):
+                cache = kvc.append(kvc.write_prefill_at, cache, k[0],
+                                   v[0], page_row, cached_len, last)
+                kctx, vctx = kvc.context_dense(cache, page_row[None],
+                                               q.dtype)
+                o = _cached_context_attention(q, kctx, vctx, k, v,
+                                              cached_len)
+                return o, cache[1]
+        return tokens, positions, last, attn_hook
+
+    def _latent_hooks(self, kind: str, args):
+        """:meth:`_kv_hooks` over one latent row a token (the cache's
+        ``(rank, rope)``).  The block hands the hook a sublayer's query
+        parts, the new rows' parts and its ``W_kvb``
+        (``ops/attention.py`` has the algebra):
+
+        - ``"decode"``: the rows are laid in place, the query is
+          absorbed and attends over the latent pages where they lie
+          (``kv_cache.attend``), and the output goes through the value
+          half of ``W_kvb``;
+        - ``"prefill"`` / ``"prefill_cached"``: the rows are written,
+          the slot's pages gathered (``kv_cache.context_dense``), K and
+          V materialised from them, and the bucket's queries attend
+          over positions up to their own
+          (``ops/attention.py:latent_prefill_attention``): a cold
+          prefill is the cached one at ``cached_len`` 0."""
+        rank = self._latent[0]
+        scale = self.cfg.qk_head_dim ** -0.5
+        last = None
+        if kind == "decode":
+            tokens, lengths, page_table = args
+            positions = lengths[:, None]                   # [B, 1]
+            tokens = tokens[:, None]
+
+            def attn_hook(q_nope, q_rot, c, k_rot, w_kvb, cache):
+                cache = kvc.append_decode(cache, c[:, 0], k_rot[:, 0],
+                                          page_table, lengths)
+                with jax.named_scope("attn/decode_pallas/absorb"):
+                    q = absorb_query(q_nope[:, 0], q_rot[:, 0], w_kvb)
+                o = kvc.attend(q, cache, page_table, lengths + 1,
+                               scale=scale, value_dim=rank)
+                with jax.named_scope("attn/decode_pallas/expand"):
+                    o = expand_output(o, w_kvb)
+                return o[:, None], cache[1]
+        else:
+            if kind == "prefill":
+                tokens, last, page_row = args
+                cached_len = jnp.int32(0)
+            else:
+                tokens, cached_len, last, page_row = args
+            positions = cached_len + jnp.arange(tokens.shape[1])
+
+            def attn_hook(q_nope, q_rot, c, k_rot, w_kvb, cache):
+                cache = kvc.append(kvc.write_prefill_at, cache, c[0],
+                                   k_rot[0], page_row, cached_len, last)
+                ctx, k_rot = kvc.context_dense(cache, page_row[None],
+                                               c.dtype, value_dim=rank)
+                k_nope, v = latent_kv(ctx[0], w_kvb)
+                o = latent_prefill_attention(
+                    jnp.swapaxes(q_nope[0], 0, 1),
+                    jnp.swapaxes(q_rot[0], 0, 1), k_nope, k_rot[0], v,
+                    cached_len, scale=scale)
+                return jnp.swapaxes(o, 0, 1)[None], cache[1]
+        return tokens, positions, last, attn_hook

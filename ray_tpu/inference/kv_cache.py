@@ -239,6 +239,8 @@ def export_pages(cache: "KVCache", pages: Sequence[int]
     ``{"k", "v"[, "k_scale", "v_scale"]}`` stacked ``[L, n_pages, ...]``
     in page order.  One gather per array (a DMA on a real device; the
     in-place object-store put is the on-chip follow-up)."""
+    if cache.latent:
+        refuse_latent("export_pages (a KVHandoff's or a spill's contents)")
     idx = np.asarray(list(pages), np.int32)
     return {name: np.ascontiguousarray(
                 np.moveaxis(np.asarray(a[:, idx]), -1, 2))
@@ -252,6 +254,8 @@ def import_pages(cache: "KVCache", pages: Sequence[int],
     host — a functional ``.at[].set`` that the next compiled step's
     donated state picks up; pages the importer already holds by content
     hash are simply absent from ``sel`` (the skip-transfer path)."""
+    if cache.latent:
+        refuse_latent("import_pages (a KVHandoff's contents)")
     if not len(pages):
         return
     idx = np.asarray(list(pages), np.int32)
@@ -322,6 +326,8 @@ def install_spill_page(cache: "KVCache", page: int,
     an int8 cache verbatim; a model-dtype cache dequantizes on the
     host first (the int8-budget approximation the r11 parity tests
     bound)."""
+    if cache.latent:
+        refuse_latent("install_spill_page (the spill tiers)")
     if cache.quantized:
         if entry["fmt"] == "int8":
             k, ks = entry["k"], entry["k_scale"]
@@ -843,11 +849,22 @@ class KVCache:
     engine threads :attr:`state` — ``(k, v)`` or
     ``(k, v, k_scale, v_scale)`` — through its donated compiled steps,
     so decode allocates nothing in either mode.
-    """
+
+    ``latent=(rank, rope)`` declares the other row this file knows: one
+    latent vector of ``rank`` values plus a rotary part of ``rope``
+    values a token a layer (latent attention: every head shares the
+    row, and K and V are projections of it).  The pool is then one
+    array ``[n_layers, pages, rank + rope, page_size]``, the page offset
+    minor as above (a page is a lane-dense ``[rank + rope, page_size]``
+    block with no padding: 576 values are 36 bfloat16 sublane tiles),
+    :attr:`state` is ``(rows,)``, and ``n_heads`` / ``head_dim`` are not
+    read.  What is written over K and V and has not been ported refuses
+    such a cache by name (:func:`refuse_latent`)."""
 
     def __init__(self, *, n_layers: int, num_pages: int, page_size: int,
-                 n_heads: int, head_dim: int, dtype,
-                 kv_dtype: str = "model"):
+                 n_heads: int = 0, head_dim: int = 0, dtype=None,
+                 kv_dtype: str = "model",
+                 latent: Optional[Tuple[int, int]] = None):
         if kv_dtype not in ("model", "int8"):
             raise ValueError(f"unknown kv_dtype {kv_dtype!r}; "
                              "expected 'model' or 'int8'")
@@ -855,6 +872,15 @@ class KVCache:
         self.page_size = page_size
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
+        self.latent = tuple(latent) if latent else None
+        if self.latent:
+            if self.quantized:
+                refuse_latent("an int8 KV cache (kv_dtype='int8') and "
+                              "its scales")
+            self.k = jnp.zeros((n_layers, num_pages, sum(self.latent),
+                                page_size), dtype)
+            self.v = None
+            return
         shape = (n_layers, num_pages, n_heads, head_dim, page_size)
         store = jnp.int8 if self.quantized else dtype
         self.k = jnp.zeros(shape, store)
@@ -875,13 +901,17 @@ class KVCache:
     @property
     def state(self) -> Tuple:
         """The donated device arrays, in step-argument order."""
+        if self.latent:
+            return (self.k,)
         if self.quantized:
             return (self.k, self.v, self.k_scale, self.v_scale)
         return (self.k, self.v)
 
     @state.setter
     def state(self, arrays: Tuple) -> None:
-        if self.quantized:
+        if self.latent:
+            self.k, = arrays
+        elif self.quantized:
             self.k, self.v, self.k_scale, self.v_scale = arrays
         else:
             self.k, self.v = arrays
@@ -898,6 +928,39 @@ class KVCache:
         scales across all layers) — the capacity-planning figure the
         telemetry summary reports."""
         return pages_per_slot * (self.bytes // self.num_pages)
+
+    @property
+    def reads_in_place(self) -> bool:
+        """Whether a decode attends over this pool through a kernel
+        (:func:`attend`'s own decision, from the backend and the pool's
+        shape and dtype)."""
+        from ray_tpu.ops import attention as ops
+        if self.latent:
+            return ops.latent_decode_uses_pallas(
+                self.k.shape[-2], self.page_size, self.dtype)
+        return ops.decode_uses_pallas(self.k.shape[-2], self.page_size,
+                                      quantized=self.quantized)
+
+    @property
+    def writes_in_place(self) -> bool:
+        """Whether a decode lays its rows into the live slots' tail
+        pages in place (the write kernel) or blends every slot's tail
+        page whole: :func:`append_decode`'s own decision."""
+        from ray_tpu.ops import attention as ops
+        if self.latent:
+            return ops.latent_decode_uses_pallas(
+                self.k.shape[-2], self.page_size, self.dtype)
+        return ops.decode_write_uses_pallas(self.k.shape[-2],
+                                            self.page_size, self.dtype)
+
+
+def refuse_latent(feature: str):
+    """What is written over K and V rows ``[H, D]`` and has not been
+    ported to a latent row says so, by name, where it is asked for."""
+    raise NotImplementedError(
+        f"{feature}: not supported over a latent cache row (it is "
+        "written over K and V rows [heads, head_dim]; see "
+        "inference/kv_cache.py:KVCache)")
 
 
 def _blend_pages(pages, layer, page, rows, hit):
@@ -990,8 +1053,9 @@ def write_decode(pages, new, layer, page_table, lengths):
 # ------------------------------------------------- what a step needs --
 # A compiled step sees the cache as ``cache = (layer, arrays)``: the
 # scan's layer index and :attr:`KVCache.state`.  What a row is — K and V
-# ``[H, D]``, plus an f32 scale per head when the arrays are int8 codes
-# — is decided here and nowhere else.
+# ``[H, D]``, plus an f32 scale per head when the arrays are int8 codes,
+# or one latent vector and its rotary part side by side in one pool
+# (``len(arrays) == 1``) — is decided here and nowhere else.
 
 def _quantize_rows(kv):
     """[..., H, D] post-RoPE K or V -> (int8 codes, [..., H] f32
@@ -1009,9 +1073,13 @@ def append(write, cache, k, v, *where):
     :func:`write_prefill` at ``page_row`` (a whole prompt),
     :func:`write_prefill_at` at ``page_row, start, valid_len`` (a
     suffix), :func:`write_decode` at ``page_table, lengths`` (one row
-    per slot).  -> ``(layer, updated arrays)``."""
+    per slot).  For a latent cache ``k`` and ``v`` are the row's two
+    parts, the latent vector ``[..., rank]`` and the rotary part
+    ``[..., rope]``.  -> ``(layer, updated arrays)``."""
     layer, arrays = cache
     rows = (k, v)
+    if len(arrays) == 1:
+        rows = (jnp.concatenate([k, v], -1),)
     if len(arrays) == 4:
         (kq, ks), (vq, vs) = _quantize_rows(k), _quantize_rows(v)
         rows = (kq, vq, ks, vs)
@@ -1031,6 +1099,16 @@ def append_decode(cache, k, v, page_table, lengths):
     scale pools, :func:`write_decode` blends whole pages as before."""
     from ray_tpu.ops.attention import decode_write, decode_write_uses_pallas
     layer, arrays = cache
+    if len(arrays) == 1:
+        from ray_tpu.ops.attention import (latent_decode_uses_pallas,
+                                           latent_decode_write)
+        pool, = arrays
+        if not latent_decode_uses_pallas(pool.shape[-2], pool.shape[-1],
+                                         pool.dtype):
+            return append(write_decode, cache, k, v, page_table, lengths)
+        return layer, (latent_decode_write(
+            pool, jnp.concatenate([k, v], -1), lengths, page_table, layer,
+            skip_page=GARBAGE_PAGE),)
     if not decode_write_uses_pallas(arrays[0].shape[-2],
                                     arrays[0].shape[-1], arrays[0].dtype):
         return append(write_decode, cache, k, v, page_table, lengths)
@@ -1044,32 +1122,53 @@ def append_decode(cache, k, v, page_table, lengths):
         skip_page=GARBAGE_PAGE)) + scales
 
 
-def attend(q, cache, page_table, lengths):
+def attend(q, cache, page_table, lengths, *, scale=None, value_dim=None):
     """One query row per slot, ``q`` [B, H, D], over the first
     ``lengths`` [B] positions of each slot's pages (``page_table``
     [B, max_pages]) in one layer of the cache -> [B, H, D].  The pool
     is read where it lies: ``ops/attention.py:decode_attention`` gets
     the whole stacked arrays, the layer and the table (an int8 cache's
     scales with them), and no context is gathered.  Rows of no
-    sequence come back as zeros."""
+    sequence come back as zeros.
+
+    Over a latent cache ``q`` is the absorbed query ``[B, H, rank +
+    rope]`` (it meets a row as it is stored), ``scale`` the softmax
+    scale of the unabsorbed product and ``value_dim`` the row's latent
+    part (``KVCache.latent[0]``): the values are the rows' first
+    ``value_dim`` entries, and what comes back is ``[B, H, value_dim]``,
+    still to be taken through the value half of the projection
+    (``ops/attention.py:latent_decode_attention``)."""
     from ray_tpu.ops.attention import decode_attention
     layer, arrays = cache
-    k, v, *scales = arrays
     # a row whose table starts at the garbage page is no sequence (a
     # free slot, or a held one sitting this decode out): nothing of
     # the pool is read for it
     lengths = jnp.where(page_table[:, 0] == GARBAGE_PAGE, 0, lengths)
+    if len(arrays) == 1:
+        from ray_tpu.ops.attention import latent_decode_attention
+        return latent_decode_attention(q, arrays[0], lengths, page_table,
+                                       layer, scale=scale,
+                                       value_dim=value_dim)
+    k, v, *scales = arrays
     return decode_attention(q, k, v, lengths, page_table, layer,
                             **dict(zip(_NAMES[2:], scales)))
 
 
-def context_dense(cache, page_table, dtype):
+def context_dense(cache, page_table, dtype, *, value_dim=None):
     """Gather one layer's pages for ``page_table`` [B, max_pages] ->
     ``(K, V)``, each ``[B, max_pages * page, H, D]``: a model-dtype
     cache's as stored, an int8 cache's dequantised to ``dtype``.  What
     a cached-suffix prefill and a verify attend over (one slot's row);
-    a decode reads the pool in place (:func:`attend`)."""
+    a decode reads the pool in place (:func:`attend`).  A latent
+    cache's: the rows' two parts, ``([B, C, value_dim], [B, C, rope])``,
+    for the caller to project K and V from."""
     layer, arrays = cache
+    if len(arrays) == 1:
+        rows = arrays[0][layer, page_table]       # [B, max_pages, R, page]
+        B, max_pages, R, page_size = rows.shape
+        rows = jnp.moveaxis(rows, -1, 2).reshape(B, max_pages * page_size,
+                                                 R)
+        return rows[..., :value_dim], rows[..., value_dim:]
     k, v, *scales = (a[layer, page_table] for a in arrays)
     if scales:
         k, v = ((a.astype(jnp.float32) * s[:, :, :, None]).astype(dtype)

@@ -106,12 +106,19 @@ def _build_engine(model: str, model_config: Optional[Dict[str, Any]],
     import jax
 
     from ray_tpu.inference.engine import InferenceEngine
-    from ray_tpu.models.gpt import GPTConfig, init_params
+    from ray_tpu.models import gpt, longcat
 
-    if model not in _PRESETS:
+    # a preset is a classmethod of its model's config, and the model's
+    # module draws the weights
+    if model in _PRESETS:
+        config_cls, init_params = gpt.GPTConfig, gpt.init_params
+    elif model in longcat.PRESETS:
+        config_cls, init_params = (longcat.LongcatConfig,
+                                   longcat.init_params)
+    else:
         raise ValueError(f"unknown model preset {model!r}; "
-                         f"expected one of {_PRESETS}")
-    cfg = getattr(GPTConfig, model)(**(model_config or {}))
+                         f"expected one of {_PRESETS + longcat.PRESETS}")
+    cfg = getattr(config_cls, model)(**(model_config or {}))
     params = init_params(cfg, jax.random.PRNGKey(seed))
     return cfg, InferenceEngine(cfg, params, **(engine_config or {}))
 

@@ -1709,3 +1709,419 @@ def decode_write(k, v, k_new, v_new, lengths, page_table, layer, *,
             interpret=_use_interpret(),
         )(slot, pages, offs, jnp.asarray(layer, jnp.int32).reshape(1),
           k_new.astype(k.dtype), v_new.astype(v.dtype), k, v)
+
+
+# ---------------------------------------------------------------------------
+# decode over a latent pool (latent attention: one row a token, shared by
+# every head), in place
+#
+# The pool is ``[L, P, R, page]``: a row is ``R = rank + rope`` values, the
+# latent vector and the rotary key part side by side, the page offset minor,
+# so a page of one layer is one lane-dense ``[R, page]`` block.  A decode's
+# query arrives *absorbed* (``absorb_query`` below): ``[B, H,
+# R]``, so that a head's score against a token is its dot product with the
+# row as it is stored, and the values are the rows' first ``value_dim``
+# entries; every head reads the same block, so a step is two plain matrix
+# products, ``[H, R] x [R, page]`` and ``[H, page] x [page, value_dim]``:
+# compute and bandwidth side by side, where the K/V decode above is
+# bandwidth alone.  A page is 147 KB at 576 values, which the chip moves in
+# less time than a grid step costs, so a step takes ``_LATENT_GROUP`` of a
+# slot's pages: the pool is handed to the kernel that many times, each with
+# its own index map over the work list (``_live_page_groups``: a slot's
+# pages in groups, the last group filled up with the garbage page, whose
+# positions the length masks).  The write's work list and the aliased zeros
+# are the K/V kernels' (``_live_rows``).
+# ---------------------------------------------------------------------------
+
+_LATENT_GROUP = 4      # pages of one slot a grid step of the decode takes
+
+
+def _live_page_groups(lengths, page_table, page: int, group: int):
+    """The work list of a latent decode: ``(count, slot, j, pages)`` —
+    for each of the ``count`` live groups of ``group`` pages, slot by
+    slot in order, its slot, its index among the slot's groups and the
+    pool pages it holds (``[n * group]``, page 0 where the slot's pages
+    end inside the group; lists meaningless past ``count``)."""
+    B, max_pages = page_table.shape
+    n_pages = jnp.minimum((lengths + page - 1) // page, max_pages)
+    n = (n_pages + group - 1) // group
+    max_groups = -(-max_pages // group)
+    ends = jnp.cumsum(n)
+    g = jnp.arange(B * max_groups, dtype=jnp.int32)
+    slot = jnp.minimum((g[:, None] >= ends[None, :]).sum(1), B - 1)
+    j = jnp.clip(g - (ends - n)[slot], 0, max_groups - 1)
+    idx = j[:, None] * group + jnp.arange(group, dtype=jnp.int32)[None, :]
+    pages = jnp.where(
+        idx < n_pages[slot][:, None],
+        page_table[slot[:, None], jnp.minimum(idx, max_pages - 1)], 0)
+    return ends[-1], slot, j, pages.reshape(-1)
+
+def latent_decode_uses_pallas(R: int, page: int, dtype) -> bool:
+    """Whether a latent pool of this geometry is read
+    (:func:`latent_decode_attention`) and written
+    (:func:`latent_decode_write`) by the kernels — the single source of
+    the decision, as :func:`decode_uses_pallas` is for K and V: wherever
+    kernels are compiled (a TPU) and the pool blocks (whole 128-lane
+    pages, rows that fill the 16-bit sublane tiles)."""
+    return (not _use_interpret() and page % 128 == 0 and R % 16 == 0
+            and jnp.dtype(dtype).itemsize == 2)
+
+
+def _latent_decode_kernel(slot_ref, j_ref, page_ref, layer_ref, len_ref,
+                          q_ref, *rest, scale: float, page: int,
+                          value_dim: int, group: int):
+    """q [1, H, R]; ``group`` blocks c [1, 1, R, page] (a slot's live
+    pages, one group of them); o [1, H, value_dim].  Online softmax over
+    a slot's consecutive steps, as ``_decode_kernel``, one update a
+    group."""
+    del page_ref, layer_ref                  # the index maps read them
+    c_refs, (_zeros, o_ref, acc_sc, m_sc, l_sc) = rest[:group], rest[group:]
+    g = pl.program_id(0)
+    j, n = j_ref[g], len_ref[slot_ref[g]]    # this slot's j-th group, n rows
+
+    @pl.when(j == 0)
+    def _init():
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    q = q_ref[0]
+    cs = [c_ref[0, 0] for c_ref in c_refs]                   # [R, page]
+    s = jnp.concatenate(
+        [jnp.dot(q, c, preferred_element_type=jnp.float32) for c in cs],
+        axis=1) * scale                                  # [H, group * page]
+    col = j * group * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(col < n, s, _NEG_INF)
+    m_prev = m_sc[:]                         # [H, 128] (col-bcast)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[:, :1])
+    l_sc[:] = l_sc[:] * alpha + jnp.sum(p, 1, keepdims=True)
+    acc = acc_sc[:] * alpha[:, :1]
+    for t, c in enumerate(cs):
+        acc = acc + jax.lax.dot_general(
+            p[:, t * page:(t + 1) * page].astype(c.dtype), c[:value_dim],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    acc_sc[:] = acc
+    m_sc[:] = m_new
+
+    @pl.when((j + 1) * group * page >= n)    # the slot's last live group
+    def _finalize():
+        o_ref[0] = (acc_sc[:] / jnp.maximum(l_sc[:, :1], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def latent_decode_attention(q, rows, lengths, page_table, layer=0, *,
+                            scale: float, value_dim: int):
+    """Single-token decode attention over a latent pool, in place.
+
+    q: [B, H, R] — the current token's absorbed queries; rows: [L, P, R,
+    page] — the cache's whole stacked pool; lengths, page_table, layer:
+    as :func:`decode_attention`; ``scale``: the softmax scale (of the
+    unabsorbed head width); ``value_dim``: the leading part of a row
+    that is the value.  Returns [B, H, value_dim] in q's dtype, zeros
+    for a slot that holds nothing.  The kernel where
+    :func:`latent_decode_uses_pallas` says so, a masked einsum over the
+    gathered pages elsewhere."""
+    B, H, R = q.shape
+    page = rows.shape[-1]
+    max_pages = page_table.shape[1]
+    lengths = lengths.astype(jnp.int32)
+    page_table = page_table.astype(jnp.int32)
+    if not latent_decode_uses_pallas(R, page, rows.dtype):
+        with jax.named_scope("attn/decode_xla"):
+            c = rows[layer, page_table]                  # [B, mp, R, page]
+            s = jnp.einsum("bhr,bprk->bhpk", q, c,
+                           preferred_element_type=jnp.float32) * scale
+            pos = (jnp.arange(max_pages)[:, None] * page
+                   + jnp.arange(page)[None, :])
+            mask = pos[None, None] < lengths[:, None, None, None]
+            s = jnp.where(mask, s, _NEG_INF)
+            m = jnp.max(s, (2, 3), keepdims=True)
+            p = jnp.where(mask, jnp.exp(s - m), 0.0)
+            l = jnp.sum(p, (2, 3))[..., None]              # [B, H, 1]
+            o = jnp.einsum("bhpk,bprk->bhr", p.astype(c.dtype),
+                           c[:, :, :value_dim],
+                           preferred_element_type=jnp.float32)
+            return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
+    with jax.named_scope("attn/decode_pallas"):
+        group = min(_LATENT_GROUP, max_pages)
+        count, slot, j, pages = _live_page_groups(lengths, page_table, page,
+                                                  group)
+        q_spec = pl.BlockSpec((1, H, R), lambda g, slot, *_: (slot[g], 0, 0))
+        o_spec = pl.BlockSpec((1, H, value_dim),
+                              lambda g, slot, *_: (slot[g], 0, 0))
+        c_specs = [pl.BlockSpec(
+            (1, 1, R, page),
+            lambda g, slot, j, pages, lay, lens, t=t:
+            (lay[0], pages[g * group + t], 0, 0)) for t in range(group)]
+        return pl.pallas_call(
+            functools.partial(_latent_decode_kernel, scale=scale, page=page,
+                              value_dim=value_dim, group=group),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(count,),
+                in_specs=[q_spec, *c_specs,
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=o_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((H, value_dim), jnp.float32),
+                    pltpu.VMEM((H, 128), jnp.float32),
+                    pltpu.VMEM((H, 128), jnp.float32),
+                ],
+            ),
+            compiler_params=_CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
+            input_output_aliases={6 + group: 0},
+            interpret=_use_interpret(),
+        )(slot, j, pages, jnp.asarray(layer, jnp.int32).reshape(1),
+          lengths, q, *([rows] * group),
+          jnp.zeros((B, H, value_dim), q.dtype))
+
+
+def _latent_write_kernel(slot_ref, page_ref, off_ref, layer_ref, new_ref,
+                         c_ref, o_ref):
+    """new [1, R, page] (the slot's new row, on every lane); c, o [1, 1,
+    R, page] (its tail page, in and out): the row is laid over lane
+    ``offset``."""
+    del slot_ref, page_ref, layer_ref        # the index maps read them
+    off = off_ref[pl.program_id(0)]
+    hit = jax.lax.broadcasted_iota(jnp.int32, new_ref.shape[1:], 1) == off
+    o_ref[0, 0] = jnp.where(hit, new_ref[0], c_ref[0, 0])
+
+
+def latent_decode_write(rows, new, lengths, page_table, layer, *,
+                        skip_page: int):
+    """Lay one new row per live slot into a latent pool, in place.
+
+    rows: [L, P, R, page] — the cache's whole stacked pool; new: [B, R]
+    — each slot's new row; lengths, page_table, layer, ``skip_page``:
+    as :func:`decode_write`.  A row is one lane of its page's block, so
+    a step moves the slot's tail page in, lays the row over lane
+    ``offset`` and moves it out; the pool is aliased in and out.  The
+    row comes spread over a page's lanes (``[B, R, page]``, a few
+    megabytes a decode): the kernel then selects, and turns nothing.
+    Returns the pool, which aliases the argument."""
+    B, R = new.shape
+    page = rows.shape[-1]
+    with jax.named_scope("attn/write_pallas"):
+        count, slot, pages, offs = _live_rows(
+            lengths.astype(jnp.int32), page_table.astype(jnp.int32), page,
+            skip_page)
+        spread = jnp.broadcast_to(new.astype(rows.dtype)[:, :, None],
+                                  (B, R, page))
+        page_spec = pl.BlockSpec(
+            (1, 1, R, page),
+            lambda g, slot, pages, offs, lay: (lay[0], pages[g], 0, 0))
+        return pl.pallas_call(
+            _latent_write_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(count,),
+                in_specs=[pl.BlockSpec((1, R, page),
+                                       lambda g, slot, *_: (slot[g], 0, 0)),
+                          page_spec],
+                out_specs=page_spec,
+            ),
+            compiler_params=_CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            out_shape=jax.ShapeDtypeStruct(rows.shape, rows.dtype),
+            input_output_aliases={5: 0},
+            interpret=_use_interpret(),
+        )(slot, pages, offs, jnp.asarray(layer, jnp.int32).reshape(1),
+          spread, rows)
+
+
+# ---------------------------------------------------------------------------
+# a prefill's attention over a slot's gathered latent context
+#
+# A bucket's queries sit at absolute positions ``start .. start + S`` and
+# attend over the slot's whole gathered context (positions ``0 .. C``, the
+# new tokens' own rows among them), each over the keys not past its own
+# position.  K and V are materialised from the latent rows by the caller
+# (``latent_kv`` below), head-major; the rotary key part is one
+# ``[C, rope]`` array every head shares, so a score is two products,
+# ``q_nope . k_nope + q_rot . k_rot``, and no ``[C, H, nope + rope]`` key is
+# ever laid down.  The kernel is a flash forward with the causal edge moved
+# by ``start`` (scalar prefetch): the scores of a ``[block_q, block_k]``
+# tile live in VMEM alone, and the key blocks past a query block's last
+# position are neither fetched (the index map stops at the edge) nor
+# computed.  The masked einsum it replaces wrote and re-read ``[H, S, C]``
+# float32 scores through HBM for every softmax pass.
+# ---------------------------------------------------------------------------
+
+_PREFILL_BLOCKS_Q = (768, 640, 512, 384, 256, 128)   # the widest that divides S
+_PREFILL_BLOCK_K = 512
+
+
+def latent_prefill_uses_pallas(S: int, C: int, dtype) -> bool:
+    """Whether :func:`latent_prefill_attention` runs the kernel for a
+    bucket of ``S`` queries over ``C`` gathered positions: wherever
+    kernels are compiled (a TPU) and both block."""
+    return (not _use_interpret() and S % _PREFILL_BLOCKS_Q[-1] == 0
+            and C % _PREFILL_BLOCK_K == 0
+            and jnp.dtype(dtype).itemsize == 2)
+
+
+def _latent_prefill_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                           o_ref, acc_sc, m_sc, l_sc, *, scale: float,
+                           block_q: int, block_k: int, num_kv: int):
+    """qn [1, bq, nope], qr [1, bq, rope]; kn [1, bk, nope], kr [bk,
+    rope], v [1, bk, dv]; o [1, bq, dv]."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    first = start_ref[0] + i * block_q       # the block's first position
+
+    @pl.when(j == 0)
+    def _init():
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    @pl.when(j * block_k <= first + block_q - 1)
+    def _compute():
+        nt = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qn_ref[0], kn_ref[0], nt,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], kr_ref[...], nt,
+                                   preferred_element_type=jnp.float32)
+             ) * scale                                       # [bq, bk]
+        row = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col <= row, s, _NEG_INF)
+        m_prev = m_sc[:]                      # [bq, 128] (col-bcast)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        l_sc[:] = l_sc[:] * alpha + jnp.sum(p, 1, keepdims=True)
+        v = v_ref[0]
+        acc_sc[:] = (acc_sc[:] * alpha[:, :1]
+                     + jax.lax.dot_general(
+                         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32))
+        m_sc[:] = m_new
+
+    @pl.when(j == num_kv - 1)
+    def _finalize():
+        o_ref[0] = (acc_sc[:] / jnp.maximum(l_sc[:, :1], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def latent_prefill_attention(q_nope, q_rot, k_nope, k_rot, v, start, *,
+                             scale: float):
+    """Attention of a bucket's queries over a slot's gathered context.
+
+    q_nope [H, S, nope], q_rot [H, S, rope]: the queries, at absolute
+    positions ``start + (0 .. S)``; k_nope [H, C, nope], v [H, C, dv]:
+    K's unrotated part and V of positions ``0 .. C``, materialised from
+    the latent rows; k_rot [C, rope]: the rows' rotary part, shared by
+    the heads; start: int32 scalar, traced or not.  A query sees the
+    keys at positions not past its own, which also hides what the
+    slot's pages hold beyond the prompt.  -> [H, S, dv].  The kernel
+    where :func:`latent_prefill_uses_pallas` says so; elsewhere a masked
+    einsum, a chunk of queries at a time, so that the scores held at
+    once are ``[H, chunk, C]`` whatever the bucket."""
+    H, S, _ = q_nope.shape
+    C = k_nope.shape[1]
+    start = jnp.asarray(start, jnp.int32)
+    if not latent_prefill_uses_pallas(S, C, q_nope.dtype):
+        with jax.named_scope("attn/prefill_xla"):
+            chunk = min(_PREFILL_BLOCKS_Q[-1], S)
+            pad = -S % chunk
+
+            def one(args):
+                qn, qr, first = args                    # [H, chunk, *]
+                s = (jnp.einsum("hqd,hkd->hqk", qn, k_nope,
+                                preferred_element_type=jnp.float32)
+                     + jnp.einsum("hqd,kd->hqk", qr, k_rot,
+                                  preferred_element_type=jnp.float32)
+                     ) * scale
+                seen = (jnp.arange(C)[None, :]
+                        <= first + jnp.arange(chunk)[:, None])
+                p = jax.nn.softmax(jnp.where(seen[None], s, _NEG_INF), -1)
+                return jnp.einsum("hqk,hkd->hqd", p.astype(v.dtype), v,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(q_nope.dtype)
+
+            def chunks(q):
+                q = jnp.pad(q, ((0, 0), (0, pad), (0, 0)))
+                return jnp.moveaxis(
+                    q.reshape(H, -1, chunk, q.shape[-1]), 1, 0)
+
+            firsts = start + chunk * jnp.arange((S + pad) // chunk)
+            o = jax.lax.map(one, (chunks(q_nope), chunks(q_rot), firsts))
+            return jnp.moveaxis(o, 0, 1).reshape(H, S + pad, -1)[:, :S]
+    # a query block streams its head's K and V once, so the widest
+    # block re-reads them least
+    bq = next(b for b in _PREFILL_BLOCKS_Q if S % b == 0)
+    bk = _PREFILL_BLOCK_K
+    num_kv = C // bk
+
+    def kv_block(i, j, start):
+        # the last key block a query block reaches; later steps name it
+        # again, so nothing past the edge is fetched
+        return jnp.minimum(j, (start[0] + (i + 1) * bq - 1) // bk)
+
+    with jax.named_scope("attn/prefill_pallas"):
+        q_spec = lambda d: pl.BlockSpec(                    # noqa: E731
+            (1, bq, d), lambda h, i, j, start: (h, i, 0))
+        kv_spec = lambda d: pl.BlockSpec(                   # noqa: E731
+            (1, bk, d), lambda h, i, j, start: (h, kv_block(i, j, start), 0))
+        dv = v.shape[-1]
+        return pl.pallas_call(
+            functools.partial(_latent_prefill_kernel, scale=scale,
+                              block_q=bq, block_k=bk, num_kv=num_kv),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(H, S // bq, num_kv),
+                in_specs=[q_spec(q_nope.shape[-1]), q_spec(q_rot.shape[-1]),
+                          kv_spec(k_nope.shape[-1]),
+                          pl.BlockSpec(
+                              (bk, k_rot.shape[-1]),
+                              lambda h, i, j, start:
+                              (kv_block(i, j, start), 0)),
+                          kv_spec(dv)],
+                out_specs=q_spec(dv),
+                scratch_shapes=[
+                    pltpu.VMEM((bq, dv), jnp.float32),
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                ],
+            ),
+            compiler_params=_CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            out_shape=jax.ShapeDtypeStruct((H, S, dv), q_nope.dtype),
+            interpret=_use_interpret(),
+        )(start.reshape(1), q_nope, q_rot, k_nope, k_rot, v)
+
+
+# ---------------------------------------------------------------------------
+# a latent row's algebra: what joins a model's projections to the two paths
+# above.  ``w_kvb`` is a sublayer's ``(W_kb [H, rank, nope], W_vb [H, rank,
+# v])``: the K half and the V half of the matrix that expands a latent row
+# into a head's key and value, head-major.
+
+def latent_kv(c, w_kvb):
+    """Materialise K's unrotated part [H, C, nope] and V [H, C, v],
+    head-major, from the cached rows' latent part ``c`` [C, rank]: what a
+    prefill attends over (the rows' rotary part is K's other part as it
+    is stored, shared by every head)."""
+    wk, wv = w_kvb
+    return (jnp.einsum("cr,hrk->hck", c, wk),
+            jnp.einsum("cr,hrk->hck", c, wv))
+
+
+def absorb_query(q_nope, q_rot, w_kvb):
+    """A decode's query against the latent rows themselves: ``q_nope .
+    k_nope = (q_nope W_k^T) . c``, so q [..., H, nope | rope] becomes
+    [..., H, rank + rope] and meets a row as it is stored."""
+    wk, _ = w_kvb
+    return jnp.concatenate(
+        [jnp.einsum("...hk,hrk->...hr", q_nope, wk), q_rot], -1)
+
+
+def expand_output(o_latent, w_kvb):
+    """The other half of the absorption: attention's output over the
+    latent rows [..., H, rank] -> [..., H, v]."""
+    _, wv = w_kvb
+    return jnp.einsum("...hr,hrk->...hk", o_latent, wv)

@@ -5,12 +5,21 @@ top-k token routing with a static capacity (XLA needs static shapes — no
 ragged dispatch), expressed as one-hot einsums the compiler turns into
 MXU-friendly matmuls; under an ``ep`` axis the dispatched tokens move to
 their experts with ``lax.all_to_all`` and return the same way.
+
+:func:`dropless_moe` is the serve path's expert layer: no capacity, so no
+token is dropped.  It is told which experts of a deployment it holds,
+routes over all of them, and computes the held experts' part by a
+grouped matrix product over the rows that picked them, and the identity
+experts' part; an expert held elsewhere adds nothing (the exchange that
+would bring its part is not run here).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Sequence
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -158,3 +167,116 @@ def make_moe_fn(mesh, *, top_k: int = 2, capacity_factor: float = 1.5):
         return out, aux
 
     return fn
+
+
+# ---------------------------------------------------------------------------
+# the dropless expert layer of the serve path
+# ---------------------------------------------------------------------------
+
+# what :func:`dropless_moe` counts, in the order of its counts vector
+MOE_COUNTS = ("rows", "held_picks", "identity_picks", "picks",
+              "experts_hit", "calls")
+_TILE = 128       # rows of one expert a step of the grouped product takes
+
+
+def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead):
+    """sum over a row's held picks of ``weight * expert(x)``, float32.
+
+    x [T, d]; local [T, K] int32: the pick's index among the experts
+    held, or ``held`` (one past the last) where the pick is not this
+    chip's; weight [T, K] float32; e_gate, e_up [*lead, held, d, f];
+    e_down [*lead, held, f, d], read at the indices ``lead`` (a layer
+    of stacked weights: an expert's matrices are sliced where they
+    stand, one expert a step, and never a layer's).  The picks are sorted by expert, and a loop takes one
+    tile of one expert's rows a step: gather the rows, the expert's
+    swiglu, scatter-add the weighted outputs.  Its trip count is the
+    tiles the picks fill (``sum_e ceil(n_e / tile)``), so a step costs
+    what was picked: an expert nobody picked is never read, and nothing
+    is padded to a worst case."""
+    T, K = local.shape
+    held = e_gate.shape[len(lead)]
+
+    def expert(w, e):
+        at = len(lead) + 1
+        return lax.dynamic_slice(
+            w, (*lead, e) + (0,) * (w.ndim - at),
+            (1,) * at + w.shape[at:]).reshape(w.shape[at:])
+
+    tile = min(_TILE, -(-T // 16) * 16)    # an expert has at most T rows
+    flat = local.reshape(T * K)
+    order = jnp.argsort(flat, stable=True)            # held picks first
+    pad = jnp.zeros((tile,), jnp.int32)
+    token = jnp.concatenate([(order // K).astype(jnp.int32), pad])
+    wsort = jnp.concatenate([weight.reshape(T * K)[order],
+                             pad.astype(jnp.float32)])
+    n = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], axis=0,
+                dtype=jnp.int32)                          # rows an expert
+    start = jnp.cumsum(n) - n
+    tiles = (n + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+
+    def step(t, out):
+        e = jnp.minimum(jnp.sum(t >= tile_end), held - 1)
+        k = t - (tile_end[e] - tiles[e])       # the expert's k-th tile
+        first = start[e] + k * tile
+        live = jnp.arange(tile) < n[e] - k * tile
+        rows = jnp.where(live, lax.dynamic_slice(token, (first,), (tile,)),
+                         0)
+        w = jnp.where(live, lax.dynamic_slice(wsort, (first,), (tile,)),
+                      0.0)
+        xs = x[rows]
+        h = jax.nn.silu(xs @ expert(e_gate, e)) * (xs @ expert(e_up, e))
+        y = (h @ expert(e_down, e)).astype(jnp.float32) * w[:, None]
+        return out.at[rows].add(y)
+
+    out = lax.fori_loop(0, tile_end[-1], step,
+                        jnp.zeros(x.shape, jnp.float32))
+    return out, jnp.sum(n > 0)
+
+
+def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
+                 held: Sequence[int], n_routed: int, top_k: int,
+                 scale: float, valid=None, lead=()):
+    """One chip's part of a dropless expert layer on x [T, d] -> (its
+    output [T, d], counts [len(MOE_COUNTS)] int32).
+
+    ``router`` [d, E] scores every expert of the deployment in float32
+    (softmax); the ``top_k`` of ``score + router_bias`` are a row's
+    picks, weighted by their scores as they are (no renormalisation)
+    and by ``scale``.  Experts ``0 .. n_routed - 1`` are swiglu experts,
+    of which this chip holds ``held`` (their ids, in the order of
+    ``e_gate, e_up`` [*lead, held, d, f] and ``e_down`` [*lead, held,
+    f, d], read at the indices ``lead``: stacked layers); experts
+    from ``n_routed`` up are identity experts (``E_e(x) = x``), computed
+    where the row lives.  A pick on a routed expert held elsewhere adds
+    nothing.  ``valid`` [T] bool marks the rows that are tokens of a
+    sequence (absent: all): the others pick nothing and count nothing.
+    Device scopes: ``route``, ``experts``, ``identity`` (the caller
+    names the layer)."""
+    T, d = x.shape
+    E = router.shape[1]
+    if valid is None:
+        valid = jnp.ones((T,), bool)
+    with jax.named_scope("route"):
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        score = jax.nn.softmax(logits, axis=-1)
+        _, pick = lax.top_k(score + router_bias.astype(jnp.float32), top_k)
+        weight = jnp.take_along_axis(score, pick, axis=-1)       # [T, K]
+        local_of = np.full((E,), len(held), np.int32)
+        local_of[list(held)] = np.arange(len(held))
+        local = jnp.where(valid[:, None], jnp.asarray(local_of)[pick],
+                          len(held))
+        identity = valid[:, None] & (pick >= n_routed)
+        ours = local < len(held)
+    with jax.named_scope("experts"):
+        out, hit = _grouped_experts(x, local, jnp.where(ours, weight, 0.0),
+                                    e_gate, e_up, e_down, tuple(lead))
+    with jax.named_scope("identity"):
+        out = out + x.astype(jnp.float32) * jnp.sum(
+            jnp.where(identity, weight, 0.0), -1, keepdims=True)
+    rows = jnp.sum(valid)
+    counts = jnp.stack([rows, jnp.sum(ours), jnp.sum(identity),
+                        rows * top_k, hit, jnp.int32(1)]).astype(jnp.int32)
+    return (scale * out).astype(x.dtype), counts

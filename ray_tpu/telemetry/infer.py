@@ -80,6 +80,11 @@ class InferTelemetry:
         # same count where the write is in place, every slot's page
         # where whole pages are blended
         self.decode_writes = [0, 0]
+        # a routed model's expert layers (``parallel/moe.py:MOE_COUNTS``,
+        # summed over every step's layers), and the same of its decodes
+        # alone: a prefill's hundreds of rows hit every held expert, a
+        # decode's few rows hit few, and a ratio over both hides it
+        self.moe: Dict[str, int] = {}
         # speculative decoding (r21): cumulative proposed/accepted
         # draft counts and verify-step count — the accept rate is the
         # one number that decides whether speculation pays
@@ -144,6 +149,19 @@ class InferTelemetry:
         self.decodes.append({"wall_s": wall_s, "active": active})
         del self.decodes[:-self._MAX_RECORDS]
         self._emit_decode(wall_s, active)
+
+    def record_moe(self, *, decode: bool, **counts: int) -> None:
+        """One step's expert-layer counts (rows through an expert
+        layer, picks on held experts, picks on identity experts, all
+        picks, experts hit, expert-layer calls), fetched with the
+        step's tokens; ``decode``: the step was a decode."""
+        if not self.enabled:
+            return
+        for name, n in counts.items():
+            self.moe[name] = self.moe.get(name, 0) + n
+            if decode:
+                key = "decode_" + name
+                self.moe[key] = self.moe.get(key, 0) + n
 
     def record_verify(self, wall_s: float, *, proposed: int,
                       accepted: int, emitted: int) -> None:
@@ -348,6 +366,9 @@ class InferTelemetry:
                              "pages_table": self.decode_pages[1],
                              "rows_written": self.decode_writes[0],
                              "tail_pages_rewritten": self.decode_writes[1]}
+        if self.moe:
+            # counts only: a share is the ratio of two of them
+            out["moe"] = dict(self.moe)
         if self.spec_verify_steps:
             out["spec"] = {
                 "verify_steps": self.spec_verify_steps,

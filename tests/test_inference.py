@@ -1868,10 +1868,19 @@ def _ahead_case(name, cfg, params):
                 assert [(t, d, e) for t, d, _lp, e in tail] == [
                     (-1, True, "DeadlineExceededError")]
             assert len(streams[1]) == 8
-    elif name in ("prefix_hits", "prefix_hits_int8"):
+    elif name in ("prefix_hits", "prefix_hits_int8", "latent_row"):
         kwargs = {"prefix": True, "slots": 3}
         if name.endswith("int8"):
             kwargs["kv_dtype"] = "int8"
+        if name == "latent_row":
+            # the other model: a latent cache row, two cache layers a
+            # block, an expert layer whose counts ride on the fetch
+            import jax
+            import jax.numpy as jnp
+            from ray_tpu.models import longcat
+            lc = longcat.LongcatConfig.longcat_tiny(dtype=jnp.float32)
+            kwargs["model"] = (lc, longcat.init_params(
+                lc, jax.random.PRNGKey(0)))
         shared = _prompt(32, cfg.vocab_size, seed=4)
 
         def script(engine, tick, streams):
@@ -1885,6 +1894,10 @@ def _ahead_case(name, cfg, params):
         def check(ahead, level, streams):
             for engine in (ahead, level):
                 assert engine.scheduler.prefix_requests_hit == 2
+            if name == "latent_row":
+                # the same steps, so the same counts
+                assert (ahead.telemetry.summary()["moe"]
+                        == level.telemetry.summary()["moe"])
     elif name == "int8_cache":
         kwargs = {"kv_dtype": "int8"}
         script = _ahead_plain(cfg)
@@ -2017,13 +2030,14 @@ _AHEAD_CASES = (
     "max_new_1", "max_new_2", "cancel_in_flight", "deadline_in_flight",
     "prefix_hits", "prefix_hits_int8", "int8_cache", "lora_bank",
     "hold_pages_export", "set_params_between_ticks", "speculating_slot",
-    "decode_fault_then_resume", "debug_logits")
+    "decode_fault_then_resume", "debug_logits", "latent_row")
 
 
 @pytest.mark.parametrize("case", _AHEAD_CASES)
 def test_running_ahead_matches_the_synchronous_tick(tiny_f32, case):
     cfg, params = tiny_f32
     kwargs, script, share, check = _ahead_case(case, cfg, params)
+    cfg, params = kwargs.pop("model", (cfg, params))
     ahead = _make_engine(cfg, params, telemetry=True, **kwargs)
     level = _make_engine(cfg, params, telemetry=True, **kwargs)
     try:
@@ -2182,7 +2196,6 @@ def test_decode_counts_the_rows_it_lays_and_the_pages_it_moves(
     attention's, and decodes the same tokens either way: the kernel arm
     runs the real kernel, in interpret mode, where the decision is made
     to say yes."""
-    from ray_tpu.inference import engine as engine_mod
     from ray_tpu.ops import attention
     cfg, params = tiny_f32
     slots, jobs = 3, [(5, 4), (30, 6)]     # (prompt tokens, new tokens)
@@ -2206,8 +2219,9 @@ def test_decode_counts_the_rows_it_lays_and_the_pages_it_moves(
     assert impl == "blend" and decode["rows_written"] == rows
     assert decode["tail_pages_rewritten"] == decode["dispatches"] * slots
     if in_place:
-        for mod in (attention, engine_mod):
-            monkeypatch.setattr(mod, "decode_write_uses_pallas",
-                                lambda D, page, dtype: True)
+        # the one decision, read where it is taken (the cache's writer
+        # asks it, the engine reports the cache's answer)
+        monkeypatch.setattr(attention, "decode_write_uses_pallas",
+                            lambda D, page, dtype: True)
         assert run() == (tokens, "pallas", {**decode,
                                             "tail_pages_rewritten": rows})

@@ -233,6 +233,113 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, cell, kind):
         assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+# the latent cell's engine (benchmark/cells/serve-longcat-flash-omni-
+# agent.json): slots, pages, max_seq; the model as its configuration
+# file builds it
+_LATENT_CELL = (16, 2561, 12288)
+
+
+def _latent_cfg():
+    from ray_tpu.models import longcat
+    return longcat.LongcatConfig.longcat_flash_omni(
+        n_layers=4, held_experts=tuple(range(16)), vocab_size=16384,
+        max_seq=_LATENT_CELL[2], dtype=BF16)
+
+
+def _pool_copies(hlo, shape):
+    dims = "[" + ",".join(map(str, shape)) + "]"
+    return [ln for ln in hlo.splitlines()
+            if dims in ln.split(" copy(")[0] and " copy(" in ln]
+
+
+def test_latent_decode_kernels_compile_for_v5e(v5e):
+    """The two kernels over a latent pool alone, at the cell's geometry
+    (a 576-value row, 64 heads, 16 slots of 96 pages): Mosaic takes
+    both, and the pool goes in and comes out as it is."""
+    slots, pages, max_seq = _LATENT_CELL
+    cfg = _latent_cfg()
+    rank, rope = cfg.latent_row
+    pool = (cfg.cache_layers, pages, rank + rope, PAGE)
+    with substrate.compile_for_tpu():
+        assert attention.latent_decode_uses_pallas(rank + rope, PAGE, BF16)
+
+    def step(rows, q, new, lengths, page_table, layer):
+        rows = attention.latent_decode_write(rows, new, lengths, page_table,
+                                             layer, skip_page=0)
+        return rows, attention.latent_decode_attention(
+            q, rows, lengths + 1, page_table, layer,
+            scale=cfg.qk_head_dim ** -0.5, value_dim=rank)
+
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in (
+        (pool, BF16), ((slots, cfg.n_heads, rank + rope), BF16),
+        ((slots, rank + rope), BF16), ((slots,), jnp.int32),
+        ((slots, max_seq // PAGE), jnp.int32), ((), jnp.int32))]
+    with substrate.compile_for_tpu():
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(*specs) \
+            .compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert not _pool_copies(hlo, pool)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("kind, bucket", [("decode", 0),
+                                          ("prefill_cached", 384)])
+def test_latent_serve_step_keeps_the_pool_in_place_on_v5e(v5e, kind,
+                                                          bucket):
+    """The engine's steps over the latent pool at the cell's own shapes
+    (published widths, 4 blocks, 16 held experts): the pool comes in, is
+    written and read, and goes out with no copy of it; no layer's
+    weights are copied out of their stack (a matrix is sliced where it
+    stands, an expert only when a row picked it); a decode's executable
+    holds the write and the attention of both sublayers (the scan's
+    body: four kernels) and its temporaries are megabytes."""
+    from ray_tpu.inference.engine import InferenceEngine
+    from ray_tpu.models import longcat
+
+    slots, pages, max_seq = _LATENT_CELL
+    cfg = _latent_cfg()
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: longcat.init_params(cfg,
+                                                   jax.random.PRNGKey(0))))
+    pool = (cfg.cache_layers, pages, sum(cfg.latent_row), PAGE)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.lora_cfg = cfg, None
+    eng.cache = types.SimpleNamespace(state=(spec(pool, BF16),))
+    i32, mp = jnp.int32, max_seq // PAGE
+    if kind == "decode":
+        tail = (spec((slots,), i32), spec((slots,), i32),
+                spec((slots, mp), i32))
+    else:
+        tail = (spec((1, bucket), i32), spec((), i32), spec((), i32),
+                spec((mp,), i32))
+    with substrate.compile_for_tpu():
+        compiled = eng._build_step(kind).lower(
+            params, spec(pool, BF16), *tail).compile()
+    hlo = compiled.as_text()
+    assert not _pool_copies(hlo, pool)
+    # no stacked weight, and no layer's slice of one, is copied (a
+    # prefill turns the two halves of W_kvb, 67 MB each, once a step:
+    # 0.2 ms of its tens)
+    for name, a in params["layers"].items():
+        if a.ndim >= 3 and (kind == "decode"
+                            or name not in ("wk_b", "wv_b")):
+            assert not _pool_copies(hlo, a.shape), name
+            assert not _pool_copies(hlo, a.shape[1:]), name
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    kernels = hlo.count("custom_call_target=\"tpu_custom_call\"")
+    if kind == "decode":
+        assert kernels == 4 and temp < 64 << 20
+    else:
+        # the flash forward over the gathered context, both sublayers:
+        # no [heads, queries, 12288] scores in HBM
+        assert kernels == 2 and temp < 1 << 30
+        assert "f32[64,128,12288]" not in hlo
+
+
 def _sampler_specs(rows, vocab, sharding=None):
     return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in (
         ((rows, vocab), jnp.float32), ((rows,), jnp.int32),
