@@ -153,7 +153,7 @@ def train_loop(config: dict) -> None:
     from ray_tpu import train
     from ray_tpu._private.compile_cache import (compile_stats,
                                                 enable_compile_cache)
-    from ray_tpu.models import training
+    from ray_tpu.models import gpt, training
     from ray_tpu.ops import flash_ce, fused_norm
     from ray_tpu.ops.attention import uses_pack2
     from ray_tpu.parallel.mesh import make_mesh
@@ -183,12 +183,9 @@ def train_loop(config: dict) -> None:
     # what the gates chose, from the shapes the step ran ...
     N, d, V = B * S, cfg.d_model, cfg.vocab_size
     gate = dict(n_devices=n, norm=cfg.norm, has_bias=cfg.use_bias)
-    if flash_ce.uses_flash_ce_norm(N, d, V, **gate):
+    ce = gpt.ce_path(N, d, V, ce_chunk=cfg.ce_chunk, n_devices=n)
+    if flash_ce.uses_flash_ce_norm(N, d, V, ce_chunk=cfg.ce_chunk, **gate):
         ce = "flash_norm"
-    elif flash_ce.uses_flash_ce(N, d, V, n_devices=n):
-        ce = "flash"
-    else:
-        ce = "xla_noremat" if cfg.ce_chunk < 0 else "xla_chunked"
     gates = {
         "attn_pack2": uses_pack2(S, S, cfg.n_heads, cfg.head_dim),
         "ce": ce,
@@ -313,7 +310,10 @@ def kernel_parity(config: dict) -> dict:
         del a, wo, resid, cts
 
     # -- flash-CE with the final norm in its prologue ----------------------
-    if flash_ce.uses_flash_ce_norm(N, d, V, n_devices=n_train, **norm_gate):
+    # (at the default recipe, cfg.ce_chunk >= 0: the train phase's keeps
+    # its logits and runs XLA's head, which needs no parity row)
+    if flash_ce.uses_flash_ce_norm(N, d, V, ce_chunk=cfg.ce_chunk,
+                                   n_devices=n_train, **norm_gate):
         x, head = rand((N, d)), rand((d, V), 0.02)
         scale = (1 + 0.1 * rand((d,), dtype=f32)).astype(dt)
         tgt = jax.random.randint(next(keys), (N,), 0, V)

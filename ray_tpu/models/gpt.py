@@ -195,8 +195,8 @@ def param_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
     return axes
 
 
-# The CE path knobs live in ray_tpu.ops.flash_ce.ce_config() (env
-# RAY_TPU_CE).
+# Which loss head runs follows ``GPTConfig.ce_chunk``: :func:`ce_path`
+# names it, over the one gate ray_tpu.ops.flash_ce.uses_flash_ce.
 
 
 def norm_eps(cfg: "GPTConfig") -> float:
@@ -486,11 +486,11 @@ def loss_from_hidden(params, x, targets, cfg: GPTConfig, *, mesh=None,
     """(final *normed* hidden [B,S,d], targets [B,S]) -> mean NLL
     (CE glue shared by the dense and pipeline-parallel trainers).
 
-    ``ce_mode`` pins the CE schedule for A/B drivers (default: the
-    process-wide ``ray_tpu.ops.flash_ce.ce_config``); ``mesh`` gates
-    the Pallas paths to single-device meshes (a ``pallas_call`` has no
-    SPMD rule, so on a sharded mesh the XLA formulations run instead —
-    lifting that with a shard_map wrapper is an open item).
+    ``ce_mode`` pins the loss head for tests and A/B drivers (default:
+    what ``cfg.ce_chunk`` says, ``flash_ce.uses_flash_ce``); ``mesh``
+    gates the Pallas paths to single-device meshes (a ``pallas_call``
+    has no SPMD rule, so on a sharded mesh the XLA formulations run
+    instead — lifting that with a shard_map wrapper is an open item).
 
     ``norm_scale``: when given, ``x`` is the RAW residual stream (the
     final hidden *before* ``ln_f``) and the norm fuses into the
@@ -500,16 +500,15 @@ def loss_from_hidden(params, x, targets, cfg: GPTConfig, *, mesh=None,
     the norm runs here in XLA and the regular CE dispatch follows (the
     loud end of the fallback chain — ``ce/norm_xla`` in timelines)."""
     B, S, d = x.shape
-    n_dev = getattr(mesh, "size", 1) if mesh is not None else 1
+    recipe = _ce_recipe(cfg, mesh, ce_mode)
     with jax.named_scope("gpt/ce"):
         if norm_scale is not None:
             from ray_tpu.ops import flash_ce
             # enabled=True: passing norm_scale IS the caller's knob
             # decision — only the kernel-capability half re-gates here
             if flash_ce.uses_flash_ce_norm(
-                    B * S, d, cfg.vocab_size, mode=ce_mode,
-                    n_devices=n_dev, norm=cfg.norm,
-                    has_bias=cfg.use_bias, enabled=True):
+                    B * S, d, cfg.vocab_size, norm=cfg.norm,
+                    has_bias=cfg.use_bias, enabled=True, **recipe):
                 s, n = flash_ce.flash_ce_norm_sum(
                     x.reshape(B * S, d), lm_head(params, cfg),
                     targets.reshape(B * S), norm_scale,
@@ -518,10 +517,16 @@ def loss_from_hidden(params, x, targets, cfg: GPTConfig, *, mesh=None,
             x = _norm(x, norm_scale, cfg.norm,
                       bias=params.get("ln_f_b"), eps=norm_eps(cfg))
         s, n = _chunked_ce(x.reshape(B * S, d), lm_head(params, cfg),
-                           targets.reshape(B * S),
-                           chunk=getattr(cfg, "ce_chunk", _CE_CHUNK),
-                           mesh=mesh, mode=ce_mode)
+                           targets.reshape(B * S), **recipe)
         return s / jnp.maximum(n, 1.0)
+
+
+def _ce_recipe(cfg, mesh, ce_mode) -> Dict[str, Any]:
+    """What ``flash_ce.uses_flash_ce`` is asked beside the shapes."""
+    return dict(ce_chunk=getattr(cfg, "ce_chunk", _CE_CHUNK),
+                n_devices=getattr(mesh, "size", 1) if mesh is not None
+                else 1,
+                mode=ce_mode)
 
 
 def forward_hidden(params: Dict[str, Any], tokens, cfg: GPTConfig, *,
@@ -628,35 +633,48 @@ def forward(params: Dict[str, Any], tokens, cfg: GPTConfig, *,
 _CE_CHUNK = 4096
 
 
-def _chunked_ce(x, head, targets, *, chunk: int = _CE_CHUNK, mesh=None,
-                mode: Optional[str] = None):
-    """x [N, d] (bf16 ok), head [d, V], targets [N] -> (sum_nll, n_valid).
+def ce_path(N: int, d: int, V: int, *, ce_chunk: int = _CE_CHUNK,
+            n_devices: int = 1, mode: Optional[str] = None) -> str:
+    """The loss head a step of this shape, recipe and mesh runs, by
+    name — what :func:`_chunked_ce` switches on and the step telemetry
+    reports and prices its FLOPs from:
 
-    Dispatch order (``mode`` defaults to ``flash_ce.ce_config().mode``):
-
-    - ``flash``: streamed-logits Pallas CE (``ops/flash_ce.py``) — the
-      [N, V] logits exist only as VMEM tiles in both passes; engages
-      for supported shapes on single-device meshes regardless of
-      ``chunk`` (it strictly dominates both XLA formulations on
-      memory).
-    - ``xla`` (or a decline above): the ``chunk``-driven XLA paths —
-      ``chunk < 0`` no-remat (backward reuses saved f32 logits),
-      ``chunk > 0`` row-chunked remat.  Chunks are a *python* loop
-      (static N): a lax.scan here stashes its residuals with
-      dynamic-update-slice, which profiles slower than the unrolled
-      chunks whose remat boundaries XLA schedules freely.
+    - ``flash``: flash-CE (``ops/flash_ce.py``), where its gate
+      ``flash_ce.uses_flash_ce`` passes: the recipe recomputes anyway
+      (``ce_chunk >= 0``) on one device; the [N, V] logits exist only
+      as VMEM tiles in both passes (``mode`` pins the gate, for tests
+      and A/B drivers).
+    - ``xla_saved`` (``ce_chunk < 0``): the f32 logits are kept for
+      the backward — three vocabulary matmuls, the fastest head where
+      they fit (``PERF.md`` section 6, PR 49).
+    - ``xla_chunked`` (``ce_chunk >= 0`` and the gate declined): row
+      chunks under ``jax.checkpoint``, four (``0``: one chunk).
     """
     from ray_tpu.ops import flash_ce
+    if flash_ce.uses_flash_ce(N, d, V, ce_chunk=ce_chunk,
+                              n_devices=n_devices, mode=mode):
+        return "flash"
+    return "xla_saved" if ce_chunk < 0 else "xla_chunked"
+
+
+def _chunked_ce(x, head, targets, *, ce_chunk: int = _CE_CHUNK,
+                n_devices: int = 1, mode: Optional[str] = None):
+    """x [N, d] (bf16 ok), head [d, V], targets [N] -> (sum_nll, n_valid)
+    through the loss head :func:`ce_path` names.
+
+    The XLA heads' chunks are a *python* loop (static N): a lax.scan
+    here stashes its residuals with dynamic-update-slice, which
+    profiles slower than the unrolled chunks whose remat boundaries
+    XLA schedules freely.
+    """
     N, d = x.shape
-    if mode is None:
-        mode = flash_ce.ce_config().mode
-    single_dev = mesh is None or getattr(mesh, "size", 1) <= 1
-    if (mode == "flash" and single_dev
-            and flash_ce.supports(N, d, head.shape[1])):
+    path = ce_path(N, d, head.shape[1], ce_chunk=ce_chunk,
+                   n_devices=n_devices, mode=mode)
+    if path == "flash":
+        from ray_tpu.ops import flash_ce
         return flash_ce.flash_ce_sum(x, head.astype(x.dtype), targets)
-    remat = chunk >= 0
-    if chunk <= 0:
-        chunk = N
+    remat = path == "xla_chunked"
+    chunk = ce_chunk if ce_chunk > 0 else N
 
     def chunk_loss(xc, tc):
         logits = jnp.einsum("nd,dv->nv", xc, head,
@@ -688,7 +706,9 @@ def loss_fn(params, batch, cfg: GPTConfig, *, attn_fn=None, mesh=None,
     ``RAY_TPU_FUSE_NORM``): the per-layer out-proj epilogue in
     ``layer_apply``, plus — when the flash-CE-with-norm gate passes —
     skipping the XLA ``ln_f`` entirely and folding it into the
-    vocab-matmul kernel's prologue.
+    vocab-matmul kernel's prologue.  That gate declines where the
+    recipe keeps its logits (``cfg.ce_chunk < 0``): the loss head is
+    then XLA's, and so is ``ln_f``.
 
     Sample-packed batches additionally carry ``segment_ids`` and
     ``positions`` [B, S] (``ray_tpu.data``): attention masks
@@ -696,11 +716,10 @@ def loss_fn(params, batch, cfg: GPTConfig, *, attn_fn=None, mesh=None,
     ``targets`` already mask document boundaries with ``-1``."""
     from ray_tpu.ops import flash_ce
     B, S = batch["tokens"].shape
-    n_dev = getattr(mesh, "size", 1) if mesh is not None else 1
     ce_norm = flash_ce.uses_flash_ce_norm(
-        B * S, cfg.d_model, cfg.vocab_size, mode=ce_mode,
-        n_devices=n_dev, norm=cfg.norm, has_bias=cfg.use_bias,
-        enabled=fuse_norm)
+        B * S, cfg.d_model, cfg.vocab_size, norm=cfg.norm,
+        has_bias=cfg.use_bias, enabled=fuse_norm,
+        **_ce_recipe(cfg, mesh, ce_mode))
     x, aux = forward_hidden(params, batch["tokens"], cfg, attn_fn=attn_fn,
                             mesh=mesh, fuse_norm=fuse_norm,
                             final_norm=not ce_norm,

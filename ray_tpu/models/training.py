@@ -216,8 +216,12 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
     "ring" (ring attention) or "ulysses" (all-to-all head resharding).
     ``attn_pack2`` pins the two-head lane-packed attention schedule for
     A/B drivers (default: ``ray_tpu.ops.attention.attention_config``);
-    ``ce_mode`` pins the loss-head schedule the same way ("flash" /
-    "fused" / "xla"; default: ``ray_tpu.ops.flash_ce.ce_config``).
+    ``ce_mode`` pins the loss head for tests and A/B drivers ("flash"
+    / "xla"); the default, ``None``, follows the recipe's
+    ``cfg.ce_chunk`` through ``ray_tpu.ops.flash_ce.uses_flash_ce``:
+    ``< 0`` keeps the f32 logits for the backward (three vocabulary
+    matmuls), ``>= 0`` recomputes them, in flash-CE on one device and
+    in row chunks under ``jax.checkpoint`` on a sharded mesh.
     ``comm_mode`` pins the multi-chip collective schedule ("gspmd" /
     "overlap"; default: ``ray_tpu.parallel.overlap.comm_config``) —
     "overlap" runs the explicit shard_map schedule (prefetched

@@ -33,14 +33,42 @@ dx accumulates across the sequential vocab sweep in VMEM scratch (the
 emitted as per-row-block partials ``[N/block_n, d, V]`` and summed in
 one XLA pass — the only vocab-sized HBM tensor in the whole path, a
 write-once/read-once transient at ~1/13th the traffic of the logits
-residual it replaces (and it vanishes from the *resident* footprint,
-which is what re-opens the batch-32 probe the r05 recipe was capped
-by).  Total matmul work is 4 vocab-matmul-equivalents (fwd, bwd
-recompute, dx, dhead) vs the no-remat path's 3 — the bet is that one
-extra matmul at MXU rate beats the serialized HBM-rate reduces, *iff*
-the Pallas matmul is competitive with XLA's at ``[24576, 768] x [768,
-50304]`` (``ce_ms_per_step`` in ``benchmark/run.py``'s train cells;
-the A/B against ``xla`` is ROADMAP S3).
+residual it replaces (and it vanishes from the *resident* footprint:
+batch 32 x 1024 fits on a v5e, 12.67 GiB of temporaries, though it
+buys no more tokens a second than batch 24 does — the readings are
+below).
+
+The bet and its outcome.  Total matmul work is 4 vocab-matmul-
+equivalents (fwd, bwd recompute, dx, dhead) vs the saved-logits
+path's 3; the bet was that one extra matmul at MXU rate beats the
+serialized HBM-rate reduces.  On a v5e at ``[24576, 768] x [768,
+50304]`` it is lost on the count, not on the kernels: the forward runs
+at 79 % and the backward at 97 % of the bf16 peak, and 4 x 9.6 ms is
+still more than XLA's three matmuls plus 6.6 ms of reductions (45.0
+against 38.5 ms a step on one chip: the paired reading of
+``PERF.md`` section 6, PR 49).  So the kernel serves the recipes that
+recompute anyway, and only those:
+
+- ``ce_chunk >= 0`` (the default ``GPTConfig``; the logits may not be
+  kept): flash-CE, with the final norm in its prologue.  Both XLA
+  alternatives pay the fourth matmul there too, and this one never
+  writes a vocab-sized residual.  Measured on a v5e, GPT-2 124M at
+  24 x 1024 tokens, ``ce_chunk=4096`` (``PERF.md`` section 6, PR 49):
+  the step takes 186.1 ms with flash-CE against 201.6 ms with the
+  row-chunked XLA head (199.9 as one chunk), 8.3 % more tokens a
+  second; at 28 and 32 x 1024 the same (215.9 against 234.0 ms, 247.2
+  against 267.1), and there it also beats the saved-logits head, which
+  still fits at 28 but runs 226.1 ms a step.
+- ``ce_chunk < 0`` (the recipe keeps its logits): the saved-logits XLA
+  formulation of ``models.gpt._chunked_ce``; the gate declines with
+  that reason.
+- a sharded mesh, or a shape :func:`supports` declines: the XLA
+  formulations, as ``ce_chunk`` says.
+
+:func:`uses_flash_ce` is the one place that decides; the model
+(``models.gpt.ce_path`` names the head it and the XLA branches of
+``_chunked_ce`` come to), the trainer's ``ce_mode=`` pin and the step
+telemetry all ask it.
 
 Handles: masked ``-1`` targets (excluded from both loss and grads),
 vocab sizes that are not a multiple of the block (lane-aligned padding
@@ -48,10 +76,10 @@ with in-kernel column masking — V=50304 pads to the block grid, padded
 columns contribute exp(-inf)=0), and row counts that are not a multiple
 of ``block_n`` (zero-padded rows with ``-1`` targets).
 
-Dispatch is owned by :func:`ce_config` — the single home for CE env
-knobs.
-Unsupported shapes fall back to the dense XLA formulation, decided
-from shapes by :func:`supports`; a Mosaic compile failure is a failure
+Blocking is owned by :func:`ce_config` — the single home for CE env
+knobs.  Called directly, the ops fall back to the dense XLA
+formulation for shapes :func:`supports` declines; a Mosaic compile
+failure is a failure
 (``tests/test_tpu_aot.py`` compiles the kernels for a v5e ahead of any
 chip run).
 
@@ -77,7 +105,7 @@ from jax.experimental.pallas import tpu as pltpu
 # substrate
 from ray_tpu.ops.substrate import (NEG_INF as _NEG_INF, STATS_LANES,
                                    CompilerParams as _CompilerParams,
-                                   Support, env_int, env_str,
+                                   Support, env_int,
                                    resolve_blocks,
                                    stats_in as _stats_in, supported,
                                    unsupported,
@@ -86,23 +114,18 @@ from ray_tpu.ops.substrate import (NEG_INF as _NEG_INF, STATS_LANES,
 
 @dataclasses.dataclass(frozen=True)
 class CEConfig:
-    """Loss-head schedule knobs, resolved once from the environment.
+    """Flash-CE blocking knobs, resolved once from the environment.
 
     The single home for CE env flags (consolidation precedent: r06's
-    ``attention_config``):
+    ``attention_config``).  Which loss head runs is no knob: it follows
+    the recipe's ``ce_chunk`` (:func:`uses_flash_ce`).
 
-    - ``RAY_TPU_CE`` (default ``flash``): which CE custom path the
-      model's loss head dispatches to for supported shapes —
-      ``flash`` (this kernel) or ``xla`` (no custom path: the
-      ``ce_chunk``-driven no-remat / chunked XLA formulations; any
-      other value runs as ``xla``).
     - ``RAY_TPU_CE_BN`` / ``RAY_TPU_CE_BV`` (default 1024/1024):
       forward row/vocab blocking.
     - ``RAY_TPU_CE_BWD_BN`` / ``RAY_TPU_CE_BWD_BV`` (default
       1024/512): backward blocking — the bwd tile also carries the
       [bn, d] f32 dx accumulator, so it wants a narrower vocab block.
     """
-    mode: str = "flash"
     block_n: int = 1024
     block_v: int = 1024
     bwd_block_n: int = 1024
@@ -120,7 +143,6 @@ def ce_config(refresh: bool = False) -> CEConfig:
     global _CONFIG
     if _CONFIG is None or refresh:
         _CONFIG = CEConfig(
-            mode=env_str("RAY_TPU_CE", "flash"),
             block_n=env_int("RAY_TPU_CE_BN", 1024),
             block_v=env_int("RAY_TPU_CE_BV", 1024),
             bwd_block_n=env_int("RAY_TPU_CE_BWD_BN", 1024),
@@ -139,19 +161,41 @@ def supports(N: int, d: int, V: int) -> bool:
     return d % 128 == 0 and 0 < d <= 2048 and N > 0 and V > 1
 
 
-def uses_flash_ce(N: int, d: int, V: int, *,
-                  mode: Optional[str] = None,
-                  n_devices: int = 1) -> bool:
-    """Whether the model loss head takes the flash-CE path for this
-    shape under the current :func:`ce_config` (``mode`` overrides the
-    config, for A/B drivers) — the reporting mirror, so a summary
-    can't claim a schedule the dispatch declined.
-    ``n_devices`` is the mesh size the loss head will run under: the
-    dispatch declines sharded meshes (a ``pallas_call`` has no SPMD
-    rule), so pass it for anything but a single-chip run."""
-    if mode is None:
-        mode = ce_config().mode
-    return mode == "flash" and n_devices <= 1 and supports(N, d, V)
+def uses_flash_ce(N: int, d: int, V: int, *, ce_chunk: int,
+                  n_devices: int = 1,
+                  mode: Optional[str] = None) -> Support:
+    """Whether a loss head of this shape, recipe and mesh takes
+    flash-CE, with the reason — the one gate the model's dispatch, the
+    trainer and the step telemetry ask, so a summary can't claim a
+    schedule the dispatch declined.
+
+    ``ce_chunk`` is the recipe's (``GPTConfig.ce_chunk``): negative
+    says the logits may be kept, and the saved-logits formulation is
+    then one vocabulary matmul cheaper than this kernel's recompute
+    (183.4 against 186.1 ms a step at the cells' shape); where they
+    are recomputed anyway the kernel is 15.5 ms a step ahead of XLA's
+    chunks (the module's header has the readings).
+    ``n_devices`` is the mesh size the loss head will run under (a
+    ``pallas_call`` has no SPMD rule).  ``mode`` pins the choice for
+    tests and A/B drivers: ``"flash"`` takes the kernel whatever
+    ``ce_chunk`` says, ``"xla"`` never does."""
+    if mode not in (None, "flash", "xla"):
+        raise ValueError(f"ce_mode={mode!r}: expected None, 'flash' or "
+                         "'xla'")
+    if mode == "xla":
+        return unsupported("pinned to the XLA loss head (ce_mode='xla')")
+    if n_devices > 1:
+        return unsupported(f"sharded mesh (n_devices={n_devices}): a "
+                           "pallas_call has no SPMD rule")
+    if not supports(N, d, V):
+        return unsupported(f"shape outside the kernel grid (N={N}, "
+                           f"d={d}, V={V}: d must be a multiple of 128, "
+                           "at most 2048)")
+    if mode is None and ce_chunk < 0:
+        return unsupported(f"the recipe keeps its logits "
+                           f"(ce_chunk={ce_chunk}): three vocabulary "
+                           "matmuls against flash-CE's four")
+    return supported("flash-CE: the logits are recomputed tile by tile")
 
 
 # Mosaic's default scoped-VMEM budget is 16 MiB; at the default blocks
@@ -514,9 +558,9 @@ def _flash_ce_norm_bwd(eps, block_n, block_v, bwd_block_n, bwd_block_v,
 _flash_ce_norm.defvjp(_flash_ce_norm_fwd, _flash_ce_norm_bwd)
 
 
-def uses_flash_ce_norm(N: int, d: int, V: int, *,
-                       mode: Optional[str] = None,
+def uses_flash_ce_norm(N: int, d: int, V: int, *, ce_chunk: int,
                        n_devices: int = 1,
+                       mode: Optional[str] = None,
                        norm: str = "rmsnorm",
                        has_bias: bool = False,
                        enabled: Optional[bool] = None) -> Support:
@@ -524,9 +568,9 @@ def uses_flash_ce_norm(N: int, d: int, V: int, *,
 
     The single source of the decision ``models.gpt.loss_fn`` makes
     before skipping the XLA final norm — also the reporting mirror.
-    Requires the flash-CE path itself
-    (:func:`uses_flash_ce`'s conditions) plus the fused-norm knob and
-    a norm the prologue can fuse."""
+    Requires the flash-CE path itself (:func:`uses_flash_ce`, whose
+    reason it passes on) plus the fused-norm knob and a norm the
+    prologue can fuse."""
     from ray_tpu.ops.fused_norm import fuse_config
     if enabled is None:
         enabled = fuse_config().enabled
@@ -537,10 +581,10 @@ def uses_flash_ce_norm(N: int, d: int, V: int, *,
     if has_bias:
         return unsupported("bias norms (GPT-2 exact-architecture mode) "
                            "stay on the XLA path")
-    if not uses_flash_ce(N, d, V, mode=mode, n_devices=n_devices):
-        return unsupported(
-            f"flash-CE path declined (mode={mode or ce_config().mode!r}, "
-            f"n_devices={n_devices}, N={N}, d={d}, V={V})")
+    flash = uses_flash_ce(N, d, V, ce_chunk=ce_chunk,
+                          n_devices=n_devices, mode=mode)
+    if not flash:
+        return unsupported(f"flash-CE path declined: {flash.reason}")
     return supported("flash-CE with fused final-norm prologue")
 
 

@@ -25,7 +25,7 @@ code on top of these pieces, not a subsystem:
 - :class:`Support` — block/grid validation verdicts that carry a
   *reason*, so dispatch gates can decline loudly and tests can assert
   on why.
-- :func:`env_int` / :func:`env_str` / :func:`env_flag` — env-knob
+- :func:`env_int` / :func:`env_flag` — env-knob
   readers for the per-family config dataclasses
   (``attention_config()`` / ``ce_config()`` / ``fuse_config()``).
 
@@ -167,10 +167,6 @@ def unsupported(reason: str) -> Support:
 
 def env_int(name: str, default: int) -> int:
     return int(os.environ.get(name, str(default)))
-
-
-def env_str(name: str, default: str) -> str:
-    return os.environ.get(name, default)
 
 
 def env_flag(name: str, default: bool = True) -> bool:

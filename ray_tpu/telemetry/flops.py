@@ -84,12 +84,13 @@ def gpt_train_flops_per_token(cfg, seq: int, *, causal: bool = True,
     configured schedule actually pays: ``cfg.remat`` re-runs every
     block's forward in the backward (+1× the layer stack), and a
     rematerializing CE recomputes the head matmul once (``+2·d·V``).
-    ``ce_recompute`` says whether the CE path pays that recompute —
-    True for ``ce_chunk >= 0`` remat AND for the flash-CE kernel
-    (4 vocab matmuls even at ``ce_chunk=-1``); ``None`` infers from
-    ``cfg.ce_chunk`` alone, which undercounts a flash-CE no-remat
-    config — callers that know the dispatched CE mode (the telemetry
-    recorder, bench) should pass it.
+    ``ce_recompute`` says whether the loss head pays that recompute —
+    True for the chunked-remat head and for flash-CE (four vocabulary
+    matmuls), False for the saved-logits head (three); ``None`` infers
+    it from ``cfg.ce_chunk`` alone, which is what the dispatch follows
+    (``ops.flash_ce.uses_flash_ce``) unless a ``ce_mode="flash"`` pin
+    overrides it — the telemetry recorder, which knows the pin, passes
+    what the gate said.
     """
     fwd = gpt_fwd_flops_per_token(cfg, seq, causal=causal)
     head = 2 * cfg.d_model * cfg.vocab_size

@@ -137,6 +137,7 @@ class StepTelemetry:
         self._batch: Optional[int] = None
         self._peak = chip_peak_tflops
         self._fpt: Optional[float] = None   # cached; -1 = unavailable
+        self._ce_path: Optional[str] = None  # cached once the shape is known
         self._metrics = None          # lazily-created metric objects
         self._metrics_dead = False    # no cluster / emission failed
         self._metrics_last = 0.0      # last emission (monotonic)
@@ -212,6 +213,8 @@ class StepTelemetry:
                         peak)
         if loss is not None:
             rec["loss"] = loss
+        if i == 0 and self.ce_path() is not None:
+            rec["ce_path"] = self.ce_path()   # fixed for the run
         self.records.append(rec)
         if len(self.records) > self._MAX_RECORDS:
             # bounded like the control plane's task-event buffer: a
@@ -281,25 +284,24 @@ class StepTelemetry:
             self._peak = flops_mod.chip_peak_tflops(device)
         return self._peak
 
-    def _ce_recompute(self) -> Optional[bool]:
-        """Whether the CE path recomputes the head matmul (4th vocab
-        matmul): pinned mode wins; otherwise infer the dispatch —
-        flash-CE pays it even at ``ce_chunk=-1``."""
-        chunk_remat = getattr(self.cfg, "ce_chunk", 0) >= 0
-        if self.ce_mode == "flash":
-            return True
-        if self.ce_mode == "xla":
-            return chunk_remat
-        if chunk_remat or self._seq is None or self._batch is None:
-            return chunk_remat
-        try:
-            from ray_tpu.ops.flash_ce import uses_flash_ce
-            return uses_flash_ce(self._batch * self._seq,
-                                 self.cfg.d_model,
-                                 self.cfg.vocab_size,
-                                 n_devices=self.n_devices())
-        except Exception:  # noqa: BLE001 — best-effort inference
-            return chunk_remat
+    def ce_path(self) -> Optional[str]:
+        """The loss head this step runs — ``flash``, ``xla_saved`` or
+        ``xla_chunked`` — as the model's dispatch names it
+        (``models.gpt.ce_path``, over the gate
+        ``ops.flash_ce.uses_flash_ce``: the recipe's ``ce_chunk``, the
+        shapes, the mesh size, the ``ce_mode`` pin).  ``None`` until a
+        batch has shown its shape, or for a config with no such recipe;
+        constant from then on, so asked once."""
+        cfg = self.cfg
+        if self._seq is None or not hasattr(cfg, "ce_chunk"):
+            return None
+        if self._ce_path is None:
+            from ray_tpu.models.gpt import ce_path
+            self._ce_path = ce_path(
+                self._batch * self._seq, cfg.d_model, cfg.vocab_size,
+                ce_chunk=cfg.ce_chunk, n_devices=self.n_devices(),
+                mode=self.ce_mode)
+        return self._ce_path
 
     def flops_per_token(self) -> Optional[float]:
         if self.cfg is None or self._seq is None:
@@ -308,7 +310,9 @@ class StepTelemetry:
             try:
                 self._fpt = flops_mod.gpt_train_flops_per_token(
                     self.cfg, self._seq,
-                    ce_recompute=self._ce_recompute())
+                    # only the saved-logits head runs three
+                    # vocabulary matmuls; the other two recompute one
+                    ce_recompute=self.ce_path() != "xla_saved")
             except Exception:  # noqa: BLE001 — non-GPT cfg
                 self._fpt = -1.0
         return None if self._fpt < 0 else self._fpt
@@ -359,6 +363,9 @@ class StepTelemetry:
                 fpt, peak = self.flops_per_token(), self.chip_peak()
                 if fpt is not None:
                     out["flops_per_token"] = fpt
+                path = self.ce_path()
+                if path is not None:
+                    out["ce_path"] = path
                 if fpt is not None and peak is not None:
                     out["chip_peak_tflops"] = peak
                     out["mfu"] = flops_mod.mfu(

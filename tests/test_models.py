@@ -141,8 +141,21 @@ def _fuse_norm_parity_cfg():
                                         cfg.vocab_size)
     assert fused_norm.out_proj_norm_plan(2 * 32, 128, 128, seq=32,
                                          enabled=True)
-    assert flash_ce.uses_flash_ce_norm(2 * 32, 128, 512, enabled=True)
+    assert flash_ce.uses_flash_ce_norm(2 * 32, 128, 512, enabled=True,
+                                       ce_chunk=cfg.ce_chunk)
     return cfg, batch
+
+
+def _assert_every_grad_close(got, want, tol=1e-4):
+    """Each parameter's gradient, to ``tol`` of its largest element."""
+    import numpy as np
+
+    for (path, a), b in zip(jax.tree.leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        na, nb = np.asarray(a), np.asarray(b)
+        denom = max(1e-8, float(np.abs(nb).max()))
+        err = float(np.abs(na - nb).max()) / denom
+        assert err < tol, (jax.tree_util.keystr(path), err)
 
 
 def test_gpt_train_fuse_norm_parity():
@@ -151,8 +164,6 @@ def test_gpt_train_fuse_norm_parity():
     (ln1/ln2/ln_f) that come back through the fused kernels'
     per-row-block partials — with RAY_TPU_FUSE_NORM pinned on vs
     off."""
-    import numpy as np
-
     from ray_tpu.models import gpt
 
     mesh = make_mesh(dp=1, devices=jax.devices()[:1])
@@ -165,12 +176,43 @@ def test_gpt_train_fuse_norm_parity():
                                   fuse_norm=fuse))(params)
     assert float(losses[True]) == pytest.approx(float(losses[False]),
                                                 abs=2e-5)
-    for (path, a), b in zip(jax.tree.leaves_with_path(grads[True]),
-                            jax.tree.leaves(grads[False])):
-        na, nb = np.asarray(a), np.asarray(b)
-        denom = max(1e-8, float(np.abs(nb).max()))
-        err = float(np.abs(na - nb).max()) / denom
-        assert err < 1e-4, (jax.tree_util.keystr(path), err)
+    _assert_every_grad_close(grads[True], grads[False])
+
+
+def test_gpt_train_keep_logits_matches_flash_ce():
+    """PR 49: a recipe that keeps its logits (``ce_chunk=-1``: XLA's
+    saved-logits head, ``ln_f`` in XLA) and the default one
+    (``ce_chunk=4096``: flash-CE with the norm in its prologue,
+    interpret mode here) are the same loss and the same gradient of
+    every parameter, through the loss closure and the step that
+    ``build_gpt_train`` compiles."""
+    import dataclasses
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import flash_ce
+
+    mesh = make_mesh(dp=1, devices=jax.devices()[:1])
+    flash_cfg, batch = _fuse_norm_parity_cfg()
+    keep_cfg = dataclasses.replace(flash_cfg, ce_chunk=-1)
+    gate = flash_ce.uses_flash_ce_norm(2 * 32, 128, 512, ce_chunk=-1)
+    assert not gate and "keeps its logits" in gate.reason
+    params = init_params(flash_cfg, jax.random.PRNGKey(0))
+    grads, losses, gnorm = {}, {}, {}
+    for name, cfg in (("flash", flash_cfg), ("keep", keep_cfg)):
+        losses[name], grads[name] = jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, batch, cfg, mesh=mesh))(params)
+        fns = training.build_gpt_train(cfg, mesh)
+        _, metrics = fns["step_fn"](fns["init_fn"](jax.random.PRNGKey(0)),
+                                    batch)
+        assert float(metrics["loss"]) == pytest.approx(
+            float(losses[name]), abs=2e-5)
+        gnorm[name] = float(metrics["grad_norm"])
+        assert fns["telemetry"].records[0]["ce_path"] == (
+            "flash" if name == "flash" else "xla_saved")
+    assert float(losses["keep"]) == pytest.approx(float(losses["flash"]),
+                                                  abs=2e-5)
+    assert gnorm["keep"] == pytest.approx(gnorm["flash"], rel=1e-4)
+    _assert_every_grad_close(grads["keep"], grads["flash"])
 
 
 @pytest.mark.slow  # two extra full train-step jits; grads covered above

@@ -119,6 +119,48 @@ def test_mfu_arithmetic_vs_hand_computed_flops(aot_run):
     assert rec["mfu"] == pytest.approx(expect_mfu, rel=1e-6)
 
 
+@pytest.mark.parametrize("ce_chunk, n_dev, ce_mode, path, vocab_matmuls", [
+    (-1, 1, None, "xla_saved", 3),       # both train cells' recipe
+    (4096, 1, None, "flash", 4),         # the default GPTConfig
+    (0, 1, None, "flash", 4),
+    (-1, 4, None, "xla_saved", 3),
+    (4096, 4, None, "xla_chunked", 4),   # sharded: the kernel declines
+    (-1, 1, "flash", "flash", 4),        # an A/B driver's pins
+    (4096, 1, "xla", "xla_chunked", 4),
+])
+def test_step_record_names_the_loss_head_and_prices_it(
+        ce_chunk, n_dev, ce_mode, path, vocab_matmuls):
+    """The run's ``ce_path`` (its first record and the summary carry
+    it) and the FLOPs a token follow the head the model's dispatch
+    names (``models.gpt.ce_path``, over ``flash_ce.uses_flash_ce``): three
+    vocabulary matmuls where the recipe keeps its logits, four wherever
+    they are recomputed, in flash-CE or in chunks."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.telemetry import StepTelemetry
+    from ray_tpu.telemetry.flops import gpt_fwd_flops_per_token
+
+    cfg = GPTConfig(vocab_size=512, d_model=128, n_layers=2, n_heads=2,
+                    max_seq=64, dtype=jnp.float32, ce_chunk=ce_chunk)
+    mesh = make_mesh(dp=n_dev, devices=jax.devices()[:n_dev])
+    tel = StepTelemetry(cfg, mesh, ce_mode=ce_mode,
+                        chip_peak_tflops=_PEAK)
+    step = tel.wrap(lambda state, batch: (state, {"loss": 0.0}))
+    batch = {"tokens": jnp.zeros((4, 32), jnp.int32)}
+    for _ in range(2):
+        step(None, batch)
+    assert [r.get("ce_path") for r in tel.records] == [path, None]
+    assert tel.summary()["ce_path"] == path
+    head = 2 * cfg.d_model * cfg.vocab_size
+    layers = 3 * (gpt_fwd_flops_per_token(cfg, 32) - head)
+    assert (tel.flops_per_token() - layers) / head == vocab_matmuls
+    # before a batch has shown its shape there is nothing to name
+    assert StepTelemetry(cfg, mesh, ce_mode=ce_mode).ce_path() is None
+
+
 def test_chrome_trace_export_valid(aot_run):
     """The exporter emits Perfetto-loadable JSON: a ``traceEvents``
     list of complete events carrying both host spans and step

@@ -12,6 +12,7 @@ interpret-mode rule, the peak-FLOPs table, and where the compile cache
 lives.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -75,6 +76,61 @@ def test_flash_ce_with_norm_fwd_bwd_compiles_for_v5e(v5e):
 
     _compile_for_v5e(step, v5e, ((N, DM), BF16), ((DM, V), BF16),
                      ((N,), jnp.int32), ((DM,), BF16))
+
+
+# What a v5e's allocator has to give (``memory_stats()["bytes_limit"]``
+# on the chip, PR 49: 15.75 GiB of the 16), and what the one-chip cell's
+# step may take of it: its arguments and temporaries read 0.69 + 12.36
+# GiB with the logits kept (PR 49), which leaves 2.7 GiB; the limit
+# below leaves 1.75 GiB, room for the loss head's f32 logits to grow by
+# a third before this fails ahead of the chip.
+V5E_HBM_BYTES = 16_909_336_064
+TRAIN_STEP_LIMIT_BYTES = 14 * 2**30
+
+
+@pytest.mark.parametrize("ce_chunk, n_layers, flash_calls", [
+    # the one-chip train cell's recipe (benchmark/cells/
+    # train-gpt2-124m-b24x1024.json), whole: it keeps its logits
+    (-1, 12, 0),
+    # a recipe that recomputes them, two layers deep (the loss head is
+    # what is looked for): flash-CE, forward and backward
+    (4096, 2, 2),
+], ids=["cell_recipe_keeps_logits", "default_recomputes"])
+def test_train_step_loss_head_and_memory_on_v5e(v5e, ce_chunk, n_layers,
+                                                flash_calls):
+    """The step ``build_gpt_train`` compiles at 24 x 1024 for one v5e:
+    which loss head is in the executable follows the recipe's
+    ``ce_chunk`` (no ``ce/flash`` Mosaic call where the logits are
+    kept, the forward and the backward kernel where they are
+    recomputed), and the cell's step, logits resident, fits the chip
+    with the margin stated above."""
+    from ray_tpu.models import training
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel.mesh import make_mesh
+
+    cfg = dataclasses.replace(
+        GPTConfig.gpt2(vocab_size=V, max_seq=S, dtype=BF16, remat=False,
+                       unroll_layers=True, ce_chunk=ce_chunk),
+        n_layers=n_layers)
+    mesh = make_mesh(devices=list(v5e.mesh.devices.flat), dp=-1)
+    fns = training.build_gpt_train(cfg, mesh, telemetry=False)
+    state = jax.eval_shape(fns["init_fn"], jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((B, S), jnp.int32,
+                                     sharding=fns["batch_sharding"])
+             for k in ("tokens", "targets")}
+    with substrate.compile_for_tpu():
+        compiled = fns["step_fn"].lower(state, batch).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "op_name=" in line]
+    assert any("attn/pack2" in line for line in kernels)
+    assert sum("ce/flash" in line for line in kernels) == flash_calls, \
+        [line for line in kernels if "/ce" in line]
+    if n_layers == 12:
+        mem = compiled.memory_analysis()
+        taken = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert taken < TRAIN_STEP_LIMIT_BYTES < V5E_HBM_BYTES, (
+            f"arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB + "
+            f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB")
 
 
 def test_fused_norm_epilogue_fwd_bwd_compiles_for_v5e(v5e):
