@@ -337,13 +337,15 @@ def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
     and must return ``(attn_out, new_cache)``; the block then returns
     ``(hidden, aux, new_cache)`` instead of the 2-tuple.
 
-    ``fuse_norm`` pins the fused out-proj epilogue (out-proj matmul +
-    residual add + pre-FFN rmsnorm in one Pallas kernel,
-    ``ray_tpu.ops.fused_norm``) for A/B drivers; default follows
-    ``RAY_TPU_FUSE_NORM``.  The dispatch gate
-    (``fused_norm.out_proj_norm_plan``) declines layernorm, biases,
-    sharded meshes and the S=1 decode step — those keep the XLA
-    einsum + ``_norm`` path unchanged.
+    The out-proj epilogue (out-proj matmul + residual add + pre-FFN
+    rmsnorm) goes through ``ray_tpu.ops.fused_norm`` where its dispatch
+    gate (``fused_norm.out_proj_norm_plan``) engages: one Pallas kernel
+    in a call nobody differentiates (a prefill), XLA's einsum + add +
+    norm and their gradients in a differentiated one (a train step),
+    which is what the branch below the gate writes.  The gate declines
+    layernorm, biases, sharded meshes and the S=1 decode step;
+    ``fuse_norm=False`` declines it too (the tests' pin; ``None`` is
+    on).
 
     ``lora``: per-layer low-rank adapter factors (``lora_delta``
     layout, single or banked) added to the qkv/out-proj/MLP matmul
@@ -405,15 +407,11 @@ def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
             n_devices=getattr(mesh, "size", 1) if mesh is not None else 1,
             seq=S, enabled=fuse_norm)
         if plan:
-            # out-proj + residual add + pre-FFN norm in one kernel:
-            # the residual stream is written once and the ln2 stats
-            # never run as their own XLA fusion
-            r2, y2 = fnorm.matmul_residual_norm(
-                attn.reshape(B * S, Hn * hd),
-                lp["wo"].reshape(Hn * hd, d),
-                x.reshape(B * S, d), lp["ln2"], eps=eps)
-            x = r2.reshape(B, S, d)
-            h2 = y2.reshape(B, S, d)
+            # out-proj + residual add + pre-FFN norm as one op: a
+            # kernel that writes the residual stream once where no
+            # gradient is taken, the branch below where one is
+            x, h2 = fnorm.matmul_residual_norm(attn, lp["wo"], x,
+                                               lp["ln2"], eps=eps)
         else:
             proj = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
             if lora is not None:
@@ -702,13 +700,13 @@ def loss_fn(params, batch, cfg: GPTConfig, *, attn_fn=None, mesh=None,
             fuse_norm: Optional[bool] = None, lora=None):
     """batch: dict(tokens [B,S], targets [B,S]); returns scalar loss.
 
-    ``fuse_norm`` pins the fused norm epilogues (default:
-    ``RAY_TPU_FUSE_NORM``): the per-layer out-proj epilogue in
-    ``layer_apply``, plus — when the flash-CE-with-norm gate passes —
-    skipping the XLA ``ln_f`` entirely and folding it into the
-    vocab-matmul kernel's prologue.  That gate declines where the
-    recipe keeps its logits (``cfg.ce_chunk < 0``): the loss head is
-    then XLA's, and so is ``ln_f``.
+    ``fuse_norm=False`` pins the fused norm epilogues off (``None`` is
+    on): the per-layer out-proj epilogue in ``layer_apply`` (under a
+    gradient XLA's formulation either way), plus — when the
+    flash-CE-with-norm gate passes — skipping the XLA ``ln_f`` entirely
+    and folding it into the vocab-matmul kernel's prologue.  That gate
+    declines where the recipe keeps its logits (``cfg.ce_chunk < 0``):
+    the loss head is then XLA's, and so is ``ln_f``.
 
     Sample-packed batches additionally carry ``segment_ids`` and
     ``positions`` [B, S] (``ray_tpu.data``): attention masks

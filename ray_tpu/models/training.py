@@ -239,11 +239,12 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
     and it is a plain-wire no-op on a single-pod mesh.  Either is
     dropped loudly when the effective comm_mode is "gspmd"
     (GSPMD owns its collectives), and the effective value is returned
-    as ``fns["comm_quant"]``.  ``fuse_norm`` pins the fused norm
-    epilogues ("on"/"off" via bool; default:
-    ``ray_tpu.ops.fused_norm.fuse_config`` from ``RAY_TPU_FUSE_NORM``)
-    — the out-proj residual/norm epilogue kernel in every block and
-    the ``ln_f``-in-flash-CE prologue, both of which decline loudly
+    as ``fns["comm_quant"]``.  ``fuse_norm=False`` pins the fused norm
+    epilogues off (``None`` is on; no environment variable decides):
+    the out-proj residual/norm epilogue of every block, which a
+    differentiated step runs in XLA's formulation either way (its
+    Pallas kernel is the forward-only call's, ``ops/fused_norm.py``),
+    and the ``ln_f``-in-flash-CE prologue; both decline loudly
     (reasoned gates) on sharded meshes and unsupported shapes.
     The overlap step/loss
     use their own block formulation (einsum attention, vocab-parallel

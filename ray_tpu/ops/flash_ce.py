@@ -569,13 +569,10 @@ def uses_flash_ce_norm(N: int, d: int, V: int, *, ce_chunk: int,
     The single source of the decision ``models.gpt.loss_fn`` makes
     before skipping the XLA final norm — also the reporting mirror.
     Requires the flash-CE path itself (:func:`uses_flash_ce`, whose
-    reason it passes on) plus the fused-norm knob and a norm the
-    prologue can fuse."""
-    from ray_tpu.ops.fused_norm import fuse_config
-    if enabled is None:
-        enabled = fuse_config().enabled
-    if not enabled:
-        return unsupported("disabled (RAY_TPU_FUSE_NORM=0)")
+    reason it passes on) plus a norm the prologue can fuse;
+    ``enabled=False`` pins it off (the tests' pin; ``None`` is on)."""
+    if enabled is not None and not enabled:
+        return unsupported("disabled (enabled=False)")
     if norm != "rmsnorm":
         return unsupported(f"norm={norm!r}: only rmsnorm fuses")
     if has_bias:

@@ -1,49 +1,45 @@
-"""Fused norm epilogue: out-proj matmul + residual add + RMSNorm in
-one Pallas kernel (the attention family's epilogue member).
+"""Out-proj epilogue: out-proj matmul + residual add + RMSNorm, one
+computation with two implementations (the attention family's epilogue
+member).
 
-PERF.md's remaining-headroom analysis pins ~18 ms/step of the GPT-2
-single-chip gap on work XLA cannot fuse across custom-call boundaries:
-the attention out-proj's residual/norm fusions (~13 ms) and the
-``[768]``-output reductions that compute the norm-scale gradients
-(~10.7 ms of the backward tail).  Once attention itself is a custom
-call, the neighbouring norm is orphaned — XLA schedules it as
-standalone HBM-rate fusions on either side of the kernel boundary.
+    r = resid + attn @ wo          # the residual stream, written once
+    y = rmsnorm(r) * scale         # the next block's normed input
 
-This kernel moves the whole residual/norm block *inside* the boundary.
-Forward, per ``block_n`` row block (one grid sweep, everything
-VMEM-resident):
+The bet (r13): once attention is a custom call its neighbouring norm
+is orphaned, and XLA schedules the out-proj's residual/norm work as
+standalone HBM-rate fusions on either side of the boundary; a Pallas
+kernel that keeps the whole block VMEM-resident per ``block_n`` rows
+(MXU matmul with f32 accumulation, the add in the storage dtype, the
+statistics in f32) would take them off the step.
 
-    p    = attn_blk @ wo            # MXU, f32 accumulation
-    r    = resid_blk + p            # the residual stream, written once
-    rstd = rsqrt(mean(r^2) + eps)   # norm statistics in the epilogue
-    y    = r * rstd * scale         # the next block's normed input
+Its outcome on the chip (PERF.md, PRs 49 and 53): the *forward* kernel
+holds at a prefill's 32-1024 rows (0.0020 s of a traced chat piece
+against XLA's 0.0041 + 0.0010).  The *backward* lost at a train step's
+24,576 rows: a ``[768, 768]`` weight-grad partial per row block, 96 of
+them summed afterwards, and a 2-D reshape of the attention kernel's
+output on each side cost the one-chip GPT-2 step 10.9 ms of 182 against
+the compiler's own einsum + add + norm and their gradients.
 
-emitting ``(r, y)`` plus an ``[N]``-sized ``rstd`` residual — the norm
-statistics are never re-derived from a re-materialized tensor.  The
-custom-vjp backward recomputes ``xhat = r * rstd`` from the saved
-stats and fuses the norm backward into the matmul grads:
-
-    dr       = rstd * (dy*scale - xhat * mean(dy*scale * xhat)) + dr_in
-    da_blk   = dr @ wo^T                      # back into attention
-    dwo[i]   = attn_blk^T @ dr                # per-row-block partial
-    dscale[i]= sum_rows(dy * xhat)            # per-row-block partial
-
-``dwo``/``dscale`` partials are emitted per row block and summed in
-one XLA pass — the ``flash_ce`` dhead idiom — which is what deletes
-the standalone ``[768]``-reduction dispatches from the step.
+So the call decides by whether it is differentiated.  ``_mrn`` is a
+``jax.custom_vjp`` whose primal is the Pallas forward kernel and whose
+rule is XLA's: JAX runs the primal only where no gradient is taken
+(the engine's prefill, a forward-only evaluation) and the rule
+wherever one is (under ``jax.checkpoint`` too), where the forward is
+:func:`xla_matmul_residual_norm` and the backward is ``jax.vjp`` of
+it, with the compiler's own residuals.  A differentiated step so
+compiles to what ``models.gpt.layer_apply``'s declined branch
+compiles to.
 
 Dispatch is a reasoned gate (:func:`out_proj_norm_plan`): rmsnorm
 only, no biases, single-device mesh (``pallas_call`` has no SPMD
 rule), lane-aligned ``K``/``d``, and a real sequence (the S=1 decode
-step keeps the XLA epilogue — per-token kernel launches lose there).
-``RAY_TPU_FUSE_NORM=0`` reverts everything.  Built directly on
-``ops/substrate.py``; numerics tests vs the unfused formulation live
-in ``tests/test_ops.py``.
+step keeps the XLA epilogue: per-token kernel launches lose there).
+Built directly on ``ops/substrate.py``; numerics tests against the XLA
+formulation live in ``tests/test_ops.py``.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -52,43 +48,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ray_tpu.ops.substrate import (STATS_LANES, CompilerParams, Support,
-                                   env_flag, env_int, resolve_blocks,
-                                   stats_in, supported, unsupported,
+                                   resolve_blocks, supported, unsupported,
                                    use_interpret)
 
-
-@dataclasses.dataclass(frozen=True)
-class FuseNormConfig:
-    """Fused-norm-epilogue knobs, resolved once from the environment.
-
-    - ``RAY_TPU_FUSE_NORM`` (default on; ``0`` disables): fold the
-      attention out-proj residual/norm and the final-norm CE prologue
-      into their neighbouring Pallas kernels wherever the dispatch
-      gates pass.
-    - ``RAY_TPU_FUSE_NORM_BN`` (default 256): row blocking — the
-      backward tile carries ``[bn, K]`` + ``[bn, d]`` f32 work plus
-      the ``[K, d]`` weight-grad partial, so it wants a narrower row
-      block than the attention kernels' 512/1024.
-    """
-    enabled: bool = True
-    block_n: int = 256
-
-
-_CONFIG: Optional[FuseNormConfig] = None
-
-
-def fuse_config(refresh: bool = False) -> FuseNormConfig:
-    """The process-wide :class:`FuseNormConfig` (env read once, cached).
-
-    ``refresh=True`` re-reads the environment — for tests and A/B
-    drivers that flip flags after import."""
-    global _CONFIG
-    if _CONFIG is None or refresh:
-        _CONFIG = FuseNormConfig(
-            enabled=env_flag("RAY_TPU_FUSE_NORM"),
-            block_n=env_int("RAY_TPU_FUSE_NORM_BN", 256),
-        )
-    return _CONFIG
+# the forward's row block (rows of attn/resid resident per grid step)
+BLOCK_N = 256
 
 
 def supports(N: int, K: int, d: int) -> Support:
@@ -96,8 +60,8 @@ def supports(N: int, K: int, d: int) -> Support:
 
     ``K`` (contraction) and ``d`` (output/norm) are both lane
     dimensions of VMEM-resident tiles, so they must be lane-aligned
-    and small enough that the weight block plus its grad partial fit
-    VMEM alongside the row blocks."""
+    and small enough that the weight block fits VMEM alongside the
+    row blocks."""
     if N <= 0:
         return unsupported(f"N={N} has no rows")
     if K % 128:
@@ -105,8 +69,8 @@ def supports(N: int, K: int, d: int) -> Support:
     if d % 128:
         return unsupported(f"d={d} not lane-aligned (128)")
     if K > 1536 or d > 1536:
-        return unsupported(f"K={K}, d={d}: weight block + grad partial "
-                           "exceed the VMEM budget (cap 1536)")
+        return unsupported(f"K={K}, d={d}: weight block exceeds the "
+                           "VMEM budget (cap 1536)")
     return supported("pallas fused out-proj epilogue")
 
 
@@ -119,12 +83,10 @@ def out_proj_norm_plan(N: int, K: int, d: int, *, norm: str = "rmsnorm",
     The single source of the fused-vs-XLA decision — shared by
     ``models.gpt.layer_apply`` and whatever reports the schedule, so a
     summary can't claim a fusion the dispatch declined.
-    ``enabled`` pins the knob for A/B drivers (default:
-    :func:`fuse_config`)."""
-    if enabled is None:
-        enabled = fuse_config().enabled
-    if not enabled:
-        return unsupported("disabled (RAY_TPU_FUSE_NORM=0)")
+    ``enabled=False`` pins the XLA formulation (the tests' A/B pin;
+    ``None`` is on: no environment variable decides)."""
+    if enabled is not None and not enabled:
+        return unsupported("disabled (enabled=False)")
     if norm != "rmsnorm":
         return unsupported(f"norm={norm!r}: only rmsnorm fuses")
     if has_bias:
@@ -140,7 +102,7 @@ def out_proj_norm_plan(N: int, K: int, d: int, *, norm: str = "rmsnorm",
 
 
 # ---------------------------------------------------------------------------
-# kernels
+# kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(a_ref, w_ref, r_ref, s_ref, rout_ref, y_ref, rstd_ref,
@@ -159,52 +121,23 @@ def _fwd_kernel(a_ref, w_ref, r_ref, s_ref, rout_ref, y_ref, rstd_ref,
     rstd_ref[0] = jnp.broadcast_to(rstd, rstd_ref.shape[1:])
 
 
-def _bwd_kernel(a_ref, w_ref, rout_ref, s_ref, rstd_ref, drout_ref,
-                dy_ref, da_ref, dresid_ref, dwp_ref, dsp_ref):
-    # (no eps here: the saved rstd already bakes it in — xhat is
-    # reconstructed as rout * rstd, never re-derived from statistics)
-    r32 = rout_ref[...].astype(jnp.float32)              # [bn, d]
-    rstd = rstd_ref[0][:, 0:1]                           # [bn, 1]
-    xhat = r32 * rstd
-    dy = dy_ref[...].astype(jnp.float32)
-    dxhat = dy * s_ref[...].astype(jnp.float32)
-    m = jnp.mean(dxhat * xhat, -1, keepdims=True)
-    # total cotangent into the residual stream: the norm backward plus
-    # whatever flowed in from downstream consumers of r
-    dr32 = rstd * (dxhat - xhat * m) + drout_ref[...].astype(jnp.float32)
-    dsp_ref[0] = jnp.sum(dy * xhat, 0, keepdims=True)    # [1, d] partial
-    dresid_ref[...] = dr32.astype(dresid_ref.dtype)
-    dp = dr32.astype(w_ref.dtype)
-    da_ref[...] = jax.lax.dot_general(
-        dp, w_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(da_ref.dtype)
-    dwp_ref[0] = jax.lax.dot_general(
-        a_ref[...], dp, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dwp_ref.dtype)
-
-
-# ---------------------------------------------------------------------------
-# custom VJP + public API
-# ---------------------------------------------------------------------------
-
 def _pad_rows(x, Np: int):
     return x if x.shape[0] == Np else \
         jnp.pad(x, ((0, Np - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
 
 
-def _row_blocks(N: int, block_n: int):
-    """(bn, Np, num_n) — the substrate's resolve_blocks row half (the
-    16-row alignment is the tree-wide bf16-safe sublane tile)."""
-    bn, _, Np, _ = resolve_blocks(N, 1, block_n, 1, lane_align=1)
-    return bn, Np, Np // bn
-
-
 def _run_fwd(a, w, resid, scale, eps, block_n):
+    """a [N, K], w [K, d], resid [N, d] -> (r, y), each [N, d]."""
     N, K = a.shape
     d = w.shape[1]
-    bn, Np, num_n = _row_blocks(N, block_n)
+    # the substrate's resolve_blocks row half (the 16-row alignment is
+    # the tree-wide bf16-safe sublane tile)
+    bn, _, Np, _ = resolve_blocks(N, 1, block_n, 1, lane_align=1)
+    num_n = Np // bn
     a, resid = _pad_rows(a, Np), _pad_rows(resid, Np)
-    rout, y, rstd = pl.pallas_call(
+    # the third output is the rows' rstd, which the Pallas backward
+    # read until PR 53; nothing reads it now (PERF.md, open questions)
+    rout, y, _ = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         grid=(num_n,),
         compiler_params=CompilerParams(
@@ -227,102 +160,65 @@ def _run_fwd(a, w, resid, scale, eps, block_n):
         ],
         interpret=use_interpret(),
     )(a, w, resid, scale[None, :])
-    return rout[:N], y[:N], rstd[:, :, 0].reshape(Np)[:N]
+    return rout[:N], y[:N]
+
+
+# ---------------------------------------------------------------------------
+# the two implementations + public API
+# ---------------------------------------------------------------------------
+
+def xla_matmul_residual_norm(attn, wo, resid, scale, *, eps: float = 1e-6):
+    """The XLA formulation: what a differentiated call runs, and the
+    parity oracle in tests/test_ops.py.  Step for step what
+    ``models.gpt.layer_apply``'s declined branch writes (its einsum on
+    the 4-D attention output, its add in the storage dtype, ``_norm``'s
+    rmsnorm with f32 statistics); tests/test_models.py holds the two to
+    one lowering."""
+    r = resid + jnp.einsum("bshk,hkd->bsd", attn, wo)
+    r32 = r.astype(jnp.float32)
+    r32 = r32 * jax.lax.rsqrt(jnp.mean(r32 * r32, -1, keepdims=True) + eps)
+    r32 = r32 * scale.astype(jnp.float32)
+    return r, r32.astype(r.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _mrn(a, w, resid, scale, eps, block_n):
-    (rout, y), _ = _mrn_fwd(a, w, resid, scale, eps, block_n)
-    return rout, y
+def _mrn(attn, wo, resid, scale, eps, block_n):
+    B, S, H, hd = attn.shape
+    d = wo.shape[-1]
+    with jax.named_scope("norm/fused_epilogue"):
+        r, y = _run_fwd(attn.reshape(B * S, H * hd), wo.reshape(H * hd, d),
+                        resid.reshape(B * S, d), scale, eps, block_n)
+    return r.reshape(B, S, d), y.reshape(B, S, d)
 
 
-def _mrn_fwd(a, w, resid, scale, eps, block_n):
-    rout, y, rstd = _run_fwd(a, w, resid, scale, eps, block_n)
-    # residuals are [N]-sized stats plus the inputs the grads contract
-    # against — the residual stream is saved once (rout), never both
-    # sides of the add
-    return (rout, y), (a, w, rout, scale, rstd)
+def _mrn_fwd(attn, wo, resid, scale, eps, block_n):
+    # the residuals are whatever XLA's own differentiation keeps
+    return jax.vjp(functools.partial(xla_matmul_residual_norm, eps=eps),
+                   attn, wo, resid, scale)
 
 
-def _mrn_bwd(eps, block_n, res, cts):
-    a, w, rout, scale, rstd = res
-    drout, dy = cts
-    N, K = a.shape
-    d = w.shape[1]
-    bn, Np, num_n = _row_blocks(N, block_n)
-    a, rout = _pad_rows(a, Np), _pad_rows(rout, Np)
-    drout, dy = _pad_rows(drout, Np), _pad_rows(dy, Np)
-    rstd_b = stats_in(_pad_rows(rstd[:, None], Np)[:, 0], num_n, bn)
-    da, dresid, dwp, dsp = pl.pallas_call(
-        _bwd_kernel,
-        grid=(num_n,),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
-        in_specs=[
-            pl.BlockSpec((bn, K), lambda i: (i, 0)),
-            pl.BlockSpec((K, d), lambda i: (0, 0)),
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, bn, STATS_LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn, K), lambda i: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, K, d), lambda i: (i, 0, 0)),
-            # [num_n, 1, d]: a (1, d) block over [num_n, d] breaks the
-            # TPU tiling rule (sublane dim 1 neither 8-aligned nor the
-            # array's); over a unit middle dim it is the full extent
-            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Np, K), a.dtype),
-            jax.ShapeDtypeStruct((Np, d), rout.dtype),
-            jax.ShapeDtypeStruct((num_n, K, d), w.dtype),
-            jax.ShapeDtypeStruct((num_n, 1, d), jnp.float32),
-        ],
-        interpret=use_interpret(),
-    )(a, w, rout, scale[None, :], rstd_b, drout, dy)
-    # per-row-block partials summed in ONE XLA pass each — these sums
-    # replace the standalone [d]-output reduction dispatches
-    dw = jnp.sum(dwp.astype(jnp.float32), 0).astype(w.dtype)
-    dscale = jnp.sum(dsp, (0, 1)).astype(scale.dtype)
-    return da[:N], dw, dresid[:N], dscale
+def _mrn_bwd(eps, block_n, vjp_fn, cts):
+    return vjp_fn(cts)
 
 
 _mrn.defvjp(_mrn_fwd, _mrn_bwd)
 
 
-def matmul_residual_norm(a, w, resid, scale, *, eps: float = 1e-6,
-                         block_n: Optional[int] = None
+def matmul_residual_norm(attn, wo, resid, scale, *, eps: float = 1e-6,
+                         block_n: int = BLOCK_N
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``(resid + a @ w, rmsnorm(resid + a @ w) * scale)`` — fused.
+    """``(resid + attn @ wo, rmsnorm(resid + attn @ wo) * scale)``.
 
-    a [N, K] (bf16 ok), w [K, d], resid [N, d], scale [d].  Returns
-    ``(r, y)``: the updated residual stream and the normed/scaled
-    hidden, with only ``[N]``-sized norm statistics saved between the
-    passes.  Differentiable in all four operands; ``dscale``/``dw``
-    come back through per-row-block partials (see module docstring).
-    Shapes :func:`supports` declines raise — dispatch is the caller's
-    job (:func:`out_proj_norm_plan`)."""
-    ok = supports(a.shape[0], a.shape[1], w.shape[1])
+    attn [B, S, H, hd] (bf16 ok), wo [H, hd, d], resid [B, S, d],
+    scale [d].  Returns ``(r, y)``: the updated residual stream and the
+    normed/scaled hidden.  A call nobody differentiates is one Pallas
+    kernel over ``block_n`` rows of ``B * S``; a differentiated call is
+    :func:`xla_matmul_residual_norm` and XLA's gradient of it, in all
+    four operands (see module docstring).  Shapes :func:`supports`
+    declines raise: dispatch is the caller's job
+    (:func:`out_proj_norm_plan`)."""
+    B, S, H, hd = attn.shape
+    ok = supports(B * S, H * hd, wo.shape[-1])
     if not ok:
         raise ValueError(f"matmul_residual_norm cannot tile: {ok.reason}")
-    if block_n is None:
-        block_n = fuse_config().block_n
-    with jax.named_scope("norm/fused_epilogue"):
-        return _mrn(a, w, resid, scale, eps, block_n)
-
-
-def xla_matmul_residual_norm(a, w, resid, scale, *, eps: float = 1e-6):
-    """Unfused XLA reference (the fallback formulation and the parity
-    oracle in tests/test_ops.py) — numerics mirror of
-    ``models.gpt.layer_apply``'s einsum + add + ``_norm`` path."""
-    r = resid + jax.lax.dot_general(
-        a, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(resid.dtype)
-    r32 = r.astype(jnp.float32)
-    rstd = jax.lax.rsqrt(jnp.mean(r32 * r32, -1, keepdims=True) + eps)
-    y = (r32 * rstd * scale.astype(jnp.float32)).astype(r.dtype)
-    return r, y
+    return _mrn(attn, wo, resid, scale, eps, block_n)

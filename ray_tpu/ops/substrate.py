@@ -27,7 +27,7 @@ code on top of these pieces, not a subsystem:
   on why.
 - :func:`env_int` / :func:`env_flag` — env-knob
   readers for the per-family config dataclasses
-  (``attention_config()`` / ``ce_config()`` / ``fuse_config()``).
+  (``attention_config()`` / ``ce_config()``).
 
 A kernel that Mosaic refuses is declined by its own gate, from shapes,
 before tracing — never by catching a compile error.

@@ -161,9 +161,8 @@ def _assert_every_grad_close(got, want, tol=1e-4):
 def test_gpt_train_fuse_norm_parity():
     """r13 acceptance: loss/grad parity of the exact loss closure
     build_gpt_train compiles — including the norm-scale grads
-    (ln1/ln2/ln_f) that come back through the fused kernels'
-    per-row-block partials — with RAY_TPU_FUSE_NORM pinned on vs
-    off."""
+    (ln1/ln2/ln_f; ln_f's comes back through the flash-CE prologue's
+    per-row-block partials) — with ``fuse_norm`` pinned on vs off."""
     from ray_tpu.models import gpt
 
     mesh = make_mesh(dp=1, devices=jax.devices()[:1])
@@ -177,6 +176,46 @@ def test_gpt_train_fuse_norm_parity():
     assert float(losses[True]) == pytest.approx(float(losses[False]),
                                                 abs=2e-5)
     _assert_every_grad_close(grads[True], grads[False])
+
+
+@pytest.mark.parametrize("layers", [
+    dict(unroll_layers=True),               # the train cells' recipe
+    dict(unroll_layers=True, remat=True),
+    dict(remat=True),                       # scanned, under checkpoint
+])
+def test_gpt_grad_lowers_as_xla_epilogue(layers):
+    """PR 53: the out-proj epilogue decides by whether it is
+    differentiated.  With the gate engaged (asserted), the gradient of
+    ``loss_fn`` lowers to the text it lowers to with the gate pinned
+    off (locations stripped, the counters jax appends to its private
+    functions' names too): the rule's einsum + add + norm is
+    ``layer_apply``'s declined branch.  The forward-only
+    ``forward_hidden`` at the same shapes keeps one kernel a layer."""
+    import dataclasses
+    import re
+
+    from ray_tpu.models import gpt
+
+    mesh = make_mesh(dp=1, devices=jax.devices()[:1])
+    cfg, batch = _fuse_norm_parity_cfg()
+    cfg = dataclasses.replace(cfg, ce_chunk=-1, **layers)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    texts = {}
+    for fuse in (None, False):
+        grad = jax.jit(jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, batch, cfg, mesh=mesh,
+                                  fuse_norm=fuse)))
+        texts[fuse] = re.sub(r"@([A-Za-z_]+?)_\d+\b", r"@\1",
+                             grad.lower(params).as_text())
+    assert texts[None] == texts[False]
+
+    def kernels(fuse):
+        return str(jax.make_jaxpr(lambda p: gpt.forward_hidden(
+            p, batch["tokens"], cfg, mesh=mesh, fuse_norm=fuse))(params)
+        ).count("pallas_call")
+    # one a layer, or one in the scanned body
+    assert kernels(None) == (cfg.n_layers if cfg.unroll_layers else 1)
+    assert kernels(False) == 0
 
 
 def test_gpt_train_keep_logits_matches_flash_ce():

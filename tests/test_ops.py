@@ -833,14 +833,18 @@ def test_decode_write_dispatch():
 # one kernel, and the ln_f-in-flash-CE prologue
 # ---------------------------------------------------------------------------
 def _mrn_inputs(N, K, d, dtype, seed=0):
+    """The layer's own shapes: attn [B, S, H, hd] with B * S = N rows
+    and H * hd = K, wo [H, hd, d], resid [B, S, d]."""
+    B, H = 2, 2
+    S, hd = N // B, K // H
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    a = jax.random.normal(ks[0], (N, K), dtype) * 0.3
-    w = jax.random.normal(ks[1], (K, d), dtype) * K ** -0.5
-    resid = jax.random.normal(ks[2], (N, d), dtype)
+    a = jax.random.normal(ks[0], (B, S, H, hd), dtype) * 0.3
+    w = jax.random.normal(ks[1], (H, hd, d), dtype) * K ** -0.5
+    resid = jax.random.normal(ks[2], (B, S, d), dtype)
     scale = (jnp.ones((d,)) + jax.random.normal(ks[3], (d,)) * 0.1
              ).astype(dtype)
-    drout = jax.random.normal(ks[4], (N, d), dtype)
-    dy = jax.random.normal(ks[5], (N, d), dtype)
+    drout = jax.random.normal(ks[4], (B, S, d), dtype)
+    dy = jax.random.normal(ks[5], (B, S, d), dtype)
     return a, w, resid, scale, drout, dy
 
 
@@ -855,12 +859,12 @@ def _mrn_inputs(N, K, d, dtype, seed=0):
                  marks=pytest.mark.slow),
 ])
 def test_matmul_residual_norm_matches_reference(dtype, N, tol):
-    """The fused out-proj epilogue kernel (interpret mode here, Mosaic
-    on chip): fwd (residual stream + normed hidden) and every grad —
-    attention input, out-proj weight, incoming residual, and the
-    norm-scale grad that comes back through per-row-block partials —
-    match the unfused XLA formulation, with cotangents flowing into
-    BOTH outputs like the real block."""
+    """The out-proj epilogue: the forward kernel (interpret mode here,
+    Mosaic on chip) gives the XLA formulation's residual stream and
+    normed hidden, and the differentiation rule's wiring gives its
+    gradients (attention input, out-proj weight, incoming residual,
+    norm scale), with cotangents flowing into BOTH outputs like the
+    real block."""
     import numpy as np
 
     from ray_tpu.ops import fused_norm as FN
@@ -896,6 +900,31 @@ def test_matmul_residual_norm_matches_reference(dtype, N, tol):
         n2 = np.asarray(x2, np.float32)
         denom = max(1e-6, float(np.abs(n2).max()))
         assert float(np.abs(n1 - n2).max()) / denom < tol * 10, name
+
+
+def test_matmul_residual_norm_kernel_only_without_gradient():
+    """PR 53: the epilogue decides by whether it is differentiated.  A
+    plain call is one ``pallas_call`` (the forward kernel); under
+    ``jax.grad`` or ``jax.value_and_grad``, also through
+    ``jax.checkpoint``, no kernel is left: forward and backward are
+    XLA's."""
+    from ray_tpu.ops import fused_norm as FN
+
+    a, w, resid, scale, drout, dy = _mrn_inputs(64, 128, 128, jnp.float32)
+
+    def loss(a, w, resid, scale):
+        r, y = FN.matmul_residual_norm(a, w, resid, scale)
+        return jnp.sum(r * drout) + jnp.sum(y * dy)
+
+    def kernels(fn):
+        return str(jax.make_jaxpr(fn)(a, w, resid, scale)).count(
+            "pallas_call")
+    assert kernels(FN.matmul_residual_norm) == 1
+    assert kernels(loss) == 1
+    for diff in (jax.grad(loss, argnums=(0, 1, 2, 3)),
+                 jax.value_and_grad(loss),
+                 jax.grad(jax.checkpoint(loss))):
+        assert kernels(diff) == 0
 
 
 @pytest.mark.parametrize("dtype,N,V,tol", [
@@ -984,7 +1013,7 @@ def test_fused_norm_dispatch_reasons(monkeypatch):
 
     # out-proj epilogue gate, one declining reason per condition
     cases = [
-        (dict(enabled=False), "RAY_TPU_FUSE_NORM=0"),
+        (dict(enabled=False), "enabled=False"),
         (dict(norm="layernorm"), "only rmsnorm"),
         (dict(has_bias=True), "bias"),
         (dict(n_devices=8), "no SPMD rule"),
@@ -1004,33 +1033,27 @@ def test_fused_norm_dispatch_reasons(monkeypatch):
     assert ok and "pallas" in ok.reason
     # unsupported shapes must raise at the op (dispatch is the caller)
     with pytest.raises(ValueError, match="cannot tile"):
-        FN.matmul_residual_norm(jnp.zeros((8, 96)), jnp.zeros((96, 128)),
-                                jnp.zeros((8, 128)), jnp.zeros((128,)))
+        FN.matmul_residual_norm(
+            jnp.zeros((1, 8, 1, 96)), jnp.zeros((1, 96, 128)),
+            jnp.zeros((1, 8, 128)), jnp.zeros((128,)))
 
-    # CE-prologue gate mirrors the same knob + the flash-CE conditions
+    # CE-prologue gate mirrors the same pin + the flash-CE conditions
     # (test_flash_ce_gate has those), at a recipe that recomputes
     norm_gate = functools.partial(FC.uses_flash_ce_norm, 128, 128, 512,
                                   ce_chunk=4096)
     assert norm_gate(enabled=True)
-    assert "RAY_TPU_FUSE_NORM=0" in norm_gate(enabled=False).reason
+    assert "enabled=False" in norm_gate(enabled=False).reason
     assert "only rmsnorm" in norm_gate(norm="layernorm",
                                        enabled=True).reason
     assert "bias" in norm_gate(has_bias=True, enabled=True).reason
     assert "declined" in norm_gate(n_devices=8, enabled=True).reason
     assert "declined" in norm_gate(mode="xla", enabled=True).reason
 
-    # the env knob resolves through fuse_config (cached; refresh
-    # re-reads) and both gates follow it when not pinned
-    try:
-        monkeypatch.setenv("RAY_TPU_FUSE_NORM", "0")
-        monkeypatch.setenv("RAY_TPU_FUSE_NORM_BN", "128")
-        cfg = FN.fuse_config(refresh=True)
-        assert not cfg.enabled and cfg.block_n == 128
-        assert "RAY_TPU_FUSE_NORM=0" in FN.out_proj_norm_plan(
-            128, 128, 128, norm="rmsnorm", seq=64).reason
-        assert "RAY_TPU_FUSE_NORM=0" in norm_gate().reason
-        monkeypatch.delenv("RAY_TPU_FUSE_NORM")
-        assert FN.fuse_config(refresh=True).enabled   # default on
-    finally:
-        monkeypatch.undo()
-        FN.fuse_config(refresh=True)
+    # PR 53: no environment variable decides; setting the ones that
+    # did changes no plan, and an unpinned gate is on
+    unpinned = dict(norm="rmsnorm", seq=64)
+    monkeypatch.setenv("RAY_TPU_FUSE_NORM", "0")
+    monkeypatch.setenv("RAY_TPU_FUSE_NORM_BN", "128")
+    assert FN.out_proj_norm_plan(128, 128, 128, **unpinned) == ok
+    assert norm_gate() == norm_gate(enabled=True)
+    assert norm_gate()

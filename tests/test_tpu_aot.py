@@ -123,6 +123,8 @@ def test_train_step_loss_head_and_memory_on_v5e(v5e, ce_chunk, n_layers,
     kernels = [line for line in compiled.as_text().splitlines()
                if "tpu_custom_call" in line and "op_name=" in line]
     assert any("attn/pack2" in line for line in kernels)
+    # PR 53: a differentiated step takes XLA's out-proj epilogue
+    assert not [line for line in kernels if "norm/fused_epilogue" in line]
     assert sum("ce/flash" in line for line in kernels) == flash_calls, \
         [line for line in kernels if "/ce" in line]
     if n_layers == 12:
@@ -134,16 +136,32 @@ def test_train_step_loss_head_and_memory_on_v5e(v5e, ce_chunk, n_layers,
 
 
 def test_fused_norm_epilogue_fwd_bwd_compiles_for_v5e(v5e):
+    """The out-proj epilogue at a prefill's rows (1 x 1024) and at the
+    train step's (24 x 1024): the forward compiles as Mosaic's kernel,
+    a value-and-grad of the same call holds no Mosaic call at all (PR
+    53: its forward and backward are XLA's)."""
+    def fwd(a, w, resid, scale):
+        return fused_norm.matmul_residual_norm(a, w, resid, scale)
+
     def step(a, w, resid, scale):
         def loss(a, w, resid, scale):
-            r, y = fused_norm.matmul_residual_norm(a, w, resid, scale)
+            r, y = fwd(a, w, resid, scale)
             return (r.astype(jnp.float32).sum()
                     + (y.astype(jnp.float32) ** 2).sum())
         return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
             a, w, resid, scale)
 
-    _compile_for_v5e(step, v5e, ((N, DM), BF16), ((DM, DM), BF16),
-                     ((N, DM), BF16), ((DM,), BF16))
+    def shapes(b):
+        return (((b, S, H, D), BF16), ((H, D, DM), BF16),
+                ((b, S, DM), BF16), ((DM,), BF16))
+
+    for b in (1, B):
+        hlo = _compile_for_v5e(fwd, v5e, *shapes(b)).as_text()
+        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes(B)]
+    with substrate.compile_for_tpu():
+        compiled = jax.jit(step, out_shardings=v5e).lower(*specs).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8])
