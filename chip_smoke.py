@@ -155,7 +155,7 @@ def train_loop(config: dict) -> None:
                                                 enable_compile_cache)
     from ray_tpu.models import gpt, training
     from ray_tpu.ops import flash_ce
-    from ray_tpu.ops.attention import uses_pack2
+    from ray_tpu.ops.attention import train_causal_coverage, uses_pack2
     from ray_tpu.parallel.mesh import make_mesh
 
     cache_dir = enable_compile_cache()
@@ -188,6 +188,10 @@ def train_loop(config: dict) -> None:
         ce = "flash_norm"
     gates = {
         "attn_pack2": uses_pack2(S, S, cfg.n_heads, cfg.head_dim),
+        # the share of the causal score square that schedule executes
+        # (0.5005 needed at 1024; whole blocks of 512 masked ran 0.75)
+        "causal_coverage": train_causal_coverage(S, cfg.n_heads,
+                                                 cfg.head_dim),
         "ce": ce,
         # off whatever the shapes: the step is differentiated, and a
         # differentiated out-proj epilogue is XLA's einsum + add + norm
@@ -258,10 +262,12 @@ def kernel_parity(config: dict) -> dict:
     def up(x):
         return x.astype(f32)
 
-    def row(kernel, shape, errs, tols, calls=None, want_calls=None):
+    def row(kernel, shape, errs, tols, calls=None, want_calls=None,
+            **facts):
         """``calls``: the Mosaic kernels counted in the executable (None
-        where none was counted), which have to be ``want_calls``."""
-        r = {"kernel": kernel, "shape": shape,
+        where none was counted), which have to be ``want_calls``;
+        ``facts``: what else the row says of the kernel it checked."""
+        r = {"kernel": kernel, "shape": shape, **facts,
              "err": {k: float(f"{v:.3g}") for k, v in errs.items()},
              "ok": all(errs[k] <= tols[k] for k in errs)}
         if calls is not None:
@@ -305,7 +311,8 @@ def kernel_parity(config: dict) -> dict:
         row(name + "+rope fwd+bwd", [B, S, H, D],
             {"o": _rel_err(o, o_ref), "dq": _rel_err(g[0], g_ref[0]),
              "dk": _rel_err(g[1], g_ref[1]), "dv": _rel_err(g[2], g_ref[2])},
-            {"o": TOL_OUT, "dq": TOL_GRAD, "dk": TOL_GRAD, "dv": TOL_GRAD})
+            {"o": TOL_OUT, "dq": TOL_GRAD, "dk": TOL_GRAD, "dv": TOL_GRAD},
+            causal_coverage=A.train_causal_coverage(S, H, D))
         del q, k, v, w
 
     # -- out-proj + residual + rmsnorm epilogue, differentiated ------------

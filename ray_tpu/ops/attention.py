@@ -24,6 +24,17 @@ full-width output) and the op *count* halves.  Controlled by
 head counts, head_dim 128 and shapes the packed grid cannot tile fall
 back to the single-head schedule unchanged.
 
+Causal structure: the packed kernels carry it in the schedule, not in
+a mask over whole blocks (see "the causal structure, as a schedule"
+below): blocks above the diagonal are skipped, blocks below it run with
+no mask, and a block the diagonal crosses is walked in row sub-blocks
+over the columns each can see, with a mask on the one sub-tile the
+diagonal crosses.  :func:`causal_coverage` counts what that executes
+of the square (0.625 at 1024 tokens, 0.5005 needed; 0.75 when whole
+blocks of 512 were masked).  The single-head kernels still skip and
+mask by whole block: walked the same way their forward read slower on
+the chip (0.58 -> 0.67 ms a layer at head_dim 128, PR 54).
+
 Numerics: scores/stats in f32 regardless of input dtype; probability
 blocks are cast back to the value dtype for the MXU matmuls.  Numerics
 tests vs the einsum path (packed and unpacked) live in
@@ -60,18 +71,23 @@ class AttentionConfig:
     was removed after A/B showed VPU exp is not the bottleneck):
 
     - ``RAY_TPU_ATTN_BWD_BQ`` / ``RAY_TPU_ATTN_BWD_BK`` (default 512):
-      causal-backward blocking, profiled on v5e at GPT-2 shapes.
+      causal-backward blocking of the single-head schedule, whose only
+      causal skip is of whole blocks, profiled on v5e at GPT-2 shapes.
     - ``RAY_TPU_ATTN_PACK2`` (default on; ``0`` disables): two-head lane
       packing for head_dim-64 even-head attention (see module docstring).
-    - ``RAY_TPU_ATTN_PACK2_BQ`` / ``RAY_TPU_ATTN_PACK2_BK`` (default 512):
-      packed-kernel blocking — scores are [bq, 2*bk] so the packed
-      forward wants smaller blocks than the unpacked 1024 default.
+    - ``RAY_TPU_ATTN_PACK2_BQ`` / ``RAY_TPU_ATTN_PACK2_BK`` (default
+      1024): packed-kernel blocking, forward and backward.  The packed
+      kernels walk every block in row sub-blocks and skip what lies
+      above the diagonal by sub-tile, so a large block costs no
+      coverage and saves grid steps, re-fetched K/V and repeated RoPE:
+      at 24 x 1024 x 12 x 64 on a v5e, 1024 reads 1.26 + 2.25 ms a
+      layer forward + backward where 512 reads 1.55 + 2.75 (PR 54).
     """
     bwd_block_q: int = 512
     bwd_block_k: int = 512
     pack2: bool = True
-    pack2_block_q: int = 512
-    pack2_block_k: int = 512
+    pack2_block_q: int = 1024
+    pack2_block_k: int = 1024
 
 
 _CONFIG: Optional[AttentionConfig] = None
@@ -88,8 +104,8 @@ def attention_config(refresh: bool = False) -> AttentionConfig:
             bwd_block_q=env_int("RAY_TPU_ATTN_BWD_BQ", 512),
             bwd_block_k=env_int("RAY_TPU_ATTN_BWD_BK", 512),
             pack2=env_flag("RAY_TPU_ATTN_PACK2"),
-            pack2_block_q=env_int("RAY_TPU_ATTN_PACK2_BQ", 512),
-            pack2_block_k=env_int("RAY_TPU_ATTN_PACK2_BK", 512),
+            pack2_block_q=env_int("RAY_TPU_ATTN_PACK2_BQ", 1024),
+            pack2_block_k=env_int("RAY_TPU_ATTN_PACK2_BK", 1024),
         )
     return _CONFIG
 
@@ -222,13 +238,6 @@ def _half_mask(rows: int, sub_d: int):
     return _lane_ids(rows, 2 * sub_d) < sub_d
 
 
-def _blockdiag2(x, sub_d: int):
-    """Packed rows [r, 2*sub_d] -> block-diagonal [2r, 2*sub_d]."""
-    m = _half_mask(x.shape[0], sub_d)
-    z = jnp.zeros_like(x)
-    return jnp.concatenate([jnp.where(m, x, z), jnp.where(m, z, x)], 0)
-
-
 def _fold2(t, bk: int, sub_d: int):
     """Inverse of the block-diagonal output: [2*bk, 128] -> [bk, 128].
 
@@ -268,26 +277,149 @@ def _rot2_t(g, cos2, sinm, sub_d: int):
     return out.astype(g.dtype)
 
 
-def _masked_scores2(qp, kd, i, j, *, scale: float, causal: bool,
-                    block_q: int, block_k: int):
-    """Packed scores [bq, 2*bk] for blocks (i, j): head A on columns
-    :bk, head B on columns bk:.  One [bq, 128] x [128, 2*bk] matmul —
-    the zeros in the block-diagonal ``kd`` annihilate the other head's
-    q lanes, so no separation mask is needed; the causal mask applies
-    per half (both heads sit at the same positions)."""
+def _heads2(x, sub_d: int):
+    """Packed rows [r, 2*sub_d] -> (head A's rows, head B's rows), each
+    with the other head's lanes zeroed: rows ``[lo:hi]`` of the two,
+    stacked, are the block-diagonal arrangement of rows ``[lo:hi]``."""
+    m = _half_mask(x.shape[0], sub_d)
+    z = jnp.zeros_like(x)
+    return jnp.where(m, x, z), jnp.where(m, z, x)
+
+
+def _mask_tail(s, keep):
+    """Scores [n, L] with their last ``w`` columns masked by ``keep``
+    (bool [n, w], None: nothing to mask) — the only columns a causal
+    piece's diagonal crosses; the columns before them are not touched."""
+    if keep is None:
+        return s
+    L, w = s.shape[1], keep.shape[1]
+    if w == L:
+        return jnp.where(keep, s, _NEG_INF)
+    return jnp.concatenate(
+        [s[:, :L - w], jnp.where(keep, s[:, L - w:], _NEG_INF)], 1)
+
+
+def _scores2(q, kd, *, scale: float, keep=None):
+    """Packed f32 scores of q rows [n, 128] against L kv rows in their
+    block-diagonal arrangement ``kd`` [2*L, 128] (:func:`_heads2`,
+    stacked): (head A's [n, L], head B's [n, L]) from one
+    [n, 128] x [128, 2*L] matmul — the zeros in ``kd`` annihilate the
+    other head's q lanes.  ``keep`` (bool [n, w]) masks the last ``w``
+    columns of each head, the only ones a causal tile's diagonal
+    crosses; columns before them are not touched."""
+    L = kd.shape[0] // 2
     s = jax.lax.dot_general(
-        qp, kd, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # [bq, 2*bk]
-    if causal:
-        q_idx = (i * block_q
-                 + jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, 2 * block_k), 0))
-        k_idx = (j * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, 2 * block_k), 1)
-                 % block_k)
-        s = jnp.where(q_idx >= k_idx, s, _NEG_INF)
-    return s
+        q, kd, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # [n, 2*L]
+    return _mask_tail(s[:, :L], keep), _mask_tail(s[:, L:], keep)
+
+
+# ---------------------------------------------------------------------------
+# the causal structure, as a schedule
+#
+# A block (i, j) sits at offset d = i*block_q - j*block_k from the
+# diagonal: local row r sees local column c iff r + d >= c.  It is dead
+# (d <= -block_q: never computed), interior (d >= block_k - 1: every
+# element live, computed with no mask) or diagonal.  The packed kernels
+# walk every block in row sub-blocks of ``sub`` rows, so a piece of work
+# is [sub, 2*L] scores whatever the block sizes are; a row sub-block of a
+# diagonal block (:func:`_diag_rows`) computes the block's columns
+# [0, L) it can see, and of those only the last ``sub`` — the one
+# sub-tile the diagonal crosses — are masked; sub-tiles above the
+# diagonal are never computed.  Everything here is a static shape fact,
+# so the kernels unroll the walk at trace time.
+# ---------------------------------------------------------------------------
+
+def _causal_sub(block_q: int, block_k: int) -> Optional[int]:
+    """The sub-tile edge of the walk: the largest of 256, 128 that
+    divides both block sizes (None: a block is one piece, and a
+    diagonal block is masked whole)."""
+    for t in (256, 128):
+        if block_q % t == 0 and block_k % t == 0:
+            return t
+    return None
+
+
+def _is_interior(d, block_k: int):
+    return d >= block_k - 1
+
+
+def _is_diagonal(d, block_q: int, block_k: int):
+    return (d > -block_q) & (d < block_k - 1)
+
+
+def _full_rows(block_q: int, block_k: int, sub: Optional[int]):
+    """The walk of a block with nothing to mask: ``(r0, r1, L, w)`` as
+    :func:`_diag_rows` gives them."""
+    sub = sub or block_q
+    return [(r0, r0 + sub, block_k, 0) for r0 in range(0, block_q, sub)]
+
+
+def _diag_rows(block_q: int, block_k: int, sub: Optional[int], d: int):
+    """The walk of a diagonal block at offset ``d``: ``(r0, r1, L, w)``
+    — rows [r0, r1) against the block's columns [0, L), of which the
+    last ``w`` are masked (0: none).  Rows that see nothing have no
+    entry."""
+    if sub is None:
+        return [(0, block_q, block_k, block_k)]
+    rows = []
+    for r0 in range(0, block_q, sub):
+        edge = r0 + d                  # the crossed sub-tile's column
+        if 0 <= edge < block_k:
+            rows.append((r0, r0 + sub, edge + sub, sub))
+        elif edge >= block_k:
+            rows.append((r0, r0 + sub, block_k, 0))
+    return rows
+
+
+def _causal_keep(rows: int, cols: int, shift):
+    """bool [rows, cols]: local row r sees local column c."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) + shift
+            >= jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _offsets(num_q: int, block_q: int, kv_starts):
+    """Every offset from the diagonal at which one of ``num_q`` q blocks
+    meets a kv block that starts at one of the columns ``kv_starts``."""
+    return [i * block_q - c0 for i in range(num_q) for c0 in kv_starts]
+
+
+def _causal_walk(d, offsets, begin, *, causal: bool, block_q: int,
+                 block_k: int):
+    """Walk what a q block sees of a kv block at the (traced) offset
+    ``d``, one of the static ``offsets`` the grid can put there.
+    ``begin()`` prepares the pair's operands once and returns
+    ``update(r0, r1, L, keep)``, which is run over :func:`_full_rows`
+    for an interior block and over :func:`_diag_rows` for a diagonal
+    one (each such offset is its own branch, so every slice is static);
+    a dead block begins nothing."""
+    sub = _causal_sub(block_q, block_k)
+
+    def _unmasked():
+        update = begin()
+        for r0, r1, L, _ in _full_rows(block_q, block_k, sub):
+            update(r0, r1, L, None)
+
+    if not causal:
+        _unmasked()
+        return
+    if any(_is_interior(d0, block_k) for d0 in offsets):
+        pl.when(_is_interior(d, block_k))(_unmasked)
+    if sub is None:
+        @pl.when(_is_diagonal(d, block_q, block_k))
+        def _masked_whole():
+            begin()(0, block_q, block_k,
+                    _causal_keep(block_q, block_k, d))
+        return
+    for d0 in sorted(set(offsets)):
+        if not _is_diagonal(d0, block_q, block_k):
+            continue
+
+        @pl.when(d == d0)
+        def _diagonal(d0=d0):
+            update, tri = begin(), _causal_keep(sub, sub, 0)
+            for r0, r1, L, w in _diag_rows(block_q, block_k, sub, d0):
+                update(r0, r1, L, tri if w else None)
 
 
 # ---------------------------------------------------------------------------
@@ -405,13 +537,29 @@ def _fwd(q, k, v, *, scale: float, causal: bool,
     return o, lse
 
 
+# What the packed kernels may take of a v5e's 128 MiB of VMEM.  The
+# backward keeps the whole kv sequence resident (k, v, their RoPE
+# tables, dk and dv double-buffered, two f32 accumulators, the rotated
+# k) beside its q block and a walk step's [256, 2*1024] f32
+# temporaries.  Under the default 16 MiB limit Mosaic refused it at
+# 2048 rows with q blocks of 1024, and at 4096 and 8192 with any blocks
+# ("Ran out of memory in memory space vmem", compiled for a described
+# v5e, PR 54), though the gate admits 8192; under this one every such
+# shape compiles.
+_PACK2_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
 def _fwd_pack2_kernel(q_ref, k_ref, v_ref, *rest, scale: float,
                       causal: bool, block_q: int, block_k: int,
-                      num_kv: int, has_rope: bool, sub_d: int):
+                      num_q: int, num_kv: int, has_rope: bool,
+                      sub_d: int):
     """Packed forward: blocks are [bq, 128] head pairs; scores/stats run
     per half while both matmuls go through the MXU at full lane width
-    (one [bq, 128] x [128, 2*bk] score op, one [bq, 2*bk] x [2*bk, 128]
-    accumulate op — half the op count of the unpacked pair)."""
+    (one [n, 128] x [128, 2*L] score op, one [n, 2*L] x [2*L, 128]
+    accumulate op — half the op count of the unpacked pair).  The
+    causal structure is the schedule's (:func:`_causal_walk`): one
+    update per row sub-block over the columns it sees, each with its
+    own slice of the online-softmax state."""
     if has_rope:
         (cq_ref, sq_ref, ck_ref, sk_ref,
          o_ref, lse0_ref, lse1_ref, acc_sc, m_sc, l_sc) = rest
@@ -425,37 +573,44 @@ def _fwd_pack2_kernel(q_ref, k_ref, v_ref, *rest, scale: float,
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k))
-    def _compute():
+    def _begin():
         qp = q_ref[0, 0]                     # [bq, 128] packed pair
         kp = k_ref[0, 0]                     # [bk, 128]
-        vp = v_ref[0, 0]
         if has_rope:
             qp = _rot2(qp, cq_ref[...], sq_ref[...], sub_d)
             kp = _rot2(kp, ck_ref[...], sk_ref[...], sub_d)
-        kd = _blockdiag2(kp, sub_d)          # [2*bk, 128]
-        s = _masked_scores2(qp, kd, i, j, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k)
-        s0, s1 = s[:, :block_k], s[:, block_k:]
-        m0_prev, m1_prev = m_sc[0], m_sc[1]  # [bq, 128] (col-bcast)
-        m0 = jnp.maximum(m0_prev, jnp.max(s0, axis=1, keepdims=True))
-        m1 = jnp.maximum(m1_prev, jnp.max(s1, axis=1, keepdims=True))
-        a0 = jnp.exp(m0_prev - m0)
-        a1 = jnp.exp(m1_prev - m1)
-        p0 = jnp.exp(s0 - m0[:, :1])
-        p1 = jnp.exp(s1 - m1[:, :1])
-        l_sc[0] = l_sc[0] * a0 + jnp.sum(p0, 1, keepdims=True)
-        l_sc[1] = l_sc[1] * a1 + jnp.sum(p1, 1, keepdims=True)
-        pd = jnp.concatenate([p0, p1], 1).astype(vp.dtype)
-        vd = _blockdiag2(vp, sub_d)          # [2*bk, 128]
-        alpha = jnp.where(_half_mask(block_q, sub_d), a0, a1)
-        acc_sc[:] = (acc_sc[:] * alpha
-                     + jax.lax.dot_general(
-                         pd, vd, (((1,), (0,)), ((), ())),
-                         preferred_element_type=jnp.float32))
-        m_sc[0] = m0
-        m_sc[1] = m1
+        ka, kb = _heads2(kp, sub_d)
+        va, vb = _heads2(v_ref[0, 0], sub_d)
+
+        def _update(r0, r1, L, keep):
+            kd = jnp.concatenate([ka[:L], kb[:L]], 0)      # [2*L, 128]
+            s0, s1 = _scores2(qp[r0:r1], kd, scale=scale, keep=keep)
+            m0_prev, m1_prev = m_sc[0, r0:r1], m_sc[1, r0:r1]  # [n, 128]
+            m0 = jnp.maximum(m0_prev, jnp.max(s0, axis=1, keepdims=True))
+            m1 = jnp.maximum(m1_prev, jnp.max(s1, axis=1, keepdims=True))
+            a0 = jnp.exp(m0_prev - m0)
+            a1 = jnp.exp(m1_prev - m1)
+            p0 = jnp.exp(s0 - m0[:, :1])
+            p1 = jnp.exp(s1 - m1[:, :1])
+            l_sc[0, r0:r1] = (l_sc[0, r0:r1] * a0
+                              + jnp.sum(p0, 1, keepdims=True))
+            l_sc[1, r0:r1] = (l_sc[1, r0:r1] * a1
+                              + jnp.sum(p1, 1, keepdims=True))
+            pd = jnp.concatenate([p0, p1], 1).astype(va.dtype)
+            vd = jnp.concatenate([va[:L], vb[:L]], 0)      # [2*L, 128]
+            alpha = jnp.where(_half_mask(r1 - r0, sub_d), a0, a1)
+            acc_sc[r0:r1] = (acc_sc[r0:r1] * alpha
+                             + jax.lax.dot_general(
+                                 pd, vd, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32))
+            m_sc[0, r0:r1] = m0
+            m_sc[1, r0:r1] = m1
+        return _update
+
+    _causal_walk(i * block_q - j * block_k,
+                 _offsets(num_q, block_q,
+                          range(0, num_kv * block_k, block_k)),
+                 _begin, causal=causal, block_q=block_q, block_k=block_k)
 
     @pl.when(j == num_kv - 1)
     def _finalize():
@@ -469,13 +624,24 @@ def _fwd_pack2_kernel(q_ref, k_ref, v_ref, *rest, scale: float,
         lse1_ref[0, 0, 0] = jnp.broadcast_to(lse1, lse1_ref.shape[3:])
 
 
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "causal", "block_q", "block_k", "sub_d", "interpret"))
 def _fwd_pack2(q, k, v, *, scale: float, causal: bool, block_q: int,
-               block_k: int, rope=None, sub_d: int = 64):
+               block_k: int, interpret: bool, rope=None, sub_d: int = 64):
     """Packed q,k,v: [B, Hp, S, 2*sub_d] -> (o packed, lse0, lse1 each
     [B, Hp, S // bq, bq, STATS_LANES] f32 — per-sub-head row stats).
 
     ``rope``: optional packed tables (cos2 [S, 128], sinm [S, 128] —
-    the D=sub_d tables duplicated along lanes)."""
+    the D=sub_d tables duplicated along lanes).
+
+    An inlined ``jit``: the compiled program is the same to the op name,
+    and an unrolled model's layers, which all make this call, trace and
+    lower the kernel's walk once, not once a layer (the 24 kernels of
+    the 12-layer train step lower in 5 s without it and in 1.5 s with
+    it, on the sandbox's CPU for a described v5e, PR 54; every run pays
+    that in its set-up).  What the body reads besides its arguments has
+    to be constant, so ``interpret`` (``substrate.use_interpret()``: a
+    context decides it) is the caller's to pass."""
     B, Hp, S, Dp = q.shape
     Sk = k.shape[2]
     bq, bk = min(block_q, S), min(block_k, Sk)
@@ -484,8 +650,8 @@ def _fwd_pack2(q, k, v, *, scale: float, causal: bool, block_q: int,
 
     kernel = functools.partial(
         _fwd_pack2_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, num_kv=num_kv, has_rope=rope is not None,
-        sub_d=sub_d)
+        block_k=bk, num_q=grid[2], num_kv=num_kv,
+        has_rope=rope is not None, sub_d=sub_d)
     rope_args, rope_specs = (), []
     if rope is not None:
         cos2, sinm = rope
@@ -505,7 +671,8 @@ def _fwd_pack2(q, k, v, *, scale: float, causal: bool, block_q: int,
         grid=grid,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=_PACK2_VMEM_LIMIT_BYTES),
         in_specs=[
             pl.BlockSpec((1, 1, bq, Dp), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, Dp), lambda b, h, i, j: (b, h, j, 0)),
@@ -527,7 +694,7 @@ def _fwd_pack2(q, k, v, *, scale: float, causal: bool, block_q: int,
             pltpu.VMEM((2, bq, 128), jnp.float32),
             pltpu.VMEM((2, bq, 128), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(q, k, v, *rope_args)
     return o, lse0, lse1
 
@@ -680,18 +847,22 @@ def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
                       num_q: int, num_kv: int, has_rope: bool,
                       sub_d: int):
     """Packed strip-mined fused backward: the packed analogue of
-    `_bwd_fused_kernel` (same grid, same dead-strip skipping, same
-    rope-at-the-boundary structure), with every matmul full-width:
+    `_bwd_fused_kernel` (same grid, same rope-at-the-boundary
+    structure), with every matmul full-width.  For n q rows against L
+    kv rows of a strip:
 
-        s  = qp @ kd^T          [bq, 128] x [128, 2*bk]
-        dp = do @ vd^T          [bq, 128] x [128, 2*bk]
-        dv = fold(pd^T @ do)    [2*bk, bq] x [bq, 128]
-        dk = fold(dsd^T @ qp)   [2*bk, bq] x [bq, 128]
-        dq = dsd @ kd           [bq, 2*bk] x [2*bk, 128]
+        s  = qp @ kd^T          [n, 128] x [128, 2*L]
+        dp = do @ vd^T          [n, 128] x [128, 2*L]
+        dv = fold(pd^T @ do)    [2*L, n] x [n, 128]
+        dk = fold(dsd^T @ qp)   [2*L, n] x [n, 128]
+        dq = dsd @ kd           [n, 2*L] x [2*L, 128]
 
-    — 5 ops per strip for a head *pair* vs 10 half-width ops on the
-    unpacked schedule.  ``fold`` keeps each half's own lanes and drops
-    the cross-head lanes the widened transpose matmuls produce."""
+    — 5 ops for a head *pair* vs 10 half-width ops on the unpacked
+    schedule.  ``fold`` keeps each half's own lanes and drops the
+    cross-head lanes the widened transpose matmuls produce.  Which
+    (n, L) pieces of a strip run, and which of them are masked, is the
+    schedule's (:func:`_causal_walk`): dq accumulates by row sub-block,
+    dk/dv into the rows of their scratch the piece covers."""
     if has_rope:
         (cq_ref, sq_ref, ck_ref, sk_ref,
          dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, krot_sc) = rest
@@ -703,7 +874,8 @@ def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
     def _init_kv():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
-        if has_rope and num_kv > 1:
+        if has_rope:
+            # rotate k ONCE per (b, h): every q block's strips reuse it
             krot_sc[:] = _rot2(k_ref[0, 0], ck_ref[...], sk_ref[...],
                                sub_d)
 
@@ -715,60 +887,46 @@ def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
     lse1 = lse1_ref[0, 0, 0][:, 0:1]
     delta0 = delta0_ref[0, 0, 0][:, 0:1]
     delta1 = delta1_ref[0, 0, 0][:, 0:1]
+    dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    def _strip_math(kp, vp, j):
-        kd = _blockdiag2(kp, sub_d)              # [2*bk, 128]
-        vd = _blockdiag2(vp, sub_d)
-        s = _masked_scores2(qp, kd, i, j, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k)
-        p0 = jnp.exp(s[:, :block_k] - lse0)
-        p1 = jnp.exp(s[:, block_k:] - lse1)
-        dp = jax.lax.dot_general(
-            do, vd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bq, 2*bk]
-        ds0 = p0 * (dp[:, :block_k] - delta0) * scale
-        ds1 = p1 * (dp[:, block_k:] - delta1) * scale
-        pd = jnp.concatenate([p0, p1], 1)
-        dsd = jnp.concatenate([ds0, ds1], 1)
-        return kd, pd, dsd
+    for j in range(num_kv):
+        lo, hi = j * block_k, (j + 1) * block_k
 
-    if num_kv == 1:
-        kp = k_ref[0, 0]
-        if has_rope:
-            kp = _rot2(kp, ck_ref[...], sk_ref[...], sub_d)
-        kd, pd, dsd = _strip_math(kp, v_ref[0, 0], 0)
-        dv_sc[:] += _fold2(jax.lax.dot_general(
-            pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32), block_k, sub_d)
-        dk_sc[:] += _fold2(jax.lax.dot_general(
-            dsd.astype(qp.dtype), qp, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32), block_k, sub_d)
-        dq = jax.lax.dot_general(
-            dsd.astype(kd.dtype), kd, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    else:
-        dq_sc[:] = jnp.zeros_like(dq_sc)
-        for j in range(num_kv):
-            lo, hi = j * block_k, (j + 1) * block_k
+        def _begin(lo=lo, hi=hi):
+            k_rows = krot_sc if has_rope else k_ref.at[0, 0]
+            ka, kb = _heads2(k_rows[lo:hi, :], sub_d)
+            va, vb = _heads2(v_ref[0, 0, lo:hi, :], sub_d)
 
-            @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                                 block_k=block_k))
-            def _strip(j=j, lo=lo, hi=hi):
-                if has_rope:
-                    kp = krot_sc[lo:hi, :]
-                else:
-                    kp = k_ref[0, 0, lo:hi, :]
-                kd, pd, dsd = _strip_math(kp, v_ref[0, 0, lo:hi, :], j)
-                dv_sc[lo:hi, :] += _fold2(jax.lax.dot_general(
-                    pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32), block_k, sub_d)
-                dk_sc[lo:hi, :] += _fold2(jax.lax.dot_general(
-                    dsd.astype(qp.dtype), qp, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32), block_k, sub_d)
-                dq_sc[:] += jax.lax.dot_general(
+            def _update(r0, r1, L, keep):
+                q, g = qp[r0:r1], do[r0:r1]
+                kd = jnp.concatenate([ka[:L], kb[:L]], 0)  # [2*L, 128]
+                vd = jnp.concatenate([va[:L], vb[:L]], 0)
+                s0, s1 = _scores2(q, kd, scale=scale, keep=keep)
+                p0 = jnp.exp(s0 - lse0[r0:r1])
+                p1 = jnp.exp(s1 - lse1[r0:r1])
+                dp = jax.lax.dot_general(
+                    g, vd, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)    # [n, 2*L]
+                ds0 = p0 * (dp[:, :L] - delta0[r0:r1]) * scale
+                ds1 = p1 * (dp[:, L:] - delta1[r0:r1]) * scale
+                pd = jnp.concatenate([p0, p1], 1)
+                dsd = jnp.concatenate([ds0, ds1], 1)
+                dv_sc[lo:lo + L, :] += _fold2(jax.lax.dot_general(
+                    pd.astype(g.dtype), g, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32), L, sub_d)
+                dk_sc[lo:lo + L, :] += _fold2(jax.lax.dot_general(
+                    dsd.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32), L, sub_d)
+                dq_sc[r0:r1, :] += jax.lax.dot_general(
                     dsd.astype(kd.dtype), kd, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-        dq = dq_sc[:]
+            return _update
+
+        _causal_walk(i * block_q - lo,
+                     _offsets(num_q, block_q, [lo]),
+                     _begin, causal=causal, block_q=block_q,
+                     block_k=block_k)
+    dq = dq_sc[:]
     if has_rope:
         dq = _rot2_t(dq, cq_ref[...], sq_ref[...], sub_d)
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
@@ -917,11 +1075,15 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
     return dq, dk, dv
 
 
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "causal", "block_q", "block_k", "sub_d", "interpret"))
 def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
-               block_q: int, block_k: int, rope=None, sub_d: int = 64):
+               block_q: int, block_k: int, interpret: bool, rope=None,
+               sub_d: int = 64):
     """Packed backward dispatcher (strip-mined fused path only — the
     `flash_attention` gate keeps pack2 off for kv sequences whose
-    [Sk, 128] f32 dk/dv scratch would not fit VMEM)."""
+    [Sk, 128] f32 dk/dv scratch would not fit VMEM); an inlined ``jit``
+    as :func:`_fwd_pack2` is."""
     B, Hp, S, Dp = q.shape
     Sk = k.shape[2]
     bq, bk = min(block_q, S), min(block_k, Sk)
@@ -962,7 +1124,8 @@ def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
                           sub_d=sub_d),
         grid=(B, Hp, num_q),
         compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_PACK2_VMEM_LIMIT_BYTES),
         in_specs=[qs, ks, ks, qs, rs, rs, rs, rs, *rope_specs],
         out_specs=[qs, ks, ks],
         out_shape=[jax.ShapeDtypeStruct((B, Hp, S, Dp), q.dtype),
@@ -974,7 +1137,7 @@ def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
              pltpu.VMEM((Sk, Dp), jnp.float32)]
             + ([pltpu.VMEM((Sk, Dp), q.dtype)]
                if rope is not None else [])),
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse0, lse1, delta0, delta1, *rope_args)
     return dq, dk, dv
 
@@ -1040,14 +1203,16 @@ _flash_bhsd_rope.defvjp(_flash_bhsd_rope_fwd, _flash_bhsd_rope_bwd)
 def _flash_pack2(q, k, v, scale, causal, block_q, block_k,
                  bwd_block_q, bwd_block_k):
     o, _, _ = _fwd_pack2(q, k, v, scale=scale, causal=causal,
-                         block_q=block_q, block_k=block_k)
+                         block_q=block_q, block_k=block_k,
+                         interpret=_use_interpret())
     return o
 
 
 def _flash_pack2_fwd(q, k, v, scale, causal, block_q, block_k,
                      bwd_block_q, bwd_block_k):
     o, lse0, lse1 = _fwd_pack2(q, k, v, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               interpret=_use_interpret())
     return o, (q, k, v, o, lse0, lse1)
 
 
@@ -1056,7 +1221,8 @@ def _flash_pack2_bwd(scale, causal, block_q, block_k, bwd_block_q,
     q, k, v, o, lse0, lse1 = res
     dq, dk, dv = _bwd_pack2(q, k, v, o, lse0, lse1, do, scale=scale,
                             causal=causal, block_q=bwd_block_q,
-                            block_k=bwd_block_k)
+                            block_k=bwd_block_k,
+                            interpret=_use_interpret())
     return dq, dk, dv
 
 
@@ -1068,7 +1234,7 @@ def _flash_pack2_rope(q, k, v, cos2, sinm, scale, causal, block_q,
                       block_k, bwd_block_q, bwd_block_k):
     o, _, _ = _fwd_pack2(q, k, v, scale=scale, causal=causal,
                          block_q=block_q, block_k=block_k,
-                         rope=(cos2, sinm))
+                         interpret=_use_interpret(), rope=(cos2, sinm))
     return o
 
 
@@ -1076,6 +1242,7 @@ def _flash_pack2_rope_fwd(q, k, v, cos2, sinm, scale, causal, block_q,
                           block_k, bwd_block_q, bwd_block_k):
     o, lse0, lse1 = _fwd_pack2(q, k, v, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
+                               interpret=_use_interpret(),
                                rope=(cos2, sinm))
     return o, (q, k, v, cos2, sinm, o, lse0, lse1)
 
@@ -1085,7 +1252,9 @@ def _flash_pack2_rope_bwd(scale, causal, block_q, block_k, bwd_block_q,
     q, k, v, cos2, sinm, o, lse0, lse1 = res
     dq, dk, dv = _bwd_pack2(q, k, v, o, lse0, lse1, do, scale=scale,
                             causal=causal, block_q=bwd_block_q,
-                            block_k=bwd_block_k, rope=(cos2, sinm))
+                            block_k=bwd_block_k,
+                            interpret=_use_interpret(),
+                            rope=(cos2, sinm))
     return dq, dk, dv, None, None
 
 
@@ -1142,6 +1311,15 @@ def supports(S: int, Sk: int, D: int, *, block_q: int = 1024,
             and bq % 8 == 0 and bk % 128 == 0)
 
 
+def _tiling_block(block: int, n: int) -> int:
+    """``block``, halved while it does not tile ``n`` rows (1024 -> 512
+    at 1536), never under the 128 a block needs."""
+    block = min(block, n)
+    while block > 128 and n % block:
+        block //= 2
+    return block
+
+
 def _pack2_plan(S, Sk, H, D, causal, block_q, block_k, bwd_block_q,
                 bwd_block_k, pack2):
     """(pbq, pbk, pbwq, pbwk) if the packed schedule applies, else None.
@@ -1155,13 +1333,12 @@ def _pack2_plan(S, Sk, H, D, causal, block_q, block_k, bwd_block_q,
     if not (pack2 and D == 64 and H % 2 == 0 and H > 0):
         return None
     Dp = 2 * D
-    pbq = min(block_q, cfg.pack2_block_q)
-    pbk = min(block_k, cfg.pack2_block_k)
-    pbwq = bwd_block_q if bwd_block_q is not None else \
-        (cfg.bwd_block_q if causal else pbq)
-    pbwk = bwd_block_k if bwd_block_k is not None else \
-        (cfg.bwd_block_k if causal else pbk)
-    pbwq, pbwk = min(pbq, pbwq), min(pbk, pbwk)
+    pbq = _tiling_block(min(block_q, cfg.pack2_block_q), S)
+    pbk = _tiling_block(min(block_k, cfg.pack2_block_k), Sk)
+    # the backward walks the same schedule, so it takes the same blocks
+    # unless the call pins its own
+    pbwq = pbq if bwd_block_q is None else min(pbq, bwd_block_q)
+    pbwk = pbk if bwd_block_k is None else min(pbk, bwd_block_k)
     # packed backward only has the strip-mined fused path: dk/dv ride
     # in [Sk, 128] f32 VMEM scratch
     ok = (supports(S, Sk, Dp, block_q=pbq, block_k=pbk)
@@ -1179,6 +1356,51 @@ def uses_pack2(S: int, Sk: int, H: int, D: int, *, causal: bool = True,
                        None, pack2) is not None
 
 
+def causal_coverage(S: int, Sk: int, block_q: int, block_k: int,
+                    sub: Optional[int]) -> float:
+    """The share of the ``S x Sk`` score square a causal schedule of
+    ``block_q x block_k`` blocks executes when its diagonal blocks are
+    walked in ``sub``-edged sub-tiles (None: masked whole) — counted by
+    the predicates and the walk the packed kernels unroll with.  Causal
+    attention needs ``1/2 + 1/(2*S)`` of a square."""
+    bq, bk = min(block_q, S), min(block_k, Sk)
+    done = 0
+    for i in range(S // bq):
+        for j in range(Sk // bk):
+            d = i * bq - j * bk
+            if _is_interior(d, bk):
+                done += bq * bk
+            elif _is_diagonal(d, bq, bk):
+                done += sum((r1 - r0) * L
+                            for r0, r1, L, _ in _diag_rows(bq, bk, sub, d))
+    return done / (S * Sk)
+
+
+def train_causal_coverage(S: int, H: int, D: int, *, block_q: int = 1024,
+                          block_k: int = 1024,
+                          pack2: Optional[bool] = None) -> float:
+    """:func:`causal_coverage` of the schedule :func:`flash_attention`
+    takes for a causal train step at this shape, the mean over its seven
+    score-sized matmuls (two forward, five backward, each under its own
+    blocks).  The single-head schedule masks its diagonal blocks whole;
+    a shape no grid tiles runs the einsum, the whole square."""
+    plan = _pack2_plan(S, S, H, D, True, block_q, block_k, None, None,
+                       pack2)
+    if plan is not None:
+        fwd, bwd = plan[:2], plan[2:]
+        subs = _causal_sub(*fwd), _causal_sub(*bwd)
+    else:
+        cfg = attention_config()
+        fwd = block_q, block_k
+        bwd = min(block_q, cfg.bwd_block_q), min(block_k, cfg.bwd_block_k)
+        if not (supports(S, S, D, block_q=fwd[0], block_k=fwd[1])
+                and supports(S, S, D, block_q=bwd[0], block_k=bwd[1])):
+            return 1.0
+        subs = None, None
+    return (2 * causal_coverage(S, S, *fwd, subs[0])
+            + 5 * causal_coverage(S, S, *bwd, subs[1])) / 7
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, block_q: int = 1024,
                     block_k: int = 1024,
@@ -1194,11 +1416,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     falls back to the einsum path for shapes the grid cannot tile.
 
     ``block_q``/``block_k`` tile the forward grid; ``bwd_block_q``/
-    ``bwd_block_k`` (default: profiled per-shape choice) tile the
-    strip-mined backward independently — the fwd likes one big block
-    (per-grid-step overhead dominates any causal-skip win there) while
-    the bwd walks kv strips inside the kernel and genuinely skips the
-    causally-dead ones.
+    ``bwd_block_k`` tile the strip-mined backward independently.  The
+    packed schedule carries the causal structure itself (interior
+    blocks unmasked, diagonal blocks walked by sub-tile), so a big
+    block costs it no coverage and it takes 1024 forward and backward
+    (fewer grid steps, K/V fetched and rotated once: measured on a
+    v5e, PR 54).  The single-head schedule skips whole blocks only:
+    its forward takes one big block and masks it (per-grid-step
+    overhead outweighs the causal skip there, and so did the walk's
+    smaller matmuls when it was tried, PR 54), its backward walks kv
+    strips of 512 inside the kernel and skips the causally-dead ones.
 
     ``positions`` [S] enables fused RoPE: q/k are rotated inside the
     kernels (zero extra HBM passes) when the kv sequence fits one
@@ -1539,8 +1766,19 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
                            pack2=pack2)
     if rope_theta is not None:
         fn = functools.partial(fn, rope_theta=rope_theta)
-    if mesh is None or getattr(mesh, "size", 1) <= 1:
+    one_device = mesh is None or getattr(mesh, "size", 1) <= 1
+    tp_size = 1 if one_device else mesh.shape.get("tp", 1)
+
+    def causal_coverage(S: int, H: int, D: int) -> float:
+        """The share of the score square the schedule this fn takes for
+        ``H`` global heads executes (:func:`train_causal_coverage`)."""
+        return train_causal_coverage(
+            S, H // tp_size, D, block_q=block_q, block_k=block_k,
+            pack2=pack2) if causal else 1.0
+
+    if one_device:
         fn.fused_rope = rope_theta is not None
+        fn.causal_coverage = causal_coverage
         return fn
 
     from jax.sharding import PartitionSpec as P
@@ -1585,6 +1823,7 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
             return sharded(q, k, v, positions)
 
         wrapped.fused_rope = True
+        wrapped.causal_coverage = causal_coverage
         return wrapped
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec,) * 3,
@@ -1598,6 +1837,7 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
         return sharded(q, k, v)
 
     sharded_fn.fused_rope = False
+    sharded_fn.causal_coverage = causal_coverage
     return sharded_fn
 
 

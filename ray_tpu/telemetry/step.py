@@ -110,6 +110,7 @@ class StepTelemetry:
                  comm_mode: Optional[str] = None,
                  comm_quant: Optional[str] = None,
                  ce_mode: Optional[str] = None,
+                 attn_fn=None,
                  label: str = "train",
                  aot: bool = False,
                  chip_peak_tflops: Optional[float] = None,
@@ -121,6 +122,7 @@ class StepTelemetry:
         self.comm_mode = comm_mode
         self.comm_quant = comm_quant
         self.ce_mode = ce_mode
+        self.attn_fn = attn_fn
         self.label = label
         self.records: List[Dict[str, Any]] = []
         self.step_count = 0      # total steps seen (survives trimming)
@@ -138,6 +140,7 @@ class StepTelemetry:
         self._peak = chip_peak_tflops
         self._fpt: Optional[float] = None   # cached; -1 = unavailable
         self._ce_path: Optional[str] = None  # cached once the shape is known
+        self._coverage: Optional[float] = None   # likewise
         self._metrics = None          # lazily-created metric objects
         self._metrics_dead = False    # no cluster / emission failed
         self._metrics_last = 0.0      # last emission (monotonic)
@@ -215,6 +218,8 @@ class StepTelemetry:
             rec["loss"] = loss
         if i == 0 and self.ce_path() is not None:
             rec["ce_path"] = self.ce_path()   # fixed for the run
+        if i == 0 and self.causal_coverage() is not None:
+            rec["causal_coverage"] = self.causal_coverage()
         self.records.append(rec)
         if len(self.records) > self._MAX_RECORDS:
             # bounded like the control plane's task-event buffer: a
@@ -303,6 +308,22 @@ class StepTelemetry:
                 mode=self.ce_mode)
         return self._ce_path
 
+    def causal_coverage(self) -> Optional[float]:
+        """The share of the causal score square the step's attention
+        schedule executes, as the attention fn the step was built with
+        counts it (``ops.attention.make_flash_attention_fn``: 0.75 where
+        whole blocks of 512 are masked at 1024 tokens, 0.5005 needed).
+        ``None`` until a batch has shown its shape, and for a step whose
+        attention says nothing of the kind (ring, ulysses, the einsum)."""
+        count = getattr(self.attn_fn, "causal_coverage", None)
+        if (count is None or self._seq is None
+                or not hasattr(self.cfg, "n_heads")):
+            return None
+        if self._coverage is None:
+            self._coverage = count(self._seq, self.cfg.n_heads,
+                                   self.cfg.head_dim)
+        return self._coverage
+
     def flops_per_token(self) -> Optional[float]:
         if self.cfg is None or self._seq is None:
             return None
@@ -366,6 +387,9 @@ class StepTelemetry:
                 path = self.ce_path()
                 if path is not None:
                     out["ce_path"] = path
+                coverage = self.causal_coverage()
+                if coverage is not None:
+                    out["causal_coverage"] = coverage
                 if fpt is not None and peak is not None:
                     out["chip_peak_tflops"] = peak
                     out["mfu"] = flops_mod.mfu(
@@ -539,6 +563,7 @@ def instrument(fns: Dict[str, Any], cfg=None, mesh=None, *,
     disabled."""
     rec = StepTelemetry(cfg, mesh, comm_mode=comm_mode,
                         comm_quant=comm_quant, ce_mode=ce_mode,
+                        attn_fn=fns.get("attn_fn"),
                         label=label, aot=aot, config=config)
     if not rec.enabled:
         return fns

@@ -121,15 +121,16 @@ def test_flash_rope_multiblock_falls_back_to_external():
 # the driver's entry check re-run them before any on-chip measurement).
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("S,block", [(256, 128), (768, 384)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_pack2_fwd_matches_einsum(causal):
+def test_pack2_fwd_matches_einsum(causal, S, block):
     key = jax.random.PRNGKey(20)
-    B, S, H, D = 2, 256, 4, 64
+    B, H, D = 2, 4, 64
     q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
                for kk in jax.random.split(key, 3))
     ref = local_attention(q, k, v, causal=causal)
-    out = A.flash_attention(q, k, v, causal=causal, block_q=128,
-                            block_k=128, pack2=True)
+    out = A.flash_attention(q, k, v, causal=causal, block_q=block,
+                            block_k=block, pack2=True)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
@@ -148,19 +149,21 @@ def test_pack2_fwd_bf16():
     assert err < 3e-2   # bf16 has ~3 significant decimal digits
 
 
+@pytest.mark.parametrize("S,block", [(256, 128), (768, 384)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_pack2_grads_match_einsum_multistrip(causal):
+def test_pack2_grads_match_einsum_multistrip(causal, S, block):
     # bwd_block_k < S: the packed fused backward walks 2 kv strips and
-    # (causal) skips the dead one for the first q block
+    # (causal) skips the dead one for the first q block; at 384 the
+    # diagonal strips are walked in 128-row sub-blocks
     key = jax.random.PRNGKey(22)
-    B, S, H, D = 2, 256, 4, 64
+    B, H, D = 2, 4, 64
     q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
                for kk in jax.random.split(key, 3))
 
     def loss_pack(q, k, v):
-        return (A.flash_attention(q, k, v, causal=causal, block_q=128,
-                                  block_k=128, bwd_block_q=128,
-                                  bwd_block_k=128, pack2=True) ** 2).sum()
+        return (A.flash_attention(q, k, v, causal=causal, block_q=block,
+                                  block_k=block, bwd_block_q=block,
+                                  bwd_block_k=block, pack2=True) ** 2).sum()
 
     def loss_ref(q, k, v):
         return (local_attention(q, k, v, causal=causal) ** 2).sum()
@@ -268,6 +271,97 @@ def test_pack2_seq_not_divisible_falls_back():
                             pack2=True)
     ref = local_attention(q, k, v, causal=True)
     assert float(jnp.abs(out - ref).max()) < 1e-5
+
+
+# The causal structure as a schedule (PR 54).  ``S, (block_q, block_k,
+# bwd_block_q, bwd_block_k)``, chosen so that every kind of tile occurs
+# under the packed kernels' walk: an interior block (no mask), a
+# diagonal block whose sub-tiles are skipped, unmasked and masked, block
+# pairs at offsets other than 0, and a block no sub-tile divides.
+_SCHEDULES = {
+    "blocks128": (256, (128, 128, 128, 128)),   # one masked tile a block
+    "walk3": (768, (384, 384, 384, 384)),       # 3 x 3 sub-tiles of 128
+    "cell": (1024, (512, 512, 512, 512)),       # the train cells' own
+    "wide_kv": (512, (128, 256, 128, 256)),     # bq < bk: offsets 0, 128
+    "tall_q": (512, (256, 128, 256, 128)),      # bq > bk: offsets -128, 0
+    "fwd_bwd_differ": (512, (256, 128, 128, 256)),
+    "nosub": (256, (64, 128, 64, 128)),         # 64 rows: masked whole
+}
+
+
+def _rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+
+@pytest.mark.parametrize("rope", [False, True], ids=["norope", "rope"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("sched", sorted(_SCHEDULES))
+def test_pack2_causal_schedule_matches_einsum(sched, dtype, tol, rope):
+    # forward and all three gradients of the walked schedule against
+    # the einsum in f32 (an f32 run is exact to rounding: a tile skipped
+    # or masked wrongly is an error of order one)
+    S, (bq, bk, wq, wk) = _SCHEDULES[sched]
+    B, H, D = 1, 2, 64
+    q, k, v, w = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
+                  .astype(dtype)
+                  for kk in jax.random.split(jax.random.PRNGKey(30), 4))
+    pos = jnp.arange(S) if rope else None
+    assert A.uses_pack2(S, S, H, D, block_q=bq, block_k=bk, pack2=True)
+
+    def packed(q, k, v):
+        return A.flash_attention(q, k, v, block_q=bq, block_k=bk,
+                                 bwd_block_q=wq, bwd_block_k=wk,
+                                 positions=pos, pack2=True)
+
+    def ref(q, k, v):
+        if rope:
+            q, k = (A.rope_rotate(x, pos, 10000.0) for x in (q, k))
+        return local_attention(q, k, v, causal=True)
+
+    o, pull = jax.vjp(packed, q, k, v)
+    o_ref, pull_ref = jax.vjp(ref, *(x.astype(jnp.float32)
+                                     for x in (q, k, v)))
+    for name, a, b in zip(("o", "dq", "dk", "dv"), (o, *pull(w)),
+                          (o_ref, *pull_ref(w.astype(jnp.float32)))):
+        assert _rel(a, b) < tol, (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("sched", sorted(_SCHEDULES))
+def test_causal_coverage_counts_the_tiles_the_walk_runs(sched):
+    # the counter is the kernels' own predicates and walk: under every
+    # sub-tile that divides the blocks (None: whole blocks) it must
+    # equal the sub-tiles that hold a live (query, key) pair — none
+    # skipped that the mask left alive, none run that it killed whole
+    import numpy as np
+    S, (bq, bk, _, _) = _SCHEDULES[sched]
+    causal = np.tril(np.ones((S, S), bool))
+    for sub in (None, 128, 256, 512):
+        if sub is not None and (bq % sub or bk % sub):
+            continue
+        tq, tk = (bq, bk) if sub is None else (sub, sub)
+        live = causal.reshape(S // tq, tq, S // tk, tk).any(axis=(1, 3))
+        assert A.causal_coverage(S, S, bq, bk, sub) == \
+            live.sum() * tq * tk / (S * S), sub
+    # and the rule the kernels take their sub-tile by
+    want = next((t for t in (256, 128) if bq % t == 0 and bk % t == 0),
+                None)
+    assert A._causal_sub(bq, bk) == want
+
+
+def test_causal_coverage_at_the_train_cells_shape():
+    # GPT-2 124M at 1024: blocks 512 x 512 forward and backward; the
+    # parent's schedule (whole blocks masked) executed 0.75
+    assert A.causal_coverage(1024, 1024, 512, 512, None) == 0.75
+    got = A.train_causal_coverage(1024, 12, 64)
+    assert got == A.causal_coverage(1024, 1024, 512, 512,
+                                    A._causal_sub(512, 512)) < 0.65
+    # the single-head schedule masks whole blocks (forward one block of
+    # 1024, backward 512s); a shape no grid tiles runs the whole square
+    assert A.train_causal_coverage(1024, 12, 128) == (2 + 5 * 0.75) / 7
+    assert A.train_causal_coverage(1000, 12, 64) == 1.0
 
 
 def test_attention_config_env_escape_hatch(monkeypatch):

@@ -161,6 +161,39 @@ def test_step_record_names_the_loss_head_and_prices_it(
     assert StepTelemetry(cfg, mesh, ce_mode=ce_mode).ce_path() is None
 
 
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_step_record_carries_the_attention_schedules_coverage(n_dev):
+    """``causal_coverage`` (the run's first record and the summary carry
+    it beside ``ce_path``) is what the attention fn the step was built
+    with counts for its schedule, on one device and under ``shard_map``;
+    a step whose attention says nothing of the kind reports none."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.ops import attention as A
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.telemetry import StepTelemetry
+
+    cfg = GPTConfig(vocab_size=512, d_model=128, n_layers=2, n_heads=2,
+                    max_seq=1024, dtype=jnp.float32)
+    mesh = make_mesh(dp=n_dev, devices=jax.devices()[:n_dev])
+    attn_fn = A.make_flash_attention_fn(mesh, rope_theta=cfg.rope_theta)
+    batch = {"tokens": jnp.zeros((n_dev, 1024), jnp.int32)}
+    for fn, want in ((attn_fn, A.train_causal_coverage(1024, 2, 64)),
+                     (None, None)):
+        tel = StepTelemetry(cfg, mesh, attn_fn=fn, chip_peak_tflops=_PEAK)
+        assert tel.causal_coverage() is None    # no batch seen yet
+        step = tel.wrap(lambda state, batch: (state, {"loss": 0.0}))
+        for _ in range(2):
+            step(None, batch)
+        assert [r.get("causal_coverage") for r in tel.records] == \
+            [want, None]
+        assert tel.summary().get("causal_coverage") == want
+    # the train cells' schedule: under 0.65 where the parent's ran 0.75
+    assert A.train_causal_coverage(1024, 2, 64) < 0.65
+
+
 def test_chrome_trace_export_valid(aot_run):
     """The exporter emits Perfetto-loadable JSON: a ``traceEvents``
     list of complete events carrying both host spans and step

@@ -56,15 +56,29 @@ def _compile_for_v5e(fn, sharding, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("pack2", [True, False])
-def test_flash_attention_fwd_bwd_compiles_for_v5e(v5e, pack2):
+@pytest.mark.parametrize("batch, seq, pack2", [
+    (B, S, True), (B, S, False),
+    # the train cells' exact call: the fn build_gpt_train makes, fused
+    # RoPE, default blocks, nothing pinned (the walked pack2 schedule,
+    # forward and backward, VMEM included)
+    (B, S, None),
+    # two blocks of 1024 a side: an interior block, walked unmasked
+    (B // 2, 2 * S, None),
+], ids=["pack2", "single_head", "train_cell", "seq2048"])
+def test_flash_attention_fwd_bwd_compiles_for_v5e(v5e, batch, seq, pack2):
+    attn = attention.make_flash_attention_fn(rope_theta=10000.0,
+                                             pack2=pack2)
+
     def step(q, k, v):
         return jax.value_and_grad(
-            lambda q, k, v: attention.flash_attention(
-                q, k, v, positions=jnp.arange(S), pack2=pack2)
+            lambda q, k, v: attn(q, k, v, positions=jnp.arange(seq))
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    _compile_for_v5e(step, v5e, *[((B, S, H, D), BF16)] * 3)
+    hlo = _compile_for_v5e(step, v5e,
+                           *[((batch, seq, H, D), BF16)] * 3).as_text()
+    if pack2 is not False:
+        assert attention.uses_pack2(seq, seq, H, D, pack2=pack2)
+        assert "attn/pack2" in hlo and "attn/flash" not in hlo
 
 
 def test_flash_ce_with_norm_fwd_bwd_compiles_for_v5e(v5e):
