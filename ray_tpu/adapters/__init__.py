@@ -13,7 +13,7 @@ multi-tenant seam across the stack:
   constants.
 - :mod:`~ray_tpu.adapters.store` — :class:`AdapterStore`, the
   fleet-shared content-addressed publication point (object-store
-  backed like ``WeightStore``/``KVPageStore``), keyed
+  backed like ``WeightStore``), keyed
   ``(model_id, version)`` with a monotonic per-model latest pointer.
 - :mod:`~ray_tpu.adapters.registry` — :class:`AdapterRegistry`, the
   per-engine resident-adapter bookkeeping: which ``(model_id,

@@ -187,8 +187,8 @@ def salt_bytes(model_id: Optional[str], version: int) -> bytes:
     """Prefix-cache chain-root salt for an (adapter, version) pair.
 
     Adapter K/V differs from base K/V for identical token prefixes, so
-    salted chains keep the r16 prefix index and the r23 tiered store
-    from ever aliasing tenants; a version republish changes the salt,
+    salted chains keep the r16 prefix index from ever aliasing
+    tenants; a version republish changes the salt,
     so stale entries simply miss and age out of the LRU — no flush."""
     if not model_id:
         return b""

@@ -11,7 +11,7 @@ a handoff for an adapter it has never seen pulls the exact pinned
 version here — never recompiles, because the bank is a call arg).
 
 Leak-audit contract: ``in_flight`` counts checked-out fetches and must
-be 0 after a fleet drain, exactly like ``KVPageStore.in_flight``.
+be 0 after a fleet drain.
 """
 
 from __future__ import annotations

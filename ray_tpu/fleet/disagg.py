@@ -916,10 +916,4 @@ class DisaggRouter:
             "in_flight": len(self._by_rid),
             "handoffs_in_store": self._store.in_flight,
             "affinity": self.affinity,
-            # r23: the fleet-shared KV page store, when any replica
-            # tiers into one (replicas share the instance, so the
-            # first is everyone's view)
-            "kv_store": next(
-                (r.engine.store.stats() for r in self.replicas()
-                 if r.engine.store is not None), None),
         }

@@ -101,11 +101,6 @@ class Reconciler:
         self._spawned = 0
         self.restarts_total = 0
         self.demotion_restarts = 0   # gray-failure drain-restarts
-        # r23: spawns whose engine came up attached to a non-empty
-        # fleet-shared KV page store — a restart or scale-from-zero
-        # replica that warms up from other replicas' prefix pages on
-        # its first admissions instead of re-prefilling everything
-        self.warm_starts = 0
         self._breach_since: Optional[float] = None
         self._idle_since: Optional[float] = None
         self._last_scale_ts = now
@@ -131,9 +126,6 @@ class Reconciler:
         rid = self._new_id()
         replica = self.factory(rid)
         self.router.add_replica(replica)
-        store = getattr(getattr(replica, "engine", None), "store", None)
-        if store is not None and len(store):
-            self.warm_starts += 1
         inst = Instance(replica=replica, state=state, since=now,
                         restarts=restarts)
         self.instances[rid] = inst

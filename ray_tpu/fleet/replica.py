@@ -262,39 +262,13 @@ class EngineReplica:
     def leak_free(self) -> bool:
         """Fleet-wide leak audit: every slot free, every page either
         free or parked idle in the prefix pool, nothing in flight —
-        and (r23) the engine's tier inventory partitions exactly, with
-        no store fetch left checked out."""
+        and the engine's own page and adapter inventory audits clean."""
         sched = self.engine.scheduler
         return (not sched.active and not sched.waiting
                 and len(sched.free_slots) == self.engine.slots
                 and sched.allocator.free_count
                 == sched.allocator.num_pages - 1
                 and self.engine.leak_free())
-
-    def tier_hits(self, chain_hashes) -> "tuple[int, int]":
-        """How far this replica's warm tiers cover a prompt's chained
-        page hashes: ``(n_hbm, n_dram)`` — consecutive leading pages
-        resident in HBM, then consecutive pages sitting in the host-
-        DRAM pool.  The router's tier-aware cost signal: an HBM hit is
-        a refcount bump, a DRAM hit pays a host->device copy, and the
-        store is deliberately absent — any replica can fetch a store
-        page at the same price, so store coverage never differentiates
-        candidates."""
-        digest = self.prefix_digest()
-        n_hbm = 0
-        for h in chain_hashes:
-            if h not in digest:
-                break
-            n_hbm += 1
-        n_dram = 0
-        pool = self.engine.host_pool
-        if pool is not None:
-            ver = self.engine.param_version
-            for h in chain_hashes[n_hbm:]:
-                if (h, ver) not in pool:
-                    break
-                n_dram += 1
-        return n_hbm, n_dram
 
     def stats(self) -> Dict[str, Any]:
         out = self.engine.stats()

@@ -382,16 +382,6 @@ class FleetRouter:
             None)
         return store.salt_for(model_id) if store is not None else b""
 
-    # Tier-aware affinity weights (r23): an HBM-resident page is a
-    # pure refcount bump; a host-DRAM page pays one host->device page
-    # copy, so it is worth most-but-not-all of an HBM hit — a replica
-    # holding the whole prefix spilled still beats one holding a short
-    # resident stub.  The store tier is deliberately weightless: any
-    # replica fetches a store page at the same price, so store
-    # coverage cannot differentiate candidates (those requests fall
-    # through to the pow-2 load pick and warm whichever replica wins).
-    TIER_WEIGHT_HBM = 1.0
-    TIER_WEIGHT_DRAM = 0.8
     # Adapter residency (r25): a resident tenant skips the store
     # fetch + bank install a cold replica would pay — worth a couple
     # of page hits, but a long prefix hit should still dominate (the
@@ -402,10 +392,10 @@ class FleetRouter:
     def _affinity_pick(self, prompt, cands,
                        model_id: Optional[str] = None
                        ) -> Optional[EngineReplica]:
-        """The tier-aware cost model over the r16 prefix-affinity
-        pick: candidates score by how much re-prefill their warm tiers
-        save (HBM hit > DRAM hit > nothing; ties break toward the
-        shallower queue), and the winner still yields to pow-2 when
+        """The r16 prefix-affinity pick: candidates score by how many
+        leading pages of the prompt their prefix cache holds (the
+        prefill a hit saves; ties break toward the shallower queue),
+        and the winner still yields to pow-2 when
         its queue is past the affinity cap — a hot cache must not
         become a hot spot.  Multi-tenant requests (r25) compose an
         adapter-residency bonus into the same score — their prefix
@@ -421,9 +411,12 @@ class FleetRouter:
             return None
         best, best_score = None, 0.0
         for r in cands:
-            n_hbm, n_dram = r.tier_hits(hashes) if hashes else (0, 0)
-            score = (n_hbm * self.TIER_WEIGHT_HBM
-                     + n_dram * self.TIER_WEIGHT_DRAM)
+            digest = r.prefix_digest() if hashes else ()
+            score = 0.0
+            for h in hashes:
+                if h not in digest:
+                    break
+                score += 1.0
             if score_adapters and model_id in r.adapter_digest():
                 score += self.ADAPTER_WEIGHT
             if score > best_score or (
@@ -939,15 +932,8 @@ class FleetRouter:
             "in_flight": len(self._by_rid),
             "affinity": self.affinity,
             "hedge_deadline_s": self.hedge_deadline_s(),
-            # r23: the fleet-shared KV page store, when any replica
-            # tiers into one (replicas share the instance, so the
-            # first is everyone's view)
-            "kv_store": next(
-                (r.engine.store.stats()
-                 for r in self._replicas.values()
-                 if r.engine.store is not None), None),
-            # r25: the fleet-shared adapter store (same one-instance
-            # convention as kv_store)
+            # r25: the fleet-shared adapter store (replicas share the
+            # instance, so the first is everyone's view)
             "adapter_store": next(
                 (getattr(r.engine, "adapter_store", None).stats()
                  for r in self._replicas.values()

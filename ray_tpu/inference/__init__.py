@@ -16,20 +16,17 @@ from ray_tpu.inference.config import (InferConfig,  # noqa: F401
 from ray_tpu.inference.engine import (InferenceEngine,  # noqa: F401
                                       StepEvent)
 from ray_tpu.inference.kv_cache import (HandoffContentMissing,  # noqa: F401
-                                        HostPagePool, KVCache,
-                                        KVHandoff, KVPageStore,
+                                        KVCache, KVHandoff,
                                         PageAllocator, PrefixIndex)
 from ray_tpu.inference.sampling import SamplingParams  # noqa: F401
 from ray_tpu.inference.scheduler import (DeadlineExceededError,  # noqa: F401
                                          QueueFullError,
                                          Request, SlotScheduler)
-from ray_tpu.inference.spec import DraftState  # noqa: F401
 
 __all__ = [
     "InferConfig", "infer_config", "default_buckets",
     "InferenceEngine", "StepEvent", "KVCache", "PageAllocator",
     "PrefixIndex", "KVHandoff", "HandoffContentMissing",
-    "HostPagePool", "KVPageStore",
     "SamplingParams", "QueueFullError", "DeadlineExceededError",
-    "Request", "SlotScheduler", "DraftState",
+    "Request", "SlotScheduler",
 ]

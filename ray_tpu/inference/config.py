@@ -80,43 +80,6 @@ class InferConfig:
       tokens waiting but has not been pumped for the budget —
       releasing its slot/pages/prefix refcounts instead of decoding
       to ``max_new_tokens`` for a reader that is gone.
-    - ``RAY_TPU_INFER_SPEC`` (default ``0`` = off): speculative
-      decoding default — the zero-parameter self-drafter proposes up
-      to ``spec_k`` continuation tokens per slot from the request's
-      own context and one batched verify forward (the cached-context
-      prefill executable, per k-bucket) scores them all; exact
-      acceptance sampling keeps outputs distribution-identical to
-      plain decode (greedy bit-exact, sampled trajectory-exact).
-      Per-request ``SamplingParams.spec`` overrides win.
-    - ``RAY_TPU_INFER_SPEC_K`` (default ``4``): default draft length
-      cap per verify step when speculation is on.  Per-request
-      ``SamplingParams.spec_k`` overrides win.
-    - ``RAY_TPU_KV_HOST_PAGES`` (default ``0`` = tiering off): capacity
-      in pages of the per-engine host-DRAM spill pool (tier 1).  With
-      it set, LRU evictions from HBM *demote* a prefix page's contents
-      host-side instead of forgetting them, and admission's prefix
-      walk extends through the pool — a later request promotes the
-      page back into fresh HBM between ticks at zero prefill compute.
-    - ``RAY_TPU_KV_STORE`` (default ``1``): participate in the
-      fleet-shared content-addressed page store (tier 2) when tiering
-      is on — host-pool overflow demotes on to the store, and
-      admission's walk extends through it, so every replica (including
-      restarts and scale-from-zero spawns) warms up from pages any
-      other replica prefilled.  ``0`` caps the hierarchy at host DRAM.
-    - ``RAY_TPU_KV_STORE_CAP`` (default ``0`` = unbounded): byte cap on
-      the fleet-shared page store (tier 2).  Over-cap puts evict the
-      least-recently-checked-out entries (never one mid-checkout —
-      in-flight fetches pin their entry), counted in the store's
-      ``evictions`` stat and the ``infer_kv_store_evictions_total``
-      counter.  A re-admit whose store pages were evicted degrades to
-      suffix prefill — exact continuations, just cold.
-    - ``RAY_TPU_KV_SPILL_DTYPE`` (default ``int8``): spill/wire format
-      for demoted pages — ``int8`` (per-vector block-scaled codes,
-      ``head_dim + 4`` bytes per cached vector: ~2x cheaper DRAM/store
-      residency and fetch bytes, the r11/r22 trick applied to the spill
-      tier) or ``model`` (raw storage-dtype bytes, exact).  int8
-      caches always spill their codes + scales verbatim (already the
-      cheapest exact form).
     """
     slots: int = 8
     page_size: int = 128
@@ -129,12 +92,6 @@ class InferConfig:
     deadline: float = 0.0
     watchdog: float = 0.0
     stream_idle: float = 0.0
-    spec: bool = False
-    spec_k: int = 4
-    host_pages: int = 0
-    store: bool = True
-    store_cap: int = 0
-    spill_dtype: str = "int8"
 
 
 _CONFIG: Optional[InferConfig] = None
@@ -175,26 +132,6 @@ def infer_config(refresh: bool = False) -> InferConfig:
                                 "watchdog off")
         stream_idle = nonneg_float("RAY_TPU_INFER_STREAM_IDLE",
                                    "idle-stream reaper off")
-        spec_k = int(env("RAY_TPU_INFER_SPEC_K", "4"))
-        if spec_k < 1:
-            print(f"RAY_TPU_INFER_SPEC_K={spec_k} < 1; using 4",
-                  file=sys.stderr)
-            spec_k = 4
-        host_pages = int(env("RAY_TPU_KV_HOST_PAGES", "0"))
-        if host_pages < 0:
-            print(f"RAY_TPU_KV_HOST_PAGES={host_pages} negative; "
-                  "using 0 (tiering off)", file=sys.stderr)
-            host_pages = 0
-        store_cap = int(env("RAY_TPU_KV_STORE_CAP", "0"))
-        if store_cap < 0:
-            print(f"RAY_TPU_KV_STORE_CAP={store_cap} negative; "
-                  "using 0 (unbounded)", file=sys.stderr)
-            store_cap = 0
-        spill_dtype = env("RAY_TPU_KV_SPILL_DTYPE", "int8")
-        if spill_dtype not in ("int8", "model"):
-            print(f"RAY_TPU_KV_SPILL_DTYPE={spill_dtype!r} unknown; "
-                  "using 'int8'", file=sys.stderr)
-            spill_dtype = "int8"
         _CONFIG = InferConfig(
             slots=int(env("RAY_TPU_INFER_SLOTS", "8")),
             page_size=int(env("RAY_TPU_INFER_PAGE_SIZE", "128")),
@@ -207,12 +144,6 @@ def infer_config(refresh: bool = False) -> InferConfig:
             deadline=deadline,
             watchdog=watchdog,
             stream_idle=stream_idle,
-            spec=env("RAY_TPU_INFER_SPEC", "0") != "0",
-            spec_k=spec_k,
-            host_pages=host_pages,
-            store=env("RAY_TPU_KV_STORE", "1") != "0",
-            store_cap=store_cap,
-            spill_dtype=spill_dtype,
         )
     return _CONFIG
 

@@ -39,26 +39,6 @@ rest written host-side between ticks) and seeds the slot at the
 absolute context offset, so the ordinary fixed-slot decode step
 continues the sequence — neither seam adds an executable.
 
-**Speculative decoding (r21).**  With ``RAY_TPU_INFER_SPEC`` (or a
-per-request ``SamplingParams.spec``) on, each tick plans up to
-``spec_k`` self-drafted tokens per slot (``spec.DraftState`` — n-gram
-copy over the request's own context, zero parameters) and scores them
-all in ONE batched verify forward: the cached-context prefill
-executable run in all-rows mode over the suffix ``[last_token,
-d1..dk]`` at the slot's current length, compiled once per power-of-two
-k-bucket (``verify`` compile counters).  Each verify row is sampled
-under the SAME ``fold_in(seed, n_generated)`` key plain decode would
-use, so accepting a draft iff the sampled token equals it reproduces
-the plain trajectory exactly (greedy bit-exact, sampled
-trajectory-exact) — speculation is a pure throughput transform.  A
-rejected tail rolls back by simply not advancing the slot's length:
-the stale K/V beyond it is length-masked and overwritten by the next
-writes, and the write window is slot-private by r12's
-never-write-shared invariant (asserted before every dispatch).
-Speculating and plain slots co-batch in one tick: the plain decode
-step runs with speculating slots' page-table rows masked to the
-garbage page, then each speculating slot verifies.
-
 The steps themselves derive from the training model: ``embed`` +
 ``layer_apply`` with a KV-cache hook threaded through (post-RoPE keys
 written to the paged cache, decode attention over the live pages where
@@ -85,7 +65,7 @@ import contextlib
 import dataclasses
 import threading
 import time
-from typing import Any, Dict, List, Optional, Set, Tuple, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -98,12 +78,10 @@ from ray_tpu.adapters import (AdapterRegistry, AdapterStore,
 from ray_tpu.adapters import lora as lora_mod
 from ray_tpu.inference import kv_cache as kvc
 from ray_tpu.inference.config import default_buckets, infer_config
-from ray_tpu.inference.sampling import (SamplingParams, accept_drafts,
-                                        sample_path,
+from ray_tpu.inference.sampling import (SamplingParams, sample_path,
                                         sample_tokens_logprobs)
 from ray_tpu.inference.scheduler import (DeadlineExceededError,
                                          Request, SlotScheduler)
-from ray_tpu.inference.spec import DraftState
 from ray_tpu.models import gpt as gpt_mod
 from ray_tpu.ops.attention import (_NEG_INF, absorb_query, expand_output,
                                    latent_kv, latent_prefill_attention)
@@ -142,9 +120,8 @@ class StepEvent(tuple):
 class _Flight:
     """One dispatched step whose sampled tokens are still on the device.
 
-    The engine fetches and delivers these oldest first (``_land``): a
-    synchronous tick right after the dispatch, a tick that runs ahead
-    only after the *next* decode is dispatched, so the fetch, the
+    The engine fetches and delivers these oldest first (``_land``), a
+    decode only after the *next* decode is dispatched, so the fetch, the
     per-row delivery and the serve front's fan-out happen while the
     chip works.  ``rows`` pairs a row of the sampler's output with the
     request it was dispatched for: a request that ended meanwhile
@@ -247,12 +224,7 @@ class InferenceEngine:
     one row of its own still in flight: the row's cache write lands
     beyond the request's context, ordered before any later writer of
     the same page by the donated cache state every step threads, and
-    its sampled token is dropped.  The engine chooses from its own
-    state: a tick in which a slot may draft (``spec_k > 0``: drafting
-    reads the tokens on the host) first brings the host level with the
-    device and runs synchronously, as every tick did before.  No
-    token, logprob or logits row differs between the two; only the
-    tick an event is returned in.  ``has_work()`` stays true while a
+    its sampled token is dropped.  ``has_work()`` stays true while a
     token is in flight.
 
     Knobs default to :func:`ray_tpu.inference.config.infer_config`
@@ -278,11 +250,6 @@ class InferenceEngine:
                  max_queue: Optional[int] = None,
                  ttft_deadline: Optional[float] = None,
                  deadline: Optional[float] = None,
-                 spec: Optional[bool] = None,
-                 spec_k: Optional[int] = None,
-                 host_pages: Optional[int] = None,
-                 store: Union["kvc.KVPageStore", bool, None] = None,
-                 spill_dtype: Optional[str] = None,
                  telemetry: Optional[bool] = None,
                  debug_logits: bool = False,
                  executable_cache: Optional[Dict[Any, Any]] = None,
@@ -317,13 +284,6 @@ class InferenceEngine:
                               is None else float(ttft_deadline)) or None
         self.deadline = (icfg.deadline if deadline is None
                          else float(deadline)) or None
-        # speculative-decoding defaults; per-request SamplingParams
-        # overrides win (resolved once at submit onto Request.spec_k)
-        self.spec = icfg.spec if spec is None else bool(spec)
-        self.spec_k = icfg.spec_k if spec_k is None else int(spec_k)
-        if self.spec_k < 1:
-            raise ValueError(f"spec_k must be >= 1, got {self.spec_k} "
-                             "(check RAY_TPU_INFER_SPEC_K)")
         if self.kv_dtype not in ("model", "int8"):
             raise ValueError(f"unknown kv_dtype {self.kv_dtype!r} "
                              "(check RAY_TPU_KV_DTYPE)")
@@ -360,52 +320,6 @@ class InferenceEngine:
                 page_size=self.page_size, n_heads=cfg.n_heads,
                 head_dim=cfg.head_dim, dtype=cfg.dtype,
                 kv_dtype=self.kv_dtype)
-        # tiered KV cache (r23): HBM (tier 0, the refcounted pages
-        # above) -> per-engine host-DRAM spill pool (tier 1) ->
-        # fleet-shared content-addressed page store (tier 2).  ``store``
-        # takes a shared KVPageStore (the fleet wiring), True for a
-        # private one, None to follow config (a private store when
-        # tiering is on and RAY_TPU_KV_STORE allows).  Tiering needs
-        # the prefix index — demoted entries are keyed by its chain
-        # hashes (+ param version, the set_params invalidation).
-        self.host_pages = (icfg.host_pages if host_pages is None
-                           else int(host_pages))
-        self.spill_dtype = spill_dtype or icfg.spill_dtype
-        if self.spill_dtype not in kvc.SPILL_DTYPES:
-            raise ValueError(
-                f"unknown spill_dtype {self.spill_dtype!r} "
-                "(check RAY_TPU_KV_SPILL_DTYPE)")
-        if self.host_pages < 0:
-            raise ValueError(f"host_pages must be >= 0, got "
-                             f"{self.host_pages} "
-                             "(check RAY_TPU_KV_HOST_PAGES)")
-        if isinstance(store, kvc.KVPageStore):
-            self.store: Optional[kvc.KVPageStore] = store
-        elif store is True or (store is None and icfg.store
-                               and self.host_pages > 0):
-            self.store = kvc.KVPageStore()
-        else:
-            self.store = None
-        self.tiered = self.prefix and (self.host_pages > 0
-                                       or self.store is not None)
-        if self._latent and self.tiered:
-            kvc.refuse_latent("the spill tiers (host_pages / store)")
-        if self._latent and self.spec:
-            kvc.refuse_latent("speculative decoding (spec)")
-        if self.tiered:
-            self.host_pool: Optional[kvc.HostPagePool] = \
-                kvc.HostPagePool(self.host_pages, store=self.store)
-            self.scheduler.allocator.spill_hook = self._spill_page
-            self.scheduler.tier_lookup = self._tier_probe
-        else:
-            self.host_pool = None
-        # per-tier hit/traffic counters (stats()["tiers"] + telemetry)
-        self.tier_hits = {"hbm": 0, "dram": 0, "store": 0}
-        self.spill_bytes = 0
-        self.spill_faults = 0
-        self.fetches = 0
-        self.fetch_seconds = 0.0
-        self.fetch_faults = 0
         # multi-tenant LoRA serving (r25): ``lora`` takes a LoraConfig
         # (explicit geometry), True (env defaults, forced on), or
         # None/False (follow RAY_TPU_LORA).  When on, the engine holds
@@ -451,22 +365,10 @@ class InferenceEngine:
         self._exec_key = (cfg, self.slots, self.page_size, num_pages,
                           max_pages_per_slot, self.kv_dtype, lora_key)
         self.compile_counts: Dict[str, int] = {
-            "prefill": 0, "prefill_cached": 0, "decode": 0,
-            "verify": 0}
+            "prefill": 0, "prefill_cached": 0, "decode": 0}
         self.hit_counts: Dict[str, int] = {
-            "prefill": 0, "prefill_cached": 0, "decode": 0,
-            "verify": 0}
+            "prefill": 0, "prefill_cached": 0, "decode": 0}
         self._requests: Dict[int, Request] = {}
-        # speculative-decoding state: per-request drafter indexes
-        # (popped at retirement — any terminal path — and bulk-cleared
-        # by drain_requests so the reaped-corpse audit stays clean)
-        # plus cumulative accept accounting for stats()/telemetry
-        self._drafts: Dict[int, DraftState] = {}
-        self.spec_proposed = 0
-        self.spec_accepted = 0
-        # accepted-per-verify histogram: m -> number of verify steps
-        # that accepted exactly m drafts
-        self.spec_k_hist: Dict[int, int] = {}
         # retired-but-held requests (r20 disagg export seam): pages
         # stay refcounted until export_request/release_held — the leak
         # audit counts them, so an orphaned export is visible
@@ -477,10 +379,6 @@ class InferenceEngine:
         # fleet.replica.EngineReplica so cross-replica trace trees can
         # attribute work; None = a bare engine)
         self.trace_label: Optional[str] = None
-        # store-eviction telemetry is a scrape: the shared store's
-        # cumulative counter, deltas reported per tick
-        self._store_evictions_seen = (self.store.evictions
-                                      if self.store is not None else 0)
         # dispatched steps whose tokens the host has not fetched,
         # oldest first (see _Flight), and events delivered outside a
         # tick (``_level``), which the next tick returns
@@ -661,22 +559,6 @@ class InferenceEngine:
             return self.adapters.digest()
 
     # --------------------------------------------------------- requests
-    def _resolve_spec_k(self, sampling: SamplingParams) -> int:
-        """The request's speculative draft budget (0 = plain decode):
-        per-request ``SamplingParams.spec``/``spec_k`` override the
-        engine defaults, resolved ONCE here so the hot planning loop
-        reads a plain int off the request."""
-        on = self.spec if sampling.spec is None else bool(sampling.spec)
-        if not on:
-            return 0
-        if self._latent:
-            kvc.refuse_latent("speculative decoding (SamplingParams.spec)")
-        k = (self.spec_k if sampling.spec_k is None
-             else int(sampling.spec_k))
-        if k < 1:
-            raise ValueError(f"spec_k must be >= 1, got {k}")
-        return k
-
     def submit(self, prompt, max_new_tokens: int = 16,
                sampling: Optional[SamplingParams] = None,
                eos_token: Optional[int] = None,
@@ -690,8 +572,8 @@ class InferenceEngine:
         for :meth:`export_request` instead of releasing — the prefill
         side of a prefill/decode split.  ``trace_ctx`` (r24, a
         :class:`~ray_tpu.telemetry.trace.TraceContext`) attaches the
-        request to a distributed trace: queue / prefix-walk /
-        tier-fetch / prefill / verify spans all hang off its id."""
+        request to a distributed trace: queue / prefix-walk / prefill
+        spans all hang off its id."""
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise ValueError("empty prompt")
@@ -736,8 +618,6 @@ class InferenceEngine:
                           deadline_s=(self.deadline if deadline_s
                                       is None else deadline_s or None),
                           hold_pages=bool(hold_pages),
-                          spec_k=self._resolve_spec_k(
-                              sampling or SamplingParams()),
                           trace=trace_ctx,
                           model_id=model_id or None,
                           adapter_slot=-1 if model_id else 0)
@@ -788,10 +668,6 @@ class InferenceEngine:
         held = list(self._held)
         for rid in held:
             self.release_held(rid)
-        # any in-flight drafter state goes with the requests — a
-        # reaped replica must not leak per-request indexes either
-        # (stats()["spec"]["drafts"] is the audit's counter)
-        self._drafts.clear()
         return len(rids) + len(held)
 
     # --------------------------------------------- disagg handoff (r20)
@@ -909,8 +785,6 @@ class InferenceEngine:
                                       is None else deadline_s or None),
                           chain_hashes=list(handoff.chain_hashes),
                           import_payload=handoff,
-                          spec_k=self._resolve_spec_k(
-                              sampling or SamplingParams()),
                           trace=trace_ctx,
                           # the importer must decode under the EXACT
                           # factors the prefill used: the version pins
@@ -940,7 +814,6 @@ class InferenceEngine:
                 if req.rid in cancelled:
                     sched.retire(slot)
                     self._requests.pop(req.rid, None)
-                    self._drafts.pop(req.rid, None)
                     self._adapter_release(req)
             for req in [r for r in sched.waiting
                         if r.rid in cancelled]:
@@ -990,7 +863,6 @@ class InferenceEngine:
                     sched.retire(slot)
                     req.error = err
                     self._requests.pop(req.rid, None)
-                    self._drafts.pop(req.rid, None)
                     self._adapter_release(req)
                     expired.append(req)
         for req in expired:
@@ -1039,11 +911,6 @@ class InferenceEngine:
         # its tokens are fetched first, and come out of the next step()
         self._level()
         self.scheduler.flush_prefix()
-        if self.host_pool is not None:
-            # spilled entries hold K/V computed under the old params;
-            # drop them rather than demote (the store invalidates by
-            # key — the bumped version simply never matches)
-            self.host_pool.clear()
         new = jax.device_put(params)
         jax.block_until_ready(new)
         old, self.params = self.params, new
@@ -1105,33 +972,6 @@ class InferenceEngine:
             "exports": self.exports,
             "imports": self.imports,
             "held": len(self._held),
-            # speculative decoding (r21): cumulative draft accounting
-            # plus live drafter-state count (the reaped-corpse audit —
-            # a drained engine must read drafts == 0)
-            "spec": {
-                "proposed": self.spec_proposed,
-                "accepted": self.spec_accepted,
-                "accept_rate": (self.spec_accepted / self.spec_proposed
-                                if self.spec_proposed else 0.0),
-                "k_hist": dict(sorted(self.spec_k_hist.items())),
-                "drafts": len(self._drafts),
-            },
-            # tiered KV cache (r23): per-tier prefix hits plus the
-            # demote/promote legs' byte/latency/fault accounting
-            "tiers": {
-                "enabled": self.tiered,
-                "hits": dict(self.tier_hits),
-                "spill_dtype": self.spill_dtype,
-                "spill_bytes": self.spill_bytes,
-                "spill_faults": self.spill_faults,
-                "fetches": self.fetches,
-                "fetch_seconds": self.fetch_seconds,
-                "fetch_faults": self.fetch_faults,
-                "host": (self.host_pool.stats()
-                         if self.host_pool is not None else None),
-                "store": (self.store.stats()
-                          if self.store is not None else None),
-            },
             # multi-tenant LoRA (r25): registry residency/hit counters
             # plus the shared store's publish/fetch accounting
             "adapters": {
@@ -1154,44 +994,14 @@ class InferenceEngine:
         fetches and delivers what was dispatched before that decode: so
         it returns the first tokens of the requests it admitted and the
         tokens of the decode the *previous* tick dispatched, while the
-        chip is already at work on this tick's.  A tick with a slot
-        that may draft is synchronous and returns its own decode's
-        tokens too (see the class docstring)."""
+        chip is already at work on this tick's."""
         events, self._backlog = self._backlog, []
         with tracing.span("infer/step", tick=self.ticks) as tick:
             admitted = self._admit(events)
-            active = self.scheduler.active
-            # drafting reads a request's tokens on the host: a tick in
-            # which any slot may draft brings the host level first and
-            # fetches its own decode at once, as every tick used to
-            ahead = not any(r.spec_k > 0 for r in active.values())
-            plan: Dict[int, List[int]] = {}
-            if not ahead:
-                self._land(events)
-                # speculating slots leave the plain decode batch for
-                # this tick (their verify forward IS their decode) and
-                # plain slots co-batch as always; an all-speculating
-                # tick skips the decode dispatch entirely
-                plan = self._plan_speculation()
-            sent = None
-            if len(plan) < len(active):
-                sent = self._decode(skip=set(plan), ahead=ahead)
-            self._land(events, keep=sent if ahead else None)
-            for slot, drafts in plan.items():
-                self._verify(slot, drafts, events)
+            sent = self._decode()
+            self._land(events, keep=sent)
             self.ticks += 1
             self.last_tick_ts = time.monotonic()
-            if self.store is not None:
-                ev = self.store.evictions
-                if ev > self._store_evictions_seen:
-                    self.telemetry.record_kv_store_evictions(
-                        ev - self._store_evictions_seen)
-                    self._store_evictions_seen = ev
-            if self.tiered and self.telemetry.enabled:
-                self.telemetry.record_tier_occupancy(
-                    hbm=len(self.scheduler.prefix_index or ()),
-                    dram=len(self.host_pool) if self.host_pool else 0,
-                    store=len(self.store) if self.store else 0)
             tick.set(admitted=admitted, events=len(events),
                      active=len(self.scheduler.active))
         return events
@@ -1199,8 +1009,8 @@ class InferenceEngine:
     def _admit(self, events: List[StepEvent]) -> int:
         """The scheduler's part of a tick, an ``infer/admit`` span per
         pass: cancels, deadlines and adapters first, then requests taken
-        off the queue one at a time (prefix walk, page allocation, tier
-        and import installs), each one's prefill dispatched before the
+        off the queue one at a time (prefix walk, page allocation, an
+        import's install), each one's prefill dispatched before the
         next is looked at (its first token is fetched with the rest of
         the tick's, ``_land``).  Returns how many were admitted."""
         admitted = 0
@@ -1220,13 +1030,6 @@ class InferenceEngine:
                 if req.import_payload is not None:
                     self._install_import(req, events)
                     continue
-                if req.n_hit_pages:
-                    self.tier_hits["hbm"] += req.n_hit_pages
-                    if self.telemetry.enabled:
-                        self.telemetry.record_prefix_hits(
-                            req.n_hit_pages, tier="hbm")
-                if req.tier_plan:
-                    self._install_tier_hits(req)
             self._prefill(req)
 
     def generate(self, prompts, max_new_tokens: int = 16,
@@ -1316,7 +1119,7 @@ class InferenceEngine:
                                          *scalars, sched.page_table[slot])
             out, path = self._sample_slots(sp, logits, [req])
         req.in_flight += 1
-        self._register_prefix(req)
+        self.scheduler.register_prefix(req)
         sched.lengths[slot] = plen
         self._flight.append(_Flight(
             kind, [(0, req)], out, sp, path=path,
@@ -1394,7 +1197,7 @@ class InferenceEngine:
                              [present.index(i) for i in needed])
         # contents are in cache: the imported full pages are immutable
         # from here on and registrable for later handoffs/prompts
-        self._register_prefix(req)
+        self.scheduler.register_prefix(req)
         sched.lengths[slot] = n_ctx
         req.generated = [int(handoff.next_token)]
         req.logprobs = [float(handoff.next_logprob)]
@@ -1410,119 +1213,11 @@ class InferenceEngine:
                 pages_written=len(needed), hit_pages=req.n_hit_pages,
                 replica=self.trace_label)
 
-    # ------------------------------------------------ tiered cache (r23)
-    def _register_prefix(self, req: Request) -> None:
-        """Register the request's freshly-written full pages, then drop
-        any of those hashes from the host pool: a degraded fetch (fault
-        or stale plan) leaves the page to the prefill, and without the
-        discard the hash would sit in two local tiers at once — the
-        exact-partition invariant the leak audit asserts."""
-        self.scheduler.register_prefix(req)
-        if self.host_pool is not None and req.chain_hashes:
-            for h in req.chain_hashes[req.n_hit_pages:]:
-                self.host_pool.discard((h, self.param_version))
-
-    def _tier_probe(self, chain_hash: bytes) -> bool:
-        """Does a lower tier hold this hash under the live params?
-        The scheduler's ``tier_lookup`` — advisory only: the install
-        re-resolves each page and degrades any miss to prefill."""
-        key = (chain_hash, self.param_version)
-        if self.host_pool is not None and key in self.host_pool:
-            return True
-        return self.store is not None and key in self.store
-
-    def _spill_page(self, page: int, chain_hash: bytes) -> None:
-        """HBM -> host-DRAM demote leg (the allocator's ``spill_hook``,
-        fired when pressure evicts a registered idle page).  One
-        device->host gather, encoded in the spill dtype, keyed by
-        (chain hash, param version).  An injected ``kv.spill`` fault
-        degrades to the pre-r23 behavior — the page is simply
-        forgotten and a later request re-prefills it."""
-        from ray_tpu.util import chaos
-        try:
-            chaos.maybe_fail("kv.spill")
-        except chaos.InjectedFault:
-            self.spill_faults += 1
-            return
-        contents = kvc.export_pages(self.cache, [page])
-        entry = kvc.encode_spill_page(contents,
-                                      quantized=self.cache.quantized,
-                                      spill_dtype=self.spill_dtype)
-        nb = kvc.spill_entry_bytes(entry)
-        self.spill_bytes += nb
-        self.host_pool.put((chain_hash, self.param_version), entry)
-        if self.telemetry.enabled:
-            self.telemetry.record_kv_spill(nb)
-
-    def _install_tier_hits(self, req: Request) -> None:
-        """Promote the admission plan's lower-tier pages into the
-        request's freshly-allocated HBM pages, between ticks (the
-        ``import_pages`` pattern: functional ``.at[].set``, zero new
-        executables).  Pages install front-to-back and the first
-        failure — an injected ``kv.fetch`` fault, a plan gone stale
-        (demoted past reach or invalidated), a foreign-geometry store
-        entry — stops the walk: the remaining pages stay with the
-        suffix prefill, so any fault degrades to re-prefill-from-
-        prompt with exact continuations, never a hang.  Each installed
-        page registers immediately (resident for the next request) and
-        counts as a prefix hit via ``note_tier_hits``."""
-        from ray_tpu.util import chaos
-        sched = self.scheduler
-        installed = 0
-        for i in range(req.n_hit_pages,
-                       req.n_hit_pages + req.tier_plan):
-            key = (req.chain_hashes[i], self.param_version)
-            t0 = time.monotonic()
-            try:
-                chaos.maybe_fail("kv.fetch")
-            except chaos.InjectedFault:
-                self.fetch_faults += 1
-                break
-            tier = "dram"
-            entry = (self.host_pool.take(key)
-                     if self.host_pool is not None else None)
-            checked_out = False
-            if entry is None and self.store is not None:
-                entry = self.store.checkout(key)
-                checked_out = entry is not None
-                tier = "store"
-            if entry is None:
-                break           # advisory plan went stale: prefill
-            try:
-                if not kvc.spill_entry_matches(self.cache, entry):
-                    break       # foreign geometry reads as a miss
-                kvc.install_spill_page(self.cache, req.pages[i],
-                                       entry)
-            finally:
-                if checked_out:
-                    self.store.checkin(key)
-            if sched.prefix_index is not None:
-                sched.prefix_index.register(req.chain_hashes[i],
-                                            req.pages[i])
-            wall = time.monotonic() - t0
-            self.tier_hits[tier] += 1
-            self.fetches += 1
-            self.fetch_seconds += wall
-            if req.trace is not None and req.trace.sampled:
-                from ray_tpu.telemetry import trace as trace_mod
-                trace_mod.record_span(
-                    "tier_fetch", req.trace,
-                    start=trace_mod.epoch_of(t0), dur=wall,
-                    rid=req.rid, tier=tier, page_index=i,
-                    replica=self.trace_label)
-            if self.telemetry.enabled:
-                self.telemetry.record_kv_fetch(wall, tier=tier)
-                self.telemetry.record_prefix_hits(1, tier=tier)
-            installed += 1
-        req.tier_plan = 0
-        sched.note_tier_hits(req, installed)
-
     def leak_free(self) -> bool:
-        """Tier-inventory audit: the usable HBM pages partition exactly
-        into free / idle / held, the host pool respects its capacity
-        and never holds a hash that is also resident (a demoted entry
-        is in exactly one local tier), and no store fetch is left in
-        flight.  The fleet replicas' audits call through here."""
+        """Inventory audit: the usable pages partition exactly into
+        free / idle / held, every adapter pin belongs to a live request
+        and no adapter checkout is left in flight.  The fleet replicas'
+        audits call through here."""
         alloc = self.scheduler.allocator
         free = set(alloc._free)
         idle = set(alloc._idle)
@@ -1532,16 +1227,6 @@ class InferenceEngine:
                 or (free & held) or (idle & held)):
             return False
         if len(alloc._free) != len(alloc._free_set):
-            return False
-        if self.host_pool is not None:
-            if len(self.host_pool) > self.host_pool.capacity:
-                return False
-            if self.scheduler.prefix_index is not None:
-                resident = {(h, self.param_version) for h in
-                            self.scheduler.prefix_index.digest()}
-                if resident & set(self.host_pool._entries):
-                    return False
-        if self.store is not None and self.store.in_flight != 0:
             return False
         if self.adapters is not None:
             # every live pin must belong to a live multi-tenant
@@ -1556,10 +1241,10 @@ class InferenceEngine:
         return True
 
     # ----------------------------------------------------------- decode
-    def _decode(self, skip: Set[int], ahead: bool) -> Optional[_Flight]:
+    def _decode(self) -> Optional[_Flight]:
         """Dispatch one decode over every active row that has a token
-        to come and is not in ``skip`` -> its record in ``_flight``
-        (None if there is no such row).  Nothing is fetched: the token
+        to come -> its record in ``_flight``, left in flight (None if
+        there is no such row).  Nothing is fetched: the token
         input is the previous decode's sampled tokens where that decode
         is still in flight, and what delivery used to settle for the
         next dispatch is settled here, by count."""
@@ -1576,8 +1261,7 @@ class InferenceEngine:
         for slot, req in sched.active.items():
             # a request whose last token is in flight holds its slot
             # until that token is delivered, and is not decoded again
-            if slot not in skip and (len(req.generated) + req.in_flight
-                                     < req.max_new_tokens):
+            if len(req.generated) + req.in_flight < req.max_new_tokens:
                 reqs[slot] = req
             else:
                 dead.append(slot)
@@ -1589,19 +1273,18 @@ class InferenceEngine:
         lengths = sched.lengths.copy()
         page_table = sched.page_table.copy()
         if dead:
-            # held slots that sit this decode out (speculating, or
-            # done but for delivery) ride it as dead rows (the decode
-            # step's shape is fixed): their page rows mask to the
-            # garbage page so the batched K/V write cannot touch the
-            # positions a verify forward is about to fill, and their
-            # sampled outputs are never delivered
+            # held slots that are done but for delivery ride this
+            # decode as dead rows (its shape is fixed): their page rows
+            # mask to the garbage page, so nothing of theirs is read,
+            # written or counted as a token, and their sampled outputs
+            # are never delivered
             page_table[dead, :] = kvc.GARBAGE_PAGE
         # the pages this decode's attention reads: each row's context
         # with the token it writes, by the host's own count
         live = lengths[[slot for slot, _req in rows]]
         pages = int((live // self.page_size + 1).sum())
         with tracing.span("infer/decode", active=len(rows),
-                          ahead=int(ahead), pages=pages,
+                          ahead=1, pages=pages,
                           tokens=int(live.sum()) + len(rows)) as sp:
             logits, moe = self._run_step(("decode",), reqs,
                                          self._token_input(rows), lengths,
@@ -1731,127 +1414,6 @@ class InferenceEngine:
         wait for the next :meth:`step` to return them."""
         self._land(self._backlog)
 
-    # ---------------------------------------------- speculation (r21)
-    def _plan_speculation(self) -> Dict[int, List[int]]:
-        """slot -> drafted tokens for this tick (empty dict = plain
-        decode for everyone).  A slot speculates when its request
-        opted in (``spec_k > 0``), has more than one token left to
-        generate, and its drafter finds a context match; the draft
-        budget is clipped to the remaining token budget so the verify
-        write window provably stays inside the pages reserved at
-        admission (highest written position = ``len(prompt) +
-        max_new_tokens - 1``, the last reserved token)."""
-        plan: Dict[int, List[int]] = {}
-        for slot, req in self.scheduler.active.items():
-            if req.spec_k <= 0:
-                continue
-            remaining = req.max_new_tokens - len(req.generated)
-            k = min(req.spec_k, remaining)
-            if k < 1:
-                continue
-            ds = self._drafts.get(req.rid)
-            if ds is None:
-                ds = DraftState(req.prompt)
-                self._drafts[req.rid] = ds
-            ds.sync(req.prompt, req.generated)
-            drafts = ds.propose(k)
-            if drafts:
-                plan[slot] = drafts
-        return plan
-
-    @staticmethod
-    def _verify_bucket(n_drafts: int) -> int:
-        """Power-of-two draft-capacity bucket: one verify executable
-        per bucket serves every draft length up to it (suffix_len is a
-        traced scalar), so mixed-k traffic compiles O(log max_k)
-        executables, then zero."""
-        kb = 1
-        while kb < n_drafts:
-            kb *= 2
-        return kb
-
-    def _verify(self, slot: int, drafts: List[int], events) -> None:
-        """Score ``[last_token, d1..dk]`` in ONE cached-context
-        forward (all-rows mode), sample every row under the request's
-        own ``fold_in`` key chain, and emit the accepted prefix plus
-        one more real token (``sampling.accept_drafts``).  The slot's
-        length advances only over emitted tokens — the rejected tail's
-        K/V stays behind the length mask and is overwritten by the
-        next writes, which IS the rollback (the write window is
-        slot-private; asserted below)."""
-        sched = self.scheduler
-        req = sched.active[slot]
-        L = int(sched.lengths[slot])
-        n_drafts = len(drafts)
-        kb = self._verify_bucket(n_drafts)
-        # never-write-shared: the verify writes positions L..L+k of
-        # this slot — all strictly past every shared/registered page
-        # by construction (full prompt/context pages end before the
-        # first decode position), so rollback can never corrupt a
-        # page another request reads
-        kvc.assert_tail_private(
-            sched.allocator, sched.prefix_index, req.pages,
-            L, L + n_drafts, self.page_size)
-        tokens = np.zeros((1, kb + 1), np.int32)
-        tokens[0, 0] = req.generated[-1]
-        tokens[0, 1:1 + n_drafts] = drafts
-        with tracing.span("infer/verify", rid=req.rid, k=n_drafts) as sp:
-            logits, _moe = self._run_step(
-                ("verify", kb), [req], tokens, np.int32(L),
-                np.int32(n_drafts + 1), sched.page_table[slot])
-            # every row samples under the key plain decode would use
-            # at that position: row i's token lands when generated has
-            # len(generated) + i tokens, so counts advance from there
-            c = len(req.generated)
-            n_rows = kb + 1
-            with tracing.span("infer/sample", rows=n_rows) as ssp:
-                seeds = np.full((n_rows,), req.sampling.seed, np.int32)
-                counts = c + np.arange(n_rows, dtype=np.int32)
-                temps = np.full((n_rows,), req.sampling.temperature,
-                                np.float32)
-                top_ks = np.full((n_rows,), req.sampling.top_k, np.int32)
-                top_ps = np.full((n_rows,), req.sampling.top_p,
-                                 np.float32)
-                path = self._sample_path(ssp, temps, top_ks, top_ps)
-                if path is not None:
-                    ssp.set(path=path)
-                # a verify is fetched at once: its tick is synchronous
-                toks, logps = jax.device_get(sample_tokens_logprobs(
-                    logits[0], seeds, counts, temps, top_ks, top_ps))
-        with self._deliver_span(events):
-            m, emitted = accept_drafts(toks[:n_drafts + 1], drafts)
-            self.spec_proposed += n_drafts
-            self.spec_accepted += m
-            self.spec_k_hist[m] = self.spec_k_hist.get(m, 0) + 1
-            if req.trace is not None and req.trace.sampled:
-                from ray_tpu.telemetry import trace as trace_mod
-                trace_mod.record_span(
-                    "verify", req.trace,
-                    start=trace_mod.epoch_of(sp.start), dur=sp.dur,
-                    rid=req.rid, proposed=n_drafts, accepted=m,
-                    replica=self.trace_label)
-            if self.debug_logits:
-                host_logits = np.asarray(logits[0])
-            delivered = 0
-            for i, tok in enumerate(emitted):
-                # the input token of row i (last_token or draft i) is
-                # now cached at position L + i; advancing BEFORE
-                # delivery keeps the decode-step length semantics, and
-                # a retire inside the block (EOS / max_new) resets the
-                # slot anyway
-                sched.lengths[slot] = L + i + 1
-                if self.debug_logits:
-                    self.logits_trace.setdefault(req.rid, []).append(
-                        host_logits[i])
-                self._deliver(req, int(tok), float(logps[i]), events)
-                delivered += 1
-                if req.done:
-                    break
-            if self.telemetry.enabled:
-                self.telemetry.record_verify(
-                    sp.dur, proposed=n_drafts, accepted=m,
-                    emitted=delivered)
-
     @contextlib.contextmanager
     def _deliver_span(self, events):
         """``infer/deliver``: what a tick does with tokens once they are
@@ -1884,7 +1446,6 @@ class InferenceEngine:
             self._adapter_release(req)
             if self.telemetry.enabled:
                 self.telemetry.record_request_done()
-            self._drafts.pop(req.rid, None)
             if not self.debug_logits:
                 # a serve replica lives for the deployment's lifetime:
                 # finished requests must not accumulate (debug engines
@@ -2082,10 +1643,6 @@ class InferenceEngine:
           merged in one softmax.  ``cached_len`` / ``suffix_len`` are
           traced scalars, so one executable per *suffix bucket* serves
           every cached length.  Logits [1, V] of the last valid row.
-        - ``"verify"`` (r21): the same step one slot at a time over
-          ``[last_token, d1..dk]`` with logits at EVERY suffix row
-          [1, S_bucket, V] (row i scores the token after draft i).  It
-          is jitted under the name ``prefill_cached``.
         - ``"decode"`` (tokens [slots] (each slot's next input token),
           lengths [slots] (tokens already cached = the new token's
           absolute position), page_table [slots, max_pages]): one row
@@ -2124,19 +1681,17 @@ class InferenceEngine:
                 x, cache_state, *counts = own_stack(
                     params, tokens, positions, cache_state, attn_hook,
                     _token_rows(kind, args))
-            if kind in ("prefill", "prefill_cached"):
+            if kind != "decode":
                 x = jnp.take(x[0], last - 1, axis=0)[None, None]  # [1,1,d]
             logits = jnp.einsum("bsd,dv->bsv", x,
                                 gpt_mod.lm_head(params, cfg)
                                 if own_stack is None
-                                else cfg.lm_head(params))
-            if kind != "verify":
-                logits = logits[:, 0]
+                                else cfg.lm_head(params))[:, 0]
             return ((logits.astype(jnp.float32),)
                     + (tuple(counts) if counted else ())
                     + tuple(cache_state))
 
-        step.__name__ = "prefill_cached" if kind == "verify" else kind
+        step.__name__ = kind
         first = 2 if lora_on else 1      # cache state shifts past bank
         return jax.jit(step,
                        donate_argnums=tuple(range(first,

@@ -60,22 +60,6 @@ scales).  At head_dim 64 that is 68 bytes per cached
 vector (64 codes + one f32 scale) vs 128 in bf16 — :meth:`KVCache.bytes`
 counts both arrays, so the ~2x capacity-per-HBM-byte claim is
 asserted, not assumed.
-
-**Tiered spill (r23).**  LRU eviction *demotes* instead of forgets:
-when :meth:`PageAllocator.alloc` runs the free list dry and reclaims
-an idle prefix page, the allocator's ``spill_hook`` first copies the
-page's contents host-side into a per-engine :class:`HostPagePool`
-(tier 1, pinned DRAM), and the pool's own LRU overflow demotes on to a
-fleet-shared content-addressed :class:`KVPageStore` (tier 2, the
-object store).  Entries are keyed ``(chain_hash, param_version)`` so a
-``set_params`` swap invalidates by key mismatch, never by a store
-sweep; the spill format defaults to int8 codes + per-vector scales
-(:func:`encode_spill_page`), halving resident and wire bytes exactly
-as the r20 handoff and r22 DCN paths do.  Promotion is the reverse
-walk: admission finds the hash in a lower tier, a fresh HBM page is
-allocated, and :func:`install_spill_page` scatters the contents back
-between ticks — the same functional ``.at[].set`` as
-:func:`import_pages`, zero new executables.
 """
 
 from __future__ import annotations
@@ -137,10 +121,10 @@ class KVHandoff:
     Shapes: ``k``/``v`` are ``[n_layers, n_pages, page_size, kv_heads,
     head_dim]`` in the cache's storage dtype; ``k_scale``/``v_scale``
     (int8 caches only) are ``[n_layers, n_pages, page_size, kv_heads]``
-    f32.  That is the format of everything that leaves this file (a
-    handoff, a spill entry), whatever the device pool's own layout:
-    :func:`export_pages` and the installers convert at the boundary,
-    so contents written by an older replica still install.  Page order
+    f32.  That is the format of what leaves this file, whatever the
+    device pool's own layout: :func:`export_pages` and
+    :func:`import_pages` convert at the boundary, so contents written
+    by an older replica still install.  Page order
     matches :func:`pages_needed` over ``context``: full pages first,
     then the partial tail (whose positions past ``len(context) %
     page_size`` are garbage the decode attention masks, exactly as on
@@ -240,7 +224,7 @@ def export_pages(cache: "KVCache", pages: Sequence[int]
     in page order.  One gather per array (a DMA on a real device; the
     in-place object-store put is the on-chip follow-up)."""
     if cache.latent:
-        refuse_latent("export_pages (a KVHandoff's or a spill's contents)")
+        refuse_latent("export_pages (a KVHandoff's contents)")
     idx = np.asarray(list(pages), np.int32)
     return {name: np.ascontiguousarray(
                 np.moveaxis(np.asarray(a[:, idx]), -1, 2))
@@ -263,329 +247,6 @@ def import_pages(cache: "KVCache", pages: Sequence[int],
     cache.state = tuple(
         a.at[:, idx].set(np.moveaxis(getattr(handoff, name)[:, sel], 2, -1))
         for name, a in zip(_NAMES, cache.state))
-
-
-SPILL_DTYPES = ("int8", "model")
-
-
-def _quantize_page(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Per-vector symmetric int8: ``scale = amax/127`` over the last
-    axis, codes rounded-to-nearest — the same block shape the int8
-    cache stores, so a spilled page prices identically to a resident
-    one (``head_dim + 4`` bytes per cached vector)."""
-    x = np.asarray(x, np.float32)
-    amax = np.abs(x).max(axis=-1)
-    scale = (amax / 127.0).astype(np.float32)
-    safe = np.where(scale == 0.0, 1.0, scale)
-    codes = np.rint(x / safe[..., None]).clip(-127, 127)
-    return codes.astype(np.int8), scale
-
-
-def encode_spill_page(contents: Dict[str, np.ndarray], *,
-                      quantized: bool,
-                      spill_dtype: str = "int8") -> Dict[str, object]:
-    """One page's host-side spill entry from an :func:`export_pages`
-    single-page gather.  int8 caches pass their codes + scales through
-    unchanged (already the cheapest exact form); model-dtype caches
-    quantize per vector when ``spill_dtype="int8"`` (the default — the
-    r11/r22 trick applied to the spill/wire tier) or keep raw bytes
-    under ``"model"``."""
-    k, v = contents["k"][:, 0], contents["v"][:, 0]
-    if quantized:
-        return {"fmt": "int8", "k": k, "v": v,
-                "k_scale": contents["k_scale"][:, 0],
-                "v_scale": contents["v_scale"][:, 0]}
-    if spill_dtype == "int8":
-        k8, ks = _quantize_page(k)
-        v8, vs = _quantize_page(v)
-        return {"fmt": "int8", "k": k8, "v": v8,
-                "k_scale": ks, "v_scale": vs}
-    return {"fmt": "model", "k": np.asarray(k), "v": np.asarray(v)}
-
-
-def spill_entry_bytes(entry: Dict[str, object]) -> int:
-    return sum(a.nbytes for a in entry.values()
-               if isinstance(a, np.ndarray))
-
-
-def spill_entry_matches(cache: "KVCache",
-                        entry: Dict[str, object]) -> bool:
-    """Geometry guard before an install: a fleet-shared store entry
-    written by a different-geometry engine must read as a miss, never
-    a shape error mid-admission."""
-    L, _, H, D, page_size = cache.k.shape
-    return tuple(entry["k"].shape) == (L, page_size, H, D)
-
-
-def install_spill_page(cache: "KVCache", page: int,
-                       entry: Dict[str, object]) -> None:
-    """Scatter one spilled entry back into device ``page`` — the
-    promote leg.  Functional ``.at[:, page].set`` between ticks, like
-    :func:`import_pages`: the next compiled step's donated state picks
-    it up, so promotion needs zero new executables.  int8 entries feed
-    an int8 cache verbatim; a model-dtype cache dequantizes on the
-    host first (the int8-budget approximation the r11 parity tests
-    bound)."""
-    if cache.latent:
-        refuse_latent("install_spill_page (the spill tiers)")
-    if cache.quantized:
-        if entry["fmt"] == "int8":
-            k, ks = entry["k"], entry["k_scale"]
-            v, vs = entry["v"], entry["v_scale"]
-        else:
-            k, ks = _quantize_page(entry["k"])
-            v, vs = _quantize_page(entry["v"])
-        rows = (k, v, ks, vs)
-    elif entry["fmt"] == "int8":
-        rows = (entry["k"].astype(np.float32) * entry["k_scale"][..., None],
-                entry["v"].astype(np.float32) * entry["v_scale"][..., None])
-    else:
-        rows = (entry["k"], entry["v"])
-    # an entry's page is [L, page_size, ...]; the pool's [L, ..., page_size]
-    cache.state = tuple(
-        a.at[:, page].set(jnp.asarray(np.moveaxis(r, 1, -1), a.dtype))
-        for a, r in zip(cache.state, rows))
-
-
-class HostPagePool:
-    """Tier 1: the per-engine pinned host-DRAM spill pool.
-
-    An LRU ``(chain_hash, param_version) -> spill entry`` map with a
-    hard page capacity.  :meth:`put` is the HBM demote target;
-    overflow demotes the oldest entry on to the fleet-shared
-    :class:`KVPageStore` (tier 2) when one is attached — through the
-    ``kv.spill`` chaos site, so a faulted store leg degrades to
-    forgetting the page (a later request re-prefills; nothing hangs).
-    :meth:`take` pops — tiers stay exclusive per engine, which is what
-    lets the leak audit assert the free/idle/held/host partition
-    exactly.
-    """
-
-    def __init__(self, capacity_pages: int,
-                 store: Optional["KVPageStore"] = None):
-        if capacity_pages < 0:
-            raise ValueError("host pool capacity must be >= 0")
-        self.capacity = capacity_pages
-        self.store = store
-        self._entries: "collections.OrderedDict[Tuple[bytes, int], Dict]" \
-            = collections.OrderedDict()
-        self.spills = 0          # entries accepted (HBM -> DRAM)
-        self.demotions = 0       # entries pushed on to the store
-        self.dropped = 0         # overflow with no store / faulted leg
-        self.hits = 0
-        self.misses = 0
-        self.bytes_spilled = 0
-        self.bytes = 0           # current resident bytes
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-    def __contains__(self, key: Tuple[bytes, int]) -> bool:
-        return key in self._entries
-
-    def put(self, key: Tuple[bytes, int],
-            entry: Dict[str, object]) -> None:
-        from ray_tpu.util import chaos
-        if self.capacity == 0:
-            self._demote(key, entry, chaos)
-            return
-        if key in self._entries:
-            self._entries.move_to_end(key)
-            return
-        self._entries[key] = entry
-        nb = spill_entry_bytes(entry)
-        self.spills += 1
-        self.bytes_spilled += nb
-        self.bytes += nb
-        while len(self._entries) > self.capacity:
-            old_key, old = self._entries.popitem(last=False)
-            self.bytes -= spill_entry_bytes(old)
-            self._demote(old_key, old, chaos)
-
-    def _demote(self, key, entry, chaos) -> None:
-        """DRAM -> store leg (or a straight drop without a store)."""
-        if self.store is None:
-            self.dropped += 1
-            return
-        try:
-            chaos.maybe_fail("kv.spill")
-        except chaos.InjectedFault:
-            self.dropped += 1       # degrade: re-prefill later
-            return
-        self.store.put(key, entry)
-        self.demotions += 1
-
-    def take(self, key: Tuple[bytes, int]
-             ) -> Optional[Dict[str, object]]:
-        """Pop an entry for promotion (None on miss)."""
-        entry = self._entries.pop(key, None)
-        if entry is None:
-            self.misses += 1
-            return None
-        self.hits += 1
-        self.bytes -= spill_entry_bytes(entry)
-        return entry
-
-    def discard(self, key: Tuple[bytes, int]) -> None:
-        """Silently drop an entry that just became HBM-resident again
-        (a degraded fetch fell back to prefill and re-registered the
-        hash): without this, the hash would sit in two local tiers at
-        once and break the exact-partition leak audit.  Not a miss —
-        no counter moves."""
-        entry = self._entries.pop(key, None)
-        if entry is not None:
-            self.bytes -= spill_entry_bytes(entry)
-
-    def clear(self) -> int:
-        """Drop everything (weight swap: contents are stale)."""
-        n = len(self._entries)
-        self._entries.clear()
-        self.bytes = 0
-        return n
-
-    def stats(self) -> Dict[str, int]:
-        return {"entries": len(self._entries),
-                "capacity": self.capacity, "bytes": self.bytes,
-                "spills": self.spills, "demotions": self.demotions,
-                "dropped": self.dropped, "hits": self.hits,
-                "misses": self.misses,
-                "bytes_spilled": self.bytes_spilled}
-
-
-class KVPageStore:
-    """Tier 2: the fleet-shared content-addressed page store.
-
-    ``(chain_hash, param_version) -> spill entry``, shared by every
-    replica that holds a reference — the fleet's hit rate compounds
-    with each replica added, and a restarted or scaled-from-zero
-    replica warms up from here on its first admissions.  Mirrors
-    :class:`~ray_tpu.fleet.disagg.HandoffStore`: payloads ride the
-    real object store when a session is up (in-process otherwise), a
-    put is idempotent by key (content-addressed: same key, same
-    bytes), and a :meth:`checkout`/:meth:`checkin` pair brackets every
-    fetch so the leak audit can assert no promotion is left in flight.
-    Unlike the host pool, :meth:`checkout` does *not* pop — the store
-    is shared, and the next replica's miss is this entry's hit.
-    ``set_params`` invalidation is by key: a bumped param version
-    simply never matches, no sweep required.
-
-    **Byte cap (r24).**  ``RAY_TPU_KV_STORE_CAP`` bounds resident
-    bytes: an over-cap put evicts least-recently-*used* entries
-    (checkout recency, then insertion order) until the new entry fits.
-    An entry mid-checkout is pinned — eviction skips it — so a fetch
-    in flight can never lose its payload; if nothing evictable remains
-    the cap is allowed to overshoot rather than drop live data.  A
-    request whose store pages were evicted simply misses on the walk
-    and prefills the suffix — exact greedy continuations, just cold.
-    """
-
-    def __init__(self, use_object_store: Optional[bool] = None,
-                 capacity_bytes: Optional[int] = None):
-        if use_object_store is None:
-            try:
-                from ray_tpu._private.worker import is_initialized
-                use_object_store = is_initialized()
-            except Exception:
-                use_object_store = False
-        if capacity_bytes is None:
-            from ray_tpu.inference.config import infer_config
-            capacity_bytes = infer_config().store_cap
-        self._use_ray = bool(use_object_store)
-        self.capacity_bytes = int(capacity_bytes)   # 0 = unbounded
-        # insertion/recency-ordered: move_to_end on checkout makes the
-        # front the LRU eviction candidate
-        self._entries: "collections.OrderedDict[Tuple[bytes, int], object]" \
-            = collections.OrderedDict()
-        self._bytes: Dict[Tuple[bytes, int], int] = {}
-        # per-key checkout pin counts — an entry with fetches in
-        # flight is never evicted
-        self._pins: Dict[Tuple[bytes, int], int] = {}
-        self.puts = 0
-        self.dup_puts = 0
-        self.gets = 0
-        self.misses = 0
-        self.bytes_put = 0
-        self.evictions = 0
-        self.bytes_evicted = 0
-        self.in_flight = 0
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-    def __contains__(self, key: Tuple[bytes, int]) -> bool:
-        return key in self._entries
-
-    @property
-    def bytes(self) -> int:
-        return sum(self._bytes.values())
-
-    def _evict_for(self, incoming: int) -> None:
-        if self.capacity_bytes <= 0:
-            return
-        resident = self.bytes
-        victims = [k for k in self._entries
-                   if not self._pins.get(k)]
-        for key in victims:
-            if resident + incoming <= self.capacity_bytes:
-                break
-            nb = self._bytes.pop(key, 0)
-            del self._entries[key]
-            resident -= nb
-            self.evictions += 1
-            self.bytes_evicted += nb
-
-    def put(self, key: Tuple[bytes, int],
-            entry: Dict[str, object]) -> None:
-        if key in self._entries:        # content-addressed: a no-op
-            self.dup_puts += 1
-            return
-        nb = spill_entry_bytes(entry)
-        self._evict_for(nb)
-        obj: object = entry
-        if self._use_ray:
-            import ray_tpu
-            obj = ray_tpu.put(entry)
-        self._entries[key] = obj
-        self._bytes[key] = nb
-        self.puts += 1
-        self.bytes_put += nb
-
-    def checkout(self, key: Tuple[bytes, int]
-                 ) -> Optional[Dict[str, object]]:
-        """Fetch an entry without removing it; pair with
-        :meth:`checkin` once the install (or its failure path) is
-        done.  The entry is pinned against eviction until checked
-        back in."""
-        obj = self._entries.get(key)
-        if obj is None:
-            self.misses += 1
-            return None
-        self._entries.move_to_end(key)
-        self._pins[key] = self._pins.get(key, 0) + 1
-        self.gets += 1
-        self.in_flight += 1
-        if self._use_ray:
-            import ray_tpu
-            return ray_tpu.get(obj)
-        return obj
-
-    def checkin(self, key: Tuple[bytes, int]) -> None:
-        self.in_flight -= 1
-        pins = self._pins.get(key, 0) - 1
-        if pins > 0:
-            self._pins[key] = pins
-        else:
-            self._pins.pop(key, None)
-
-    def stats(self) -> Dict[str, int]:
-        return {"entries": len(self._entries), "bytes": self.bytes,
-                "capacity_bytes": self.capacity_bytes,
-                "puts": self.puts, "dup_puts": self.dup_puts,
-                "gets": self.gets, "misses": self.misses,
-                "bytes_put": self.bytes_put,
-                "evictions": self.evictions,
-                "bytes_evicted": self.bytes_evicted,
-                "in_flight": self.in_flight}
 
 
 class PrefixIndex:
@@ -632,8 +293,8 @@ class PrefixIndex:
         ``salt`` overrides the chain root (r25 multi-tenant serving:
         ``adapters.lora.salt_bytes(model_id, version)``).  Adapter K/V
         differs from base K/V for identical token prefixes, so salted
-        chains keep tenants from ever aliasing in the prefix index or
-        the tiered store; base traffic keeps the unsalted root, so its
+        chains keep tenants from ever aliasing in the prefix index;
+        base traffic keeps the unsalted root, so its
         hashes — and every pre-r25 digest — are unchanged."""
         h = salt or cls.ROOT
         out = []
@@ -666,11 +327,6 @@ class PrefixIndex:
 
     def has(self, page: int) -> bool:
         return page in self._by_page
-
-    def hash_of(self, page: int) -> Optional[bytes]:
-        """The chain hash a resident page is registered under — what
-        the allocator's spill hook keys the demoted copy by."""
-        return self._by_page.get(page)
 
     def forget(self, page: int) -> None:
         h = self._by_page.pop(page, None)
@@ -733,12 +389,6 @@ class PageAllocator:
             collections.OrderedDict()
         self._index = index
         self.evictions = 0
-        # r23: called as spill_hook(page, chain_hash) just before a
-        # pressure eviction forgets a registered idle page — the
-        # engine installs a closure that demotes the page's contents
-        # to the host pool.  flush_idle() never spills: a bulk flush
-        # means the params changed and the contents are stale.
-        self.spill_hook = None
 
     @property
     def free_count(self) -> int:
@@ -785,10 +435,6 @@ class PageAllocator:
                 p, _ = self._idle.popitem(last=False)   # oldest idle
                 self.evictions += 1
                 if self._index is not None:
-                    if self.spill_hook is not None:
-                        h = self._index.hash_of(p)
-                        if h is not None:
-                            self.spill_hook(p, h)       # demote leg
                     self._index.forget(p)
             self._refcount[p] = 1
             pages.append(p)
@@ -1008,9 +654,8 @@ def write_prefill_at(pages, new, layer, page_row, start, valid_len):
     layer is one more coordinate of the write, never a slice); new:
     [S, *rest] (bucket-padded suffix); layer/start/valid_len: traced
     scalars; page_row: [max_pages] int32.  The suffix touches at most
-    ``ceil(S / page_size) + 1`` of the slot's pages (``start`` need
-    not be page-aligned: a speculative verify starts mid-page); each
-    is rewritten whole with the valid rows laid over what it held
+    ``ceil(S / page_size) + 1`` of the slot's pages; each is rewritten
+    whole with the valid rows laid over what it held
     (:func:`_blend_pages`).  Rows past ``valid_len`` are written
     nowhere, and a candidate page that holds no valid row routes to
     the garbage page *explicitly* — a suffix bucket can overhang the
@@ -1141,8 +786,8 @@ def attend(q, cache, page_table, lengths, *, scale=None, value_dim=None):
     from ray_tpu.ops.attention import decode_attention
     layer, arrays = cache
     # a row whose table starts at the garbage page is no sequence (a
-    # free slot, or a held one sitting this decode out): nothing of
-    # the pool is read for it
+    # free slot, or a held one done but for delivery): nothing of the
+    # pool is read for it
     lengths = jnp.where(page_table[:, 0] == GARBAGE_PAGE, 0, lengths)
     if len(arrays) == 1:
         from ray_tpu.ops.attention import latent_decode_attention
@@ -1158,7 +803,7 @@ def context_dense(cache, page_table, dtype, *, value_dim=None):
     """Gather one layer's pages for ``page_table`` [B, max_pages] ->
     ``(K, V)``, each ``[B, max_pages * page, H, D]``: a model-dtype
     cache's as stored, an int8 cache's dequantised to ``dtype``.  What
-    a cached-suffix prefill and a verify attend over (one slot's row);
+    a cached-suffix prefill attends over (one slot's row);
     a decode reads the pool in place (:func:`attend`).  A latent
     cache's: the rows' two parts, ``([B, C, value_dim], [B, C, rope])``,
     for the caller to project K and V from."""
@@ -1180,30 +825,3 @@ def context_dense(cache, page_table, dtype, *, value_dim=None):
 
 def pages_needed(tokens: int, page_size: int) -> int:
     return -(-tokens // page_size)
-
-
-def assert_tail_private(allocator: PageAllocator,
-                        index: Optional[PrefixIndex],
-                        pages: List[int], first_pos: int,
-                        last_pos: int, page_size: int) -> None:
-    """Assert the never-write-shared invariant over a slot's write
-    window before a speculative verify dispatches: every page that
-    positions ``first_pos..last_pos`` land in must be exclusively
-    owned (refcount 1) and unregistered — so a rejected draft tail is
-    rolled back by simply not advancing the slot's length, and can
-    never have clobbered K/V another request shares.
-
-    Provably true by construction (prefix hits and registered pages
-    only ever cover FULL prompt/context pages, all strictly below the
-    first decode position), so a failure here is a scheduler bug, not
-    a traffic pattern — hence an assertion, not an error path."""
-    for idx in range(first_pos // page_size,
-                     last_pos // page_size + 1):
-        page = pages[idx]
-        assert allocator.refcount(page) == 1, (
-            f"speculative write window touches shared page {page} "
-            f"(refcount {allocator.refcount(page)}) — "
-            "never-write-shared violated")
-        assert index is None or not index.has(page), (
-            f"speculative write window touches prefix-registered "
-            f"page {page} — never-write-shared violated")

@@ -65,15 +65,6 @@ class SamplingParams:
     are then irrelevant).  ``top_k = 0`` disables the top-k filter;
     ``top_p = 1.0`` disables the nucleus filter.
 
-    ``spec``/``spec_k`` are the per-request speculative-decoding
-    overrides (r21): ``None`` defers to the engine defaults
-    (``RAY_TPU_INFER_SPEC`` / ``RAY_TPU_INFER_SPEC_K``); ``spec=False``
-    pins plain decode for this request, ``spec=True`` opts in with up
-    to ``spec_k`` drafted tokens per verify step.  Speculation never
-    changes what is sampled — the verify rows run the SAME
-    ``fold_in(seed, n_generated)`` key chain as plain decode, so the
-    knobs are pure throughput knobs.
-
     ``model_id`` (r25 multi-tenant serving) selects the LoRA adapter
     this request decodes under (``None`` = the base model).  It rides
     the per-request path like every other knob — serve payload ->
@@ -85,8 +76,6 @@ class SamplingParams:
     top_k: int = 0
     top_p: float = 1.0
     seed: int = 0
-    spec: Optional[bool] = None
-    spec_k: Optional[int] = None
     model_id: Optional[str] = None
 
 
@@ -162,33 +151,6 @@ def sample_tokens_logprobs(logits, seeds, counts, temps, top_ks,
     [B] f32), row-independent.  The logprob is ``log_softmax`` of the
     raw logits at the chosen id (see module docstring)."""
     return _sample(logits, seeds, counts, temps, top_ks, top_ps)
-
-
-def accept_drafts(sampled, drafts):
-    """Vectorized accept/reject for one verify step.
-
-    ``sampled`` [k+1] — the tokens the target model sampled at each
-    verify row (row i conditioned on the drafts before it, each under
-    its own ``fold_in(seed, count+i)`` key — i.e. EXACTLY the token
-    plain decode would have produced at that position); ``drafts`` [k]
-    — the self-drafter's proposals.  Draft i is accepted iff every
-    earlier draft was and ``sampled[i] == drafts[i]`` (sample-then-
-    compare: because the sampled token IS the plain-decode token, a
-    full-prefix match means the speculative trajectory and the plain
-    trajectory coincide, so acceptance is exact by construction —
-    greedy bit-exact, sampled trajectory-exact, no correction
-    distribution needed).
-
-    Returns ``(n_accepted, emitted)``: ``emitted`` is
-    ``sampled[:n_accepted + 1]`` — the accepted drafts plus one more
-    real token (the correction on a reject, the bonus row on a full
-    accept)."""
-    sampled = np.asarray(sampled)
-    drafts = np.asarray(drafts, dtype=sampled.dtype)
-    k = drafts.shape[0]
-    matches = sampled[:k] == drafts
-    n_acc = int(matches.argmin()) if not matches.all() else k
-    return n_acc, [int(t) for t in sampled[:n_acc + 1]]
 
 
 @jax.jit

@@ -14,12 +14,6 @@ functions).  Requests move ``waiting -> active(slot) -> finished``:
   hits are installed into the page-table row with refcount bumps and
   **zero prefill compute**; only the pages past the last hit are
   freshly allocated, and the engine prefills only the uncached suffix.
-  With tiering on (r23) the walk keeps going where the resident index
-  stops: the remaining hashes are looked up in the host-DRAM spill
-  pool and the fleet page store, and every consecutive lower-tier hit
-  is planned for promotion — the engine installs those pages into the
-  freshly-allocated storage between ticks and prefills only what no
-  tier holds.
 - **retire** (EOS / max-new-tokens): the request's page references are
   released — shared pages survive under their other owners' refcounts,
   registered refcount-0 pages park in the allocator's idle pool, the
@@ -139,18 +133,6 @@ class Request:
     # the payload instead of prefilled
     hold_pages: bool = False
     import_payload: Optional[Any] = None
-    # tiered cache (r23): how many eligible pages past the resident
-    # hits a lower tier (host pool / page store) held at admission —
-    # the engine promotes them into the fresh pages between ticks and
-    # converts each success into a hit via ``note_tier_hits``; any
-    # fetch failure just leaves the page to the suffix prefill
-    tier_plan: int = 0
-    # speculative decoding (r21): the resolved draft budget for this
-    # request — 0 = plain decode; > 0 = up to this many self-drafted
-    # tokens verified per engine tick.  Resolved at submit time from
-    # ``SamplingParams.spec``/``spec_k`` overriding the engine
-    # defaults, so the scheduler and engine never re-consult config.
-    spec_k: int = 0
     # distributed tracing (r24): the request's TraceContext (a
     # telemetry.trace.TraceContext, None = untraced) — minted at the
     # router/serve boundary, carried here so every lifecycle stage can
@@ -162,8 +144,8 @@ class Request:
     # the adapter and pins it before this request's first admission
     # attempt); ``adapter_version`` pins the store version (0 = latest,
     # resolved in place).  ``hash_salt`` overrides the prefix-chain
-    # root so adapter K/V never aliases base K/V in the index/tiers —
-    # it MUST be set before the first ``_prefix_walk`` computes
+    # root so adapter K/V never aliases base K/V in the index — it
+    # MUST be set before the first ``_prefix_walk`` computes
     # ``chain_hashes``.
     model_id: Optional[str] = None
     adapter_slot: int = 0
@@ -193,10 +175,6 @@ class SlotScheduler:
         self.prefix_hit_pages = 0
         self.prefix_hit_tokens = 0
         self.prefix_requests_hit = 0
-        # r23: engine-installed probe over the lower tiers —
-        # ``tier_lookup(chain_hash) -> bool`` (does the host pool or
-        # the fleet store hold this hash under the live params?)
-        self.tier_lookup = None
 
     # ------------------------------------------------------------ admit
     def submit(self, req: Request) -> None:
@@ -258,19 +236,6 @@ class SlotScheduler:
             if page is None:
                 break
             hits.append(page)
-        # r23: walk the remaining eligible hashes through the lower
-        # tiers (host pool, then the fleet store — the probe hides the
-        # order).  Recomputed on every attempt like the resident walk:
-        # demotions since the last attempt can move hits between
-        # tiers, and promotions can turn them resident.  The plan is
-        # advisory — the engine re-resolves each page at install time
-        # and degrades any miss or fault to plain prefill.
-        req.tier_plan = 0
-        if self.tier_lookup is not None and req.import_payload is None:
-            for h_i in req.chain_hashes[len(hits):eligible]:
-                if not self.tier_lookup(h_i):
-                    break
-                req.tier_plan += 1
         return hits
 
     def try_admit(self) -> Optional[Request]:
@@ -322,26 +287,8 @@ class SlotScheduler:
             _trace.record_span(
                 "prefix_walk", req.trace,
                 start=_trace.epoch_of(walk_t0), dur=walk_dur,
-                hits=len(hits), tier_plan=req.tier_plan,
-                eligible=len(req.chain_hashes or []))
+                hits=len(hits), eligible=len(req.chain_hashes or []))
         return req
-
-    def note_tier_hits(self, req: Request, n_pages: int) -> None:
-        """Account ``n_pages`` lower-tier promotions the engine just
-        installed for ``req`` (between admission and its prefill):
-        the request's cached window grows page-aligned, and the shared
-        prefix counters treat promoted pages exactly like resident
-        hits — they skipped the same prefill compute.  The request
-        joins ``requests_hit`` only if the resident walk found nothing
-        (it was already counted otherwise)."""
-        if n_pages <= 0:
-            return
-        if req.n_hit_pages == 0:
-            self.prefix_requests_hit += 1
-        req.n_hit_pages += n_pages
-        req.cached_tokens += n_pages * self.page_size
-        self.prefix_hit_pages += n_pages
-        self.prefix_hit_tokens += n_pages * self.page_size
 
     def register_prefix(self, req: Request) -> None:
         """Register the request's freshly-prefilled full prompt pages

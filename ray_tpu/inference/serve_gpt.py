@@ -76,8 +76,6 @@ def parse_request(request: Dict[str, Any]) -> Dict[str, Any]:
     and the fleet router both route requests through it, so a field
     added to the payload can never silently exist in one path and not
     the other."""
-    spec = request.get("speculation")
-    spec_k = request.get("speculation_k")
     return {
         "max_new_tokens": int(request.get("max_new_tokens", 16)),
         "sampling": SamplingParams(
@@ -85,12 +83,6 @@ def parse_request(request: Dict[str, Any]) -> Dict[str, Any]:
             top_k=int(request.get("top_k", 0)),
             top_p=float(request.get("top_p", 1.0)),
             seed=int(request.get("seed", 0)),
-            # speculation knobs: absent = engine defaults
-            # (RAY_TPU_INFER_SPEC{,_K}); explicit values pin this
-            # request on or off — a pure throughput knob, outputs are
-            # distribution-exact either way
-            spec=None if spec is None else bool(spec),
-            spec_k=None if spec_k is None else int(spec_k),
             # multi-tenant (r25): which LoRA adapter this request
             # decodes under; absent/None = the base model
             model_id=request.get("model_id")),
@@ -148,7 +140,6 @@ class GPTDeployment:
     int, "temperature": float, "top_k": int, "top_p": float, "seed":
     int, "eos_token": int | None, "logprobs": bool,
     "ttft_deadline_s": float | None, "deadline_s": float | None,
-    "speculation": bool | None, "speculation_k": int | None,
     "model_id": str | None}`` —
     yields generated token ids; with ``"logprobs": True`` each item is
     ``{"token": int, "logprob": float}`` instead (the sampled token's

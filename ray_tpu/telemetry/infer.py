@@ -67,15 +67,15 @@ class InferTelemetry:
         # ``draw`` / ``filter``, ``inference/sampling.py``): only
         # ``filter`` pays the full-vocabulary sorts
         self.sample_paths: Dict[str, int] = {}
-        # plain decodes by how their tick ran: [synchronous, ahead of
-        # the host] (a verify step is counted by ``spec_verify_steps``)
+        # decodes by how they were dispatched: [fetched at once, left
+        # in flight ahead of the host]
         self.decode_dispatches = [0, 0]
-        # pages of the KV pool the plain decodes' attention read (each
+        # pages of the KV pool the decodes' attention read (each
         # dispatched row's live pages) and pages their tables could
         # name (slots x max pages a slot): what reading the pool in
         # place saves over a padded context
         self.decode_pages = [0, 0]
-        # rows of K/V the plain decodes laid into the pool (one a
+        # rows of K/V the decodes laid into the pool (one a
         # dispatched row) and tail pages they moved to lay them: the
         # same count where the write is in place, every slot's page
         # where whole pages are blended
@@ -85,19 +85,6 @@ class InferTelemetry:
         # alone: a prefill's hundreds of rows hit every held expert, a
         # decode's few rows hit few, and a ratio over both hides it
         self.moe: Dict[str, int] = {}
-        # speculative decoding (r21): cumulative proposed/accepted
-        # draft counts and verify-step count — the accept rate is the
-        # one number that decides whether speculation pays
-        self.spec_proposed = 0
-        self.spec_accepted = 0
-        self.spec_verify_steps = 0
-        # tiered KV cache (r23): prefix hits by serving tier, plus the
-        # demote (spill bytes) and promote (fetch latency) legs
-        self.tier_hits: Dict[str, int] = {}
-        self.kv_spill_bytes = 0
-        self.kv_fetches = 0
-        self.kv_fetch_seconds = 0.0
-        self.kv_store_evictions = 0
         # multi-tenant LoRA (r25): per-replica adapter-cache outcomes
         # and load latency — the hit rate is what the router's
         # adapter-affinity scoring is supposed to move
@@ -110,7 +97,6 @@ class InferTelemetry:
         self._metrics_dead = False
         self._metrics_last = 0.0
         self._queue_last = 0.0
-        self._tier_last = 0.0
 
     # ---------------------------------------------------------- records
     def record_prefill(self, wall_s: float, *, prompt_tokens: int,
@@ -130,13 +116,13 @@ class InferTelemetry:
                       ahead: bool = False, pages_read: int = 0,
                       pages_table: int = 0, rows_written: int = 0,
                       tail_pages_rewritten: int = 0) -> None:
-        """One plain decode, recorded when its tokens are on the host.
+        """One decode, recorded when its tokens are on the host.
         ``ahead``: it was dispatched on the device's own tokens, ahead
-        of the host (``inference/engine.py``), and not by a synchronous
-        tick.  ``pages_read`` of ``pages_table``: the pages its rows
-        held at the dispatch, of those its page table has room for.
-        ``rows_written``: the rows it laid into the pool;
-        ``tail_pages_rewritten``: the pages it moved to lay them."""
+        of the host (``inference/engine.py``).  ``pages_read`` of
+        ``pages_table``: the pages its rows held at the dispatch, of
+        those its page table has room for.  ``rows_written``: the rows
+        it laid into the pool; ``tail_pages_rewritten``: the pages it
+        moved to lay them."""
         if not self.enabled:
             return
         self.decode_count += 1
@@ -162,26 +148,6 @@ class InferTelemetry:
             if decode:
                 key = "decode_" + name
                 self.moe[key] = self.moe.get(key, 0) + n
-
-    def record_verify(self, wall_s: float, *, proposed: int,
-                      accepted: int, emitted: int) -> None:
-        """One speculative verify step: ``proposed`` drafts scored,
-        ``accepted`` of them kept, ``emitted`` real tokens delivered
-        (accepted + the correction/bonus row, clipped by EOS/max_new).
-        The step folds into the decode series — its wall time and
-        emitted tokens are decode throughput, just > 1 token per
-        dispatch — so ``decode_tokens_per_sec`` stays the honest
-        engine-wide figure with speculation on."""
-        if not self.enabled:
-            return
-        self.decode_count += 1
-        self.decode_tokens += emitted
-        self.spec_verify_steps += 1
-        self.spec_proposed += proposed
-        self.spec_accepted += accepted
-        self.decodes.append({"wall_s": wall_s, "active": emitted})
-        del self.decodes[:-self._MAX_RECORDS]
-        self._emit_verify(wall_s, proposed, accepted, emitted)
 
     def record_sample(self, path: str) -> None:
         """One sampler call that ran the body ``path`` (the engine has
@@ -246,45 +212,6 @@ class InferTelemetry:
             self.deadline_exceeded.get(kind, 0) + 1
         self._emit_deadline(kind)
 
-    def record_prefix_hits(self, n_pages: int, *, tier: str) -> None:
-        """``n_pages`` prefix pages served from ``tier`` (``hbm`` —
-        resident refcount bump; ``dram`` — promoted from the host
-        pool; ``store`` — fetched from the fleet-shared object store).
-        The per-tier split is the whole point of the r23 hierarchy:
-        a flat hit rate cannot say which tier is earning its bytes."""
-        if not self.enabled:
-            return
-        self.tier_hits[tier] = self.tier_hits.get(tier, 0) + n_pages
-        self._emit_prefix_hits(n_pages, tier)
-
-    def record_kv_spill(self, nbytes: int) -> None:
-        """One page demoted out of HBM (``nbytes`` in the spill
-        encoding — int8 codes + scales by default, ~half the model-
-        dtype figure)."""
-        if not self.enabled:
-            return
-        self.kv_spill_bytes += nbytes
-        self._emit_kv_spill(nbytes)
-
-    def record_kv_fetch(self, wall_s: float, *, tier: str) -> None:
-        """One page promoted back into HBM from a lower tier — the
-        latency the admission paid instead of prefill FLOPs."""
-        if not self.enabled:
-            return
-        self.kv_fetches += 1
-        self.kv_fetch_seconds += wall_s
-        self._emit_kv_fetch(wall_s, tier)
-
-    def record_kv_store_evictions(self, n: int) -> None:
-        """``n`` entries LRU-evicted from the capped fleet page store
-        (``RAY_TPU_KV_STORE_CAP``) — the churn signal: a high rate says
-        the cap is below the working set and re-admits are paying
-        suffix prefills for pages the fleet once held."""
-        if not self.enabled or n <= 0:
-            return
-        self.kv_store_evictions += n
-        self._emit_store_evictions(n)
-
     def record_adapter_cache(self, *, hit: bool) -> None:
         """One adapter-resolution outcome: ``hit`` means the tenant's
         factors were already resident in the engine's bank (zero-cost
@@ -309,19 +236,6 @@ class InferTelemetry:
         self.adapter_loads += 1
         self.adapter_load_seconds += wall_s
         self._emit_adapter_load(wall_s, resident)
-
-    def record_tier_occupancy(self, *, hbm: int, dram: int,
-                              store: int) -> None:
-        """Per-tick tier occupancy gauges (pages resident per tier),
-        throttled like the decode emitter — the engine calls this every
-        tick."""
-        if not self.enabled or self._metrics_dead:
-            return
-        now = time.monotonic()
-        if now - self._tier_last < self._EMIT_INTERVAL_S:
-            return
-        self._tier_last = now
-        self._emit_tier_occupancy(hbm, dram, store)
 
     def record_cache_info(self, *, kv_dtype: str, cache_bytes: int,
                           kv_bytes_per_slot: int) -> None:
@@ -369,15 +283,6 @@ class InferTelemetry:
         if self.moe:
             # counts only: a share is the ratio of two of them
             out["moe"] = dict(self.moe)
-        if self.spec_verify_steps:
-            out["spec"] = {
-                "verify_steps": self.spec_verify_steps,
-                "proposed": self.spec_proposed,
-                "accepted": self.spec_accepted,
-                "accept_rate": (self.spec_accepted
-                                / self.spec_proposed
-                                if self.spec_proposed else 0.0),
-            }
         if self.prompt_tokens:
             out["prefix_hit_rate"] = (self.prefix_hit_tokens
                                       / self.prompt_tokens)
@@ -389,14 +294,6 @@ class InferTelemetry:
                 "cache_hit_rate": self.adapter_cache_hits / looked,
                 "loads": self.adapter_loads,
                 "load_seconds": self.adapter_load_seconds,
-            }
-        if self.tier_hits or self.kv_fetches or self.kv_spill_bytes:
-            out["tiers"] = {
-                "hits": dict(self.tier_hits),
-                "spill_bytes": self.kv_spill_bytes,
-                "fetches": self.kv_fetches,
-                "fetch_seconds": self.kv_fetch_seconds,
-                "store_evictions": self.kv_store_evictions,
             }
         if self.ttfts:
             out["ttft_s"] = statistics.median(self.ttfts)
@@ -461,49 +358,6 @@ class InferTelemetry:
                     "infer_deadline_exceeded_total",
                     "requests retired past their TTFT/total deadline",
                     tag_keys=("label", "kind")),
-                "spec_proposed": Counter(
-                    "infer_spec_proposed_total",
-                    "speculative draft tokens proposed",
-                    tag_keys=tags),
-                "spec_accepted": Counter(
-                    "infer_spec_accepted_total",
-                    "speculative draft tokens accepted",
-                    tag_keys=tags),
-                "spec_rate": Gauge(
-                    "infer_spec_accept_rate",
-                    "cumulative speculative accept rate",
-                    tag_keys=tags),
-                # a gauge, not a histogram: draft counts are neither
-                # seconds nor bytes, and the naming lint
-                # (tests/test_metrics_naming.py) holds histograms to
-                # those units — the accept *distribution* lives in
-                # ``stats()["spec"]["k_hist"]``
-                "spec_hist": Gauge(
-                    "infer_spec_accepted_tokens",
-                    "drafts accepted in the most recent verify step",
-                    tag_keys=tags),
-                "prefix_hits": Counter(
-                    "infer_prefix_hits_total",
-                    "prefix pages served, by tier",
-                    tag_keys=("label", "tier")),
-                "kv_spill": Counter(
-                    "infer_kv_spill_bytes_total",
-                    "KV page bytes demoted out of HBM",
-                    tag_keys=tags),
-                "kv_fetch": Histogram(
-                    "infer_kv_fetch_seconds",
-                    "KV page promote latency, by source tier",
-                    boundaries=_STEP_BOUNDARIES,
-                    tag_keys=("label", "tier")),
-                "tier_pages": Gauge(
-                    "infer_kv_tier_pages",
-                    "prefix pages resident, by tier",
-                    tag_keys=("label", "tier")),
-                "store_evictions": Counter(
-                    "infer_kv_store_evictions_total",
-                    "entries LRU-evicted from the capped fleet "
-                    "KV page store",
-                    tag_keys=tags),
                 "adapter_hits": Counter(
                     "serve_adapter_cache_hits_total",
                     "adapter resolutions served from the resident bank",
@@ -561,80 +415,6 @@ class InferTelemetry:
         except Exception:  # noqa: BLE001 — never tax the serve loop
             self._metrics_dead = True
 
-    def _emit_verify(self, wall_s: float, proposed: int,
-                     accepted: int, emitted: int):
-        if self._metrics_dead:
-            return
-        try:
-            metrics = self._metric_objects()
-            if metrics is None:
-                return
-            tags = {"label": self.label}
-            # counters are exact (never throttled — rates must add up);
-            # the gauge/histograms ride the decode emitter's throttle
-            metrics["spec_proposed"].inc(float(proposed), tags=tags)
-            metrics["spec_accepted"].inc(float(accepted), tags=tags)
-            now = time.monotonic()
-            if (self.spec_verify_steps > 1
-                    and now - self._metrics_last
-                    < self._EMIT_INTERVAL_S):
-                return
-            self._metrics_last = now
-            metrics["spec_hist"].set(float(accepted), tags=tags)
-            if self.spec_proposed:
-                metrics["spec_rate"].set(
-                    self.spec_accepted / self.spec_proposed, tags=tags)
-            metrics["step"].observe(wall_s, tags=tags)
-            if wall_s > 0:
-                metrics["tok"].set(emitted / wall_s, tags=tags)
-        except Exception:  # noqa: BLE001 — never tax the serve loop
-            self._metrics_dead = True
-
-    def _emit_prefix_hits(self, n_pages: int, tier: str):
-        if self._metrics_dead:
-            return
-        try:
-            metrics = self._metric_objects()
-            if metrics is not None:
-                metrics["prefix_hits"].inc(
-                    float(n_pages),
-                    tags={"label": self.label, "tier": tier})
-        except Exception:  # noqa: BLE001 — never tax the serve loop
-            self._metrics_dead = True
-
-    def _emit_kv_spill(self, nbytes: int):
-        if self._metrics_dead:
-            return
-        try:
-            metrics = self._metric_objects()
-            if metrics is not None:
-                metrics["kv_spill"].inc(float(nbytes),
-                                        tags={"label": self.label})
-        except Exception:  # noqa: BLE001 — never tax the serve loop
-            self._metrics_dead = True
-
-    def _emit_kv_fetch(self, wall_s: float, tier: str):
-        if self._metrics_dead:
-            return
-        try:
-            metrics = self._metric_objects()
-            if metrics is not None:
-                metrics["kv_fetch"].observe(
-                    wall_s, tags={"label": self.label, "tier": tier})
-        except Exception:  # noqa: BLE001 — never tax the serve loop
-            self._metrics_dead = True
-
-    def _emit_store_evictions(self, n: int):
-        if self._metrics_dead:
-            return
-        try:
-            metrics = self._metric_objects()
-            if metrics is not None:
-                metrics["store_evictions"].inc(
-                    float(n), tags={"label": self.label})
-        except Exception:  # noqa: BLE001 — never tax the serve loop
-            self._metrics_dead = True
-
     def _emit_adapter_cache(self, hit: bool):
         if self._metrics_dead:
             return
@@ -655,18 +435,6 @@ class InferTelemetry:
                 tags = {"label": self.label}
                 metrics["adapter_load"].observe(wall_s, tags=tags)
                 metrics["adapter_resident"].set(resident, tags=tags)
-        except Exception:  # noqa: BLE001 — never tax the serve loop
-            self._metrics_dead = True
-
-    def _emit_tier_occupancy(self, hbm: int, dram: int, store: int):
-        try:
-            metrics = self._metric_objects()
-            if metrics is None:
-                return
-            for tier, n in (("hbm", hbm), ("dram", dram),
-                            ("store", store)):
-                metrics["tier_pages"].set(
-                    n, tags={"label": self.label, "tier": tier})
         except Exception:  # noqa: BLE001 — never tax the serve loop
             self._metrics_dead = True
 

@@ -2,16 +2,16 @@
 
 The per-subsystem aggregates (``telemetry/infer.py``,
 ``telemetry/fleet.py``) explain throughput but not *one* request: a
-p99 TTFT outlier's queue wait, routing pick, tier fetches, handoff
-legs and decode ticks are invisible as a causal timeline.  This module
-is the cross-cutting layer that connects them:
+p99 TTFT outlier's queue wait, routing pick, handoff legs and decode
+ticks are invisible as a causal timeline.  This module is the
+cross-cutting layer that connects them:
 
 - :class:`TraceContext` — ``(trace_id, parent_id, sampled)``, minted
   at ``FleetRouter``/``DisaggRouter`` submission (head-based sampling,
   ``RAY_TPU_TRACE_SAMPLE``) and propagated through every attempt: the
-  routing pick, the engine's queue/prefix-walk/tier-fetch/prefill
-  path, hedge races, cause-tagged failovers, and *across replicas* by
-  riding the :class:`~ray_tpu.inference.kv_cache.KVHandoff` payload
+  routing pick, the engine's queue/prefix-walk/prefill path, hedge
+  races, cause-tagged failovers, and *across replicas* by riding the
+  :class:`~ray_tpu.inference.kv_cache.KVHandoff` payload
   (``to_wire``/``from_wire``).
 - :class:`FlightRecorder` — a bounded per-process ring buffer
   (``RAY_TPU_TRACE_RING`` spans) every span lands in.  Recording is a

@@ -75,17 +75,6 @@ itself).  Current sites:
   re-prefill-from-prompt failover with the held pages and the
   in-flight store object released (the disagg leak audit covers
   both);
-- ``kv.spill`` — the r23 tiered-cache demote legs: fires once on the
-  HBM→host-DRAM spill (before the page's contents leave the device)
-  and once per host-pool overflow on the DRAM→store leg.  A faulted
-  leg simply *forgets* the page — the pre-r23 eviction semantics — so
-  a later request re-prefills it from the prompt; nothing hangs and
-  the leak audit's tier partition stays exact;
-- ``kv.fetch`` — the promote legs: fires per page as admission
-  installs a DRAM/store hit back into HBM, or ``:delay=`` stretches
-  the fetch (a slow object-store read).  A fault stops the install
-  walk at that page and the suffix prefill covers the rest — greedy
-  continuations stay bit-exact vs the unfaulted run;
 - ``serve.adapter_load`` — the r25 multi-tenant adapter-cache miss
   leg: fires as a replica resolves a request's ``model_id`` that is
   not yet resident in its LoRA bank (cache hits never pay the site),

@@ -157,24 +157,33 @@ def test_actor_restart(ray_start_regular):
             self.n += 1
             return self.n
 
+        def pid(self):
+            import os
+            return os.getpid()
+
         def die(self):
             import os
             os._exit(1)
 
     f = Flaky.remote()
     assert ray.get(f.incr.remote()) == 1
+    first = ray.get(f.pid.remote())
     f.die.remote()
     time.sleep(1.0)
-    # restarted: state reset
-    deadline = time.time() + 20
+    # wait for the restart on a call that changes nothing: a call that
+    # times out here may still run later, and a retried ``incr`` that
+    # did would be counted against the state check below
+    deadline = time.time() + 60
     while time.time() < deadline:
         try:
-            assert ray.get(f.incr.remote(), timeout=10) == 1
-            break
+            if ray.get(f.pid.remote(), timeout=10) != first:
+                break
         except Exception:
-            time.sleep(0.5)
+            pass
+        time.sleep(0.5)
     else:
         pytest.fail("actor did not restart")
+    assert ray.get(f.incr.remote(), timeout=30) == 1    # state reset
 
 
 def test_async_actor(ray_start_regular):

@@ -1,7 +1,7 @@
 """Multi-tenant LoRA serving (r25): factor math and the merged-weights
 oracle, the versioned AdapterStore and per-engine LRU registry, the
 engine parity battery (adapter-on output == merged weights, across
-int8 KV, prefix hits, speculation and mixed co-batching), compile
+int8 KV, prefix hits and mixed co-batching), compile
 counters frozen across hot-load and republish, chaos on the load path,
 adapter-only RL publish round-trip, and the two-replica fleet
 acceptance run."""
@@ -311,21 +311,6 @@ def test_adapter_parity_int8_kv(tiny_f32, adapters):
     p = _prompt(8, cfg.vocab_size, seed=4)
     assert eng.generate([p], 8, _greedy("t1")) == ref.generate(
         [p], 8, _greedy())
-
-
-def test_adapter_parity_spec_decode(tiny_f32, adapters):
-    """Speculation is a pure throughput knob under adapters too: the
-    self-drafting verify path emits the same greedy tokens as plain
-    decode on the merged reference."""
-    eng = _engine(tiny_f32, lora=_lcfg(), spec=True, spec_k=3)
-    eng.load_adapter("t1", adapters["t1"], scale=0.5)
-    ref = _engine(tiny_f32, params=_merged(tiny_f32, adapters["t1"]))
-    cfg, _ = tiny_f32
-    # a prompt with a repeated bigram so the n-gram drafter proposes
-    p = _prompt(6, cfg.vocab_size, seed=5) * 2
-    assert eng.generate([p], 10, _greedy("t1")) == ref.generate(
-        [p], 10, _greedy())
-    assert eng.leak_free()
 
 
 def test_adapter_prefix_reuse_and_salt_non_aliasing(tiny_f32, adapters):

@@ -157,7 +157,7 @@ def test_export_import_roundtrip_fp32(tiny_f32, plen):
     assert dec.stats()["imports"] == 1
     assert dec.stats()["compiles"] == {"prefill": 0,
                                        "prefill_cached": 0,
-                                       "decode": 0, "verify": 0}
+                                       "decode": 0}
     for eng in (pre, dec):
         sched = eng.scheduler
         assert not sched.active and not sched.waiting
@@ -205,7 +205,7 @@ def test_export_import_roundtrip_int8(tiny_f32):
     assert _drain(dec, out) == want
     assert dec.stats()["compiles"] == {"prefill": 0,
                                        "prefill_cached": 0,
-                                       "decode": 0, "verify": 0}
+                                       "decode": 0}
     # dtype mismatch is refused loudly — the contents would be
     # reinterpreted, not converted
     with pytest.raises(ValueError, match="kv_dtype"):
@@ -357,8 +357,7 @@ def test_disagg_acceptance(tiny_f32):
     # by the reference replica)
     for r in router.replicas():
         assert r.engine.stats()["compiles"] == {
-            "prefill": 0, "prefill_cached": 0, "decode": 0,
-            "verify": 0}
+            "prefill": 0, "prefill_cached": 0, "decode": 0}
     # fleet-wide leak audit, including the handoff store
     assert router.leak_free()
     assert router.store.in_flight == 0
@@ -552,8 +551,7 @@ def test_prefill_death_after_export_acceptance(tiny_f32):
     assert len(router.replicas("prefill")) == 2
     for r in router.replicas():
         assert r.engine.stats()["compiles"] == {
-            "prefill": 0, "prefill_cached": 0, "decode": 0,
-            "verify": 0}
+            "prefill": 0, "prefill_cached": 0, "decode": 0}
     assert router.quiesce() and router.leak_free()
 
 

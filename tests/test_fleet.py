@@ -127,16 +127,6 @@ class StubReplica:
     def prefix_digest(self):
         return self._digest
 
-    def tier_hits(self, chain_hashes):
-        # replica protocol (r23): consecutive leading pages in the
-        # digest count as HBM-resident; the stub has no DRAM pool
-        n_hbm = 0
-        for h in chain_hashes:
-            if h not in self._digest:
-                break
-            n_hbm += 1
-        return n_hbm, 0
-
     def drain(self):
         self.draining = True
 
@@ -310,8 +300,7 @@ def test_fleet_failover_mid_stream_chaos(tiny_f32):
     # zero steady-state recompiles: replacements compiled NOTHING
     for r in router.replicas():
         assert r.engine.stats()["compiles"] == {
-            "prefill": 0, "prefill_cached": 0, "decode": 0,
-            "verify": 0}
+            "prefill": 0, "prefill_cached": 0, "decode": 0}
     # fleet-wide leak audit (dead replicas were reaped at failover)
     assert router.leak_free()
     for r in reps:
@@ -908,8 +897,7 @@ def test_gray_failure_acceptance(tiny_f32):
     for router, reps in ((router_on, reps_on), (router_off, reps_off)):
         for r in router.replicas():
             assert r.engine.stats()["compiles"] == {
-                "prefill": 0, "prefill_cached": 0, "decode": 0,
-                "verify": 0}
+                "prefill": 0, "prefill_cached": 0, "decode": 0}
         assert router.leak_free()
         assert all(r.leak_free() for r in reps)
     router_on.close()

@@ -188,7 +188,7 @@ def test_int8_kv_cache_parity_and_bytes(tiny_f32):
     assert outs[q8][1] == outs[base][1]
     assert q8.stats()["compiles"] == {"prefill": 1,
                                       "prefill_cached": 0,
-                                      "decode": 1, "verify": 0}
+                                      "decode": 1}
 
     # ragged co-batching stays invisible under quantization too
     p2 = _prompt(14, cfg.vocab_size, seed=12)
@@ -261,27 +261,16 @@ def test_scheduler_refcount_fuzz():
     level (no compiled steps — register_prefix is called as the engine
     would, after 'prefill'): no page freed while referenced, refcounts
     exactly match the active references, every page always in exactly
-    one of {free, idle, allocated}, and nothing leaks at drain.
-
-    r23 rides the same 300 ops: evictions demote through a host pool
-    into a store (the engine's spill wiring, with a stub payload), and
-    the tier inventory must partition exactly every step — the pool
-    never holds a hash that is also resident, never exceeds capacity,
-    and no store fetch is left in flight."""
+    one of {free, idle, allocated}, and nothing leaks at drain."""
     import collections
 
-    from ray_tpu.inference import (HostPagePool, KVPageStore, Request,
-                                   SamplingParams, SlotScheduler)
+    from ray_tpu.inference import (Request, SamplingParams,
+                                   SlotScheduler)
     rng = np.random.RandomState(42)
     ps = 8
     sched = SlotScheduler(slots=3, page_size=ps, num_pages=24,
                           max_pages_per_slot=8, prefix=True)
     alloc = sched.allocator
-    store = KVPageStore(use_object_store=False)
-    pool = HostPagePool(3, store=store)
-    stub = {"fmt": "model", "k": np.zeros(1, np.float32),
-            "v": np.zeros(1, np.float32)}
-    alloc.spill_hook = lambda page, h: pool.put((h, 0), dict(stub))
     # a small pool of shared prefixes drives real hit/shared-page load
     prefixes = [list(rng.randint(0, 97, 2 * ps)) for _ in range(3)]
     rid = 0
@@ -300,8 +289,6 @@ def test_scheduler_refcount_fuzz():
             req = sched.try_admit()
             if req is not None:
                 sched.register_prefix(req)     # "prefill finished"
-                for h in req.chain_hashes[req.n_hit_pages:]:
-                    pool.discard((h, 0))       # engine _register_prefix
         elif sched.active:
             slot = list(sched.active)[rng.randint(len(sched.active))]
             sched.retire(slot)
@@ -325,18 +312,11 @@ def test_scheduler_refcount_fuzz():
         # idle pages are exactly the registered refcount-0 pages
         for p in idle:
             assert sched.prefix_index.has(p)
-        # tier inventory (r23): the host pool respects capacity, holds
-        # no hash that is also HBM-resident (demoted = in exactly one
-        # local tier), and no store fetch dangles
-        assert len(pool) <= pool.capacity
-        resident = sched.prefix_index.digest()
-        assert not any(h in resident for h, _ in pool._entries)
-        assert store.in_flight == 0
     while sched.active:
         sched.retire(next(iter(sched.active)))
     assert not alloc._refcount
     assert alloc.free_count == 23              # nothing leaked
-    assert pool.spills > 0 and store.puts > 0  # the tiers saw traffic
+    assert alloc.evictions > 0      # pressure reached the idle pool
 
 
 def test_prefix_hit_decode_parity(tiny_f32):
@@ -416,7 +396,7 @@ def test_prefix_mixed_traffic_zero_recompiles(tiny_f32):
             out[r].append(tok)
     st = engine.stats()
     assert st["compiles"] == {"prefill": 1, "prefill_cached": 1,
-                              "decode": 1, "verify": 0}
+                              "decode": 1}
     assert st["prefix"]["requests_hit"] == 2
     assert st["prefix"]["hit_tokens"] == 2 * 32
     assert out[rids[1]] == solo
@@ -577,7 +557,7 @@ def test_zero_steady_state_recompiles(tiny_f32):
         engine.step()
     stats = engine.stats()
     assert stats["compiles"] == {"prefill": 1, "prefill_cached": 0,
-                                 "decode": 1, "verify": 0}
+                                 "decode": 1}
     assert stats["hits"]["prefill"] == 3
     assert stats["hits"]["decode"] > 0
 
@@ -1420,8 +1400,7 @@ def _step_executable(engine, kind):
 
 
 @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached",
-                                  "verify"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached"])
 def test_step_never_materialises_a_layers_pool(tiny_f32, kind, kv_dtype):
     """The structure that keeps the per-layer pool copy from coming
     back: in every serve executable the stacked cache arrays are
@@ -1501,6 +1480,16 @@ def _reference_kv(cfg, params, tokens):
     return np.stack(ks), np.stack(vs)
 
 
+def _quantize_rows(x):
+    """The plain writer's int8 rows: per-vector symmetric codes,
+    ``scale = amax / 127`` over the last axis, rounded to nearest."""
+    x = np.asarray(x, np.float32)
+    scale = (np.abs(x).max(axis=-1) / 127.0).astype(np.float32)
+    safe = np.where(scale == 0.0, 1.0, scale)
+    codes = np.rint(x / safe[..., None]).clip(-127, 127)
+    return codes.astype(np.int8), scale
+
+
 @pytest.mark.parametrize("lora", [False, True], ids=["base", "lora"])
 @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
 def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
@@ -1508,14 +1497,13 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
     that places each cached token's post-RoPE K and V (int8: codes and
     scales) at ``[layer, page, ..., position % page_size]`` (the pool's
     page offset is its minor dimension) — after a cold
-    prefill, a prefix hit with a cached-suffix prefill, decode ticks
-    beside an inactive slot, and a speculative verify that rejects a
-    tail; pages no request owns stay untouched; and the emitted tokens
-    are the teacher-forced forward's."""
+    prefill, a prefix hit with a cached-suffix prefill and decode ticks
+    beside an inactive slot; pages no request owns stay untouched; and
+    the emitted tokens are the teacher-forced forward's."""
     import jax
 
     from ray_tpu.inference import SamplingParams
-    from ray_tpu.inference.kv_cache import GARBAGE_PAGE, _quantize_page
+    from ray_tpu.inference.kv_cache import GARBAGE_PAGE
     cfg, params = tiny_f32
     kw, model_id, ref_params = {}, None, params
     if lora:
@@ -1534,9 +1522,8 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
     ps = engine.page_size
 
     # the script: A prefills cold; B shares A's first two pages (a
-    # prefix hit, so only its suffix prefills); C drafts from its own
-    # repeated motif and has drafts rejected; the fourth slot stays
-    # inactive throughout
+    # prefix hit, so only its suffix prefills); C decodes beside
+    # them; the fourth slot stays inactive throughout
     prompt_a = _prompt(37, cfg.vocab_size, seed=31)
     prompt_b = prompt_a[:32] + _prompt(9, cfg.vocab_size, seed=32)
     prompt_c = _prompt(6, cfg.vocab_size, seed=33) * 3
@@ -1544,18 +1531,16 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
     engine.step()
     rids.append(engine.submit(prompt_b, max_new_tokens=24,
                               sampling=greedy))
-    rids.append(engine.submit(
-        prompt_c, max_new_tokens=24,
-        sampling=SamplingParams(temperature=0.0, model_id=model_id,
-                                spec=True, spec_k=4)))
+    rids.append(engine.submit(prompt_c, max_new_tokens=24,
+                              sampling=greedy))
     for _ in range(6):
         engine.step()
+    engine._level()     # the decode in flight has written its rows
     reqs = [engine._requests[r] for r in rids]
     assert not any(r.done for r in reqs)
     st = engine.stats()
     assert st["prefix"]["hit_tokens"] == 32
     assert st["compiles"]["prefill_cached"] == 1
-    assert st["spec"]["proposed"] > st["spec"]["accepted"]  # a rejected tail
     assert st["free_slots"] == 1                            # an idle slot
 
     names = ("k", "v", "k_scale", "v_scale")[:len(engine.cache.state)]
@@ -1584,9 +1569,9 @@ def test_cache_contents_match_plain_writer(tiny_f32, kv_dtype, lora):
                 want["v"][:, page, ..., off] = v[:, t]
             else:
                 (want["k"][:, page, ..., off],
-                 want["k_scale"][:, page, ..., off]) = _quantize_page(k[:, t])
+                 want["k_scale"][:, page, ..., off]) = _quantize_rows(k[:, t])
                 (want["v"][:, page, ..., off],
-                 want["v_scale"][:, page, ..., off]) = _quantize_page(v[:, t])
+                 want["v_scale"][:, page, ..., off]) = _quantize_rows(v[:, t])
     assert live.sum() == sum(engine.scheduler.lengths) - 32   # shared pages
     # [L, page, offset, ...] views, for the [page, offset] mask
     got, want = ({n: np.moveaxis(a, -1, 2) for n, a in d.items()}
@@ -1805,10 +1790,10 @@ def _ahead_plain(cfg, sampling=None, **submit_kw):
 
 
 def _ahead_case(name, cfg, params):
-    """-> (engine kwargs, script, what the ahead engine's
-    ``ahead_share`` must read, a check of the two engines or None)."""
+    """-> (engine kwargs, script, a check of the two engines or
+    None)."""
     from ray_tpu.inference import SamplingParams
-    kwargs, share, check = {}, 1.0, None
+    kwargs, check = {}, None
     if name == "greedy":
         script = _ahead_plain(cfg)
     elif name == "temperature":
@@ -1966,26 +1951,6 @@ def _ahead_case(name, cfg, params):
             assert streams[0][:4] == unswapped[0][:4]
             assert streams[0] != unswapped[0]
             assert ahead.param_version == 7
-    elif name == "speculating_slot":
-        # a slot that may draft makes its ticks synchronous: exact, and
-        # the counter shows it; the plain request outlives it, and the
-        # engine runs ahead again
-        share = None
-
-        def submit(engine):
-            motif = _prompt(6, cfg.vocab_size, seed=3) * 4
-            engine.submit(motif, max_new_tokens=6,
-                          sampling=SamplingParams(spec=True, spec_k=4))
-            engine.submit(_prompt(9, cfg.vocab_size), max_new_tokens=16)
-        script = _at_start(submit)
-
-        def check(ahead, level, streams):
-            solo = _make_engine(cfg, params).generate(
-                [_prompt(9, cfg.vocab_size)], max_new_tokens=16)[0]
-            assert [t for t, *_ in streams[1]] == solo
-            seen = ahead.telemetry.summary()["decode"]
-            assert 0.0 < seen["ahead_share"] < 1.0
-            assert ahead.stats()["spec"]["proposed"] > 0
     elif name == "decode_fault_then_resume":
         # the first decode's dispatch fails after the prefills of its
         # tick were dispatched: their first tokens, never fetched, are
@@ -2022,21 +1987,21 @@ def _ahead_case(name, cfg, params):
                 rtol=2e-4, atol=2e-4)
     else:
         raise KeyError(name)
-    return kwargs, script, share, check
+    return kwargs, script, check
 
 
 _AHEAD_CASES = (
     "greedy", "temperature", "top_k_top_p", "eos_mid_stream",
     "max_new_1", "max_new_2", "cancel_in_flight", "deadline_in_flight",
     "prefix_hits", "prefix_hits_int8", "int8_cache", "lora_bank",
-    "hold_pages_export", "set_params_between_ticks", "speculating_slot",
+    "hold_pages_export", "set_params_between_ticks",
     "decode_fault_then_resume", "debug_logits", "latent_row")
 
 
 @pytest.mark.parametrize("case", _AHEAD_CASES)
 def test_running_ahead_matches_the_synchronous_tick(tiny_f32, case):
     cfg, params = tiny_f32
-    kwargs, script, share, check = _ahead_case(case, cfg, params)
+    kwargs, script, check = _ahead_case(case, cfg, params)
     cfg, params = kwargs.pop("model", (cfg, params))
     ahead = _make_engine(cfg, params, telemetry=True, **kwargs)
     level = _make_engine(cfg, params, telemetry=True, **kwargs)
@@ -2060,8 +2025,8 @@ def test_running_ahead_matches_the_synchronous_tick(tiny_f32, case):
                 == sched.allocator.num_pages - 1)
         assert all(r.in_flight == 0 for r in engine._requests.values())
     decode = ahead.telemetry.summary().get("decode")
-    if share is not None and decode is not None:
-        assert decode["ahead_share"] == share
+    if decode is not None:      # every decode was left in flight
+        assert decode["ahead_share"] == 1.0
         assert decode["dispatches"] == in_flight
 
 

@@ -214,9 +214,6 @@ def test_rows_of_no_sequence_pick_nothing(tiny):
 
 @pytest.mark.parametrize("feature, kwargs", [
     ("int8", {"kv_dtype": "int8"}),
-    ("spill tiers", {"host_pages": 4}),
-    ("spill tiers", {"store": True}),
-    ("speculative", {"spec": True}),
     ("LoRA", {"lora": True}),
 ])
 def test_what_is_written_over_k_and_v_refuses_a_latent_row(tiny, feature,
@@ -226,13 +223,9 @@ def test_what_is_written_over_k_and_v_refuses_a_latent_row(tiny, feature,
         _engine(cfg, params, **kwargs)
 
 
-def test_handoff_and_per_request_speculation_refuse_a_latent_row(tiny):
-    from ray_tpu.inference.sampling import SamplingParams
+def test_handoff_refuses_a_latent_row(tiny):
     cfg, params = tiny
     engine = _engine(cfg, params)
-    with pytest.raises(NotImplementedError, match="speculative"):
-        engine.submit(_prompt(5, 0), max_new_tokens=2,
-                      sampling=SamplingParams(spec=True, spec_k=2))
     with pytest.raises(NotImplementedError, match="KVHandoff"):
         engine.export_request(0)
     with pytest.raises(NotImplementedError, match="KVHandoff"):

@@ -156,8 +156,9 @@ def test_autoscaler_up_and_down(ray_start_cluster):
 
 
 _ATTACH_SCRIPT = """
+import sys
 import ray_tpu
-ray_tpu.init(address="auto")
+ray_tpu.init(address=sys.argv[1])
 @ray_tpu.remote
 def double(v):
     return v * 2
@@ -173,13 +174,16 @@ ray_tpu.shutdown()
 
 
 def test_attach_second_driver(ray_start_regular):
-    """init(address='auto') joins the running cluster as another driver:
+    """init(address=...) joins the running cluster as another driver:
     shared named actors, tasks on cluster resources, shm objects
-    (parity: ray.init(address=...) connect-to-existing)."""
+    (parity: ray.init(address=...) connect-to-existing).  The address
+    is this test's own session: ``"auto"`` takes the host's newest, and
+    under ``-n 6`` that is another worker's."""
     import subprocess
     import sys
 
     import ray_tpu
+    from ray_tpu._private.worker import global_node
 
     @ray_tpu.remote
     class KV:
@@ -197,7 +201,8 @@ def test_attach_second_driver(ray_start_regular):
     ray_tpu.get(kv.put.remote("x", 21), timeout=60)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    p = subprocess.run([sys.executable, "-c", _ATTACH_SCRIPT], env=env,
+    p = subprocess.run([sys.executable, "-c", _ATTACH_SCRIPT,
+                        global_node().session_dir], env=env,
                        capture_output=True, text=True, timeout=120,
                        cwd=REPO)
     assert p.returncode == 0, p.stderr

@@ -555,12 +555,12 @@ def test_rl_kill_recovery_acceptance(tmp_path, tiny_rl, rl_learner_fns):
     # engine compiled NOTHING — every executable came from the shared
     # cache (restart cost is construction, not XLA)
     assert rec["restart_compiles"] == [
-        {"prefill": 0, "prefill_cached": 0, "decode": 0, "verify": 0}]
+        {"prefill": 0, "prefill_cached": 0, "decode": 0}]
     # steady state after recovery: the surviving engines also show no
     # new compiles vs the cache (all compile keys pre-existed)
     for st in rec["engine_stats"]:
         assert st["compiles"] == {"prefill": 0, "prefill_cached": 0,
-                                  "decode": 0, "verify": 0}
+                                  "decode": 0}
     # (a) recovery quality: the loop still learns — improvement over
     # its own first third AND final-third mean within tolerance of the
     # uninterrupted run (trajectories diverge after the kill by

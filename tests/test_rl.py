@@ -188,7 +188,7 @@ def test_weight_publication_zero_recompiles_and_donation(tiny_rl):
     engine.generate([prompt], max_new_tokens=4)
     compiles0 = dict(engine.compile_counts)
     assert compiles0 == {"prefill": 1, "prefill_cached": 0,
-                         "decode": 1, "verify": 0}
+                         "decode": 1}
     assert engine.stats()["param_version"] == 0
 
     host = jax.tree.map(np.asarray, params)

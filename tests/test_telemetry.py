@@ -510,57 +510,6 @@ def test_infer_telemetry_deadline_counter():
     assert off.summary() == {"enabled": False}
 
 
-def test_infer_telemetry_spec_summary():
-    """r21: verify steps fold into the decode series (wall + emitted
-    tokens ARE decode throughput, just > 1 token per dispatch) and the
-    draft accounting surfaces as the ``spec`` summary block — absent
-    entirely when speculation never ran."""
-    from ray_tpu.telemetry import InferTelemetry
-    from ray_tpu.telemetry.config import TelemetryConfig
-
-    tel = InferTelemetry(config=TelemetryConfig(enabled=True))
-    assert "spec" not in tel.summary()
-    tel.record_decode(0.01, active=1)
-    tel.record_verify(0.01, proposed=4, accepted=4, emitted=5)
-    tel.record_verify(0.01, proposed=4, accepted=0, emitted=1)
-    out = tel.summary()
-    assert out["spec"] == {"verify_steps": 2, "proposed": 8,
-                           "accepted": 4, "accept_rate": 0.5}
-    assert out["decode_steps"] == 3          # verifies count as steps
-    assert out["decode_tokens"] == 1 + 5 + 1
-    off = InferTelemetry(config=TelemetryConfig(enabled=False))
-    off.record_verify(0.01, proposed=4, accepted=2, emitted=3)
-    assert off.summary() == {"enabled": False}
-
-
-def test_infer_telemetry_tier_summary():
-    """r23: per-tier prefix hits plus the spill/fetch legs fold into a
-    ``tiers`` summary block — absent entirely when tiering never
-    moved a page."""
-    from ray_tpu.telemetry import InferTelemetry
-    from ray_tpu.telemetry.config import TelemetryConfig
-
-    tel = InferTelemetry(config=TelemetryConfig(enabled=True))
-    assert "tiers" not in tel.summary()
-    tel.record_prefix_hits(2, tier="hbm")
-    tel.record_prefix_hits(1, tier="dram")
-    tel.record_prefix_hits(3, tier="store")
-    tel.record_kv_spill(4096)
-    tel.record_kv_fetch(0.002, tier="dram")
-    tel.record_kv_fetch(0.004, tier="store")
-    tel.record_tier_occupancy(hbm=5, dram=2, store=7)
-    out = tel.summary()["tiers"]
-    assert out["hits"] == {"hbm": 2, "dram": 1, "store": 3}
-    assert out["spill_bytes"] == 4096
-    assert out["fetches"] == 2
-    assert abs(out["fetch_seconds"] - 0.006) < 1e-9
-    off = InferTelemetry(config=TelemetryConfig(enabled=False))
-    off.record_prefix_hits(2, tier="hbm")
-    off.record_kv_spill(4096)
-    off.record_kv_fetch(0.002, tier="dram")
-    assert off.summary() == {"enabled": False}
-
-
 def test_infer_telemetry_adapter_summary():
     """r25: adapter-cache lookups and load walls fold into an
     ``adapters`` summary block — absent when no tenant ever looked
@@ -701,12 +650,6 @@ def test_dashboard_timeline_and_metrics_show_train_steps(
     RLTelemetry(config=on).record_actor_restart()
     infer = InferTelemetry(config=on)
     infer.record_deadline_exceeded(kind="ttft")
-    infer.record_verify(0.002, proposed=4, accepted=3, emitted=4)
-    infer.record_prefix_hits(2, tier="hbm")
-    infer.record_prefix_hits(1, tier="store")
-    infer.record_kv_spill(4096)
-    infer.record_kv_fetch(0.002, tier="dram")
-    infer.record_tier_occupancy(hbm=5, dram=2, store=7)
     infer.record_adapter_cache(hit=True)
     infer.record_adapter_cache(hit=False)
     infer.record_adapter_load(0.01, resident=2)
@@ -771,19 +714,6 @@ def test_dashboard_timeline_and_metrics_show_train_steps(
     assert 'pool="prefill"' in text and 'pool="decode"' in text
     assert "user_histogram_serve_ttft_seconds_bucket" in text
     assert 'mode="disagg"' in text
-    # r21 speculative-decoding series: exact proposal/accept counters,
-    # the cumulative accept-rate gauge, accepted-per-verify histogram
-    assert "infer_spec_proposed_total" in text
-    assert "infer_spec_accepted_total" in text
-    assert "infer_spec_accept_rate" in text
-    assert "user_histogram_infer_spec_accepted_tokens_bucket" in text
-    # r23 tiered-KV series: per-tier prefix-hit counter, spill-bytes
-    # counter, fetch-latency histogram, tier-occupancy gauge
-    assert "infer_prefix_hits_total" in text
-    assert "infer_kv_spill_bytes_total" in text
-    assert "user_histogram_infer_kv_fetch_seconds_bucket" in text
-    assert "infer_kv_tier_pages" in text
-    assert 'tier="hbm"' in text and 'tier="dram"' in text
     # r25 multi-tenant adapter series: cache hit/miss counters, the
     # load-wall histogram, the resident-adapter gauge
     assert "serve_adapter_cache_hits_total" in text

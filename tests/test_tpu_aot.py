@@ -241,13 +241,12 @@ def test_decode_write_compiles_for_v5e(v5e, cell, kv_dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached",
-                                  "verify"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_cached"])
 @pytest.mark.parametrize("cell", sorted(_SERVE_CELLS))
 def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, cell, kind):
     """The TPU compiler's verdict on the engine's cache handling, at the
     two serve cells' own geometry (GPT-2 124M: 12 heads, 128 slots, 1025
-    pages; GPT-2 large: 20 heads, 64 slots, 513 pages) and for all four
+    pages; GPT-2 large: 20 heads, 64 slots, 513 pages) and for all three
     serve executables: the stacked K and V come in, are written and
     read, and go out in ONE layout — no ``copy`` of them, nothing that
     produces a layer's ``[pages, H, D, page]`` pool.  (A row-granular
@@ -287,9 +286,9 @@ def test_serve_step_keeps_the_cache_in_place_on_v5e(v5e, cell, kind):
                 spec((slots, mp), i32))
     elif kind == "prefill":
         tail = (spec((1, 256), i32), spec((), i32), spec((mp,), i32))
-    else:       # a cached suffix's bucket, a verify's draft bucket
-        tail = (spec((1, 64 if kind == "prefill_cached" else 8), i32),
-                spec((), i32), spec((), i32), spec((mp,), i32))
+    else:       # a cached suffix's bucket
+        tail = (spec((1, 64), i32), spec((), i32), spec((), i32),
+                spec((mp,), i32))
     with substrate.compile_for_tpu():
         compiled = fn.lower(params, *state, *tail).compile()
     hlo = compiled.as_text()

@@ -6,9 +6,7 @@ through routing, queueing, the prefix walk, prefill, both handoff legs
 hedge races — the span tree must be complete and gap-free in every
 case.  Anomalies (injected chaos faults here) dump the ring as a
 loadable Perfetto JSON.  The steady-state decode overhead of tracing
-is budgeted under 1% by decomposition (the r09 telemetry pattern), and
-the r24 ``KVPageStore`` byte cap evicts LRU without ever losing a
-pinned fetch or an exact greedy continuation.
+is budgeted under 1% by decomposition (the r09 telemetry pattern).
 """
 
 import json
@@ -309,7 +307,6 @@ def test_unsampled_records_nothing_anomaly_still_lands(tiny_f32,
 
 
 def test_trace_env_knobs(monkeypatch):
-    from ray_tpu.inference.config import infer_config
     from ray_tpu.telemetry import trace
     monkeypatch.setenv("RAY_TPU_TRACE_SAMPLE", "0.25")
     monkeypatch.setenv("RAY_TPU_TRACE_RING", "128")
@@ -326,13 +323,6 @@ def test_trace_env_knobs(monkeypatch):
     assert cfg.sample == 1.0 and cfg.ring == 4096
     monkeypatch.setenv("RAY_TPU_TRACE_SAMPLE", "7")
     assert trace.trace_config(refresh=True).sample == 1.0
-    # the store byte-cap knob (satellite: RAY_TPU_KV_STORE_CAP)
-    monkeypatch.setenv("RAY_TPU_KV_STORE_CAP", "1048576")
-    assert infer_config(refresh=True).store_cap == 1048576
-    monkeypatch.setenv("RAY_TPU_KV_STORE_CAP", "-1")
-    assert infer_config(refresh=True).store_cap == 0
-    monkeypatch.delenv("RAY_TPU_KV_STORE_CAP")
-    assert infer_config(refresh=True).store_cap == 0
 
 
 def test_ring_is_bounded_and_counts_drops(monkeypatch):
@@ -431,77 +421,3 @@ def test_trace_overhead_under_one_percent(tiny_f32):
         f"per-tick tracing cost {per_tick * 1e6:.1f}µs is "
         f"{overhead:.2%} of the {steady * 1e3:.2f}ms steady decode "
         "step — exceeds the 1% budget")
-
-
-# ------------------------------------------------------------- store cap
-def test_kv_store_cap_lru_pins_and_counters():
-    """Unit: over-cap puts evict least-recently-used unpinned entries;
-    a checked-out entry is pinned (the cap overshoots rather than drop
-    live data); counters partition exactly."""
-    from ray_tpu.inference import KVPageStore
-    from ray_tpu.inference.kv_cache import spill_entry_bytes
-
-    def entry():
-        return {"fmt": "model", "k": np.zeros(64, np.float32),
-                "v": np.zeros(64, np.float32)}
-
-    nb = spill_entry_bytes(entry())
-    store = KVPageStore(use_object_store=False, capacity_bytes=2 * nb)
-    store.put((b"a", 0), entry())
-    store.put((b"b", 0), entry())
-    assert len(store) == 2 and store.evictions == 0
-    assert store.checkout((b"a", 0)) is not None   # a: pinned + recent
-    store.put((b"c", 0), entry())                  # evicts b (LRU)
-    assert (b"b", 0) not in store and (b"a", 0) in store
-    assert store.evictions == 1 and store.bytes_evicted == nb
-    store.checkin((b"a", 0))
-    store.put((b"d", 0), entry())                  # a is now evictable
-    assert (b"a", 0) not in store
-    assert sorted(k for k, _ in store._entries) == [b"c", b"d"]
-    assert store.evictions == 2 and store.bytes_evicted == 2 * nb
-    # pin BOTH residents: nothing evictable -> the cap overshoots
-    assert store.checkout((b"c", 0)) is not None
-    assert store.checkout((b"d", 0)) is not None
-    store.put((b"e", 0), entry())
-    assert len(store) == 3 and store.evictions == 2
-    assert store.bytes == 3 * nb > store.capacity_bytes
-    store.checkin((b"c", 0))
-    store.checkin((b"d", 0))
-    assert store.in_flight == 0
-    st = store.stats()
-    assert st["capacity_bytes"] == 2 * nb and st["evictions"] == 2
-
-
-def test_kv_store_cap_engine_degrades_to_suffix_prefill(tiny_f32):
-    """Engine-level: a byte-capped shared store under spill pressure
-    evicts the shared prefix; a re-admitting engine simply misses the
-    store and prefills the suffix — greedy continuations stay EXACT,
-    the eviction counter reaches telemetry, and the tier/leak audits
-    partition clean."""
-    from ray_tpu.inference import KVPageStore
-    cfg, _ = tiny_f32
-    shared = _prompt(40, cfg.vocab_size, seed=9)
-    cold = _make_engine(tiny_f32, num_pages=9, spill_dtype="model")
-    ref = cold.generate([shared + [1, 2]], max_new_tokens=6)[0]
-    # cap of 1 byte: every put evicts everything evictable first, so
-    # the shared prefix's page chain can never sit whole in the store
-    store = KVPageStore(use_object_store=False, capacity_bytes=1)
-    a = _make_engine(tiny_f32, num_pages=9, host_pages=0, store=store,
-                     spill_dtype="model", telemetry=True)
-    assert a.generate([shared + [1, 2]], max_new_tokens=6)[0] == ref
-    for i in range(3):                     # eviction pressure
-        a.generate([_prompt(60, cfg.vocab_size, seed=100 + i)],
-                   max_new_tokens=4)
-    assert store.evictions > 0
-    assert len(store) <= 1                 # the cap held
-    # the eviction counter reached telemetry (scraped by step())
-    assert a.telemetry.summary()["tiers"]["store_evictions"] > 0
-    # re-admission on a second engine: store-evicted prefix = cold
-    # suffix prefill, continuation exact
-    b = _make_engine(tiny_f32, num_pages=9, host_pages=0, store=store,
-                     spill_dtype="model")
-    assert b.generate([shared + [1, 2]], max_new_tokens=6)[0] == ref
-    st = b.stats()["tiers"]
-    assert st["hits"]["store"] < 2         # the full chain was gone
-    assert a.leak_free() and b.leak_free()
-    assert store.in_flight == 0
