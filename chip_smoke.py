@@ -64,6 +64,13 @@ REAL = {
     "train_kwargs": {"remat": False, "unroll_layers": True,
                      "ce_chunk": -1},
     "batch_per_chip": 24, "seq": 1024,
+    # the routed 8k train cell's kernels (train-mellum2-12b-a2.5b-ep4-
+    # b2x8192): one K/V head with its 8 query heads of one sequence (a
+    # K/V head's work is independent of the others'), and one expert
+    # layer at the cell's rows
+    "routed": {"seq": 8192, "group": 8, "head_dim": 128, "window": 1024,
+               "rows": 16384, "d_model": 2304, "expert_ff": 896,
+               "held": 16, "experts": 64, "top_k": 8},
     # (prompt length, new tokens); page 128, buckets 32..1024
     "shared_prefix": 288,
     "requests": {"r0": (20, 48), "r1": (100, 64), "r2": (300, 32),
@@ -84,6 +91,9 @@ TOY = {
     "train_kwargs": {"remat": False, "unroll_layers": True,
                      "ce_chunk": -1},
     "batch_per_chip": 2, "seq": 256,
+    "routed": {"seq": 256, "group": 2, "head_dim": 128, "window": 96,
+               "rows": 128, "d_model": 128, "expert_ff": 128,
+               "held": 2, "experts": 8, "top_k": 2},
     "shared_prefix": 288,
     "requests": {"r0": (20, 8), "r1": (100, 8), "r2": (300, 6),
                  "r3": (40, 8), "r4": (340, 6), "r5": (50, 8),
@@ -314,6 +324,74 @@ def kernel_parity(config: dict) -> dict:
             {"o": TOL_OUT, "dq": TOL_GRAD, "dk": TOL_GRAD, "dv": TOL_GRAD},
             causal_coverage=A.train_causal_coverage(S, H, D))
         del q, k, v, w
+
+    # -- the routed 8k cell's attention: window and full layers, 8 query
+    # heads on a K/V head, fused YaRN rope, the single-head schedule ------
+    r = config["routed"]
+    Sr, G, Dr = r["seq"], r["group"], r["head_dim"]
+    yarn = A.Rope(theta=500000.0, factor=16.0, original_max=8192,
+                  attention_factor=1.2772588722239782)
+    pos = jnp.arange(Sr)
+    for window in (r["window"], None):
+        rope = yarn if window is None else 500000.0
+        q, w = rand((1, Sr, G, Dr)), rand((1, Sr, G, Dr))
+        k, v = rand((1, Sr, 1, Dr)), rand((1, Sr, 1, Dr))
+        hook = A.make_flash_attention_fn(window=window, kv_heads=1,
+                                         rope=rope)
+        o, g = vjp_np(lambda q, k, v: hook(q, k, v, positions=pos),
+                      (q, k, v), w)
+        o_ref, g_ref = vjp_np(lambda q, k, v: A.xla_attention(
+            A.rope_rotate(q, pos, rope), A.rope_rotate(k, pos, rope), v,
+            causal=True, window=window), (up(q), up(k), up(v)), up(w))
+        row(f"attn/flash {'window' if window else 'full'}+group+rope "
+            "fwd+bwd", [1, Sr, G, 1, Dr],
+            {"o": _rel_err(o, o_ref), "dq": _rel_err(g[0], g_ref[0]),
+             "dk": _rel_err(g[1], g_ref[1]), "dv": _rel_err(g[2], g_ref[2])},
+            {"o": TOL_OUT, "dq": TOL_GRAD, "dk": TOL_GRAD, "dv": TOL_GRAD},
+            coverage=hook.coverage(Sr, G, Dr))
+        del q, k, v, w, o_ref, g_ref
+
+    # -- its expert layer, differentiated: grouped products over the
+    # sorted held picks against every held expert over every row ---------
+    from ray_tpu.parallel import moe
+    T, dm, fe = r["rows"], r["d_model"], r["expert_ff"]
+    held, E, topk = tuple(range(r["held"])), r["experts"], r["top_k"]
+    x, ct = rand((T, dm)), rand((T, dm))
+    router = rand((dm, E), 3 * dm ** -0.5)
+    gate, upm = (rand((len(held), dm, fe), dm ** -0.5) for _ in range(2))
+    down = rand((len(held), fe, dm), fe ** -0.5)
+
+    def routed(x, router, gate, upm, down):
+        return moe.dropless_moe(
+            x, router, jnp.zeros((E,), f32), gate, upm, down, held=held,
+            n_routed=E, top_k=topk, scale=1.0, renormalise=True)
+
+    def dense(x, router, gate, upm, down):
+        p = jax.nn.softmax(jnp.einsum(
+            "td,de->te", x, router, precision=jax.lax.Precision.HIGHEST), -1)
+        top, pick = jax.lax.top_k(p, topk)
+        wgt = top / top.sum(-1, keepdims=True)
+
+        def one(out, e):
+            j, g1, u1, d1 = e
+            mine = jnp.sum(jnp.where(pick == j, wgt, 0.0), -1)
+            return out + mine[:, None] * (
+                (jax.nn.silu(x @ g1) * (x @ u1)) @ d1), None
+        return jax.lax.scan(one, jnp.zeros_like(x),
+                            (jnp.asarray(held), gate, upm, down))[0]
+
+    args = (x, router, gate, upm, down)
+    counts = np.asarray(jax.jit(routed)(*args)[1])
+    o, g = vjp_np(lambda *a: routed(*a)[0], args, ct)
+    with jax.default_matmul_precision("highest"):
+        o_ref, g_ref = vjp_np(dense, tuple(up(a) for a in args), up(ct))
+    names = ("dx", "drouter", "dgate", "dup", "ddown")
+    errs = {"o": _rel_err(o, o_ref)}
+    errs.update({n: _rel_err(a, b) for n, a, b in zip(names, g, g_ref)})
+    row("moe/grouped fwd+bwd", [T, dm, fe, len(held), E, topk], errs,
+        {"o": TOL_OUT, **dict.fromkeys(names, TOL_GRAD)},
+        counts=dict(zip(moe.MOE_COUNTS, (int(c) for c in counts))))
+    del x, ct, args, o_ref, g_ref
 
     # -- out-proj + residual + rmsnorm epilogue, differentiated ------------
     # (PR 53: the rule is XLA's, so this row guards its wiring: no Mosaic

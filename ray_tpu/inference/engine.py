@@ -197,6 +197,37 @@ def _cached_context_attention(q, kctx, vctx, ks, vs, cached_len,
     return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
+def refuse_unserved(cfg) -> None:
+    """Raise, by name, for what a ``GPTConfig`` may ask that the serve
+    path does not have (the train path has it): window layers, grouped
+    K/V heads, the dropless expert layer.  A config that runs its own
+    stack (``serve_hidden``: ``models/longcat.py``) says what it holds
+    to that stack, not to this block."""
+    if hasattr(cfg, "serve_hidden"):
+        return
+    unserved = [what for what, there in (
+        (f"window layers (layer_types={getattr(cfg, 'layer_types', None)}"
+         f", window={getattr(cfg, 'window', None)})",
+         getattr(cfg, "layer_types", None) is not None),
+        (f"grouped K/V heads (n_kv_heads={getattr(cfg, 'n_kv_heads', None)}"
+         f" under n_heads={getattr(cfg, 'n_heads', None)})",
+         getattr(cfg, "n_kv_heads", None) not in (
+             None, getattr(cfg, "n_heads", None))),
+        ("held_experts (the dropless expert layer of the train path)",
+         getattr(cfg, "held_experts", None) is not None)) if there]
+    if unserved:
+        raise NotImplementedError(
+            "GPTConfig with " + "; ".join(unserved) + " is not served: "
+            "inference/kv_cache.py keeps one page population and one "
+            "row size [H, D] for every layer (a window layer would "
+            "release pages behind its window, a grouped layer's row "
+            "has n_kv_heads heads), ops/attention.py:decode_attention "
+            "reads one K/V head a query head and every live page, and "
+            "the engine's step runs models/gpt.py's dense FFN; such a "
+            "config runs on the train path (models/training.py:"
+            "build_gpt_train)")
+
+
 class InferenceEngine:
     """Continuous-batching decode engine over one GPT parameter set.
 
@@ -265,6 +296,7 @@ class InferenceEngine:
                 "a decode of a few rows through the cache cannot "
                 "reproduce a prefill's output row by row; the serve "
                 "path's routed layer is parallel/moe.py:dropless_moe")
+        refuse_unserved(cfg)
         from ray_tpu._private.compile_cache import enable_compile_cache
         enable_compile_cache()
         icfg = infer_config()

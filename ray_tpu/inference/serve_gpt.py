@@ -55,7 +55,10 @@ import ray_tpu.serve as serve
 from ray_tpu.inference.sampling import SamplingParams
 from ray_tpu.util import tracing
 
-_PRESETS = ("tiny", "gpt2", "gpt2_medium", "gpt2_large")
+# the last two are named so that they are refused with the reason
+# (inference/engine.py:refuse_unserved), not as unknown names
+_PRESETS = ("tiny", "gpt2", "gpt2_medium", "gpt2_large",
+            "mellum2_12b_a2_5b", "mellum_tiny")
 
 
 class ReplicaDrainingError(RuntimeError):
@@ -111,6 +114,10 @@ def _build_engine(model: str, model_config: Optional[Dict[str, Any]],
         raise ValueError(f"unknown model preset {model!r}; "
                          f"expected one of {_PRESETS + longcat.PRESETS}")
     cfg = getattr(config_cls, model)(**(model_config or {}))
+    if config_cls is gpt.GPTConfig:
+        # before any weight is drawn
+        from ray_tpu.inference.engine import refuse_unserved
+        refuse_unserved(cfg)
     params = init_params(cfg, jax.random.PRNGKey(seed))
     return cfg, InferenceEngine(cfg, params, **(engine_config or {}))
 

@@ -7,18 +7,28 @@ but built natively for XLA: stacked layer params swept by ``lax.scan``
 ring attention over an ``sp`` axis, optional MoE FFNs sharded over ``ep``,
 and logical-axis annotations so one model runs under any
 dp/fsdp/tp/sp/ep mesh (see ``ray_tpu.parallel.sharding``).
+
+The same block also runs, on the train path, with fewer K/V heads than
+query heads (``n_kv_heads``), with layers of two kinds in one stack
+(``layer_types``: ``window`` layers, whose rows see ``window`` keys, and
+``full`` ones, each kind with its own rope and its own attention hook:
+:func:`attention_fns`), and with a dropless expert FFN that is told
+which experts of a deployment it holds (``held_experts``:
+``parallel/moe.py:dropless_moe``, one chip's share of an
+expert-parallel layer, the exchange between chips not run).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops.attention import Rope
 from ray_tpu.parallel import sharding as shd
 from ray_tpu.parallel.ring_attention import local_attention, ring_attention
 
@@ -58,10 +68,90 @@ class GPTConfig:
     # resident between forward and backward).  Smaller positive chunks
     # bound the [chunk, V] f32 logits transient.
     ce_chunk: int = 4096
+    # K/V heads (None: as many as query heads); query head i reads K/V
+    # head i // (n_heads // n_kv_heads)
+    n_kv_heads: Optional[int] = None
+    # one period of the layer pattern, "window" and "full", repeated
+    # over the depth (None: every layer full).  A window layer's rows
+    # see ``window`` keys, their own included, and keep the plain
+    # ``rope_theta``; the full layers take ``rope_full`` where it is set
+    # (a context-extended model scales the rope of the layers that see
+    # the whole context)
+    layer_types: Optional[Tuple[str, ...]] = None
+    window: Optional[int] = None
+    rope_full: Optional[Rope] = None
+    # the dropless expert FFN (parallel/moe.py:dropless_moe): the ids,
+    # among the deployment's ``n_routed_experts``, of the experts this
+    # chip holds; the router scores all of them and picks ``moe_top_k``,
+    # weighted by their scores over the picked scores' sum where
+    # ``moe_renormalise``.  No capacity, no drop, no auxiliary loss.
+    # (``n_experts`` is the capacity path's and stays 0.)
+    held_experts: Optional[Tuple[int, ...]] = None
+    n_routed_experts: int = 0
+    moe_renormalise: bool = False
+    # a recipe key, as ``remat`` and ``ce_chunk`` are: the steps over
+    # which the builders' default optimizer (``models/training.py:
+    # default_optimizer``) warms its learning rate up.  A dropless
+    # layer's work follows its router, so a run whose steps are to cost
+    # the same takes a warm-up under which the router stays where it
+    # was drawn (PERF.md section 6, PR 56)
+    warmup_steps: int = 100
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            if (not kinds or set(kinds) - {"window", "full"}
+                    or self.n_layers % len(kinds)):
+                raise ValueError(
+                    f"layer_types {kinds} must be a period of 'window' "
+                    f"and 'full' that divides n_layers={self.n_layers}")
+            if "window" in kinds and not self.window:
+                raise ValueError("window layers need window=")
+            object.__setattr__(self, "layer_types", kinds)
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"n_heads={self.n_heads} is no multiple of "
+                             f"n_kv_heads={self.kv_heads}")
+        if self.held_experts is not None:
+            held = tuple(int(e) for e in self.held_experts)
+            if self.n_experts:
+                raise ValueError("held_experts is the dropless layer's; "
+                                 "n_experts is the capacity path's")
+            if len(set(held)) != len(held) or any(
+                    not 0 <= e < self.n_routed_experts for e in held):
+                raise ValueError(
+                    f"held_experts must be distinct ids below "
+                    f"n_routed_experts={self.n_routed_experts}, got {held}")
+            object.__setattr__(self, "held_experts", held)
 
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def dropless(self) -> bool:
+        return self.held_experts is not None
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Every layer's kind, in order."""
+        period = self.layer_types or ("full",)
+        return period * (self.n_layers // len(period))
+
+    @property
+    def plain_attention(self) -> bool:
+        """One kind of layer with a K/V head a query head: what every
+        attention hook of the repo computes."""
+        return self.layer_types is None and self.kv_heads == self.n_heads
+
+    def rope(self, kind: str = "full"):
+        """The rope of a layer kind: a plain theta or a ``Rope``."""
+        if kind == "full" and self.rope_full is not None:
+            return self.rope_full
+        return self.rope_theta
 
     @property
     def ff_dim(self) -> int:
@@ -89,11 +179,89 @@ class GPTConfig:
         kw.setdefault("max_seq", 128)
         return cls(d_model=64, n_layers=2, n_heads=4, **kw)
 
+    @classmethod
+    def mellum2_12b_a2_5b(cls, **kw):
+        """Mellum2-12B-A2.5B at its published widths
+        (huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct
+        config.json): 32 query heads on 4 K/V heads of 128, three window
+        layers (1024 keys, plain rope) then one full layer (static YaRN,
+        factor 16 over 8192) a period, every FFN 8 of 64 experts of
+        width 896 with renormalised weights, untied head.  A
+        deployment's share narrows ``n_layers``, ``held_experts`` and
+        ``vocab_size``."""
+        kw.setdefault("vocab_size", 98304)
+        kw.setdefault("n_layers", 28)
+        kw.setdefault("max_seq", 8192)
+        kw.setdefault("held_experts", tuple(range(64)))
+        return cls(d_model=2304, n_heads=32, n_kv_heads=4, d_head=128,
+                   d_ff=896, rope_theta=500000.0,
+                   layer_types=("window", "window", "window", "full"),
+                   window=1024,
+                   rope_full=Rope(theta=500000.0, factor=16.0,
+                                  original_max=8192, beta_fast=32.0,
+                                  beta_slow=1.0,
+                                  attention_factor=1.2772588722239782),
+                   n_routed_experts=64, moe_top_k=8,
+                   moe_renormalise=True, tie_embeddings=False, **kw)
+
+    @classmethod
+    def mellum_tiny(cls, **kw):
+        """The same shape of block at test size: two periods of (window,
+        window, window, full), 2 K/V heads under 4 query heads, 8
+        experts top-2 of which 4 are held, a window shorter than the
+        tests' sequences, YaRN on the full layers."""
+        kw.setdefault("vocab_size", 512)
+        kw.setdefault("max_seq", 256)
+        kw.setdefault("n_heads", 4)
+        kw.setdefault("held_experts", (0, 1, 2, 3))
+        return cls(d_model=64, n_layers=8, n_kv_heads=2, d_head=16,
+                   d_ff=32, rope_theta=500000.0,
+                   layer_types=("window", "window", "window", "full"),
+                   window=48,
+                   rope_full=Rope(theta=500000.0, factor=4.0,
+                                  original_max=64, beta_fast=32.0,
+                                  beta_slow=1.0,
+                                  attention_factor=1.1386294361119891),
+                   n_routed_experts=8, moe_top_k=2,
+                   moe_renormalise=True, tie_embeddings=False, **kw)
+
+
+# a routed config's factor on its drawn queries, by layer kind (the
+# attention scores' standard deviation before a rope's own factor)
+_ROUTED_QUERY_SCALE = {"window": 4.0, "full": 3.0}
+
+
+def _query_scales(cfg: GPTConfig):
+    """Every layer's factor on its drawn queries, ``[L, 1, 1, 1]``: 1 but
+    for a routed config, whose work follows its draw.  With scores of
+    standard deviation 1 a row's softmax over a thousand keys is flat,
+    every token's attention output is the same average, and the router
+    sends every token to the same few experts (one held expert took 17
+    rows of 16,384 and another 5,360 in the fourth layer, my chip run,
+    PR 56).  The sharper the window layers' softmax, the nearer a
+    layer's experts come to equal loads, and with them the picks this
+    chip holds to their expectation whatever the seed: over eight seeds
+    the held picks a step spread 2,754 (standard deviation, of 131 k)
+    with every layer's queries at 3, 1,319 at 4, 969 with the window
+    layers' at 5, 665 at 7.5, and the full layer's own sharpness moved
+    nothing (its scores stand at 4.9 already, through the 1.63 that
+    YaRN's factor puts on them).  Against that, a sharper softmax shows
+    more of bfloat16's rounding beside the float32 reference: with the
+    window layers at 5 the clean loss gap reached 2.8e-4 of the 3.4e-4
+    allowed, at 4 it stays under 1.3e-4 over thirteen seeds, and ten
+    seeds' tokens a second spread 0.25 % (PERF.md section 6, PR 56)."""
+    if not cfg.dropless:
+        return 1.0
+    return jnp.asarray([_ROUTED_QUERY_SCALE[kind]
+                        for kind in cfg.layer_kinds],
+                       jnp.float32)[:, None, None, None]
+
 
 def init_params(cfg: GPTConfig, key) -> Dict[str, Any]:
     keys = iter(jax.random.split(key, 24))
     d, H, hd, f, L = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.ff_dim,
                       cfg.n_layers)
+    Hkv = cfg.kv_heads
     dt = cfg.dtype
 
     def norm_init(k, shape, scale):
@@ -106,14 +274,30 @@ def init_params(cfg: GPTConfig, key) -> Dict[str, Any]:
         params["pos_embed"] = norm_init(next(keys), (cfg.max_seq, d), 0.02)
     layer = {
         "ln1": jnp.ones((L, d), dt),
-        "wq": norm_init(next(keys), (L, d, H, hd), d ** -0.5),
-        "wk": norm_init(next(keys), (L, d, H, hd), d ** -0.5),
-        "wv": norm_init(next(keys), (L, d, H, hd), d ** -0.5),
+        # a routed model's queries are drawn for sharper attention
+        # scores, by layer kind (``_query_scales``)
+        "wq": norm_init(next(keys), (L, d, H, hd),
+                        _query_scales(cfg) * d ** -0.5),
+        "wk": norm_init(next(keys), (L, d, Hkv, hd), d ** -0.5),
+        "wv": norm_init(next(keys), (L, d, Hkv, hd), d ** -0.5),
         "wo": norm_init(next(keys), (L, H, hd, d),
                         (H * hd) ** -0.5 / (2 * L) ** 0.5),
         "ln2": jnp.ones((L, d), dt),
     }
-    if cfg.n_experts > 0:
+    if cfg.dropless:
+        # the router scores the deployment's experts, the matrices are
+        # the held ones'.  Router logits of standard deviation 3: a
+        # row's picks then differ in weight as a trained router's do
+        # (at 1 they are near equal and a check against the reference
+        # cannot see which experts ran; PERF.md section 6, PR 48)
+        E = len(cfg.held_experts)
+        layer["moe_wg"] = norm_init(next(keys), (L, d, cfg.n_routed_experts),
+                                    3.0 * d ** -0.5)
+        layer["moe_w1"] = norm_init(next(keys), (L, E, d, f), d ** -0.5)
+        layer["moe_w3"] = norm_init(next(keys), (L, E, d, f), d ** -0.5)
+        layer["moe_w2"] = norm_init(next(keys), (L, E, f, d),
+                                    f ** -0.5 / (2 * L) ** 0.5)
+    elif cfg.n_experts > 0:
         E = cfg.n_experts
         layer["moe_wg"] = norm_init(next(keys), (L, d, E), d ** -0.5)
         layer["moe_w1"] = norm_init(next(keys), (L, E, d, f), d ** -0.5)
@@ -131,10 +315,10 @@ def init_params(cfg: GPTConfig, key) -> Dict[str, Any]:
         layer["ln1_b"] = jnp.zeros((L, d), dt)
         layer["ln2_b"] = jnp.zeros((L, d), dt)
         layer["bq"] = jnp.zeros((L, H, hd), dt)
-        layer["bk"] = jnp.zeros((L, H, hd), dt)
-        layer["bv"] = jnp.zeros((L, H, hd), dt)
+        layer["bk"] = jnp.zeros((L, Hkv, hd), dt)
+        layer["bv"] = jnp.zeros((L, Hkv, hd), dt)
         layer["bo"] = jnp.zeros((L, d), dt)
-        if cfg.n_experts == 0:
+        if cfg.n_experts == 0 and not cfg.dropless:
             layer["b1"] = jnp.zeros((L, f), dt)
             if cfg.act == "swiglu":
                 layer["b3"] = jnp.zeros((L, f), dt)
@@ -163,7 +347,7 @@ def param_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
         "wo": (None, "heads", None, "embed_fsdp"),
         "ln2": (None, None),
     }
-    if cfg.n_experts > 0:
+    if cfg.n_experts > 0 or cfg.dropless:
         layer["moe_wg"] = (None, None, None)
         layer["moe_w1"] = (None, "experts", "embed_fsdp", "expert_mlp")
         if cfg.act == "swiglu":
@@ -181,7 +365,7 @@ def param_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
         layer["bk"] = (None, "heads", None)
         layer["bv"] = (None, "heads", None)
         layer["bo"] = (None, None)
-        if cfg.n_experts == 0:
+        if cfg.n_experts == 0 and not cfg.dropless:
             layer["b1"] = (None, "mlp")
             if cfg.act == "swiglu":
                 layer["b3"] = (None, "mlp")
@@ -218,7 +402,7 @@ def _norm(x, scale, kind: str, bias=None, eps: float = 1e-6):
     return x32.astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
+def _rope(x, positions, theta):
     """x: [B, S, H, D]; rotate pairs along D.
 
     Angles/cos/sin in f32 (position precision), the rotation itself in
@@ -320,10 +504,87 @@ def _moe_ffn(lp, x, cfg: GPTConfig):
     return out.reshape(B, S, d), aux
 
 
+def _dropless_ffn(lp, x, cfg: GPTConfig):
+    """The dropless expert FFN on x [B, S, d] -> (its output, the
+    layer's counts: ``parallel/moe.py:MOE_COUNTS`` and then the rows
+    each held expert took)."""
+    from ray_tpu.parallel.moe import dropless_moe
+    B, S, d = x.shape
+    with jax.named_scope("moe"):
+        out, counts, load = dropless_moe(
+            x.reshape(B * S, d), lp["moe_wg"],
+            jnp.zeros((cfg.n_routed_experts,), jnp.float32),
+            lp["moe_w1"], lp["moe_w3"], lp["moe_w2"],
+            held=cfg.held_experts, n_routed=cfg.n_routed_experts,
+            top_k=cfg.moe_top_k, scale=1.0,
+            renormalise=cfg.moe_renormalise, with_load=True)
+    return out.reshape(B, S, d), jnp.concatenate([counts, load])
+
+
+def moe_counts_len(cfg: GPTConfig) -> int:
+    """Length of a layer's counts vector: ``MOE_COUNTS``, then a row
+    count a held expert."""
+    from ray_tpu.parallel.moe import MOE_COUNTS
+    return len(MOE_COUNTS) + len(cfg.held_experts or ())
+
+
+def xla_attention_fn(cfg: GPTConfig, kind: str = "full"):
+    """The einsum attention hook of one layer kind (no kernel): what a
+    config with window layers or grouped K/V heads runs where no hook
+    is given."""
+    from ray_tpu.ops.attention import xla_attention
+    window = cfg.window if kind == "window" else None
+    fn = functools.partial(xla_attention, causal=True, window=window)
+    fn.window, fn.kv_heads = window, cfg.kv_heads
+    return fn
+
+
+def attention_fns(cfg: GPTConfig, mesh=None, **kw) -> Dict[str, Callable]:
+    """One flash-attention hook a layer kind of ``cfg``
+    (``ops.attention.make_flash_attention_fn`` with the kind's window,
+    K/V heads and rope): what ``forward_hidden`` takes as ``attn_fn``
+    for a config whose layers differ."""
+    from ray_tpu.ops.attention import make_flash_attention_fn
+    return {
+        kind: make_flash_attention_fn(
+            mesh, causal=True,
+            window=cfg.window if kind == "window" else None,
+            kv_heads=cfg.kv_heads,
+            rope=cfg.rope(kind) if cfg.pos == "rope" else None, **kw)
+        for kind in sorted(set(cfg.layer_kinds))}
+
+
+def _kind_attn_fn(attn_fn, cfg: GPTConfig, kind: str):
+    """The hook layer kind ``kind`` runs, checked: a window layer never
+    runs a hook that does not say it honours that window."""
+    if isinstance(attn_fn, dict):
+        attn_fn = attn_fn[kind]
+    want = cfg.window if kind == "window" else None
+    if cfg.plain_attention:
+        return attn_fn
+    if (getattr(attn_fn, "window", None) != want
+            or getattr(attn_fn, "kv_heads", cfg.n_heads) != cfg.kv_heads):
+        raise ValueError(
+            f"a {kind!r} layer of a config with window layers or "
+            f"grouped K/V heads (window={want}, "
+            f"n_kv_heads={cfg.kv_heads}) needs an attention hook made "
+            "for it (models.gpt.attention_fns); the hook given says "
+            f"window={getattr(attn_fn, 'window', None)}, "
+            f"kv_heads={getattr(attn_fn, 'kv_heads', None)}")
+    return attn_fn
+
+
 def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
-                cache=None, fuse_norm=None, lora=None):
+                cache=None, fuse_norm=None, lora=None, kind: str = "full",
+                with_counts: bool = False):
     """One transformer block: ``(layer params, hidden [B,S,d]) -> (hidden,
-    moe aux)``.  Shared by the stacked ``lax.scan`` in ``forward_hidden``,
+    moe aux)``.  ``kind`` is the layer's kind (``cfg.layer_kinds``): it
+    picks the rope, and the hook (``attn_fn``: one callable, or one a
+    kind) is checked to be that kind's.  A window layer's attention
+    runs under the scope ``window`` (``gpt/attn/window/attn/...``).
+    With ``with_counts`` the block also returns its expert layer's
+    counts (zeros for a dense FFN) last.
+    Shared by the stacked ``lax.scan`` in ``forward_hidden``,
     the per-stage scan in the pipeline-parallel trainer
     (``models/training.py`` build_gpt_train_pp) and the inference
     engine's prefill/decode steps (``ray_tpu.inference.engine``).
@@ -357,6 +618,8 @@ def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
     constrain = functools.partial(shd.constrain, mesh=mesh)
     eps = norm_eps(cfg)
     h2 = None
+    attn_fn = _kind_attn_fn(attn_fn, cfg, kind)
+    counts = None
     with jax.named_scope("gpt/attn"):
         h = _norm(x, lp["ln1"], cfg.norm, bias=lp.get("ln1_b"), eps=eps)
         # (a fused [d, 3Hk] qkv projection was A/B'd on the v5e bench
@@ -382,8 +645,8 @@ def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
         fused_rope = (cfg.pos == "rope"
                       and getattr(attn_fn, "fused_rope", False))
         if cfg.pos == "rope" and not fused_rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = _rope(q, positions, cfg.rope(kind))
+            k = _rope(k, positions, cfg.rope(kind))
         q = constrain(q, ("batch", "seq", "heads", None))
         k = constrain(k, ("batch", "seq", "heads", None))
         v = constrain(v, ("batch", "seq", "heads", None))
@@ -394,6 +657,10 @@ def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
                     "cache entries must store post-RoPE keys, but a "
                     "fused_rope attn_fn receives them un-rotated")
             attn, cache = attn_fn(q, k, v, cache=cache)
+        elif kind == "window":
+            with jax.named_scope("window"):
+                attn = (attn_fn(q, k, v, positions=positions)
+                        if fused_rope else attn_fn(q, k, v))
         elif fused_rope:
             attn = attn_fn(q, k, v, positions=positions)
         else:
@@ -425,18 +692,24 @@ def layer_apply(lp, x, cfg: GPTConfig, *, positions, attn_fn, mesh=None,
         if h2 is None:
             h2 = _norm(x, lp["ln2"], cfg.norm, bias=lp.get("ln2_b"),
                        eps=eps)
-        if cfg.n_experts > 0:
-            if lora is not None:
-                raise ValueError("LoRA adapters are dense-FFN only "
-                                 "(see adapters.lora.effective_targets)")
+        if (cfg.n_experts > 0 or cfg.dropless) and lora is not None:
+            raise ValueError("LoRA adapters are dense-FFN only "
+                             "(see adapters.lora.effective_targets)")
+        if cfg.dropless:
+            # no capacity, so no auxiliary term: aux is 0
+            ffn_out, counts = _dropless_ffn(lp, h2, cfg)
+            aux = jnp.float32(0)
+        elif cfg.n_experts > 0:
             ffn_out, aux = _moe_ffn(lp, h2, cfg)
         else:
             ffn_out, aux = _dense_ffn(lp, h2, cfg, lora=lora), jnp.float32(0)
         x = x + ffn_out
         x = constrain(x, ("batch", "seq", None))
-    if cache is not None:
-        return x, aux, cache
-    return x, aux
+    out = (x, aux) if cache is None else (x, aux, cache)
+    if with_counts:
+        out += (jnp.zeros((moe_counts_len(cfg),), jnp.int32)
+                if counts is None else counts,)
+    return out
 
 
 def _with_segments(attn_fn, segment_ids):
@@ -445,9 +718,14 @@ def _with_segments(attn_fn, segment_ids):
     in-tree hook (``local_attention``, ``flash_attention`` and the
     ``make_flash_attention_fn`` wrappers) accepts the kwarg; the
     Pallas schedules decline it with the XLA segment formulation."""
-    fused = getattr(attn_fn, "fused_rope", False)
+    if isinstance(attn_fn, dict):
+        return {kind: _with_segments(fn, segment_ids)
+                for kind, fn in attn_fn.items()}
     fn = functools.partial(attn_fn, segment_ids=segment_ids)
-    fn.fused_rope = fused
+    for mark in ("window", "kv_heads"):
+        if hasattr(attn_fn, mark):
+            setattr(fn, mark, getattr(attn_fn, mark))
+    fn.fused_rope = getattr(attn_fn, "fused_rope", False)
     return fn
 
 
@@ -531,11 +809,20 @@ def forward_hidden(params: Dict[str, Any], tokens, cfg: GPTConfig, *,
                    attn_fn: Optional[Callable] = None, mesh=None,
                    fuse_norm: Optional[bool] = None,
                    final_norm: bool = True,
-                   segment_ids=None, positions=None, lora=None):
-    """tokens [B, S] int32 -> (final hidden [B, S, d], moe aux loss).
+                   segment_ids=None, positions=None, lora=None,
+                   with_counts: bool = False):
+    """tokens [B, S] int32 -> (final hidden [B, S, d], moe aux loss),
+    and with ``with_counts`` the expert layers' summed counts
+    (``parallel/moe.py:MOE_COUNTS``) last.
 
     ``attn_fn(q, k, v) -> out`` defaults to causal local attention; pass a
-    ring-attention fn (``make_ring_attention_fn``) for sp>1 meshes.
+    ring-attention fn (``make_ring_attention_fn``) for sp>1 meshes.  A
+    config whose layers differ in kind (``cfg.layer_types``) or whose
+    K/V heads are grouped takes one hook a kind
+    (:func:`attention_fns`; default: the einsum formulation).  Unrolled
+    layers pick their kind's hook statically; the scan sweeps whole
+    periods of the pattern, so a window layer is never run as a full
+    one.
 
     ``fuse_norm`` pins the fused norm epilogues (see ``layer_apply``);
     ``final_norm=False`` skips the closing ``ln_f`` and returns the raw
@@ -554,7 +841,10 @@ def forward_hidden(params: Dict[str, Any], tokens, cfg: GPTConfig, *,
     """
     B, S = tokens.shape
     if attn_fn is None:
-        attn_fn = functools.partial(local_attention, causal=True)
+        attn_fn = (functools.partial(local_attention, causal=True)
+                   if cfg.plain_attention else
+                   {kind: xla_attention_fn(cfg, kind)
+                    for kind in set(cfg.layer_kinds)})
     if segment_ids is not None:
         if positions is None:
             # global arange positions across packed documents would
@@ -576,31 +866,51 @@ def forward_hidden(params: Dict[str, Any], tokens, cfg: GPTConfig, *,
     if lora is not None:
         lora_scan = {k: v for k, v in lora.items() if k != "scale"}
 
-    def layer_body(x, lp_la):
+    def layer_body(x, lp_la, kind="full"):
         lp, la = lp_la
         layer_lora = None if la is None else {**la, "scale": lora["scale"]}
-        return layer_apply(lp, x, cfg, positions=positions,
-                           attn_fn=attn_fn, mesh=mesh,
-                           fuse_norm=fuse_norm, lora=layer_lora)
+        out = layer_apply(lp, x, cfg, positions=positions,
+                          attn_fn=attn_fn, mesh=mesh,
+                          fuse_norm=fuse_norm, lora=layer_lora,
+                          kind=kind, with_counts=with_counts)
+        return out[0], out[1:]
 
+    kinds = cfg.layer_kinds
+    bodies = {kind: functools.partial(layer_body, kind=kind)
+              for kind in set(kinds)}
     if cfg.remat:
-        layer_body = jax.checkpoint(layer_body)
+        bodies = {kind: jax.checkpoint(body)
+                  for kind, body in bodies.items()}
+
+    def sweep(x, stacked, kinds):
+        """The first ``len(kinds)`` layers of the stacked trees, in
+        order -> (x, what they return beside it, summed)."""
+        sums = None
+        for i, kind in enumerate(kinds):
+            x, extra = bodies[kind](x, jax.tree.map(lambda a: a[i], stacked))
+            sums = extra if sums is None else jax.tree.map(
+                jnp.add, sums, extra)
+        return x, sums
+
+    stacked = (params["layers"], lora_scan)
     if cfg.unroll_layers:
-        aux_total = jnp.float32(0)
-        for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            la = None if lora_scan is None else \
-                jax.tree.map(lambda a: a[i], lora_scan)
-            x, aux = layer_body(x, (lp, la))
-            aux_total = aux_total + aux
+        x, sums = sweep(x, stacked, kinds)
+    elif cfg.layer_types is None:
+        x, extras = lax.scan(bodies["full"], x, stacked)
+        sums = jax.tree.map(lambda a: jnp.sum(a, axis=0), extras)
     else:
-        x, auxes = lax.scan(layer_body, x,
-                            (params["layers"], lora_scan))
-        aux_total = jnp.sum(auxes)
+        # one scan step is one period of the layer pattern: [L, ...] is
+        # swept as [L / p, p, ...]
+        period = cfg.layer_types
+        folded = jax.tree.map(
+            lambda a: a.reshape(a.shape[0] // len(period), len(period),
+                                *a.shape[1:]), stacked)
+        x, extras = lax.scan(lambda x, one: sweep(x, one, period), x, folded)
+        sums = jax.tree.map(lambda a: jnp.sum(a, axis=0), extras)
     if final_norm:
         x = _norm(x, params["ln_f"], cfg.norm,
                   bias=params.get("ln_f_b"), eps=norm_eps(cfg))
-    return x, aux_total
+    return (x,) + tuple(sums)
 
 
 def lm_head(params, cfg: GPTConfig):
@@ -697,8 +1007,16 @@ def _chunked_ce(x, head, targets, *, ce_chunk: int = _CE_CHUNK,
 
 def loss_fn(params, batch, cfg: GPTConfig, *, attn_fn=None, mesh=None,
             aux_weight: float = 0.01, ce_mode: Optional[str] = None,
-            fuse_norm: Optional[bool] = None, lora=None):
-    """batch: dict(tokens [B,S], targets [B,S]); returns scalar loss.
+            fuse_norm: Optional[bool] = None, lora=None,
+            with_counts: bool = False):
+    """batch: dict(tokens [B,S], targets [B,S]); returns scalar loss:
+    the mean next-token NLL plus ``aux_weight`` times the expert layers'
+    auxiliary term.  Only the capacity path (``n_experts``) has one: a
+    dense FFN's and the dropless layer's (``held_experts``: nothing is
+    dropped, so there is no load to balance a capacity against) are 0,
+    and their loss is the NLL alone.  With ``with_counts``: ``(loss,
+    the expert layers' summed counts)``, for ``jax.value_and_grad(...,
+    has_aux=True)``.
 
     ``fuse_norm=False`` pins the fused norm epilogues off (``None`` is
     on): the per-layer out-proj epilogue in ``layer_apply`` (under a
@@ -718,15 +1036,17 @@ def loss_fn(params, batch, cfg: GPTConfig, *, attn_fn=None, mesh=None,
         B * S, cfg.d_model, cfg.vocab_size, norm=cfg.norm,
         has_bias=cfg.use_bias, enabled=fuse_norm,
         **_ce_recipe(cfg, mesh, ce_mode))
-    x, aux = forward_hidden(params, batch["tokens"], cfg, attn_fn=attn_fn,
-                            mesh=mesh, fuse_norm=fuse_norm,
-                            final_norm=not ce_norm,
-                            segment_ids=batch.get("segment_ids"),
-                            positions=batch.get("positions"), lora=lora)
+    x, aux, *counts = forward_hidden(
+        params, batch["tokens"], cfg, attn_fn=attn_fn, mesh=mesh,
+        fuse_norm=fuse_norm, final_norm=not ce_norm,
+        segment_ids=batch.get("segment_ids"),
+        positions=batch.get("positions"), lora=lora,
+        with_counts=with_counts)
     loss = loss_from_hidden(
         params, x, batch["targets"], cfg, mesh=mesh, ce_mode=ce_mode,
         norm_scale=params["ln_f"] if ce_norm else None)
-    return loss + aux_weight * aux
+    loss = loss + aux_weight * aux
+    return (loss, counts[0]) if with_counts else loss
 
 
 def num_params(params) -> int:

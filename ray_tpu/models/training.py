@@ -284,7 +284,7 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
     from ray_tpu.parallel import overlap as ovl
 
     enable_compile_cache()
-    tx = optimizer or default_optimizer()
+    tx = optimizer or default_optimizer(warmup=cfg.warmup_steps)
     if accum_steps is None:
         accum_steps = default_accum_steps()
     accum_steps = int(accum_steps)
@@ -338,7 +338,22 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
     if lcfg is not None:
         base, param_sh, init_adapter, lora_tree = _adapter_fns(
             cfg, lcfg, base_params, mesh, param_sh)
-    if mesh.shape.get("sp", 1) > 1:
+    routed = cfg.dropless
+    if routed and (accum_steps > 1 or lcfg is not None):
+        raise NotImplementedError(
+            "a config with held_experts (the dropless expert layer) "
+            "trains with accum_steps=1 and no LoRA adapter: the "
+            "microbatch scan and the adapter forward carry no expert "
+            "counts (models/training.py:_accum_value_and_grad)")
+    if not cfg.plain_attention:
+        if mesh.shape.get("sp", 1) > 1 or comm_mode == "overlap":
+            raise NotImplementedError(
+                "a config with window layers or grouped K/V heads has "
+                "no sequence-parallel (ring, ulysses) or overlap-"
+                "schedule attention: build on an sp=1 mesh with "
+                "comm_mode='gspmd'")
+        attn_fn = gpt_mod.attention_fns(cfg, mesh, pack2=attn_pack2)
+    elif mesh.shape.get("sp", 1) > 1:
         if sp_impl == "ulysses":
             from ray_tpu.parallel.ulysses import make_ulysses_attention_fn
             attn_fn = make_ulysses_attention_fn(mesh, causal=True)
@@ -372,7 +387,7 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
                                    lora=lora_tree(params))
         return gpt_mod.loss_fn(params, batch, cfg, attn_fn=attn_fn,
                                mesh=mesh, ce_mode=ce_mode,
-                               fuse_norm=fuse_norm)
+                               fuse_norm=fuse_norm, with_counts=routed)
 
     overlap_fns = (ovl.build_overlap_step_fns(cfg, mesh, quant=comm_quant)
                    if comm_mode == "overlap" else None)
@@ -388,7 +403,7 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
                     "with comm_mode='gspmd' for streamed packed input")
             return overlap_fns["value_and_grad"](
                 params, batch["tokens"], batch["targets"])
-        return jax.value_and_grad(loss)(params, batch)
+        return jax.value_and_grad(loss, has_aux=routed)(params, batch)
 
     def init(key) -> TrainState:
         params = init_adapter(key) if lcfg is not None \
@@ -406,20 +421,30 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
                 value_and_grad, state.params, batch, accum_steps)
         else:
             loss_val, grads = value_and_grad(state.params, batch)
+        metrics = {}
+        if routed:
+            # the expert layers' counts, summed over layers, ride on the
+            # step's one fetch with the loss
+            loss_val, metrics["moe_counts"] = loss_val
         updates, opt_state = tx.update(grads, state.opt_state,
                                        state.params)
         params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        # a routed step's gradient norm in float32: bfloat16's 2^-8
+        # would be the resolution of the check that reads it
+        gnorm = optax.global_norm(
+            jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            if routed else grads)
         return (TrainState(params, opt_state, state.step + 1),
                 {"loss": loss_val, "grad_norm": gnorm,
-                 "step": state.step + 1})
+                 "step": state.step + 1, **metrics})
 
     @functools.partial(jax.jit, in_shardings=(st_sh.params, batch_sh))
     def loss_eval(params, batch):
         if overlap_fns is not None:
             return overlap_fns["loss"](params, batch["tokens"],
                                        batch["targets"])
-        return loss(params, batch)
+        out = loss(params, batch)
+        return out[0] if routed else out
 
     @functools.partial(jax.jit, in_shardings=(st_sh.params, batch_sh),
                        out_shardings=None)
@@ -827,7 +852,7 @@ def build_gpt_train_pp(cfg: "gpt_mod.GPTConfig", mesh, *,
     Ls = cfg.n_layers // pp
     M = num_microbatches or default_pp_microbatches() or 2 * pp
     enable_compile_cache()
-    tx = optimizer or default_optimizer()
+    tx = optimizer or default_optimizer(warmup=cfg.warmup_steps)
     stats = pipe.pipeline_schedule_stats(pp, M, schedule)
 
     # one rule table for both schedules: "stage" follows the stage

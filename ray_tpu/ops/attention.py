@@ -21,8 +21,20 @@ block-diagonal K/V arrangement: every MXU op is then
 ``[block, 128] x [128, block]``-shaped (full-width contraction or
 full-width output) and the op *count* halves.  Controlled by
 ``attention_config()`` (env ``RAY_TPU_ATTN_PACK2=0`` to disable); odd
-head counts, head_dim 128 and shapes the packed grid cannot tile fall
-back to the single-head schedule unchanged.
+head counts, head_dim 128 and shapes the packed grid cannot tile take
+the single-head schedule.
+
+The single-head schedule (``_fwd``, ``_bwd``) is the schedule of
+grouped-query and window layers, and the first a benchmark cell times
+(the routed 8k train cell: 32 query heads on 4 K/V heads of 128, three
+window layers to one full).  Its grids carry the K/V head in their index
+maps (query head ``h`` reads K/V head ``h // group``), the backward sums
+``dk`` / ``dv`` over a K/V head's query heads in VMEM (the strip-mined
+kernel holds the head's whole K and V once for all of them), and a
+``window`` joins the causal edge in the schedule: a kv block wholly
+behind a q block's window is skipped like one wholly above its
+diagonal, neither computed nor fetched (its index is clamped to a live
+block's), and every block that runs is masked whole, with both edges.
 
 Causal structure: the packed kernels carry it in the schedule, not in
 a mask over whole blocks (see "the causal structure, as a schedule"
@@ -31,9 +43,14 @@ no mask, and a block the diagonal crosses is walked in row sub-blocks
 over the columns each can see, with a mask on the one sub-tile the
 diagonal crosses.  :func:`causal_coverage` counts what that executes
 of the square (0.625 at 1024 tokens, 0.5005 needed; 0.75 when whole
-blocks of 512 were masked).  The single-head kernels still skip and
-mask by whole block: walked the same way their forward read slower on
-the chip (0.58 -> 0.67 ms a layer at head_dim 128, PR 54).
+blocks of 512 were masked).  The single-head kernels skip and mask by
+whole block: walked the same way their forward read slower on the chip
+(0.58 -> 0.67 ms a layer at head_dim 128, PR 54).  For them
+``coverage`` (``make_flash_attention_fn(...).coverage(S, H, D)``) counts
+the blocks that run, each whole, as a share of the ``S x S`` square,
+the mean over the seven score-sized matmuls of a train step, beside the
+share the layer needs (:func:`needed_coverage`): at 8192 tokens 0.54 of
+0.50 for a full layer, 0.19 of 0.117 for a window of 1024.
 
 Numerics: scores/stats in f32 regardless of input dtype; probability
 blocks are cast back to the value dtype for the MXU matmuls.  Numerics
@@ -45,7 +62,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -123,20 +143,70 @@ def attention_config(refresh: bool = False) -> AttentionConfig:
 # since roll(x, D/2) swaps halves and the sign pattern folds into sinm.
 # ---------------------------------------------------------------------------
 
-def rope_tables(positions, D: int, theta: float, dtype):
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """One layer kind's rotary frequencies.  ``factor == 1`` is the plain
+    ``theta ** (-2c / D)``; above it, static YaRN as transformers'
+    ``_compute_yarn_parameters`` has it: with ``low, high`` the
+    correction dims of ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max`` positions (floored and ceiled), channel ``c``
+    blends the plain frequency (below ``low``) into the plain one over
+    ``factor`` (above ``high``) along ``ramp_c = clip((c - low) / (high
+    - low), 0, 1)``, and cos and sin are both multiplied by
+    ``attention_factor``."""
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, D: int) -> np.ndarray:
+        half = D // 2
+        base = np.exp(-np.log(self.theta) * np.arange(half) / half)
+        if self.factor == 1.0:
+            return base.astype(np.float32)
+
+        def dim(rotations):
+            return (D * math.log(self.original_max
+                                 / (rotations * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        low = max(math.floor(dim(self.beta_fast)), 0)
+        high = min(math.ceil(dim(self.beta_slow)), D - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(half) - low) / (high - low), 0, 1)
+        return ((1 - ramp) * base + ramp * base / self.factor
+                ).astype(np.float32)
+
+
+def as_rope(rope) -> Rope:
+    """A :class:`Rope`, from one or from a plain ``theta``."""
+    return rope if isinstance(rope, Rope) else Rope(theta=float(rope))
+
+
+def rope_tables(positions, D: int, theta, dtype):
     """positions [S] (or any leading shape) -> (cos2, sinm) each
-    [*positions.shape, D] for the fused kernels."""
-    half = D // 2
-    freqs = jnp.exp(-jnp.log(theta) * jnp.arange(half) / half)
+    [*positions.shape, D] for the fused kernels.  ``theta``: a plain
+    base, or a layer kind's :class:`Rope`."""
+    rope = as_rope(theta)
+    if rope.factor == 1.0:
+        half = D // 2
+        freqs = jnp.exp(-jnp.log(rope.theta) * jnp.arange(half) / half)
+    else:
+        freqs = jnp.asarray(rope.inv_freq(D))
     angles = positions[..., None].astype(jnp.float32) * freqs
     cos = jnp.cos(angles)
     sin = jnp.sin(angles)
+    if rope.attention_factor != 1.0:
+        cos, sin = cos * rope.attention_factor, sin * rope.attention_factor
     cos2 = jnp.concatenate([cos, cos], -1).astype(dtype)
     sinm = jnp.concatenate([-sin, sin], -1).astype(dtype)
     return cos2, sinm
 
 
-def rope_rotate(x, positions, theta: float):
+def rope_rotate(x, positions, theta):
     """XLA-side RoPE: x [B, S, H, D] rotated per-position.
 
     ``positions`` is [S] (one schedule shared across the batch — the
@@ -178,8 +248,11 @@ def _rot_t(g, cos2, sinm, D: int):
 
 
 def _masked_scores(q, k, i, j, *, scale: float, causal: bool,
-                   block_q: int, block_k: int):
-    """f32 scaled q@k^T for blocks (i, j) with the causal mask applied."""
+                   block_q: int, block_k: int,
+                   window: Optional[int] = None):
+    """f32 scaled q@k^T for blocks (i, j) with the causal mask applied,
+    and the window's (key ``c`` is visible to row ``r`` iff ``c <= r``
+    and ``r - c < window``)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale       # [bq, bk]
@@ -190,24 +263,56 @@ def _masked_scores(q, k, i, j, *, scale: float, causal: bool,
         k_idx = (j * block_k
                  + jax.lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 1))
-        s = jnp.where(q_idx >= k_idx, s, _NEG_INF)
+        keep = q_idx >= k_idx
+        if window is not None:
+            keep = keep & (q_idx - k_idx < window)
+        s = jnp.where(keep, s, _NEG_INF)
     return s
 
 
-def _block_live(i, j, *, causal: bool, block_q: int, block_k: int):
-    """Whether kv block j contributes anything to q block i."""
-    return (j * block_k <= i * block_q + block_q - 1) if causal else True
+def _block_live(i, j, *, causal: bool, block_q: int, block_k: int,
+                window: Optional[int] = None):
+    """Whether kv block j contributes anything to q block i: not wholly
+    above the diagonal, nor wholly behind the window's lower edge."""
+    if not causal:
+        return True
+    live = j * block_k <= i * block_q + block_q - 1
+    if window is not None:
+        live = live & (j * block_k + block_k - 1 >= i * block_q - window + 1)
+    return live
+
+
+def _live_range(i, *, causal: bool, block_q: int, block_k: int,
+                window: Optional[int], num_kv: int):
+    """(first, last) kv block that :func:`_block_live` passes for q
+    block ``i``: what an index map clamps to, so that a dead block is a
+    block already fetched."""
+    if not causal:
+        return 0, num_kv - 1
+    last = jnp.minimum((i * block_q + block_q - 1) // block_k, num_kv - 1)
+    if window is None:
+        return 0, last
+    return jnp.maximum(i * block_q - window + 1, 0) // block_k, last
+
+
+def _live_kv_block(i, j, **schedule):
+    """``j`` clamped to :func:`_live_range` of q block ``i``: what a K/V
+    index map returns, so that a dead step fetches nothing new."""
+    if not schedule["causal"]:
+        return j
+    return jnp.clip(j, *_live_range(i, **schedule))
 
 
 def _grad_blocks(q, k, v, do, lse, delta, i, j, *, scale: float,
-                 causal: bool, block_q: int, block_k: int):
+                 causal: bool, block_q: int, block_k: int,
+                 window: Optional[int] = None):
     """Shared backward block math: (p [bq,bk] f32, ds [bq,bk] f32).
 
     p = exp(s - lse) recomputed from the block scores; ds is the score
     gradient.  dq/dk/dv follow as single matmuls against k/q/do in the
     caller (which differ per kernel in what they accumulate)."""
     s = _masked_scores(q, k, i, j, scale=scale, causal=causal,
-                       block_q=block_q, block_k=block_k)
+                       block_q=block_q, block_k=block_k, window=window)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -428,7 +533,7 @@ def _causal_walk(d, offsets, begin, *, causal: bool, block_q: int,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
                 block_q: int, block_k: int, num_kv: int,
-                has_rope: bool):
+                has_rope: bool, window: Optional[int] = None):
     if has_rope:
         (cq_ref, sq_ref, ck_ref, sk_ref,
          o_ref, lse_ref, acc_sc, m_sc, l_sc) = rest
@@ -443,7 +548,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
         l_sc[:] = jnp.zeros_like(l_sc)
 
     @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k))
+                         block_k=block_k, window=window))
     def _compute():
         q = q_ref[0, 0]                      # [bq, D]
         k = k_ref[0, 0]                      # [bk, D]
@@ -453,7 +558,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
             q = _rot(q, cq_ref[...], sq_ref[...], D)
             k = _rot(k, ck_ref[...], sk_ref[...], D)
         s = _masked_scores(q, k, i, j, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k,
+                           window=window)
         m_prev = m_sc[:]                      # [bq, 128] (col-bcast)
         m_cur = jnp.max(s, axis=1, keepdims=True)          # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)                 # [bq, 128]
@@ -476,21 +582,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
 
 
 def _fwd(q, k, v, *, scale: float, causal: bool,
-         block_q: int, block_k: int, rope=None):
-    """q,k,v: [B, H, S, D] -> (o [B, H, S, D],
+         block_q: int, block_k: int, rope=None,
+         window: Optional[int] = None):
+    """q: [B, H, S, D]; k, v: [B, Hkv, Sk, D], query head ``h`` reading
+    K/V head ``h // (H // Hkv)`` -> (o [B, H, S, D],
     lse [B, H, S // bq, bq, STATS_LANES] f32 — lane-padded row stats).
 
     ``rope``: optional (cos2 [S, D], sinm [S, D]) tables from
-    ``rope_tables``; q/k blocks are rotated in-kernel."""
+    ``rope_tables``; q/k blocks are rotated in-kernel.  ``window``: a
+    row sees the ``window`` keys up to its own.  A kv block that is
+    dead for a q block (above the diagonal, behind the window) is not
+    computed, and not fetched either: its index is clamped to the
+    nearest live block's, which is in VMEM already."""
     B, H, S, D = q.shape
     Sk = k.shape[2]
+    group = H // k.shape[1]
     bq, bk = min(block_q, S), min(block_k, Sk)
     grid = (B, H, S // bq, Sk // bk)
     num_kv = grid[3]
 
+    kv_block = functools.partial(_live_kv_block, causal=causal, block_q=bq,
+                                 block_k=bk, window=window, num_kv=num_kv)
+
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        num_kv=num_kv, has_rope=rope is not None)
+        num_kv=num_kv, has_rope=rope is not None, window=window)
     rope_args, rope_specs = (), []
     if rope is not None:
         cos2, sinm = rope
@@ -498,8 +614,8 @@ def _fwd(q, k, v, *, scale: float, causal: bool,
         rope_specs = [
             pl.BlockSpec((bq, D), lambda b, h, i, j: (i, 0)),
             pl.BlockSpec((bq, D), lambda b, h, i, j: (i, 0)),
-            pl.BlockSpec((bk, D), lambda b, h, i, j: (j, 0)),
-            pl.BlockSpec((bk, D), lambda b, h, i, j: (j, 0)),
+            pl.BlockSpec((bk, D), lambda b, h, i, j: (kv_block(i, j), 0)),
+            pl.BlockSpec((bk, D), lambda b, h, i, j: (kv_block(i, j), 0)),
         ]
     o, lse = pl.pallas_call(
         kernel,
@@ -509,8 +625,10 @@ def _fwd(q, k, v, *, scale: float, causal: bool,
                                  "arbitrary")),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (
+                b, h // group, kv_block(i, j), 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (
+                b, h // group, kv_block(i, j), 0)),
             *rope_specs,
         ],
         out_specs=[
@@ -705,7 +823,8 @@ def _fwd_pack2(q, k, v, *, scale: float, causal: bool, block_q: int,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_sc, *, scale: float, causal: bool,
-                   block_q: int, block_k: int, num_kv: int):
+                   block_q: int, block_k: int, num_kv: int,
+                   window: Optional[int] = None):
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -713,13 +832,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
     @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k))
+                         block_k=block_k, window=window))
     def _compute():
         k = k_ref[0, 0]
         _, ds = _grad_blocks(
             q_ref[0, 0], k, v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0, 0][:, 0:1], delta_ref[0, 0, 0][:, 0:1], i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            window=window)
         dq_sc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -732,8 +852,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *rest, scale: float, causal: bool, block_q: int,
                       block_k: int, num_q: int, num_kv: int,
-                      has_rope: bool):
-    """Strip-mined fused backward: dq, dk, dv in one pass over (b, h, i).
+                      has_rope: bool, group: int = 1,
+                      window: Optional[int] = None):
+    """Strip-mined fused backward: dq, dk, dv in one pass over (b, K/V
+    head, query head of its group, i).
 
     The two-kernel backward (`_bwd_dq_kernel` + `_bwd_dkv_kernel`)
     recomputes the score block and dp in each kernel — 2 extra
@@ -747,8 +869,12 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     score matmuls and, just as importantly on TPU, of the VPU
     exp/mask work that otherwise rivals the MXU time at head_dim 64.
     dq accumulates in VMEM scratch per q block; dk/dv accumulate in
-    [Sk, D] scratch across the sequential i sweep (VMEM-bounded: the
-    `_bwd` dispatcher falls back to the two-kernel path for long Sk).
+    [Sk, D] scratch across the sequential sweep over the ``group``
+    query heads that share the K/V head and their q blocks, so K and V
+    are fetched, and the rotated K made, once a K/V head
+    (VMEM-bounded: the `_bwd` dispatcher falls back to the two-kernel
+    path for long Sk).  With ``window``, the strips wholly behind a q
+    block's window are skipped like the ones above its diagonal.
 
     With ``has_rope``, q/k are rotated in-kernel for the score
     recompute; score-gradients land on the *rotated* q/k, so dq takes
@@ -761,9 +887,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, krot_sc) = rest
     else:
         dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
-    i = pl.program_id(2)                        # q block index
+    g, i = pl.program_id(2), pl.program_id(3)   # query head, q block
 
-    @pl.when(i == 0)
+    @pl.when((g == 0) & (i == 0))
     def _init_kv():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
@@ -792,7 +918,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p, ds = _grad_blocks(
             q, k, v_ref[0, 0], do, lse, delta, i, 0,
             scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k)
+            block_k=block_k, window=window)
         dv_sc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bk, D]
@@ -808,7 +934,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             lo, hi = j * block_k, (j + 1) * block_k
 
             @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                                 block_k=block_k))
+                                 block_k=block_k, window=window))
             def _strip(j=j, lo=lo, hi=hi):
                 if has_rope:
                     k = krot_sc[lo:hi, :]
@@ -817,7 +943,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 p, ds = _grad_blocks(
                     q, k, v_ref[0, 0, lo:hi, :], do, lse, delta, i, j,
                     scale=scale, causal=causal, block_q=block_q,
-                    block_k=block_k)
+                    block_k=block_k, window=window)
                 dv_sc[lo:hi, :] += jax.lax.dot_general(
                     p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)  # [bk, D]
@@ -832,7 +958,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq = _rot_t(dq, cq_ref[...], sq_ref[...], D)
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
-    @pl.when(i == num_q - 1)
+    @pl.when((g == group - 1) & (i == num_q - 1))
     def _finalize():
         dk = dk_sc[:]
         if has_rope:
@@ -943,23 +1069,26 @@ def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
                     causal: bool, block_q: int, block_k: int,
-                    num_q: int):
-    j, i = pl.program_id(2), pl.program_id(3)   # kv outer, q inner
+                    num_q: int, group: int = 1,
+                    window: Optional[int] = None):
+    # kv block outer; the group's query heads, then their q blocks, inner
+    j, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
-    @pl.when(i == 0)
+    @pl.when((g == 0) & (i == 0))
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
     @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k))
+                         block_k=block_k, window=window))
     def _compute():
         q = q_ref[0, 0]
         do = do_ref[0, 0]
         p, ds = _grad_blocks(
             q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, 0, 0][:, 0:1],
             delta_ref[0, 0, 0][:, 0:1], i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            window=window)
         dv_sc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bk, D]
@@ -967,22 +1096,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bk, D]
 
-    @pl.when(i == num_q - 1)
+    @pl.when((g == group - 1) & (i == num_q - 1))
     def _finalize():
         dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
 
 
+# The strip-mined backward at its gate's edge (8192 x 128: K, V, their
+# RoPE tables and dk, dv double-buffered, two f32 accumulators and the
+# rotated K) needs 34 MiB of VMEM, over Mosaic's default 16; shapes
+# under _BWD_VMEM_DEFAULT_ROWS keep the default limit and lower as they
+# always have.
+_BWD_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# the two f32 [Sk, D] accumulators the strip-mined backward may hold
+_FUSED_BWD_SCRATCH_BYTES = 8 * 1024 * 1024
+_BWD_VMEM_DEFAULT_ROWS = 2048 * 128
+
+
 def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
-         block_q: int, block_k: int, rope=None):
+         block_q: int, block_k: int, rope=None,
+         window: Optional[int] = None):
+    """-> (dq [B, H, S, D], dk, dv [B, Hkv, Sk, D]): the K/V gradients
+    are summed over the ``H // Hkv`` query heads of a K/V head inside
+    the kernels' grids, so they are written once, at K/V's size."""
     B, H, S, D = q.shape
-    Sk = k.shape[2]
+    Hkv, Sk = k.shape[1], k.shape[2]
+    group = H // Hkv
     bq, bk = min(block_q, S), min(block_k, Sk)
     num_q, num_kv = S // bq, Sk // bk
-    if lse.shape[3] != bq:
-        # fwd ran with a different q block; the stats are [.., S, LANES]
-        # rows underneath — regroup to this pass's blocking
-        lse = lse.reshape(B, H, num_q, bq, STATS_LANES)
+    # the forward's stats arrive one value a row ([B, H, S // fwd bq,
+    # fwd bq]): regroup to this pass's blocking and pad to the lanes
+    lse = jnp.broadcast_to(lse.reshape(B, H, num_q, bq, 1),
+                           (B, H, num_q, bq, STATS_LANES))
     delta = jnp.broadcast_to(
         jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                 axis=-1).reshape(B, H, num_q, bq, 1),
@@ -992,35 +1137,40 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
     # and the kernel walks it in bk strips (skipping causally-dead
     # ones).  [Sk, D] f32 scratch x2 bounds it to moderate Sk; longer
     # sequences take the two-kernel path below.
-    if Sk * D * 4 * 2 <= 8 * 1024 * 1024:
-        qs = pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0))
-        ks = pl.BlockSpec((1, 1, Sk, D), lambda b, h, i: (b, h, 0, 0))
+    if Sk * D * 4 * 2 <= _FUSED_BWD_SCRATCH_BYTES:
+        qs = pl.BlockSpec((1, 1, bq, D),
+                          lambda b, h, g, i: (b, h * group + g, i, 0))
+        ks = pl.BlockSpec((1, 1, Sk, D), lambda b, h, g, i: (b, h, 0, 0))
         rs = pl.BlockSpec((1, 1, 1, bq, STATS_LANES),
-                          lambda b, h, i: (b, h, i, 0, 0))
+                          lambda b, h, g, i: (b, h * group + g, i, 0, 0))
         rope_args, rope_specs = (), []
         if rope is not None:
             cos2, sinm = rope
             rope_args = (cos2, sinm, cos2, sinm)
             rope_specs = [
-                pl.BlockSpec((bq, D), lambda b, h, i: (i, 0)),
-                pl.BlockSpec((bq, D), lambda b, h, i: (i, 0)),
-                pl.BlockSpec((Sk, D), lambda b, h, i: (0, 0)),
-                pl.BlockSpec((Sk, D), lambda b, h, i: (0, 0)),
+                pl.BlockSpec((bq, D), lambda b, h, g, i: (i, 0)),
+                pl.BlockSpec((bq, D), lambda b, h, g, i: (i, 0)),
+                pl.BlockSpec((Sk, D), lambda b, h, g, i: (0, 0)),
+                pl.BlockSpec((Sk, D), lambda b, h, g, i: (0, 0)),
             ]
+        limit = ({} if Sk * D <= _BWD_VMEM_DEFAULT_ROWS
+                 else {"vmem_limit_bytes": _BWD_VMEM_LIMIT_BYTES})
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, scale=scale,
                               causal=causal, block_q=bq, block_k=bk,
                               num_q=num_q, num_kv=num_kv,
-                              has_rope=rope is not None),
-            grid=(B, H, num_q),
+                              has_rope=rope is not None, group=group,
+                              window=window),
+            grid=(B, Hkv, group, num_q),
             compiler_params=_CompilerParams(
                 dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")),
+                                     "arbitrary", "arbitrary"),
+                **limit),
             in_specs=[qs, ks, ks, qs, rs, rs, *rope_specs],
             out_specs=[qs, ks, ks],
             out_shape=[jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-                       jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
-                       jax.ShapeDtypeStruct((B, H, Sk, D), v.dtype)],
+                       jax.ShapeDtypeStruct((B, Hkv, Sk, D), k.dtype),
+                       jax.ShapeDtypeStruct((B, Hkv, Sk, D), v.dtype)],
             scratch_shapes=(
                 [pltpu.VMEM((bq, D), jnp.float32),
                  pltpu.VMEM((Sk, D), jnp.float32),
@@ -1033,14 +1183,19 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
     assert rope is None, \
         "fused rope requires the strip-mined backward (moderate Sk)"
 
+    kv_block = functools.partial(_live_kv_block, causal=causal, block_q=bq,
+                                 block_k=bk, window=window, num_kv=num_kv)
+
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
-    k_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0))
+    k_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (
+        b, h // group, kv_block(i, j), 0))
     r_spec = pl.BlockSpec((1, 1, 1, bq, STATS_LANES),
                           lambda b, h, i, j: (b, h, i, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, num_kv=num_kv),
+                          block_q=bq, block_k=bk, num_kv=num_kv,
+                          window=window),
         grid=(B, H, num_q, num_kv),
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -1052,22 +1207,26 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
         interpret=_use_interpret(),
     )(q, k, v, do, lse, delta)
 
-    # kv-outer grid: index maps see (b, h, j, i)
-    q_spec2 = pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0))
-    k_spec2 = pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0))
-    r_spec2 = pl.BlockSpec((1, 1, 1, bq, STATS_LANES),
-                           lambda b, h, j, i: (b, h, i, 0, 0))
+    # kv-outer grid: index maps see (b, K/V head, j, query head, i)
+    q_spec2 = pl.BlockSpec(
+        (1, 1, bq, D), lambda b, h, j, g, i: (b, h * group + g, i, 0))
+    k_spec2 = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, j, g, i: (b, h, j, 0))
+    r_spec2 = pl.BlockSpec(
+        (1, 1, 1, bq, STATS_LANES),
+        lambda b, h, j, g, i: (b, h * group + g, i, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, num_q=num_q),
-        grid=(B, H, num_kv, num_q),
+                          block_q=bq, block_k=bk, num_q=num_q,
+                          group=group, window=window),
+        grid=(B, Hkv, num_kv, group, num_q),
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary", "arbitrary")),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
         out_specs=[k_spec2, k_spec2],
-        out_shape=[jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sk, D), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((B, Hkv, Sk, D), k.dtype),
+                   jax.ShapeDtypeStruct((B, Hkv, Sk, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=_use_interpret(),
@@ -1146,53 +1305,58 @@ def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
 # public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_bhsd(q, k, v, scale, causal, block_q, block_k,
-                bwd_block_q, bwd_block_k):
+                bwd_block_q, bwd_block_k, window=None):
     o, _ = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k)
+                block_k=block_k, window=window)
     return o
 
 
 def _flash_bhsd_fwd(q, k, v, scale, causal, block_q, block_k,
-                    bwd_block_q, bwd_block_k):
+                    bwd_block_q, bwd_block_k, window=None):
     o, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k)
-    return o, (q, k, v, o, lse)
+                  block_k=block_k, window=window)
+    # the row stats are kept one value a row ([B, H, S]), not the
+    # kernel's lane-padded block: at 8192 x 32 heads the padding is
+    # 134 MB a sequence a layer held from forward to backward
+    return o, (q, k, v, o, lse[..., 0])
 
 
 def _flash_bhsd_bwd(scale, causal, block_q, block_k, bwd_block_q,
-                    bwd_block_k, res, do):
+                    bwd_block_k, window, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _bwd(q, k, v, o, lse, do, scale=scale, causal=causal,
-                      block_q=bwd_block_q, block_k=bwd_block_k)
+                      block_q=bwd_block_q, block_k=bwd_block_k,
+                      window=window)
     return dq, dk, dv
 
 
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_bhsd_rope(q, k, v, cos2, sinm, scale, causal, block_q,
-                     block_k, bwd_block_q, bwd_block_k):
+                     block_k, bwd_block_q, bwd_block_k, window=None):
     o, _ = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k, rope=(cos2, sinm))
+                block_k=block_k, rope=(cos2, sinm), window=window)
     return o
 
 
 def _flash_bhsd_rope_fwd(q, k, v, cos2, sinm, scale, causal, block_q,
-                         block_k, bwd_block_q, bwd_block_k):
+                         block_k, bwd_block_q, bwd_block_k, window=None):
     o, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, rope=(cos2, sinm))
-    return o, (q, k, v, cos2, sinm, o, lse)
+                  block_k=block_k, rope=(cos2, sinm), window=window)
+    return o, (q, k, v, cos2, sinm, o, lse[..., 0])
 
 
 def _flash_bhsd_rope_bwd(scale, causal, block_q, block_k, bwd_block_q,
-                         bwd_block_k, res, do):
+                         bwd_block_k, window, res, do):
     q, k, v, cos2, sinm, o, lse = res
     dq, dk, dv = _bwd(q, k, v, o, lse, do, scale=scale, causal=causal,
                       block_q=bwd_block_q, block_k=bwd_block_k,
-                      rope=(cos2, sinm))
+                      rope=(cos2, sinm), window=window)
     return dq, dk, dv, None, None
 
 
@@ -1303,6 +1467,35 @@ def segment_attention(q, k, v, segment_ids, *, causal: bool = True,
     return (o / jnp.maximum(l_q, 1e-30)).astype(q.dtype)
 
 
+def xla_attention(q, k, v, *, causal: bool = True,
+                  scale: Optional[float] = None,
+                  window: Optional[int] = None):
+    """The einsum formulation of what the single-head kernels compute:
+    q [B, S, H, D] against k, v [B, Sk, Hkv, D], query head ``h`` on K/V
+    head ``h // (H // Hkv)``, key ``c`` visible to row ``r`` iff ``c <=
+    r`` (``causal``) and ``r - c < window``; float32 scores and softmax.
+    What the kernels are tested against, and what runs where no grid
+    tiles the shape."""
+    B, S, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+    if scale is None:
+        scale = D ** -0.5
+    qg = q.reshape(B, S, Hkv, H // Hkv, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        r = jnp.arange(S)[:, None] + (Sk - S)
+        c = jnp.arange(Sk)[None, :]
+        keep = c <= r
+        if window is not None:
+            keep = keep & (r - c < window)
+        s = jnp.where(keep, s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, S, H, D).astype(q.dtype)
+
+
 def supports(S: int, Sk: int, D: int, *, block_q: int = 1024,
              block_k: int = 1024) -> bool:
     """Shapes the kernel grid can tile (fallback to einsum otherwise)."""
@@ -1357,13 +1550,21 @@ def uses_pack2(S: int, Sk: int, H: int, D: int, *, causal: bool = True,
 
 
 def causal_coverage(S: int, Sk: int, block_q: int, block_k: int,
-                    sub: Optional[int]) -> float:
+                    sub: Optional[int],
+                    window: Optional[int] = None) -> float:
     """The share of the ``S x Sk`` score square a causal schedule of
     ``block_q x block_k`` blocks executes when its diagonal blocks are
     walked in ``sub``-edged sub-tiles (None: masked whole) — counted by
     the predicates and the walk the packed kernels unroll with.  Causal
-    attention needs ``1/2 + 1/(2*S)`` of a square."""
+    attention needs ``1/2 + 1/(2*S)`` of a square.  With ``window``
+    (the single-head schedule's: whole blocks, ``sub`` None) the blocks
+    :func:`_block_live` passes are counted, each whole."""
     bq, bk = min(block_q, S), min(block_k, Sk)
+    if window is not None:
+        live = sum(bool(_block_live(i, j, causal=True, block_q=bq,
+                                    block_k=bk, window=window))
+                   for i in range(S // bq) for j in range(Sk // bk))
+        return live * bq * bk / (S * Sk)
     done = 0
     for i in range(S // bq):
         for j in range(Sk // bk):
@@ -1376,16 +1577,29 @@ def causal_coverage(S: int, Sk: int, block_q: int, block_k: int,
     return done / (S * Sk)
 
 
+def needed_coverage(S: int, window: Optional[int] = None) -> float:
+    """The share of the ``S x S`` square causal attention needs: the
+    pairs a row sees, its own key and at most ``window - 1`` before."""
+    if window is None or window >= S:
+        return (S + 1) / (2 * S)
+    return (window * (window + 1) / 2 + (S - window) * window) / (S * S)
+
+
 def train_causal_coverage(S: int, H: int, D: int, *, block_q: int = 1024,
                           block_k: int = 1024,
-                          pack2: Optional[bool] = None) -> float:
+                          pack2: Optional[bool] = None,
+                          window: Optional[int] = None,
+                          grouped: bool = False) -> float:
     """:func:`causal_coverage` of the schedule :func:`flash_attention`
     takes for a causal train step at this shape, the mean over its seven
     score-sized matmuls (two forward, five backward, each under its own
-    blocks).  The single-head schedule masks its diagonal blocks whole;
-    a shape no grid tiles runs the einsum, the whole square."""
-    plan = _pack2_plan(S, S, H, D, True, block_q, block_k, None, None,
-                       pack2)
+    blocks).  The single-head schedule, which is also the one a
+    ``window`` or ``grouped`` K/V heads take, skips the blocks wholly
+    above the diagonal or behind the window and masks every block it
+    runs, whole; a shape no grid tiles runs the einsum, the whole
+    square."""
+    plan = None if (window is not None or grouped) else _pack2_plan(
+        S, S, H, D, True, block_q, block_k, None, None, pack2)
     if plan is not None:
         fwd, bwd = plan[:2], plan[2:]
         subs = _causal_sub(*fwd), _causal_sub(*bwd)
@@ -1397,8 +1611,8 @@ def train_causal_coverage(S: int, H: int, D: int, *, block_q: int = 1024,
                 and supports(S, S, D, block_q=bwd[0], block_k=bwd[1])):
             return 1.0
         subs = None, None
-    return (2 * causal_coverage(S, S, *fwd, subs[0])
-            + 5 * causal_coverage(S, S, *bwd, subs[1])) / 7
+    return (2 * causal_coverage(S, S, *fwd, subs[0], window)
+            + 5 * causal_coverage(S, S, *bwd, subs[1], window)) / 7
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -1407,10 +1621,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     positions=None,
-                    rope_theta: float = 10000.0,
+                    rope_theta=10000.0,
                     pack2: Optional[bool] = None,
-                    segment_ids=None):
-    """Fused causal attention.  q,k,v: [B, S, H, D] -> [B, S, H, D].
+                    segment_ids=None,
+                    window: Optional[int] = None):
+    """Fused causal attention.  q: [B, S, H, D]; k, v: [B, Sk, Hkv, D]
+    with ``H`` a multiple of ``Hkv`` (query head ``h`` reads K/V head
+    ``h // (H // Hkv)``) -> [B, S, H, D].
 
     Drop-in for ``ray_tpu.parallel.ring_attention.local_attention``;
     falls back to the einsum path for shapes the grid cannot tile.
@@ -1421,11 +1638,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
     blocks unmasked, diagonal blocks walked by sub-tile), so a big
     block costs it no coverage and it takes 1024 forward and backward
     (fewer grid steps, K/V fetched and rotated once: measured on a
-    v5e, PR 54).  The single-head schedule skips whole blocks only:
-    its forward takes one big block and masks it (per-grid-step
-    overhead outweighs the causal skip there, and so did the walk's
-    smaller matmuls when it was tried, PR 54), its backward walks kv
-    strips of 512 inside the kernel and skips the causally-dead ones.
+    v5e, PR 54).  The single-head schedule (head_dim 128, odd head
+    counts, grouped K/V heads, window layers) skips whole blocks and
+    masks every block it runs: its forward takes blocks of 1024 (one
+    block at 1024 tokens: per-grid-step overhead outweighs the causal
+    skip there, and so did the walk's smaller matmuls when it was
+    tried, PR 54; 36 of 64 block pairs at 8192, 15 with a window of
+    1024), its backward walks kv strips of 512 inside the kernel and
+    skips the dead ones, above the diagonal or behind the window.
 
     ``positions`` [S] enables fused RoPE: q/k are rotated inside the
     kernels (zero extra HBM passes) when the kv sequence fits one
@@ -1437,6 +1657,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     counts, other head dims and untileable shapes use the single-head
     schedule regardless.
 
+    ``window`` (a row sees its own key and the ``window - 1`` before
+    it) and K/V heads fewer than query heads take the single-head
+    schedule: its grids carry the K/V head in their index maps, skip the
+    blocks behind the window like the ones above the diagonal, and sum
+    ``dk`` / ``dv`` over a K/V head's query heads in VMEM.
+    ``rope_theta`` may be a layer kind's :class:`Rope`.
+
     ``segment_ids`` [B, S] (sample-packed batches) is a reasoned
     decline of every Pallas schedule: the per-batch block-diagonal
     mask has no kernel yet, so RoPE (when ``positions`` is given) is
@@ -1444,14 +1671,26 @@ def flash_attention(q, k, v, *, causal: bool = True,
     runs — loud in timelines as ``attn/segment_xla``.
     """
     B, S, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hkv = k.shape[1], k.shape[2]
+    grouped = Hkv != H
+    if H % Hkv:
+        raise ValueError(f"{H} query heads do not divide into {Hkv} "
+                         "K/V heads")
     cfg = attention_config()
     if scale is None:
         scale = D ** -0.5
     if positions is not None and S != Sk:
         raise ValueError(f"rope needs q and kv positions to match: "
                          f"S={S} vs Sk={Sk}")
+    if window is not None and not causal:
+        raise ValueError("a window is a causal layer's: causal=False "
+                         "with window= has no kernel or formulation")
     if segment_ids is not None:
+        if window is not None or grouped:
+            raise NotImplementedError(
+                "segment_ids (sample-packed batches) with a window or "
+                "grouped K/V heads: ops/attention.py:segment_attention "
+                "has neither")
         if positions is not None:
             q = rope_rotate(q, positions, rope_theta)
             k = rope_rotate(k, positions, rope_theta)
@@ -1459,8 +1698,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
             return segment_attention(q, k, v, segment_ids,
                                      causal=causal, scale=scale)
 
-    plan = _pack2_plan(S, Sk, H, D, causal, block_q, block_k,
-                       bwd_block_q, bwd_block_k, pack2)
+    plan = None if (window is not None or grouped) else _pack2_plan(
+        S, Sk, H, D, causal, block_q, block_k, bwd_block_q, bwd_block_k,
+        pack2)
     if plan is not None:
         pbq, pbk, pbwq, pbwk = plan
         Dp = 2 * D
@@ -1504,8 +1744,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
         q = rope_rotate(q, positions, rope_theta)
         k = rope_rotate(k, positions, rope_theta)
     if not kernel_ok:
-        from ray_tpu.parallel.ring_attention import local_attention
         with jax.named_scope("attn/xla"):
+            if window is not None or grouped:
+                return xla_attention(q, k, v, causal=causal, scale=scale,
+                                     window=window)
+            from ray_tpu.parallel.ring_attention import local_attention
             return local_attention(q, k, v, causal=causal, scale=scale)
     with jax.named_scope("attn/flash"):
         qt = jnp.swapaxes(q, 1, 2)
@@ -1515,10 +1758,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
             cos2, sinm = rope_tables(positions, D, rope_theta, q.dtype)
             o = _flash_bhsd_rope(qt, kt, vt, cos2, sinm, scale, causal,
                                  block_q, block_k, bwd_block_q,
-                                 bwd_block_k)
+                                 bwd_block_k, window)
         else:
             o = _flash_bhsd(qt, kt, vt, scale, causal, block_q,
-                            block_k, bwd_block_q, bwd_block_k)
+                            block_k, bwd_block_q, bwd_block_k, window)
         return jnp.swapaxes(o, 1, 2)
 
 
@@ -1743,8 +1986,11 @@ def decode_attention(q, k, v, lengths, page_table, layer=0, *,
 
 def make_flash_attention_fn(mesh=None, *, causal: bool = True,
                             block_q: int = 1024, block_k: int = 1024,
-                            rope_theta: Optional[float] = None,
-                            pack2: Optional[bool] = None):
+                            rope_theta=None,
+                            pack2: Optional[bool] = None,
+                            window: Optional[int] = None,
+                            kv_heads: Optional[int] = None,
+                            rope=None):
     """Mesh-aware flash attention (drop-in for ``make_ring_attention_fn``).
 
     A ``pallas_call`` has no SPMD partitioning rule, so on a >1-device
@@ -1760,26 +2006,56 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
     process-wide :func:`attention_config`); note a tp-sharded mesh
     hands each device its *local* head count, which is what the
     even-head gate sees.
+
+    One hook serves one kind of layer: ``window`` (its rows see that
+    many keys), ``kv_heads`` (K/V heads, where fewer than the query
+    heads; it is what the hook's counts are told, the kernels read it
+    from the shapes) and ``rope`` (the kind's :class:`Rope`, in place
+    of ``rope_theta``) are that kind's, and the hook carries them as
+    attributes with ``coverage(S, H, D)``: the share of the ``S x S``
+    square its schedule executes beside the share the layer needs.
+    On a mesh that shards heads (tp > 1) a K/V group has no rule here
+    and is refused.
     """
+    if rope is not None:
+        rope_theta = rope
     fn = functools.partial(flash_attention, causal=causal,
                            block_q=block_q, block_k=block_k,
                            pack2=pack2)
+    if window is not None:
+        fn = functools.partial(fn, window=window)
     if rope_theta is not None:
         fn = functools.partial(fn, rope_theta=rope_theta)
     one_device = mesh is None or getattr(mesh, "size", 1) <= 1
     tp_size = 1 if one_device else mesh.shape.get("tp", 1)
+    if kv_heads is not None and tp_size > 1:
+        raise NotImplementedError(
+            "grouped K/V heads on a mesh that shards heads (tp > 1): "
+            "make_flash_attention_fn's shard_map gives each device its "
+            "query heads' slice and knows no K/V head's")
 
     def causal_coverage(S: int, H: int, D: int) -> float:
         """The share of the score square the schedule this fn takes for
         ``H`` global heads executes (:func:`train_causal_coverage`)."""
         return train_causal_coverage(
             S, H // tp_size, D, block_q=block_q, block_k=block_k,
-            pack2=pack2) if causal else 1.0
+            pack2=pack2, window=window,
+            grouped=kv_heads not in (None, H)) if causal else 1.0
+
+    def coverage(S: int, H: int, D: int) -> dict:
+        return {"executed": causal_coverage(S, H, D),
+                "needed": needed_coverage(S, window) if causal else 1.0}
+
+    def marked(hook, fused: bool):
+        hook.fused_rope = fused
+        hook.causal_coverage = causal_coverage
+        hook.coverage = coverage
+        hook.window = window
+        hook.kv_heads = kv_heads
+        return hook
 
     if one_device:
-        fn.fused_rope = rope_theta is not None
-        fn.causal_coverage = causal_coverage
-        return fn
+        return marked(fn, rope_theta is not None)
 
     from jax.sharding import PartitionSpec as P
 
@@ -1822,9 +2098,7 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
                                         segment_ids)
             return sharded(q, k, v, positions)
 
-        wrapped.fused_rope = True
-        wrapped.causal_coverage = causal_coverage
-        return wrapped
+        return marked(wrapped, True)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec,) * 3,
                        out_specs=spec)
@@ -1836,9 +2110,7 @@ def make_flash_attention_fn(mesh=None, *, causal: bool = True,
             return sharded_seg(q, k, v, segment_ids)
         return sharded(q, k, v)
 
-    sharded_fn.fused_rope = False
-    sharded_fn.causal_coverage = causal_coverage
-    return sharded_fn
+    return marked(sharded_fn, False)
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,11 @@ token is dropped.  It is told which experts of a deployment it holds,
 routes over all of them, and computes the held experts' part by a
 grouped matrix product over the rows that picked them, and the identity
 experts' part; an expert held elsewhere adds nothing (the exchange that
-would bring its part is not run here).
+would bring its part is not run here).  A call nobody differentiates
+takes that loop; a differentiated one (a train step) takes the same
+layer as three grouped products over the sorted picks
+(``jax.lax.ragged_dot``), with a backward of its own, because a loop
+with a traced trip count has no reverse mode.
 """
 
 from __future__ import annotations
@@ -234,16 +238,236 @@ def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead):
     return out, jnp.sum(n > 0)
 
 
+def _at(w, lead):
+    return w[tuple(lead)] if lead else w
+
+
+def _ragged(lhs, rhs, sizes, transposed: bool = False):
+    """``lhs [M, k]`` against each group's ``rhs [G, k, n]`` (or, with
+    ``transposed``, ``rhs [G, n, k]``).  Always the canonical product of
+    ``jax.lax.ragged_dot``, the one the TPU compiler has a kernel for: a
+    product that contracts another dimension of ``rhs`` it expands into
+    a dense one over every group, sixteen times the work here (compiled
+    for a described v5e, PR 56), so the transpose is spelled out."""
+    return lax.ragged_dot(
+        lhs, jnp.swapaxes(rhs, 1, 2) if transposed else rhs, sizes)
+
+
+def _ragged_outer(a, b, sizes):
+    """``sum over a group's rows of a[m]^T b[m]``: [G, ka, kb], the
+    gradient of a grouped product in its matrices."""
+    return lax.ragged_dot_general(
+        a, b, sizes, lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
+
+
+def _pick_sum(rows, pos, ours):
+    """``out[t] = sum over t's picks k with ours[t, k] of rows[pos[t,
+    k]]``, float32: a gather of ``T x K`` rows, so nothing is scattered.
+    It is the dearest thing around the products: rows read in no order
+    take 43 ns each on a v5e (5.6 ms for 16384 x 8 rows of 2304) where
+    rows in ascending runs, ``x[token]``, take 7.6; a scatter-add of the
+    held rows alone, sorted by token or not, read 5.1-6.5 ms (PR 56)."""
+    T, K = pos.shape
+    picked = rows[pos.reshape(T * K)].reshape(T, K, -1)
+    return jnp.sum(jnp.where(ours[:, :, None], picked.astype(jnp.float32),
+                             0.0), axis=1)
+
+
+# A piece of the sorted picks, over what uniform routing fills, rounded
+# up to _PIECE_ROWS.  A piece that barely overflows pays a second
+# piece's fixed costs (~25 ms a layer in the routed 8k cell, PR 56):
+# half again is room for a draw's skew and for some of a router's drift.
+# Against one buffer for every pick the pieces read 42.4 k tokens/s for
+# 37.8 k on the chip there (the elementwise work and gathers on rows no
+# pick fills) and 1.3 GB less of the step's temporaries (AOT)
+_PIECE_HEADROOM = 1.5
+_PIECE_ROWS = 512
+
+
+def piece_rows(T: int, top_k: int, held: int, experts: int) -> int:
+    """Rows of one piece of the sorted picks (:func:`_sorted_experts_fwd`):
+    what the held experts take under uniform routing, ``T * top_k * held /
+    experts``, and half again; every pick's row (``T *
+    top_k``) where this chip holds every expert."""
+    want = _PIECE_HEADROOM * T * top_k * held / experts
+    return min(T * top_k, -(-int(want) // _PIECE_ROWS) * _PIECE_ROWS)
+
+
+def _sorted_picks(local, weight, held: int):
+    """The picks sorted by expert, the held ones first: for each sorted
+    row its token and weight (0 behind the last held pick), for each
+    pick its sorted row (``pos [T, K]``), and the first sorted row of
+    each held expert and the one past its last."""
+    T, K = local.shape
+    flat = local.reshape(T * K)
+    order = jnp.argsort(flat, stable=True)
+    token = (order // K).astype(jnp.int32)
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, K)
+    n = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], axis=0,
+                dtype=jnp.int32)                      # rows an expert
+    ends = jnp.cumsum(n)
+    live = jnp.arange(T * K) < ends[-1]
+    ws = jnp.where(live, weight.reshape(T * K)[order], 0.0)
+    return token, pos, ws, ends - n, ends
+
+
+def _piece(a: int, b: int, token, pos, ws, starts, ends, ours):
+    """Sorted rows ``a .. b - 1``: their tokens and weights, which of
+    them are held picks, each expert's rows among them, and the picks
+    ``[T, K]`` that lie in the piece with their row in it."""
+    n = jnp.clip(jnp.minimum(ends, b) - jnp.maximum(starts, a), 0, None)
+    live = jnp.arange(a, b) < ends[-1]
+    mine = ours & (pos >= a) & (pos < b)
+    # a pick outside the piece reads some row of it (masked where it is
+    # summed): rows spread over the piece, not one row for all of them
+    spread = jnp.arange(pos.size, dtype=jnp.int32).reshape(pos.shape) % (b - a)
+    return (token[a:b], ws[a:b], live, n, mine,
+            jnp.where(mine, pos - a, spread))
+
+
+def _pieces(M: int, piece: int):
+    return [(a, min(a + piece, M)) for a in range(0, M, piece)]
+
+
+def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
+                        piece):
+    """:func:`_grouped_experts` for a call that is differentiated: the
+    same sum, as grouped products over the picks sorted by expert (gate
+    and up as one product against the two matrices side by side).
+
+    Every pick has a row in the sorted order (``T * K``: no pick can be
+    dropped), the held picks first.  The rows are taken in static
+    pieces of ``piece`` rows: the first always, a later one only if a
+    held pick lies in it (``lax.cond``), so under any routing the work,
+    the gathers and the buffers that are touched follow the picks to
+    within a piece, and nothing is sized by the worst case but the
+    index vectors.  Inside a piece the products run over the groups'
+    own sizes; the rows behind the last held pick are never computed
+    and are masked wherever they are read.  The weight meets the hidden
+    product before the down projection, so the combine is a sum of
+    gathered rows.  Kept for the backward: the first piece's gate|up
+    product; a later piece computes its own again."""
+    T, K = local.shape
+    held = e_gate.shape[len(lead)]
+    ours = local < held
+    sort = _sorted_picks(local, weight, held)
+    w_gu, w_down = _gate_up(e_gate, e_up, lead), _at(e_down, lead)
+
+    def run(a, b):
+        token, ws, live, n, mine, at = _piece(a, b, *sort, ours)
+        gu = _ragged(x[token], w_gu, n)                       # [b - a, 2f]
+        y = _ragged(_hidden(gu, ws, live), w_down, n)
+        return _pick_sum(y, at, mine), gu
+
+    (first, *rest) = _pieces(T * K, piece)
+    out, gu = run(*first)
+    for a, b in rest:
+        out = lax.cond(sort[-1][-1] > a,
+                       lambda out, a=a, b=b: out + run(a, b)[0],
+                       lambda out: out, out)
+    hit = jnp.sum(sort[-1] > sort[-2])
+    return (out, hit), (x, local, weight, gu, e_gate, e_up, e_down, lead)
+
+
+def _gate_up(e_gate, e_up, lead):
+    return jnp.concatenate([_at(e_gate, lead), _at(e_up, lead)], axis=-1)
+
+
+def _hidden(gu, ws, live):
+    """``silu(gate) * up * weight`` of the live sorted rows, 0 behind
+    them; gu [M, 2f] -> [M, f]."""
+    g, u = jnp.split(gu.astype(jnp.float32), 2, axis=-1)
+    h = jax.nn.silu(g) * u * ws[:, None]
+    return jnp.where(live[:, None], h, 0.0).astype(gu.dtype)
+
+
+def _sorted_experts_bwd(piece, res, cts):
+    x, local, weight, gu_first, e_gate, e_up, e_down, lead = res
+    # what the backward computes again from the residuals (the sort, the
+    # gathered rows, the float32 view of gate|up, the hidden product)
+    # the compiler would otherwise share with the forward's and keep
+    # alive in between, a sorted buffer a layer: the barrier keeps them
+    # apart
+    x, local, weight, gu_first = lax.optimization_barrier(
+        (x, local, weight, gu_first))
+    T, K = local.shape
+    dt = x.dtype
+    held = e_gate.shape[len(lead)]
+    f = gu_first.shape[1] // 2
+    ours = local < held
+    sort = _sorted_picks(local, weight, held)
+    w_gu, w_down = _gate_up(e_gate, e_up, lead), _at(e_down, lead)
+    dout = cts[0].astype(dt)
+
+    def run(a, b, gu=None):
+        token, ws, live, n, mine, at = _piece(a, b, *sort, ours)
+        rows = live[:, None]
+        xs = x[token]
+        if gu is None:
+            gu = _ragged(xs, w_gu, n)
+        dy = jnp.where(rows, dout[token], 0)                   # [b - a, d]
+        dh = jnp.where(rows, _ragged(dy, w_down, n, True), 0
+                       ).astype(jnp.float32)                   # [b - a, f]
+        g, u = jnp.split(gu.astype(jnp.float32), 2, axis=-1)
+        sg = jax.nn.sigmoid(g)
+        act = g * sg
+        dws = jnp.sum(dh * act * u, axis=-1)
+        dh = dh * ws[:, None]
+        dgu = jnp.concatenate([dh * u * sg * (1.0 + g * (1.0 - sg)),
+                               dh * act], axis=-1).astype(dt)
+        dxs = jnp.where(rows, _ragged(dgu, w_gu, n, True), 0)
+        return (_pick_sum(dxs, at, mine),
+                jnp.where(mine, dws[at], 0.0),
+                _ragged_outer(xs, dgu, n),                     # [G, d, 2f]
+                _ragged_outer(_hidden(gu, ws, live), dy, n))
+
+    (first, *rest) = _pieces(T * K, piece)
+    grads = run(*first, gu_first)
+    for a, b in rest:
+        grads = lax.cond(
+            sort[-1][-1] > a,
+            lambda grads, a=a, b=b: jax.tree.map(jnp.add, grads, run(a, b)),
+            lambda grads: grads, grads)
+    dx, dweight, dw_gu, dw_down = grads
+    # nothing downstream waits for the matrices' gradients, and a
+    # scheduler free to put them off keeps every layer's sorted operands
+    # alive until it does: they leave with dx
+    dx, dw_gu, dw_down = lax.optimization_barrier((dx, dw_gu, dw_down))
+
+    def stacked(dw, w):
+        dw = dw.astype(w.dtype)
+        return jnp.zeros_like(w).at[tuple(lead)].set(dw) if lead else dw
+
+    return (dx.astype(dt), None, dweight, stacked(dw_gu[..., :f], e_gate),
+            stacked(dw_gu[..., f:], e_up), stacked(dw_down, e_down), None)
+
+
+def _loop_experts(x, local, weight, e_gate, e_up, e_down, lead, piece):
+    return _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead)
+
+
+# one expert layer, two lowerings: JAX runs the primal (the loop over
+# the tiles the picks fill) where no gradient is taken, and the rule
+# (grouped products over the sorted picks) wherever one is
+_experts = jax.custom_vjp(_loop_experts, nondiff_argnums=(7,))
+_experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)
+
+
 def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
                  held: Sequence[int], n_routed: int, top_k: int,
-                 scale: float, valid=None, lead=()):
+                 scale: float, valid=None, lead=(),
+                 renormalise: bool = False, with_load: bool = False):
     """One chip's part of a dropless expert layer on x [T, d] -> (its
     output [T, d], counts [len(MOE_COUNTS)] int32).
 
     ``router`` [d, E] scores every expert of the deployment in float32
     (softmax); the ``top_k`` of ``score + router_bias`` are a row's
-    picks, weighted by their scores as they are (no renormalisation)
-    and by ``scale``.  Experts ``0 .. n_routed - 1`` are swiglu experts,
+    picks, weighted by their scores as they are, or with
+    ``renormalise`` by their scores over the sum of the row's ``top_k``
+    picked scores wherever those experts live (so the shares of a
+    deployment add up to the uncut layer), and by ``scale``.  Experts ``0 .. n_routed - 1`` are swiglu experts,
     of which this chip holds ``held`` (their ids, in the order of
     ``e_gate, e_up`` [*lead, held, d, f] and ``e_down`` [*lead, held,
     f, d], read at the indices ``lead``: stacked layers); experts
@@ -251,8 +475,14 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
     where the row lives.  A pick on a routed expert held elsewhere adds
     nothing.  ``valid`` [T] bool marks the rows that are tokens of a
     sequence (absent: all): the others pick nothing and count nothing.
+    ``with_load`` appends the rows each held expert took ([held] int32)
+    to what is returned.
     Device scopes: ``route``, ``experts``, ``identity`` (the caller
-    names the layer)."""
+    names the layer).  A differentiated call computes the held experts'
+    part by grouped products over the sorted picks and has gradients in
+    ``x``, ``router`` (through the weights) and the three expert
+    matrices; a call nobody differentiates lowers as it always has
+    (:func:`_grouped_experts`)."""
     T, d = x.shape
     E = router.shape[1]
     if valid is None:
@@ -264,6 +494,8 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
         score = jax.nn.softmax(logits, axis=-1)
         _, pick = lax.top_k(score + router_bias.astype(jnp.float32), top_k)
         weight = jnp.take_along_axis(score, pick, axis=-1)       # [T, K]
+        if renormalise:
+            weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
         local_of = np.full((E,), len(held), np.int32)
         local_of[list(held)] = np.arange(len(held))
         local = jnp.where(valid[:, None], jnp.asarray(local_of)[pick],
@@ -271,12 +503,18 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
         identity = valid[:, None] & (pick >= n_routed)
         ours = local < len(held)
     with jax.named_scope("experts"):
-        out, hit = _grouped_experts(x, local, jnp.where(ours, weight, 0.0),
-                                    e_gate, e_up, e_down, tuple(lead))
+        out, hit = _experts(x, local, jnp.where(ours, weight, 0.0),
+                            e_gate, e_up, e_down, tuple(lead),
+                            piece_rows(T, top_k, len(held), n_routed))
     with jax.named_scope("identity"):
         out = out + x.astype(jnp.float32) * jnp.sum(
             jnp.where(identity, weight, 0.0), -1, keepdims=True)
     rows = jnp.sum(valid)
     counts = jnp.stack([rows, jnp.sum(ours), jnp.sum(identity),
                         rows * top_k, hit, jnp.int32(1)]).astype(jnp.int32)
-    return (scale * out).astype(x.dtype), counts
+    out = (scale * out).astype(x.dtype)
+    if with_load:
+        load = jnp.sum(local[:, :, None] == jnp.arange(len(held)),
+                       axis=(0, 1), dtype=jnp.int32)
+        return out, counts, load
+    return out, counts

@@ -150,8 +150,11 @@ def overlap_supported(cfg, mesh) -> Optional[str]:
     for axis in ("sp", "pp", "ep"):
         if mesh.shape.get(axis, 1) > 1:
             return f"mesh has {axis}>1 (overlap path covers dp/fsdp/tp)"
-    if getattr(cfg, "n_experts", 0) > 0:
+    if getattr(cfg, "n_experts", 0) > 0 or getattr(cfg, "dropless", False):
         return "MoE blocks not supported by the overlap schedule"
+    if not getattr(cfg, "plain_attention", True):
+        return ("window layers and grouped K/V heads not supported by "
+                "the overlap schedule's einsum attention")
     fsdp = mesh.shape.get("fsdp", 1)
     tp = mesh.shape.get("tp", 1)
     if cfg.d_model % fsdp:

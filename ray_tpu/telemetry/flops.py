@@ -41,19 +41,27 @@ def chip_peak_tflops(device=None) -> float:
         "its source, or pass the peak explicitly")
 
 
-def gpt_fwd_flops_per_token(cfg, seq: int, *, causal: bool = True) -> float:
+def gpt_fwd_flops_per_token(cfg, seq: int, *, causal: bool = True,
+                            held_picks_per_token: Optional[float] = None
+                            ) -> float:
     """Matmul FLOPs per token of ONE forward pass of ``cfg`` at ``seq``.
 
     Counted per token of a length-``seq`` sequence (2 FLOPs per MAC):
 
-    - qkv projections: ``3 · 2·d·H·hd``
+    - qkv projections: ``2·d·hd·(H + 2·Hkv)`` (K and V have
+      ``n_kv_heads`` heads where the config groups them)
     - attention score + value matmuls: ``2 · 2·seq·H·hd`` (each is an
       ``S×S×(H·hd)`` matmul per sequence → ``2·seq·H·hd`` per token),
-      halved under a causal mask
+      halved under a causal mask; a window layer's rows see
+      ``visible_keys(seq, window)`` keys on average instead of
+      ``seq / 2``
     - output projection: ``2·H·hd·d``
     - FFN: ``2·d·f`` per matmul — 3 matmuls for swiglu (w1, w3, w2),
       2 for gelu; MoE charges the gate (``2·d·E``) plus ``top_k``
-      experts' FFN
+      experts' FFN; the dropless layer (``held_experts``) the router
+      over the deployment's experts plus the *held* picks' FFN:
+      ``held_picks_per_token`` where measured, else its expectation
+      under uniform routing, ``top_k · held / experts``
     - lm head: ``2·d·V``
 
     Embedding lookups are gathers (no MXU FLOPs) and norms/activations
@@ -62,21 +70,38 @@ def gpt_fwd_flops_per_token(cfg, seq: int, *, causal: bool = True) -> float:
     """
     d, H, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
     f, L, V = cfg.ff_dim, cfg.n_layers, cfg.vocab_size
-    qkv = 3 * 2 * d * H * hd
-    attn = 2 * 2 * seq * H * hd
-    if causal:
-        attn /= 2
+    qkv = 2 * d * hd * (H + 2 * getattr(cfg, "kv_heads", H))
     out = 2 * H * hd * d
     ffn_matmuls = 3 if cfg.act == "swiglu" else 2
     ffn = ffn_matmuls * 2 * d * f
-    if cfg.n_experts > 0:
+    if getattr(cfg, "dropless", False):
+        if held_picks_per_token is None:
+            held_picks_per_token = (cfg.moe_top_k * len(cfg.held_experts)
+                                    / cfg.n_routed_experts)
+        ffn = 2 * d * cfg.n_routed_experts + held_picks_per_token * ffn
+    elif cfg.n_experts > 0:
         ffn = 2 * d * cfg.n_experts + cfg.moe_top_k * ffn
-    layer = qkv + attn + out + ffn
-    return L * layer + 2 * d * V
+    total = 2 * d * V
+    for kind in getattr(cfg, "layer_kinds", ("full",) * L):
+        keys = seq / 2 if causal else seq
+        if kind == "window" and causal:
+            keys = visible_keys(seq, cfg.window)
+        total += qkv + 2 * 2 * keys * H * hd + out + ffn
+    return total
+
+
+def visible_keys(seq: int, window: int) -> float:
+    """The keys a row of a causal window layer sees, its own included,
+    on average over a length-``seq`` sequence (``seq / 2``, the full
+    layers' convention, where the window does not bind)."""
+    if window >= seq:
+        return seq / 2
+    return (window * (window + 1) / 2 + (seq - window) * window) / seq
 
 
 def gpt_train_flops_per_token(cfg, seq: int, *, causal: bool = True,
-                              ce_recompute: Optional[bool] = None
+                              ce_recompute: Optional[bool] = None,
+                              held_picks_per_token: Optional[float] = None
                               ) -> float:
     """Matmul FLOPs per token of ONE training step of ``cfg`` at ``seq``.
 
@@ -92,7 +117,8 @@ def gpt_train_flops_per_token(cfg, seq: int, *, causal: bool = True,
     overrides it — the telemetry recorder, which knows the pin, passes
     what the gate said.
     """
-    fwd = gpt_fwd_flops_per_token(cfg, seq, causal=causal)
+    fwd = gpt_fwd_flops_per_token(
+        cfg, seq, causal=causal, held_picks_per_token=held_picks_per_token)
     head = 2 * cfg.d_model * cfg.vocab_size
     total = 3 * fwd
     if cfg.remat:

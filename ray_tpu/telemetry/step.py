@@ -11,7 +11,14 @@ Wraps a jitted train step (``build_gpt_train``/``build_gpt_train_pp``
 - tokens/sec and an analytic-FLOPs MFU estimate
   (:mod:`ray_tpu.telemetry.flops`) against the chip peak,
 - logical collective bytes/step per comm_mode
-  (``ray_tpu.parallel.overlap.collective_bytes_per_step``).
+  (``ray_tpu.parallel.overlap.collective_bytes_per_step``),
+- for a step that returns its expert layers' counts (``moe_counts``, a
+  config with ``held_experts``): ``moe.rows``, ``moe.held_picks``,
+  ``moe.experts_hit`` and ``moe.imbalance`` (the busiest held expert's
+  rows over the mean), read with the loss in the step's one fetch; and
+  on the first record the attention coverage of each layer kind
+  (``attn_coverage``: the share of the score square the kind's schedule
+  executes beside the share it needs).
 
 Records flow to three sinks: the Chrome-trace exporter
 (:mod:`ray_tpu.telemetry.chrome_trace`, merged into the dashboard
@@ -184,12 +191,13 @@ class StepTelemetry:
                 jax.block_until_ready(out)
             with tracing.span(f"{self.label}/loss_read", step=i):
                 loss = self._maybe_loss(out)
+                moe = self._maybe_moe(out)
             with tracing.span(f"{self.label}/record", step=i):
-                self._record(i, ts, disp, sync, loss)
+                self._record(i, ts, disp, sync, loss, moe)
         self._profile(i, before=False)
         return out
 
-    def _record(self, i, ts, disp, sync, loss):
+    def _record(self, i, ts, disp, sync, loss, moe=None):
         rec: Dict[str, Any] = {
             "step": i,
             "ts": ts,
@@ -216,6 +224,10 @@ class StepTelemetry:
                         peak)
         if loss is not None:
             rec["loss"] = loss
+        if moe is not None:
+            rec["moe"] = moe
+        if i == 0 and self.attn_coverage() is not None:
+            rec["attn_coverage"] = self.attn_coverage()
         if i == 0 and self.ce_path() is not None:
             rec["ce_path"] = self.ce_path()   # fixed for the run
         if i == 0 and self.causal_coverage() is not None:
@@ -266,6 +278,26 @@ class StepTelemetry:
         except Exception:  # noqa: BLE001 — loss stays optional
             pass
         return None
+
+    def _maybe_moe(self, out) -> Optional[Dict[str, float]]:
+        """The expert layers' counts of a step that returns them
+        (``metrics["moe_counts"]``: ``parallel/moe.py:MOE_COUNTS`` summed
+        over layers, then the rows each held expert took)."""
+        if not (isinstance(out, tuple) and len(out) == 2
+                and isinstance(out[1], dict) and "moe_counts" in out[1]):
+            return None
+        import numpy as np
+
+        from ray_tpu.parallel.moe import MOE_COUNTS
+        vec = np.asarray(out[1]["moe_counts"])
+        named = dict(zip(MOE_COUNTS, (int(v) for v in vec)))
+        load = vec[len(MOE_COUNTS):]
+        moe = {"rows": named["rows"], "held_picks": named["held_picks"],
+               "experts_hit": named["experts_hit"],
+               "calls": named["calls"]}
+        if load.size and load.sum() > 0:
+            moe["imbalance"] = float(load.max() / load.mean())
+        return moe
 
     def compiled_step(self):
         """The AOT-compiled executable (``aot=True`` after the first
@@ -324,9 +356,30 @@ class StepTelemetry:
                                    self.cfg.head_dim)
         return self._coverage
 
-    def flops_per_token(self) -> Optional[float]:
+    def attn_coverage(self) -> Optional[Dict[str, Dict[str, float]]]:
+        """For a step built with one attention hook a layer kind
+        (``models.gpt.attention_fns``): each kind's share of the score
+        square executed and needed, as its hook counts them."""
+        fns = self.attn_fn
+        if (not isinstance(fns, dict) or self._seq is None
+                or not hasattr(self.cfg, "n_heads")):
+            return None
+        return {kind: fn.coverage(self._seq, self.cfg.n_heads,
+                                  self.cfg.head_dim)
+                for kind, fn in fns.items() if hasattr(fn, "coverage")}
+
+    def flops_per_token(self, held_picks_per_token: Optional[float] = None
+                        ) -> Optional[float]:
+        """Analytic train FLOPs a token; a routed config's expert FLOPs
+        at ``held_picks_per_token`` where given (the measured count),
+        else at the uniform expectation (which is what is cached)."""
         if self.cfg is None or self._seq is None:
             return None
+        if held_picks_per_token is not None:
+            return flops_mod.gpt_train_flops_per_token(
+                self.cfg, self._seq,
+                ce_recompute=self.ce_path() != "xla_saved",
+                held_picks_per_token=held_picks_per_token)
         if self._fpt is None:     # constant once the batch shape is known
             try:
                 self._fpt = flops_mod.gpt_train_flops_per_token(
@@ -382,8 +435,30 @@ class StepTelemetry:
                 out["tokens_per_sec_per_device"] = \
                     tok_s / self.n_devices()
                 fpt, peak = self.flops_per_token(), self.chip_peak()
+                routed = [r["moe"] for r in steady if "moe" in r]
+                if routed:
+                    # mean over the steady steps; the layers' calls are
+                    # summed, so per layer where a layer is meant
+                    n = len(routed)
+                    layers = max(1, routed[0]["calls"])
+                    moe = {key: sum(m[key] for m in routed) / n
+                           for key in ("rows", "held_picks", "experts_hit")}
+                    moe["held_picks_per_token"] = (
+                        moe["held_picks"] / max(1.0, moe["rows"]))
+                    moe["experts_hit_per_layer"] = (
+                        moe["experts_hit"] / layers)
+                    spread = [m["imbalance"] for m in routed
+                              if "imbalance" in m]
+                    if spread:
+                        moe["imbalance"] = sum(spread) / len(spread)
+                    out["moe"] = moe
+                    # the model's FLOPs at the picks that were computed
+                    fpt = self.flops_per_token(moe["held_picks_per_token"])
                 if fpt is not None:
                     out["flops_per_token"] = fpt
+                cover = self.attn_coverage()
+                if cover is not None:
+                    out["attn_coverage"] = cover
                 path = self.ce_path()
                 if path is not None:
                     out["ce_path"] = path

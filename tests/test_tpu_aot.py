@@ -149,6 +149,88 @@ def test_train_step_loss_head_and_memory_on_v5e(v5e, ce_chunk, n_layers,
             f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB")
 
 
+# one chip's share of the routed 8k model (benchmark/configs/
+# mellum2-12b-a2.5b-ep4.json): 32 query heads on 4 K/V heads of 128
+MELLUM = dict(n_layers=4, vocab_size=24576, held_experts=tuple(range(16)),
+              max_seq=8192, dtype=BF16)
+MELLUM_STEP_LIMIT_BYTES = 15.75 * 1e9
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_window_and_kv_group_kernels_compile_for_v5e(v5e, window):
+    """The single-head kernels as the routed model's layers call them, 2
+    x 8192 x 32/4 x 128, forward and backward, fused YaRN rope: the
+    strip-mined backward holds a K/V head's whole sequence and its
+    dk / dv accumulators (34 MiB of VMEM) over the head's 8 query
+    heads."""
+    from ray_tpu.models.gpt import GPTConfig
+    cfg = GPTConfig.mellum2_12b_a2_5b(**MELLUM)
+    kind = "full" if window is None else "window"
+    attn = attention.make_flash_attention_fn(
+        window=window, kv_heads=cfg.kv_heads, rope=cfg.rope(kind))
+    b, s, h, kv, d = 2, 8192, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+
+    def step(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: attn(q, k, v, positions=jnp.arange(s))
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile_for_v5e(step, v5e, ((b, s, h, d), BF16),
+                           ((b, s, kv, d), BF16),
+                           ((b, s, kv, d), BF16)).as_text()
+    # one forward and one fused backward kernel, and dk / dv at K/V's size
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert f"bf16[{b},{kv},{s},{d}]" in hlo
+    cover = attn.coverage(s, h, d)
+    assert cover["needed"] < cover["executed"] < (0.2 if window else 0.55)
+
+
+def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
+    """The 4-layer step of ``train-mellum2-12b-a2.5b-ep4-b2x8192`` with
+    the cell file's recipe, compiled for one v5e: window and full
+    attention kernels, the grouped products and their gradients as
+    Mosaic calls, and arguments + temporaries under the 15.75 GB the
+    issue allows (13.5 GB when the recipe was settled, PR 56)."""
+    import json
+
+    from ray_tpu.models import training
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel.mesh import make_mesh
+
+    with open(os.path.join(REPO, "benchmark", "cells",
+                           "train-mellum2-12b-a2.5b-ep4-b2x8192.json")) as f:
+        recipe = json.load(f)["train"]["kwargs"]
+    cfg = GPTConfig.mellum2_12b_a2_5b(**MELLUM, **recipe)
+    mesh = make_mesh(devices=list(v5e.mesh.devices.flat), dp=-1)
+    fns = training.build_gpt_train(cfg, mesh, telemetry=False)
+    state = jax.eval_shape(fns["init_fn"], jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((2, 8192), jnp.int32,
+                                     sharding=fns["batch_sharding"])
+             for k in ("tokens", "targets")}
+    with substrate.compile_for_tpu():
+        compiled = fns["step_fn"].lower(state, batch).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "op_name=" in line]
+    window = [line for line in kernels if "window/attn/flash" in line]
+    flash = [line for line in kernels if "attn/flash" in line]
+    assert len(window) == 6 and len(flash) == 8, (len(window), len(flash))
+    # the compiler's own grouped-product kernel, never its dense
+    # expansion over every expert (what a ragged product that contracts
+    # another dimension of the matrices got, 16 x the work).  A layer's
+    # first piece of sorted picks: gate|up and down forward, two in the
+    # rows and two in the matrices backward; each of its two overflow
+    # pieces, under their conditionals: two forward, five backward
+    assert sum('op_name="ragged-dot-none"' in line
+               for line in kernels) == 4 * (6 + 2 * 7)
+    mem = compiled.memory_analysis()
+    # the state: 6 bytes a parameter (bfloat16 and two bfloat16 moments)
+    assert 0 <= mem.argument_size_in_bytes - 595_153_152 * 6 < 1e6
+    taken = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert taken < MELLUM_STEP_LIMIT_BYTES < V5E_HBM_BYTES, (
+        f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB + "
+        f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+
+
 def test_fused_norm_epilogue_fwd_bwd_compiles_for_v5e(v5e):
     """The out-proj epilogue at a prefill's rows (1 x 1024) and at the
     train step's (24 x 1024): the forward compiles as Mosaic's kernel,
