@@ -166,6 +166,7 @@ def train_loop(config: dict) -> None:
     from ray_tpu.models import gpt, training
     from ray_tpu.ops import flash_ce
     from ray_tpu.ops.attention import train_causal_coverage, uses_pack2
+    from ray_tpu.parallel import moe
     from ray_tpu.parallel.mesh import make_mesh
 
     cache_dir = enable_compile_cache()
@@ -193,6 +194,7 @@ def train_loop(config: dict) -> None:
     # what the gates chose, from the shapes the step ran ...
     N, d, V = B * S, cfg.d_model, cfg.vocab_size
     gate = dict(n_devices=n, norm=cfg.norm, has_bias=cfg.use_bias)
+    routed = config["routed"]
     ce = gpt.ce_path(N, d, V, ce_chunk=cfg.ce_chunk, n_devices=n)
     if flash_ce.uses_flash_ce_norm(N, d, V, ce_chunk=cfg.ce_chunk, **gate):
         ce = "flash_norm"
@@ -208,6 +210,12 @@ def train_loop(config: dict) -> None:
         # (ops/fused_norm.py; its kernel is the forward-only call's,
         # which the kernels phase checks at the prefill buckets)
         "fuse_norm": False,
+        # the form the routed 8k cell's differentiated expert layers
+        # take their grouped products in (this step has no such layer;
+        # the kernels phase checks them at these shapes)
+        "moe_product": moe.product_path(
+            routed["rows"], routed["top_k"], routed["held"],
+            routed["experts"], routed["d_model"], routed["expert_ff"]),
     }
     # ... and what the compiled step holds (the jitted call's own
     # executable comes back out of the cache)
@@ -388,10 +396,54 @@ def kernel_parity(config: dict) -> dict:
     names = ("dx", "drouter", "dgate", "dup", "ddown")
     errs = {"o": _rel_err(o, o_ref)}
     errs.update({n: _rel_err(a, b) for n, a, b in zip(names, g, g_ref)})
+    product = moe.product_path(T, topk, len(held), E, dm, fe)
     row("moe/grouped fwd+bwd", [T, dm, fe, len(held), E, topk], errs,
         {"o": TOL_OUT, **dict.fromkeys(names, TOL_GRAD)},
-        counts=dict(zip(moe.MOE_COUNTS, (int(c) for c in counts))))
+        counts=dict(zip(moe.MOE_COUNTS, (int(c) for c in counts))),
+        moe_product=product)
     del x, ct, args, o_ref, g_ref
+
+    # -- its grouped products, kernel by kernel, over one piece of sorted
+    # rows with uneven groups and rows no group has: the forward's
+    # product, the gradient in the rows (the matrices read transposed)
+    # and the gradient in the matrices, against jax.lax.ragged_dot ------
+    if product == "pallas":
+        from ray_tpu.ops import grouped_matmul
+        rows_p = moe.piece_rows(T, topk, len(held), E)
+        n = np.random.default_rng(7).multinomial(
+            2 * rows_p // 3, np.ones(len(held)) / len(held))
+        n[len(held) // 2] = 0                   # an expert nobody picked
+        n = jnp.asarray(n, jnp.int32)
+        xs, dgu = rand((rows_p, dm)), rand((rows_p, 2 * fe))
+        w_gu = rand((len(held), dm, 2 * fe), dm ** -0.5)
+        live = (jnp.arange(rows_p) < jnp.sum(n))[:, None]
+
+        def ours(xs, dgu, w_gu):
+            return (grouped_matmul.gmm(xs, w_gu, n),
+                    grouped_matmul.gmm(dgu, w_gu, n, transpose_rhs=True),
+                    grouped_matmul.tgmm(xs, dgu, n))
+
+        def xla(xs, dgu, w_gu):
+            return (jnp.where(live, moe._ragged(xs, w_gu, n, None), 0),
+                    jnp.where(live, moe._ragged(dgu, w_gu, n, None, True), 0),
+                    moe._ragged_outer(xs, dgu, n, None))
+
+        exe, calls = compiled(ours, xs, dgu, w_gu)
+        got = jax.tree.map(np.asarray, exe(xs, dgu, w_gu))
+        with jax.default_matmul_precision("highest"):
+            want = jax.tree.map(np.asarray, jax.jit(xla)(
+                up(xs), up(dgu), up(w_gu)))
+        kinds = ("gmm", "gmm_transposed", "tgmm")
+        row("moe/grouped products", [rows_p, dm, 2 * fe, len(held)],
+            {k: _rel_err(a, b) for k, a, b in zip(kinds, got, want)},
+            {"gmm": TOL_OUT, "gmm_transposed": TOL_GRAD, "tgmm": TOL_GRAD},
+            calls, 3, moe_product=product,
+            tiles={"gmm": grouped_matmul.tiling(rows_p, dm, 2 * fe),
+                   "gmm_transposed": grouped_matmul.tiling(
+                       rows_p, 2 * fe, dm),
+                   "tgmm": grouped_matmul.tiling(
+                       rows_p, dm, 2 * fe, transposed_lhs=True)})
+        del xs, dgu, w_gu, got, want
 
     # -- out-proj + residual + rmsnorm epilogue, differentiated ------------
     # (PR 53: the rule is XLA's, so this row guards its wiring: no Mosaic

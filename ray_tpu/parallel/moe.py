@@ -13,9 +13,12 @@ grouped matrix product over the rows that picked them, and the identity
 experts' part; an expert held elsewhere adds nothing (the exchange that
 would bring its part is not run here).  A call nobody differentiates
 takes that loop; a differentiated one (a train step) takes the same
-layer as three grouped products over the sorted picks
-(``jax.lax.ragged_dot``), with a backward of its own, because a loop
-with a traced trip count has no reverse mode.
+layer as grouped products over the sorted picks, with a backward of its
+own, because a loop with a traced trip count has no reverse mode.  The
+products are ``ops/grouped_matmul.py``'s Pallas kernels wherever their
+tiles divide the layer's shapes (``grouped_matmul.uses_kernel``;
+:func:`product_path` names the form) and ``jax.lax.ragged_dot``
+elsewhere.
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.parallel.compat import shard_map
 
 
@@ -242,20 +246,30 @@ def _at(w, lead):
     return w[tuple(lead)] if lead else w
 
 
-def _ragged(lhs, rhs, sizes, transposed: bool = False):
+def _ragged(lhs, rhs, sizes, walk, transposed: bool = False):
     """``lhs [M, k]`` against each group's ``rhs [G, k, n]`` (or, with
-    ``transposed``, ``rhs [G, n, k]``).  Always the canonical product of
-    ``jax.lax.ragged_dot``, the one the TPU compiler has a kernel for: a
-    product that contracts another dimension of ``rhs`` it expands into
-    a dense one over every group, sixteen times the work here (compiled
-    for a described v5e, PR 56), so the transpose is spelled out."""
+    ``transposed``, ``rhs [G, n, k]``): ``grouped_matmul.gmm`` over the
+    rows' schedule ``walk``, which reads a transposed matrix through its
+    index map; where the layer's shapes keep the compiler's product
+    (``walk`` is None), the canonical form of ``jax.lax.ragged_dot``,
+    the one the TPU compiler has a kernel for: a product that contracts
+    another dimension of ``rhs`` it expands into a dense one over every
+    group, sixteen times the work in the routed 8k cell (compiled for a
+    described v5e, PR 56), so the transpose is spelled out there."""
+    if walk is not None:
+        return grouped_matmul.gmm(lhs, rhs, transpose_rhs=transposed,
+                                  walk=walk)
     return lax.ragged_dot(
         lhs, jnp.swapaxes(rhs, 1, 2) if transposed else rhs, sizes)
 
 
-def _ragged_outer(a, b, sizes):
+def _ragged_outer(a, b, sizes, walk):
     """``sum over a group's rows of a[m]^T b[m]``: [G, ka, kb], the
-    gradient of a grouped product in its matrices."""
+    gradient of a grouped product in its matrices
+    (``grouped_matmul.tgmm``, or ``jax.lax.ragged_dot_general`` where
+    ``walk`` is None)."""
+    if walk is not None:
+        return grouped_matmul.tgmm(a, b, walk=walk)
     return lax.ragged_dot_general(
         a, b, sizes, lax.RaggedDotDimensionNumbers(
             dot_dimension_numbers=(((0,), (0,)), ((), ())),
@@ -295,11 +309,32 @@ def piece_rows(T: int, top_k: int, held: int, experts: int) -> int:
     return min(T * top_k, -(-int(want) // _PIECE_ROWS) * _PIECE_ROWS)
 
 
-def _sorted_picks(local, weight, held: int):
+def _kernels_take(rows: int, d: int, f: int) -> bool:
+    """Whether a layer's grouped products over ``rows`` sorted rows are
+    ``ops/grouped_matmul.py``'s: one decision for the six kinds of a
+    layer, the gate's on each contraction and width among them (gate|up
+    and down forward, and the two read transposed for the rows'
+    gradients; the matrices' gradients have the same widths)."""
+    return all(grouped_matmul.uses_kernel(rows, k, n) for k, n in (
+        (d, 2 * f), (f, d), (d, f), (2 * f, d)))
+
+
+def product_path(T: int, top_k: int, held: int, experts: int, d: int,
+                 f: int) -> str:
+    """The form a differentiated layer's grouped products take at these
+    shapes, ``pallas`` or ``ragged_dot`` (the step telemetry's
+    ``moe_product``)."""
+    return "pallas" if _kernels_take(
+        piece_rows(T, top_k, held, experts), d, f) else "ragged_dot"
+
+
+def _sorted_picks(local, weight, held: int, piece: int):
     """The picks sorted by expert, the held ones first: for each sorted
     row its token and weight (0 behind the last held pick), for each
     pick its sorted row (``pos [T, K]``), and the first sorted row of
-    each held expert and the one past its last."""
+    each held expert and the one past its last.  The rows' vectors are
+    padded to whole pieces with rows no pick has, so that every piece
+    has ``piece`` rows and its products one shape."""
     T, K = local.shape
     flat = local.reshape(T * K)
     order = jnp.argsort(flat, stable=True)
@@ -310,25 +345,80 @@ def _sorted_picks(local, weight, held: int):
     ends = jnp.cumsum(n)
     live = jnp.arange(T * K) < ends[-1]
     ws = jnp.where(live, weight.reshape(T * K)[order], 0.0)
-    return token, pos, ws, ends - n, ends
+    pad = (0, -(T * K) % piece)
+    return jnp.pad(token, pad), pos, jnp.pad(ws, pad), ends - n, ends
 
 
-def _piece(a: int, b: int, token, pos, ws, starts, ends, ours):
-    """Sorted rows ``a .. b - 1``: their tokens and weights, which of
-    them are held picks, each expert's rows among them, and the picks
-    ``[T, K]`` that lie in the piece with their row in it."""
+def _piece(a, rows: int, token, pos, ws, starts, ends, ours):
+    """Sorted rows ``a .. a + rows - 1`` (``a`` an int32 operand): their
+    tokens and weights, which of them are held picks, each expert's rows
+    among them, and the picks ``[T, K]`` that lie in the piece with their
+    row in it."""
+    b = a + rows
     n = jnp.clip(jnp.minimum(ends, b) - jnp.maximum(starts, a), 0, None)
-    live = jnp.arange(a, b) < ends[-1]
+    live = a + jnp.arange(rows) < ends[-1]
     mine = ours & (pos >= a) & (pos < b)
     # a pick outside the piece reads some row of it (masked where it is
     # summed): rows spread over the piece, not one row for all of them
-    spread = jnp.arange(pos.size, dtype=jnp.int32).reshape(pos.shape) % (b - a)
-    return (token[a:b], ws[a:b], live, n, mine,
+    spread = jnp.arange(pos.size, dtype=jnp.int32).reshape(pos.shape) % rows
+    return (lax.dynamic_slice_in_dim(token, a, rows),
+            lax.dynamic_slice_in_dim(ws, a, rows), live, n, mine,
             jnp.where(mine, pos - a, spread))
 
 
 def _pieces(M: int, piece: int):
-    return [(a, min(a + piece, M)) for a in range(0, M, piece)]
+    """The sorted rows in pieces of ``piece``; the last may end behind
+    the last row (:func:`_sorted_picks` pads the rows' vectors)."""
+    return [(a, a + piece) for a in range(0, M, piece)]
+
+
+def _later_pieces(M: int, piece: int, ends, run, first):
+    """``first`` and, added to it leaf by leaf, ``run(a)`` of each piece
+    behind the first that a held pick lies in (``a`` its first row, an
+    int32), one after another: a loop of as many passes as there are
+    such pieces, so the step holds their code once however many there
+    may be, under a conditional, so that a step whose held picks fit the
+    first piece carries nothing through a loop (with the loop alone the
+    routed 8k cell's step read 9 ms longer, ``PERF.md`` section 6,
+    PR 58)."""
+    if len(_pieces(M, piece)) == 1:
+        return first
+
+    def later(first):
+        # (the bounds int32 and not a weak 1: ``i * piece`` then has the
+        # type the first piece's row 0 has, and one trace serves both)
+        return lax.fori_loop(
+            jnp.int32(1), lax.div(ends[-1] + (piece - 1), jnp.int32(piece)),
+            lambda i, total: jax.tree.map(jnp.add, total, run(i * piece)),
+            first)
+
+    return lax.cond(ends[-1] > piece, later, lambda first: first, first)
+
+
+def _walk(n, rows: int, d: int, f: int):
+    """The schedule of a piece's products over its ``rows`` sorted rows
+    (``grouped_matmul.group_tiles``), made once a piece for all of them,
+    or None where the products are ``jax.lax.ragged_dot``'s."""
+    if not _kernels_take(rows, d, f):
+        return None
+    return grouped_matmul.group_tiles(n, rows,
+                                      grouped_matmul.tile_rows(rows))
+
+
+@functools.partial(jax.jit, static_argnames=("piece",))
+def _piece_fwd(a, x, sort, ours, w_gu, w_down, *, piece: int):
+    """The forward of the ``piece`` sorted rows from ``a`` (int32): its
+    part of the layer's sum, and its gate|up product.  One jitted
+    function for the first piece and the later ones of every layer: a
+    step traces it once where it traced a closure once a piece of every
+    layer (0.8 s of a warm worker's set-up in the routed 8k cell,
+    ``PERF.md`` section 6, PR 58), and the compiler, which inlines it,
+    sees ``a == 0`` where that is what the caller passed."""
+    token, ws, live, n, mine, at = _piece(a, piece, *sort, ours)
+    walk = _walk(n, piece, x.shape[1], w_down.shape[1])
+    gu = _ragged(x[token], w_gu, n, walk)                     # [piece, 2f]
+    y = _ragged(_hidden(gu, ws, live), w_down, n, walk)
+    return _pick_sum(y, at, mine), gu
 
 
 def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
@@ -338,10 +428,10 @@ def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
     and up as one product against the two matrices side by side).
 
     Every pick has a row in the sorted order (``T * K``: no pick can be
-    dropped), the held picks first.  The rows are taken in static
+    dropped), the held picks first.  The rows are taken in equal
     pieces of ``piece`` rows: the first always, a later one only if a
-    held pick lies in it (``lax.cond``), so under any routing the work,
-    the gathers and the buffers that are touched follow the picks to
+    held pick lies in it (:func:`_later_pieces`), so under any routing
+    the work, the gathers and the buffers that are touched follow the picks to
     within a piece, and nothing is sized by the worst case but the
     index vectors.  Inside a piece the products run over the groups'
     own sizes; the rows behind the last held pick are never computed
@@ -352,21 +442,15 @@ def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
     T, K = local.shape
     held = e_gate.shape[len(lead)]
     ours = local < held
-    sort = _sorted_picks(local, weight, held)
+    sort = _sorted_picks(local, weight, held, piece)
     w_gu, w_down = _gate_up(e_gate, e_up, lead), _at(e_down, lead)
 
-    def run(a, b):
-        token, ws, live, n, mine, at = _piece(a, b, *sort, ours)
-        gu = _ragged(x[token], w_gu, n)                       # [b - a, 2f]
-        y = _ragged(_hidden(gu, ws, live), w_down, n)
-        return _pick_sum(y, at, mine), gu
+    def run(a):
+        with grouped_matmul.one_trace():
+            return _piece_fwd(a, x, sort, ours, w_gu, w_down, piece=piece)
 
-    (first, *rest) = _pieces(T * K, piece)
-    out, gu = run(*first)
-    for a, b in rest:
-        out = lax.cond(sort[-1][-1] > a,
-                       lambda out, a=a, b=b: out + run(a, b)[0],
-                       lambda out: out, out)
+    out, gu = run(jnp.int32(0))
+    out = _later_pieces(T * K, piece, sort[-1], lambda a: run(a)[0], out)
     hit = jnp.sum(sort[-1] > sort[-2])
     return (out, hit), (x, local, weight, gu, e_gate, e_up, e_down, lead)
 
@@ -383,6 +467,36 @@ def _hidden(gu, ws, live):
     return jnp.where(live[:, None], h, 0.0).astype(gu.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("piece",))
+def _piece_bwd(a, gu, x, dout, sort, ours, w_gu, w_down, *, piece: int):
+    """The backward of the ``piece`` sorted rows from ``a``
+    (:func:`_piece_fwd`), given the piece's gate|up product or None, in
+    which case it is computed again: its part of the gradients in ``x``
+    and the weights ``[T, K]``, and the two matrices' gradients."""
+    token, ws, live, n, mine, at = _piece(a, piece, *sort, ours)
+    dt = x.dtype
+    walk = _walk(n, piece, x.shape[1], w_down.shape[1])
+    rows = live[:, None]
+    xs = x[token]
+    if gu is None:
+        gu = _ragged(xs, w_gu, n, walk)
+    dy = jnp.where(rows, dout[token], 0)                       # [piece, d]
+    dh = jnp.where(rows, _ragged(dy, w_down, n, walk, True), 0
+                   ).astype(jnp.float32)                       # [piece, f]
+    g, u = jnp.split(gu.astype(jnp.float32), 2, axis=-1)
+    sg = jax.nn.sigmoid(g)
+    act = g * sg
+    dws = jnp.sum(dh * act * u, axis=-1)
+    dh = dh * ws[:, None]
+    dgu = jnp.concatenate([dh * u * sg * (1.0 + g * (1.0 - sg)),
+                           dh * act], axis=-1).astype(dt)
+    dxs = jnp.where(rows, _ragged(dgu, w_gu, n, walk, True), 0)
+    return (_pick_sum(dxs, at, mine),
+            jnp.where(mine, dws[at], 0.0),
+            _ragged_outer(xs, dgu, n, walk),                   # [G, d, 2f]
+            _ragged_outer(_hidden(gu, ws, live), dy, n, walk))
+
+
 def _sorted_experts_bwd(piece, res, cts):
     x, local, weight, gu_first, e_gate, e_up, e_down, lead = res
     # what the backward computes again from the residuals (the sort, the
@@ -397,39 +511,17 @@ def _sorted_experts_bwd(piece, res, cts):
     held = e_gate.shape[len(lead)]
     f = gu_first.shape[1] // 2
     ours = local < held
-    sort = _sorted_picks(local, weight, held)
+    sort = _sorted_picks(local, weight, held, piece)
     w_gu, w_down = _gate_up(e_gate, e_up, lead), _at(e_down, lead)
     dout = cts[0].astype(dt)
 
-    def run(a, b, gu=None):
-        token, ws, live, n, mine, at = _piece(a, b, *sort, ours)
-        rows = live[:, None]
-        xs = x[token]
-        if gu is None:
-            gu = _ragged(xs, w_gu, n)
-        dy = jnp.where(rows, dout[token], 0)                   # [b - a, d]
-        dh = jnp.where(rows, _ragged(dy, w_down, n, True), 0
-                       ).astype(jnp.float32)                   # [b - a, f]
-        g, u = jnp.split(gu.astype(jnp.float32), 2, axis=-1)
-        sg = jax.nn.sigmoid(g)
-        act = g * sg
-        dws = jnp.sum(dh * act * u, axis=-1)
-        dh = dh * ws[:, None]
-        dgu = jnp.concatenate([dh * u * sg * (1.0 + g * (1.0 - sg)),
-                               dh * act], axis=-1).astype(dt)
-        dxs = jnp.where(rows, _ragged(dgu, w_gu, n, True), 0)
-        return (_pick_sum(dxs, at, mine),
-                jnp.where(mine, dws[at], 0.0),
-                _ragged_outer(xs, dgu, n),                     # [G, d, 2f]
-                _ragged_outer(_hidden(gu, ws, live), dy, n))
+    def run(a, gu=None):
+        with grouped_matmul.one_trace():
+            return _piece_bwd(a, gu, x, dout, sort, ours, w_gu, w_down,
+                              piece=piece)
 
-    (first, *rest) = _pieces(T * K, piece)
-    grads = run(*first, gu_first)
-    for a, b in rest:
-        grads = lax.cond(
-            sort[-1][-1] > a,
-            lambda grads, a=a, b=b: jax.tree.map(jnp.add, grads, run(a, b)),
-            lambda grads: grads, grads)
+    grads = _later_pieces(T * K, piece, sort[-1], run,
+                          run(jnp.int32(0), gu_first))
     dx, dweight, dw_gu, dw_down = grads
     # nothing downstream waits for the matrices' gradients, and a
     # scheduler free to put them off keeps every layer's sorted operands

@@ -18,7 +18,8 @@ Wraps a jitted train step (``build_gpt_train``/``build_gpt_train_pp``
   rows over the mean), read with the loss in the step's one fetch; and
   on the first record the attention coverage of each layer kind
   (``attn_coverage``: the share of the score square the kind's schedule
-  executes beside the share it needs).
+  executes beside the share it needs) and the form of the layers'
+  grouped products (``moe_product``: ``pallas`` / ``ragged_dot``).
 
 Records flow to three sinks: the Chrome-trace exporter
 (:mod:`ray_tpu.telemetry.chrome_trace`, merged into the dashboard
@@ -232,6 +233,8 @@ class StepTelemetry:
             rec["ce_path"] = self.ce_path()   # fixed for the run
         if i == 0 and self.causal_coverage() is not None:
             rec["causal_coverage"] = self.causal_coverage()
+        if i == 0 and self.moe_product() is not None:
+            rec["moe_product"] = self.moe_product()
         self.records.append(rec)
         if len(self.records) > self._MAX_RECORDS:
             # bounded like the control plane's task-event buffer: a
@@ -339,6 +342,22 @@ class StepTelemetry:
                 ce_chunk=cfg.ce_chunk, n_devices=self.n_devices(),
                 mode=self.ce_mode)
         return self._ce_path
+
+    def moe_product(self) -> Optional[str]:
+        """The form a routed config's differentiated expert layers take
+        their grouped products in — ``pallas``
+        (``ops/grouped_matmul.py``) or ``ragged_dot`` — as the layer
+        decides it from the step's shapes
+        (``parallel.moe.product_path``, over the gate
+        ``grouped_matmul.uses_kernel``).  ``None`` until a batch has
+        shown its shape, and for a config with no such layer."""
+        cfg = self.cfg
+        if self._seq is None or not getattr(cfg, "held_experts", None):
+            return None
+        from ray_tpu.parallel.moe import product_path
+        return product_path(self._batch * self._seq, cfg.moe_top_k,
+                            len(cfg.held_experts), cfg.n_routed_experts,
+                            cfg.d_model, cfg.ff_dim)
 
     def causal_coverage(self) -> Optional[float]:
         """The share of the causal score square the step's attention
@@ -465,6 +484,9 @@ class StepTelemetry:
                 coverage = self.causal_coverage()
                 if coverage is not None:
                     out["causal_coverage"] = coverage
+                product = self.moe_product()
+                if product is not None:
+                    out["moe_product"] = product
                 if fpt is not None and peak is not None:
                     out["chip_peak_tflops"] = peak
                     out["mfu"] = flops_mod.mfu(

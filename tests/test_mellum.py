@@ -182,19 +182,44 @@ def _dense(x, router, gate, up, down, *, held, K):
     return out
 
 
-@pytest.mark.parametrize("pieces", ["one_piece", "four_pieces"])
-@pytest.mark.parametrize("routing", ["uniform", "one_expert", "idle_expert"])
+# the layer at widths that keep the compiler's product, and at widths the
+# Pallas kernels' tiles divide (interpreted here); and for each how the
+# sorted picks (T x 2 rows) are cut: (headroom, rounding) -> rows a piece
+_LAYERS = {"ragged_dot": dict(T=96, d=32, f=48),
+           "pallas": dict(T=256, d=128, f=128)}
+_CUTS = {("ragged_dot", "four_pieces"): (0.5, 8, 48),            # 4 x 48
+         ("ragged_dot", "padded_last_piece"): (0.5, 40, 80),     # 192 of 240
+         ("pallas", "four_pieces"): (0.5, 128, 128),             # 4 x 128
+         ("pallas", "padded_last_piece"): (0.5, 384, 384)}       # 512 of 768
+
+
+@pytest.mark.parametrize("products", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize("pieces", ["one_piece", "four_pieces",
+                                    "padded_last_piece"])
+@pytest.mark.parametrize("routing", ["uniform", "one_expert", "idle_expert",
+                                     "all_held"])
 def test_differentiated_grouped_products_match_loop_and_dense(
-        monkeypatch, routing, pieces):
-    if pieces == "four_pieces":
-        # the sorted picks in pieces of 48 rows of 192: the later ones
-        # run only where a held pick lies in them
-        monkeypatch.setattr(moe, "_PIECE_HEADROOM", 0.5)
-        monkeypatch.setattr(moe, "_PIECE_ROWS", 8)
-        assert moe.piece_rows(96, 2, 4, 8) == 48
-    L = _layer()
+        monkeypatch, routing, pieces, products):
+    shape = _LAYERS[products]
+    if pieces != "one_piece":
+        # the sorted picks in equal pieces, the later ones run only where
+        # a held pick lies in them; the last may end behind the last row
+        headroom, rounding, rows = _CUTS[products, pieces]
+        monkeypatch.setattr(moe, "_PIECE_HEADROOM", headroom)
+        monkeypatch.setattr(moe, "_PIECE_ROWS", rounding)
+        assert moe.piece_rows(shape["T"], 2, 4, 8) == rows
+    assert moe.product_path(shape["T"], 2, 4, 8, shape["d"],
+                            shape["f"]) == products
+    L = _layer(**shape)
+    # the cases the parent had keep its tolerances; RTOL_WIDER
+    rtol = 2e-6 if (products == "pallas" or routing == "all_held"
+                    or pieces == "padded_last_piece") else 1e-7
     held, E, K = L["held"], L["E"], 2
     router = L["router"]
+    if routing == "all_held":          # every pick on a held expert: the
+        # sorted rows are full to the last, every piece runs
+        L["x"] = L["x"].at[:, 0].set(6.0)
+        router = router.at[0, jnp.array([0, 3, 4, 7])].set(-50.0)
     if routing == "one_expert":        # every row's first pick is expert 5
         router = router.at[:, 5].set(0.0)
         L["x"] = L["x"].at[:, 0].set(6.0)
@@ -205,12 +230,14 @@ def test_differentiated_grouped_products_match_loop_and_dense(
     args = (L["x"], router, L["gate"], L["up"], L["down"])
     out, counts = _dropless(*args, held=held, E=E, K=K)      # the loop
     want = _dense(*args, held=held, K=K)
-    np.testing.assert_allclose(out, want, atol=2e-5)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=rtol)
     named = dict(zip(moe.MOE_COUNTS, np.asarray(counts)))
     if routing == "one_expert":
         assert named["held_picks"] >= L["x"].shape[0]
     if routing == "idle_expert":
         assert named["experts_hit"] == len(held) - 1
+    if routing == "all_held":
+        assert named["held_picks"] == named["picks"]
 
     def loss(fn):
         return lambda *a: jnp.sum(fn(*a) * L["target"])
@@ -223,7 +250,7 @@ def test_differentiated_grouped_products_match_loop_and_dense(
     # the rule's forward (sorted, grouped) is the loop's sum
     assert float(got_v) == pytest.approx(float(want_v), rel=1e-5)
     for g, w, name in zip(got, want_g, ("x", "router", "gate", "up", "down")):
-        np.testing.assert_allclose(g, w, atol=5e-5, err_msg=name)
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=rtol, err_msg=name)
     if routing == "idle_expert":
         assert not np.any(np.asarray(got[2][1]))   # expert 2: no gradient
 
@@ -247,8 +274,14 @@ def test_a_piece_is_what_uniform_routing_fills_and_half_again():
     # the routed cell's layer: 16,384 rows, 8 of 64, 16 held
     assert moe.piece_rows(16384, 8, 16, 64) == 49152
     assert moe.piece_rows(16384, 8, 64, 64) == 16384 * 8   # all held
+    # equal pieces, so that their products have one shape: the last ends
+    # behind the last row, on rows no pick has
     assert moe._pieces(131072, 49152) == [
-        (0, 49152), (49152, 98304), (98304, 131072)]
+        (0, 49152), (49152, 98304), (98304, 147456)]
+    sort = moe._sorted_picks(jnp.zeros((8, 2), jnp.int32),
+                             jnp.ones((8, 2)), 1, 12)
+    assert sort[0].shape == sort[2].shape == (24,)
+    assert not np.any(np.asarray(sort[2][16:]))
 
 
 def test_the_four_shares_of_ep4_add_up_to_the_uncut_layer():
@@ -286,8 +319,21 @@ def _tiny(**kw):
                          "targets": jnp.roll(tokens, -1, axis=1)}
 
 
-@pytest.mark.parametrize("unroll", [True, False], ids=["unrolled", "scan"])
-def test_tiny_preset_matches_the_reference_in_loss_and_every_leaf(unroll):
+@pytest.mark.parametrize("unroll, piece", [
+    (True, None), (False, None), (False, 264), (False, 136)],
+    ids=["unrolled", "scan", "padded_last_piece", "four_pieces"])
+def test_tiny_preset_matches_the_reference_in_loss_and_every_leaf(
+        monkeypatch, unroll, piece):
+    if piece:
+        # the layers' held picks are 145 to 286 of 512 sorted rows: in
+        # pieces of 264 the first layer's overflow into the second and
+        # last piece, which ends 16 rows behind the last row; in pieces of
+        # 136 every layer's reach the second and the first layer's the
+        # third, and the last is not run
+        monkeypatch.setattr(moe, "_PIECE_HEADROOM", 0.5)
+        monkeypatch.setattr(moe, "_PIECE_ROWS", piece)
+        assert moe.piece_rows(256, 2, 4, 8) == piece
+        assert len(moe._pieces(512, piece)) == -(-512 // piece)
     cfg, params, batch = _tiny(unroll_layers=unroll)
     assert cfg.layer_kinds == ("window",) * 3 + ("full",) + (
         "window",) * 3 + ("full",)
@@ -344,7 +390,13 @@ def test_step_returns_counts_and_telemetry_reports_them_with_coverage():
     assert set(first["attn_coverage"]) == {"window", "full"}
     assert first["moe"]["rows"] == 8 * 256 and first["moe"]["calls"] == 8
     assert first["moe"]["imbalance"] >= 1.0
+    # the form of the grouped products, from the step's shapes: experts
+    # 32 wide keep the compiler's; the routed 8k cell's take the kernels
+    assert first["moe_product"] == "ragged_dot"
+    assert "moe_product" not in tel.records[1]
+    assert moe.product_path(2 * 8192, 8, 16, 64, 2304, 896) == "pallas"
     summary = tel.summary()
+    assert summary["moe_product"] == "ragged_dot"
     assert summary["moe"]["experts_hit_per_layer"] <= 4
     picks = summary["moe"]["held_picks_per_token"]
     assert 0 < picks <= 2

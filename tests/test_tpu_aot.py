@@ -14,6 +14,7 @@ lives.
 
 import dataclasses
 import os
+import re
 import subprocess
 import sys
 import types
@@ -185,16 +186,25 @@ def test_window_and_kv_group_kernels_compile_for_v5e(v5e, window):
     assert cover["needed"] < cover["executed"] < (0.2 if window else 0.55)
 
 
+def _kernel_bodies(functions):
+    """The distinct serialized Mosaic kernels in the text of some lowered
+    functions."""
+    return {body for f in functions for body in re.findall(
+        r"body\\22: \\22([A-Za-z0-9+/=]+)", f)}
+
+
 def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     """The 4-layer step of ``train-mellum2-12b-a2.5b-ep4-b2x8192`` with
     the cell file's recipe, compiled for one v5e: window and full
     attention kernels, the grouped products and their gradients as
-    Mosaic calls, and arguments + temporaries under the 15.75 GB the
-    issue allows (13.5 GB when the recipe was settled, PR 56)."""
+    Mosaic calls of at most six distinct kernels, and arguments +
+    temporaries under the 15.75 GB the issue allows (13.5 GB when the
+    recipe was settled, PR 56)."""
     import json
 
     from ray_tpu.models import training
     from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel import moe
     from ray_tpu.parallel.mesh import make_mesh
 
     with open(os.path.join(REPO, "benchmark", "cells",
@@ -208,20 +218,36 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
                                      sharding=fns["batch_sharding"])
              for k in ("tokens", "targets")}
     with substrate.compile_for_tpu():
-        compiled = fns["step_fn"].lower(state, batch).compile()
+        lowered = fns["step_fn"].lower(state, batch)
+        compiled = lowered.compile()
     kernels = [line for line in compiled.as_text().splitlines()
                if "tpu_custom_call" in line and "op_name=" in line]
     window = [line for line in kernels if "window/attn/flash" in line]
     flash = [line for line in kernels if "attn/flash" in line]
     assert len(window) == 6 and len(flash) == 8, (len(window), len(flash))
-    # the compiler's own grouped-product kernel, never its dense
-    # expansion over every expert (what a ragged product that contracts
-    # another dimension of the matrices got, 16 x the work).  A layer's
-    # first piece of sorted picks: gate|up and down forward, two in the
-    # rows and two in the matrices backward; each of its two overflow
-    # pieces, under their conditionals: two forward, five backward
-    assert sum('op_name="ragged-dot-none"' in line
-               for line in kernels) == 4 * (6 + 2 * 7)
+    # the grouped products are ops/grouped_matmul.py's kernels, under the
+    # layer's scope, and none is left to the compiler's ragged-dot
+    # rewrite.  A layer's first piece of sorted picks: gate|up and down
+    # forward, two in the rows and two in the matrices backward; the
+    # pieces behind it, one loop of as many passes as there are such
+    # pieces with a held pick: two forward, five backward
+    assert "ragged-dot-none" not in compiled.as_text()
+    products = [line for line in kernels if "moe/experts" in line]
+    assert len(products) == 4 * (6 + 7) == len(kernels) - len(flash)
+    assert sum("while/body" in line for line in products) == 4 * 7
+    # ... and the module every process traces and lowers before it can
+    # look the executable up holds six distinct kernels for them, however
+    # many layers and pieces call them: every piece has the first's rows,
+    # and every call goes through the two module-level jits (a call
+    # inside a loop's body and one outside it lower to two functions of
+    # one body: twelve functions, the same serialized kernel in each two)
+    functions = [f for f in lowered.as_text().split("func.func ")
+                 if re.match(r"private @_t?gmm", f)]
+    assert len(functions) <= 12 and len(_kernel_bodies(functions)) == 6, (
+        len(functions), len(_kernel_bodies(functions)))
+    assert moe.product_path(2 * 8192, cfg.moe_top_k, len(cfg.held_experts),
+                            cfg.n_routed_experts, cfg.d_model,
+                            cfg.ff_dim) == "pallas"
     mem = compiled.memory_analysis()
     # the state: 6 bytes a parameter (bfloat16 and two bfloat16 moments)
     assert 0 <= mem.argument_size_in_bytes - 595_153_152 * 6 < 1e6
@@ -229,6 +255,47 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     assert taken < MELLUM_STEP_LIMIT_BYTES < V5E_HBM_BYTES, (
         f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB + "
         f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+
+
+@pytest.mark.parametrize("width, path", [(256, "pallas"),
+                                         (192, "ragged_dot")])
+def test_expert_width_the_tiles_do_not_divide_keeps_ragged_dot(v5e, width,
+                                                               path):
+    """``grouped_matmul.uses_kernel`` decides a differentiated layer's
+    products from its shapes: experts 192 wide, which tiles of 128 do
+    not divide, keep the compiler's grouped product (``ragged-dot-none``
+    in the executable) and hold no kernel of ours; 256 wide they are
+    ours and no ragged product is left."""
+    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.parallel import moe
+    T, d, E, held, top_k = 1024, 256, 8, (0, 1, 2, 3), 2
+    rows = moe.piece_rows(T, top_k, len(held), E)
+    assert bool(grouped_matmul.uses_kernel(rows, width, d)) == (
+        path == "pallas")
+    assert moe.product_path(T, top_k, len(held), E, d, width) == path
+
+    def step(x, router, gate, up, down):
+        def loss(*a):
+            return moe.dropless_moe(
+                a[0], a[1], jnp.zeros((E,)), *a[2:], held=held, n_routed=E,
+                top_k=top_k, scale=1.0, renormalise=True
+            )[0].astype(jnp.float32).sum()
+        return jax.grad(loss, (0, 1, 2, 3, 4))(x, router, gate, up, down)
+
+    n = len(held)
+    specs = [jax.ShapeDtypeStruct(shape, BF16, sharding=v5e) for shape in (
+        (T, d), (d, E), (n, d, width), (n, d, width), (n, width, d))]
+    with substrate.compile_for_tpu():
+        lowered = jax.jit(step, out_shardings=v5e).lower(*specs)
+        hlo = lowered.compile().as_text()
+    ours = [f for f in lowered.as_text().split("func.func ")
+            if re.match(r"private @_t?gmm", f)]
+    if path == "pallas":
+        assert "ragged-dot-none" not in hlo
+        assert 0 < len(_kernel_bodies(ours)) <= 6
+    else:
+        assert "ragged-dot-none" in hlo and not ours
+        assert "tpu_custom_call" in hlo      # the compiler's own kernel
 
 
 def test_fused_norm_epilogue_fwd_bwd_compiles_for_v5e(v5e):
