@@ -194,7 +194,8 @@ def train_loop(config: dict) -> None:
     # what the gates chose, from the shapes the step ran ...
     N, d, V = B * S, cfg.d_model, cfg.vocab_size
     gate = dict(n_devices=n, norm=cfg.norm, has_bias=cfg.use_bias)
-    routed = config["routed"]
+    routed_shapes = [config["routed"][k] for k in (
+        "rows", "top_k", "held", "experts", "d_model", "expert_ff")]
     ce = gpt.ce_path(N, d, V, ce_chunk=cfg.ce_chunk, n_devices=n)
     if flash_ce.uses_flash_ce_norm(N, d, V, ce_chunk=cfg.ce_chunk, **gate):
         ce = "flash_norm"
@@ -213,9 +214,9 @@ def train_loop(config: dict) -> None:
         # the form the routed 8k cell's differentiated expert layers
         # take their grouped products in (this step has no such layer;
         # the kernels phase checks them at these shapes)
-        "moe_product": moe.product_path(
-            routed["rows"], routed["top_k"], routed["held"],
-            routed["experts"], routed["d_model"], routed["expert_ff"]),
+        "moe_product": moe.product_path(*routed_shapes),
+        # ... and the combines of their rows with them
+        "moe_combine": moe.combine_path(*routed_shapes),
     }
     # ... and what the compiled step holds (the jitted call's own
     # executable comes back out of the cache)
@@ -444,6 +445,36 @@ def kernel_parity(config: dict) -> dict:
                    "tgmm": grouped_matmul.tiling(
                        rows_p, dm, 2 * fe, transposed_lhs=True)})
         del xs, dgu, w_gu, got, want
+
+        # -- its combine: a router's picks sorted by expert, the held
+        # picks' rows of the first piece summed into their tokens where
+        # the sort left them, against the gather of every pick's row ----
+        pick = jax.lax.top_k(rand((T, E), dtype=f32), topk)[1]
+        local = jnp.where(pick < len(held), pick, len(held)).astype(
+            jnp.int32)
+        sort = moe._sorted_picks(local, jnp.ones(local.shape, f32),
+                                 len(held), rows_p)
+        token, _, live, _, mine, at = jax.jit(lambda: moe._piece(
+            jnp.int32(0), rows_p, *sort, local < len(held)))()
+        # (whatever lies behind the last live row must not reach a sum)
+        y = jnp.where(live[:, None], rand((rows_p, dm)), jnp.nan)
+        tiles = grouped_matmul.combine_tiling(T, dm)
+
+        def summed(y):
+            runs = grouped_matmul.combine_runs(local, sort[3],
+                                               tile_t=tiles[0])
+            return (grouped_matmul.combine(y, token, runs, T=T),
+                    grouped_matmul.combine_windows(runs, [0], rows_p,
+                                                   tiles[1]))
+
+        exe, calls = compiled(summed, y)
+        got, windows = exe(y)
+        want = jax.jit(moe._pick_sum)(y, at, mine)
+        row("moe/combine", [T, rows_p, dm, len(held)],
+            {"sum": _rel_err(got, want)}, {"sum": 2e-6}, calls, 1,
+            moe_combine="pallas", tiles=tiles, windows=int(windows),
+            held_rows=int(jnp.sum(mine)))
+        del y, got, want
 
     # -- out-proj + residual + rmsnorm epilogue, differentiated ------------
     # (PR 53: the rule is XLA's, so this row guards its wiring: no Mosaic

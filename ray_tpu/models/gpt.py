@@ -506,26 +506,26 @@ def _moe_ffn(lp, x, cfg: GPTConfig):
 
 def _dropless_ffn(lp, x, cfg: GPTConfig):
     """The dropless expert FFN on x [B, S, d] -> (its output, the
-    layer's counts: ``parallel/moe.py:MOE_COUNTS`` and then the rows
-    each held expert took)."""
+    layer's counts: ``parallel/moe.py:MOE_COUNTS``, then the rows each
+    held expert took, then the windows its combines bring)."""
     from ray_tpu.parallel.moe import dropless_moe
     B, S, d = x.shape
     with jax.named_scope("moe"):
-        out, counts, load = dropless_moe(
+        out, counts, load, windows = dropless_moe(
             x.reshape(B * S, d), lp["moe_wg"],
             jnp.zeros((cfg.n_routed_experts,), jnp.float32),
             lp["moe_w1"], lp["moe_w3"], lp["moe_w2"],
             held=cfg.held_experts, n_routed=cfg.n_routed_experts,
             top_k=cfg.moe_top_k, scale=1.0,
             renormalise=cfg.moe_renormalise, with_load=True)
-    return out.reshape(B, S, d), jnp.concatenate([counts, load])
+    return out.reshape(B, S, d), jnp.concatenate([counts, load, windows[None]])
 
 
 def moe_counts_len(cfg: GPTConfig) -> int:
     """Length of a layer's counts vector: ``MOE_COUNTS``, then a row
-    count a held expert."""
+    count a held expert and its combines' windows."""
     from ray_tpu.parallel.moe import MOE_COUNTS
-    return len(MOE_COUNTS) + len(cfg.held_experts or ())
+    return len(MOE_COUNTS) + len(cfg.held_experts or ()) + 1
 
 
 def xla_attention_fn(cfg: GPTConfig, kind: str = "full"):

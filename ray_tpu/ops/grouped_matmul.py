@@ -13,7 +13,10 @@ belonging to no group:
   an empty group.  (The rows of no group are masked in ``lhs`` alone:
   ``rhs`` is finite there.)
 
-Both walk the same schedule, :func:`group_tiles`: the row tiles of
+- :func:`combine` — ``out[t] = sum of the sorted rows whose token is t``
+  over the runs the sort left them in (below) -> ``[T, d]`` float32.
+
+The two products walk the same schedule, :func:`group_tiles`: the row tiles of
 ``tile_m`` rows in order, a tile that straddles a boundary once for
 each group it touches with the other groups' rows masked, so the steps
 that compute follow the live rows and an expert nobody picked is never
@@ -47,8 +50,23 @@ compiler keeps, and its code is one chunk's product.  Tilings are
 functions of the shapes alone (:func:`tiling`); nothing is tuned at run
 time.
 
-:func:`uses_kernel` is the one place that decides from shapes whether
-a product takes these kernels or ``jax.lax.ragged_dot``.
+**The combine** (PR 59).  A stable sort by group leaves a group's rows
+ascending by token, so the rows of group ``g`` that belong to a tile of
+``tile_t`` consecutive tokens are one contiguous run of the sorted rows
+(:func:`combine_runs`).  :func:`combine` takes a token tile's sum as one
+grid step, run by run: a run is brought in windows of ``window`` rows by
+DMA from the rows where they lie in HBM (a window starts on a multiple
+of 128 rows: Mosaic slices the ``(16, 128)``-tiled bfloat16 buffer in
+whole tiles only — a one-row copy is refused, "Slice shape along
+dimension 0 must be aligned to tiling (8), but is 1" — and the tokens'
+``[1, M]`` lane vector on multiples of 128), met with a 0/1 matrix
+``S[t, r] = (token[r] == t)`` on the MXU and added in float32: products
+by 0 or 1, the sum a gather of the rows makes, in another order.  Two
+windows are in flight while one is summed.
+
+:func:`uses_kernel` and :func:`combine_uses_kernel` are the one place
+that decides from shapes whether a product or a combine takes these
+kernels or the compiler's form.
 """
 
 from __future__ import annotations
@@ -92,6 +110,17 @@ _CHUNK = 256
 # (gmm: 4.7 lhs + 42.5 rhs + 4.7 out + 2.4 product; in bfloat16 28),
 # over Mosaic's default 16; a v5e has 128
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# the combine: tokens of a tile and rows of a window, and the columns one
+# product takes.  At the routed 8k cell's shapes (16,384 tokens, 16
+# groups, ~32.7 k of 49,152 rows live, 2304 wide; the gather 5.96 ms):
+# tiles of 512 tokens read 1.52 ms a call, 256 1.61, 128 2.20, windows
+# of 256 rows 2.37-2.47; and the window's product over the whole width
+# at once, where walked 256 columns at a time it read 2.63 for 1.61 (a
+# product's fixed latency nine times a window), 768 1.86, 1152 1.73
+# (``PERF.md`` section 6, PR 59)
+_TILE_T = (512, 256, 128)
+_WINDOW = 128
+_COMBINE_CHUNK = 2304
 
 
 def _largest_tile(n: int, cap: int) -> int:
@@ -140,6 +169,27 @@ def tiling(M: int, k: int, n: int, *, transposed_lhs: bool = False
                 _largest_tile(tile_n, _CHUNK))
     tile_n = _largest_tile(n, _GMM_TILE_N)
     return tile_rows(M), tile_n, _largest_tile(tile_n, _CHUNK)
+
+
+def combine_uses_kernel(T: int, M: int, d: int) -> substrate.Support:
+    """Whether the combine of ``M`` sorted rows ``[M, d]`` into ``T``
+    tokens takes :func:`combine`: wherever the token tiles divide ``T``,
+    the windows ``M`` and the lanes ``d``.  Other shapes keep the
+    compiler's gather (``parallel/moe.py:_pick_sum``)."""
+    for name, size, tile in (("T", T, _TILE_T[-1]), ("M", M, _WINDOW),
+                             ("d", d, _LANES)):
+        if size <= 0 or size % tile:
+            return substrate.unsupported(
+                f"{name}={size} is not a multiple of {tile}")
+    return substrate.supported("pallas")
+
+
+def combine_tiling(T: int, d: int) -> Tuple[int, int, int]:
+    """``(tile_t, window, chunk)`` of :func:`combine` at these shapes:
+    tokens a tile, rows a window, columns one pass of the kernel's loop
+    takes."""
+    return (next(t for t in _TILE_T if T % t == 0), _WINDOW,
+            _largest_tile(d, _COMBINE_CHUNK))
 
 
 def one_trace():
@@ -225,6 +275,67 @@ def _group_tiles(sizes, *, M: int, tile_m: int) -> GroupTiles:
                   jnp.where(n_empty > 0, last_empty, last_held)))
     return GroupTiles(jnp.concatenate([jnp.zeros((1,), i32), ends]), group,
                       tile, zeroed, active.reshape(1))
+
+
+class Runs(NamedTuple):
+    """Where a stable sort by group left the rows of each (token tile,
+    group) (:func:`combine_runs`): one run of the sorted rows each."""
+    first: jax.Array      # [T / tile_t, G] the run's first sorted row
+    count: jax.Array      # [T / tile_t, G] its rows
+
+
+def combine_runs(local, starts, *, tile_t: int) -> Runs:
+    """The runs of picks sorted by group with a stable sort: ``local [T,
+    K]`` a pick's group (``G`` and up: none), ``starts [G]`` the first
+    sorted row of each group.  A group's rows ascend by token, so those
+    of a tile of ``tile_t`` consecutive tokens lie together.  Computed
+    once for the combines over every piece of the sorted rows."""
+    with one_trace():
+        return _combine_runs(local, starts, tile_t=tile_t)
+
+
+@functools.partial(jax.jit, static_argnames=("tile_t",))
+def _combine_runs(local, starts, *, tile_t: int) -> Runs:
+    # (broadcast comparisons and sums, as ``_group_tiles`` and for its
+    # reason; the picks along the lanes: [G, tiles])
+    T, K = local.shape
+    G = starts.shape[0]
+    tiles = T // tile_t
+    i32 = jnp.int32
+    count = jnp.sum(
+        local.reshape(1, tiles, tile_t * K) == lax.iota(i32, G)[:, None, None],
+        axis=2, dtype=i32)
+    ids = lax.iota(i32, tiles)
+    before = jnp.sum(jnp.where(ids[None, :] < ids[:, None], count[:, None, :],
+                               0), axis=2, dtype=i32)
+    return Runs((starts.astype(i32)[:, None] + before).T, count.T)
+
+
+def _windows(lo, hi, window: int):
+    """The first row of the first window of the run ``lo .. hi`` of a
+    piece's rows, and the windows of ``window`` rows it takes from
+    there (none for an empty run): scalars in the kernel, arrays in the
+    count."""
+    first = lax.div(lo, jnp.int32(_WINDOW)) * _WINDOW
+    return first, jnp.where(
+        hi > lo, lax.div(hi - first + (window - 1), jnp.int32(window)), 0)
+
+
+def _in_piece(runs: Runs, a, M: int):
+    """The runs' parts among the ``M`` sorted rows from row ``a``, as
+    rows of that piece: ``lo, hi``, equal where a run has no row
+    there."""
+    lo = runs.first - a
+    return jnp.clip(lo, 0, M), jnp.clip(lo + runs.count, 0, M)
+
+
+def combine_windows(runs: Runs, pieces, M: int, window: int):
+    """The windows :func:`combine` brings for ``runs`` over the pieces of
+    ``M`` sorted rows that start at the rows ``pieces [P]``, an int32
+    (the step telemetry's ``moe.combine_windows``)."""
+    lo, hi = _in_piece(runs, jnp.asarray(pieces, jnp.int32)[:, None, None],
+                       M)
+    return jnp.sum(_windows(lo, hi, window)[1], dtype=jnp.int32)
 
 
 def _last_computing(s, active_ref):
@@ -313,6 +424,130 @@ def _tgmm_kernel(offsets, zeroed, tile, active, lhs_ref, rhs_ref, out_ref,
     @pl.when((s == steps - 1) | (zeroed[jnp.minimum(s + 1, steps - 1)] != g))
     def _():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _combine_kernel(lo_ref, hi_ref, live_ref, token_ref, rows_ref, out_ref,
+                    win_ref, sem_ref, *, G: int, tile_t: int, window: int,
+                    chunk: int):
+    i = pl.program_id(0)
+    M = rows_ref.shape[0]
+    slots = win_ref.shape[0]
+
+    def run(g):
+        # group g's run in this tile, the first row of its first window
+        # and its windows (none behind the last group)
+        at = i * G + jnp.minimum(g, G - 1)
+        lo, hi = lo_ref[at], hi_ref[at]
+        first, n = _windows(lo, hi, window)
+        return lo, hi, first, jnp.where(g < G, n, 0)
+
+    def seek(g):
+        # the first group from g on that has a run (G and up: none)
+        return lax.while_loop(lambda h: (h < G) & (run(h)[3] == 0),
+                              lambda h: h + 1, g)
+
+    def following(g, j):
+        # the window behind window j of group g: the run's next, or the
+        # first of the next group that has one
+        more = j + 1 < run(g)[3]
+        return jnp.where(more, g, seek(g + 1)), jnp.where(more, j + 1, 0)
+
+    def copy(g, j, slot):
+        # (the buffer's last window where one would end behind it: the
+        # rows an earlier window brought are masked below)
+        row0 = pl.multiple_of(
+            jnp.minimum(run(g)[2] + j * window, M - window), _WINDOW)
+        return row0, pltpu.make_async_copy(
+            rows_ref.at[pl.ds(row0, window)], win_ref.at[slot],
+            sem_ref.at[slot])
+
+    def start(g, j, slot):
+        @pl.when(g < G)
+        def _():
+            copy(g, j, slot)[1].start()
+
+    def add(state):
+        # two windows are in flight while one is summed
+        g, j, g1, j1, slot = state
+        g2, j2 = following(g1, j1)
+        start(g2, j2, lax.rem(slot + 2, slots))
+        row0, brought = copy(g, j, slot)
+        brought.wait()
+        # the run's rows of the window that no earlier window brought:
+        # the others are 0 in the 0/1 matrix
+        lo, hi, first, _ = run(g)
+        lo = jnp.maximum(lo, first + j * window)
+        token = token_ref[:, pl.ds(row0, window)]              # [1, window]
+        lane = row0 + lax.broadcasted_iota(jnp.int32, (1, window), 1)
+        tokens = i * tile_t + lax.broadcasted_iota(
+            jnp.int32, (tile_t, window), 0)
+        pick = ((token == tokens) & (lane >= lo) & (lane < hi)).astype(
+            win_ref.dtype)                                 # [tile_t, window]
+
+        def adding(live):
+            def store(cols):
+                rows = win_ref[slot, :, cols]
+                if live is not None:
+                    rows = jnp.where(live, rows.astype(jnp.float32),
+                                     0.0).astype(rows.dtype)
+                out_ref[:, cols] += jnp.dot(
+                    pick, rows, preferred_element_type=jnp.float32)
+            return store
+
+        # a row behind the last live one may hold anything, and 0 x NaN
+        # is NaN: a window that reaches behind them is masked itself (a
+        # pass over the window that the others are spared: it was more
+        # than their product, ``PERF.md`` section 6, PR 59)
+        behind = row0 + window > live_ref[0]
+
+        @pl.when(behind)
+        def _():
+            narrow = _largest_tile(chunk, _CHUNK)
+            _columns(out_ref.shape[1], narrow, adding(
+                _rows_of(row0, (window, 1), 0, live_ref[0])))
+
+        @pl.when(~behind)
+        def _():
+            _columns(out_ref.shape[1], chunk, adding(None))
+        return g1, j1, g2, j2, lax.rem(slot + 1, slots)
+
+    out_ref[...] = jnp.zeros_like(out_ref)
+    g, j = seek(jnp.int32(0)), jnp.int32(0)
+    g1, j1 = following(g, j)
+    start(g, j, 0)
+    start(g1, j1, 1)
+    lax.while_loop(lambda state: state[0] < G, add,
+                   (g, j, g1, j1, jnp.int32(0)))
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def _combine(rows, token, runs, a, *, tiles, interpret):
+    tile_t, window, chunk = tiles
+    M, d = rows.shape
+    lo, hi = _in_piece(runs, a, M)
+    # (the runs lie end to end: the rows before the last run's end are
+    # the live ones)
+    live = jnp.max(hi).reshape(1)
+    n_tiles, G = lo.shape
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, G=G, tile_t=tile_t,
+                          window=window, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles,),
+            in_specs=[pl.BlockSpec((1, M), lambda i, *_: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile_t, d), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((3, window, d), rows.dtype),
+                            pltpu.SemaphoreType.DMA((3,))],
+        ),
+        compiler_params=substrate.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile_t, d), jnp.float32),
+        interpret=interpret,
+        name="combine",
+    )(lo.reshape(-1), hi.reshape(-1), live, token.reshape(1, M), rows)
 
 
 def _check(steps, M: int, tile_m: int, G: int):
@@ -435,3 +670,21 @@ def tgmm(lhs, rhs, sizes=None, *, walk: GroupTiles = None):
                      walk.active, tiles=tiles,
                      out_dtype=jnp.result_type(lhs, rhs),
                      interpret=substrate.use_interpret())
+
+
+def combine(rows, token, runs: Runs, a=0, *, T: int):
+    """``out[t] = sum of rows[r] over the sorted rows r of token t that
+    lie in a run``, float32: ``rows [M, d]`` the piece of the sorted rows
+    from row ``a`` (an int32 operand), ``token [M]`` their tokens
+    (int32), ``runs`` those of the picks (:func:`combine_runs` at
+    ``combine_tiling``'s tile) -> ``[T, d]``.  A row outside every run
+    is never added, and one behind the last run's end may hold anything
+    (a sort's runs lie end to end: those are the rows no pick has)."""
+    tiles = combine_tiling(T, rows.shape[1])
+    if runs.first.shape[0] * tiles[0] != T:
+        raise ValueError(
+            f"runs of {runs.first.shape[0]} token tiles are not those of "
+            f"{T} tokens in tiles of {tiles[0]}")
+    with one_trace():
+        return _combine(rows, token, runs, jnp.asarray(a, jnp.int32),
+                        tiles=tiles, interpret=substrate.use_interpret())

@@ -15,10 +15,11 @@ would bring its part is not run here).  A call nobody differentiates
 takes that loop; a differentiated one (a train step) takes the same
 layer as grouped products over the sorted picks, with a backward of its
 own, because a loop with a traced trip count has no reverse mode.  The
-products are ``ops/grouped_matmul.py``'s Pallas kernels wherever their
-tiles divide the layer's shapes (``grouped_matmul.uses_kernel``;
-:func:`product_path` names the form) and ``jax.lax.ragged_dot``
-elsewhere.
+products and the combine of their rows into tokens are
+``ops/grouped_matmul.py``'s Pallas kernels wherever their tiles divide
+the layer's shapes (``grouped_matmul.uses_kernel`` and
+``combine_uses_kernel``; :func:`product_path` names the form) and
+``jax.lax.ragged_dot`` and a gather elsewhere.
 """
 
 from __future__ import annotations
@@ -282,7 +283,11 @@ def _pick_sum(rows, pos, ours):
     It is the dearest thing around the products: rows read in no order
     take 43 ns each on a v5e (5.6 ms for 16384 x 8 rows of 2304) where
     rows in ascending runs, ``x[token]``, take 7.6; a scatter-add of the
-    held rows alone, sorted by token or not, read 5.1-6.5 ms (PR 56)."""
+    held rows alone, sorted by token or not, read 5.1-6.5 ms (PR 56).
+    So a layer whose shapes the kernels take sums the held picks' rows
+    where the sort left them (:func:`_combine`: 1.5 ms for this form's
+    5.96 at those shapes, PR 59), and this is the form of the others
+    and the kernel's reference."""
     T, K = pos.shape
     picked = rows[pos.reshape(T * K)].reshape(T, K, -1)
     return jnp.sum(jnp.where(ours[:, :, None], picked.astype(jnp.float32),
@@ -309,14 +314,17 @@ def piece_rows(T: int, top_k: int, held: int, experts: int) -> int:
     return min(T * top_k, -(-int(want) // _PIECE_ROWS) * _PIECE_ROWS)
 
 
-def _kernels_take(rows: int, d: int, f: int) -> bool:
-    """Whether a layer's grouped products over ``rows`` sorted rows are
-    ``ops/grouped_matmul.py``'s: one decision for the six kinds of a
-    layer, the gate's on each contraction and width among them (gate|up
-    and down forward, and the two read transposed for the rows'
-    gradients; the matrices' gradients have the same widths)."""
-    return all(grouped_matmul.uses_kernel(rows, k, n) for k, n in (
-        (d, 2 * f), (f, d), (d, f), (2 * f, d)))
+def _kernels_take(T: int, rows: int, d: int, f: int) -> bool:
+    """Whether a layer's grouped products over ``rows`` sorted rows of
+    ``T`` tokens, and the combines of their rows, are
+    ``ops/grouped_matmul.py``'s: one decision for a layer, the gate's on
+    each contraction and width of its six kinds of product (gate|up and
+    down forward, and the two read transposed for the rows' gradients;
+    the matrices' gradients have the same widths) and the combine's on
+    the tokens, the rows and the width."""
+    return bool(grouped_matmul.combine_uses_kernel(T, rows, d)) and all(
+        grouped_matmul.uses_kernel(rows, k, n) for k, n in (
+            (d, 2 * f), (f, d), (d, f), (2 * f, d)))
 
 
 def product_path(T: int, top_k: int, held: int, experts: int, d: int,
@@ -325,7 +333,14 @@ def product_path(T: int, top_k: int, held: int, experts: int, d: int,
     shapes, ``pallas`` or ``ragged_dot`` (the step telemetry's
     ``moe_product``)."""
     return "pallas" if _kernels_take(
-        piece_rows(T, top_k, held, experts), d, f) else "ragged_dot"
+        T, piece_rows(T, top_k, held, experts), d, f) else "ragged_dot"
+
+
+def combine_path(*shapes: int) -> str:
+    """The form the layer of :func:`product_path`'s shapes sums its rows
+    into tokens in, ``pallas`` or ``xla`` (a gather), with its products:
+    one decision a layer (the step telemetry's ``moe_combine``)."""
+    return "pallas" if product_path(*shapes) == "pallas" else "xla"
 
 
 def _sorted_picks(local, weight, held: int, piece: int):
@@ -395,18 +410,56 @@ def _later_pieces(M: int, piece: int, ends, run, first):
     return lax.cond(ends[-1] > piece, later, lambda first: first, first)
 
 
-def _walk(n, rows: int, d: int, f: int):
+def _walk(n, T: int, rows: int, d: int, f: int):
     """The schedule of a piece's products over its ``rows`` sorted rows
     (``grouped_matmul.group_tiles``), made once a piece for all of them,
     or None where the products are ``jax.lax.ragged_dot``'s."""
-    if not _kernels_take(rows, d, f):
+    if not _kernels_take(T, rows, d, f):
         return None
     return grouped_matmul.group_tiles(n, rows,
                                       grouped_matmul.tile_rows(rows))
 
 
+def _runs(local, starts, piece: int, d: int, f: int):
+    """Where the sort left the rows of each (token tile, held expert)
+    (``grouped_matmul.combine_runs`` at the combine's tile), made once a
+    layer and direction for the combines of every piece, or None where
+    the layer sums its rows by a gather (:func:`_pick_sum`)."""
+    T = local.shape[0]
+    if not _kernels_take(T, piece, d, f):
+        return None
+    return grouped_matmul.combine_runs(
+        local, starts, tile_t=grouped_matmul.combine_tiling(T, d)[0])
+
+
+def _combine(rows, a, token, runs, at, mine):
+    """``out[t] = sum of the piece's rows of t's held picks``, float32:
+    ``grouped_matmul.combine`` over the runs' parts in the piece from
+    ``a`` where the layer's shapes take the kernels, else
+    :func:`_pick_sum`."""
+    if runs is None:
+        return _pick_sum(rows, at, mine)
+    return grouped_matmul.combine(rows, token, runs, a, T=at.shape[0])
+
+
+def _combine_windows(local, load, experts: int, d: int, f: int):
+    """The windows of sorted rows that the combines of one direction of
+    a layer bring for the picks ``local [T, K]`` (``load [held]``: the
+    rows each held expert took), over every piece, an int32; 0 where the
+    layer keeps the gather.  (The step telemetry's
+    ``moe.combine_windows``; the backward brings as many again.)"""
+    T, K = local.shape
+    piece = piece_rows(T, K, load.shape[0], experts)
+    runs = _runs(local, jnp.cumsum(load) - load, piece, d, f)
+    if runs is None:
+        return jnp.int32(0)
+    return grouped_matmul.combine_windows(
+        runs, [a for a, _ in _pieces(T * K, piece)], piece,
+        grouped_matmul.combine_tiling(T, d)[1])
+
+
 @functools.partial(jax.jit, static_argnames=("piece",))
-def _piece_fwd(a, x, sort, ours, w_gu, w_down, *, piece: int):
+def _piece_fwd(a, x, sort, ours, runs, w_gu, w_down, *, piece: int):
     """The forward of the ``piece`` sorted rows from ``a`` (int32): its
     part of the layer's sum, and its gate|up product.  One jitted
     function for the first piece and the later ones of every layer: a
@@ -415,10 +468,10 @@ def _piece_fwd(a, x, sort, ours, w_gu, w_down, *, piece: int):
     ``PERF.md`` section 6, PR 58), and the compiler, which inlines it,
     sees ``a == 0`` where that is what the caller passed."""
     token, ws, live, n, mine, at = _piece(a, piece, *sort, ours)
-    walk = _walk(n, piece, x.shape[1], w_down.shape[1])
+    walk = _walk(n, x.shape[0], piece, x.shape[1], w_down.shape[1])
     gu = _ragged(x[token], w_gu, n, walk)                     # [piece, 2f]
     y = _ragged(_hidden(gu, ws, live), w_down, n, walk)
-    return _pick_sum(y, at, mine), gu
+    return _combine(y, a, token, runs, at, mine), gu
 
 
 def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
@@ -444,10 +497,12 @@ def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
     ours = local < held
     sort = _sorted_picks(local, weight, held, piece)
     w_gu, w_down = _gate_up(e_gate, e_up, lead), _at(e_down, lead)
+    runs = _runs(local, sort[3], piece, x.shape[1], w_down.shape[1])
 
     def run(a):
         with grouped_matmul.one_trace():
-            return _piece_fwd(a, x, sort, ours, w_gu, w_down, piece=piece)
+            return _piece_fwd(a, x, sort, ours, runs, w_gu, w_down,
+                              piece=piece)
 
     out, gu = run(jnp.int32(0))
     out = _later_pieces(T * K, piece, sort[-1], lambda a: run(a)[0], out)
@@ -468,14 +523,15 @@ def _hidden(gu, ws, live):
 
 
 @functools.partial(jax.jit, static_argnames=("piece",))
-def _piece_bwd(a, gu, x, dout, sort, ours, w_gu, w_down, *, piece: int):
+def _piece_bwd(a, gu, x, dout, sort, ours, runs, w_gu, w_down, *,
+               piece: int):
     """The backward of the ``piece`` sorted rows from ``a``
     (:func:`_piece_fwd`), given the piece's gate|up product or None, in
     which case it is computed again: its part of the gradients in ``x``
     and the weights ``[T, K]``, and the two matrices' gradients."""
     token, ws, live, n, mine, at = _piece(a, piece, *sort, ours)
     dt = x.dtype
-    walk = _walk(n, piece, x.shape[1], w_down.shape[1])
+    walk = _walk(n, x.shape[0], piece, x.shape[1], w_down.shape[1])
     rows = live[:, None]
     xs = x[token]
     if gu is None:
@@ -490,8 +546,12 @@ def _piece_bwd(a, gu, x, dout, sort, ours, w_gu, w_down, *, piece: int):
     dh = dh * ws[:, None]
     dgu = jnp.concatenate([dh * u * sg * (1.0 + g * (1.0 - sg)),
                            dh * act], axis=-1).astype(dt)
-    dxs = jnp.where(rows, _ragged(dgu, w_gu, n, walk, True), 0)
-    return (_pick_sum(dxs, at, mine),
+    dxs = _ragged(dgu, w_gu, n, walk, True)
+    if runs is None:
+        # (the kernel's combine reads no row behind the live ones, and
+        # takes the product's rows as the forward's does: one trace)
+        dxs = jnp.where(rows, dxs, 0)
+    return (_combine(dxs, a, token, runs, at, mine),
             jnp.where(mine, dws[at], 0.0),
             _ragged_outer(xs, dgu, n, walk),                   # [G, d, 2f]
             _ragged_outer(_hidden(gu, ws, live), dy, n, walk))
@@ -513,12 +573,13 @@ def _sorted_experts_bwd(piece, res, cts):
     ours = local < held
     sort = _sorted_picks(local, weight, held, piece)
     w_gu, w_down = _gate_up(e_gate, e_up, lead), _at(e_down, lead)
+    runs = _runs(local, sort[3], piece, x.shape[1], f)
     dout = cts[0].astype(dt)
 
     def run(a, gu=None):
         with grouped_matmul.one_trace():
-            return _piece_bwd(a, gu, x, dout, sort, ours, w_gu, w_down,
-                              piece=piece)
+            return _piece_bwd(a, gu, x, dout, sort, ours, runs, w_gu,
+                              w_down, piece=piece)
 
     grads = _later_pieces(T * K, piece, sort[-1], run,
                           run(jnp.int32(0), gu_first))
@@ -567,8 +628,9 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
     where the row lives.  A pick on a routed expert held elsewhere adds
     nothing.  ``valid`` [T] bool marks the rows that are tokens of a
     sequence (absent: all): the others pick nothing and count nothing.
-    ``with_load`` appends the rows each held expert took ([held] int32)
-    to what is returned.
+    ``with_load`` appends to what is returned the rows each held expert
+    took ([held] int32) and the windows a differentiated call's combines
+    bring in one direction (:func:`_combine_windows`).
     Device scopes: ``route``, ``experts``, ``identity`` (the caller
     names the layer).  A differentiated call computes the held experts'
     part by grouped products over the sorted picks and has gradients in
@@ -608,5 +670,6 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
     if with_load:
         load = jnp.sum(local[:, :, None] == jnp.arange(len(held)),
                        axis=(0, 1), dtype=jnp.int32)
-        return out, counts, load
+        return out, counts, load, _combine_windows(
+            local, load, n_routed, d, e_gate.shape[-1])
     return out, counts

@@ -14,12 +14,15 @@ Wraps a jitted train step (``build_gpt_train``/``build_gpt_train_pp``
   (``ray_tpu.parallel.overlap.collective_bytes_per_step``),
 - for a step that returns its expert layers' counts (``moe_counts``, a
   config with ``held_experts``): ``moe.rows``, ``moe.held_picks``,
-  ``moe.experts_hit`` and ``moe.imbalance`` (the busiest held expert's
-  rows over the mean), read with the loss in the step's one fetch; and
+  ``moe.experts_hit``, ``moe.imbalance`` (the busiest held expert's
+  rows over the mean) and ``moe.combine_windows`` (the windows of sorted
+  rows the layers' combines bring in one direction, 0 where they are
+  gathers), read with the loss in the step's one fetch; and
   on the first record the attention coverage of each layer kind
   (``attn_coverage``: the share of the score square the kind's schedule
   executes beside the share it needs) and the form of the layers'
-  grouped products (``moe_product``: ``pallas`` / ``ragged_dot``).
+  grouped products and of their combines (``moe_product``: ``pallas`` /
+  ``ragged_dot``, ``moe_combine``: ``pallas`` / ``xla``).
 
 Records flow to three sinks: the Chrome-trace exporter
 (:mod:`ray_tpu.telemetry.chrome_trace`, merged into the dashboard
@@ -235,6 +238,7 @@ class StepTelemetry:
             rec["causal_coverage"] = self.causal_coverage()
         if i == 0 and self.moe_product() is not None:
             rec["moe_product"] = self.moe_product()
+            rec["moe_combine"] = self.moe_combine()
         self.records.append(rec)
         if len(self.records) > self._MAX_RECORDS:
             # bounded like the control plane's task-event buffer: a
@@ -285,7 +289,8 @@ class StepTelemetry:
     def _maybe_moe(self, out) -> Optional[Dict[str, float]]:
         """The expert layers' counts of a step that returns them
         (``metrics["moe_counts"]``: ``parallel/moe.py:MOE_COUNTS`` summed
-        over layers, then the rows each held expert took)."""
+        over layers, then the rows each held expert took, then the
+        windows the combines bring)."""
         if not (isinstance(out, tuple) and len(out) == 2
                 and isinstance(out[1], dict) and "moe_counts" in out[1]):
             return None
@@ -294,10 +299,10 @@ class StepTelemetry:
         from ray_tpu.parallel.moe import MOE_COUNTS
         vec = np.asarray(out[1]["moe_counts"])
         named = dict(zip(MOE_COUNTS, (int(v) for v in vec)))
-        load = vec[len(MOE_COUNTS):]
+        load = vec[len(MOE_COUNTS):-1]
         moe = {"rows": named["rows"], "held_picks": named["held_picks"],
                "experts_hit": named["experts_hit"],
-               "calls": named["calls"]}
+               "calls": named["calls"], "combine_windows": int(vec[-1])}
         if load.size and load.sum() > 0:
             moe["imbalance"] = float(load.max() / load.mean())
         return moe
@@ -351,13 +356,26 @@ class StepTelemetry:
         (``parallel.moe.product_path``, over the gate
         ``grouped_matmul.uses_kernel``).  ``None`` until a batch has
         shown its shape, and for a config with no such layer."""
+        from ray_tpu.parallel.moe import product_path
+        shapes = self._moe_shapes()
+        return shapes and product_path(*shapes)
+
+    def _moe_shapes(self):
         cfg = self.cfg
         if self._seq is None or not getattr(cfg, "held_experts", None):
             return None
-        from ray_tpu.parallel.moe import product_path
-        return product_path(self._batch * self._seq, cfg.moe_top_k,
-                            len(cfg.held_experts), cfg.n_routed_experts,
-                            cfg.d_model, cfg.ff_dim)
+        return (self._batch * self._seq, cfg.moe_top_k,
+                len(cfg.held_experts), cfg.n_routed_experts, cfg.d_model,
+                cfg.ff_dim)
+
+    def moe_combine(self) -> Optional[str]:
+        """The form those layers sum their rows into tokens in:
+        ``pallas`` (``grouped_matmul.combine``) with the products'
+        kernels, ``xla`` (a gather) with ``ragged_dot`` — a layer's one
+        decision (``parallel.moe.combine_path``)."""
+        from ray_tpu.parallel.moe import combine_path
+        shapes = self._moe_shapes()
+        return shapes and combine_path(*shapes)
 
     def causal_coverage(self) -> Optional[float]:
         """The share of the causal score square the step's attention
@@ -461,7 +479,8 @@ class StepTelemetry:
                     n = len(routed)
                     layers = max(1, routed[0]["calls"])
                     moe = {key: sum(m[key] for m in routed) / n
-                           for key in ("rows", "held_picks", "experts_hit")}
+                           for key in ("rows", "held_picks", "experts_hit",
+                                       "combine_windows")}
                     moe["held_picks_per_token"] = (
                         moe["held_picks"] / max(1.0, moe["rows"]))
                     moe["experts_hit_per_layer"] = (
@@ -487,6 +506,7 @@ class StepTelemetry:
                 product = self.moe_product()
                 if product is not None:
                     out["moe_product"] = product
+                    out["moe_combine"] = self.moe_combine()
                 if fpt is not None and peak is not None:
                     out["chip_peak_tflops"] = peak
                     out["mfu"] = flops_mod.mfu(

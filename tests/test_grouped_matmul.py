@@ -2,7 +2,9 @@
 through ``substrate.use_interpret``) against ``jax.lax.ragged_dot`` and a
 dense loop over the groups: empty groups, boundaries inside a tile,
 groups smaller than a tile, rows no group has; the schedule they walk;
-the gate; and that call sites of one shape share one traced kernel."""
+the gate; that call sites of one shape share one traced kernel; and the
+combine of sorted rows into tokens against the layer's gather and a loop
+over the picks."""
 
 import re
 
@@ -14,6 +16,7 @@ from jax import lax
 
 from ray_tpu.ops import grouped_matmul as gm
 from ray_tpu.ops import substrate
+from ray_tpu.parallel import moe
 
 M, K, N = 384, 256, 128                 # three row tiles of 128
 
@@ -209,3 +212,129 @@ def test_call_sites_of_one_shape_share_one_kernel_body():
     bodies = re.findall(r"func\.func private @(_t?gmm)\w*\(", text)
     assert sorted(bodies) == ["_gmm", "_gmm", "_tgmm"], bodies
     assert len(re.findall(r"call @_t?gmm", text)) >= 12
+
+
+# ------------------------------------------------------------ the combine
+
+T_C, K_C, E_C, G_C = 1024, 2, 8, 4       # two token tiles of 512
+
+
+def _picks(case):
+    """``local [T, K]``: a pick's index among the ``G_C`` experts held,
+    ``G_C`` where it is not this chip's."""
+    rng = np.random.default_rng(3)
+    pick = np.stack([rng.permutation(E_C)[:K_C] for _ in range(T_C)])
+    local = np.where(pick < G_C, pick, G_C)
+    if case == "no_held_pick":
+        local[:] = G_C
+    if case == "every_pick_held":
+        local = np.stack([rng.permutation(G_C)[:K_C] for _ in range(T_C)])
+    if case == "one_token_all_picks_one_none":
+        local[5], local[6] = (0, 3), (G_C, G_C)
+    if case == "an_expert_nobody_picked":
+        local[local == 2] = G_C
+    if case == "a_run_longer_than_a_window":
+        local[:, 0] = 1                      # 512 rows a token tile
+        local[:, 1] = np.where(local[:, 1] == 1, G_C, local[:, 1])
+    if case == "runs_off_the_tiling":        # 3, 5, 40 and 21 rows
+        local[:] = G_C
+        for e, (first, n) in enumerate(((7, 3), (250, 5), (300, 40),
+                                        (100, 21))):
+            local[first:first + 2 * n:2, e % K_C] = e
+    return local.astype(np.int32)
+
+
+# case -> (first row, rows) of the piece of the T_C x K_C sorted rows
+COMBINES = {
+    "uniform": (0, 2048), "no_held_pick": (0, 2048),
+    "every_pick_held": (0, 2048), "one_token_all_picks_one_none": (0, 2048),
+    "an_expert_nobody_picked": (0, 2048),
+    "a_run_longer_than_a_window": (0, 2048),
+    "runs_off_the_tiling": (0, 128),
+    # (uniform routing holds ~1024 rows: picks before, in and behind)
+    "a_later_piece": (384, 512), "the_last_piece": (896, 256),
+    "nan_behind_the_last_live_row": (0, 1152),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(COMBINES))
+def test_combine_matches_the_gather_and_a_loop_over_the_picks(case, dtype):
+    a, rows_piece = COMBINES[case]
+    local = jnp.asarray(_picks(case))
+    d = 256
+    sort = moe._sorted_picks(local, jnp.ones(local.shape), G_C, rows_piece)
+    token, _, live, n, mine, at = moe._piece(
+        jnp.int32(a), rows_piece, *sort, local < G_C)
+    # whatever lies behind the last live row must not reach a sum
+    rows = jnp.where(live[:, None], jax.random.normal(
+        jax.random.PRNGKey(1), (rows_piece, d)), jnp.nan).astype(dtype)
+    tile_t, window, _ = gm.combine_tiling(T_C, d)
+    runs = gm.combine_runs(local, sort[3], tile_t=tile_t)
+    lo, hi = gm._in_piece(runs, a, rows_piece)
+    # the runs are each (token tile, expert)'s rows, here the piece's part
+    count = np.stack([(np.asarray(local).reshape(-1, tile_t * K_C) == e
+                       ).sum(1) for e in range(G_C)], 1)
+    first = np.asarray(sort[3])[None] + np.cumsum(count, 0) - count
+    np.testing.assert_array_equal(lo, np.clip(first - a, 0, rows_piece))
+    np.testing.assert_array_equal(
+        hi, np.clip(first + count - a, 0, rows_piece))
+    np.testing.assert_array_equal(runs.first, first)
+    np.testing.assert_array_equal(runs.count, count)
+    got = np.asarray(gm.combine(rows, token, runs, jnp.int32(a), T=T_C))
+    want = np.zeros((T_C, d), np.float32)
+    held = np.zeros((T_C,), np.int32)
+    for r in range(int(jnp.sum(live))):
+        want[token[r]] += np.asarray(rows[r], np.float32)
+        held[token[r]] += 1
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
+    np.testing.assert_allclose(got, moe._pick_sum(rows, at, mine),
+                               rtol=2e-6, atol=1e-6)
+    np.testing.assert_array_equal(got[held <= 1], want[held <= 1])
+    assert held.sum() == int(jnp.sum(mine)) == int(jnp.sum(hi - lo))
+    if case == "a_run_longer_than_a_window":
+        assert int(jnp.max(hi - lo)) == tile_t > window
+    if case == "no_held_pick":
+        assert not got.any()
+    # the windows a run takes: from the 128 rows its first row lies in
+    windows = sum(-(-(h - l // 128 * 128) // window)
+                  for l, h in zip(np.asarray(lo).ravel(),
+                                  np.asarray(hi).ravel()) if h > l)
+    assert int(gm.combine_windows(runs, [a], rows_piece, window)) == windows
+    assert windows > 0 or case == "no_held_pick"
+
+
+def test_combine_gate_declines_what_its_tiles_do_not_divide():
+    assert gm.combine_uses_kernel(16384, 49152, 2304).reason == "pallas"
+    assert gm.combine_tiling(16384, 2304) == (512, 128, 2304)
+    assert gm.combine_tiling(384, 128)[0] == 128
+    for shape, name in (((192, 512, 128), "T=192"), ((256, 80, 128), "M=80"),
+                        ((256, 512, 64), "d=64")):
+        gate = gm.combine_uses_kernel(*shape)
+        assert not gate and name in gate.reason
+    with pytest.raises(ValueError, match="token tiles"):
+        gm.combine(jnp.zeros((128, 128)), jnp.zeros((128,), jnp.int32),
+                   gm.Runs(jnp.zeros((3, 4), jnp.int32),
+                           jnp.zeros((3, 4), jnp.int32)), T=256)
+
+
+def test_combines_of_one_shape_share_one_kernel_body():
+    local = jnp.asarray(_picks("uniform"))
+    sort = moe._sorted_picks(local, jnp.ones(local.shape), G_C, 2048)
+    rows = jax.random.normal(jax.random.PRNGKey(1), (2048, 128))
+
+    def loss(rows):
+        out = 0.0
+        for a in (0, 0, 0):
+            runs = gm.combine_runs(local, sort[3], tile_t=512)
+            y = gm.combine(rows, sort[0], runs, jnp.int32(a), T=T_C)
+            out = out + jnp.sum(lax.cond(
+                runs.first[0, 0] == 0, lambda: y + gm.combine(
+                    rows, sort[0], runs, jnp.int32(a), T=T_C), lambda: y))
+        return out
+
+    text = jax.jit(loss).lower(rows).as_text()
+    bodies = re.findall(r"func\.func private @(_combine(?:_runs)?)\w*\(", text)
+    assert sorted(bodies) == ["_combine", "_combine_runs"], bodies
+    assert len(re.findall(r"call @_combine\b", text)) >= 6

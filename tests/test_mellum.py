@@ -182,18 +182,24 @@ def _dense(x, router, gate, up, down, *, held, K):
     return out
 
 
-# the layer at widths that keep the compiler's product, and at widths the
-# Pallas kernels' tiles divide (interpreted here); and for each how the
-# sorted picks (T x 2 rows) are cut: (headroom, rounding) -> rows a piece
+# the layer at widths that keep the compiler's product and its gather;
+# at widths the Pallas kernels' tiles divide (interpreted here: the
+# grouped products and the combine of their rows); at those widths with
+# tokens the combine's tiles do not divide, which keeps the compiler's
+# forms for the whole layer; and for each how the sorted picks (T x 2
+# rows) are cut: (headroom, rounding) -> rows a piece
 _LAYERS = {"ragged_dot": dict(T=96, d=32, f=48),
-           "pallas": dict(T=256, d=128, f=128)}
+           "pallas": dict(T=256, d=128, f=128),
+           "tokens_off_the_tiles": dict(T=192, d=128, f=128)}
 _CUTS = {("ragged_dot", "four_pieces"): (0.5, 8, 48),            # 4 x 48
          ("ragged_dot", "padded_last_piece"): (0.5, 40, 80),     # 192 of 240
          ("pallas", "four_pieces"): (0.5, 128, 128),             # 4 x 128
-         ("pallas", "padded_last_piece"): (0.5, 384, 384)}       # 512 of 768
+         ("pallas", "padded_last_piece"): (0.5, 384, 384),       # 512 of 768
+         ("tokens_off_the_tiles", "four_pieces"): (0.5, 128, 128),  # 3
+         ("tokens_off_the_tiles", "padded_last_piece"): (0.5, 256, 256)}
 
 
-@pytest.mark.parametrize("products", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize("products", list(_LAYERS))
 @pytest.mark.parametrize("pieces", ["one_piece", "four_pieces",
                                     "padded_last_piece"])
 @pytest.mark.parametrize("routing", ["uniform", "one_expert", "idle_expert",
@@ -208,11 +214,11 @@ def test_differentiated_grouped_products_match_loop_and_dense(
         monkeypatch.setattr(moe, "_PIECE_HEADROOM", headroom)
         monkeypatch.setattr(moe, "_PIECE_ROWS", rounding)
         assert moe.piece_rows(shape["T"], 2, 4, 8) == rows
-    assert moe.product_path(shape["T"], 2, 4, 8, shape["d"],
-                            shape["f"]) == products
+    assert moe.product_path(shape["T"], 2, 4, 8, shape["d"], shape["f"]) == (
+        "pallas" if products == "pallas" else "ragged_dot")
     L = _layer(**shape)
     # the cases the parent had keep its tolerances; RTOL_WIDER
-    rtol = 2e-6 if (products == "pallas" or routing == "all_held"
+    rtol = 2e-6 if (products != "ragged_dot" or routing == "all_held"
                     or pieces == "padded_last_piece") else 1e-7
     held, E, K = L["held"], L["E"], 2
     router = L["router"]
@@ -384,7 +390,8 @@ def test_step_returns_counts_and_telemetry_reports_them_with_coverage():
     for _ in range(3):
         state, metrics = fns["step_fn"](state, batch)
     assert metrics["grad_norm"].dtype == jnp.float32
-    assert metrics["moe_counts"].shape == (len(moe.MOE_COUNTS) + 4,)
+    # MOE_COUNTS, a row count a held expert, the combines' windows
+    assert metrics["moe_counts"].shape == (len(moe.MOE_COUNTS) + 4 + 1,)
     tel = fns["telemetry"]
     first = tel.records[0]
     assert set(first["attn_coverage"]) == {"window", "full"}
@@ -393,10 +400,16 @@ def test_step_returns_counts_and_telemetry_reports_them_with_coverage():
     # the form of the grouped products, from the step's shapes: experts
     # 32 wide keep the compiler's; the routed 8k cell's take the kernels
     assert first["moe_product"] == "ragged_dot"
+    # ... and their combines the gather, which brings no window
+    assert first["moe_combine"] == "xla"
+    assert first["moe"]["combine_windows"] == 0
     assert "moe_product" not in tel.records[1]
+    assert "moe_combine" not in tel.records[1]
     assert moe.product_path(2 * 8192, 8, 16, 64, 2304, 896) == "pallas"
     summary = tel.summary()
     assert summary["moe_product"] == "ragged_dot"
+    assert summary["moe_combine"] == "xla"
+    assert summary["moe"]["combine_windows"] == 0
     assert summary["moe"]["experts_hit_per_layer"] <= 4
     picks = summary["moe"]["held_picks_per_token"]
     assert 0 < picks <= 2
@@ -405,6 +418,97 @@ def test_step_returns_counts_and_telemetry_reports_them_with_coverage():
         cfg, 128, ce_recompute=False, held_picks_per_token=picks)
     with pytest.raises(NotImplementedError, match="accum_steps"):
         training.build_gpt_train(cfg, mesh, accum_steps=2)
+
+
+def _windows_by_hand(local, held, piece, tile_t, window):
+    """The windows the combines of one direction bring: for each piece
+    of the sorted rows and each (token tile, held expert) with a row in
+    it, from the 128 rows its run's first row there lies in to the run's
+    end, in windows of ``window``."""
+    local = np.asarray(local)
+    T, K = local.shape
+    n = np.array([(local == e).sum() for e in range(held)])
+    starts, total = np.cumsum(n) - n, 0
+    for a in range(0, T * K, piece):
+        for e in range(held):
+            at = starts[e]
+            for tile in local.reshape(T // tile_t, tile_t * K):
+                rows = int((tile == e).sum())
+                lo, hi = (int(np.clip(v - a, 0, piece))
+                          for v in (at, at + rows))
+                at += rows
+                if hi > lo:
+                    total += -(-(hi - lo // 128 * 128) // window)
+    return total
+
+
+@pytest.mark.parametrize("pieces", ["one_piece", "four_pieces"])
+def test_layer_counts_its_combines_windows_as_the_routing_gives_them(
+        monkeypatch, pieces):
+    """``dropless_moe(with_load=True)`` appends the windows its combines
+    bring in one direction; 0 for a layer that keeps the gather."""
+    from ray_tpu.ops import grouped_matmul
+    shape = _LAYERS["pallas"]
+    if pieces != "one_piece":
+        headroom, rounding, _ = _CUTS["pallas", pieces]
+        monkeypatch.setattr(moe, "_PIECE_HEADROOM", headroom)
+        monkeypatch.setattr(moe, "_PIECE_ROWS", rounding)
+    L = _layer(**shape)
+    held, E, K = L["held"], L["E"], 2
+    kw = dict(held=held, n_routed=E, top_k=K, scale=1.0, renormalise=True,
+              with_load=True)
+    args = (L["x"], L["router"], jnp.zeros((E,)), L["gate"], L["up"],
+            L["down"])
+    _, counts, load, windows = moe.dropless_moe(*args, **kw)
+    pick = jax.lax.top_k(jax.nn.softmax(L["x"] @ L["router"], -1), K)[1]
+    local_of = np.full((E,), len(held))
+    local_of[list(held)] = np.arange(len(held))
+    tile_t, window, _ = grouped_matmul.combine_tiling(shape["T"], shape["d"])
+    want = _windows_by_hand(
+        local_of[np.asarray(pick)], len(held),
+        moe.piece_rows(shape["T"], K, len(held), E), tile_t, window)
+    assert int(windows) == want > 0
+    assert int(load.sum()) == int(counts[moe.MOE_COUNTS.index("held_picks")])
+    # every held row lies in a window: the windows cover the held picks
+    assert int(windows) * window >= int(load.sum())
+    small = _layer()                    # 32 wide: the compiler's forms
+    _, _, _, none = moe.dropless_moe(
+        small["x"], small["router"], jnp.zeros((E,)), small["gate"],
+        small["up"], small["down"], **kw)
+    assert int(none) == 0
+
+
+def test_telemetry_names_the_routed_cells_combine_and_reads_its_windows():
+    """At the routed 8k cell's shapes the layers' combines are the
+    kernel's (``moe_combine`` with ``moe_product``: one decision a
+    layer), and the step's counts carry their windows last."""
+    from ray_tpu.telemetry.step import StepTelemetry
+    cfg = gpt.GPTConfig.mellum2_12b_a2_5b(
+        n_layers=4, vocab_size=24576, held_experts=tuple(range(16)),
+        max_seq=8192)
+    tel = StepTelemetry(cfg)
+    held = len(cfg.held_experts)
+    vec = np.zeros((gpt.moe_counts_len(cfg),), np.int32)
+    vec[:len(moe.MOE_COUNTS)] = (4 * 16384, 130_000, 0, 4 * 131_072, 64, 4)
+    vec[len(moe.MOE_COUNTS):-1] = 130_000 // held
+    vec[-1] = 3_052
+    tokens = jnp.zeros((2, 8192), jnp.int32)
+
+    def step(state, batch):
+        return state, {"loss": jnp.float32(1.0), "moe_counts": vec}
+
+    wrapped = tel.wrap(step)
+    for _ in range(3):
+        wrapped(None, {"tokens": tokens, "targets": tokens})
+    first = tel.records[0]
+    assert first["moe_product"] == first["moe_combine"] == "pallas"
+    assert first["moe"]["combine_windows"] == 3_052
+    assert first["moe"]["imbalance"] == 1.0
+    summary = tel.summary()
+    assert summary["moe_combine"] == "pallas"
+    assert summary["moe"]["combine_windows"] == 3_052
+    # how much the windows over-read: held picks over the rows brought
+    assert 0.2 < summary["moe"]["held_picks"] / (3_052 * 128) < 0.4
 
 
 @pytest.mark.parametrize("warmup", [10, 1000])

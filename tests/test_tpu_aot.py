@@ -196,10 +196,12 @@ def _kernel_bodies(functions):
 def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     """The 4-layer step of ``train-mellum2-12b-a2.5b-ep4-b2x8192`` with
     the cell file's recipe, compiled for one v5e: window and full
-    attention kernels, the grouped products and their gradients as
-    Mosaic calls of at most six distinct kernels, and arguments +
-    temporaries under the 15.75 GB the issue allows (13.5 GB when the
-    recipe was settled, PR 56)."""
+    attention kernels, the grouped products, their gradients and the
+    combines of their rows as Mosaic calls of seven distinct kernels, no
+    gather of a pick's row from a piece's buffer, no more HLO
+    instructions than the step had before the combine was a kernel and
+    2 %, and arguments + temporaries under the 15.75 GB the issue allows
+    (13.5 GB when the recipe was settled, PR 56)."""
     import json
 
     from ray_tpu.models import training
@@ -230,20 +232,47 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     # rewrite.  A layer's first piece of sorted picks: gate|up and down
     # forward, two in the rows and two in the matrices backward; the
     # pieces behind it, one loop of as many passes as there are such
-    # pieces with a held pick: two forward, five backward
-    assert "ragged-dot-none" not in compiled.as_text()
+    # pieces with a held pick: two forward, five backward.  And with
+    # each piece's products the combine of its rows into their tokens,
+    # one forward (the down projection's rows) and one backward (the
+    # gradients in the sorted rows)
+    hlo = compiled.as_text()
+    assert "ragged-dot-none" not in hlo
     products = [line for line in kernels if "moe/experts" in line]
-    assert len(products) == 4 * (6 + 7) == len(kernels) - len(flash)
-    assert sum("while/body" in line for line in products) == 4 * 7
+    assert len(products) == 4 * (8 + 9) == len(kernels) - len(flash)
+    assert sum("while/body" in line for line in products) == 4 * 9
+    combines = [line for line in products if "jit(_combine)/combine" in line]
+    assert len(combines) == 4 * 4
+    assert sum("while/body" in line for line in combines) == 4 * 2
+    # ... so no gather is left that fetches a row a pick (T x K =
+    # 131,072 rows of 2304, sixteen in the step before) from a piece's
+    # [49152, 2304] buffer; those that fetch a piece's rows of x and
+    # dout by token are: 49,152 rows of [16384, 2304]
+    piece = moe.piece_rows(2 * 8192, cfg.moe_top_k, len(cfg.held_experts),
+                           cfg.n_routed_experts)
+    assert piece == 49152
+    fetched = re.findall(r"= \w+\[([\d,]+)\]\S* gather\(", hlo)
+    assert f"{piece},{cfg.d_model}" in fetched
+    assert f"{2 * 8192 * cfg.moe_top_k},{cfg.d_model}" not in fetched, (
+        sorted(set(fetched)))
+    # a warm worker's load costs ~0.23 ms an instruction of the compiled
+    # module (PERF.md section 6, PR 58): the schedule of the combines'
+    # runs is inlined once a layer and direction
+    instructions = sum(1 for line in hlo.splitlines() if re.match(
+        r"\s+(ROOT )?%?[\w.\-]+ = ", line))
+    assert instructions <= 16_530 * 1.02, (
+        f"{instructions} HLO instructions; the parent's step (PR 58, "
+        "XLA's gather for the combine) held 16,530")
     # ... and the module every process traces and lowers before it can
-    # look the executable up holds six distinct kernels for them, however
-    # many layers and pieces call them: every piece has the first's rows,
-    # and every call goes through the two module-level jits (a call
-    # inside a loop's body and one outside it lower to two functions of
-    # one body: twelve functions, the same serialized kernel in each two)
+    # look the executable up holds seven distinct kernels for them,
+    # however many layers and pieces call them: every piece has the
+    # first's rows, and every call goes through the three module-level
+    # jits (a call inside a loop's body and one outside it lower to two
+    # functions of one body: fourteen functions, the same serialized
+    # kernel in each two)
     functions = [f for f in lowered.as_text().split("func.func ")
-                 if re.match(r"private @_t?gmm", f)]
-    assert len(functions) <= 12 and len(_kernel_bodies(functions)) == 6, (
+                 if re.match(r"private @(_t?gmm|_combine\b|_combine_\d)", f)]
+    assert len(functions) <= 14 and len(_kernel_bodies(functions)) == 7, (
         len(functions), len(_kernel_bodies(functions)))
     assert moe.product_path(2 * 8192, cfg.moe_top_k, len(cfg.held_experts),
                             cfg.n_routed_experts, cfg.d_model,
@@ -264,8 +293,9 @@ def test_expert_width_the_tiles_do_not_divide_keeps_ragged_dot(v5e, width,
     """``grouped_matmul.uses_kernel`` decides a differentiated layer's
     products from its shapes: experts 192 wide, which tiles of 128 do
     not divide, keep the compiler's grouped product (``ragged-dot-none``
-    in the executable) and hold no kernel of ours; 256 wide they are
-    ours and no ragged product is left."""
+    in the executable), its gather of a row a pick for the combine, and
+    hold no kernel of ours; 256 wide the products and the combine are
+    ours and neither is left: one decision a layer."""
     from ray_tpu.ops import grouped_matmul
     from ray_tpu.parallel import moe
     T, d, E, held, top_k = 1024, 256, 8, (0, 1, 2, 3), 2
@@ -290,11 +320,17 @@ def test_expert_width_the_tiles_do_not_divide_keeps_ragged_dot(v5e, width,
         hlo = lowered.compile().as_text()
     ours = [f for f in lowered.as_text().split("func.func ")
             if re.match(r"private @_t?gmm", f)]
+    combines = [f for f in lowered.as_text().split("func.func ")
+                if re.match(r"private @_combine(_\d+)?\(", f)]
+    a_row_a_pick = f"{T * top_k},{d}" in re.findall(
+        r"= \w+\[([\d,]+)\]\S* gather\(", hlo)
     if path == "pallas":
         assert "ragged-dot-none" not in hlo
         assert 0 < len(_kernel_bodies(ours)) <= 6
+        assert len(_kernel_bodies(combines)) == 1 and not a_row_a_pick
     else:
         assert "ragged-dot-none" in hlo and not ours
+        assert not combines and a_row_a_pick
         assert "tpu_custom_call" in hlo      # the compiler's own kernel
 
 
