@@ -71,6 +71,15 @@ REAL = {
     "routed": {"seq": 8192, "group": 8, "head_dim": 128, "window": 1024,
                "rows": 16384, "d_model": 2304, "expert_ff": 896,
                "held": 16, "experts": 64, "top_k": 8},
+    # the latent, routed serve cell's expert layer as its model calls it
+    # (serve-sarvam-105b-ep4-docs: sigmoid scores, a bias, renormalised,
+    # 32 of 128 experts held, beside a shared expert) at a prefill's
+    # rows, and the geometry its latent kernels' gates are asked at (the
+    # row, the page, a bucket over the gathered context)
+    "latent_routed": {"rows": 1152, "d_model": 4096, "expert_ff": 2048,
+                      "held": 32, "experts": 128, "top_k": 8,
+                      "scale": 2.5, "row": 576, "page": 128,
+                      "bucket": 2304, "context": 8192},
     # (prompt length, new tokens); page 128, buckets 32..1024
     "shared_prefix": 288,
     "requests": {"r0": (20, 48), "r1": (100, 64), "r2": (300, 32),
@@ -94,6 +103,10 @@ TOY = {
     "routed": {"seq": 256, "group": 2, "head_dim": 128, "window": 96,
                "rows": 128, "d_model": 128, "expert_ff": 128,
                "held": 2, "experts": 8, "top_k": 2},
+    "latent_routed": {"rows": 48, "d_model": 128, "expert_ff": 128,
+                      "held": 4, "experts": 16, "top_k": 4,
+                      "scale": 2.5, "row": 48, "page": 128,
+                      "bucket": 256, "context": 512},
     "shared_prefix": 288,
     "requests": {"r0": (20, 8), "r1": (100, 8), "r2": (300, 6),
                  "r3": (40, 8), "r4": (340, 6), "r5": (50, 8),
@@ -165,7 +178,9 @@ def train_loop(config: dict) -> None:
                                                 enable_compile_cache)
     from ray_tpu.models import gpt, training
     from ray_tpu.ops import flash_ce
-    from ray_tpu.ops.attention import train_causal_coverage, uses_pack2
+    from ray_tpu.ops.attention import (latent_decode_uses_pallas,
+                                       latent_prefill_uses_pallas,
+                                       train_causal_coverage, uses_pack2)
     from ray_tpu.parallel import moe
     from ray_tpu.parallel.mesh import make_mesh
 
@@ -196,6 +211,7 @@ def train_loop(config: dict) -> None:
     gate = dict(n_devices=n, norm=cfg.norm, has_bias=cfg.use_bias)
     routed_shapes = [config["routed"][k] for k in (
         "rows", "top_k", "held", "experts", "d_model", "expert_ff")]
+    latent = config["latent_routed"]
     ce = gpt.ce_path(N, d, V, ce_chunk=cfg.ce_chunk, n_devices=n)
     if flash_ce.uses_flash_ce_norm(N, d, V, ce_chunk=cfg.ce_chunk, **gate):
         ce = "flash_norm"
@@ -217,6 +233,13 @@ def train_loop(config: dict) -> None:
         "moe_product": moe.product_path(*routed_shapes),
         # ... and the combines of their rows with them
         "moe_combine": moe.combine_path(*routed_shapes),
+        # the latent models' serve kernels at the latent, routed serve
+        # cell's geometry: the decode's read and write over the pool's
+        # rows, and a prefill's attention over the gathered context
+        "latent_decode_pallas": latent_decode_uses_pallas(
+            latent["row"], latent["page"], cfg.dtype),
+        "latent_prefill_pallas": latent_prefill_uses_pallas(
+            latent["bucket"], latent["context"], cfg.dtype),
     }
     # ... and what the compiled step holds (the jitted call's own
     # executable comes back out of the cache)
@@ -403,6 +426,57 @@ def kernel_parity(config: dict) -> dict:
         counts=dict(zip(moe.MOE_COUNTS, (int(c) for c in counts))),
         moe_product=product)
     del x, ct, args, o_ref, g_ref
+
+    # -- the serve path's expert layer as the latent, routed model calls
+    # it (sigmoid scores, selection by a bias, the picks renormalised,
+    # a share of the experts held, beside a shared expert; nobody
+    # differentiates it: the loop over the tiles the picks fill) against
+    # the benchmark's plain reference of that layer at the cell's widths
+    from benchmark.reference import sarvam as plain
+    from ray_tpu.models import latent
+    lr = config["latent_routed"]
+    Tl, dl, fl, El = (lr[k] for k in ("rows", "d_model", "expert_ff",
+                                       "experts"))
+    mine = tuple(range(lr["held"]))
+    w = {"router": rand((1, dl, El), 3 * dl ** -0.5),
+         "router_bias": rand((1, El), 0.05, f32),
+         **{"e_" + n: rand((1, len(mine)) + shape, sc)
+            for n, shape, sc in (("gate", (dl, fl), dl ** -0.5),
+                                 ("up", (dl, fl), dl ** -0.5),
+                                 ("down", (fl, dl), fl ** -0.5))},
+         **{"s_" + n: rand((1,) + shape, sc)
+            for n, shape, sc in (("gate", (dl, fl), dl ** -0.5),
+                                 ("up", (dl, fl), dl ** -0.5),
+                                 ("down", (fl, dl), fl ** -0.5))}}
+    x = rand((Tl, dl))
+
+    def served(x, w):
+        out, counts = moe.dropless_moe(
+            x, w["router"][0], w["router_bias"][0], w["e_gate"],
+            w["e_up"], w["e_down"], held=mine, n_routed=El,
+            top_k=lr["top_k"], scale=lr["scale"], lead=(0,),
+            renormalise=True, scoring="sigmoid")
+        return out + latent.swiglu(x[None], w["s_gate"][0], w["s_up"][0],
+                                   w["s_down"][0])[0], counts
+
+    def reference(x, w):
+        with jax.default_matmul_precision("highest"):
+            return plain.moe(up(x), plain._reader(w, 0),
+                             (lr["top_k"], lr["scale"], mine),
+                             lambda a: a, jnp.asarray(plain.FAULTS[""], f32))
+
+    o, counts = jax.jit(served)(x, w)
+    o_ref, margin = jax.jit(reference)(x, w)
+    # a row a held expert stands at the top-k's edge of differs rightly
+    # (bfloat16 rows against the reference's float32 ones)
+    decided = np.asarray(margin) >= plain.CHOICE_MARGIN
+    row("moe/serve sigmoid+bias+renorm+shared",
+        [Tl, dl, fl, len(mine), El, lr["top_k"]],
+        {"o": _rel_err(np.asarray(up(o))[decided],
+                       np.asarray(o_ref)[decided])}, {"o": TOL_OUT},
+        counts=dict(zip(moe.MOE_COUNTS, (int(c) for c in counts))),
+        decided_rows=int(decided.sum()))
+    del x, w, o, o_ref
 
     # -- its grouped products, kernel by kernel, over one piece of sorted
     # rows with uneven groups and rows no group has: the forward's

@@ -1401,11 +1401,13 @@ class InferenceEngine:
     def _land_moe(self, rec: _Flight, ssp, moe) -> None:
         """A routed model's expert-layer counts of one step
         (``parallel/moe.py:MOE_COUNTS``, summed over its layers), on
-        the host: the fetch's span carries the picks this chip computed
-        and the experts they hit, and the telemetry adds them up."""
+        the host: the fetch's span carries the step's kind, the picks
+        this chip computed, the experts they hit and the trips of the
+        experts' loop, and the telemetry adds them up."""
         counts = dict(zip(self.cfg.step_counts, (int(c) for c in moe)))
-        ssp.set(moe_held=counts["held_picks"],
-                moe_hit=counts["experts_hit"])
+        ssp.set(kind=rec.kind, moe_held=counts["held_picks"],
+                moe_hit=counts["experts_hit"],
+                moe_trips=counts["loop_trips"])
         if self.telemetry.enabled:
             self.telemetry.record_moe(decode=rec.kind == "decode",
                                       **counts)
@@ -1783,7 +1785,7 @@ class InferenceEngine:
           (``ops/attention.py:latent_prefill_attention``): a cold
           prefill is the cached one at ``cached_len`` 0."""
         rank = self._latent[0]
-        scale = self.cfg.qk_head_dim ** -0.5
+        scale = self.cfg.softmax_scale
         last = None
         if kind == "decode":
             tokens, lengths, page_table = args

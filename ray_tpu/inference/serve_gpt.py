@@ -55,11 +55,6 @@ import ray_tpu.serve as serve
 from ray_tpu.inference.sampling import SamplingParams
 from ray_tpu.util import tracing
 
-# the last two are named so that they are refused with the reason
-# (inference/engine.py:refuse_unserved), not as unknown names
-_PRESETS = ("tiny", "gpt2", "gpt2_medium", "gpt2_large",
-            "mellum2_12b_a2_5b", "mellum_tiny")
-
 
 class ReplicaDrainingError(RuntimeError):
     """Typed admission rejection while the replica drains: new
@@ -100,25 +95,23 @@ def _build_engine(model: str, model_config: Optional[Dict[str, Any]],
                   engine_config: Optional[Dict[str, Any]], seed: int):
     import jax
 
-    from ray_tpu.inference.engine import InferenceEngine
-    from ray_tpu.models import gpt, longcat
+    from ray_tpu.inference.engine import InferenceEngine, refuse_unserved
+    from ray_tpu.models import gpt, longcat, sarvam
 
-    # a preset is a classmethod of its model's config, and the model's
-    # module draws the weights
-    if model in _PRESETS:
-        config_cls, init_params = gpt.GPTConfig, gpt.init_params
-    elif model in longcat.PRESETS:
-        config_cls, init_params = (longcat.LongcatConfig,
-                                   longcat.init_params)
-    else:
-        raise ValueError(f"unknown model preset {model!r}; "
-                         f"expected one of {_PRESETS + longcat.PRESETS}")
-    cfg = getattr(config_cls, model)(**(model_config or {}))
-    if config_cls is gpt.GPTConfig:
-        # before any weight is drawn
-        from ray_tpu.inference.engine import refuse_unserved
-        refuse_unserved(cfg)
-    params = init_params(cfg, jax.random.PRNGKey(seed))
+    # a preset is a classmethod of its model's config (``CONFIG``), and
+    # the model's module names its own (``PRESETS``) and draws the
+    # weights (``init_params``)
+    models = (gpt, longcat, sarvam)
+    module = next((m for m in models if model in m.PRESETS), None)
+    if module is None:
+        raise ValueError(
+            f"unknown model preset {model!r}; expected one of "
+            f"{sum((m.PRESETS for m in models), ())}")
+    cfg = getattr(module.CONFIG, model)(**(model_config or {}))
+    # before any weight is drawn (a config that runs its own stack
+    # passes: it says what it holds to that stack)
+    refuse_unserved(cfg)
+    params = module.init_params(cfg, jax.random.PRNGKey(seed))
     return cfg, InferenceEngine(cfg, params, **(engine_config or {}))
 
 

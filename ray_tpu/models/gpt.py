@@ -226,6 +226,13 @@ class GPTConfig:
                    moe_renormalise=True, tie_embeddings=False, **kw)
 
 
+# the presets a deployment may name (``inference/serve_gpt.py``); the
+# last two are named so that they are refused with the reason
+# (``inference/engine.py:refuse_unserved``), not as unknown names
+PRESETS = ("tiny", "gpt2", "gpt2_medium", "gpt2_large",
+           "mellum2_12b_a2_5b", "mellum_tiny")
+CONFIG = GPTConfig
+
 # a routed config's factor on its drawn queries, by layer kind (the
 # attention scores' standard deviation before a rope's own factor)
 _ROUTED_QUERY_SCALE = {"window": 4.0, "full": 3.0}

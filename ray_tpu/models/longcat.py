@@ -41,8 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import latent
 from ray_tpu.models.gpt import _norm
-from ray_tpu.ops.attention import rope_rotate
+from ray_tpu.ops.attention import Rope
 from ray_tpu.parallel.moe import MOE_COUNTS, dropless_moe
 
 
@@ -93,6 +94,11 @@ class LongcatConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    # what the attention hook multiplies the scores by
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5
+
     # what a serve step of this model returns between its logits and
     # the cache: one int32 vector, the expert layers' counts by name
     step_counts = MOE_COUNTS
@@ -126,6 +132,7 @@ class LongcatConfig:
 
 
 PRESETS = ("longcat_flash_omni", "longcat_tiny")
+CONFIG = LongcatConfig
 
 # the draw's scales (``init_params`` says why)
 _LOGIT_STD = 2.0        # of the softmax's logits
@@ -217,45 +224,25 @@ def init_params(cfg: LongcatConfig, key) -> Dict[str, Any]:
             "lm_head": flat((d, cfg.vocab_size), 0.02)}
 
 
-def _at(layers, name: str, *index):
-    """``layers[name][index]``, sliced where the stacked weight stands
-    (one slice a use, so that the compiler reads a matrix from the
-    stack and copies no layer out of it)."""
-    a = layers[name]
-    n = len(index)
-    return lax.dynamic_slice(a, index + (0,) * (a.ndim - n),
-                             (1,) * n + a.shape[n:]).reshape(a.shape[n:])
-
-
 def _mla(lp, i: int, h, cfg: LongcatConfig, positions, attn_fn, cache):
     """Sublayer ``i``'s attention on the normed h [B, S, d] -> (out
-    [B, S, d], the cache's updated arrays).  ``lp(name, *index)`` reads
-    the block's weights."""
-    eps, d = cfg.norm_eps, cfg.d_model
-    rope = cfg.qk_rope_head_dim
-    c_q = _norm(jnp.einsum("bsd,dr->bsr", h, lp("wq_a", i)),
-                lp("q_norm", i), "rmsnorm", eps=eps)
-    q = jnp.einsum("bsr,rhk->bshk", c_q, lp("wq_b", i))
-    if cfg.mla_scale_q_lora:
-        q = q * (d / cfg.q_lora_rank) ** 0.5
-    kv = jnp.einsum("bsd,dr->bsr", h, lp("wkv_a", i))
-    c = _norm(kv[..., :-rope], lp("kv_norm", i), "rmsnorm", eps=eps)
-    if cfg.mla_scale_kv_lora:
-        c = c * (d / cfg.kv_lora_rank) ** 0.5
-    q_nope = q[..., :-rope]
-    q_rot = rope_rotate(q[..., -rope:], positions, cfg.rope_theta)
-    k_rot = rope_rotate(kv[..., None, -rope:], positions,
-                        cfg.rope_theta)[:, :, 0]
-    o, arrays = attn_fn(q_nope, q_rot, c, k_rot,
-                        (lp("wk_b", i), lp("wv_b", i)), cache=cache)
-    return jnp.einsum("bsk,kd->bsd", o.reshape(o.shape[:2] + (-1,)),
-                      lp("wo", i)), arrays
+    [B, S, d], the cache's updated arrays): ``models/latent.py:mla``
+    with the query's low-rank path and the ``mla_scale_*`` multipliers.
+    ``lp(name, *index)`` reads the block's weights."""
+    d = cfg.d_model
+    return latent.mla(
+        lambda name: lp(name, i), h, positions=positions, attn_fn=attn_fn,
+        cache=cache, rope=Rope(theta=cfg.rope_theta),
+        rope_dim=cfg.qk_rope_head_dim, eps=cfg.norm_eps,
+        q_gain=(d / cfg.q_lora_rank) ** 0.5 if cfg.mla_scale_q_lora
+        else 1.0,
+        c_gain=(d / cfg.kv_lora_rank) ** 0.5 if cfg.mla_scale_kv_lora
+        else 1.0)
 
 
 def _ffn(lp, i: int, h):
-    g = jnp.einsum("bsd,df->bsf", h, lp("w_gate", i))
-    u = jnp.einsum("bsd,df->bsf", h, lp("w_up", i))
-    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, lp("w_down", i))
+    return latent.swiglu(h, lp("w_gate", i), lp("w_up", i),
+                         lp("w_down", i))
 
 
 def block_apply(layers, x, cfg: LongcatConfig, *, positions, attn_fn, cache,
@@ -269,7 +256,7 @@ def block_apply(layers, x, cfg: LongcatConfig, *, positions, attn_fn, cache,
     expert layer computes and counts those alone.  -> (x, arrays,
     the expert layer's counts)."""
     b, arrays = cache
-    lp = lambda name, *index: _at(layers, name, b, *index)  # noqa: E731
+    lp = lambda name, *index: latent.at(layers, name, b, *index)  # noqa: E731
     n = lambda a, name, i: _norm(a, lp(name, i), "rmsnorm",  # noqa: E731
                                  eps=cfg.norm_eps)
     B, S, d = x.shape

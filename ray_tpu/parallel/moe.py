@@ -184,8 +184,17 @@ def make_moe_fn(mesh, *, top_k: int = 2, capacity_factor: float = 1.5):
 
 # what :func:`dropless_moe` counts, in the order of its counts vector
 MOE_COUNTS = ("rows", "held_picks", "identity_picks", "picks",
-              "experts_hit", "calls")
+              "experts_hit", "calls", "loop_trips")
 _TILE = 128       # rows of one expert a step of the grouped product takes
+# how a router's logits become scores (:func:`dropless_moe`'s ``scoring``)
+SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+            "sigmoid": jax.nn.sigmoid}
+
+
+def _tile_rows(T: int) -> int:
+    """Rows of one step of :func:`_grouped_experts` over ``T`` tokens (an
+    expert has at most ``T`` rows)."""
+    return min(_TILE, -(-T // 16) * 16)
 
 
 def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead):
@@ -211,7 +220,7 @@ def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead):
             w, (*lead, e) + (0,) * (w.ndim - at),
             (1,) * at + w.shape[at:]).reshape(w.shape[at:])
 
-    tile = min(_TILE, -(-T // 16) * 16)    # an expert has at most T rows
+    tile = _tile_rows(T)
     flat = local.reshape(T * K)
     order = jnp.argsort(flat, stable=True)            # held picks first
     pad = jnp.zeros((tile,), jnp.int32)
@@ -611,12 +620,14 @@ _experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)
 def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
                  held: Sequence[int], n_routed: int, top_k: int,
                  scale: float, valid=None, lead=(),
-                 renormalise: bool = False, with_load: bool = False):
+                 renormalise: bool = False, scoring: str = "softmax",
+                 with_load: bool = False):
     """One chip's part of a dropless expert layer on x [T, d] -> (its
     output [T, d], counts [len(MOE_COUNTS)] int32).
 
     ``router`` [d, E] scores every expert of the deployment in float32
-    (softmax); the ``top_k`` of ``score + router_bias`` are a row's
+    (``scoring``: a ``softmax`` over the row's logits, or each logit's
+    ``sigmoid``); the ``top_k`` of ``score + router_bias`` are a row's
     picks, weighted by their scores as they are, or with
     ``renormalise`` by their scores over the sum of the row's ``top_k``
     picked scores wherever those experts live (so the shares of a
@@ -645,7 +656,7 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        score = jax.nn.softmax(logits, axis=-1)
+        score = SCORINGS[scoring](logits)
         _, pick = lax.top_k(score + router_bias.astype(jnp.float32), top_k)
         weight = jnp.take_along_axis(score, pick, axis=-1)       # [T, K]
         if renormalise:
@@ -664,12 +675,17 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
         out = out + x.astype(jnp.float32) * jnp.sum(
             jnp.where(identity, weight, 0.0), -1, keepdims=True)
     rows = jnp.sum(valid)
+    load = jnp.sum(local[:, :, None] == jnp.arange(len(held)),
+                   axis=(0, 1), dtype=jnp.int32)
+    # the tiles the picks fill: the trips of the loop a call nobody
+    # differentiates takes, which is what its time follows
+    tile = _tile_rows(T)
     counts = jnp.stack([rows, jnp.sum(ours), jnp.sum(identity),
-                        rows * top_k, hit, jnp.int32(1)]).astype(jnp.int32)
+                        rows * top_k, hit, jnp.int32(1),
+                        jnp.sum((load + tile - 1) // tile)]
+                       ).astype(jnp.int32)
     out = (scale * out).astype(x.dtype)
     if with_load:
-        load = jnp.sum(local[:, :, None] == jnp.arange(len(held)),
-                       axis=(0, 1), dtype=jnp.int32)
         return out, counts, load, _combine_windows(
             local, load, n_routed, d, e_gate.shape[-1])
     return out, counts

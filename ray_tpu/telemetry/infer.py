@@ -139,7 +139,8 @@ class InferTelemetry:
     def record_moe(self, *, decode: bool, **counts: int) -> None:
         """One step's expert-layer counts (rows through an expert
         layer, picks on held experts, picks on identity experts, all
-        picks, experts hit, expert-layer calls), fetched with the
+        picks, experts hit, expert-layer calls, the trips of the
+        experts' loop: the tiles the picks filled), fetched with the
         step's tokens; ``decode``: the step was a decode."""
         if not self.enabled:
             return

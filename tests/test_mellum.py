@@ -489,7 +489,8 @@ def test_telemetry_names_the_routed_cells_combine_and_reads_its_windows():
     tel = StepTelemetry(cfg)
     held = len(cfg.held_experts)
     vec = np.zeros((gpt.moe_counts_len(cfg),), np.int32)
-    vec[:len(moe.MOE_COUNTS)] = (4 * 16384, 130_000, 0, 4 * 131_072, 64, 4)
+    vec[:len(moe.MOE_COUNTS)] = (4 * 16384, 130_000, 0, 4 * 131_072, 64, 4,
+                                 1_050)
     vec[len(moe.MOE_COUNTS):-1] = 130_000 // held
     vec[-1] = 3_052
     tokens = jnp.zeros((2, 8192), jnp.int32)

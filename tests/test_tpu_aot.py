@@ -612,6 +612,68 @@ def test_latent_serve_step_keeps_the_pool_in_place_on_v5e(v5e, kind,
         assert "f32[64,128,12288]" not in hlo
 
 
+# the latent, routed cell's engine (benchmark/cells/serve-sarvam-105b-
+# ep4-docs.json): slots, pages, max_seq; the model as its configuration
+# file builds it
+_SARVAM_CELL = (16, 1025, 8192)
+
+
+@pytest.mark.parametrize("kind, bucket", [("decode", 0), ("prefill", 1152),
+                                          ("prefill", 6144)])
+def test_sarvam_serve_step_keeps_pool_and_weights_in_place_on_v5e(
+        v5e, kind, bucket):
+    """The engine's steps over sarvam-105b's share (published widths,
+    the dense layer and 5 routed layers, 32 held experts): the pool goes
+    in and out with no copy of it, no stacked weight or layer's slice of
+    one is copied, a decode holds the write and the attention of the
+    dense layer and of the scan's body (four kernels) in megabytes of
+    temporaries, and the widest bucket's arguments and temporaries stay
+    under what the chip gives a program (12.5 GB of 15.75: the cell
+    file's arithmetic)."""
+    from ray_tpu.inference.engine import InferenceEngine
+    from ray_tpu.models import sarvam
+
+    slots, pages, max_seq = _SARVAM_CELL
+    cfg = sarvam.SarvamConfig.sarvam_105b(
+        n_layers=6, held_experts=tuple(range(32)), vocab_size=65536,
+        max_seq=max_seq, dtype=BF16)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: sarvam.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    pool = (cfg.cache_layers, pages, sum(cfg.latent_row), PAGE)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.lora_cfg = cfg, None
+    eng.cache = types.SimpleNamespace(state=(spec(pool, BF16),))
+    i32, mp = jnp.int32, max_seq // PAGE
+    if kind == "decode":
+        tail = (spec((slots,), i32), spec((slots,), i32),
+                spec((slots, mp), i32))
+    else:
+        tail = (spec((1, bucket), i32), spec((), i32), spec((mp,), i32))
+    with substrate.compile_for_tpu():
+        compiled = eng._build_step(kind).lower(
+            params, spec(pool, BF16), *tail).compile()
+    hlo = compiled.as_text()
+    assert not _pool_copies(hlo, pool)
+    for stack in ("dense", "layers"):
+        for name, a in params[stack].items():
+            if a.ndim >= 3 and (kind == "decode"
+                                or name not in ("wk_b", "wv_b")):
+                assert not _pool_copies(hlo, a.shape), name
+                assert not _pool_copies(hlo, a.shape[1:]), name
+    mem = compiled.memory_analysis()
+    kernels = hlo.count("custom_call_target=\"tpu_custom_call\"")
+    if kind == "decode":
+        assert kernels == 4 and mem.temp_size_in_bytes < 64 << 20
+    else:
+        assert kernels == 2 and mem.temp_size_in_bytes < 1 << 30
+        assert f"f32[64,{bucket},{max_seq}]" not in hlo
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.0e9
+
+
 def _sampler_specs(rows, vocab, sharding=None):
     return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in (
         ((rows, vocab), jnp.float32), ((rows,), jnp.int32),
