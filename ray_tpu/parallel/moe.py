@@ -178,9 +178,7 @@ def make_moe_fn(mesh, *, top_k: int = 2, capacity_factor: float = 1.5):
     return fn
 
 
-# ---------------------------------------------------------------------------
-# the dropless expert layer of the serve path
-# ---------------------------------------------------------------------------
+# ---- the dropless expert layer of the serve path and the routed train step
 
 # what :func:`dropless_moe` counts, in the order of its counts vector
 MOE_COUNTS = ("rows", "held_picks", "identity_picks", "picks",
@@ -192,25 +190,54 @@ SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
 
 
 def _tile_rows(T: int) -> int:
-    """Rows of one step of :func:`_grouped_experts` over ``T`` tokens (an
-    expert has at most ``T`` rows)."""
+    """Rows of one step of :func:`_grouped_experts` over ``T`` tokens."""
     return min(_TILE, -(-T // 16) * 16)
 
 
-def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead):
+def _sort_picks(local, weight, held: int):
+    """The picks ``[T, K]`` sorted by expert, stably (the held first, an
+    expert's rows ascending by token): each sorted row's pick, an index
+    into the flat ``T * K``, its weight, and the rows each held expert
+    has.  The weight rides the sort: what belongs to a pick is never
+    fetched by index (0.94-1.34 ms for a sort's 0.05 in the routed 8k
+    cell, and a gather's gradient is a scatter: PR 62)."""
+    flat = local.reshape(local.size)
+    _, order, ws = lax.sort(
+        (flat, jnp.arange(flat.size, dtype=jnp.int32),
+         weight.reshape(flat.size)), num_keys=1, is_stable=True)
+    return order, ws, jnp.sum(flat[:, None] == jnp.arange(held)[None, :],
+                              axis=0, dtype=jnp.int32)
+
+
+def _to_picks(order, rows):
+    """``out[order[r]] = rows[r]``: the sorted rows' values back at their
+    picks, the permutation's inverse as a sort keyed on the picks."""
+    return lax.sort((order, rows), num_keys=1, is_stable=False)[1]
+
+
+def _at_picks(table, pick):
+    """``table[t, pick[t, k]]`` (``table [T, E]`` or ``[1, E]``, ``pick [T,
+    K]``): the columns' indices compared with the pick and the row summed,
+    exact (one term is not 0; a ``where``: nothing else in a row can leak)."""
+    hit = pick[:, :, None] == jnp.arange(table.shape[-1])
+    return jnp.sum(jnp.where(hit, table[:, None, :], 0), axis=-1)
+
+
+def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead,
+                     piece=None):
     """sum over a row's held picks of ``weight * expert(x)``, float32.
 
     x [T, d]; local [T, K] int32: the pick's index among the experts
     held, or ``held`` (one past the last) where the pick is not this
     chip's; weight [T, K] float32; e_gate, e_up [*lead, held, d, f];
-    e_down [*lead, held, f, d], read at the indices ``lead`` (a layer
-    of stacked weights: an expert's matrices are sliced where they
-    stand, one expert a step, and never a layer's).  The picks are sorted by expert, and a loop takes one
-    tile of one expert's rows a step: gather the rows, the expert's
-    swiglu, scatter-add the weighted outputs.  Its trip count is the
-    tiles the picks fill (``sum_e ceil(n_e / tile)``), so a step costs
-    what was picked: an expert nobody picked is never read, and nothing
-    is padded to a worst case."""
+    e_down [*lead, held, f, d], read at the indices ``lead`` (a layer of
+    stacked weights: an expert's matrices are sliced where they stand,
+    one expert a step, never a layer's).  The picks are sorted by expert
+    (:func:`_sort_picks`), and a loop takes one tile of one expert's
+    rows a step: gather the rows, the expert's swiglu, scatter-add the
+    weighted outputs.  Its trip count is the tiles the picks fill
+    (``sum_e ceil(n_e / tile)``): an expert nobody picked is never read,
+    and nothing is padded to a worst case (``piece`` is the rule's)."""
     T, K = local.shape
     held = e_gate.shape[len(lead)]
 
@@ -221,14 +248,10 @@ def _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead):
             (1,) * at + w.shape[at:]).reshape(w.shape[at:])
 
     tile = _tile_rows(T)
-    flat = local.reshape(T * K)
-    order = jnp.argsort(flat, stable=True)            # held picks first
+    order, wsort, n = _sort_picks(local, weight, held)  # held picks first
     pad = jnp.zeros((tile,), jnp.int32)
-    token = jnp.concatenate([(order // K).astype(jnp.int32), pad])
-    wsort = jnp.concatenate([weight.reshape(T * K)[order],
-                             pad.astype(jnp.float32)])
-    n = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], axis=0,
-                dtype=jnp.int32)                          # rows an expert
+    token = jnp.concatenate([order // K, pad])
+    wsort = jnp.concatenate([wsort, pad.astype(jnp.float32)])
     start = jnp.cumsum(n) - n
     tiles = (n + tile - 1) // tile
     tile_end = jnp.cumsum(tiles)
@@ -260,12 +283,11 @@ def _ragged(lhs, rhs, sizes, walk, transposed: bool = False):
     """``lhs [M, k]`` against each group's ``rhs [G, k, n]`` (or, with
     ``transposed``, ``rhs [G, n, k]``): ``grouped_matmul.gmm`` over the
     rows' schedule ``walk``, which reads a transposed matrix through its
-    index map; where the layer's shapes keep the compiler's product
-    (``walk`` is None), the canonical form of ``jax.lax.ragged_dot``,
-    the one the TPU compiler has a kernel for: a product that contracts
-    another dimension of ``rhs`` it expands into a dense one over every
-    group, sixteen times the work in the routed 8k cell (compiled for a
-    described v5e, PR 56), so the transpose is spelled out there."""
+    index map; where ``walk`` is None, the canonical form of
+    ``jax.lax.ragged_dot``, the one the TPU compiler has a kernel for (a
+    product that contracts another dimension of ``rhs`` it expands into
+    a dense one over every group, sixteen times the work in the routed
+    8k cell, AOT, PR 56: the transpose is spelled out there)."""
     if walk is not None:
         return grouped_matmul.gmm(lhs, rhs, transpose_rhs=transposed,
                                   walk=walk)
@@ -289,14 +311,12 @@ def _ragged_outer(a, b, sizes, walk):
 def _pick_sum(rows, pos, ours):
     """``out[t] = sum over t's picks k with ours[t, k] of rows[pos[t,
     k]]``, float32: a gather of ``T x K`` rows, so nothing is scattered.
-    It is the dearest thing around the products: rows read in no order
-    take 43 ns each on a v5e (5.6 ms for 16384 x 8 rows of 2304) where
-    rows in ascending runs, ``x[token]``, take 7.6; a scatter-add of the
-    held rows alone, sorted by token or not, read 5.1-6.5 ms (PR 56).
-    So a layer whose shapes the kernels take sums the held picks' rows
-    where the sort left them (:func:`_combine`: 1.5 ms for this form's
-    5.96 at those shapes, PR 59), and this is the form of the others
-    and the kernel's reference."""
+    Rows read in no order take 43 ns each on a v5e (5.6 ms for 16384 x 8
+    rows of 2304) where rows in ascending runs, ``x[token]``, take 7.6;
+    a scatter-add of the held rows alone read 5.1-6.5 ms (PR 56).  So a
+    layer whose shapes the kernels take sums the held picks' rows where
+    the sort left them (:func:`_combine`: 1.5 ms for this form's 5.96,
+    PR 59); this is the form of the others and the kernel's reference."""
     T, K = pos.shape
     picked = rows[pos.reshape(T * K)].reshape(T, K, -1)
     return jnp.sum(jnp.where(ours[:, :, None], picked.astype(jnp.float32),
@@ -306,10 +326,9 @@ def _pick_sum(rows, pos, ours):
 # A piece of the sorted picks, over what uniform routing fills, rounded
 # up to _PIECE_ROWS.  A piece that barely overflows pays a second
 # piece's fixed costs (~25 ms a layer in the routed 8k cell, PR 56):
-# half again is room for a draw's skew and for some of a router's drift.
+# half again is room for a draw's skew and some of a router's drift.
 # Against one buffer for every pick the pieces read 42.4 k tokens/s for
-# 37.8 k on the chip there (the elementwise work and gathers on rows no
-# pick fills) and 1.3 GB less of the step's temporaries (AOT)
+# 37.8 k there and 1.3 GB less of the step's temporaries (AOT)
 _PIECE_HEADROOM = 1.5
 _PIECE_ROWS = 512
 
@@ -317,8 +336,7 @@ _PIECE_ROWS = 512
 def piece_rows(T: int, top_k: int, held: int, experts: int) -> int:
     """Rows of one piece of the sorted picks (:func:`_sorted_experts_fwd`):
     what the held experts take under uniform routing, ``T * top_k * held /
-    experts``, and half again; every pick's row (``T *
-    top_k``) where this chip holds every expert."""
+    experts``, and half again; at most every pick's row (``T * top_k``)."""
     want = _PIECE_HEADROOM * T * top_k * held / experts
     return min(T * top_k, -(-int(want) // _PIECE_ROWS) * _PIECE_ROWS)
 
@@ -327,10 +345,9 @@ def _kernels_take(T: int, rows: int, d: int, f: int) -> bool:
     """Whether a layer's grouped products over ``rows`` sorted rows of
     ``T`` tokens, and the combines of their rows, are
     ``ops/grouped_matmul.py``'s: one decision for a layer, the gate's on
-    each contraction and width of its six kinds of product (gate|up and
-    down forward, and the two read transposed for the rows' gradients;
-    the matrices' gradients have the same widths) and the combine's on
-    the tokens, the rows and the width."""
+    each contraction and width of its products (gate|up and down, and
+    the two read transposed for the rows' gradients; the matrices'
+    gradients have the same widths) and the combine's on its shapes."""
     return bool(grouped_matmul.combine_uses_kernel(T, rows, d)) and all(
         grouped_matmul.uses_kernel(rows, k, n) for k, n in (
             (d, 2 * f), (f, d), (d, f), (2 * f, d)))
@@ -346,34 +363,28 @@ def product_path(T: int, top_k: int, held: int, experts: int, d: int,
 
 
 def combine_path(*shapes: int) -> str:
-    """The form the layer of :func:`product_path`'s shapes sums its rows
-    into tokens in, ``pallas`` or ``xla`` (a gather), with its products:
-    one decision a layer (the step telemetry's ``moe_combine``)."""
+    """The form that layer sums its rows into tokens in, ``pallas`` or
+    ``xla`` (a gather), with its products (telemetry's ``moe_combine``)."""
     return "pallas" if product_path(*shapes) == "pallas" else "xla"
 
 
 def _sorted_picks(local, weight, held: int, piece: int):
     """The picks sorted by expert, the held ones first: for each sorted
-    row its token and weight (0 behind the last held pick), for each
-    pick its sorted row (``pos [T, K]``), and the first sorted row of
-    each held expert and the one past its last.  The rows' vectors are
-    padded to whole pieces with rows no pick has, so that every piece
-    has ``piece`` rows and its products one shape."""
+    row its pick (:func:`_sort_picks`; its token is ``pick // K``) and
+    weight (0 behind the last held pick), for each pick its sorted row
+    (``pos [T, K]``), and the first sorted row of each held expert and
+    the one past its last.  The rows' vectors are padded to whole pieces
+    with rows no pick has: every piece's products have one shape."""
     T, K = local.shape
-    flat = local.reshape(T * K)
-    order = jnp.argsort(flat, stable=True)
-    token = (order // K).astype(jnp.int32)
-    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, K)
-    n = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], axis=0,
-                dtype=jnp.int32)                      # rows an expert
+    order, ws, n = _sort_picks(local, weight, held)
+    pos = _to_picks(order, jnp.arange(T * K, dtype=jnp.int32)).reshape(T, K)
     ends = jnp.cumsum(n)
-    live = jnp.arange(T * K) < ends[-1]
-    ws = jnp.where(live, weight.reshape(T * K)[order], 0.0)
+    ws = jnp.where(jnp.arange(T * K) < ends[-1], ws, 0.0)
     pad = (0, -(T * K) % piece)
-    return jnp.pad(token, pad), pos, jnp.pad(ws, pad), ends - n, ends
+    return jnp.pad(order, pad), pos, jnp.pad(ws, pad), ends - n, ends
 
 
-def _piece(a, rows: int, token, pos, ws, starts, ends, ours):
+def _piece(a, rows: int, order, pos, ws, starts, ends, ours):
     """Sorted rows ``a .. a + rows - 1`` (``a`` an int32 operand): their
     tokens and weights, which of them are held picks, each expert's rows
     among them, and the picks ``[T, K]`` that lie in the piece with their
@@ -385,26 +396,23 @@ def _piece(a, rows: int, token, pos, ws, starts, ends, ours):
     # a pick outside the piece reads some row of it (masked where it is
     # summed): rows spread over the piece, not one row for all of them
     spread = jnp.arange(pos.size, dtype=jnp.int32).reshape(pos.shape) % rows
-    return (lax.dynamic_slice_in_dim(token, a, rows),
+    return (lax.dynamic_slice_in_dim(order, a, rows) // pos.shape[1],
             lax.dynamic_slice_in_dim(ws, a, rows), live, n, mine,
             jnp.where(mine, pos - a, spread))
 
 
 def _pieces(M: int, piece: int):
-    """The sorted rows in pieces of ``piece``; the last may end behind
-    the last row (:func:`_sorted_picks` pads the rows' vectors)."""
+    """The sorted rows in pieces of ``piece``; the last may end in the pad."""
     return [(a, a + piece) for a in range(0, M, piece)]
 
 
 def _later_pieces(M: int, piece: int, ends, run, first):
     """``first`` and, added to it leaf by leaf, ``run(a)`` of each piece
     behind the first that a held pick lies in (``a`` its first row, an
-    int32), one after another: a loop of as many passes as there are
-    such pieces, so the step holds their code once however many there
-    may be, under a conditional, so that a step whose held picks fit the
-    first piece carries nothing through a loop (with the loop alone the
-    routed 8k cell's step read 9 ms longer, ``PERF.md`` section 6,
-    PR 58)."""
+    int32): a loop of as many passes as there are such pieces, so the
+    step holds their code once, under a conditional, so that a step
+    whose held picks fit the first piece carries nothing through a loop
+    (9 ms a step in the routed 8k cell, ``PERF.md`` section 6, PR 58)."""
     if len(_pieces(M, piece)) == 1:
         return first
 
@@ -420,9 +428,8 @@ def _later_pieces(M: int, piece: int, ends, run, first):
 
 
 def _walk(n, T: int, rows: int, d: int, f: int):
-    """The schedule of a piece's products over its ``rows`` sorted rows
-    (``grouped_matmul.group_tiles``), made once a piece for all of them,
-    or None where the products are ``jax.lax.ragged_dot``'s."""
+    """The schedule of a piece's products (``grouped_matmul.group_tiles``),
+    made once a piece, or None where they are ``jax.lax.ragged_dot``'s."""
     if not _kernels_take(T, rows, d, f):
         return None
     return grouped_matmul.group_tiles(n, rows,
@@ -431,9 +438,8 @@ def _walk(n, T: int, rows: int, d: int, f: int):
 
 def _runs(local, starts, piece: int, d: int, f: int):
     """Where the sort left the rows of each (token tile, held expert)
-    (``grouped_matmul.combine_runs`` at the combine's tile), made once a
-    layer and direction for the combines of every piece, or None where
-    the layer sums its rows by a gather (:func:`_pick_sum`)."""
+    (``grouped_matmul.combine_runs``), made once a layer and direction
+    for every piece's combine, or None where :func:`_pick_sum` sums."""
     T = local.shape[0]
     if not _kernels_take(T, piece, d, f):
         return None
@@ -444,8 +450,7 @@ def _runs(local, starts, piece: int, d: int, f: int):
 def _combine(rows, a, token, runs, at, mine):
     """``out[t] = sum of the piece's rows of t's held picks``, float32:
     ``grouped_matmul.combine`` over the runs' parts in the piece from
-    ``a`` where the layer's shapes take the kernels, else
-    :func:`_pick_sum`."""
+    ``a`` where the layer takes the kernels, else :func:`_pick_sum`."""
     if runs is None:
         return _pick_sum(rows, at, mine)
     return grouped_matmul.combine(rows, token, runs, a, T=at.shape[0])
@@ -455,8 +460,7 @@ def _combine_windows(local, load, experts: int, d: int, f: int):
     """The windows of sorted rows that the combines of one direction of
     a layer bring for the picks ``local [T, K]`` (``load [held]``: the
     rows each held expert took), over every piece, an int32; 0 where the
-    layer keeps the gather.  (The step telemetry's
-    ``moe.combine_windows``; the backward brings as many again.)"""
+    layer keeps the gather (telemetry's ``moe.combine_windows``)."""
     T, K = local.shape
     piece = piece_rows(T, K, load.shape[0], experts)
     runs = _runs(local, jnp.cumsum(load) - load, piece, d, f)
@@ -471,11 +475,10 @@ def _combine_windows(local, load, experts: int, d: int, f: int):
 def _piece_fwd(a, x, sort, ours, runs, w_gu, w_down, *, piece: int):
     """The forward of the ``piece`` sorted rows from ``a`` (int32): its
     part of the layer's sum, and its gate|up product.  One jitted
-    function for the first piece and the later ones of every layer: a
-    step traces it once where it traced a closure once a piece of every
-    layer (0.8 s of a warm worker's set-up in the routed 8k cell,
-    ``PERF.md`` section 6, PR 58), and the compiler, which inlines it,
-    sees ``a == 0`` where that is what the caller passed."""
+    function for every piece of every layer: a step traces it once, not
+    a closure a piece and layer (0.8 s of a warm worker's set-up in the
+    routed 8k cell, PR 58), and the compiler, which inlines it, sees
+    ``a == 0`` where that is what the caller passed."""
     token, ws, live, n, mine, at = _piece(a, piece, *sort, ours)
     walk = _walk(n, x.shape[0], piece, x.shape[1], w_down.shape[1])
     gu = _ragged(x[token], w_gu, n, walk)                     # [piece, 2f]
@@ -489,18 +492,17 @@ def _sorted_experts_fwd(x, local, weight, e_gate, e_up, e_down, lead,
     same sum, as grouped products over the picks sorted by expert (gate
     and up as one product against the two matrices side by side).
 
-    Every pick has a row in the sorted order (``T * K``: no pick can be
-    dropped), the held picks first.  The rows are taken in equal
-    pieces of ``piece`` rows: the first always, a later one only if a
-    held pick lies in it (:func:`_later_pieces`), so under any routing
-    the work, the gathers and the buffers that are touched follow the picks to
-    within a piece, and nothing is sized by the worst case but the
-    index vectors.  Inside a piece the products run over the groups'
-    own sizes; the rows behind the last held pick are never computed
-    and are masked wherever they are read.  The weight meets the hidden
-    product before the down projection, so the combine is a sum of
-    gathered rows.  Kept for the backward: the first piece's gate|up
-    product; a later piece computes its own again."""
+    Every pick has a row in the sorted order (``T * K``: none can be
+    dropped), the held picks first.  The rows are taken in equal pieces
+    of ``piece`` rows: the first always, a later one only if a held pick
+    lies in it (:func:`_later_pieces`), so under any routing the work
+    and the buffers touched follow the picks to within a piece, and
+    nothing is sized by the worst case but the index vectors.  Inside a
+    piece the products run over the groups' own sizes; rows behind the
+    last held pick are never computed and masked wherever read.  The
+    weight meets the hidden product before the down projection, so the
+    combine is a sum of rows.  Kept for the backward: the first piece's
+    gate|up product; a later piece computes its own again."""
     T, K = local.shape
     held = e_gate.shape[len(lead)]
     ours = local < held
@@ -534,10 +536,9 @@ def _hidden(gu, ws, live):
 @functools.partial(jax.jit, static_argnames=("piece",))
 def _piece_bwd(a, gu, x, dout, sort, ours, runs, w_gu, w_down, *,
                piece: int):
-    """The backward of the ``piece`` sorted rows from ``a``
-    (:func:`_piece_fwd`), given the piece's gate|up product or None, in
-    which case it is computed again: its part of the gradients in ``x``
-    and the weights ``[T, K]``, and the two matrices' gradients."""
+    """The backward of the ``piece`` sorted rows from ``a``, given the
+    piece's gate|up product or None (it is computed again): its part of
+    the gradients in ``x``, the weights ``[T, K]`` and the two matrices."""
     token, ws, live, n, mine, at = _piece(a, piece, *sort, ours)
     dt = x.dtype
     walk = _walk(n, x.shape[0], piece, x.shape[1], w_down.shape[1])
@@ -560,8 +561,11 @@ def _piece_bwd(a, gu, x, dout, sort, ours, runs, w_gu, w_down, *,
         # (the kernel's combine reads no row behind the live ones, and
         # takes the product's rows as the forward's does: one trace)
         dxs = jnp.where(rows, dxs, 0)
+    # (back to their picks through a sort: zeros where the piece has no row)
+    dws = _to_picks(sort[0][:mine.size], lax.dynamic_update_slice_in_dim(
+        jnp.zeros(sort[0].shape, dws.dtype), dws, a, 0)[:mine.size])
     return (_combine(dxs, a, token, runs, at, mine),
-            jnp.where(mine, dws[at], 0.0),
+            jnp.where(mine, dws.reshape(mine.shape), 0.0),
             _ragged_outer(xs, dgu, n, walk),                   # [G, d, 2f]
             _ragged_outer(_hidden(gu, ws, live), dy, n, walk))
 
@@ -569,10 +573,9 @@ def _piece_bwd(a, gu, x, dout, sort, ours, runs, w_gu, w_down, *,
 def _sorted_experts_bwd(piece, res, cts):
     x, local, weight, gu_first, e_gate, e_up, e_down, lead = res
     # what the backward computes again from the residuals (the sort, the
-    # gathered rows, the float32 view of gate|up, the hidden product)
-    # the compiler would otherwise share with the forward's and keep
-    # alive in between, a sorted buffer a layer: the barrier keeps them
-    # apart
+    # fetched rows, the float32 view of gate|up, the hidden product) the
+    # compiler would otherwise share with the forward's and keep alive
+    # in between, a sorted buffer a layer: the barrier keeps them apart
     x, local, weight, gu_first = lax.optimization_barrier(
         (x, local, weight, gu_first))
     T, K = local.shape
@@ -593,9 +596,8 @@ def _sorted_experts_bwd(piece, res, cts):
     grads = _later_pieces(T * K, piece, sort[-1], run,
                           run(jnp.int32(0), gu_first))
     dx, dweight, dw_gu, dw_down = grads
-    # nothing downstream waits for the matrices' gradients, and a
-    # scheduler free to put them off keeps every layer's sorted operands
-    # alive until it does: they leave with dx
+    # (a scheduler free to put the matrices' gradients off keeps the sorted
+    # operands alive: they leave with dx)
     dx, dw_gu, dw_down = lax.optimization_barrier((dx, dw_gu, dw_down))
 
     def stacked(dw, w):
@@ -606,14 +608,9 @@ def _sorted_experts_bwd(piece, res, cts):
             stacked(dw_gu[..., f:], e_up), stacked(dw_down, e_down), None)
 
 
-def _loop_experts(x, local, weight, e_gate, e_up, e_down, lead, piece):
-    return _grouped_experts(x, local, weight, e_gate, e_up, e_down, lead)
-
-
-# one expert layer, two lowerings: JAX runs the primal (the loop over
-# the tiles the picks fill) where no gradient is taken, and the rule
-# (grouped products over the sorted picks) wherever one is
-_experts = jax.custom_vjp(_loop_experts, nondiff_argnums=(7,))
+# one expert layer, two lowerings: JAX runs the primal (the loop over the
+# tiles the picks fill) where no gradient is taken, the rule elsewhere
+_experts = jax.custom_vjp(_grouped_experts, nondiff_argnums=(7,))
 _experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)
 
 
@@ -628,26 +625,26 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
     ``router`` [d, E] scores every expert of the deployment in float32
     (``scoring``: a ``softmax`` over the row's logits, or each logit's
     ``sigmoid``); the ``top_k`` of ``score + router_bias`` are a row's
-    picks, weighted by their scores as they are, or with
-    ``renormalise`` by their scores over the sum of the row's ``top_k``
-    picked scores wherever those experts live (so the shares of a
-    deployment add up to the uncut layer), and by ``scale``.  Experts ``0 .. n_routed - 1`` are swiglu experts,
-    of which this chip holds ``held`` (their ids, in the order of
-    ``e_gate, e_up`` [*lead, held, d, f] and ``e_down`` [*lead, held,
-    f, d], read at the indices ``lead``: stacked layers); experts
-    from ``n_routed`` up are identity experts (``E_e(x) = x``), computed
-    where the row lives.  A pick on a routed expert held elsewhere adds
-    nothing.  ``valid`` [T] bool marks the rows that are tokens of a
-    sequence (absent: all): the others pick nothing and count nothing.
-    ``with_load`` appends to what is returned the rows each held expert
-    took ([held] int32) and the windows a differentiated call's combines
-    bring in one direction (:func:`_combine_windows`).
-    Device scopes: ``route``, ``experts``, ``identity`` (the caller
-    names the layer).  A differentiated call computes the held experts'
-    part by grouped products over the sorted picks and has gradients in
-    ``x``, ``router`` (through the weights) and the three expert
-    matrices; a call nobody differentiates lowers as it always has
-    (:func:`_grouped_experts`)."""
+    picks, weighted by their scores as they are, or with ``renormalise``
+    by their scores over the sum of the row's ``top_k`` picked scores
+    wherever those experts live (so the shares of a deployment add up to
+    the uncut layer), and by ``scale``.  Experts ``0 .. n_routed - 1``
+    are swiglu experts, of which this chip holds ``held`` (their ids, in
+    the order of ``e_gate, e_up`` [*lead, held, d, f] and ``e_down``
+    [*lead, held, f, d], read at the indices ``lead``: stacked layers);
+    experts from ``n_routed`` up are identity experts (``E_e(x) = x``),
+    computed where the row lives.  A pick on a routed expert held
+    elsewhere adds nothing.  ``valid`` [T] bool marks the rows that are
+    tokens of a sequence (absent: all): the others pick nothing and
+    count nothing.  ``with_load`` appends to what is returned the rows
+    each held expert took ([held] int32) and the windows a
+    differentiated call's combines bring in one direction
+    (:func:`_combine_windows`).  Device scopes: ``route``, ``experts``,
+    ``identity`` (the caller names the layer).  A differentiated call
+    computes the held experts' part by grouped products over the sorted
+    picks and has gradients in ``x``, ``router`` (through the weights)
+    and the three expert matrices; a call nobody differentiates takes
+    the loop (:func:`_grouped_experts`)."""
     T, d = x.shape
     E = router.shape[1]
     if valid is None:
@@ -658,12 +655,16 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
                             precision=lax.Precision.HIGHEST)
         score = SCORINGS[scoring](logits)
         _, pick = lax.top_k(score + router_bias.astype(jnp.float32), top_k)
-        weight = jnp.take_along_axis(score, pick, axis=-1)       # [T, K]
+        weight = _at_picks(score, pick)                          # [T, K]
         if renormalise:
+            # (apart from the selection's sum, or the compiler merges the
+            # two and adds a row's K scores in another order: an ulp)
+            weight = lax.optimization_barrier(weight)
             weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
         local_of = np.full((E,), len(held), np.int32)
         local_of[list(held)] = np.arange(len(held))
-        local = jnp.where(valid[:, None], jnp.asarray(local_of)[pick],
+        local = jnp.where(valid[:, None],
+                          _at_picks(jnp.asarray(local_of)[None], pick),
                           len(held))
         identity = valid[:, None] & (pick >= n_routed)
         ours = local < len(held)
@@ -677,8 +678,7 @@ def dropless_moe(x, router, router_bias, e_gate, e_up, e_down, *,
     rows = jnp.sum(valid)
     load = jnp.sum(local[:, :, None] == jnp.arange(len(held)),
                    axis=(0, 1), dtype=jnp.int32)
-    # the tiles the picks fill: the trips of the loop a call nobody
-    # differentiates takes, which is what its time follows
+    # the tiles the picks fill: the loop's trips, which its time follows
     tile = _tile_rows(T)
     counts = jnp.stack([rows, jnp.sum(ours), jnp.sum(identity),
                         rows * top_k, hit, jnp.int32(1),

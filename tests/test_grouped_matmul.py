@@ -328,7 +328,8 @@ def test_combines_of_one_shape_share_one_kernel_body():
         out = 0.0
         for a in (0, 0, 0):
             runs = gm.combine_runs(local, sort[3], tile_t=512)
-            y = gm.combine(rows, sort[0], runs, jnp.int32(a), T=T_C)
+            y = gm.combine(rows, sort[0] // local.shape[1], runs,
+                           jnp.int32(a), T=T_C)
             out = out + jnp.sum(lax.cond(
                 runs.first[0, 0] == 0, lambda: y + gm.combine(
                     rows, sort[0], runs, jnp.int32(a), T=T_C), lambda: y))

@@ -261,6 +261,107 @@ def test_differentiated_grouped_products_match_loop_and_dense(
         assert not np.any(np.asarray(got[2][1]))   # expert 2: no gradient
 
 
+# the three forms PR 62 took out of ``parallel/moe.py``, kept here alone:
+# the weights through the sort's order, values back through its inverse,
+# and a table's entries at the picks, each by a gather of T x K scalars
+def _gathered_sort_picks(local, weight, held):
+    flat = local.reshape(local.size)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    return order, weight.reshape(flat.size)[order], jnp.sum(
+        flat[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+
+
+def _gathered_to_picks(order, rows):
+    return rows[jnp.argsort(order)]
+
+
+def _gathered_at_picks(table, pick):
+    return jnp.take_along_axis(jnp.broadcast_to(
+        table, (pick.shape[0], table.shape[-1])), pick, axis=-1)
+
+
+# how each routed family calls the layer: mellum (train), sarvam and
+# longcat (serve; longcat's identity experts are columns behind the
+# routed ones, and some of its rows are no tokens)
+_ROUTINGS = {
+    "softmax_renormalised": dict(scoring="softmax", renormalise=True,
+                                 bias=0.0, identity=0, scale=1.0),
+    "sigmoid_bias_renormalised": dict(scoring="sigmoid", renormalise=True,
+                                      bias=0.1, identity=0, scale=2.5),
+    "softmax_identity_experts": dict(scoring="softmax", renormalise=False,
+                                     bias=0.02, identity=4, scale=6.0)}
+
+
+@pytest.mark.parametrize("routing", list(_ROUTINGS))
+def test_values_that_ride_the_sort_or_a_compare_equal_the_gathers_bit_for_bit(
+        monkeypatch, routing):
+    R = _ROUTINGS[routing]
+    # four pieces of 48 sorted rows, and a draw skewed to expert 5, whose
+    # rows alone overflow the first: the later pieces' loop runs
+    monkeypatch.setattr(moe, "_PIECE_HEADROOM", 0.5)
+    monkeypatch.setattr(moe, "_PIECE_ROWS", 8)
+    L = _layer()
+    held, n_routed, K = L["held"], L["E"], 2
+    assert moe.piece_rows(96, K, len(held), n_routed) == 48
+    ks = jax.random.split(jax.random.PRNGKey(62), 3)
+    router = jnp.concatenate([L["router"], jax.random.normal(
+        ks[0], (32, R["identity"])) * 0.5], axis=1)
+    # (the skewed dimension feeds expert 5 alone: a row's second pick is
+    # its own, on a held, a foreign or an identity expert)
+    router = router.at[0].set(0.0).at[:, 5].set(0.0).at[0, 5].set(1.0)
+    x = L["x"].at[:, 0].set(6.0)
+    bias = R["bias"] * jax.random.normal(ks[1], (router.shape[1],))
+    valid = jnp.arange(96) % 7 != 3 if R["identity"] else None
+    args = (x, router, L["gate"], L["up"], L["down"])
+
+    local = jax.random.randint(ks[2], (96, K), 0, len(held) + 1)
+    weight = jax.random.uniform(ks[2], (96, K))
+
+    def everything():
+        # (functions of this call's own: a trace is cached by function)
+        def layer(*a):
+            return moe.dropless_moe(
+                a[0], a[1], bias, *a[2:], held=held, n_routed=n_routed,
+                top_k=K, scale=R["scale"], valid=valid,
+                renormalise=R["renormalise"], scoring=R["scoring"])
+
+        def grads(*a):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(layer(*a)[0] * L["target"]),
+                (0, 1, 2, 3, 4))(*a)
+
+        out, counts = layer(*args)                  # the loop
+        value, g = grads(*args)                     # the rule
+        # (and compiled: the compiler merges what it may, as in a step)
+        value_c, g_c = jax.jit(grads)(*args)
+        return (out, counts, value, *g, value_c, *g_c,
+                *moe._sorted_picks(local, weight, len(held), 48),
+                str(jax.make_jaxpr(grads)(*args)))
+
+    *got, text = everything()
+    named = dict(zip(moe.MOE_COUNTS, np.asarray(got[1])))
+    assert 48 < named["held_picks"] < named["picks"] and (
+        named["identity_picks"] > 0) == bool(R["identity"])
+    monkeypatch.setattr(moe, "_sort_picks", _gathered_sort_picks)
+    monkeypatch.setattr(moe, "_to_picks", _gathered_to_picks)
+    monkeypatch.setattr(moe, "_at_picks", _gathered_at_picks)
+    # (a jit of its own, or the piece's backward would not be traced again)
+    inner = moe._piece_bwd.__wrapped__
+    monkeypatch.setattr(moe, "_piece_bwd", jax.jit(
+        lambda *a, piece: inner(*a, piece=piece), static_argnames=("piece",)))
+    *want, gathered = everything()
+    leaves = ("loss", "dx", "drouter", "dgate", "dup", "ddown")
+    names = ("out", "counts", *leaves, *(n + " (jit)" for n in leaves),
+             "order", "pos", "ws", "starts", "ends")
+    for g, w, name in zip(got, want, names, strict=True):
+        assert g.dtype == w.dtype and np.array_equal(g, w), name
+    assert np.any(np.asarray(got[4])) and np.any(np.asarray(got[5]))
+    # the forms are the ones named: the gathers and the scatter of the
+    # router's gradient are in the old text alone
+    assert gathered.count("gather[") >= text.count("gather[") + 5
+    assert "scatter-add" in gathered and "scatter" not in text
+
+
 def test_forward_only_call_keeps_the_loop_and_renormalise_defaults_off():
     L = _layer()
     args = (L["x"], L["router"], jnp.zeros((L["E"],)), L["gate"], L["up"],

@@ -198,9 +198,9 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     the cell file's recipe, compiled for one v5e: window and full
     attention kernels, the grouped products, their gradients and the
     combines of their rows as Mosaic calls of seven distinct kernels, no
-    gather of a pick's row from a piece's buffer, no more HLO
-    instructions than the step had before the combine was a kernel and
-    2 %, and arguments + temporaries under the 15.75 GB the issue allows
+    gather of a pick's row from a piece's buffer, no gather or scatter
+    of a scalar a pick, no more HLO instructions than the step had
+    before the combine was a kernel and 2 %, and arguments + temporaries under the 15.75 GB the issue allows
     (13.5 GB when the recipe was settled, PR 56)."""
     import json
 
@@ -255,6 +255,17 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     assert f"{piece},{cfg.d_model}" in fetched
     assert f"{2 * 8192 * cfg.moe_top_k},{cfg.d_model}" not in fetched, (
         sorted(set(fetched)))
+    # ... and none that fetches a scalar a pick (T x K = 131,072: the
+    # weights through the sort's order, the router's scores at its
+    # picks, the weights' gradients back: twenty in the step before
+    # PR 62), nor a scatter that puts one back (the scores' gradient,
+    # 131,072 updates of a [16384 x 64] table, four): those ride the
+    # sorts or a compare, and the only scatter left is the embedding's
+    picks = 2 * 8192 * cfg.moe_top_k
+    assert not {str(picks), f"{2 * 8192},{cfg.moe_top_k}"} & set(fetched), (
+        sorted(set(fetched)))
+    scattered = re.findall(r"= \w+\[([\d,]+)\]\S* scatter\(", hlo)
+    assert scattered == [f"{cfg.vocab_size},{cfg.d_model}"], scattered
     # a warm worker's load costs ~0.23 ms an instruction of the compiled
     # module (PERF.md section 6, PR 58): the schedule of the combines'
     # runs is inlined once a layer and direction
