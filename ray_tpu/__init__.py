@@ -60,16 +60,22 @@ def init(address: Optional[str] = None,
                     "num_cpus/num_tpus/resources/_system_config are "
                     "ignored (reference parity: ray.init warns too)",
                     stacklevel=2)
-            from ray_tpu._private.node import AttachedNode
-            node = AttachedNode(address, namespace=namespace)
-        else:
-            from ray_tpu._private.node import HeadNode
-            node = HeadNode(num_cpus=num_cpus, num_tpus=num_tpus,
-                            resources=resources, namespace=namespace,
-                            system_config=_system_config,
-                            session_name=kwargs.pop("session_name",
-                                                    None))
-        _worker_mod.set_global_worker(node.worker, node)
+        from ray_tpu.util import tracing
+        # the start-up record's first span (``util/tracing.py``): the
+        # node's own parts are its children, ``setup/init/<part>``
+        with tracing.span("setup/init") as sp:
+            if address is not None:
+                from ray_tpu._private.node import AttachedNode
+                node = AttachedNode(address, namespace=namespace)
+            else:
+                from ray_tpu._private.node import HeadNode
+                node = HeadNode(num_cpus=num_cpus, num_tpus=num_tpus,
+                                resources=resources, namespace=namespace,
+                                system_config=_system_config,
+                                session_name=kwargs.pop("session_name",
+                                                        None))
+                sp.set(chips=int(node.resources.get("TPU", 0)))
+            _worker_mod.set_global_worker(node.worker, node)
         return get_runtime_context()
 
 

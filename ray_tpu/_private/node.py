@@ -28,6 +28,7 @@ from ray_tpu._private.ids import JobID, NodeID, WorkerID
 from ray_tpu._private.node_manager import NodeManager
 from ray_tpu._private.object_store import ShmStore
 from ray_tpu._private.worker import CoreWorker
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -230,6 +231,8 @@ class AttachedNode:
             raise ConnectionError("no ALIVE head node in session")
         self.session_dir = session_dir or head["session_dir"]
         self.session_name = os.path.basename(self.session_dir)
+        if os.path.isdir(self.session_dir):
+            tracing.use_session_dir(self.session_dir)
         self.node_id = head["node_id"]
         nm = protocol.RpcClient(head["sock_path"])
         remote_host = (os.environ.get("RAY_TPU_REMOTE_ATTACH") == "1"
@@ -314,17 +317,66 @@ class HeadNode:
                  system_config: Optional[Dict[str, Any]] = None,
                  session_name: Optional[str] = None):
         GLOBAL_CONFIG.apply_system_config(system_config or {})
-        _gc_stale_sessions(keep=session_name)
-        self.session_name = session_name or (
-            f"session_{time.strftime('%Y%m%d_%H%M%S')}_{os.getpid()}")
-        self.session_dir = os.path.join(_default_tmp_root(),
-                                        self.session_name)
-        os.makedirs(os.path.join(self.session_dir, "sockets"), exist_ok=True)
-        os.makedirs(os.path.join(self.session_dir, "logs"), exist_ok=True)
+        with tracing.span("setup/init/session"):
+            _gc_stale_sessions(keep=session_name)
+            self.session_name = session_name or (
+                f"session_{time.strftime('%Y%m%d_%H%M%S')}_{os.getpid()}")
+            self.session_dir = os.path.join(_default_tmp_root(),
+                                            self.session_name)
+            os.makedirs(os.path.join(self.session_dir, "sockets"),
+                        exist_ok=True)
+            os.makedirs(os.path.join(self.session_dir, "logs"),
+                        exist_ok=True)
+            tracing.use_session_dir(self.session_dir)
         self.shm_root = _shm_root(self.session_name)
         self.spill_dir = (GLOBAL_CONFIG.object_spill_dir
                           or os.path.join(self.session_dir, "spill"))
+        with tracing.span("setup/init/control_plane"):
+            self._start_control_plane()
+        with tracing.span("setup/init/object_store"):
+            self.store = ShmStore(self.shm_root, spill_dir=self.spill_dir)
+        if self.store.native_error:
+            # said once, by the head: every worker falls back the same way
+            logger.warning("object store: using the Python file store, the "
+                           "native arena is unavailable (%s)",
+                           self.store.native_error)
+        self.node_id = NodeID.from_random().binary()
+        self.resources = default_resources(num_cpus, num_tpus, resources)
+        with tracing.span("setup/init/node_manager"):
+            self.node_manager = NodeManager(
+                node_id=self.node_id, session_dir=self.session_dir,
+                control_plane=self.control_plane,
+                cp_sock_path=self.cp_sock_path, shm_store=self.store,
+                resources=self.resources)
+        self.job_id = JobID.from_random()
+        with tracing.span("setup/init/core_worker"):
+            self.worker = CoreWorker(
+                mode="driver", job_id=self.job_id,
+                worker_id=WorkerID.from_random(), node_id=self.node_id,
+                control_plane=self.control_plane,
+                node_manager=self.node_manager, shm_store=self.store,
+                session_dir=self.session_dir, namespace=namespace,
+                nm_addr=self.node_manager.sock_path)
+            from ray_tpu._private.ref_tracker import install_tracker
+            install_tracker(self.worker.worker_id.binary(),
+                            self.control_plane, node_id=self.node_id)
+        self._extra_nodes: list = []
+        self._stopped = False
+        self._health_thread = threading.Thread(
+            target=self._health_loop, daemon=True, name="head-health")
+        self._health_thread.start()
+        self._gc_thread = threading.Thread(
+            target=self._gc_loop, daemon=True, name="head-object-gc")
+        self._gc_thread.start()
+        self.log_monitor = None
+        if GLOBAL_CONFIG.log_to_driver:
+            from ray_tpu._private.log_streaming import DriverLogMonitor
+            with tracing.span("setup/init/log_monitor"):
+                self.log_monitor = DriverLogMonitor(self.control_plane)
+                self.log_monitor.start()
+        atexit.register(self.shutdown)
 
+    def _start_control_plane(self):
         self.control_plane = ControlPlane()
         self.cp_journal = None
         if GLOBAL_CONFIG.cp_persistence:
@@ -352,44 +404,6 @@ class HeadNode:
         self.cp_sock_path = self.cp_server.address
         with open(os.path.join(self.session_dir, "cp_address"), "w") as f:
             f.write(self.cp_sock_path)
-        self.store = ShmStore(self.shm_root, spill_dir=self.spill_dir)
-        if self.store.native_error:
-            # said once, by the head: every worker falls back the same way
-            logger.warning("object store: using the Python file store, the "
-                           "native arena is unavailable (%s)",
-                           self.store.native_error)
-        self.node_id = NodeID.from_random().binary()
-        self.resources = default_resources(num_cpus, num_tpus, resources)
-        self.node_manager = NodeManager(
-            node_id=self.node_id, session_dir=self.session_dir,
-            control_plane=self.control_plane,
-            cp_sock_path=self.cp_sock_path, shm_store=self.store,
-            resources=self.resources)
-        self.job_id = JobID.from_random()
-        self.worker = CoreWorker(
-            mode="driver", job_id=self.job_id,
-            worker_id=WorkerID.from_random(), node_id=self.node_id,
-            control_plane=self.control_plane,
-            node_manager=self.node_manager, shm_store=self.store,
-            session_dir=self.session_dir, namespace=namespace,
-            nm_addr=self.node_manager.sock_path)
-        from ray_tpu._private.ref_tracker import install_tracker
-        install_tracker(self.worker.worker_id.binary(),
-                        self.control_plane, node_id=self.node_id)
-        self._extra_nodes: list = []
-        self._stopped = False
-        self._health_thread = threading.Thread(
-            target=self._health_loop, daemon=True, name="head-health")
-        self._health_thread.start()
-        self._gc_thread = threading.Thread(
-            target=self._gc_loop, daemon=True, name="head-object-gc")
-        self._gc_thread.start()
-        self.log_monitor = None
-        if GLOBAL_CONFIG.log_to_driver:
-            from ray_tpu._private.log_streaming import DriverLogMonitor
-            self.log_monitor = DriverLogMonitor(self.control_plane)
-            self.log_monitor.start()
-        atexit.register(self.shutdown)
 
     # ------------------------------------------------------------------
     def add_node(self, num_cpus: float = 1.0, num_tpus: float = 0.0,

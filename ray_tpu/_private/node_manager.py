@@ -34,6 +34,7 @@ from ray_tpu._private.ids import NodeID, WorkerID
 from ray_tpu._private.task_spec import (TaskSpec, acquire, fits, release)
 from ray_tpu.exceptions import (ActorDiedError, WorkerCrashedError,
                                 format_remote_traceback)
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -1367,10 +1368,13 @@ class NodeManager:
         log_dir = os.path.join(self.session_dir, "logs")
         os.makedirs(log_dir, exist_ok=True)
         out = open(os.path.join(log_dir, "forkserver.log"), "ab")
-        self._forksrv_proc = subprocess.Popen(
-            [sys.executable, "-m",
-             "ray_tpu._private.worker_forkserver"],
-            env=env, stdout=out, stderr=subprocess.STDOUT)
+        # the warm timer ``__init__`` armed runs this after ``init`` has
+        # returned: the span lies behind ``setup/init``, not inside it
+        with tracing.span("setup/forkserver"):
+            self._forksrv_proc = subprocess.Popen(
+                [sys.executable, "-m",
+                 "ray_tpu._private.worker_forkserver"],
+                env=env, stdout=out, stderr=subprocess.STDOUT)
         out.close()
 
     def _ensure_forkserver(self) -> Optional[protocol.RpcClient]:
@@ -1436,17 +1440,22 @@ class NodeManager:
         log_path = os.path.join(
             log_dir, f"worker-{worker_id.hex()[:12]}.log")
         proc = None
-        if not tpu:
-            forked = self._fork_worker(worker_id, env, log_path)
-            if forked is not None:
-                proc = _ForkedProc(*forked)
-        if proc is None:
-            out = open(log_path, "ab")
-            proc = subprocess.Popen(
-                [sys.executable, "-m", "ray_tpu._private.worker_proc"],
-                env=env, stdout=out, stderr=subprocess.STDOUT,
-                start_new_session=False)
-            out.close()
+        # the fork or the ``Popen`` itself; what the child does from its
+        # first line on is its own ``setup/worker_boot``
+        with tracing.span("setup/worker_spawn", worker=worker_id.hex()[:12],
+                          tpu=int(tpu)) as sp:
+            if not tpu:
+                forked = self._fork_worker(worker_id, env, log_path)
+                if forked is not None:
+                    proc = _ForkedProc(*forked)
+            sp.set(forked=int(proc is not None))
+            if proc is None:
+                out = open(log_path, "ab")
+                proc = subprocess.Popen(
+                    [sys.executable, "-m", "ray_tpu._private.worker_proc"],
+                    env=env, stdout=out, stderr=subprocess.STDOUT,
+                    start_new_session=False)
+                out.close()
         with self._lock:
             # a forked worker can register its stream before we get here;
             # attach the proc handle to the existing entry in that case

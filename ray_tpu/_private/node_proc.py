@@ -38,6 +38,8 @@ def build_env(*, session_dir: str, cp_addr: str, node_id: bytes,
 
 
 def main():
+    from ray_tpu.util import tracing
+    tracing.set_role("node")    # its ``setup/worker_spawn`` records
     session_dir = os.environ["RAY_TPU_SESSION_DIR"]
     cp_sock = os.environ["RAY_TPU_CP_SOCK"]
     node_id = bytes.fromhex(os.environ["RAY_TPU_NODE_ID"])

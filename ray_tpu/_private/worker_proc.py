@@ -9,11 +9,13 @@ commits results to the object store.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import inspect
 import socket
 import os
 import sys
 import threading
+import time
 import traceback
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
@@ -25,6 +27,7 @@ from ray_tpu._private.task_spec import Arg, TaskSpec
 from ray_tpu._private.worker import CoreWorker, set_global_worker
 from ray_tpu.exceptions import TaskError, format_remote_traceback
 from ray_tpu.object_ref import ObjectRef
+from ray_tpu.util import tracing
 
 
 class WorkerProcess:
@@ -64,6 +67,9 @@ class WorkerProcess:
             from ray_tpu._private.log_streaming import install_worker_tee
             self._log_drain = install_worker_tee(
                 self.cp, self.worker_id.binary())
+        # chips the node manager gave this process's actor, until its
+        # first call has left its ``setup/task`` record
+        self._first_call_chips = 0
         # actor execution machinery (populated on creation)
         self.actor_pool: Optional[ThreadPoolExecutor] = None
         self.actor_loop: Optional[asyncio.AbstractEventLoop] = None
@@ -152,6 +158,15 @@ class WorkerProcess:
                                                           host_chips)
         enable_compile_cache()
 
+    def _first_call_span(self, spec: TaskSpec):
+        """``setup/task`` around the first call of an actor that holds
+        chips, and nothing around any other: the start-up record says
+        when the chips' owner got its work, once a worker."""
+        chips, self._first_call_chips = self._first_call_chips, 0
+        if not chips:
+            return contextlib.nullcontext()
+        return tracing.span("setup/task", fn=spec.name, chips=chips)
+
     def _commit_results(self, spec: TaskSpec, result: Any):
         if spec.is_generator:
             count = 0
@@ -217,14 +232,18 @@ class WorkerProcess:
         error_payload = None
         try:
             from ray_tpu._private import runtime_env as _renv
-            self._set_visible_chips(chips, host_chips)
-            fn = self.core.load_function(spec.function_key)
-            args, kwargs = self._resolve_args(spec)
-            with _renv.applied(spec.runtime_env), task_span(spec):
-                if inspect.iscoroutinefunction(fn):
-                    result = asyncio.run(fn(*args, **kwargs))
-                else:
-                    result = fn(*args, **kwargs)
+            # a task that is given chips is its process's first and
+            # last: taking them (``import jax`` among it) is in the span
+            with (tracing.span("setup/task", fn=spec.name, chips=len(chips))
+                  if chips else contextlib.nullcontext()):
+                self._set_visible_chips(chips, host_chips)
+                fn = self.core.load_function(spec.function_key)
+                args, kwargs = self._resolve_args(spec)
+                with _renv.applied(spec.runtime_env), task_span(spec):
+                    if inspect.iscoroutinefunction(fn):
+                        result = asyncio.run(fn(*args, **kwargs))
+                    else:
+                        result = fn(*args, **kwargs)
             self._commit_results(spec, result)
         except BaseException as e:  # noqa: BLE001
             error = True
@@ -244,13 +263,19 @@ class WorkerProcess:
     def _execute_creation(self, spec: TaskSpec, chips, host_chips):
         try:
             from ray_tpu._private import runtime_env as _renv
-            self._set_visible_chips(chips, host_chips)
-            if spec.runtime_env:
-                # actors own their process: applied for life
-                _renv.apply(spec.runtime_env)
-            cls = self.core.load_function(spec.function_key)
-            args, kwargs = self._resolve_args(spec)
-            instance = cls(*args, **kwargs)
+            # taking the chips (``import jax`` among it), the class and
+            # its ``__init__``
+            with tracing.span("setup/actor_init",
+                              cls=spec.name.removesuffix(".__init__"),
+                              chips=len(chips or ())):
+                self._set_visible_chips(chips, host_chips)
+                if spec.runtime_env:
+                    # actors own their process: applied for life
+                    _renv.apply(spec.runtime_env)
+                cls = self.core.load_function(spec.function_key)
+                args, kwargs = self._resolve_args(spec)
+                instance = cls(*args, **kwargs)
+            self._first_call_chips = len(chips or ())
             self.core.current_actor = instance
             self.core.current_actor_id = spec.actor_id
             self.is_async_actor = any(
@@ -501,7 +526,7 @@ class WorkerProcess:
         try:
             method = self._lookup_method(spec)
             args, kwargs = self._resolve_args(spec)
-            with task_span(spec):
+            with self._first_call_span(spec), task_span(spec):
                 result = method(*args, **kwargs)
             if inspect.iscoroutine(result):
                 result = asyncio.run(result)
@@ -587,11 +612,30 @@ class WorkerProcess:
         return method
 
 
+def _exec_epoch() -> Optional[float]:
+    """When this process started, as an epoch stamp, from its start time
+    in ``/proc/self/stat`` (ticks since boot): what the interpreter and
+    ``import ray_tpu`` took lies between it and ``main``'s first line
+    (a forked worker's is its fork)."""
+    from ray_tpu._private.worker_forkserver import proc_start_time
+    ticks = proc_start_time(os.getpid())
+    if ticks is None:
+        return None
+    age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+           - ticks / os.sysconf("SC_CLK_TCK"))
+    return time.time() - age
+
+
 def main():
     import faulthandler
     import signal
-    faulthandler.register(signal.SIGUSR1)
-    proc = WorkerProcess()
+    tracing.set_role("worker")
+    # from here to registered with the node manager and waiting for work
+    with tracing.span("setup/worker_boot",
+                      worker=os.environ.get("RAY_TPU_WORKER_ID", "")[:12],
+                      exec_epoch=_exec_epoch()):
+        faulthandler.register(signal.SIGUSR1)
+        proc = WorkerProcess()
     try:
         proc.run()
     finally:

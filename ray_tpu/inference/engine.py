@@ -297,153 +297,158 @@ class InferenceEngine:
                 "reproduce a prefill's output row by row; the serve "
                 "path's routed layer is parallel/moe.py:dropless_moe")
         refuse_unserved(cfg)
-        from ray_tpu._private.compile_cache import enable_compile_cache
-        enable_compile_cache()
-        icfg = infer_config()
-        self.cfg = cfg
-        self.params = jax.device_put(params)
-        self.slots = slots if slots is not None else icfg.slots
-        self.page_size = (page_size if page_size is not None
-                          else icfg.page_size)
-        self.kv_dtype = kv_dtype or icfg.kv_dtype
-        self.prefix = icfg.prefix if prefix is None else bool(prefix)
-        self.max_queue = (icfg.max_queue if max_queue is None
-                          else max_queue)
-        # default per-request deadlines (0/None = none); per-submit
-        # overrides win.  Stored as None-or-positive so the expiry
-        # sweep can skip requests without budgets cheaply.
-        self.ttft_deadline = (icfg.ttft_deadline if ttft_deadline
-                              is None else float(ttft_deadline)) or None
-        self.deadline = (icfg.deadline if deadline is None
-                         else float(deadline)) or None
-        if self.kv_dtype not in ("model", "int8"):
-            raise ValueError(f"unknown kv_dtype {self.kv_dtype!r} "
-                             "(check RAY_TPU_KV_DTYPE)")
-        if self.slots < 1:
-            raise ValueError(f"need >= 1 decode slot, got {self.slots} "
-                             "(check RAY_TPU_INFER_SLOTS)")
-        if self.page_size < 1:
-            raise ValueError(f"page_size must be >= 1, got "
-                             f"{self.page_size}")
-        if self.max_queue < 0:
-            raise ValueError(f"max_queue must be >= 0, got "
-                             f"{self.max_queue} "
-                             "(check RAY_TPU_INFER_MAX_QUEUE)")
-        self.buckets = tuple(sorted(
-            b for b in (buckets or icfg.buckets
-                        or default_buckets(cfg.max_seq))
-            if b <= cfg.max_seq)) or (cfg.max_seq,)
-        max_pages_per_slot = kvc.pages_needed(cfg.max_seq, self.page_size)
-        num_pages = num_pages or icfg.pages or (
-            self.slots * max_pages_per_slot + 1)
-        self.max_pages_per_slot = max_pages_per_slot
-        self.scheduler = SlotScheduler(
-            slots=self.slots, page_size=self.page_size,
-            num_pages=num_pages, max_pages_per_slot=max_pages_per_slot,
-            prefix=self.prefix, max_queue=self.max_queue)
-        if self._latent:
-            self.cache = kvc.KVCache(
-                n_layers=cfg.cache_layers, num_pages=num_pages,
-                page_size=self.page_size, dtype=cfg.dtype,
-                kv_dtype=self.kv_dtype, latent=self._latent)
-        else:
-            self.cache = kvc.KVCache(
-                n_layers=cfg.n_layers, num_pages=num_pages,
-                page_size=self.page_size, n_heads=cfg.n_heads,
-                head_dim=cfg.head_dim, dtype=cfg.dtype,
-                kv_dtype=self.kv_dtype)
-        # multi-tenant LoRA serving (r25): ``lora`` takes a LoraConfig
-        # (explicit geometry), True (env defaults, forced on), or
-        # None/False (follow RAY_TPU_LORA).  When on, the engine holds
-        # an adapter **bank** — stacked [N, L, in, r]/[N, L, r, out]
-        # factors, slot 0 the all-zeros identity — that rides every
-        # compiled step as a call argument, plus the per-engine LRU
-        # registry mapping model_id -> bank slot.  ``adapter_store``
-        # shares the fleet's publication point; lora-on engines
-        # default to a private store so direct put()/load flows work.
-        if isinstance(lora, LoraConfig):
-            self.lora_cfg: Optional[LoraConfig] = lora
-        elif lora is True:
-            self.lora_cfg = lora_config()
-        elif lora is None and lora_config().enabled:
-            self.lora_cfg = lora_config()
-        else:
-            self.lora_cfg = None
-        if self._latent and self.lora_cfg is not None:
-            kvc.refuse_latent("LoRA adapters (lora)")
-        if self.lora_cfg is not None:
-            self._lora_targets = lora_mod.effective_targets(
-                cfg, self.lora_cfg)
-            self.lora_bank = lora_mod.bank_zeros(cfg, self.lora_cfg)
-            self.adapters: Optional[AdapterRegistry] = AdapterRegistry(
-                self.lora_cfg.cache_slots)
-            self.adapter_store: Optional[AdapterStore] = (
-                adapter_store if adapter_store is not None
-                else AdapterStore())
-            lora_key = ("lora", self.lora_cfg.rank,
-                        self.lora_cfg.bank_slots, self._lora_targets)
-        else:
-            self._lora_targets = ()
-            self.lora_bank = None
-            self.adapters = None
-            self.adapter_store = adapter_store
-            lora_key = None
-        # compile cache: key -> AOT executable; an executable raises on
-        # shape drift, so the counters below are honest.  Keys carry
-        # the full (cfg, geometry) so a shared cache cannot alias
-        # engines of different shapes.
-        self._compiled: Dict[Any, Any] = (
-            executable_cache if executable_cache is not None else {})
-        self._exec_key = (cfg, self.slots, self.page_size, num_pages,
-                          max_pages_per_slot, self.kv_dtype, lora_key)
-        self.compile_counts: Dict[str, int] = {
-            "prefill": 0, "prefill_cached": 0, "decode": 0}
-        self.hit_counts: Dict[str, int] = {
-            "prefill": 0, "prefill_cached": 0, "decode": 0}
-        self._requests: Dict[int, Request] = {}
-        # retired-but-held requests (r20 disagg export seam): pages
-        # stay refcounted until export_request/release_held — the leak
-        # audit counts them, so an orphaned export is visible
-        self._held: Dict[int, Request] = {}
-        self.exports = 0
-        self.imports = 0
-        # r24 tracing: the replica id spans carry (set by
-        # fleet.replica.EngineReplica so cross-replica trace trees can
-        # attribute work; None = a bare engine)
-        self.trace_label: Optional[str] = None
-        # dispatched steps whose tokens the host has not fetched,
-        # oldest first (see _Flight), and events delivered outside a
-        # tick (``_level``), which the next tick returns
-        self._flight: List[_Flight] = []
-        self._backlog: List[StepEvent] = []
-        self._next_rid = 0
-        self._cancelled: set = set()
-        self._lock = threading.Lock()   # submit() vs step() admissions
-        # liveness bookkeeping for the resilience watchdog: ``ticks``
-        # counts completed step() calls, ``last_tick_ts`` their wall
-        # time — a wedged step loop is has_work + neither moving
-        self.ticks = 0
-        self.last_tick_ts = time.monotonic()
-        self.deadline_exceeded = 0
-        # versioned params (the RL weight-publication contract): the
-        # construction snapshot is version 0 and may alias caller-held
-        # arrays, so the first set_params() does not delete it
-        self.param_version = 0
-        self._owns_params = False
-        self.debug_logits = debug_logits
-        # rid -> [logits row per generated token], appended in event
-        # order (parity tests only; off by default)
-        self.logits_trace: Dict[int, List[np.ndarray]] = {}
-        from ray_tpu.telemetry.infer import InferTelemetry
-        from ray_tpu.telemetry.config import TelemetryConfig
-        config = (TelemetryConfig(enabled=True) if telemetry is True
-                  else TelemetryConfig(enabled=False)
-                  if telemetry is False else None)
-        self.telemetry = InferTelemetry(config=config)
-        self.telemetry.record_cache_info(
-            kv_dtype=self.kv_dtype, cache_bytes=self.cache.bytes,
-            kv_bytes_per_slot=self.cache.bytes_per_slot(
-                max_pages_per_slot))
+        # in the start-up record (``util/tracing.py``): the weights put
+        # on the device, the cache pool made, the bank
+        with tracing.span("setup/engine") as sp:
+            from ray_tpu._private.compile_cache import enable_compile_cache
+            enable_compile_cache()
+            icfg = infer_config()
+            self.cfg = cfg
+            self.params = jax.device_put(params)
+            self.slots = slots if slots is not None else icfg.slots
+            self.page_size = (page_size if page_size is not None
+                              else icfg.page_size)
+            self.kv_dtype = kv_dtype or icfg.kv_dtype
+            self.prefix = icfg.prefix if prefix is None else bool(prefix)
+            self.max_queue = (icfg.max_queue if max_queue is None
+                              else max_queue)
+            # default per-request deadlines (0/None = none); per-submit
+            # overrides win.  Stored as None-or-positive so the expiry
+            # sweep can skip requests without budgets cheaply.
+            self.ttft_deadline = (icfg.ttft_deadline if ttft_deadline
+                                  is None else float(ttft_deadline)) or None
+            self.deadline = (icfg.deadline if deadline is None
+                             else float(deadline)) or None
+            if self.kv_dtype not in ("model", "int8"):
+                raise ValueError(f"unknown kv_dtype {self.kv_dtype!r} "
+                                 "(check RAY_TPU_KV_DTYPE)")
+            if self.slots < 1:
+                raise ValueError(f"need >= 1 decode slot, got {self.slots} "
+                                 "(check RAY_TPU_INFER_SLOTS)")
+            if self.page_size < 1:
+                raise ValueError(f"page_size must be >= 1, got "
+                                 f"{self.page_size}")
+            if self.max_queue < 0:
+                raise ValueError(f"max_queue must be >= 0, got "
+                                 f"{self.max_queue} "
+                                 "(check RAY_TPU_INFER_MAX_QUEUE)")
+            self.buckets = tuple(sorted(
+                b for b in (buckets or icfg.buckets
+                            or default_buckets(cfg.max_seq))
+                if b <= cfg.max_seq)) or (cfg.max_seq,)
+            max_pages_per_slot = kvc.pages_needed(cfg.max_seq, self.page_size)
+            num_pages = num_pages or icfg.pages or (
+                self.slots * max_pages_per_slot + 1)
+            self.max_pages_per_slot = max_pages_per_slot
+            self.scheduler = SlotScheduler(
+                slots=self.slots, page_size=self.page_size,
+                num_pages=num_pages, max_pages_per_slot=max_pages_per_slot,
+                prefix=self.prefix, max_queue=self.max_queue)
+            if self._latent:
+                self.cache = kvc.KVCache(
+                    n_layers=cfg.cache_layers, num_pages=num_pages,
+                    page_size=self.page_size, dtype=cfg.dtype,
+                    kv_dtype=self.kv_dtype, latent=self._latent)
+            else:
+                self.cache = kvc.KVCache(
+                    n_layers=cfg.n_layers, num_pages=num_pages,
+                    page_size=self.page_size, n_heads=cfg.n_heads,
+                    head_dim=cfg.head_dim, dtype=cfg.dtype,
+                    kv_dtype=self.kv_dtype)
+            # multi-tenant LoRA serving (r25): ``lora`` takes a LoraConfig
+            # (explicit geometry), True (env defaults, forced on), or
+            # None/False (follow RAY_TPU_LORA).  When on, the engine holds
+            # an adapter **bank** — stacked [N, L, in, r]/[N, L, r, out]
+            # factors, slot 0 the all-zeros identity — that rides every
+            # compiled step as a call argument, plus the per-engine LRU
+            # registry mapping model_id -> bank slot.  ``adapter_store``
+            # shares the fleet's publication point; lora-on engines
+            # default to a private store so direct put()/load flows work.
+            if isinstance(lora, LoraConfig):
+                self.lora_cfg: Optional[LoraConfig] = lora
+            elif lora is True:
+                self.lora_cfg = lora_config()
+            elif lora is None and lora_config().enabled:
+                self.lora_cfg = lora_config()
+            else:
+                self.lora_cfg = None
+            if self._latent and self.lora_cfg is not None:
+                kvc.refuse_latent("LoRA adapters (lora)")
+            if self.lora_cfg is not None:
+                self._lora_targets = lora_mod.effective_targets(
+                    cfg, self.lora_cfg)
+                self.lora_bank = lora_mod.bank_zeros(cfg, self.lora_cfg)
+                self.adapters: Optional[AdapterRegistry] = AdapterRegistry(
+                    self.lora_cfg.cache_slots)
+                self.adapter_store: Optional[AdapterStore] = (
+                    adapter_store if adapter_store is not None
+                    else AdapterStore())
+                lora_key = ("lora", self.lora_cfg.rank,
+                            self.lora_cfg.bank_slots, self._lora_targets)
+            else:
+                self._lora_targets = ()
+                self.lora_bank = None
+                self.adapters = None
+                self.adapter_store = adapter_store
+                lora_key = None
+            # compile cache: key -> AOT executable; an executable raises on
+            # shape drift, so the counters below are honest.  Keys carry
+            # the full (cfg, geometry) so a shared cache cannot alias
+            # engines of different shapes.
+            self._compiled: Dict[Any, Any] = (
+                executable_cache if executable_cache is not None else {})
+            self._exec_key = (cfg, self.slots, self.page_size, num_pages,
+                              max_pages_per_slot, self.kv_dtype, lora_key)
+            self.compile_counts: Dict[str, int] = {
+                "prefill": 0, "prefill_cached": 0, "decode": 0}
+            self.hit_counts: Dict[str, int] = {
+                "prefill": 0, "prefill_cached": 0, "decode": 0}
+            self._requests: Dict[int, Request] = {}
+            # retired-but-held requests (r20 disagg export seam): pages
+            # stay refcounted until export_request/release_held — the leak
+            # audit counts them, so an orphaned export is visible
+            self._held: Dict[int, Request] = {}
+            self.exports = 0
+            self.imports = 0
+            # r24 tracing: the replica id spans carry (set by
+            # fleet.replica.EngineReplica so cross-replica trace trees can
+            # attribute work; None = a bare engine)
+            self.trace_label: Optional[str] = None
+            # dispatched steps whose tokens the host has not fetched,
+            # oldest first (see _Flight), and events delivered outside a
+            # tick (``_level``), which the next tick returns
+            self._flight: List[_Flight] = []
+            self._backlog: List[StepEvent] = []
+            self._next_rid = 0
+            self._cancelled: set = set()
+            self._lock = threading.Lock()   # submit() vs step() admissions
+            # liveness bookkeeping for the resilience watchdog: ``ticks``
+            # counts completed step() calls, ``last_tick_ts`` their wall
+            # time — a wedged step loop is has_work + neither moving
+            self.ticks = 0
+            self.last_tick_ts = time.monotonic()
+            self.deadline_exceeded = 0
+            # versioned params (the RL weight-publication contract): the
+            # construction snapshot is version 0 and may alias caller-held
+            # arrays, so the first set_params() does not delete it
+            self.param_version = 0
+            self._owns_params = False
+            self.debug_logits = debug_logits
+            # rid -> [logits row per generated token], appended in event
+            # order (parity tests only; off by default)
+            self.logits_trace: Dict[int, List[np.ndarray]] = {}
+            from ray_tpu.telemetry.infer import InferTelemetry
+            from ray_tpu.telemetry.config import TelemetryConfig
+            config = (TelemetryConfig(enabled=True) if telemetry is True
+                      else TelemetryConfig(enabled=False)
+                      if telemetry is False else None)
+            self.telemetry = InferTelemetry(config=config)
+            self.telemetry.record_cache_info(
+                kv_dtype=self.kv_dtype, cache_bytes=self.cache.bytes,
+                kv_bytes_per_slot=self.cache.bytes_per_slot(
+                    max_pages_per_slot))
+            sp.set(slots=self.slots, pages=int(self.cache.num_pages),
+                   buckets=len(self.buckets))
 
     # ---------------------------------------- multi-tenant LoRA (r25)
     def _adapter_release(self, req: Request) -> None:

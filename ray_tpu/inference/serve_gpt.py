@@ -111,7 +111,11 @@ def _build_engine(model: str, model_config: Optional[Dict[str, Any]],
     # before any weight is drawn (a config that runs its own stack
     # passes: it says what it holds to that stack)
     refuse_unserved(cfg)
-    params = module.init_params(cfg, jax.random.PRNGKey(seed))
+    # dispatch only: nothing waits for the device here
+    with tracing.span("setup/weights") as sp:
+        params = module.init_params(cfg, jax.random.PRNGKey(seed))
+        leaves = jax.tree.leaves(params)
+        sp.set(leaves=len(leaves), bytes=sum(x.nbytes for x in leaves))
     return cfg, InferenceEngine(cfg, params, **(engine_config or {}))
 
 

@@ -133,6 +133,25 @@ def _batch_sharding(mesh):
     return NamedSharding(mesh, P(shd.data_axes(mesh), seq_axis))
 
 
+def _recorded_init(init_jit):
+    """``init_jit`` with its call in the start-up record as
+    ``setup/weights`` (``util/tracing.py``): the dispatch of the one
+    jitted call that draws the state, its trace and its executable's
+    load with it, and not the device's work, which nothing here waits
+    for."""
+    from ray_tpu.util import tracing
+
+    def init_fn(key):
+        with tracing.span("setup/weights") as sp:
+            state = init_jit(key)
+            leaves = jax.tree.leaves(state)
+            sp.set(leaves=len(leaves),
+                   bytes=sum(x.size * x.dtype.itemsize for x in leaves))
+        return state
+
+    return init_fn
+
+
 def _maybe_instrument(fns: Dict[str, Callable], cfg, mesh, *,
                       comm_mode: Optional[str] = None,
                       comm_quant: Optional[str] = None,
@@ -461,7 +480,7 @@ def build_gpt_train(cfg: "gpt_mod.GPTConfig", mesh, *,
         return logits
 
     fns = {
-        "init_fn": init_jit,
+        "init_fn": _recorded_init(init_jit),
         "step_fn": step,
         "loss_fn": loss_eval,
         "forward_fn": forward_logits,
@@ -724,7 +743,7 @@ def build_gpt_rl_train(cfg: "gpt_mod.GPTConfig", mesh, *,
         return pg_loss(params, batch)[0]
 
     return {
-        "init_fn": init_jit,
+        "init_fn": _recorded_init(init_jit),
         "step_fn": step,
         "loss_fn": loss_eval,
         "pg_grad_fn": grad_fn,
@@ -1015,7 +1034,7 @@ def build_gpt_train_pp(cfg: "gpt_mod.GPTConfig", mesh, *,
         return loss(params, batch)
 
     fns = {
-        "init_fn": init_jit,
+        "init_fn": _recorded_init(init_jit),
         "step_fn": step,
         "loss_fn": loss_eval,
         "state_shardings": st_sh,

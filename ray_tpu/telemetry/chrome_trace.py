@@ -5,9 +5,12 @@ loads in Perfetto / ``chrome://tracing``:
 
 - the host-side span recorder (``ray_tpu.util.tracing`` fallback
   recorder — submit/task spans plus the named train-loop scopes the
-  telemetry wrapper emits when tracing is enabled),
+  telemetry wrapper emits when tracing is enabled, and, always, the
+  process's start-up record: ``setup/*`` spans and jax's own
+  ``jax/trace`` / ``jax/lower`` / ``jax/load`` / ``jax/compile`` time
+  spans, a step's compile among them),
 - every live :class:`~ray_tpu.telemetry.step.StepTelemetry` recorder's
-  per-step records (step / dispatch / sync / compile complete-events),
+  per-step records (step / dispatch / sync complete-events),
 - the r24 per-request flight recorder
   (:mod:`ray_tpu.telemetry.trace` — routing, handoff, prefill and
   decode spans, grouped by replica).

@@ -144,7 +144,6 @@ class StepTelemetry:
         self._cfgobj = tcfg
         self._compiled = None
         self._signature = None
-        self._compile_ts: Optional[float] = None
         self._tokens_per_step: Optional[int] = None
         self._seq: Optional[int] = None
         self._batch: Optional[int] = None
@@ -190,7 +189,7 @@ class StepTelemetry:
         # times are theirs (wall = dispatch start to sync end)
         with jax.profiler.StepTraceAnnotation(self.label, step_num=i):
             with tracing.span(f"{self.label}/dispatch", step=i) as disp:
-                out = self._dispatch(step_fn, args, kwargs, i, ts)
+                out = self._dispatch(step_fn, args, kwargs, i)
             with tracing.span(f"{self.label}/sync", step=i) as sync:
                 jax.block_until_ready(out)
             with tracing.span(f"{self.label}/loss_read", step=i):
@@ -213,6 +212,12 @@ class StepTelemetry:
             rec["compile_s"] = self.compile_s
         if i == 0:
             self.first_step_s = rec["wall_s"]
+            # into the start-up record, from the stamps this record
+            # takes anyway: the step's trace, lowering and executable
+            # are jax's own records inside it (``util/tracing.py``)
+            from ray_tpu.util import tracing
+            tracing.keep("setup/first_step", ts, rec["wall_s"],
+                         label=self.label)
         if self._tokens_per_step:
             rec["tokens"] = self._tokens_per_step
             # step 0's wall includes the (jit or AOT) compile — a
@@ -249,13 +254,12 @@ class StepTelemetry:
             del self.records[:len(self.records) - self._MAX_RECORDS]
         self._emit(rec)
 
-    def _dispatch(self, step_fn, args, kwargs, i, ts):
+    def _dispatch(self, step_fn, args, kwargs, i):
         if not self._aot:
             return step_fn(*args, **kwargs)
         if i == 0:
             # a step that does not compile fails here: no catch, no
             # second attempt through plain jit
-            self._compile_ts = ts
             t0 = time.monotonic()
             compiled = step_fn.lower(*args, **kwargs).compile()
             self.compile_s = time.monotonic() - t0
@@ -537,11 +541,6 @@ class StepTelemetry:
         """This recorder's steps as Chrome-trace complete events."""
         evs: List[Dict[str, Any]] = []
         pid, tid = "train", self.label
-        if self.compile_s is not None and self._compile_ts is not None:
-            evs.append({"name": f"{self.label}/compile", "cat": "train",
-                        "ph": "X", "ts": self._compile_ts * 1e6,
-                        "dur": self.compile_s * 1e6,
-                        "pid": pid, "tid": tid, "args": {}})
         for r in self.records:
             args = {k: r[k] for k in ("loss", "tokens_per_sec", "mfu")
                     if k in r}

@@ -159,3 +159,38 @@ def summarize_actors() -> Dict[str, int]:
 
 def summarize_objects() -> Dict[str, Any]:
     return _cp().objects_summary()
+
+
+def startup_timeline(session_dir: Optional[str] = None
+                     ) -> List[Dict[str, Any]]:
+    """How a session's processes came up: the start-up records
+    (``setup/*`` and ``infer/compile`` spans, jax's ``jax/trace`` /
+    ``jax/lower`` / ``jax/load`` / ``jax/compile`` time spans:
+    ``util/tracing.py``) of every process that wrote
+    ``<session_dir>/logs/startup_<pid>.jsonl``, sorted by ``start``
+    (epoch seconds), each with the ``pid`` and the ``role`` of its
+    process (``driver``, ``worker``, ``node``).
+
+    ``session_dir`` absent: this process's session, live or, in the
+    process that called ``init``, after ``shutdown()`` (the files
+    outlive it).  Reads files only, so it asks nothing of a cluster."""
+    import glob
+    import json
+    import os
+
+    from ray_tpu.util import tracing
+    session_dir = session_dir or tracing.session_dir()
+    if session_dir is None:
+        raise RuntimeError("no session directory: ray_tpu.init() has "
+                           "not run in this process, and none was given")
+    out: List[Dict[str, Any]] = []
+    for path in glob.glob(os.path.join(session_dir, "logs",
+                                       "startup_*.jsonl")):
+        with open(path) as f:
+            for line in f:
+                try:
+                    out.append(json.loads(line))
+                except ValueError:      # a line still being written
+                    continue
+    out.sort(key=lambda r: r["start"])
+    return out

@@ -9,11 +9,26 @@ attributes) tuples so tests and the timeline can still observe the
 graph.  Where jax is loaded every span is also a profiler
 ``TraceAnnotation``: one primitive, and the profiler's clock for the
 spans a device trace is read against.
+
+**The start-up record.**  A process comes up before anybody could enable
+anything, so the spans of that path (names under ``setup/``, and
+``infer/compile``, which opens on a miss only) are kept in the same
+list whether or not tracing is on, with the ``pid`` and ``role`` of
+their process; :func:`keep` adds what was timed elsewhere (jax's own
+trace / lower / load time spans, ``_private/compile_cache.py``).  At
+most ``_MAX_KEPT`` a process, then :func:`kept_stats` counts the
+dropped.  Each is also appended, as it ends, as one JSON line to
+``<session_dir>/logs/startup_<pid>.jsonl`` (held until the process knows
+its session directory); ``ray_tpu.util.state.startup_timeline()`` reads
+a session's files, and ``telemetry/chrome_trace.py`` exports the list as
+it always did.
 """
 
 from __future__ import annotations
 
 import contextlib
+import json
+import os
 import sys
 import threading
 import time
@@ -23,7 +38,18 @@ _lock = threading.Lock()
 _enabled = False
 _tracer = None          # otel tracer when available
 _records: List[Dict[str, Any]] = []   # fallback recorder
-_MAX_RECORDS = 10_000
+_MAX_RECORDS = 10_000   # of the spans enable_tracing() adds
+_TRIM_EVERY = 512       # ... trimmed once this many over
+
+# the start-up record: kept whatever the flag says (module docstring)
+_KEPT_NAMES = ("setup/", "infer/compile")
+_MAX_KEPT = 2_048
+_kept = 0               # kept records in ``_records``
+_dropped = 0            # ... and those the cap turned away
+_unwritten: List[Dict[str, Any]] = []   # kept, not yet in the file
+_role = "driver"        # ``worker_proc.main`` says otherwise
+_session_dir: Optional[str] = None
+_out = None             # ((pid, session_dir), this process's open file)
 
 
 def enable_tracing() -> bool:
@@ -117,14 +143,113 @@ def is_enabled() -> bool:
 
 
 def recorded_spans() -> List[Dict[str, Any]]:
-    """Fallback-recorder contents (OTel-less environments/tests)."""
+    """Fallback-recorder contents (OTel-less environments/tests): the
+    start-up record, and what :func:`enable_tracing` added."""
     with _lock:
         return list(_records)
 
 
-def clear_recorded() -> None:
+def clear_recorded(startup: bool = False) -> None:
+    """Forget the spans :func:`enable_tracing` added; with ``startup``
+    the start-up record too, and its count of dropped (its file stays
+    as it is)."""
+    global _kept, _dropped
     with _lock:
-        _records.clear()
+        if startup:
+            _records.clear()
+            del _unwritten[:]
+            _kept = _dropped = 0
+        else:
+            _records[:] = [r for r in _records if "pid" in r]
+
+
+def set_role(role: str) -> None:
+    """What this process is (``driver``, ``worker``, ``node``): every
+    kept record says it."""
+    global _role
+    _role = role
+
+
+def use_session_dir(path: str) -> None:
+    """The session whose ``logs/`` takes this process's start-up file
+    from now on; what was kept before it was known is written there
+    now.  A worker finds its own in ``RAY_TPU_SESSION_DIR``."""
+    global _session_dir
+    with _lock:
+        _session_dir = path
+        _write_kept()
+
+
+def session_dir() -> Optional[str]:
+    """The session directory last in use here (it outlives
+    ``ray_tpu.shutdown()``, as the files do)."""
+    return _session_dir or os.environ.get("RAY_TPU_SESSION_DIR") or None
+
+
+def kept_stats() -> Dict[str, int]:
+    """The start-up record's size in this process."""
+    with _lock:
+        return {"kept": _kept, "dropped": _dropped, "cap": _MAX_KEPT}
+
+
+def _write_kept() -> None:
+    """Append the kept records not yet written to this process's file
+    (``_lock`` held), which stays open: an ``open`` a record was most of
+    a record's cost, and a worker leaves through ``os._exit``, so each
+    write is the unbuffered one line.  No session yet: they wait.  A
+    directory that is gone: they are in the list and nowhere else."""
+    global _out
+    root = session_dir()
+    if root is None or not _unwritten:
+        return
+    here = (os.getpid(), root)          # a forked child opens its own
+    try:
+        if _out is None or _out[0] != here:
+            _out = (here, open(os.path.join(
+                root, "logs", f"startup_{here[0]}.jsonl"), "ab",
+                buffering=0))
+        _out[1].write("".join(json.dumps(r, default=str) + "\n"
+                              for r in _unwritten).encode())
+    except OSError:
+        _out = None
+    del _unwritten[:]
+
+
+def _append(rec: Dict[str, Any]) -> None:
+    """One finished record into the one list; a kept one (it carries
+    ``pid``) also into the file, and no trim ever takes it out."""
+    global _kept, _dropped
+    with _lock:
+        if "pid" in rec:
+            if _kept >= _MAX_KEPT:
+                _dropped += 1
+                return
+            _kept += 1
+            _records.append(rec)
+            _unwritten.append(rec)
+            _write_kept()
+            return
+        _records.append(rec)
+        over = len(_records) - _kept - _MAX_RECORDS
+        if over >= _TRIM_EVERY:
+            # the oldest of what tracing added go, in one pass
+            stay = []
+            for r in _records:
+                if over and "pid" not in r:
+                    over -= 1
+                else:
+                    stay.append(r)
+            _records[:] = stay
+
+
+def keep(name: str, start: float, dur: float, **attributes) -> None:
+    """Add to the start-up record a span that was timed elsewhere: jax's
+    own time spans (``_private/compile_cache.py``), a train step's
+    first record.  ``start`` is an epoch stamp, ``dur`` seconds."""
+    _append({"name": name, "start": start, "dur": dur,
+             "end": start + dur, "tid": threading.get_ident(),
+             "attributes": attributes, "pid": os.getpid(),
+             "role": _role})
 
 
 def _otel_attributes(otel_span, attributes: Dict[str, Any]) -> None:
@@ -180,15 +305,17 @@ class Span:
             self._ann = profiler.TraceAnnotation(self.name,
                                                  **self.attributes)
             self._ann.__enter__()
-        if _enabled:
-            if _tracer is not None:
-                self._otel = _tracer.start_as_current_span(self.name)
-                self._otel_span = self._otel.__enter__()
-                _otel_attributes(self._otel_span, self.attributes)
-            else:
-                self._rec = {"name": self.name, "start": time.time(),
-                             "tid": threading.get_ident(),
-                             "attributes": self.attributes}
+        if _enabled and _tracer is not None:
+            self._otel = _tracer.start_as_current_span(self.name)
+            self._otel_span = self._otel.__enter__()
+            _otel_attributes(self._otel_span, self.attributes)
+        kept = self.name.startswith(_KEPT_NAMES)
+        if kept or (_enabled and _tracer is None):
+            self._rec = {"name": self.name, "start": time.time(),
+                         "tid": threading.get_ident(),
+                         "attributes": self.attributes}
+            if kept:
+                self._rec.update(pid=os.getpid(), role=_role)
         self.start = time.monotonic()
         return self
 
@@ -202,10 +329,7 @@ class Span:
         if rec is not None:
             rec["dur"] = self.dur
             rec["end"] = rec["start"] + self.dur
-            with _lock:
-                _records.append(rec)
-                if len(_records) > _MAX_RECORDS:
-                    del _records[:len(_records) - _MAX_RECORDS]
+            _append(rec)
 
 
 def span(name: str, **attributes) -> Span:
@@ -216,8 +340,9 @@ def span(name: str, **attributes) -> Span:
     ``jax.profiler.TraceAnnotation`` on the profiler's clock, so a
     device trace shows what the host was doing; with no profile running
     that is a flag check.  With :func:`enable_tracing` on it is also an
-    OTel span or a fallback record, as before.  Attributes are plain
-    ``str``/``int``/``float``.
+    OTel span or a fallback record, as before; a span of the start-up
+    path (``setup/...``, ``infer/compile``) is a record either way.
+    Attributes are plain ``str``/``int``/``float``.
 
     The fallback record keeps an *epoch* ``start`` for timeline
     placement but takes ``dur`` (and the derived ``end``) from the

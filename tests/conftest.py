@@ -30,6 +30,17 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _fresh_startup_record():
+    """The start-up record (``ray_tpu/util/tracing.py``) is a process's,
+    and a test worker is one process for many files: each file starts
+    with an empty one, so that what it reads there (a step's compile in
+    the exported timeline) never hangs on how many jits the files
+    before it left under the record's cap."""
+    from ray_tpu.util import tracing
+    tracing.clear_recorded(startup=True)
+
+
 @pytest.fixture
 def ray_start_regular():
     import ray_tpu

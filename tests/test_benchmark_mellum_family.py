@@ -13,7 +13,8 @@ def test_cell_finds_its_files_and_its_rehearsal(monkeypatch):  # noqa: F811
     manifest's last, which held until a later cell came behind it (new
     entries go to the end of their lists, and the file is a `benchmark`
     PR's to edit), and it lists the metrics that read in its cell alone,
-    which held until PR 62 put ``moe_gather_ms_per_step`` behind them:
+    which held until PR 62 put ``moe_gather_ms_per_step`` behind them
+    (and PR 63 the ``setup_*`` metrics of every cell behind that):
     the per-layer metrics are cut behind the last it knew, after an
     assertion of what stands behind; everything else it reads is the
     manifest as it is."""
@@ -23,8 +24,12 @@ def test_cell_finds_its_files_and_its_rehearsal(monkeypatch):  # noqa: F811
     assert at == 6 and len(set(names)) == len(names)
     layer = [m["name"] for m in man["per_layer"]]
     known = layer.index("moe_decode_roofline.batch") + 1
-    assert [(m["name"], m["workloads"]) for m in man["per_layer"][known:]] \
-        == [("moe_gather_ms_per_step", [theirs.CELL])]
+    behind = man["per_layer"][known:]
+    assert (behind[0]["name"], behind[0]["workloads"]) \
+        == ("moe_gather_ms_per_step", [theirs.CELL])
+    # and since PR 63 the set-up's parts, none of them this cell's alone
+    assert all(m["name"].startswith("setup_")
+               and m.get("workloads") != [theirs.CELL] for m in behind[1:])
     as_far = dict(man, workloads=man["workloads"][:at + 1],
                   per_layer=man["per_layer"][:known])
     monkeypatch.setattr(common, "manifest", lambda: as_far)
