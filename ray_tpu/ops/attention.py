@@ -849,7 +849,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0, 0] = dq_sc[:].astype(dq_ref.dtype)
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                       *rest, scale: float, causal: bool, block_q: int,
                       block_k: int, num_q: int, num_kv: int,
                       has_rope: bool, group: int = 1,
@@ -880,7 +880,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     recompute; score-gradients land on the *rotated* q/k, so dq takes
     the transposed rotation before its store and dk takes it at
     finalize (the rotation is per-row, so it commutes with the
-    accumulation over q blocks).
+    accumulation over q blocks).  A row's ``delta = sum(do * o)`` is
+    made here from the ``do`` and ``o`` blocks of the same rows: the
+    grid visits a (query head, q block) once, so it is made once.
     """
     if has_rope:
         (cq_ref, sq_ref, ck_ref, sk_ref,
@@ -905,7 +907,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if has_rope:
         q = _rot(q, cq_ref[...], sq_ref[...], D)
     lse = lse_ref[0, 0, 0][:, 0:1]
-    delta = delta_ref[0, 0, 0][:, 0:1]
+    delta = jnp.sum(do.astype(jnp.float32)
+                    * o_ref[0, 0].astype(jnp.float32),
+                    axis=-1, keepdims=True)               # [bq, 1]
 
     if num_kv == 1:
         # single strip: every block pair is live under causal masking,
@@ -967,11 +971,10 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
-                      delta0_ref, delta1_ref, *rest, scale: float,
-                      causal: bool, block_q: int, block_k: int,
-                      num_q: int, num_kv: int, has_rope: bool,
-                      sub_d: int):
+def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse0_ref,
+                      lse1_ref, *rest, scale: float, causal: bool,
+                      block_q: int, block_k: int, num_q: int,
+                      num_kv: int, has_rope: bool, sub_d: int):
     """Packed strip-mined fused backward: the packed analogue of
     `_bwd_fused_kernel` (same grid, same rope-at-the-boundary
     structure), with every matmul full-width.  For n q rows against L
@@ -988,7 +991,8 @@ def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
     cross-head lanes the widened transpose matmuls produce.  Which
     (n, L) pieces of a strip run, and which of them are masked, is the
     schedule's (:func:`_causal_walk`): dq accumulates by row sub-block,
-    dk/dv into the rows of their scratch the piece covers."""
+    dk/dv into the rows of their scratch the piece covers; a sub-head's
+    ``delta = sum(do * o)`` over its own lanes, once a grid step."""
     if has_rope:
         (cq_ref, sq_ref, ck_ref, sk_ref,
          dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, krot_sc) = rest
@@ -1011,8 +1015,10 @@ def _bwd_pack2_kernel(q_ref, k_ref, v_ref, do_ref, lse0_ref, lse1_ref,
         qp = _rot2(qp, cq_ref[...], sq_ref[...], sub_d)
     lse0 = lse0_ref[0, 0, 0][:, 0:1]
     lse1 = lse1_ref[0, 0, 0][:, 0:1]
-    delta0 = delta0_ref[0, 0, 0][:, 0:1]
-    delta1 = delta1_ref[0, 0, 0][:, 0:1]
+    prod0, prod1 = _heads2(
+        do.astype(jnp.float32) * o_ref[0, 0].astype(jnp.float32), sub_d)
+    delta0 = jnp.sum(prod0, axis=-1, keepdims=True)       # [bq, 1]
+    delta1 = jnp.sum(prod1, axis=-1, keepdims=True)
     dq_sc[:] = jnp.zeros_like(dq_sc)
 
     for j in range(num_kv):
@@ -1128,15 +1134,12 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
     # fwd bq]): regroup to this pass's blocking and pad to the lanes
     lse = jnp.broadcast_to(lse.reshape(B, H, num_q, bq, 1),
                            (B, H, num_q, bq, STATS_LANES))
-    delta = jnp.broadcast_to(
-        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1).reshape(B, H, num_q, bq, 1),
-        (B, H, num_q, bq, STATS_LANES))
 
     # strip-mined fused path: the whole kv sequence rides as one block
     # and the kernel walks it in bk strips (skipping causally-dead
     # ones).  [Sk, D] f32 scratch x2 bounds it to moderate Sk; longer
-    # sequences take the two-kernel path below.
+    # sequences take the two-kernel path below.  Its grid visits a q
+    # block once, so it takes ``o`` and makes delta itself.
     if Sk * D * 4 * 2 <= _FUSED_BWD_SCRATCH_BYTES:
         qs = pl.BlockSpec((1, 1, bq, D),
                           lambda b, h, g, i: (b, h * group + g, i, 0))
@@ -1166,7 +1169,7 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
                 dimension_semantics=("parallel", "parallel",
                                      "arbitrary", "arbitrary"),
                 **limit),
-            in_specs=[qs, ks, ks, qs, rs, rs, *rope_specs],
+            in_specs=[qs, ks, ks, qs, qs, rs, *rope_specs],
             out_specs=[qs, ks, ks],
             out_shape=[jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
                        jax.ShapeDtypeStruct((B, Hkv, Sk, D), k.dtype),
@@ -1178,10 +1181,16 @@ def _bwd(q, k, v, o, lse, do, *, scale: float, causal: bool,
                 + ([pltpu.VMEM((Sk, D), q.dtype)]
                    if rope is not None else [])),
             interpret=_use_interpret(),
-        )(q, k, v, do, lse, delta, *rope_args)
+        )(q, k, v, do, o, lse, *rope_args)
         return dq, dk, dv
     assert rope is None, \
         "fused rope requires the strip-mined backward (moderate Sk)"
+    # each kernel below visits a q block once a kv block: delta =
+    # sum(do * o), one value a row, comes to them as a slab made here
+    delta = jnp.broadcast_to(
+        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                axis=-1).reshape(B, H, num_q, bq, 1),
+        (B, H, num_q, bq, STATS_LANES))
 
     kv_block = functools.partial(_live_kv_block, causal=causal, block_q=bq,
                                  block_k=bk, window=window, num_kv=num_kv)
@@ -1252,15 +1261,6 @@ def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
     if lse0.shape[3] != bq:
         lse0 = lse0.reshape(B, Hp, num_q, bq, STATS_LANES)
         lse1 = lse1.reshape(B, Hp, num_q, bq, STATS_LANES)
-    # per-sub-head delta = sum(do * o) over each head's own lanes
-    prod = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-        B, Hp, S, 2, sub_d).sum(-1)                      # [B, Hp, S, 2]
-    delta0 = jnp.broadcast_to(
-        prod[..., 0].reshape(B, Hp, num_q, bq, 1),
-        (B, Hp, num_q, bq, STATS_LANES))
-    delta1 = jnp.broadcast_to(
-        prod[..., 1].reshape(B, Hp, num_q, bq, 1),
-        (B, Hp, num_q, bq, STATS_LANES))
 
     qs = pl.BlockSpec((1, 1, bq, Dp), lambda b, h, i: (b, h, i, 0))
     ks = pl.BlockSpec((1, 1, Sk, Dp), lambda b, h, i: (b, h, 0, 0))
@@ -1285,7 +1285,7 @@ def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_PACK2_VMEM_LIMIT_BYTES),
-        in_specs=[qs, ks, ks, qs, rs, rs, rs, rs, *rope_specs],
+        in_specs=[qs, ks, ks, qs, qs, rs, rs, *rope_specs],
         out_specs=[qs, ks, ks],
         out_shape=[jax.ShapeDtypeStruct((B, Hp, S, Dp), q.dtype),
                    jax.ShapeDtypeStruct((B, Hp, Sk, Dp), k.dtype),
@@ -1297,7 +1297,7 @@ def _bwd_pack2(q, k, v, o, lse0, lse1, do, *, scale: float, causal: bool,
             + ([pltpu.VMEM((Sk, Dp), q.dtype)]
                if rope is not None else [])),
         interpret=interpret,
-    )(q, k, v, do, lse0, lse1, delta0, delta1, *rope_args)
+    )(q, k, v, do, o, lse0, lse1, *rope_args)
     return dq, dk, dv
 
 

@@ -14,7 +14,8 @@ def test_cell_finds_its_files_and_its_rehearsal(monkeypatch):  # noqa: F811
     entries go to the end of their lists, and the file is a `benchmark`
     PR's to edit), and it lists the metrics that read in its cell alone,
     which held until PR 62 put ``moe_gather_ms_per_step`` behind them
-    (and PR 63 the ``setup_*`` metrics of every cell behind that):
+    (PR 63 the ``setup_*`` metrics of every cell behind that, and PR 64
+    ``attn_glue_ms_per_step``, the three train cells', last):
     the per-layer metrics are cut behind the last it knew, after an
     assertion of what stands behind; everything else it reads is the
     manifest as it is."""
@@ -27,9 +28,12 @@ def test_cell_finds_its_files_and_its_rehearsal(monkeypatch):  # noqa: F811
     behind = man["per_layer"][known:]
     assert (behind[0]["name"], behind[0]["workloads"]) \
         == ("moe_gather_ms_per_step", [theirs.CELL])
-    # and since PR 63 the set-up's parts, none of them this cell's alone
+    # and since PR 63 the set-up's parts, since PR 64 the attention's
+    # glue, none of them this cell's alone
     assert all(m["name"].startswith("setup_")
-               and m.get("workloads") != [theirs.CELL] for m in behind[1:])
+               and m.get("workloads") != [theirs.CELL] for m in behind[1:-1])
+    assert behind[-1]["name"] == "attn_glue_ms_per_step" \
+        and len(behind[-1]["workloads"]) == 3
     as_far = dict(man, workloads=man["workloads"][:at + 1],
                   per_layer=man["per_layer"][:known])
     monkeypatch.setattr(common, "manifest", lambda: as_far)

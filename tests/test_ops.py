@@ -364,6 +364,124 @@ def test_causal_coverage_at_the_train_cells_shape():
     assert A.train_causal_coverage(1000, 12, 64) == 1.0
 
 
+# delta = sum(do * o) inside the strip-mined backward kernels (PR 64).
+# A kernel whose grid visits a q block once takes ``o`` and makes the
+# row's delta itself; the two-kernel path of long kv sequences visits it
+# once a kv block in each of two kernels and reads the slab XLA makes.
+# ``path, S, heads, K/V heads, head_dim, backward (block_q, block_k),
+# window``: num_q and num_kv of 1 and 2 (q block 1 is an interior
+# block), a K/V group, a window narrower than a block.
+_DELTA_SHAPES = {
+    "packed_1q_1kv": ("pack2", 256, 4, 4, 64, (256, 256), None),
+    "packed_2q_2kv": ("pack2", 256, 4, 4, 64, (128, 128), None),
+    "packed_1q_2kv": ("pack2", 256, 2, 2, 64, (256, 128), None),
+    "fused_1q_1kv": ("flash", 256, 2, 2, 128, (256, 256), None),
+    "fused_2q_2kv": ("flash", 256, 2, 2, 128, (128, 128), None),
+    "fused_group4_2q_2kv": ("flash", 256, 4, 1, 128, (128, 128), None),
+    "fused_window48_2q_2kv": ("flash", 256, 2, 2, 128, (128, 128), 48),
+    "fused_group2_window48": ("flash", 256, 4, 2, 128, (128, 256), 48),
+}
+_DELTA_CASES = [
+    (shape, causal, rope)
+    for shape, spec in sorted(_DELTA_SHAPES.items())
+    for causal in ((True,) if spec[-1] else (True, False))
+    for rope in (False, True)]
+
+
+def _delta_inputs(S, H, Hkv, D):
+    ks = jax.random.split(jax.random.PRNGKey(64), 4)
+    q = jax.random.normal(ks[0], (1, S, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (1, S, Hkv, D), jnp.float32)
+    # values off zero: o has a mean, so delta is large beside dp - delta
+    v = jax.random.normal(ks[2], (1, S, Hkv, D), jnp.float32) + 1.0
+    w = jax.random.normal(ks[3], (1, S, H, D), jnp.float32)
+    return q, k, v, w
+
+
+_DELTA_LOSSES = {"weighted": lambda o, w: (o * w).sum(),
+                 "square": lambda o, w: (o ** 2).sum()}
+
+
+def _stats_operands(fn, *args):
+    """For each ``pallas_call`` a function traces to, how many lane-padded
+    f32 row-stats slabs (``[.., STATS_LANES]``) it takes as operands."""
+    from ray_tpu.ops.substrate import STATS_LANES
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield sum(v.aval.shape[-1:] == (STATS_LANES,)
+                          and v.aval.dtype == jnp.float32
+                          for v in eqn.invars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _backward_slabs_after_grads_match(kernel, einsum, q, k, v):
+    """The kernels' three gradients against the einsum's, then the stats
+    slabs each traced ``pallas_call`` of the kernels' gradient takes."""
+    grad = jax.grad(kernel, (0, 1, 2))
+    for name, a, b in zip(("dq", "dk", "dv"), grad(q, k, v),
+                          jax.grad(einsum, (0, 1, 2))(q, k, v)):
+        assert _rel(a, b) < 1e-5, (name, _rel(a, b))
+    return _stats_operands(grad, q, k, v)
+
+
+@pytest.mark.parametrize("loss", sorted(_DELTA_LOSSES))
+@pytest.mark.parametrize(
+    "shape,causal,rope", _DELTA_CASES,
+    ids=[f"{s}-{'causal' if c else 'full'}-{'rope' if r else 'norope'}"
+         for s, c, r in _DELTA_CASES])
+def test_fused_backwards_make_delta_themselves_and_match_einsum(
+        shape, causal, rope, loss):
+    # the gradients of both strip-mined backwards against the einsum's
+    # under losses whose cotangent is not uniform, and the structure:
+    # neither kernel is handed a delta slab (the packed one reads its
+    # two lse slabs, the single-head one its one)
+    path, S, H, Hkv, D, (wq, wk), window = _DELTA_SHAPES[shape]
+    q, k, v, w = _delta_inputs(S, H, Hkv, D)
+    pos = jnp.arange(S) if rope else None
+    assert A.uses_pack2(S, S, H, D, block_q=wq, block_k=wk) \
+        == (path == "pack2")
+
+    def kernel(q, k, v):
+        o = A.flash_attention(q, k, v, causal=causal, block_q=wq,
+                              block_k=wk, bwd_block_q=wq, bwd_block_k=wk,
+                              positions=pos, window=window)
+        return _DELTA_LOSSES[loss](o, w)
+
+    def einsum(q, k, v):
+        if rope:
+            q, k = (A.rope_rotate(x, pos, 10000.0) for x in (q, k))
+        return _DELTA_LOSSES[loss](
+            A.xla_attention(q, k, v, causal=causal, window=window), w)
+
+    slabs = _backward_slabs_after_grads_match(kernel, einsum, q, k, v)
+    # forward (no stats in), backward (lse alone)
+    assert slabs == ([0, 2] if path == "pack2" else [0, 1]), slabs
+
+
+@pytest.mark.parametrize("loss", sorted(_DELTA_LOSSES))
+def test_two_kernel_backward_keeps_the_delta_slab_xla_makes(monkeypatch,
+                                                            loss):
+    # long kv sequences: dq and dk / dv are two kernels, each visits a
+    # q block once a kv block, and both read lse and delta as slabs
+    monkeypatch.setattr(A, "_FUSED_BWD_SCRATCH_BYTES", 0)
+    q, k, v, w = _delta_inputs(384, 4, 2, 128)
+
+    def kernel(q, k, v):
+        o = A.flash_attention(q, k, v, block_q=128, block_k=128,
+                              bwd_block_q=128, bwd_block_k=128)
+        return _DELTA_LOSSES[loss](o, w)
+
+    def einsum(q, k, v):
+        return _DELTA_LOSSES[loss](A.xla_attention(q, k, v), w)
+
+    assert _backward_slabs_after_grads_match(kernel, einsum, q, k, v) \
+        == [0, 2, 2]
+
+
 def test_attention_config_env_escape_hatch(monkeypatch):
     # RAY_TPU_ATTN_PACK2=0 is the documented escape hatch; the config
     # caches, so flips re-resolve via refresh=True

@@ -57,6 +57,33 @@ def _compile_for_v5e(fn, sharding, *shapes):
     return compiled
 
 
+def _instructions(hlo):
+    """The HLO instructions in a compiled module's text: a warm worker's
+    load of an executable follows them (PERF.md section 6, PR 58)."""
+    return sum(1 for line in hlo.splitlines()
+               if re.match(r"\s+(ROOT )?%?[\w.\-]+ = ", line))
+
+
+def _stats_operands(hlo, scope):
+    """For each backward Mosaic call under ``scope`` in a compiled
+    module's text, how many lane-padded f32 row-stats slabs
+    (``[.., STATS_LANES]``) it takes as operands: its ``lse`` alone
+    since PR 64 (two for a packed pair of heads), which makes ``delta =
+    sum(do * o)`` in the kernel; one more a sub-head before."""
+    counts = []
+    for line in hlo.splitlines():
+        name = re.search(r'op_name="([^"]+)"', line)
+        if ("tpu_custom_call" not in line or name is None
+                or scope not in name.group(1)
+                or "transpose(jvp(" not in name.group(1)):
+            continue
+        operands = re.search(
+            r"operand_layout_constraints=\{(.*?)\}, frontend", line).group(1)
+        counts.append(len(re.findall(
+            rf"f32\[[\d,]*,{substrate.STATS_LANES}\]", operands)))
+    return counts
+
+
 @pytest.mark.parametrize("batch, seq, pack2", [
     (B, S, True), (B, S, False),
     # the train cells' exact call: the fn build_gpt_train makes, fused
@@ -65,7 +92,10 @@ def _compile_for_v5e(fn, sharding, *shapes):
     (B, S, None),
     # two blocks of 1024 a side: an interior block, walked unmasked
     (B // 2, 2 * S, None),
-], ids=["pack2", "single_head", "train_cell", "seq2048"])
+    # the packed gate's edge: the longest kv sequence whose [Sk, 128]
+    # f32 dk / dv scratch uses_pack2 admits, eight blocks a side
+    (1, 8 * S, None),
+], ids=["pack2", "single_head", "train_cell", "seq2048", "pack2_gate_edge"])
 def test_flash_attention_fwd_bwd_compiles_for_v5e(v5e, batch, seq, pack2):
     attn = attention.make_flash_attention_fn(rope_theta=10000.0,
                                              pack2=pack2)
@@ -79,7 +109,15 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(v5e, batch, seq, pack2):
                            *[((batch, seq, H, D), BF16)] * 3).as_text()
     if pack2 is not False:
         assert attention.uses_pack2(seq, seq, H, D, pack2=pack2)
+        if seq == 8 * S:
+            assert not attention.uses_pack2(2 * seq, 2 * seq, H, D)
         assert "attn/pack2" in hlo and "attn/flash" not in hlo
+        # the backward is handed o and its two lse slabs: no delta slab,
+        # and nothing under the dispatcher's scope broadcasts one
+        assert _stats_operands(hlo, "attn/pack2") == [2]
+        assert "attn/pack2))/broadcast_in_dim" not in hlo
+    else:
+        assert _stats_operands(hlo, "attn/flash") == [1]
 
 
 def test_flash_ce_with_norm_fwd_bwd_compiles_for_v5e(v5e):
@@ -135,14 +173,21 @@ def test_train_step_loss_head_and_memory_on_v5e(v5e, ce_chunk, n_layers,
              for k in ("tokens", "targets")}
     with substrate.compile_for_tpu():
         compiled = fns["step_fn"].lower(state, batch).compile()
-    kernels = [line for line in compiled.as_text().splitlines()
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
                if "tpu_custom_call" in line and "op_name=" in line]
     assert any("attn/pack2" in line for line in kernels)
+    # PR 64: a layer's backward kernel makes delta from o; the step holds
+    # no slab of it and none of the instructions that made one
+    assert _stats_operands(hlo, "attn/pack2") == [2] * n_layers
+    assert "attn/pack2/broadcast_in_dim" not in hlo
     # PR 53: a differentiated step takes XLA's out-proj epilogue
     assert not [line for line in kernels if "norm/fused_epilogue" in line]
     assert sum("ce/flash" in line for line in kernels) == flash_calls, \
         [line for line in kernels if "/ce" in line]
     if n_layers == 12:
+        # 12,100 with XLA's delta (PR 63's tree), 11,440 without
+        assert _instructions(hlo) <= 12_100, _instructions(hlo)
         mem = compiled.memory_analysis()
         taken = mem.argument_size_in_bytes + mem.temp_size_in_bytes
         assert taken < TRAIN_STEP_LIMIT_BYTES < V5E_HBM_BYTES, (
@@ -181,6 +226,8 @@ def test_window_and_kv_group_kernels_compile_for_v5e(v5e, window):
                            ((b, s, kv, d), BF16)).as_text()
     # one forward and one fused backward kernel, and dk / dv at K/V's size
     assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    # ... which is handed o and lse: no delta slab (PR 64)
+    assert _stats_operands(hlo, "attn/flash") == [1]
     assert f"bf16[{b},{kv},{s},{d}]" in hlo
     cover = attn.coverage(s, h, d)
     assert cover["needed"] < cover["executed"] < (0.2 if window else 0.55)
@@ -237,6 +284,8 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     # one forward (the down projection's rows) and one backward (the
     # gradients in the sorted rows)
     hlo = compiled.as_text()
+    # each attention layer's backward makes delta from o: lse is its one slab
+    assert _stats_operands(hlo, "attn/flash") == [1] * 4
     assert "ragged-dot-none" not in hlo
     products = [line for line in kernels if "moe/experts" in line]
     assert len(products) == 4 * (8 + 9) == len(kernels) - len(flash)
@@ -269,8 +318,7 @@ def test_routed_train_step_fits_a_v5e_with_its_cells_recipe(v5e):
     # a warm worker's load costs ~0.23 ms an instruction of the compiled
     # module (PERF.md section 6, PR 58): the schedule of the combines'
     # runs is inlined once a layer and direction
-    instructions = sum(1 for line in hlo.splitlines() if re.match(
-        r"\s+(ROOT )?%?[\w.\-]+ = ", line))
+    instructions = _instructions(hlo)
     assert instructions <= 16_530 * 1.02, (
         f"{instructions} HLO instructions; the parent's step (PR 58, "
         "XLA's gather for the combine) held 16,530")
